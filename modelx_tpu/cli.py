@@ -474,35 +474,33 @@ def cmd_programs_list(ref: str) -> None:
                    "(the program shapes differ from bf16)")
 @click.option("--cache-dir", default="",
               help="AOT cache dir to export into and bundle from (default: "
-                   "a temp dir — export, publish, discard)")
+                   "an emptied .cache/xla/programs-push under the checkout, "
+                   "so the bundle holds this surface and nothing else)")
 def cmd_programs_push(ref: str, quantize: str | None, cache_dir: str) -> None:
     """Export a model version's compiled surface and attach it as a
     program bundle. Works from the manifest's tensor index alone — no
     weight bytes are pulled; the next pod's pull then boots
     compile-warm."""
-    import tempfile
-
     try:
         r = parse_reference(ref)
         if not r.repository or not r.version:
             raise ValueError("programs push needs repo@version "
                              "(bundles pin the exact version they compile for)")
         from modelx_tpu.dl import program_store
-        from modelx_tpu.dl.serve import enable_compile_cache
+        from modelx_tpu.dl.serve import cold_cache_dir, enable_compile_cache
 
         client = r.client(quiet=True)
         manifest = client.get_manifest(r.repository, r.version)
-        with tempfile.TemporaryDirectory(prefix="modelx-programs-") as tmp:
-            out_dir = cache_dir or tmp
-            enable_compile_cache(out_dir)
-            family, cfg, sds, mesh = program_store.plan_from_manifest(
-                client, r.repository, manifest, quantize=quantize
-            )
-            keys = program_store.export_surface(family, cfg, sds, mesh, out_dir)
-            data = program_store.build_bundle(out_dir, keys=keys, mesh=mesh)
-            if data is None:
-                raise ValueError("no programs exported; nothing to push")
-            desc = program_store.publish(client.remote, r.repository, r.version, data)
+        out_dir = cache_dir or cold_cache_dir("programs-push")
+        enable_compile_cache(out_dir)
+        family, cfg, sds, mesh = program_store.plan_from_manifest(
+            client, r.repository, manifest, quantize=quantize
+        )
+        keys = program_store.export_surface(family, cfg, sds, mesh, out_dir)
+        data = program_store.build_bundle(out_dir, keys=keys, mesh=mesh)
+        if data is None:
+            raise ValueError("no programs exported; nothing to push")
+        desc = program_store.publish(client.remote, r.repository, r.version, data)
         click.echo(json.dumps({
             "name": desc.name, "digest": str(desc.digest), "size": desc.size,
             "programs": len(keys), "family": family.name,

@@ -22,9 +22,6 @@ import logging
 import os
 
 import jax
-# jax < 0.6 doesn't bind the ``export`` submodule on bare ``import jax``;
-# importing it explicitly makes ``jax.export.*`` resolve on every version
-from jax import export as _jax_export  # noqa: F401
 
 logger = logging.getLogger("modelx.aot")
 

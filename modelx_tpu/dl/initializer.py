@@ -330,7 +330,7 @@ def _blob_source(client, repository: str, blob, cache=None,
                  prefer_local: bool | None = None):
     """Best transport for a blob, tier by tier: a readable ``file``
     location (colocated registry / shared volume) beats everything — local
-    preads cost no server round-trips and no tunnel bytes; next the local
+    preads cost no server round-trips and no network bytes; next the local
     blob cache (dl/blob_cache.py) serves a digest-verified copy with zero
     network reads; finally the remote paths (presigned URL or the direct
     blob endpoint), teed into the cache for the next deploy.

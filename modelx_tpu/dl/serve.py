@@ -33,10 +33,10 @@ directory carries a ``tokenizer.json`` (pulled alongside the weights),
 ``/v1/generate`` also takes ``{"text": "..."}`` and returns the decoded
 continuation.
 
-Compile latency: a persistent XLA compilation cache can be enabled
-(MODELX_COMPILE_CACHE or ~/.cache/modelx-tpu/xla) so a sidecar restart
-skips recompilation — the TTFT budget (BASELINE: p50 < 500 ms) has no room
-for a cold pjit.
+Compile latency: a persistent XLA compilation cache is on by default
+(``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.cache/xla`` —
+see :func:`enable_compile_cache`) so a sidecar restart skips recompilation
+— the TTFT budget (BASELINE: p50 < 500 ms) has no room for a cold pjit.
 """
 
 from __future__ import annotations
@@ -47,6 +47,7 @@ import json
 import logging
 import os
 import re
+import shutil
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -194,7 +195,37 @@ class _Tokenizer:
         return self._eos
 
 
+# The one fallback when JAX_COMPILATION_CACHE_DIR is unset: a fixed path
+# inside the checkout. The directory is how one run finds another's entries
+# (a path that moves never hits), so nothing derives it from a pid, a clock
+# or a temporary name.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".cache", "xla",
+)
+
 _compile_cache_dir = ""  # set by enable_compile_cache; "" = cold every start
+
+# this process's persistent-cache traffic, counted from jax's own monitoring
+# events: requests = compiles that consulted the cache, hits = executables
+# read back, misses = entries written after a real compile
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "requests",
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "misses",
+}
+_cache_counts = {"requests": 0, "hits": 0, "misses": 0}
+_cache_counts_lock = threading.Lock()
+
+
+def _count_cache_event(event: str, **_kwargs) -> None:
+    key = _CACHE_EVENTS.get(event)
+    if key is not None:
+        with _cache_counts_lock:
+            _cache_counts[key] += 1
+
+
+jax.monitoring.register_event_listener(_count_cache_event)
 
 
 def compile_cache_dir() -> str:
@@ -203,15 +234,42 @@ def compile_cache_dir() -> str:
     return _compile_cache_dir
 
 
+def compile_cache_stats() -> dict:
+    """``{"dir", "requests", "hits", "misses"}`` for /metrics: whether a
+    restart found its programs is read here, not inferred from timing."""
+    with _cache_counts_lock:
+        return {"dir": _compile_cache_dir, **_cache_counts}
+
+
+def cold_cache_dir(leg: str) -> str:
+    """An EMPTY cache directory for a measurement whose meaning is a cold
+    start (the program-store legs): a fixed name under the default cache,
+    cleared on every call rather than renamed."""
+    path = os.path.join(DEFAULT_COMPILE_CACHE_DIR, leg)
+    shutil.rmtree(path, ignore_errors=True)
+    os.makedirs(path)
+    return path
+
+
 def enable_compile_cache(path: str = "") -> None:
-    """Persistent XLA compilation cache (idempotent)."""
+    """Persistent XLA compilation cache (idempotent).
+
+    The directory is ``JAX_COMPILATION_CACHE_DIR`` when the environment sets
+    it — jax reads the variable itself, and this function then points the
+    cache nowhere else — otherwise :data:`DEFAULT_COMPILE_CACHE_DIR`. An
+    explicit ``path`` is for :func:`cold_cache_dir` legs only."""
     global _compile_cache_dir
-    path = path or os.environ.get(
-        "MODELX_COMPILE_CACHE", os.path.expanduser("~/.cache/modelx-tpu/xla")
-    )
+    path = (path or os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or DEFAULT_COMPILE_CACHE_DIR)
     try:
         os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
+        if jax.config.jax_compilation_cache_dir != path:
+            from jax.experimental.compilation_cache import compilation_cache
+
+            jax.config.update("jax_compilation_cache_dir", path)
+            # jax opens its cache once, at the first compile: a process that
+            # already compiled keeps writing to the old directory otherwise
+            compilation_cache.reset_cache()
         # no min-compile-time floor: the program store (dl/program_store.py)
         # ships this cache's executables fleet-wide, and a program under
         # the default 1 s threshold would stay cold on EVERY pod — small
@@ -224,8 +282,8 @@ def enable_compile_cache(path: str = "") -> None:
         # dirs) could never hit each other's shipped executables
         jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
         _compile_cache_dir = path
-    except Exception as e:  # cache is an optimization, never fatal
-        logger.warning("compile cache unavailable: %s", e)
+    except OSError as e:  # cache is an optimization, never fatal
+        logger.warning("compile cache unavailable at %s: %s", path, e)
 
 
 class ModelServer:
@@ -401,6 +459,12 @@ class ModelServer:
             self.stats["load_seconds"] = round(seconds, 3)
             self.stats["load_bytes"] = total
             self.stats["load_gbps"] = round(total / max(seconds, 1e-9) / 1e9, 3)
+            from modelx_tpu import native
+
+            # whether the loader's fetch/hash hot loop ran on the C++ engine
+            # or on its pure-Python stand-in (native.lib() warns when it is
+            # the latter; this says it where a dashboard looks)
+            self.stats["native_io"] = native.available()
             self._compile()
             if compile_thread is not None:
                 compile_thread.join()
@@ -2084,10 +2148,13 @@ def serve(servers: ModelServer | ServerSet, listen: str = ":8000",
                 payload["control_plane"] = _mc.health().status()
                 if sset.device_telemetry:
                     # measured device memory next to the lifecycle
-                    # ESTIMATES (hbm_reserved_bytes): the source key is
-                    # a string, skipped by the text renderer, kept in
-                    # JSON so a reader knows how it was measured
+                    # ESTIMATES (hbm_reserved_bytes), plus what jax found
+                    # (platform / device_kind / device_count): the string
+                    # keys are skipped by the text renderer, kept in JSON
+                    # so a reader knows what was measured and how
                     payload["device"] = devmem.sample()
+                if compile_cache_dir():
+                    payload["compile_cache"] = compile_cache_stats()
                 # content negotiation (ISSUE 13): the SAME tree renders
                 # as Prometheus text on Accept: text/plain or
                 # ?format=prometheus; the default JSON is byte-unchanged
@@ -2419,7 +2486,7 @@ def serve(servers: ModelServer | ServerSet, listen: str = ":8000",
                         )
                     if n_pos and tokens.shape[1] + n > n_pos:
                         # decode past n_positions would silently clamp the
-                        # wpe gather (ADVICE r3, gpt2.py:101)
+                        # wpe gather (gpt2.py:101)
                         return self._json(400, {
                             "error": f"prompt ({tokens.shape[1]}) + "
                             f"max_new_tokens ({n}) exceeds the model's "

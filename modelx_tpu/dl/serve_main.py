@@ -279,6 +279,10 @@ def main(model_dir: str, models: tuple[str, ...], mesh: str, dtype: str, listen:
          flight_dump_dir: str, flightrec_capacity: int,
          flight_recorder: bool, device_telemetry: bool) -> None:
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(name)s %(message)s")
+    # `modelx serve-model` arrives with the CLI group's WARNING root already
+    # configured (basicConfig above is then a no-op): a pod's start-up lines
+    # — what it runs on, what it loaded — must print either way
+    logging.getLogger("modelx").setLevel(logging.INFO)
     from modelx_tpu.parallel.distributed import initialize
 
     initialize()  # no-op single-process; wires multi-host TPU pods
@@ -335,11 +339,17 @@ def main(model_dir: str, models: tuple[str, ...], mesh: str, dtype: str, listen:
 
     from modelx_tpu.parallel.mesh import make_mesh
 
-    shared_mesh = make_mesh(mesh) if mesh else make_mesh(f"dp={len(jax.devices())}")
+    devices = jax.devices()
+    shared_mesh = make_mesh(mesh) if mesh else make_mesh(f"dp={len(devices)}")
     from modelx_tpu.parallel.mesh import mesh_str, weight_shard_factor
 
+    # what jax actually found: a pod whose accelerator did not come up runs
+    # on the CPU backend and must say so (the same three fields ride the
+    # /metrics "device" block, utils/devmem.py)
     logging.getLogger("modelx.serve").info(
-        "serving mesh %s (%d device(s), weight shard factor %d)",
+        "devices: platform=%s kind=%s count=%d; serving mesh %s "
+        "(%d device(s), weight shard factor %d)",
+        devices[0].platform, devices[0].device_kind, len(devices),
         mesh_str(shared_mesh), shared_mesh.size,
         weight_shard_factor(shared_mesh),
     )

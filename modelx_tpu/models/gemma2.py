@@ -172,8 +172,11 @@ def _attend(q, k, v, cfg: Gemma2Config, q_offset, window: int,
                   logit_softcap=cfg.attn_logit_softcap, window=window)
     sp_active = (mesh is not None and "sp" in mesh.axis_names
                  and mesh.shape["sp"] > 1)
-    if prefill and not sp_active and jax.default_backend() == "tpu":
-        out = attn_ops.flash_attention(qt, kt, vt, causal=True, **kwargs)
+    flash = prefill and not sp_active and jax.default_backend() == "tpu"
+    attn_ops.note_choice("flash" if flash else "reference",
+                         qt.shape[2], kt.shape[2], mesh)
+    if flash:
+        out = attn_ops.flash_attention(qt, kt, vt, causal=True, mesh=mesh, **kwargs)
     else:
         out = attn_ops.attention_reference(
             qt, kt, vt, causal=True, q_offset=q_offset, **kwargs

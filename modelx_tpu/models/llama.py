@@ -351,10 +351,15 @@ def forward(
 
 def _attend(q, k, v, cfg: LlamaConfig, causal: bool, q_offset, mesh, impl: str):
     """q: [B,S,H,D], k/v: [B,S(,kv)...]. Transposes to [B,H,S,D] and picks
-    the attention implementation."""
+    the attention implementation. ``"auto"`` picks from what it can observe
+    (an sp axis -> ring; the TPU backend -> the pallas kernel; else the jnp
+    reference) and the pick is recorded (``attn_ops.note_choice``). A
+    ``"+interpret"`` suffix (``"flash+interpret"``) runs the kernel in
+    pallas interpret mode: callers on the CPU ask for it by name."""
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
+    impl, _, flag = impl.partition("+")
     if impl == "auto":
         if mesh is not None and "sp" in mesh.axis_names and mesh.shape["sp"] > 1:
             impl = "ring"
@@ -362,12 +367,16 @@ def _attend(q, k, v, cfg: LlamaConfig, causal: bool, q_offset, mesh, impl: str):
             impl = "flash"
         else:
             impl = "reference"
+    attn_ops.note_choice(impl, qt.shape[2], kt.shape[2], mesh)
+    interpret = flag == "interpret"
     if impl == "ring":
         out = attn_ops.ring_attention(qt, kt, vt, mesh, axis="sp", causal=causal)
     elif impl == "ulysses":
-        out = attn_ops.ulysses_attention(qt, kt, vt, mesh, axis="sp", causal=causal)
+        out = attn_ops.ulysses_attention(qt, kt, vt, mesh, axis="sp", causal=causal,
+                                         interpret=interpret)
     elif impl == "flash":
-        out = attn_ops.flash_attention(qt, kt, vt, causal=causal)
+        out = attn_ops.flash_attention(qt, kt, vt, causal=causal, mesh=mesh,
+                                       interpret=interpret)
     else:
         out = attn_ops.attention_reference(qt, kt, vt, causal=causal, q_offset=q_offset)
     return out.transpose(0, 2, 1, 3)

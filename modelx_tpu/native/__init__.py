@@ -6,13 +6,20 @@ addressing) are C++ compiled on demand with the baked-in g++ and loaded via
 ctypes — every call releases the GIL for its full duration, so loader fetch
 threads don't contend with the jax.device_put dispatch thread.
 
-Degrades gracefully: if the toolchain or a prebuilt .so is unavailable,
-``lib()`` returns None and callers keep their pure-Python paths.
+The library is named after the digest of the source it was built from
+(``_build/libmodelx_io-<sha256[:16]>.so``), so a binary is only ever loaded
+for the exact ``modelx_io.cc`` beside it: a file left in ``_build/`` by
+another checkout, or one whose mtime merely looks newer, cannot stand in
+for the committed source. If the library cannot be built or loaded,
+``lib()`` says why at WARNING (compiler stderr included), returns None, and
+callers keep their pure-Python paths.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -21,7 +28,7 @@ import threading
 logger = logging.getLogger("modelx.native")
 
 _SRC = os.path.join(os.path.dirname(__file__), "modelx_io.cc")
-_SO = os.path.join(os.path.dirname(__file__), "_build", "libmodelx_io.so")
+_BUILD_DIR = os.path.join(os.path.dirname(__file__), "_build")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -36,38 +43,48 @@ class MxRange(ctypes.Structure):
     ]
 
 
+def so_path() -> str:
+    """Where the library for the CURRENT source lives (whether or not it
+    has been built yet)."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"libmodelx_io-{digest}.so")
+
+
 def build(force: bool = False) -> str | None:
-    """Compile modelx_io.cc -> _build/libmodelx_io.so. Returns the path, or
-    None when no toolchain is available. Cached: skips when the .so is newer
-    than the source."""
-    if (
-        not force
-        and os.path.exists(_SO)
-        and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
-    ):
-        return _SO
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
+    """Compile modelx_io.cc -> ``so_path()``. Returns the path, or None
+    (with a WARNING carrying the compiler's stderr) when it cannot be
+    built. Cached by source digest: an existing library for this exact
+    source is reused — that is how a container image that bakes the .so
+    but ships no toolchain keeps working — and one built from any other
+    source is never picked up."""
+    so = so_path()
+    if not force and os.path.exists(so):
+        return so
+    os.makedirs(_BUILD_DIR, exist_ok=True)
     # per-process temp output so concurrent builds can't corrupt each other;
     # os.replace publishes atomically and last-writer-wins is fine (same src)
-    tmp = f"{_SO}.{os.getpid()}.tmp"
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-o", tmp, _SRC, "-ldl"]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
+        os.replace(tmp, so)
     except (OSError, subprocess.SubprocessError) as e:
         try:
             os.unlink(tmp)
         except OSError:
             pass
-        if os.path.exists(_SO):
-            # a container image bakes the arch-correct .so but ships no
-            # toolchain, and install mtimes can make the source look newer
-            # — an existing library beats the pure-Python fallback
-            logger.debug("native rebuild unavailable (%s); using existing .so", e)
-            return _SO
-        logger.debug("native build unavailable: %s", e)
+        stderr = getattr(e, "stderr", b"") or b""
+        logger.warning("native IO engine build failed (%s)%s", e,
+                       ": " + stderr.decode(errors="replace")[-2000:] if stderr else "")
         return None
-    return _SO
+    for stale in glob.glob(os.path.join(_BUILD_DIR, "libmodelx_io*.so")):
+        if stale != so:  # libraries of earlier sources: never loaded again
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass
+    return so
 
 
 def lib() -> ctypes.CDLL | None:
@@ -80,13 +97,14 @@ def lib() -> ctypes.CDLL | None:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        path = build()  # no-op when the .so is newer than the source
+        path = build()  # no-op when this source's library already exists
         if path is None:
             return None
         try:
             l = ctypes.CDLL(path)
         except OSError as e:
-            logger.debug("native load failed: %s", e)
+            logger.warning("native IO engine load failed (%s): pure-Python "
+                           "fetch/hash paths in use", e)
             return None
         l.mx_pread_scatter.argtypes = [
             ctypes.c_char_p, ctypes.POINTER(MxRange), ctypes.c_int, ctypes.c_int,
@@ -109,17 +127,11 @@ def lib() -> ctypes.CDLL | None:
             ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
         ]
         l.mx_http_get_range.restype = ctypes.c_int
-        try:
-            # a baked .so from an older build may predate this entry point;
-            # the quantize wrapper then falls back to numpy — the rest of
-            # the engine must keep working (degrade, don't raise)
-            l.mx_quantize_rows.argtypes = [
-                ctypes.c_void_p, ctypes.c_int, ctypes.c_int64, ctypes.c_int64,
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int,
-            ]
-            l.mx_quantize_rows.restype = ctypes.c_int
-        except AttributeError:
-            logger.debug("native quantize unavailable (stale .so)")
+        l.mx_quantize_rows.argtypes = [
+            ctypes.c_void_p, ctypes.c_int, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int,
+        ]
+        l.mx_quantize_rows.restype = ctypes.c_int
         _lib = l
         return _lib
 
@@ -226,7 +238,7 @@ def quantize_rows(arr, scales=None, want_q: bool = True, threads: int = 0):
     import numpy as np
 
     l = lib()
-    if l is None or not hasattr(l, "mx_quantize_rows"):
+    if l is None:
         return None
     arr = np.asarray(arr)
     if arr.ndim != 2:

@@ -523,8 +523,8 @@ int mx_http_get_range(MxConn *c, const char *host_hdr, const char *path,
 // ops/quant.py's host-side path (channel_scales + quantize_rows) runs
 // several full numpy passes over the weight — and for bfloat16 sources the
 // ml_dtypes ufuncs are generic element loops, which made `--quantize int8`
-// LOSE the load race on small-core hosts (BENCH_r04: 9.6 s to quantize a
-// 0.44 GB checkpoint). This is the same work as ONE fused pass per row:
+// LOSE the load race on hosts with few cores. This is the same work as ONE
+// fused pass per row:
 // absmax -> scale -> round-to-int8, GIL-free and threaded, numerically
 // identical to the numpy path (f32 divide, round-half-to-even, scale
 // computed in double exactly like numpy's f64 divide + f32 cast).

@@ -14,8 +14,16 @@ TPU-first design notes (pallas_guide.md):
   `ppermute`s k/v around the ring, so peak memory per device is
   O(seq/sp_devices) and comms ride ICI neighbor links.
 
-All three paths compute the same math; tests cross-check them (CPU uses
-interpret mode for the pallas kernel).
+All three paths compute the same math; tests cross-check them (on the CPU
+they ask for the pallas kernel's interpret mode by name — nothing selects
+it for them).
+
+Nothing here substitutes one implementation for another behind the
+caller's back: ``flash_attention`` always runs the kernel (padding ragged
+lengths up to the block), compiles it for the backend it is on, and under a
+multi-device mesh wraps it in ``shard_map`` — a bare Mosaic call cannot be
+partitioned by GSPMD. Which implementation a model's forward compiled with
+is recorded at trace time by :func:`note_choice`.
 """
 
 from __future__ import annotations
@@ -25,11 +33,19 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
-from modelx_tpu.utils.jax_compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from modelx_tpu.utils import trace
+
 NEG_INF = -1e30
+FLASH_BLOCK = 128  # q and k block: one MXU tile edge
+# rows per packed bf16 sublane tile: Mosaic refuses a block (and the k-loop's
+# dynamic slice) whose row count is not a multiple of the tile — "cannot
+# statically prove that index in dimension 1 is a multiple of 8" for a
+# 5-token /v1/forward, which interpret mode never shows
+FLASH_ROW_TILE = 16
 
 
 # -- reference (jnp) ----------------------------------------------------------
@@ -81,13 +97,16 @@ def _repeat_kv_heads(q, k, v):
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
-                  sm_scale: float, logit_softcap: float = 0.0, window: int = 0):
+                  sm_scale: float, logit_softcap: float = 0.0, window: int = 0,
+                  kv_len: int = 0):
     """One (batch*head, q-block) program: online softmax over k/v blocks.
 
     q_ref: [block_q, d], k_ref/v_ref: [seq_k, d], o_ref: [block_q, d].
     ``logit_softcap`` > 0 tanh-caps the scaled scores before masking and
     ``window`` > 0 limits each query to its last ``window`` keys (gemma2);
-    both default off, preserving the plain flash semantics.
+    both default off, preserving the plain flash semantics. ``kv_len`` > 0
+    says only the first ``kv_len`` keys are real (the rest is block
+    padding) and masks the tail.
     """
     block_q, d = q_ref.shape
     seq_k = k_ref.shape[0]
@@ -103,13 +122,17 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
         )  # [block_q, block_k]
         if logit_softcap > 0.0:
             s = logit_softcap * jnp.tanh(s / logit_softcap)
+        kpos = start_k * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
         if causal:
             qpos = q_idx * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            kpos = start_k * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             visible = kpos <= qpos
             if window > 0:
                 visible = visible & (kpos > qpos - window)
             s = jnp.where(visible, s, NEG_INF)
+        if kv_len:
+            # padded keys sit in the LAST block behind real ones, so every
+            # row's running max is real by then and exp() zeroes them
+            s = jnp.where(kpos < kv_len, s, NEG_INF)
         m_cur = jnp.max(s, axis=-1)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
@@ -143,50 +166,109 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
     o_ref[:] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "causal", "block_q", "block_k", "interpret", "scale", "logit_softcap",
-    "window"))
-def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool | None = None,
-                    scale: float | None = None, logit_softcap: float = 0.0,
-                    window: int = 0):
-    """Flash attention via pallas. q/k/v: [B, H, S, D] (GQA allowed).
+def flash_blocks(seq: int, block: int = FLASH_BLOCK) -> tuple[int, int]:
+    """(block, padded length) the kernel uses for a ``seq``-long axis: one
+    tile-aligned block when the axis is short, otherwise ``block`` with the
+    length rounded up to a multiple of it."""
+    block = min(block, -(-seq // FLASH_ROW_TILE) * FLASH_ROW_TILE)
+    return block, -(-seq // block) * block
 
-    Falls back to interpret mode automatically off-TPU so the same call site
-    works in CPU tests (pallas_guide.md: interpret=True for debugging).
-    ``scale``/``logit_softcap``/``window`` mirror attention_reference — the
-    gemma2 prefill rides the MXU kernel with its own semantics.
-    """
+
+def _flash_local(q, k, v, *, causal, block_q, block_k, interpret, scale,
+                 logit_softcap, window):
+    """The kernel on ONE device's share: q [B, H, Sq, D], k/v [B, Hkv, Sk, D].
+    Ragged lengths are padded up to the block (padded keys masked in the
+    kernel, padded query rows sliced off) — never handed to another
+    implementation."""
     q, k, v = _repeat_kv_heads(q, k, v)
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    if sq % block_q or sk % block_k:  # ragged fallback
-        return attention_reference(q, k, v, causal=causal, scale=scale,
-                                   logit_softcap=logit_softcap, window=window)
+    block_q, pq = flash_blocks(sq, block_q)
+    block_k, pk = flash_blocks(sk, block_k)
     sm_scale = scale if scale is not None else 1.0 / math.sqrt(d)
 
-    qr = q.reshape(b * h, sq, d)
-    kr = k.reshape(b * h, sk, d)
-    vr = v.reshape(b * h, sk, d)
+    def rows(x, s, padded):
+        x = x.reshape(b * h, s, d)
+        return jnp.pad(x, ((0, 0), (0, padded - s), (0, 0))) if padded != s else x
+
     out = pl.pallas_call(
         functools.partial(_flash_kernel, block_k=block_k, causal=causal,
                           sm_scale=sm_scale, logit_softcap=logit_softcap,
-                          window=window),
-        grid=(b * h, sq // block_q),
+                          window=window, kv_len=sk if pk != sk else 0),
+        grid=(b * h, pq // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, pk, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, pk, d), lambda i, j: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b * h, pq, d), q.dtype),
         interpret=interpret,
-    )(qr, kr, vr)
-    return out.reshape(b, h, sq, d)
+    )(rows(q, sq, pq), rows(k, sk, pk), rows(v, sk, pk))
+    return out[:, :sq].reshape(b, h, sq, d)
+
+
+def _axes_dividing(mesh: Mesh, names: tuple[str, ...], dim: int):
+    """The mesh axes among ``names`` (size > 1) whose product divides
+    ``dim`` — as a PartitionSpec entry (None when there are none)."""
+    kept = tuple(a for a in names if a in mesh.axis_names and mesh.shape[a] > 1)
+    if not kept or dim % math.prod(mesh.shape[a] for a in kept):
+        return None
+    return kept if len(kept) > 1 else kept[0]
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "block_q", "block_k", "interpret", "scale", "logit_softcap",
+    "window", "mesh"))
+def flash_attention(q, k, v, causal: bool = True, block_q: int = FLASH_BLOCK,
+                    block_k: int = FLASH_BLOCK, interpret: bool = False,
+                    scale: float | None = None, logit_softcap: float = 0.0,
+                    window: int = 0, mesh: Mesh | None = None):
+    """Flash attention via pallas. q/k/v: [B, H, S, D] (GQA allowed).
+
+    The kernel compiles for the backend it runs on; ``interpret=True`` is
+    for callers on the CPU that ask for it (tests, the virtual-device dry
+    run) and is never chosen here. ``scale``/``logit_softcap``/``window``
+    mirror attention_reference — the gemma2 prefill rides the MXU kernel
+    with its own semantics.
+
+    Under a ``mesh`` of more than one device the call is wrapped in
+    ``shard_map`` — batch over dp/fsdp, heads over tp, wherever they divide
+    — because GSPMD cannot partition a Mosaic kernel by itself; an axis
+    that does not divide leaves that dimension replicated.
+    """
+    local = functools.partial(
+        _flash_local, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=interpret, scale=scale, logit_softcap=logit_softcap,
+        window=window)
+    if mesh is None or mesh.size == 1:
+        return local(q, k, v)
+    batch = _axes_dividing(mesh, ("dp", "fsdp"), q.shape[0])
+    heads = _axes_dividing(mesh, ("tp",), k.shape[1])
+    if heads is None and _axes_dividing(mesh, ("tp",), q.shape[1]) is not None:
+        # fewer kv heads than tp shards: repeat them first so both sides split
+        q, k, v = _repeat_kv_heads(q, k, v)
+        heads = "tp"
+    spec = P(batch, heads, None, None)
+    return shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec, check_vma=False)(q, k, v)
+
+
+def note_choice(impl: str, sq: int, sk: int, mesh: Mesh | None = None) -> None:
+    """Record — at TRACE time, once per attention call site — which
+    implementation a forward compiled with. The record is a zero-length span
+    whose name carries the decision (``attention.flash[144x144]+pad[256x256]``),
+    so ``/v1/trace`` (and ``MODELX_TRACE=1`` logs) show what ``impl="auto"``
+    chose for each length without a new surface."""
+    name = f"attention.{impl}[{sq}x{sk}]"
+    if impl == "flash":
+        pq, pk = flash_blocks(sq)[1], flash_blocks(sk)[1]
+        if (pq, pk) != (sq, sk):
+            name += f"+pad[{pq}x{pk}]"
+        if mesh is not None and mesh.size > 1:
+            name += "+shard_map"
+    with trace.span(name):
+        pass
 
 
 # -- ring attention (sequence parallelism) ------------------------------------
@@ -254,7 +336,8 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp", causal: bool = True,
     )(q, k, v)
 
 
-def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "sp", causal: bool = True):
+def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "sp", causal: bool = True,
+                      interpret: bool = False):
     """Ulysses/DeepSpeed-style sequence parallelism via all-to-all.
 
     q/k/v: [B, H, S, D] globally, S sharded over ``axis``. Two all-to-alls
@@ -283,7 +366,8 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "sp", causal: bool = True
         # [B, H, S/n, D] -> [B, H/n, S, D]: split heads, gather sequence
         to_heads = lambda x: jax.lax.all_to_all(x, axis, split_axis=1, concat_axis=2, tiled=True)
         out = flash_attention(
-            to_heads(q_blk), to_heads(k_blk), to_heads(v_blk), causal=causal
+            to_heads(q_blk), to_heads(k_blk), to_heads(v_blk), causal=causal,
+            interpret=interpret,
         )
         # [B, H/n, S, D] -> [B, H, S/n, D]
         return jax.lax.all_to_all(out, axis, split_axis=2, concat_axis=1, tiled=True)

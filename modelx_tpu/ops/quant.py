@@ -64,19 +64,13 @@ class QTensor:
 
 
 try:  # auxdata is always None (pure pair pytree); empty-bytes round-trip
-    # import the submodule explicitly: jax < 0.6 doesn't bind ``export``
-    # on bare ``import jax``, so registering via ``jax.export.*`` only
-    # worked when some earlier import (aot_cache) had already bound it —
-    # an import-order dependency that silently skipped registration
-    from jax import export as _jax_export
-
-    _jax_export.register_pytree_node_serialization(
+    jax.export.register_pytree_node_serialization(
         QTensor,
         serialized_name="modelx_tpu.ops.quant.QTensor",
         serialize_auxdata=lambda aux: b"",
         deserialize_auxdata=lambda b: None,
     )
-except (ImportError, AttributeError, ValueError):  # older jax / double reg
+except ValueError:  # double registration (module re-imported under a new name)
     pass
 
 
@@ -84,8 +78,8 @@ def _native_quant(w, scales=None, want_q: bool = True):
     """The native fused kernel (modelx_io.cc mx_quantize_rows) when the
     engine + dtype allow, else None. One GIL-free pass replaces several
     numpy passes — decisive for bfloat16 sources, whose ml_dtypes ufuncs
-    are generic element loops (BENCH_r04: int8 host quantize cost more
-    than the link bytes it saved on a 1-core host)."""
+    are generic element loops (int8 host quantize can cost more than the
+    link bytes it saves when cores are few)."""
     try:
         from modelx_tpu import native
 

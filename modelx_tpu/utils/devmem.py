@@ -7,14 +7,15 @@ ServerlessLLM's argument (PAPERS.md) applies: memory state must be
 *accounted*, not estimated, before a scheduler can trust it. This module
 samples the accelerator's own accounting — ``Device.memory_stats()``
 where the backend provides it (TPU/GPU), the live-buffer census as the
-fallback (CPU backend, older jax) — into one small dict the engine
-snapshot, ``pool_snapshot()``, and ``/admin/models`` all share.
+fallback (CPU backend) — into one small dict the engine snapshot,
+``pool_snapshot()``, and ``/admin/models`` all share.
 
-Shim rules follow ``jax_compat``: jax is imported lazily (the module
-stays importable in jax-free contexts), every backend probe degrades
-gracefully, and the sample says HOW it measured (``source`` =
-``memory_stats`` | ``live_buffers`` | ``none``) so a reader never
-mistakes a fallback census for device truth.
+jax is imported lazily (the module stays importable in jax-free
+contexts), every backend probe degrades gracefully, and the sample says
+WHAT it measured (``platform`` / ``device_kind`` as jax reports them — a
+pod that fell back to the CPU must not look like a healthy TPU pod) and
+HOW (``source`` = ``memory_stats`` | ``live_buffers`` | ``none``) so a
+reader never mistakes a fallback census for device truth.
 
 Sampling is cached for ``max_age_s`` (default 1 s): ``/metrics`` is
 polled per scrape and ``live_buffers`` walks every allocation — the
@@ -60,20 +61,9 @@ def _device_stats(dev) -> dict | None:
 
 
 def _live_buffer_bytes(jax_mod) -> int | None:
-    """Fallback census: sum the bytes of every live jax array. Modern
-    jax exposes ``live_arrays()``; fall back through per-device
-    ``live_buffers()`` on older versions."""
-    live = getattr(jax_mod, "live_arrays", None)
+    """Fallback census: sum the bytes of every live jax array."""
     try:
-        if live is not None:
-            return sum(int(a.nbytes) for a in live())
-        total = 0
-        for dev in jax_mod.local_devices():
-            bufs = getattr(dev, "live_buffers", None)
-            if bufs is None:
-                return None
-            total += sum(int(b.nbytes) for b in bufs())
-        return total
+        return sum(int(a.nbytes) for a in jax_mod.live_arrays())
     except Exception:
         logger.debug("live-buffer census failed", exc_info=True)
         return None
@@ -81,13 +71,15 @@ def _live_buffer_bytes(jax_mod) -> int | None:
 
 def raw_sample() -> dict:
     """One uncached sample across local devices. Keys are numeric (they
-    render as promexp gauges) except ``source``, which the renderer
-    skips and the JSON keeps."""
+    render as promexp gauges) except ``source``, ``platform`` and
+    ``device_kind``, which the renderer skips and the JSON keeps."""
     out = {
         "hbm_bytes_in_use": 0,
         "hbm_bytes_reservable": 0,
         "device_count": 0,
         "source": "none",
+        "platform": "",
+        "device_kind": "",
     }
     try:
         import jax
@@ -100,6 +92,9 @@ def raw_sample() -> dict:
         logger.debug("jax.local_devices() failed", exc_info=True)
         return out
     out["device_count"] = len(devices)
+    if devices:
+        out["platform"] = str(devices[0].platform)
+        out["device_kind"] = str(devices[0].device_kind)
     per = [_device_stats(d) for d in devices]
     if any(p is not None for p in per):
         out["source"] = "memory_stats"

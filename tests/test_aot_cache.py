@@ -166,3 +166,73 @@ class TestAOTCache:
         ):
             assert tuple(s.shape) == tuple(a.shape), pth
             assert s.dtype == a.dtype, pth
+
+
+class TestCompileCachePlacement:
+    """Where the persistent XLA cache lives (dl/serve.enable_compile_cache):
+    JAX_COMPILATION_CACHE_DIR when the environment sets it — and then no
+    other directory is set in code — else one fixed path inside the
+    checkout. A path that moves never hits."""
+
+    @pytest.fixture(autouse=True)
+    def _isolate(self, monkeypatch):
+        from modelx_tpu.dl import serve
+
+        monkeypatch.setattr(serve, "_compile_cache_dir", "")
+        floor = jax.config.jax_persistent_cache_min_compile_time_secs
+        yield
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
+
+    def test_env_var_wins_and_nothing_else_is_set(self, tmp_path, monkeypatch):
+        from modelx_tpu.dl import serve
+
+        env_dir = str(tmp_path / "from-env")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        serve.enable_compile_cache()
+        assert serve.compile_cache_dir() == env_dir
+        assert jax.config.jax_compilation_cache_dir == env_dir
+        assert serve.compile_cache_stats()["dir"] == env_dir
+
+    def test_unset_resolves_to_the_fixed_in_checkout_path(self, monkeypatch):
+        from modelx_tpu.dl import serve
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        serve.enable_compile_cache()
+        here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert serve.compile_cache_dir() == os.path.join(here, ".cache", "xla")
+        assert serve.compile_cache_dir() == serve.DEFAULT_COMPILE_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == serve.DEFAULT_COMPILE_CACHE_DIR
+
+    def test_cold_leg_is_a_fixed_name_cleared_not_renamed(self, monkeypatch):
+        from modelx_tpu.dl import serve
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/nonexistent/kept-cache")
+        first = serve.cold_cache_dir("unit-test-leg")
+        with open(os.path.join(first, "stale-entry"), "w") as f:
+            f.write("x")
+        second = serve.cold_cache_dir("unit-test-leg")
+        assert first == second == os.path.join(
+            serve.DEFAULT_COMPILE_CACHE_DIR, "unit-test-leg")
+        assert os.listdir(second) == []
+        os.rmdir(second)
+
+    def test_hits_and_misses_are_counted_from_jax_events(self, tmp_path):
+        """A restart that found its programs says so in numbers: same
+        program, fresh in-memory caches, second compile is a persistent
+        hit. The directory switch takes effect in a process that has
+        already compiled (jax opens its cache once otherwise)."""
+        from modelx_tpu.dl import serve
+
+        serve.enable_compile_cache(str(tmp_path / "leg"))
+        f = jax.jit(lambda x: jnp.sin(x) * 3 + 1)
+        x = jnp.arange(8, dtype=jnp.float32)
+        before = serve.compile_cache_stats()
+        f(x).block_until_ready()
+        mid = serve.compile_cache_stats()
+        assert mid["misses"] > before["misses"]
+        assert any(n.endswith("-cache") for n in os.listdir(tmp_path / "leg"))
+        jax.clear_caches()
+        f(x).block_until_ready()
+        after = serve.compile_cache_stats()
+        assert after["hits"] > mid["hits"]
+        assert after["requests"] - before["requests"] >= 2

@@ -1,6 +1,6 @@
-"""bench.py smoke: the driver runs it unattended at round end on real
-hardware — import errors, signature drift between bench and the library,
-or a broken checkpoint builder must fail HERE, in CI, not there."""
+"""bench.py smoke: it runs unattended on real hardware — import errors,
+signature drift between bench and the library, or a broken checkpoint
+builder must fail HERE, in CI, not there."""
 
 import json
 import os
@@ -12,46 +12,124 @@ import pytest
 
 
 class TestWaitForDevice:
-    """wait_for_device: the relay-outage guard must return promptly when
-    the probe succeeds and raise (not hang forever) when it never does."""
+    """wait_for_device: ONE fail-fast probe in a short-lived subprocess — it
+    returns the device as jax reports it, refuses a cpu backend, and raises
+    (not hangs, not retries) when the probe fails or hangs."""
 
-    def test_returns_when_probe_succeeds(self, monkeypatch):
+    def test_returns_device_when_probe_succeeds(self, monkeypatch):
         import bench
 
         calls = []
 
         def fake_run(cmd, **kw):
             calls.append(cmd)
-            return subprocess.CompletedProcess(cmd, 0)
+            return subprocess.CompletedProcess(
+                cmd, 0, stdout='{"platform": "tpu", "kind": "TPU v5 lite", "count": 1}\n',
+                stderr="")
 
+        monkeypatch.setattr(bench, "_device_child_env", dict)
         monkeypatch.setattr(bench.subprocess, "run", fake_run)
-        waited = bench.wait_for_device(max_wait_s=5, probe_timeout_s=1, retry_s=0.01)
-        assert waited < 5 and len(calls) == 1
+        dev = bench.wait_for_device(probe_timeout_s=1)
+        assert dev == {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+        assert len(calls) == 1
+        # the probe itself is what rejects a cpu backend
+        assert "platform != 'cpu'" in calls[0][-1]
 
-    def test_raises_after_budget_when_probe_hangs(self, monkeypatch):
+    def test_raises_at_once_when_probe_hangs(self, monkeypatch):
         import bench
-        import pytest
+
+        calls = []
 
         def fake_run(cmd, **kw):
+            calls.append(cmd)
             raise subprocess.TimeoutExpired(cmd, kw.get("timeout", 0))
 
+        monkeypatch.setattr(bench, "_device_child_env", dict)
         monkeypatch.setattr(bench.subprocess, "run", fake_run)
-        with pytest.raises(RuntimeError, match="unreachable"):
-            bench.wait_for_device(max_wait_s=0.05, probe_timeout_s=0.01,
-                                  retry_s=0.01)
+        with pytest.raises(RuntimeError, match="hung"):
+            bench.wait_for_device(probe_timeout_s=0.01)
+        assert len(calls) == 1  # one probe, no retry loop
 
-    def test_retries_through_transient_failure(self, monkeypatch):
+    def test_failed_probe_raises_with_its_stderr(self, monkeypatch):
         import bench
 
-        state = {"n": 0}
+        calls = []
 
         def fake_run(cmd, **kw):
-            state["n"] += 1
-            return subprocess.CompletedProcess(cmd, 1 if state["n"] < 3 else 0)
+            calls.append(cmd)
+            return subprocess.CompletedProcess(
+                cmd, 1, stdout="",
+                stderr="AssertionError: cpu backend — accelerator not found")
 
+        monkeypatch.setattr(bench, "_device_child_env", dict)
         monkeypatch.setattr(bench.subprocess, "run", fake_run)
-        bench.wait_for_device(max_wait_s=10, probe_timeout_s=1, retry_s=0.01)
-        assert state["n"] == 3
+        with pytest.raises(RuntimeError, match="accelerator not found"):
+            bench.wait_for_device(probe_timeout_s=1)
+        assert len(calls) == 1
+
+    def test_real_probe_refuses_the_cpu(self, monkeypatch):
+        """No chip here: the real probe subprocess (JAX held to the CPU)
+        must be refused — nothing on the bench path records a capture
+        without an accelerator."""
+        import bench
+
+        monkeypatch.setattr(
+            bench, "_device_child_env",
+            lambda: dict(os.environ, JAX_PLATFORMS="cpu"))
+        with pytest.raises(RuntimeError, match="no accelerator"):
+            bench.wait_for_device(probe_timeout_s=120)
+
+
+class TestNoChipNoNumber:
+    def test_chip_spec_raises_on_unknown_kind(self):
+        import bench
+
+        assert bench._chip_spec(bench.PEAK_FLOPS, "TPU v5 lite") == 197e12
+        assert bench._chip_spec(bench.HBM_GBPS, "TPU v5p chip") == 2765e9
+        for kind in ("cpu", "TPU v9", ""):
+            with pytest.raises(KeyError, match="no published peak"):
+                bench._chip_spec(bench.PEAK_FLOPS, kind)
+        assert "cpu" not in bench.PEAK_FLOPS and "cpu" not in bench.HBM_GBPS
+
+    def test_parent_that_touched_jax_cannot_hand_out_the_device(self, monkeypatch):
+        """A chip belongs to one process: the stay-off-jax ordering of
+        main() is checked where every device child gets its environment."""
+        import types
+
+        import bench
+
+        fake_sys = types.SimpleNamespace(modules={"jax": object()})
+        monkeypatch.setattr(bench, "sys", fake_sys)
+        with pytest.raises(RuntimeError, match="imported jax"):
+            bench._device_child_env()
+        fake_sys.modules = {}
+        env = bench._device_child_env()
+        assert "JAX_PLATFORMS" not in env
+        assert os.path.dirname(os.path.abspath(bench.__file__)) in env["PYTHONPATH"]
+
+    @pytest.mark.parametrize("fail_probe", [True, False])
+    def test_capture_with_a_failed_leg_exits_nonzero(self, monkeypatch, capsys,
+                                                     fail_probe):
+        """A capture with leg_errors still prints its one JSON line — and
+        then exits non-zero: with no accelerator (the probe refuses), or
+        with one and a stage that dies."""
+        import bench
+
+        def probe():
+            if fail_probe:
+                raise RuntimeError("no accelerator")
+            return {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+
+        def dead_stage(*a, **kw):
+            raise RuntimeError("stage died")
+
+        monkeypatch.setattr(bench, "wait_for_device", probe)
+        monkeypatch.setattr(bench, "build_checkpoint", dead_stage)
+        rc = bench.main()
+        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert rc == 1
+        assert ("no accelerator" if fail_probe else "stage died") in out["leg_errors"]["fatal"]
+        assert out["value"] is None and ("device" in out) is (not fail_probe)
 
 
 class TestBenchSmoke:
@@ -171,12 +249,15 @@ class TestBenchSmoke:
             srv.shutdown()
 
     def test_leg_subprocess_roundtrip(self, tmp_path):
-        """The timed legs run as `bench.py --leg <kind>` children on the
-        driver's rig; each must load against a live registry and print one
-        JSON line with the fields the parent consumes (CPU backend here).
-        Legs run in order: the warm blob-cache leg consumes the cache the
-        cold leg admitted, and must report a warm hit (zero network reads
-        — its source is the cache's LocalFileSource)."""
+        """The timed legs run as `bench.py --leg <kind>` children; each
+        must load against a live registry and print one JSON line with the
+        fields the parent consumes (CPU backend here). Only cold -> warm is
+        ordered: the warm blob-cache leg consumes the cache the cold leg
+        admitted, and must report a warm hit (zero network reads — its
+        source is the cache's LocalFileSource). The rest run side by side
+        (each child pays its own interpreter + jax start)."""
+        from concurrent.futures import ThreadPoolExecutor
+
         from bench import build_checkpoint, push_checkpoint, start_registry
 
         import shutil
@@ -189,27 +270,37 @@ class TestBenchSmoke:
             push_checkpoint(base, "library/smoke", ckpt)
             here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
             env = dict(os.environ, PYTHONPATH=here, JAX_PLATFORMS="cpu")
+            fields = {
+                "ours": ("seconds", "source", "fetch_width", "bytes_to_device",
+                         "link_gbps", "overlap_seconds", "staging_allocs"),
+                "baseline": ("seconds", "link_gbps"),
+                "int8": ("seconds", "bytes_to_device"),
+                "cold": ("seconds", "cache_state", "blob_cache",
+                         "overlap_seconds", "staging_allocs"),
+                "warm": ("seconds", "cache_state", "blob_cache"),
+            }
+
+            def legs(*kinds: str) -> dict:
+                out = {}
+                for kind in kinds:
+                    p = subprocess.run(
+                        [sys.executable, os.path.join(here, "bench.py"),
+                         "--leg", kind, base, "library/smoke", workdir],
+                        capture_output=True, text=True, timeout=300, env=env,
+                    )
+                    assert p.returncode == 0, f"{kind}: {p.stderr[-1000:]}"
+                    rec = json.loads(p.stdout.strip().splitlines()[-1])
+                    for f in fields[kind]:
+                        assert f in rec, (kind, f, rec)
+                    assert rec["seconds"] > 0
+                    out[kind] = rec
+                return out
+
             recs = {}
-            for kind, fields in (
-                ("ours", ("seconds", "source", "fetch_width", "bytes_to_device",
-                          "link_gbps", "overlap_seconds", "staging_allocs")),
-                ("baseline", ("seconds", "link_gbps")),
-                ("int8", ("seconds", "bytes_to_device")),
-                ("cold", ("seconds", "cache_state", "blob_cache",
-                          "overlap_seconds", "staging_allocs")),
-                ("warm", ("seconds", "cache_state", "blob_cache")),
-            ):
-                p = subprocess.run(
-                    [sys.executable, os.path.join(here, "bench.py"),
-                     "--leg", kind, base, "library/smoke", workdir],
-                    capture_output=True, text=True, timeout=300, env=env,
-                )
-                assert p.returncode == 0, f"{kind}: {p.stderr[-1000:]}"
-                rec = json.loads(p.stdout.strip().splitlines()[-1])
-                for f in fields:
-                    assert f in rec, (kind, f, rec)
-                assert rec["seconds"] > 0
-                recs[kind] = rec
+            with ThreadPoolExecutor(4) as pool:
+                for got in pool.map(lambda ks: legs(*ks), [
+                        ("ours",), ("baseline",), ("int8",), ("cold", "warm")]):
+                    recs.update(got)
             # the cold leg streamed the network source and admitted the blob
             assert recs["cold"]["cache_state"] == "cold"
             assert recs["cold"]["blob_cache"]["admitted"] == 1

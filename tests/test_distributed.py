@@ -33,6 +33,37 @@ class TestInitialize:
             "process_id": 2,
         }]
 
+    def test_single_host_tpu_env_is_one_process(self, monkeypatch):
+        """What the one-host v5e machine sets (seen on the chip, PR 21):
+        pod variables naming ONE worker must not start the coordinator
+        handshake — it has nobody to talk to and used to fail into a
+        swallowed warning."""
+        for k in ("MODELX_COORDINATOR", "MODELX_NUM_PROCESSES",
+                  "MEGASCALE_COORDINATOR_ADDRESS"):
+            monkeypatch.delenv(k, raising=False)
+        monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+        monkeypatch.setenv("TPU_WORKER_ID", "0")
+        monkeypatch.setattr(distributed, "_initialized", False)
+        called = []
+        monkeypatch.setattr(distributed.jax.distributed, "initialize",
+                            lambda **kw: called.append(kw))
+        distributed.initialize()
+        assert not called
+
+    def test_multi_host_failure_raises(self, monkeypatch):
+        """Several workers configured and the handshake fails: the error
+        surfaces instead of a pod that serves a fraction of its mesh."""
+        monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "host-0,host-1")
+        monkeypatch.setattr(distributed, "_initialized", False)
+
+        def boom(**kw):
+            raise RuntimeError("coordinator unreachable")
+
+        monkeypatch.setattr(distributed.jax.distributed, "initialize", boom)
+        with pytest.raises(RuntimeError, match="coordinator unreachable"):
+            distributed.initialize()
+        assert distributed._initialized is False
+
     def test_idempotent(self, monkeypatch):
         monkeypatch.setattr(distributed, "_initialized", True)
         called = []

@@ -400,7 +400,7 @@ class TestPackedTransfer:
 
 
 class TestAdaptiveFetchWidth:
-    """BENCH_r04 regression: fetch width must derive from the host, and the
+    """Regression: fetch width must derive from the host, and the
     governor must shed width when per-thread throughput collapses."""
 
     def test_auto_concurrency_scales_with_host(self, tmp_path, monkeypatch):

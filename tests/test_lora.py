@@ -173,7 +173,7 @@ class TestServeIntegration:
 
 
 class TestPerModuleScaleRefusal:
-    """ADVICE r3: adapters with per-module ranks/alphas must refuse to
+    """Adapters with per-module ranks/alphas must refuse to
     merge with a single global scale, not silently mis-scale targets."""
 
     def test_rank_pattern_rejected(self, tmp_path):

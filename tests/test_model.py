@@ -1,5 +1,7 @@
 """Flagship model + ops tests on the virtual 8-device CPU mesh."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -44,12 +46,13 @@ class TestAttentionOps:
 
     def test_flash_matches_reference(self):
         ref = attn.attention_reference(self.q, self.k, self.v)
-        fl = attn.flash_attention(self.q, self.k, self.v, block_q=64, block_k=64)
+        fl = attn.flash_attention(self.q, self.k, self.v, block_q=64, block_k=64, interpret=True)
         np.testing.assert_allclose(np.asarray(fl), np.asarray(ref), atol=2e-6)
 
     def test_flash_noncausal(self):
         ref = attn.attention_reference(self.q, self.k, self.v, causal=False)
-        fl = attn.flash_attention(self.q, self.k, self.v, causal=False, block_q=64, block_k=64)
+        fl = attn.flash_attention(self.q, self.k, self.v, causal=False, block_q=64, block_k=64,
+                                  interpret=True)
         np.testing.assert_allclose(np.asarray(fl), np.asarray(ref), atol=2e-6)
 
     def test_flash_block_k_larger_than_block_q(self):
@@ -57,15 +60,86 @@ class TestAttentionOps:
         ceiling — the floor form computed ZERO visible blocks for early q
         blocks and returned all-zero rows."""
         ref = attn.attention_reference(self.q, self.k, self.v)
-        fl = attn.flash_attention(self.q, self.k, self.v, block_q=32, block_k=128)
+        fl = attn.flash_attention(self.q, self.k, self.v, block_q=32, block_k=128, interpret=True)
         np.testing.assert_allclose(np.asarray(fl), np.asarray(ref), atol=2e-6)
         assert np.abs(np.asarray(fl)).sum() > 0
 
     def test_gqa(self):
         kv = self.k[:, :2], self.v[:, :2]
         ref = attn.attention_reference(self.q, *kv)
-        fl = attn.flash_attention(self.q, *kv, block_q=64, block_k=64)
+        fl = attn.flash_attention(self.q, *kv, block_q=64, block_k=64, interpret=True)
         np.testing.assert_allclose(np.asarray(fl), np.asarray(ref), atol=2e-6)
+
+    @pytest.mark.parametrize("sq,causal,padded", [
+        (144, False, 256),  # only kv_len masks the padded keys here
+        (5, True, 16),      # below one block: up to the row tile
+    ])
+    def test_flash_ragged_pads_to_block(self, sq, causal, padded):
+        """Lengths that are not a multiple of the block — 16-bucketed ones
+        above it (144, 160, ...) and raw /v1/forward lengths below the row
+        tile (5) — stay IN the kernel, padded up (padded keys masked),
+        instead of being handed to the reference or to a compiler that
+        refuses the unaligned block."""
+        rng = np.random.RandomState(3)
+        q, k, v = (jnp.array(rng.rand(1, 4, sq, 32), jnp.float32) for _ in range(3))
+        ref = attn.attention_reference(q, k[:, :2], v[:, :2], causal=causal)
+        text = attn.flash_attention.lower(
+            q, k[:, :2], v[:, :2], causal=causal, interpret=True).as_text()
+        assert f"{padded}x32" in text  # the padded shape is in the program
+        fl = attn.flash_attention(q, k[:, :2], v[:, :2], causal=causal, interpret=True)
+        assert fl.shape == q.shape
+        np.testing.assert_allclose(np.asarray(fl), np.asarray(ref), atol=2e-6)
+
+    def test_flash_blocks(self):
+        assert attn.flash_blocks(16) == (16, 16)
+        assert attn.flash_blocks(5) == (16, 16)
+        assert attn.flash_blocks(40) == (48, 48)
+        assert attn.flash_blocks(128) == (128, 128)
+        assert attn.flash_blocks(144) == (128, 256)
+        assert attn.flash_blocks(2048) == (128, 2048)
+
+    @pytest.mark.parametrize("spec,batch,kv_heads", [
+        ("dp=1,tp=4", 1, 2),   # fewer kv heads than tp: repeated, then split
+        ("dp=2,tp=2", 2, 2),   # batch over dp AND heads over tp (kv heads divide)
+        ("dp=4", 1, 2),        # the default dp=N mesh, batch 1: replicated
+    ])
+    def test_flash_under_mesh_is_shard_mapped(self, spec, batch, kv_heads):
+        """A bare pallas_call cannot be partitioned by GSPMD (on the chip:
+        'Mosaic kernels cannot be automatically partitioned'); under a
+        multi-device mesh the kernel runs inside shard_map and still
+        matches the reference."""
+        mesh = make_mesh(spec, devices=jax.devices()[:4])
+        rng = np.random.RandomState(4)
+        q = jnp.array(rng.rand(batch, 4, 128, 32), jnp.float32)
+        k = jnp.array(rng.rand(batch, kv_heads, 128, 32), jnp.float32)
+        v = jnp.array(rng.rand(batch, kv_heads, 128, 32), jnp.float32)
+        ref = attn.attention_reference(q, k, v)
+        fl = attn.flash_attention(q, k, v, mesh=mesh, interpret=True)
+        np.testing.assert_allclose(np.asarray(fl), np.asarray(ref), atol=2e-6)
+        jaxpr = str(jax.make_jaxpr(functools.partial(
+            attn.flash_attention, mesh=mesh, interpret=True))(q, k, v))
+        assert "shard_map" in jaxpr
+
+    def test_flash_never_interprets_on_its_own(self):
+        """interpret is the caller's explicit ask: on a backend that cannot
+        compile the kernel the call FAILS rather than quietly running the
+        interpreter (or the reference) in its place."""
+        with pytest.raises(Exception, match="(?i)interpret|pallas|mosaic|cpu"):
+            jax.block_until_ready(
+                attn.flash_attention(self.q, self.k, self.v, block_q=64, block_k=64))
+
+    def test_note_choice_names_the_decision(self):
+        from modelx_tpu.utils import trace
+
+        mesh = make_mesh("dp=1,tp=4", devices=jax.devices()[:4])
+        before = len(trace.spans("attention."))
+        attn.note_choice("flash", 512, 512)
+        attn.note_choice("flash", 144, 144, mesh)
+        attn.note_choice("reference", 16, 16)
+        got = [s["path"] for s in trace.spans("attention.")[before:]]
+        assert got == ["attention.flash[512x512]",
+                       "attention.flash[144x144]+pad[256x256]+shard_map",
+                       "attention.reference[16x16]"]
 
     def test_ring_matches_reference(self):
         mesh = make_mesh("sp=8")
@@ -108,20 +182,20 @@ class TestAttentionOps:
     def test_ulysses_matches_reference(self):
         mesh = make_mesh("sp=4", devices=jax.devices()[:4])
         ref = attn.attention_reference(self.q, self.k, self.v)
-        ul = attn.ulysses_attention(self.q, self.k, self.v, mesh, axis="sp")
+        ul = attn.ulysses_attention(self.q, self.k, self.v, mesh, axis="sp", interpret=True)
         np.testing.assert_allclose(np.asarray(ul), np.asarray(ref), atol=2e-6)
 
     def test_ulysses_noncausal(self):
         mesh = make_mesh("sp=4", devices=jax.devices()[:4])
         ref = attn.attention_reference(self.q, self.k, self.v, causal=False)
-        ul = attn.ulysses_attention(self.q, self.k, self.v, mesh, axis="sp", causal=False)
+        ul = attn.ulysses_attention(self.q, self.k, self.v, mesh, axis="sp", causal=False, interpret=True)
         np.testing.assert_allclose(np.asarray(ul), np.asarray(ref), atol=2e-6)
 
     def test_ulysses_gqa_repeats_heads(self):
         mesh = make_mesh("sp=4", devices=jax.devices()[:4])
         kv = self.k[:, :2], self.v[:, :2]  # 2 kv heads don't divide sp=4
         ref = attn.attention_reference(self.q, *kv)
-        ul = attn.ulysses_attention(self.q, *kv, mesh=mesh, axis="sp")
+        ul = attn.ulysses_attention(self.q, *kv, mesh=mesh, axis="sp", interpret=True)
         np.testing.assert_allclose(np.asarray(ul), np.asarray(ref), atol=2e-6)
 
     def test_ulysses_gqa_partial_repeat(self):
@@ -133,14 +207,14 @@ class TestAttentionOps:
         v2 = jnp.array(rng.rand(2, 2, 128, 32), jnp.float32)
         mesh = make_mesh("sp=4", devices=jax.devices()[:4])
         ref = attn.attention_reference(q8, k2, v2)
-        ul = attn.ulysses_attention(q8, k2, v2, mesh, axis="sp")
+        ul = attn.ulysses_attention(q8, k2, v2, mesh, axis="sp", interpret=True)
         np.testing.assert_allclose(np.asarray(ul), np.asarray(ref), atol=2e-6)
 
     def test_ulysses_head_mismatch_raises(self):
         mesh = make_mesh("sp=8")
         q = self.q[:, :4]  # 4 heads, sp=8
         with pytest.raises(ValueError, match="heads"):
-            attn.ulysses_attention(q, self.k[:, :4], self.v[:, :4], mesh, axis="sp")
+            attn.ulysses_attention(q, self.k[:, :4], self.v[:, :4], mesh, axis="sp", interpret=True)
 
 
 class TestLlama:

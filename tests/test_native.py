@@ -246,26 +246,71 @@ class TestNativeHTTP:
             srv.close()
 
 
-class TestBakedSoFallback:
-    def test_existing_so_used_when_toolchain_missing(self, tmp_path, monkeypatch):
-        """Container images bake an arch-correct .so but ship no g++, and
-        install mtimes can make the source look newer — build() must return
-        the existing library, not None."""
+class TestBuildKeyedOnSource:
+    """The library is named after the digest of modelx_io.cc: a binary is
+    only ever used for the exact source beside it."""
+
+    @staticmethod
+    def _no_gxx(monkeypatch):
         import subprocess
-
-        from modelx_tpu import native
-
-        built = native.build(force=True)
-        if built is None:
-            pytest.skip("no local toolchain to produce a .so")
-        # make the source look newer AND the compiler unavailable
-        os.utime(native._SRC)
 
         def no_gxx(*a, **kw):
             raise OSError("g++ not found")
 
         monkeypatch.setattr(subprocess, "run", no_gxx)
-        assert native.build() == native._SO
+
+    def test_existing_so_used_when_toolchain_missing(self, monkeypatch):
+        """Container images bake an arch-correct .so but ship no g++, and
+        install mtimes can make the source look newer — build() must return
+        the library built from this source, not None."""
+        from modelx_tpu import native
+
+        built = native.build(force=True)
+        if built is None:
+            pytest.skip("no local toolchain to produce a .so")
+        os.utime(native._SRC)  # source mtime newer than the library's
+        self._no_gxx(monkeypatch)
+        assert native.build() == built == native.so_path()
+
+    def test_binary_of_other_source_is_never_picked_up(self, tmp_path,
+                                                       monkeypatch, caplog):
+        """An untracked library left in _build/ (another checkout's, or one
+        whose mtime merely looks newer) must not stand in for the committed
+        source: with no toolchain the answer is None plus a WARNING, not a
+        stale engine."""
+        import logging
+        import shutil
+
+        from modelx_tpu import native
+
+        src = tmp_path / "modelx_io.cc"
+        shutil.copy(native._SRC, src)
+        src.write_text(src.read_text() + "\n// edited\n")
+        build_dir = tmp_path / "_build"
+        build_dir.mkdir()
+        (build_dir / "libmodelx_io.so").write_bytes(b"stale")
+        monkeypatch.setattr(native, "_SRC", str(src))
+        monkeypatch.setattr(native, "_BUILD_DIR", str(build_dir))
+        self._no_gxx(monkeypatch)
+        with caplog.at_level(logging.WARNING, logger="modelx.native"):
+            assert native.build() is None
+        assert "build failed" in caplog.text and "g++ not found" in caplog.text
+
+    def test_compiler_stderr_rides_the_warning(self, tmp_path, monkeypatch, caplog):
+        import logging
+        import shutil
+
+        from modelx_tpu import native
+
+        if shutil.which("g++") is None:
+            pytest.skip("no local toolchain")
+        src = tmp_path / "modelx_io.cc"
+        src.write_text("this is not c++\n")
+        monkeypatch.setattr(native, "_SRC", str(src))
+        monkeypatch.setattr(native, "_BUILD_DIR", str(tmp_path / "_build"))
+        with caplog.at_level(logging.WARNING, logger="modelx.native"):
+            assert native.build() is None
+        assert "error" in caplog.text  # g++'s own diagnostics, not just rc 1
 
 
 class TestQuantizeRows:

@@ -631,7 +631,7 @@ class TestHelpers:
 
 
 class TestMaxCompletionTokens:
-    """ADVICE r3: max_completion_tokens (the current OpenAI chat param) is
+    """max_completion_tokens (the current OpenAI chat param) is
     an alias for max_tokens, preferred when both are present."""
 
     def _sampling(self, req):
@@ -667,7 +667,7 @@ class TestMaxCompletionTokens:
 class TestContextBound:
     def test_encode_prompt_400s_past_n_positions(self):
         """gpt2-style absolute-position models: prompt + max_tokens past
-        n_positions must 400 on the OpenAI path too (ADVICE r3)."""
+        n_positions must 400 on the OpenAI path too."""
         from types import SimpleNamespace
 
         from modelx_tpu.dl.openai_api import encode_prompt
@@ -701,7 +701,7 @@ class TestAutoEOS:
 
     def test_eos_override_from_config_sidecars(self, tmp_path):
         """An explicit eos_token_id in the checkpoint's config sidecars
-        beats the spelling probe (ADVICE r4: chatml-style vocabs carry probe
+        beats the spelling probe (chatml-style vocabs carry probe
         spellings as NON-eos specials, e.g. <|endoftext|> as pad)."""
         tokenizers = pytest.importorskip("tokenizers")
         import json as _json
@@ -747,8 +747,7 @@ class TestAutoEOS:
     def test_stream_divergent_final_flush_not_dropped(self):
         """When the final re-decode no longer extends the bytes already on
         the wire (split glyph before an EOS), the held-back remainder is
-        emitted past the longest common prefix instead of silently dropped
-        (ADVICE r4)."""
+        emitted past the longest common prefix instead of silently dropped."""
         sset, _ = self._eos_sset([[[5]], [[6, 50]]], eos=(50,))
         server = sset.servers["f"]
 

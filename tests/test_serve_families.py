@@ -636,7 +636,7 @@ class TestTextAPI:
 
 
 class TestGPT2PositionBound:
-    """ADVICE r3: decode past gpt2's n_positions silently clamps the wpe
+    """Decode past gpt2's n_positions silently clamps the wpe
     gather inside jit; both the cache constructor and the serving layer
     must refuse instead."""
 

@@ -327,6 +327,8 @@ class TestPerDeviceBudget:
 
 
 class _FakeDevice:
+    platform, device_kind = "tpu", "TPU v5 lite"
+
     def __init__(self, in_use, limit):
         self._in_use, self._limit = in_use, limit
 
@@ -336,6 +338,8 @@ class _FakeDevice:
 
 class _BareDevice:
     """A device without an accountant (CPU backend)."""
+
+    platform, device_kind = "cpu", "cpu"
 
 
 class TestDevmemPerDevice:
@@ -348,6 +352,9 @@ class TestDevmemPerDevice:
         out = devmem.raw_sample()
         assert out["source"] == "memory_stats"
         assert out["device_count"] == 2
+        # what jax found rides the same block: a pod on the cpu backend
+        # must not look like a healthy TPU pod
+        assert (out["platform"], out["device_kind"]) == ("tpu", "TPU v5 lite")
         assert out["hbm_bytes_in_use"] == 12
         assert out["hbm_bytes_reservable"] == 5 + 3
         assert out["devices"]["0"] == {
