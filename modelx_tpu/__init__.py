@@ -20,6 +20,12 @@ Subpackages
 - ``modelx_tpu.parallel`` — mesh construction and sharding rules
 """
 
-from modelx_tpu.version import __version__
+import time
+
+# the first line any entry point runs: where /proc has no start time for the
+# process, start-up (utils/trace.startup) counts from here
+T_FIRST_LINE = time.monotonic()
+
+from modelx_tpu.version import __version__  # noqa: E402
 
 __all__ = ["__version__"]

@@ -111,6 +111,19 @@ _DONE = object()  # end-of-stream sentinel on per-request output queues
 _NO_HIT = object()  # "no memoized prefix-cache lookup" sentinel (None = a miss)
 
 
+# The engine thread's time, tiled (utils/trace.Phases): every instant of
+# ``_loop`` is in exactly one of these. ``firsts_wait`` and ``wait_tokens``
+# block on the device (what ``_sync_wait_s`` adds up), ``idle`` blocks on an
+# empty queue; the rest is host work, and between two chunk dispatches it
+# is what ``boundary_host_ms`` measures as one number.
+_PHASES = ("sweep", "idle", "admit_prep", "admit_dispatch", "chunk_dispatch",
+           "pieces", "firsts_wait", "wait_tokens", "fanout", "overlap_prep",
+           "spec")
+(_P_SWEEP, _P_IDLE, _P_ADMIT_PREP, _P_ADMIT_DISPATCH, _P_CHUNK_DISPATCH,
+ _P_PIECES, _P_FIRSTS_WAIT, _P_WAIT_TOKENS, _P_FANOUT, _P_OVERLAP_PREP,
+ _P_SPEC) = range(len(_PHASES))
+
+
 def _fingerprint(ids, n: int) -> tuple:
     """Identity of one request for poison quarantine: cheap, deterministic,
     and content-addressed (two submissions of the same prompt+budget hash
@@ -619,6 +632,7 @@ class ContinuousBatcher:
             )
         if self.boundary_watchdog_s > 0:
             self.stats["watchdog_stalls"] = 0
+        self._phases = trace.Phases("continuous.boundary", _PHASES)
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
         if self.boundary_watchdog_s > 0:
@@ -2044,31 +2058,24 @@ class ContinuousBatcher:
         filtered = bool(self._use_filters[active].any())
         self._rec("dispatch", depth=depth, n_steps=n_steps,
                   active=len(self._rows), devices=self.mesh_devices)
-        # the step annotation names this dispatch in an on-demand profiler
-        # capture (POST /admin/profile) with the SAME ordinal the flight
-        # ring records, so XLA timeline steps join engine events 1:1
-        with trace.span("continuous.chunk", active=len(self._rows),
-                        depth=depth), \
-                jax.profiler.StepTraceAnnotation(
-                    "continuous.chunk", step_num=self.stats["dispatches"]):
-            # .copy() is load-bearing: jax zero-copy-aliases host numpy
-            # buffers (CPU backend) and transfers lazily, while this loop
-            # mutates the originals (retirement resets, next admissions)
-            # possibly BEFORE the in-flight chunk reads them — each dispatch
-            # gets private snapshots nobody mutates
-            args = [
-                jnp.asarray(self._offsets.copy()), jnp.asarray(self._steps.copy()),
-                jnp.asarray(self._temp.copy()),
-                jnp.asarray(self._top_k.copy()) if filtered else None,
-                jnp.asarray(self._top_p.copy()) if filtered else None,
-                jnp.asarray(self._seeds.copy()),
-            ]
-            if self.page_size > 0:
-                args.insert(0, jnp.asarray(self._table.copy()))
-            self._cache, self._tok, toks_dev = self._chunk(
-                self.server.params, self._cache, self._tok, *args,
-                n_steps=n_steps,
-            )
+        # .copy() is load-bearing: jax zero-copy-aliases host numpy
+        # buffers (CPU backend) and transfers lazily, while this loop
+        # mutates the originals (retirement resets, next admissions)
+        # possibly BEFORE the in-flight chunk reads them — each dispatch
+        # gets private snapshots nobody mutates
+        args = [
+            jnp.asarray(self._offsets.copy()), jnp.asarray(self._steps.copy()),
+            jnp.asarray(self._temp.copy()),
+            jnp.asarray(self._top_k.copy()) if filtered else None,
+            jnp.asarray(self._top_p.copy()) if filtered else None,
+            jnp.asarray(self._seeds.copy()),
+        ]
+        if self.page_size > 0:
+            args.insert(0, jnp.asarray(self._table.copy()))
+        self._cache, self._tok, toks_dev = self._chunk(
+            self.server.params, self._cache, self._tok, *args,
+            n_steps=n_steps,
+        )
         # start the device->host token copy NOW: it streams back while the
         # device runs the next program, so the lagged _deliver sync finds
         # the bytes resident instead of paying the full fetch round-trip
@@ -2162,16 +2169,20 @@ class ContinuousBatcher:
         """Hand this iteration's admitted rows their prefill tokens. Blocks
         only on the prefills (ordered before any chunk dispatched after
         them), so N admissions pay one round-trip, not N."""
+        phases = self._phases
+        phases.to(_P_FANOUT)
         firsts, self._first_pending = self._first_pending, []
         for row, first_ref, done in firsts:
             if row.ticket.cancelled:  # consumer gone: free the slot, no put
                 row.out.put(_DONE)
                 row.closed = True
                 continue
+            phases.to(_P_FIRSTS_WAIT)
             t0 = time.monotonic()
             first_np = first_ref()
             # device-wait, not host work: keep it out of boundary_host_ms
             self._sync_wait_s += time.monotonic() - t0
+            phases.to(_P_FANOUT)
             ticket = row.ticket
             if not ticket.t_first:
                 ticket.t_first = time.monotonic()
@@ -2222,9 +2233,11 @@ class ContinuousBatcher:
         if pending is None:
             return
         toks_dev, plan, depth = pending
+        self._phases.to(_P_WAIT_TOKENS)
         t0 = time.monotonic()
         toks = np.asarray(toks_dev)
         wait_s = time.monotonic() - t0
+        self._phases.to(_P_FANOUT)
         self._sync_wait_s += wait_s
         self._boundary_syncs += 1
         self._inflight_chunks = max(0, self._inflight_chunks - depth)
@@ -2487,8 +2500,11 @@ class ContinuousBatcher:
         from collections import deque
 
         pending: "deque[tuple]" = deque()  # in-flight chunks, oldest first
+        phases = self._phases
         try:
             while True:
+                # one step per iteration; the leaf phases below tile it
+                phases.begin(_P_SWEEP, self.stats["dispatches"])
                 if self._watch_stall is not None:
                     # the watchdog declared this boundary stalled while a
                     # dispatch was wedged; it already failed the waiters —
@@ -2514,6 +2530,7 @@ class ContinuousBatcher:
                 # request admits, frees its slot, and would otherwise hang
                 # its waiter by blocking here before _deliver_firsts runs
                 to_admit: list = []
+                phases.to(_P_ADMIT_PREP)
                 while True:
                     if self._waiting:
                         if not self._admits_now(self._waiting[0]):
@@ -2523,6 +2540,8 @@ class ContinuousBatcher:
                     block = (not self._rows and not self._filling
                              and not pending
                              and not self._first_pending and not to_admit)
+                    if block:
+                        phases.to(_P_IDLE)  # until a request arrives
                     try:
                         item = self._q.get(block=block)
                     except queue.Empty:
@@ -2541,6 +2560,7 @@ class ContinuousBatcher:
                         # generate IS a 1-row list, and independent clients'
                         # lists co-arrive exactly like tuples do.
                         time.sleep(self.burst_window_ms / 1e3)
+                    phases.to(_P_ADMIT_PREP)
                     if isinstance(item, list):
                         # a submit_many burst: route through the FIFO backlog
                         # so the whole burst hits ONE admission boundary
@@ -2562,6 +2582,7 @@ class ContinuousBatcher:
                             pending.popleft()
                         self._fail_active(err)
                         self._state = "stopped"
+                        phases.end()
                         return "closed"
                     if not self._admits_now(item):
                         # no slot (or, paged, not enough free pages): hold in
@@ -2571,6 +2592,7 @@ class ContinuousBatcher:
                         break
                     self._gather_prep(item, to_admit)
                 if to_admit:
+                    phases.to(_P_ADMIT_DISPATCH)
                     self._admit_all(to_admit)
                 if self._spec_ok():
                     # single greedy row: switch to speculative verify steps
@@ -2582,8 +2604,10 @@ class ContinuousBatcher:
                     while pending:
                         self._deliver(pending[0])  # deliver-then-pop: see above
                         pending.popleft()
+                    phases.to(_P_SWEEP)
                     self._sweep_closed()  # a stop may just have closed it
                     if self._spec_ok():
+                        phases.to(_P_SPEC)
                         self._spec_step()
                     continue
                 n_decode = len(self._rows)
@@ -2594,6 +2618,7 @@ class ContinuousBatcher:
                     # device time. Go deep only when nothing is waiting for
                     # a slot, nothing new sits in the queue, and no fill
                     # wants its piece interleaved at every boundary.
+                    phases.to(_P_CHUNK_DISPATCH)
                     pending.append(self._dispatch_chunk())
                     while (len(pending) < self.pipeline_depth and self._rows
                            and not self._filling
@@ -2604,6 +2629,7 @@ class ContinuousBatcher:
                     # chunk: decode rows spend first, pieces pack into the
                     # budget's remainder — a long admission can no longer
                     # freeze the running batch for its whole prompt
+                    phases.to(_P_PIECES)
                     landed = self._dispatch_pieces(n_decode * self.chunk_size)
                     if (not landed and self._filling and not self._rows
                             and not pending and not self._first_pending):
@@ -2620,10 +2646,12 @@ class ContinuousBatcher:
                     # admissions' host prep now (queue drain, fingerprint,
                     # prefix lookup), THEN block on the oldest result —
                     # boundary prep rides inside device time
+                    phases.to(_P_OVERLAP_PREP)
                     self._overlap_prep()
                     self._deliver(pending[0])
                     pending.popleft()
         except BaseException as e:  # engine death must not hang waiters
+            phases.end()
             logging.getLogger("modelx.serve").exception(
                 "continuous engine loop died"
             )
@@ -2779,6 +2807,14 @@ class ContinuousBatcher:
         # blocking token-fetch wait) — the observable the ISSUE 7 win is
         # measured by
         snap["dispatch_depth"] = self._depth_last
+        # the engine thread's time by phase (cumulative seconds / entries),
+        # its wall time and its own CPU time: over the phases that do not
+        # wait, wall minus CPU is time spent standing by for the GIL
+        phases = self._phases
+        snap["phase_s"] = {n: round(v, 6) for n, v in zip(phases.names, phases.seconds)}
+        snap["phase_n"] = dict(zip(phases.names, phases.entries))
+        snap["loop_wall_s"] = round(phases.wall_s, 6)
+        snap["loop_cpu_s"] = round(phases.cpu_s, 6)
         snap["tokens_in_flight"] = self._tokens_in_flight
         snap["sync_lag_chunks"] = self._inflight_chunks
         # snapshot() runs on HTTP handler threads while the engine loop

@@ -46,6 +46,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from modelx_tpu.dl import safetensors as st
 from modelx_tpu.dl.sharding import Rules, sharding_for
+from modelx_tpu.utils import trace
 
 DEFAULT_FETCH_CONCURRENCY = 0  # 0 = auto (auto_fetch_concurrency)
 FETCH_RETRIES = 3  # per-shard retry budget (SURVEY §5: loader retries per shard)
@@ -537,6 +538,7 @@ class LoadStats:
     # pipeline accounting (_OverlapClock): wall time ranged fetches were in
     # flight vs device_put dispatches, and the window where both were —
     # overlap ~ 0 on a big load means the fetch->HBM pipeline collapsed
+    fetch_busy_seconds: float = 0.0  # fetch_seconds is thread-seconds
     device_put_seconds: float = 0.0
     overlap_seconds: float = 0.0
     # staging pool: fresh buffer allocations vs pooled reuses; allocs track
@@ -763,7 +765,8 @@ def load_safetensors(
         try:
             clock.enter("fetch")
             try:
-                return _read_with_retry(source, offset, length, out, timer=timer)
+                with trace.span("dl.fetch", bytes=length):
+                    return _read_with_retry(source, offset, length, out, timer=timer)
             finally:
                 clock.exit("fetch")
         finally:
@@ -1047,27 +1050,28 @@ def load_safetensors(
             try:
                 clock.enter("put")
                 try:
-                    out = [
-                        (
-                            dev,
-                            jax.device_put(arr, dev),
-                            jax.device_put(scale, dev) if scale is not None else None,
-                        )
-                        for dev, _ in group
-                    ]
-                    if pooled is not None:
-                        # the transfer may still be reading the pooled host
-                        # buffer asynchronously: wait before recycling it —
-                        # and if the backend zero-copied (the device array
-                        # ALIASES the buffer, PJRT CPU with 64-byte-aligned
-                        # hosts), hand the memory over instead of recycling
-                        devs = [t[1] for t in out]
-                        jax.block_until_ready(devs)
-                        if _aliases_buffer(devs, pooled):
-                            staging_pool.forfeit(pooled)
-                        else:
-                            staging_pool.release(pooled)
-                        pooled = None
+                    with trace.span("dl.put", bytes=arr.nbytes):
+                        out = [
+                            (
+                                dev,
+                                jax.device_put(arr, dev),
+                                jax.device_put(scale, dev) if scale is not None else None,
+                            )
+                            for dev, _ in group
+                        ]
+                        if pooled is not None:
+                            # the transfer may still be reading the pooled host
+                            # buffer asynchronously: wait before recycling it —
+                            # and if the backend zero-copied (the device array
+                            # ALIASES the buffer, PJRT CPU with 64-byte-aligned
+                            # hosts), hand the memory over instead of recycling
+                            devs = [t[1] for t in out]
+                            jax.block_until_ready(devs)
+                            if _aliases_buffer(devs, pooled):
+                                staging_pool.forfeit(pooled)
+                            else:
+                                staging_pool.release(pooled)
+                            pooled = None
                 finally:
                     clock.exit("put")
                 return out
@@ -1116,7 +1120,8 @@ def load_safetensors(
                 else:
                     entries.append(r)
             settled[name] = entries
-        packed = _transfer_packs(pack_jobs)
+        with trace.span("dl.put", packs=len(pack_jobs)):
+            packed = _transfer_packs(pack_jobs)
         for name, info in tensors.items():
             sharding, _groups = plans[name]
             shards, scale_shards = [], []
@@ -1156,24 +1161,21 @@ def load_safetensors(
     stats.fetch_width = governor.width
     stats.fetch_backoffs = governor.backoffs
     stats.fetch_growths = governor.growths
+    stats.fetch_busy_seconds = clock.busy["fetch"]
     stats.device_put_seconds = clock.busy["put"]
     stats.overlap_seconds = clock.overlap_s
     stats.staging_allocs = staging_pool.allocs
     stats.staging_reuses = staging_pool.reuses
-    from modelx_tpu.utils import trace
-
-    trace.tracer().record({
-        "path": "dl.load",
-        "start_s": t0,
-        "duration_s": stats.total_seconds,
-        "tensors": stats.tensors,
-        "bytes_fetched": stats.bytes_fetched,
-        "bytes_to_device": stats.bytes_to_device,
-        "fetch_thread_s": round(stats.fetch_seconds, 3),
-        "overlap_s": round(stats.overlap_seconds, 3),
-        "staging_allocs": stats.staging_allocs,
-        "gbps": round(stats.gbps, 3),
-    })
+    trace.record(
+        "dl.load", t0, stats.total_seconds,
+        tensors=stats.tensors,
+        bytes_fetched=stats.bytes_fetched,
+        bytes_to_device=stats.bytes_to_device,
+        fetch_thread_s=round(stats.fetch_seconds, 3),
+        overlap_s=round(stats.overlap_seconds, 3),
+        staging_allocs=stats.staging_allocs,
+        gbps=round(stats.gbps, 3),
+    )
     return results, stats
 
 

@@ -214,7 +214,20 @@ _CACHE_EVENTS = {
     "/jax/compilation_cache/cache_hits": "hits",
     "/jax/compilation_cache/cache_misses": "misses",
 }
-_cache_counts = {"requests": 0, "hits": 0, "misses": 0}
+# ... and where a program's time goes, from jax's duration events: tracing
+# the python function to a jaxpr, lowering it to MLIR, and the backend's
+# compile-or-read-back (which contains the persistent cache's retrieval).
+# What a "cache hit" costs beyond these — loading the executable, its first
+# run — is the residue a caller's own clock sees.
+_CACHE_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+}
+_cache_counts = {"requests": 0, "hits": 0, "misses": 0, "programs": 0,
+                 "trace_s": 0.0, "lower_s": 0.0, "backend_compile_s": 0.0,
+                 "retrieval_s": 0.0}
 _cache_counts_lock = threading.Lock()
 
 
@@ -225,7 +238,17 @@ def _count_cache_event(event: str, **_kwargs) -> None:
             _cache_counts[key] += 1
 
 
+def _add_cache_duration(event: str, duration: float, **_kwargs) -> None:
+    key = _CACHE_DURATIONS.get(event)
+    if key is not None:
+        with _cache_counts_lock:
+            _cache_counts[key] += duration
+            if key == "backend_compile_s":
+                _cache_counts["programs"] += 1
+
+
 jax.monitoring.register_event_listener(_count_cache_event)
+jax.monitoring.register_event_duration_secs_listener(_add_cache_duration)
 
 
 def compile_cache_dir() -> str:
@@ -235,10 +258,14 @@ def compile_cache_dir() -> str:
 
 
 def compile_cache_stats() -> dict:
-    """``{"dir", "requests", "hits", "misses"}`` for /metrics: whether a
-    restart found its programs is read here, not inferred from timing."""
+    """``{"dir", "requests", "hits", "misses", "programs", "trace_s",
+    "lower_s", "backend_compile_s", "retrieval_s"}`` for /metrics: whether a
+    restart found its programs, and what each cost, is read here, not
+    inferred from timing."""
     with _cache_counts_lock:
-        return {"dir": _compile_cache_dir, **_cache_counts}
+        return {"dir": _compile_cache_dir,
+                **{k: round(v, 6) if isinstance(v, float) else v
+                   for k, v in _cache_counts.items()}}
 
 
 def cold_cache_dir(leg: str) -> str:
@@ -392,9 +419,10 @@ class ModelServer:
             # detect the family from the headers so the right partition rules
             # apply from the first byte fetched
             infos_all: dict = {}
-            for path in paths:
-                infos, _ = read_header_from_file(path)
-                infos_all.update(infos)
+            with trace.span("headers", files=len(paths)):
+                for path in paths:
+                    infos, _ = read_header_from_file(path)
+                    infos_all.update(infos)
             self.family = fam.detect(list(infos_all))
             # mirror the loader's expert fusion so header-derived shapes
             # match the params it will deliver (stacked [E, ...] experts)
@@ -428,16 +456,25 @@ class ModelServer:
             compile_thread.start()
             params: dict = {}
             total = 0
-            for path in paths:
-                src = LocalFileSource(path)
-                try:
-                    arrays, stats = load_safetensors(
-                        src, self.mesh, self.family.rules, quantize=self.quantize
-                    )
-                finally:
-                    src.close()
-                params.update(arrays)
-                total += stats.bytes_to_device
+            # the loader's own split, summed over the shards: thread-seconds
+            # in ranged reads, then wall seconds with a read in flight, with
+            # a device_put in flight, and with both
+            fetch_s = fetch_busy_s = put_s = overlap_s = 0.0
+            with trace.span("shards", files=len(paths)):
+                for path in paths:
+                    src = LocalFileSource(path)
+                    try:
+                        arrays, stats = load_safetensors(
+                            src, self.mesh, self.family.rules, quantize=self.quantize
+                        )
+                    finally:
+                        src.close()
+                    params.update(arrays)
+                    total += stats.bytes_to_device
+                    fetch_s += stats.fetch_seconds
+                    fetch_busy_s += stats.fetch_busy_seconds
+                    put_s += stats.device_put_seconds
+                    overlap_s += stats.overlap_seconds
             self.params = params
             if self.lora_dir:
                 from modelx_tpu.dl import lora
@@ -457,6 +494,10 @@ class ModelServer:
             self.stats["weight_shard_factor"] = weight_shard_factor(self.mesh)
             self.stats["family"] = self.family.name
             self.stats["load_seconds"] = round(seconds, 3)
+            self.stats["load_fetch_seconds"] = round(fetch_s, 3)
+            self.stats["load_fetch_busy_seconds"] = round(fetch_busy_s, 3)
+            self.stats["load_device_put_seconds"] = round(put_s, 3)
+            self.stats["load_overlap_seconds"] = round(overlap_s, 3)
             self.stats["load_bytes"] = total
             self.stats["load_gbps"] = round(total / max(seconds, 1e-9) / 1e9, 3)
             from modelx_tpu import native
@@ -467,7 +508,8 @@ class ModelServer:
             self.stats["native_io"] = native.available()
             self._compile()
             if compile_thread is not None:
-                compile_thread.join()
+                with trace.span("compile_join"):
+                    compile_thread.join()
             self.stats["ready_seconds"] = round(time.monotonic() - t0, 3)
             self.ready = True
             self._install_kv_bundles()
@@ -1522,8 +1564,10 @@ class ServerSet:
                         device_telemetry=self.device_telemetry,
                     )
 
+                t_build = time.monotonic()
                 try:
-                    cb = build()
+                    with trace.span("startup.engine_init", model=server.name):
+                        cb = build()
                 except Exception as exc:
                     # RESOURCE_EXHAUSTED allocating the KV/page pool: demote
                     # idle tenants' state to the host tier and retry ONCE;
@@ -1555,6 +1599,9 @@ class ServerSet:
                         raise EngineBrokenError(
                             f"KV allocation for {server.name} failed after "
                             "demoting idle state") from exc2
+                # built lazily, by the first request: outside the stages
+                # that tile process start -> ready, so noted beside them
+                trace.startup.note("engine_init", time.monotonic() - t_build)
                 self.cbatchers[server.name] = cb
         return cb
 
@@ -1718,6 +1765,7 @@ class ServerSet:
         if errs and len(errs) == len(servers):
             name, err = next(iter(errs.items()))
             raise RuntimeError(f"loading {name} failed: {err}") from err
+        trace.startup.ready()
         return {
             name: dict(s.stats, **({"error": s.load_error} if s.load_error else {}))
             for name, s in self.servers.items()
@@ -2155,6 +2203,11 @@ def serve(servers: ModelServer | ServerSet, listen: str = ":8000",
                     payload["device"] = devmem.sample()
                 if compile_cache_dir():
                     payload["compile_cache"] = compile_cache_stats()
+                started = trace.startup.snapshot()
+                if started:
+                    # process creation -> ready by stage (utils/trace.py):
+                    # the stages sum to ready_s
+                    payload["startup"] = started
                 # content negotiation (ISSUE 13): the SAME tree renders
                 # as Prometheus text on Accept: text/plain or
                 # ?format=prometheus; the default JSON is byte-unchanged
@@ -2267,6 +2320,33 @@ def serve(servers: ModelServer | ServerSet, listen: str = ":8000",
                         timing=self._timing,
                     )
 
+        def _profile(self, req: dict, key: str, out_dir, dir_key: str,
+                     admin: bool = False):
+            """One profiler capture of ``req[key]`` seconds into
+            ``out_dir()``: one at a time, python tracer off unless the body
+            says ``"python_tracer": true`` (utils/trace.jax_profile). The
+            admin surface refuses 0 seconds and echoes the duration."""
+            try:
+                seconds = float(req.get(key, 3))
+            except (TypeError, ValueError):
+                seconds = -1.0
+            if not (0 <= seconds <= MAX_PROFILE_SECONDS) or (admin and not seconds):
+                return self._json(
+                    400,
+                    {"error": f"{key} must be a number in {'(' if admin else '['}0, "
+                              f"{MAX_PROFILE_SECONDS}]"},
+                )
+            if not sset._profiling.acquire(blocking=False):
+                return self._json(409, {"error": "profile already running"})
+            try:
+                path = out_dir()
+                with trace.jax_profile(
+                        path, python_tracer=req.get("python_tracer") is True):
+                    time.sleep(seconds)
+            finally:
+                sset._profiling.release()
+            return self._json(200, {dir_key: path, **({key: seconds} if admin else {})})
+
         def _do_POST(self):
             length = int(self.headers.get("Content-Length", 0) or 0)
             try:
@@ -2280,24 +2360,8 @@ def serve(servers: ModelServer | ServerSet, listen: str = ":8000",
                 return self._json(400, {"error": "request body must be a JSON object"})
 
             if self.path == "/v1/profile":
-                try:
-                    seconds = float(req.get("seconds", 3))
-                except (TypeError, ValueError):
-                    seconds = -1.0
-                if not (0 <= seconds <= MAX_PROFILE_SECONDS):
-                    return self._json(
-                        400,
-                        {"error": f"seconds must be a number in [0, {MAX_PROFILE_SECONDS}]"},
-                    )
-                if not sset._profiling.acquire(blocking=False):
-                    return self._json(409, {"error": "profile already running"})
-                try:
-                    with trace.jax_profile(sset.trace_dir):
-                        time.sleep(seconds)
-                finally:
-                    sset._profiling.release()
-                return self._json(200, {"trace_dir": sset.trace_dir})
-
+                return self._profile(req, "seconds", lambda: sset.trace_dir,
+                                     "trace_dir")
             if self.path == "/admin/profile":
                 # on-demand XLA profiler capture (ISSUE 15): same
                 # one-at-a-time lock as /v1/profile, but admin-gated and
@@ -2306,26 +2370,8 @@ def serve(servers: ModelServer | ServerSet, listen: str = ":8000",
                 # never grow the disk without bound
                 if not self._admin_auth():
                     return
-                try:
-                    seconds = float(req.get("duration_s", 3))
-                except (TypeError, ValueError):
-                    seconds = -1.0
-                if not (0 < seconds <= MAX_PROFILE_SECONDS):
-                    return self._json(
-                        400,
-                        {"error": "duration_s must be a number in "
-                                  f"(0, {MAX_PROFILE_SECONDS}]"},
-                    )
-                if not sset._profiling.acquire(blocking=False):
-                    return self._json(409, {"error": "profile already running"})
-                try:
-                    capture_dir = sset.next_capture_dir()
-                    with trace.jax_profile(capture_dir):
-                        time.sleep(seconds)
-                finally:
-                    sset._profiling.release()
-                return self._json(200, {"capture_dir": capture_dir,
-                                        "duration_s": seconds})
+                return self._profile(req, "duration_s", sset.next_capture_dir,
+                                     "capture_dir", admin=True)
 
             if self.path == "/admin/models":
                 # runtime load: pull a registry ref (or point at a local

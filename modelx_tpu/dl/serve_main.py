@@ -14,8 +14,10 @@ import threading
 import time
 
 import click
+import jax
 
 from modelx_tpu.dl.serve import ModelServer, ServerSet, enable_compile_cache, serve
+from modelx_tpu.utils import trace
 
 
 @click.command("modelx-serve")
@@ -285,7 +287,12 @@ def main(model_dir: str, models: tuple[str, ...], mesh: str, dtype: str, listen:
     logging.getLogger("modelx").setLevel(logging.INFO)
     from modelx_tpu.parallel.distributed import initialize
 
+    # process creation -> ready by stage (/metrics "startup"): everything up
+    # to here — interpreter, imports, flag parsing — was `imports`
+    trace.startup.begin("backend_init")
     initialize()  # no-op single-process; wires multi-host TPU pods
+    devices = jax.devices()  # the backend answers: on a TPU, seconds
+    trace.startup.stage("configure")
     if compile_cache:
         enable_compile_cache()
     if blob_cache_dir:
@@ -335,11 +342,8 @@ def main(model_dir: str, models: tuple[str, ...], mesh: str, dtype: str, listen:
 
     # one mesh shared by every tenant (same devices either way; sharing keeps
     # shardings comparable and avoids rebuilding device lists per model)
-    import jax
-
     from modelx_tpu.parallel.mesh import make_mesh
 
-    devices = jax.devices()
     shared_mesh = make_mesh(mesh) if mesh else make_mesh(f"dp={len(devices)}")
     from modelx_tpu.parallel.mesh import mesh_str, weight_shard_factor
 
@@ -462,10 +466,12 @@ def main(model_dir: str, models: tuple[str, ...], mesh: str, dtype: str, listen:
             "--state-spool-dir is inert without --disk-state-budget-bytes "
             "(nothing spools to a 0-byte disk tier)"
         )
+    trace.startup.stage("listener")
     httpd = serve(sset, listen=listen,  # starts serving 503s while loading
                   access_log=access_log,
                   access_log_max_bytes=access_log_max_bytes)
-    stats = sset.load_all(concurrent=concurrent_load)
+    trace.startup.stage("load")
+    stats = sset.load_all(concurrent=concurrent_load)  # ends the last stage
     logging.getLogger("modelx.serve").info("models loaded: %s", stats)
     stop = threading.Event()
     abort = threading.Event()  # SIGINT: skip/cut short any drain window

@@ -53,11 +53,16 @@ def _device_stats(dev) -> dict | None:
     in_use = int(stats.get("bytes_in_use", 0))
     limit = int(stats.get("bytes_limit",
                           stats.get("bytes_reservable_limit", 0)))
-    return {
+    out = {
         "hbm_bytes_in_use": in_use,
         "hbm_bytes_limit": limit,
         "hbm_bytes_reservable": max(0, limit - in_use),
     }
+    if "peak_bytes_in_use" in stats:
+        # the accountant's own high-water mark since the process started:
+        # sampling bytes_in_use misses a program's temporaries
+        out["hbm_peak_bytes"] = int(stats["peak_bytes_in_use"])
+    return out
 
 
 def _live_buffer_bytes(jax_mod) -> int | None:
@@ -114,6 +119,9 @@ def raw_sample() -> dict:
                 "hbm_bytes_in_use": p["hbm_bytes_in_use"],
                 "hbm_bytes_reservable": p["hbm_bytes_reservable"],
             }
+            if "hbm_peak_bytes" in p:  # the fullest device, not a sum
+                out["hbm_peak_bytes"] = max(out.get("hbm_peak_bytes", 0),
+                                            p["hbm_peak_bytes"])
         return out
     census = _live_buffer_bytes(jax)
     if census is not None:

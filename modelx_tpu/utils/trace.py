@@ -2,46 +2,72 @@
 has no tracing at all — only per-request wall-clock logging in
 pkg/registry/helper.go:98-113).
 
-Design: a process-local collector of closed spans. ``span()`` is a context
-manager; nesting is tracked per-thread/task with a contextvar so span names
-compose into paths (``dl.load/fetch``). Zero deps, thread-safe, bounded.
+One primitive, ``span()``, with three sinks:
+
+* a cumulative aggregate per span path (count / total_s / max_s / self_s),
+  bounded by the number of distinct paths and never evicted — what
+  ``/v1/trace`` serves, so ``serve.load`` is still there after a day of
+  traffic;
+* a bounded ring of the spans closed under a request context — what
+  ``/v1/trace?request_id=`` slices;
+* the profiler: while a capture started by :func:`jax_profile` runs, a span
+  also opens a ``jax.profiler.TraceAnnotation`` under its path, so it lands
+  on the host plane of the same ``.xplane.pb``, on the device trace's clock.
+  Outside a capture no jax call is made and this module imports without jax
+  (the registry and the client import it).
 
     with trace.span("dl.load", uri=uri):
-        with trace.span("fetch", tensor=name):
+        with trace.span("fetch", tensor=name):   # path "dl.load/fetch"
             ...
 
-Every closed span is logged at DEBUG (or INFO with MODELX_TRACE=1), kept in
-the ring for ``trace.spans()`` / ``trace.export_json()``, and surfaces in
-the registry /metrics and the serve sidecar's /v1/trace endpoint.
-
-``jax_profile()`` wraps ``jax.profiler`` traces for on-demand device-level
-profiling from the serving sidecar.
+Two clocks built on the same sinks tile a thread's time instead of nesting
+in it: :class:`Phases` (the engine loop: every instant of a step is in
+exactly one leaf phase) and :data:`startup` (process creation -> ready, by
+stage). Every closed span is logged at DEBUG (INFO with ``MODELX_TRACE=1``,
+read once at import).
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
-import functools
-import inspect
-import json
 import logging
 import os
 import threading
 import time
+import weakref
 from typing import Any, Iterator
+
+from modelx_tpu import T_FIRST_LINE  # the first line any entry point runs
 
 logger = logging.getLogger("modelx.trace")
 
 MAX_SPANS = 8192
 
-_current_path: contextvars.ContextVar[str] = contextvars.ContextVar("modelx_span_path", default="")
+_LOG_LEVEL = logging.INFO if os.environ.get("MODELX_TRACE") else logging.DEBUG
+
+# the open span of this thread/task: [path, seconds its closed children took,
+# the frame of its parent]
+_current: contextvars.ContextVar[list | None] = contextvars.ContextVar(
+    "modelx_span", default=None)
 
 # the request id (ISSUE 13) rides a contextvar parallel to the span path:
 # every span closed while a request context is active carries the id, so
 # /v1/trace can filter one request's timeline out of the ring
 _current_request: contextvars.ContextVar[str] = contextvars.ContextVar(
     "modelx_request_id", default="")
+
+# Set by jax_profile for as long as its capture runs: jax's TraceAnnotation
+# class, else None. Everything that bridges to the profiler tests this one
+# name, so outside a capture a span costs no jax call.
+_annotate = None
+
+# Spans that last as long as a request are not bridged: the trace reduction
+# (benchmark/xplane.py) gives a device-idle gap to the shortest host event
+# that covers most of it, else to the one that overlaps it most — an
+# envelope overlaps every gap and would take them all.
+_ENVELOPES = ("serve.request", "serve.generate", "router.request")
 
 
 def current_request_id() -> str:
@@ -60,34 +86,51 @@ def request_context(request_id: str) -> Iterator[None]:
         _current_request.reset(token)
 
 
+def _fold(agg: dict[str, list], path: str, n: int, total: float, longest: float,
+          own: float) -> None:
+    """Add to ``agg[path]`` = [count, total_s, max_s, self_s]."""
+    a = agg.get(path)
+    if a is None:
+        a = agg[path] = [0, 0.0, 0.0, 0.0]
+    a[0] += n
+    a[1] += total
+    if longest > a[2]:
+        a[2] = longest
+    a[3] += own
+
+
 class Tracer:
-    """Collects closed spans in a bounded ring; drop count is tracked."""
+    """The aggregate (every span, never evicted) and the ring (spans of
+    requests, bounded; the drop count is tracked)."""
 
     def __init__(self, max_spans: int = MAX_SPANS) -> None:
-        import collections
-
         self._lock = threading.Lock()
         self._spans: collections.deque[dict[str, Any]] = collections.deque(maxlen=max_spans)
+        self._agg: dict[str, list] = {}  # path -> [count, total_s, max_s, self_s]
+        self._clocks: "weakref.WeakSet[Phases]" = weakref.WeakSet()
         self._dropped = 0
         self.max_spans = max_spans
 
     def record(self, span: dict[str, Any]) -> None:
+        dur = span["duration_s"]
         with self._lock:
-            if len(self._spans) == self.max_spans:
-                self._dropped += 1  # deque(maxlen) evicts the oldest in O(1)
-            self._spans.append(span)
-        level = logging.INFO if os.environ.get("MODELX_TRACE") else logging.DEBUG
-        if logger.isEnabledFor(level):
+            _fold(self._agg, span["path"], 1, dur, dur, span.get("self_s", dur))
+            if "request_id" in span:
+                if len(self._spans) == self.max_spans:
+                    self._dropped += 1  # deque(maxlen) evicts the oldest in O(1)
+                self._spans.append(span)
+        if logger.isEnabledFor(_LOG_LEVEL):
             logger.log(
-                level,
+                _LOG_LEVEL,
                 "span %s %.1fms %s",
                 span["path"],
-                span["duration_s"] * 1e3,
+                dur * 1e3,
                 {k: v for k, v in span.items() if k not in ("path", "start_s", "duration_s")},
             )
 
     def spans(self, prefix: str = "",
               request_id: str = "") -> list[dict[str, Any]]:
+        """The ring: spans closed under a request context, oldest first."""
         # one O(n) copy under the lock, filtering OUTSIDE it: concurrent
         # record() calls never wait on a caller's aggregation
         with self._lock:
@@ -101,6 +144,7 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._spans.clear()
+            self._agg.clear()
             self._dropped = 0
 
     @property
@@ -108,24 +152,28 @@ class Tracer:
         with self._lock:
             return self._dropped
 
-    def export_json(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(self.spans(), f, indent=1)
-
     def summary(self, prefix: str = "",
                 request_id: str = "") -> dict[str, dict[str, float]]:
-        """Per-path aggregate: count / total_s / max_s (for /metrics and
-        /v1/trace, optionally filtered to one request id). Aggregates
-        over a lock-snapshot copy — the tracer lock is held only for the
-        ring copy inside :meth:`spans`, never across the whole walk, so
-        concurrent ``record()`` calls proceed unblocked."""
-        agg: dict[str, dict[str, float]] = {}
-        for s in self.spans(prefix, request_id):
-            a = agg.setdefault(s["path"], {"count": 0, "total_s": 0.0, "max_s": 0.0})
-            a["count"] += 1
-            a["total_s"] += s["duration_s"]
-            a["max_s"] = max(a["max_s"], s["duration_s"])
-        return agg
+        """Per-path count / total_s / max_s / self_s (for /metrics and
+        /v1/trace). Without ``request_id``: the cumulative aggregate since
+        the process started, phase clocks included. With it: that request's
+        spans, aggregated from the ring."""
+        agg: dict[str, list] = {}
+        if request_id:
+            for s in self.spans(prefix, request_id):
+                dur = s["duration_s"]
+                _fold(agg, s["path"], 1, dur, dur, s.get("self_s", dur))
+        else:
+            with self._lock:
+                agg = {p: list(a) for p, a in self._agg.items()}
+                clocks = list(self._clocks)
+            for clock in clocks:
+                for path, row in clock.aggregate().items():
+                    _fold(agg, path, *row)
+            if prefix:
+                agg = {p: a for p, a in agg.items() if p.startswith(prefix)}
+        return {p: {"count": a[0], "total_s": a[1], "max_s": a[2], "self_s": a[3]}
+                for p, a in agg.items()}
 
 
 _tracer = Tracer()
@@ -135,76 +183,252 @@ def tracer() -> Tracer:
     return _tracer
 
 
-def spans(prefix: str = "") -> list[dict[str, Any]]:
-    return _tracer.spans(prefix)
+def record(path: str, start_s: float, duration_s: float, **attrs: Any) -> None:
+    """A span stamped after the fact (its caller kept the clock)."""
+    rid = _current_request.get()
+    if rid:
+        attrs["request_id"] = rid
+    _tracer.record({**attrs, "path": path, "start_s": start_s, "duration_s": duration_s})
 
 
-def export_json(path: str) -> None:
-    _tracer.export_json(path)
+class span:
+    """Time a block; ``with span(...) as rec`` yields a dict that accepts
+    extra attrs while open. Paths nest per thread/task (``parent/name``)."""
 
+    __slots__ = ("name", "rec", "_frame", "_token", "_start", "_ann")
 
-@contextlib.contextmanager
-def span(name: str, **attrs: Any) -> Iterator[dict[str, Any]]:
-    """Time a block; the yielded dict accepts extra attrs while open."""
-    parent = _current_path.get()
-    path = f"{parent}/{name}" if parent else name
-    token = _current_path.set(path)
-    rec: dict[str, Any] = dict(attrs)
-    start = time.monotonic()
-    try:
-        yield rec
-    except BaseException as e:
-        rec["error"] = f"{type(e).__name__}: {e}"
-        raise
-    finally:
-        _current_path.reset(token)
+    def __init__(self, name: str, **attrs: Any) -> None:
+        self.name = name
+        self.rec: dict[str, Any] = attrs
+
+    def __enter__(self) -> dict[str, Any]:
+        parent = _current.get()
+        self._frame = [f"{parent[0]}/{self.name}" if parent else self.name, 0.0, parent]
+        self._token = _current.set(self._frame)
+        self._ann = None
+        if _annotate is not None and not self.name.startswith(_ENVELOPES):
+            self._ann = _annotate(self._frame[0])
+            self._ann.__enter__()
+        self._start = time.monotonic()
+        return self.rec
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        dur = time.monotonic() - self._start
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        try:
+            _current.reset(self._token)
+        except ValueError:
+            # an abandoned generator's span, closed by the collector in
+            # another thread's context: there is nothing to restore there
+            pass
+        path, child_s, parent = self._frame
+        if parent is not None:
+            parent[1] += dur
+        rec = self.rec
+        if exc is not None:
+            rec["error"] = f"{type(exc).__name__}: {exc}"
         rec["path"] = path
-        rec["start_s"] = start
-        rec["duration_s"] = time.monotonic() - start
+        rec["start_s"] = self._start
+        rec["duration_s"] = dur
+        rec["self_s"] = max(0.0, dur - child_s)
         rid = _current_request.get()
         if rid:
             rec["request_id"] = rid
         _tracer.record(rec)
 
 
-def traced(name: str):
-    """Decorator form of :func:`span`.
+class Phases:
+    """Leaf phases that TILE one thread's loop: between ``begin`` and ``end``
+    every instant belongs to exactly one phase, so the phase seconds sum to
+    the loop's wall time by construction. ``begin(i, step_num)`` opens a step
+    (closing the one before) in phase ``i``; ``to(i)`` switches phase with
+    one clock read and no allocation. Per step, the thread's CPU time
+    (``time.thread_time``) is added up beside the wall time: over the phases
+    that do not wait, wall minus CPU is the time the thread stood by for
+    the GIL or the scheduler. During a profiler capture the step and each
+    phase are also annotations (``<step>`` with ``step_num``, and
+    ``<step>/<phase>``); phases never enter the ring."""
 
-    ``functools.wraps`` preserves the wrapped function's signature,
-    annotations, and qualname (the old manual ``__name__``/``__doc__``
-    copy dropped everything ``inspect.signature`` reads). Generator
-    functions get their own path: wrapping one in a plain ``with span``
-    closed the span at the FIRST yield — before any work ran — so the
-    generator variant keeps the span open across the whole iteration."""
+    def __init__(self, step: str, names: tuple[str, ...]) -> None:
+        self.step = step
+        self.names = names
+        self.paths = tuple(f"{step}/{n}" for n in names)
+        self.seconds = [0.0] * len(names)
+        self.entries = [0] * len(names)
+        self.longest = [0.0] * len(names)
+        self.steps = 0
+        self.wall_s = 0.0
+        self.cpu_s = 0.0
+        self._cur = -1
+        self._t = self._t_step = self._cpu_step = 0.0
+        self._ann = self._ann_step = None
+        _tracer._clocks.add(self)
 
-    def deco(fn):
-        if inspect.isgeneratorfunction(fn):
+    def _close(self, now: float) -> None:
+        dt = now - self._t
+        self.seconds[self._cur] += dt
+        if dt > self.longest[self._cur]:
+            self.longest[self._cur] = dt
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
 
-            @functools.wraps(fn)
-            def genwrapper(*args, **kwargs):
-                with span(name):
-                    yield from fn(*args, **kwargs)
+    def _open(self, i: int, now: float) -> None:
+        self._cur = i
+        self._t = now
+        self.entries[i] += 1
+        if _annotate is not None:
+            self._ann = _annotate(self.paths[i])
+            self._ann.__enter__()
 
-            return genwrapper
+    def to(self, i: int) -> None:
+        if i == self._cur or self._cur < 0:  # no entry for staying; no step open
+            return
+        now = time.monotonic()
+        self._close(now)
+        self._open(i, now)
 
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            with span(name):
-                return fn(*args, **kwargs)
+    def begin(self, i: int, step_num: int = 0) -> None:
+        self.end()
+        now = time.monotonic()
+        self._t_step = now
+        self._cpu_step = time.thread_time()
+        self.steps += 1
+        if _annotate is not None:
+            self._ann_step = _annotate(self.step, step_num=step_num)
+            self._ann_step.__enter__()
+        self._open(i, now)
 
-        return wrapper
+    def end(self) -> None:
+        if self._cur < 0:
+            return
+        now = time.monotonic()
+        self._close(now)
+        self._cur = -1
+        self.wall_s += now - self._t_step
+        self.cpu_s += time.thread_time() - self._cpu_step
+        if self._ann_step is not None:
+            self._ann_step.__exit__(None, None, None)
+            self._ann_step = None
 
-    return deco
+    def aggregate(self) -> dict[str, tuple[int, float, float, float]]:
+        """path -> (entries, seconds, longest, self seconds): a phase is a
+        leaf, and the step is all phases."""
+        out = {p: (n, s, m, s) for p, n, s, m in
+               zip(self.paths, self.entries, self.seconds, self.longest) if n}
+        if self.steps:
+            out[self.step] = (self.steps, self.wall_s, 0.0, 0.0)
+        return out
+
+
+def _process_age_s() -> float | None:
+    """Seconds since the kernel created this process: its start time in
+    ``/proc/self/stat`` (clock ticks since boot) against the boot clock."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        return time.clock_gettime(time.CLOCK_BOOTTIME) - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+class Startup:
+    """Process creation -> ready, by stage. Like :class:`Phases` the stages
+    tile: ``begin(name)`` closes ``imports`` (interpreter start and imports,
+    from the process's creation), each ``stage(name)`` closes the one before, and
+    ``ready()`` closes the last, so the stage seconds sum to ``ready_s``.
+    Each closed stage is also a span ``startup.<stage>``. ``note`` keeps
+    what falls outside the tiling (an engine built lazily by the first
+    request): its seconds, and when it ended."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self.reset()
+
+    def reset(self) -> None:
+        self.t0: float | None = None
+        self.source = ""
+        self._stages: dict[str, float] = {}
+        self._extra: dict[str, float] = {}
+        self._cur = ""
+        self._t = 0.0
+        self.ready_s: float | None = None
+
+    def begin(self, stage: str) -> None:
+        now, first = time.monotonic(), T_FIRST_LINE
+        age = _process_age_s()
+        with self._lock:
+            if self.t0 is not None:
+                return
+            # the kernel's stamp has the clock tick's resolution (10 ms);
+            # one that says the process is younger than its own first line
+            # is wrong (a sandboxed /proc)
+            if age is not None and age >= now - first - 0.02:
+                self.t0, self.source = now - age, "proc_stat"
+            else:
+                self.t0, self.source = first, "first_line"
+            self._cur, self._t = "imports", self.t0
+        self.stage(stage)
+
+    def stage(self, name: str) -> None:
+        now = time.monotonic()
+        with self._lock:
+            if self.t0 is None or self.ready_s is not None:
+                return
+            prev, start = self._cur, self._t
+            self._stages[prev] = self._stages.get(prev, 0.0) + now - start
+            self._cur, self._t = name, now
+        record(f"startup.{prev}", start, now - start)
+
+    def ready(self) -> None:
+        self.stage("")
+        with self._lock:
+            if self.t0 is not None and self.ready_s is None:
+                self.ready_s = self._t - self.t0
+
+    def note(self, name: str, seconds: float) -> None:
+        """``seconds`` of ``name`` just ended: added up under ``<name>_s``,
+        and the first such instant kept under ``<name>_at_s``."""
+        with self._lock:
+            if self.t0 is not None:
+                self._extra[f"{name}_s"] = self._extra.get(f"{name}_s", 0.0) + seconds
+                self._extra.setdefault(f"{name}_at_s", time.monotonic() - self.t0)
+
+    def snapshot(self) -> dict:
+        """``{<stage>_s..., ready_s, <noted>_s..., <instant>_at_s...,
+        source}``; empty in a process that never called ``begin``."""
+        with self._lock:
+            if self.t0 is None:
+                return {}
+            out = {f"{k}_s": round(v, 4) for k, v in self._stages.items()}
+            out.update({k: round(v, 4) for k, v in self._extra.items()})
+            if self.ready_s is not None:
+                out["ready_s"] = round(self.ready_s, 4)
+            out["source"] = self.source
+            return out
+
+
+startup = Startup()
 
 
 @contextlib.contextmanager
-def jax_profile(trace_dir: str) -> Iterator[None]:
+def jax_profile(trace_dir: str, python_tracer: bool = False) -> Iterator[None]:
     """Device-level profiling window (jax.profiler trace, viewable in
-    tensorboard/xprof). No-op if jax is unavailable."""
+    tensorboard/xprof), with spans and phases bridged into it. The python
+    tracer is off unless asked for: its per-call hooks tripled the host work
+    they were meant to time (a first request of 13.8 s read 36.7 s). jax's
+    own TraceMe events (``backend_compile``, ``PjitFunction(...)``) are
+    host-tracer events and stay. No-op if jax is unavailable."""
+    global _annotate
     try:
         import jax
 
-        jax.profiler.start_trace(trace_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 1 if python_tracer else 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        _annotate = jax.profiler.TraceAnnotation
         started = True
     except Exception as e:  # profiling must never take the service down
         logger.warning("jax profiler unavailable: %s", e)
@@ -213,6 +437,7 @@ def jax_profile(trace_dir: str) -> Iterator[None]:
         yield
     finally:
         if started:
+            _annotate = None
             try:
                 jax.profiler.stop_trace()
             except Exception as e:

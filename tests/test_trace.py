@@ -1,12 +1,18 @@
-"""Tracing subsystem (utils/trace.py): span paths, error capture, bounded
-ring, aggregation — the observability layer SURVEY.md §5 prescribes (the
-reference has only per-request wall-clock logging)."""
+"""Tracing subsystem (utils/trace.py): span paths, error capture, the
+cumulative aggregate, the ring of request spans, the phase and start-up
+clocks — the observability layer SURVEY.md §5 prescribes (the reference has
+only per-request wall-clock logging). The ring keeps the spans of requests,
+so tests that read single spans back run under a request context."""
 
-import json
+import subprocess
+import sys
+import time
 
 import pytest
 
-from modelx_tpu.utils.trace import Tracer, jax_profile, span, traced, tracer
+from modelx_tpu.utils import trace
+from modelx_tpu.utils.trace import (Phases, Startup, Tracer, jax_profile, request_context,
+                                    span, tracer)
 
 
 @pytest.fixture(autouse=True)
@@ -16,6 +22,13 @@ def clean_tracer():
     tracer().clear()
 
 
+@pytest.fixture
+def in_request():
+    with request_context("req-test"):
+        yield
+
+
+@pytest.mark.usefixtures("in_request")
 class TestSpan:
     def test_nested_paths(self):
         with span("outer"):
@@ -38,14 +51,6 @@ class TestSpan:
         (s,) = tracer().spans("boom")
         assert "ValueError" in s["error"]
 
-    def test_traced_decorator(self):
-        @traced("fn.op")
-        def f(x):
-            return x + 1
-
-        assert f(1) == 2
-        assert tracer().spans("fn.op")
-
     def test_prefix_filter(self):
         with span("a.x"):
             pass
@@ -64,7 +69,7 @@ class TestSpan:
             t = threading.Thread(target=worker)
             t.start()
             t.join()
-        paths = {s["path"] for s in tracer().spans()}
+        paths = set(tracer().summary())
         # the worker thread's span must not nest under "main"
         assert "w" in paths and "main" in paths
 
@@ -73,7 +78,7 @@ class TestTracer:
     def test_ring_bound_and_dropped(self):
         t = Tracer(max_spans=3)
         for i in range(5):
-            t.record({"path": f"s{i}", "start_s": 0, "duration_s": 0})
+            t.record({"path": f"s{i}", "start_s": 0, "duration_s": 0, "request_id": "r"})
         assert len(t.spans()) == 3
         assert t.dropped == 2
         assert t.spans()[0]["path"] == "s2"
@@ -87,14 +92,55 @@ class TestTracer:
         assert abs(agg["total_s"] - 0.4) < 1e-9
         assert abs(agg["max_s"] - 0.3) < 1e-9
 
-    def test_export_json(self, tmp_path):
-        with span("x"):
-            pass
-        p = tmp_path / "trace.json"
-        tracer().export_json(str(p))
-        assert json.loads(p.read_text())[0]["path"] == "x"
+    def test_ring_keeps_request_spans_only(self):
+        t = Tracer(max_spans=4)
+        t.record({"path": "engine.phase", "start_s": 0, "duration_s": 0.1})
+        t.record({"path": "op", "start_s": 0, "duration_s": 0.1, "request_id": "r"})
+        assert [s["path"] for s in t.spans()] == ["op"]
+        assert set(t.summary()) == {"engine.phase", "op"}
+
+    def test_aggregate_survives_what_the_ring_forgets(self):
+        """``serve.load`` closed once at start-up is still in the summary
+        after 10 000 engine and request spans turned the ring over."""
+        t = Tracer(max_spans=64)
+        t.record({"path": "serve.load", "start_s": 0, "duration_s": 9.3})
+        for i in range(10_000):
+            t.record({"path": "continuous.admit", "start_s": i, "duration_s": 0.001})
+            t.record({"path": "serve.request", "start_s": i, "duration_s": 0.002,
+                      "request_id": f"r{i}"})
+        agg = t.summary()
+        assert agg["serve.load"] == {"count": 1, "total_s": 9.3, "max_s": 9.3, "self_s": 9.3}
+        assert agg["continuous.admit"]["count"] == 10_000
+        assert len(t.spans()) == 64 and t.dropped == 10_000 - 64
+        assert t.summary(prefix="serve.l").keys() == {"serve.load"}
+        # one request's slice still comes from the ring
+        assert t.summary(request_id="r9999")["serve.request"]["count"] == 1
+
+    def test_self_seconds_on_a_hand_made_nest(self):
+        with span("outer"):
+            time.sleep(0.02)
+            with span("a"):
+                time.sleep(0.03)
+            with span("b"):
+                with span("c"):
+                    time.sleep(0.01)
+        agg = tracer().summary()
+        outer, a, b, c = (agg[p] for p in ("outer", "outer/a", "outer/b", "outer/b/c"))
+        assert a["self_s"] == a["total_s"] and c["self_s"] == c["total_s"]
+        assert b["self_s"] == pytest.approx(b["total_s"] - c["total_s"], abs=1e-9)
+        assert outer["self_s"] == pytest.approx(
+            outer["total_s"] - a["total_s"] - b["total_s"], abs=1e-9)
+        assert 0.02 <= outer["self_s"] < 0.03
+
+    def test_a_shape_or_a_decision_in_the_name_is_a_path_of_its_own(self):
+        for name in ("attention.flash[144x144]+pad[256x256]", "attention.reference[16x16]"):
+            with span(name):
+                pass
+        assert {"attention.flash[144x144]+pad[256x256]",
+                "attention.reference[16x16]"} <= set(tracer().summary("attention."))
 
 
+@pytest.mark.usefixtures("in_request")
 class TestIntegration:
     def test_loader_emits_load_span(self, tmp_path):
         import ml_dtypes
@@ -119,51 +165,99 @@ class TestIntegration:
             pass
 
 
-class TestTracedWraps:
-    """ISSUE 13: ``traced()`` must be a transparent wrapper — signature,
-    qualname, and docstring survive — and must keep a GENERATOR's span
-    open across the whole iteration instead of closing at first yield."""
+class TestPhases:
+    """The engine's clock: leaf phases that tile a thread's loop."""
 
-    def test_signature_and_metadata_preserved(self):
-        import inspect
+    def test_phases_tile_the_steps(self):
+        ph = Phases("loop.step", ("work", "wait"))
+        t0 = time.monotonic()
+        for i in range(3):
+            ph.begin(0, i)
+            time.sleep(0.004)
+            ph.to(1)
+            time.sleep(0.002)
+            ph.to(1)  # staying in a phase is not an entry
+        ph.end()
+        wall = time.monotonic() - t0
+        assert ph.entries == [3, 3] and ph.steps == 3
+        assert sum(ph.seconds) == pytest.approx(ph.wall_s, abs=1e-9)
+        assert ph.wall_s <= wall and ph.seconds[0] >= 0.012 and ph.seconds[1] >= 0.006
+        assert 0 <= ph.cpu_s < ph.wall_s  # the sleeps are not CPU time
 
-        @traced("fn.sig")
-        def f(a, b=2, *, c):
-            """docs"""
-            return a + b + c
+    def test_phases_reach_the_summary_and_never_the_ring(self):
+        ph = Phases("loop.step", ("work", "wait"))
+        ph.begin(0)
+        ph.to(1)
+        ph.end()
+        agg = tracer().summary("loop.")
+        assert set(agg) == {"loop.step", "loop.step/work", "loop.step/wait"}
+        assert agg["loop.step"]["self_s"] == 0.0  # all of a step is its phases
+        assert tracer().spans("loop.") == []
 
-        assert f.__name__ == "f"
-        assert f.__doc__ == "docs"
-        assert list(inspect.signature(f).parameters) == ["a", "b", "c"]
-        assert f(1, c=3) == 6
 
-    def test_generator_span_covers_the_whole_iteration(self):
-        import time as _time
+class TestStartup:
+    def test_stages_tile_process_start_to_ready(self):
+        st = Startup()
+        st.begin("backend_init")
+        time.sleep(0.01)
+        st.stage("load")
+        time.sleep(0.01)
+        st.note("engine_init", 0.5)  # outside the tiling
+        st.ready()
+        st.stage("too_late")  # after ready nothing moves
+        snap = st.snapshot()
+        stages = [k for k in snap if k.endswith("_s") and k != "ready_s"
+                  and not k.startswith("engine_init")]
+        assert stages == ["imports_s", "backend_init_s", "load_s"]
+        assert sum(snap[k] for k in stages) == pytest.approx(snap["ready_s"], abs=1e-3)
+        assert snap["imports_s"] > 0 and snap["engine_init_s"] == 0.5
+        assert 0 < snap["engine_init_at_s"] <= snap["ready_s"]  # noted before ready here
+        assert snap["source"] in ("proc_stat", "first_line")
+        # each closed stage is a span too
+        assert {"startup.imports", "startup.backend_init", "startup.load"} <= set(
+            tracer().summary("startup."))
 
-        @traced("gen.op")
-        def g():
-            yield 1
-            _time.sleep(0.02)  # work AFTER the first yield
-            yield 2
+    def test_a_process_that_never_began_reports_nothing(self):
+        st = Startup()
+        st.stage("load")
+        st.ready()
+        assert st.snapshot() == {}
 
-        it = g()
-        assert next(it) == 1
-        # span still open: first yield must not close it
-        assert not tracer().spans("gen.op")
-        assert list(it) == [2]
-        (s,) = tracer().spans("gen.op")
-        assert s["duration_s"] >= 0.02
 
-    def test_generator_identity_preserved(self):
-        import inspect
+class TestNoJax:
+    def test_imports_and_spans_without_jax(self):
+        """The registry and the client import this module: it must load, and
+        spans and phases must run, with jax never imported."""
+        code = (
+            "import sys\n"
+            "from modelx_tpu.utils import trace\n"
+            "with trace.span('a'):\n"
+            "    with trace.span('b'):\n"
+            "        pass\n"
+            "ph = trace.Phases('s', ('x',)); ph.begin(0); ph.end()\n"
+            "assert set(trace.tracer().summary()) == {'a', 'a/b', 's', 's/x'}\n"
+            "assert 'jax' not in sys.modules, 'jax was imported'\n"
+        )
+        subprocess.run([sys.executable, "-c", code], check=True, timeout=60)
 
-        @traced("gen.id")
-        def g(n):
-            yield from range(n)
+    def test_no_annotation_outside_a_capture(self, monkeypatch):
+        calls = []
+        assert trace._annotate is None
+        with span("quiet"):
+            pass
+        monkeypatch.setattr(trace, "_annotate", lambda path, **kw: calls.append(path) or _Null())
+        with span("serve.request"):  # an envelope: never bridged
+            with span("serve.forward"):
+                pass
+        assert calls == ["serve.request/serve.forward"]
 
-        assert inspect.isgeneratorfunction(g)
-        assert g.__name__ == "g"
-        assert list(g(3)) == [0, 1, 2]
+
+class _Null:
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
 
 
 class TestRequestContext:
@@ -180,12 +274,14 @@ class TestRequestContext:
             with span("inside"):
                 pass
         assert current_request_id() == ""
-        with span("outside"):
-            pass
         (s,) = tracer().spans(request_id="req-42")
         assert s["path"] == "inside"
-        out = tracer().spans("outside")
-        assert "request_id" not in out[0]
+        with request_context(""):
+            with span("outside"):
+                pass
+        # a span closed under no request is aggregated, not kept
+        assert tracer().spans("outside") == []
+        assert tracer().summary()["outside"]["count"] == 1
 
     def test_summary_filters_by_request_id(self):
         from modelx_tpu.utils.trace import request_context
@@ -216,5 +312,5 @@ class TestRequestContext:
             t.join()
         assert seen
         # the worker thread's span never inherits the main thread's id
-        (w,) = tracer().spans("w.op")
-        assert "request_id" not in w
+        assert tracer().spans("w.op") == []
+        assert tracer().summary()["w.op"]["count"] == 1
