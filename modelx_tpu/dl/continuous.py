@@ -286,7 +286,8 @@ class ContinuousBatcher:
                  flight_recorder: bool = True,
                  flightrec_capacity: int = 0,
                  flight_dump_dir: str = "",
-                 device_telemetry: bool = True) -> None:
+                 device_telemetry: bool = True,
+                 allocate: bool = True) -> None:
         if server.family.decode_fns is None:
             raise ValueError(f"family {server.family.name} has no cached decode")
         self.server = server
@@ -370,7 +371,7 @@ class ContinuousBatcher:
         # lengths — the table is a traced input, never a shape.
         self.page_size = int(page_size)
         try:
-            self._alloc_device_state(max_live_tokens)
+            self._alloc_device_state(max_live_tokens, allocate)
         except BaseException:
             # a RESOURCE_EXHAUSTED here may leave SOME per-layer pools
             # already allocated: drop the partial tree before re-raising
@@ -456,10 +457,16 @@ class ContinuousBatcher:
         # argument (jit caches one compiled variant per depth actually
         # used), so the fault-injection seam (tests/bench wrap self._chunk)
         # and the env-gated chaos wrap below cover deep programs too
-        self._chunk = jax.jit(
+        self._chunk_jit = jax.jit(
             self._chunk_paged_impl if paged else self._chunk_impl,
             donate_argnums=(1, 2), static_argnames=("n_steps",),
         )
+        # variants of that jit fetched AHEAD of their first dispatch
+        # (chunk_warmer: a load reads them back while the weights stream):
+        # (n_steps, filtered) -> Future of the compiled program, or of None
+        # where fetching it failed and the jit compiles it at first use
+        self._chunk_aot: dict = {}
+        self._chunk = self._run_chunk
         # chunked-prefill piece programs: a mid piece only advances the
         # slot's KV (no logits output -> XLA drops the lm_head matmul);
         # the flip (last) piece also samples the row's first token.
@@ -651,12 +658,15 @@ class ContinuousBatcher:
     # chunk_size pieces) and the stop-detection lag stay bounded
     AUTO_DISPATCH_DEPTH = 4
 
-    def _alloc_device_state(self, max_live_tokens: int) -> None:
+    def _alloc_device_state(self, max_live_tokens: int, allocate: bool) -> None:
         """The engine's big device allocations — the KV page pool (or
         dense cache), its mesh placement, and the sampled-token buffer —
         split out of ``__init__`` so a mid-allocation RESOURCE_EXHAUSTED
         has one cleanup point there (partial per-layer pools are dropped
-        before the error propagates to the demote-and-retry path)."""
+        before the error propagates to the demote-and-retry path).
+        ``allocate=False`` sizes the state and leaves the device alone:
+        an engine built by a load while the weights still stream gets its
+        arrays from ``allocate_device_state`` once they are placed."""
         if self.page_size > 0:
             if self.max_len % self.page_size:
                 raise ValueError(
@@ -674,52 +684,57 @@ class ContinuousBatcher:
                 (self.max_slots, self._pages_per_slot), np.int32
             )
             self._row_pages: dict[int, list[int]] = {}  # slot -> owned pages
-            self._cache = jax.tree_util.tree_map(
+        else:
+            self.num_pages = 0
+        self.mesh = self.server.mesh
+        self.mesh_devices = int(self.mesh.size)
+        self._cache = self._tok = None
+        if allocate:
+            self.allocate_device_state()
+
+    def allocate_device_state(self) -> None:
+        """Allocate the KV cache (or page pool) and the token buffer,
+        zeroed, on the serving mesh. The engine owns this state and donates
+        it through every program, so HBM holds exactly one copy."""
+        self._cache, self._tok = self._new_device_state()
+
+    def _new_device_state(self) -> tuple:
+        if self.page_size > 0:
+            cache = jax.tree_util.tree_map(
                 lambda leaf: jnp.zeros(
                     (self.num_pages, self.page_size) + leaf.shape[2:], leaf.dtype
                 ),
                 self._init_cache(1, self.page_size),
             )
         else:
-            self.num_pages = 0
-            # engine-owned device state: the big cache (donated through
-            # every program so HBM holds exactly one copy)
-            self._cache = self._init_cache(self.max_slots, self.max_len)
-        # -- mesh placement (tensor-parallel continuous decode) -------------
-        # On a >1-device mesh the engine's KV state gets an explicit GSPMD
-        # layout before the first program closes over it: dense caches
-        # shard slots over dp and kv heads over tp; the paged pool shards
-        # kv heads over tp only (its leading dim is a global page index no
-        # axis may split). Every program the engine compiles then inherits
-        # these input layouts, so decode math runs tensor-parallel instead
-        # of congealing on device 0. A single-device mesh skips this block
-        # entirely — the dp=1 engine stays byte-identical to before.
-        self.mesh = self.server.mesh
-        self.mesh_devices = int(self.mesh.size)
-        self._cache = self._place_cache(self._cache)
-        self._tok = jnp.zeros((self.max_slots, 1), jnp.int32)
+            cache = self._init_cache(self.max_slots, self.max_len)
+        return (self._place_cache(cache),
+                jnp.zeros((self.max_slots, 1), jnp.int32))
 
-    def _place_cache(self, cache):
-        """Lay the engine's KV state out on the serving mesh (no-op on a
-        single device — the dp=1 engine stays byte-identical to before).
-        Dense caches shard slots over dp and kv heads over tp; the paged
-        pool shards kv heads over tp only, because its leading dim is a
-        global page index no axis may split. Every program the engine
-        compiles inherits these input layouts, so decode math runs
-        tensor-parallel instead of congealing on device 0."""
+    def _cache_sharding(self, shape):
+        """Where one KV leaf lives on the serving mesh (None on a single
+        device — the dp=1 engine stays byte-identical to before). Dense
+        caches shard slots over dp and kv heads over tp; the paged pool
+        shards kv heads over tp only, because its leading dim is a global
+        page index no axis may split."""
         if self.mesh_devices <= 1:
-            return cache
+            return None
         from modelx_tpu.dl.sharding import cache_sharding
 
-        pool_batch_dim = -1 if self.page_size > 0 else 0
+        return cache_sharding(
+            self.mesh, shape, batch_dim=-1 if self.page_size > 0 else 0,
+            head_dim=len(shape) - 2,
+        )
+
+    def _place_cache(self, cache):
+        """Lay the engine's KV state out on the serving mesh with an
+        explicit GSPMD layout before the first program closes over it.
+        Every program the engine compiles inherits these input layouts, so
+        decode math runs tensor-parallel instead of congealing on device 0."""
+        if self.mesh_devices <= 1:
+            return cache
         return jax.tree_util.tree_map(
-            lambda leaf: jax.device_put(
-                leaf,
-                cache_sharding(
-                    self.mesh, leaf.shape, batch_dim=pool_batch_dim,
-                    head_dim=len(leaf.shape) - 2,
-                ),
-            ),
+            lambda leaf: jax.device_put(leaf, self._cache_sharding(leaf.shape)),
             cache,
         )
 
@@ -1129,6 +1144,93 @@ class ContinuousBatcher:
             jnp.arange(n_steps or self.chunk_size),
         )
         return cache, tok, jnp.concatenate([toks.T, tok], axis=1)
+
+    def _chunk_args(self, filtered: bool) -> list:
+        """The per-slot inputs of one chunk dispatch, after params, cache
+        and tok. Filters only when an ACTIVE row asked: the None variant
+        skips the per-step full-vocab sort (retired slots' stale values are
+        garbage rows whose tokens are discarded anyway)."""
+        # .copy() is load-bearing: jax zero-copy-aliases host numpy
+        # buffers (CPU backend) and transfers lazily, while the loop
+        # mutates the originals (retirement resets, next admissions)
+        # possibly BEFORE the in-flight chunk reads them — each dispatch
+        # gets private snapshots nobody mutates
+        args = [
+            jnp.asarray(self._offsets.copy()), jnp.asarray(self._steps.copy()),
+            jnp.asarray(self._temp.copy()),
+            jnp.asarray(self._top_k.copy()) if filtered else None,
+            jnp.asarray(self._top_p.copy()) if filtered else None,
+            jnp.asarray(self._seeds.copy()),
+        ]
+        if self.page_size > 0:
+            args.insert(0, jnp.asarray(self._table.copy()))
+        return args
+
+    def _run_chunk(self, params, cache, tok, *args, n_steps):
+        """What ``self._chunk`` is bound to: the variant a load fetched
+        ahead (``chunk_warmer``) where there is one, else the jit. A
+        dispatch that comes while the side thread still holds its variant
+        waits for that one future — the same program is never compiled
+        twice."""
+        key = (n_steps, args[-2] is not None)
+        warm = self._chunk_aot.get(key)
+        if warm is not None:
+            compiled = warm.result()
+            if compiled is not None:
+                try:
+                    return compiled(params, cache, tok, *args)
+                except (TypeError, ValueError) as e:
+                    # the loader delivered arrays the abstract params did
+                    # not describe: raised before anything ran or was donated
+                    logging.getLogger("modelx.serve").warning(
+                        "warmed chunk program refused its arguments, "
+                        "compiling at first use: %s", e)
+                    del self._chunk_aot[key]
+        return self._chunk_jit(params, cache, tok, *args, n_steps=n_steps)
+
+    def chunk_warmer(self, param_sds: dict):
+        """Reserve the chunk program every first request runs — one chunk
+        deep, no filters: its shapes are ``max_slots``, ``max_len`` and
+        ``chunk_size``, nothing a request brings — and return the work that
+        fetches it, for a side thread of the load: trace, lower and compile
+        (on a node that kept its compile cache, read back) from the abstract
+        weights and the abstract state of this engine — which need not be
+        allocated yet — described as a first dispatch meets it, so the
+        lowered module, and with it the persistent cache's key, is the one
+        that dispatch would produce. The work returns how many programs it
+        delivered."""
+        from concurrent.futures import Future
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        # a dispatch meets the engine's state as the admit program returned
+        # it — committed to the mesh — not as jnp.zeros left it
+        def as_dispatched(x, sharding=None):
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype,
+                sharding=sharding or NamedSharding(self.mesh, PartitionSpec()))
+
+        cache, tok = jax.eval_shape(self._new_device_state)
+        cache = jax.tree_util.tree_map(
+            lambda x: as_dispatched(x, self._cache_sharding(x.shape)), cache)
+        tok = as_dispatched(tok)
+        n_steps = self.chunk_size
+        fut = self._chunk_aot[(n_steps, False)] = Future()
+        args = (param_sds, cache, tok, *self._chunk_args(False))
+
+        def fetch() -> int:
+            try:
+                fut.set_result(
+                    self._chunk_jit.lower(*args, n_steps=n_steps).compile())
+                return 1
+            except Exception as e:  # only the warm start is lost
+                logging.getLogger("modelx.serve").warning(
+                    "chunk warm-up failed (cold first request): %s", e)
+                return 0
+            finally:
+                if not fut.done():  # a waiting dispatch must never hang
+                    fut.set_result(None)
+
+        return fetch
 
     def _chunk_paged_impl(self, params, pool, tok, table, offsets, steps,
                           temp, top_k, top_p, seeds, n_steps=None):
@@ -2051,30 +2153,13 @@ class ContinuousBatcher:
         ``_deliver`` finds the bytes already on their way."""
         depth = self._pick_depth()
         n_steps = depth * self.chunk_size
-        # filters only when an ACTIVE row asked: the None variant skips the
-        # per-step full-vocab sort (retired slots' stale values are garbage
-        # rows whose tokens are discarded anyway)
         active = list(self._rows)
         filtered = bool(self._use_filters[active].any())
         self._rec("dispatch", depth=depth, n_steps=n_steps,
                   active=len(self._rows), devices=self.mesh_devices)
-        # .copy() is load-bearing: jax zero-copy-aliases host numpy
-        # buffers (CPU backend) and transfers lazily, while this loop
-        # mutates the originals (retirement resets, next admissions)
-        # possibly BEFORE the in-flight chunk reads them — each dispatch
-        # gets private snapshots nobody mutates
-        args = [
-            jnp.asarray(self._offsets.copy()), jnp.asarray(self._steps.copy()),
-            jnp.asarray(self._temp.copy()),
-            jnp.asarray(self._top_k.copy()) if filtered else None,
-            jnp.asarray(self._top_p.copy()) if filtered else None,
-            jnp.asarray(self._seeds.copy()),
-        ]
-        if self.page_size > 0:
-            args.insert(0, jnp.asarray(self._table.copy()))
         self._cache, self._tok, toks_dev = self._chunk(
-            self.server.params, self._cache, self._tok, *args,
-            n_steps=n_steps,
+            self.server.params, self._cache, self._tok,
+            *self._chunk_args(filtered), n_steps=n_steps,
         )
         # start the device->host token copy NOW: it streams back while the
         # device runs the next program, so the lagged _deliver sync finds
@@ -2453,17 +2538,8 @@ class ContinuousBatcher:
                 (self.max_slots, self._pages_per_slot), np.int32
             )
             self._row_pages = {}
-            self._cache = jax.tree_util.tree_map(
-                lambda leaf: jnp.zeros(
-                    (self.num_pages, self.page_size) + leaf.shape[2:], leaf.dtype
-                ),
-                self._init_cache(1, self.page_size),
-            )
             self.stats["pages_free"] = len(self._free_pages)
-        else:
-            self._cache = self._init_cache(self.max_slots, self.max_len)
-        self._cache = self._place_cache(self._cache)
-        self._tok = jnp.zeros((self.max_slots, 1), jnp.int32)
+        self.allocate_device_state()
         self._offsets[:] = 0
         self._steps[:] = 0
         self._temp[:] = 0.0
@@ -3150,7 +3226,8 @@ class ContinuousBatcher:
             raise RuntimeError("release_device_state requires close() first")
         self._cache = None
         self._tok = None
+        self._chunk_aot.clear()
         for attr in ("_admit_prog", "_admit_cached_prog", "_admit_many_prog",
-                     "_chunk", "_piece_prog", "_piece_flip_prog",
+                     "_chunk", "_chunk_jit", "_piece_prog", "_piece_flip_prog",
                      "_seed_prog", "_snap_prog", "_spec_prog"):
             setattr(self, attr, None)

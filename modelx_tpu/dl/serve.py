@@ -380,15 +380,29 @@ class ModelServer:
 
     # the shape the dynamic batcher pads a lone first request to (seq to a
     # multiple of 16, batch to a power of two): precompiling it during load
-    # means the first real request meets a ready executable
+    # means the first real request meets a ready executable — on a pod
+    # that answers with the forward program (direct and --dynamic-batch
+    # paths, encoders). A pod that serves through the continuous engine
+    # never calls it and warms the engine's chunk program instead (load)
     WARMUP_TOKEN_SHAPES = ((1, 16),)
 
-    def load(self) -> dict:
+    def load(self, engine_at_load=None) -> dict:
         """Load every *.safetensors under model_dir onto the mesh. The
-        checkpoint headers fully determine the architecture, so the prefill
-        program for the warmup shapes AOT-compiles on a side thread WHILE
-        the weight bytes stream — a deploy pays max(load, compile), not
-        their sum (TTFT budget, BASELINE.md)."""
+        checkpoint headers fully determine the architecture, so the
+        programs this pod's first request will run are fetched on a side
+        thread WHILE the weight bytes stream — a deploy pays max(load,
+        compile), not their sum (TTFT budget, BASELINE.md).
+
+        Which programs: ``engine_at_load(server, allocate)``
+        (``ServerSet.engine_at_load`` at boot) returns the continuous engine
+        when the pod will answer through one. It is built here, as soon as
+        the headers are read, the side thread fetches its
+        request-independent chunk program
+        (``ContinuousBatcher.chunk_warmer``), and its KV cache is allocated
+        once the weights are placed. Where it returns None or is not given
+        (no --continuous-batch, an encoder family, a model loaded at run
+        time) the side thread AOT-compiles the forward for
+        WARMUP_TOKEN_SHAPES."""
         from modelx_tpu.dl.loader import LocalFileSource, load_safetensors
         from modelx_tpu.dl.safetensors import read_header_from_file
 
@@ -450,8 +464,11 @@ class ModelServer:
             # kept for the program store: surface keys (publish) and score
             # program AOT routing both need the abstract params later
             self._param_sds = sds
+            engine = engine_at_load(self, False) if engine_at_load else None
             compile_thread = threading.Thread(
-                target=self._precompile_warmup, args=(sds,), daemon=True
+                target=self._warm_programs,
+                args=(sds, None if engine is None else engine.chunk_warmer(sds)),
+                daemon=True,
             )
             compile_thread.start()
             params: dict = {}
@@ -476,6 +493,11 @@ class ModelServer:
                     put_s += stats.device_put_seconds
                     overlap_s += stats.overlap_seconds
             self.params = params
+            if engine is not None:
+                # behind the weights, where a lazily built engine always had
+                # it: allocated before them, the KV cache moved the decode
+                # cell's tokens/s by -0.6 and -2.1 % (PERF.md, PR 26)
+                engine_at_load(self, True)
             if self.lora_dir:
                 from modelx_tpu.dl import lora
 
@@ -507,9 +529,14 @@ class ModelServer:
             # the latter; this says it where a dashboard looks)
             self.stats["native_io"] = native.available()
             self._compile()
-            if compile_thread is not None:
+            if engine is None:
                 with trace.span("compile_join"):
                     compile_thread.join()
+            # else: the chunk program finishes behind the first admit — a
+            # dispatch waits for it (ContinuousBatcher._run_chunk) — because
+            # beside the load its read-back outlasts the load by seconds that
+            # ready would otherwise wait for, and the admit program's own
+            # read-back covers them (PERF.md, PR 26)
             self.stats["ready_seconds"] = round(time.monotonic() - t0, 3)
             self.ready = True
             self._install_kv_bundles()
@@ -581,6 +608,20 @@ class ModelServer:
             self.ready = True
             self._install_kv_bundles()
         return dict(self.stats)
+
+    def _warm_programs(self, sds: dict, engine_warm=None) -> None:
+        """A load's side thread: ``engine_warm`` (the engine's chunk
+        warmer) where the pod serves through the continuous engine, else
+        the forward for the warmup token shapes. ``startup`` keeps how
+        many engine programs were delivered — 0 says the forward path."""
+        programs = 0
+        if engine_warm is None:
+            self._precompile_warmup(sds)
+        else:
+            with trace.span("serve.load/engine_warm", model=self.name) as rec:
+                programs = rec["programs"] = engine_warm()
+            trace.startup.note("engine_warm", rec["duration_s"])
+        trace.startup.count("engine_warm_programs", programs)
 
     def _precompile_warmup(self, sds: dict) -> None:
         """AOT-compile the forward for the warmup token shapes (overlapped
@@ -1493,21 +1534,66 @@ class ServerSet:
         return b
 
     def continuous_for(self, server: ModelServer):
-        """Lazily create the continuous (in-flight) batching engine —
-        cached-decode causal families only. When enabled it supersedes both
-        the window batcher and speculation for generate/stream traffic:
-        iteration-level scheduling owns the device. Construction (a full
-        [max_slots, max_len] KV-cache allocation) runs under a PER-MODEL
-        lock so other tenants' traffic never stalls behind it."""
-        if (
-            not self._continuous_batch
-            or server.family is None
-            or server.family.decode_fns is None
-        ):
+        """The continuous (in-flight) batching engine — cached-decode
+        causal families only. When enabled it supersedes both the window
+        batcher and speculation for generate/stream traffic:
+        iteration-level scheduling owns the device. A boot-time model's
+        engine was built by its load (``engine_at_load``); a model added at
+        run time, one promoted from a tier, or one whose engine could not
+        be allocated at load gets it here, lazily, with the
+        demote-and-retry on RESOURCE_EXHAUSTED."""
+        if not self._serves_continuous(server):
             return None
         cb = self.cbatchers.get(server.name)
         if cb is not None:
             return cb
+        return self._build_engine(server, shed_on_oom=True)
+
+    def engine_at_load(self, server: ModelServer, allocate: bool):
+        """What ``ModelServer.load`` is given, and calls twice. After the
+        headers have given family and config (``allocate=False``): build
+        the engine a --continuous-batch pod will answer with, without its
+        device state, so the load can fetch its chunk program beside the
+        weight stream. Once the weights are placed (``allocate=True``):
+        allocate its KV cache, so /healthz follows an engine that exists.
+        None where the pod does not serve through one. A load never fails
+        for it: an engine that cannot be built or allocated now
+        (RESOURCE_EXHAUSTED in a multi-model set) is dropped and left to
+        ``continuous_for``."""
+        if not self._serves_continuous(server):
+            return None
+        try:
+            cb = self.cbatchers.get(server.name)
+            if cb is None:
+                cb = self._build_engine(server, shed_on_oom=False, allocate=False)
+            if allocate:
+                cb.allocate_device_state()
+            return cb
+        except Exception as e:
+            logger.warning("engine for %s not ready at load (left to the "
+                           "first request): %s", server.name, e)
+            self._drop_engine(server.name)
+            return None
+
+    def _drop_engine(self, name: str) -> None:
+        cb = self.cbatchers.pop(name, None)
+        if cb is not None:
+            cb.close()
+            cb.release_device_state()
+
+    def _serves_continuous(self, server: ModelServer) -> bool:
+        return bool(
+            self._continuous_batch
+            and server.family is not None
+            and server.family.decode_fns is not None
+        )
+
+    def _build_engine(self, server: ModelServer, shed_on_oom: bool,
+                      allocate: bool = True):
+        """Construct and register ``server``'s ContinuousBatcher.
+        Construction (a full [max_slots, max_len] KV-cache allocation) runs
+        under a PER-MODEL lock so other tenants' traffic never stalls
+        behind it."""
         with self._batcher_lock:
             lk = self._engine_locks.setdefault(server.name, threading.Lock())
         with lk:
@@ -1562,6 +1648,7 @@ class ServerSet:
                         flightrec_capacity=self.flightrec_capacity,
                         flight_dump_dir=self.flight_dump_dir,
                         device_telemetry=self.device_telemetry,
+                        allocate=allocate,
                     )
 
                 t_build = time.monotonic()
@@ -1576,7 +1663,8 @@ class ServerSet:
                     from modelx_tpu.dl import tiers as tiers_mod
                     from modelx_tpu.dl.serving_errors import EngineBrokenError
 
-                    if not tiers_mod.is_resource_exhausted(exc):
+                    if not (shed_on_oom
+                            and tiers_mod.is_resource_exhausted(exc)):
                         raise
                     freed = self.pool.shed_idle_for_bytes(
                         0, exclude=server.name)
@@ -1599,8 +1687,8 @@ class ServerSet:
                         raise EngineBrokenError(
                             f"KV allocation for {server.name} failed after "
                             "demoting idle state") from exc2
-                # built lazily, by the first request: outside the stages
-                # that tile process start -> ready, so noted beside them
+                # noted beside the stages that tile process start ->
+                # ready: inside `load` at boot, after ready when lazy
                 trace.startup.note("engine_init", time.monotonic() - t_build)
                 self.cbatchers[server.name] = cb
         return cb
@@ -1730,10 +1818,11 @@ class ServerSet:
             if self.pool is not None:
                 self.pool.mark_loading(s.name)
             try:
-                s.load()
+                s.load(engine_at_load=self.engine_at_load)
             except catch as e:
                 s.load_error = str(e)
                 errs[s.name] = e
+                self._drop_engine(s.name)  # built at load: return its KV cache
                 if self.pool is not None:
                     self.pool.mark_failed(s.name, str(e))
                 logger.error("loading %s failed (tenant marked FAILED, "

@@ -395,6 +395,13 @@ class Startup:
                 self._extra[f"{name}_s"] = self._extra.get(f"{name}_s", 0.0) + seconds
                 self._extra.setdefault(f"{name}_at_s", time.monotonic() - self.t0)
 
+    def count(self, name: str, n: int) -> None:
+        """``n`` more of ``name`` (a count beside the stages, under its own
+        name)."""
+        with self._lock:
+            if self.t0 is not None:
+                self._extra[name] = self._extra.get(name, 0) + n
+
     def snapshot(self) -> dict:
         """``{<stage>_s..., ready_s, <noted>_s..., <instant>_at_s...,
         source}``; empty in a process that never called ``begin``."""
