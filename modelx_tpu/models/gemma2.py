@@ -174,7 +174,8 @@ def _attend(q, k, v, cfg: Gemma2Config, q_offset, window: int,
                  and mesh.shape["sp"] > 1)
     flash = prefill and not sp_active and jax.default_backend() == "tpu"
     attn_ops.note_choice("flash" if flash else "reference",
-                         qt.shape[2], kt.shape[2], mesh)
+                         qt.shape[2], kt.shape[2], mesh,
+                         group=qt.shape[1] // kt.shape[1])
     if flash:
         out = attn_ops.flash_attention(qt, kt, vt, causal=True, mesh=mesh, **kwargs)
     else:

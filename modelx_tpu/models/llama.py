@@ -367,7 +367,8 @@ def _attend(q, k, v, cfg: LlamaConfig, causal: bool, q_offset, mesh, impl: str):
             impl = "flash"
         else:
             impl = "reference"
-    attn_ops.note_choice(impl, qt.shape[2], kt.shape[2], mesh)
+    attn_ops.note_choice(impl, qt.shape[2], kt.shape[2], mesh,
+                         group=qt.shape[1] // kt.shape[1])
     interpret = flag == "interpret"
     if impl == "ring":
         out = attn_ops.ring_attention(qt, kt, vt, mesh, axis="sp", causal=causal)

@@ -64,25 +64,32 @@ def attention_reference(q, k, v, causal: bool = True, q_offset=0,
     cap * tanh(logits / cap) BEFORE masking (the gemma2 convention).
     ``window`` > 0 limits each query to its last ``window`` keys (sliding
     window attention; needs ``causal``)."""
-    q, k, v = _repeat_kv_heads(q, k, v)
+    b, hq, qlen, d = q.shape
+    qk, pv = "bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd"
+    if k.shape[1] != hq:
+        # GQA: fold the query heads over their KV head (head h reads KV head
+        # h // G, what a repeat along axis 1 means) and contract against k/v
+        # as they lie — unfolding a decode cache G-fold, per layer and step,
+        # moved more bytes than the weights did
+        q = q.reshape(b, k.shape[1], hq // k.shape[1], qlen, d)
+        qk, pv = "bhgqd,bhkd->bhgqk", "bhgqk,bhkd->bhgqd"
     if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
+        scale = 1.0 / math.sqrt(d)
+    logits = jnp.einsum(qk, q, k, preferred_element_type=jnp.float32) * scale
     if logit_softcap > 0.0:
         logits = logit_softcap * jnp.tanh(logits / logit_softcap)
     if causal:
-        qlen, klen = q.shape[2], k.shape[2]
         off = jnp.asarray(q_offset)
         qpos = jnp.arange(qlen)[:, None] + (
-            off[:, None, None, None] if off.ndim else off
-        )  # [Q,K] or [B,1,Q,K]
-        kpos = jnp.arange(klen)[None, :]
+            jax.lax.expand_dims(off, range(1, logits.ndim)) if off.ndim else off
+        )  # [Q,K] or [B,1,(1,)Q,K]
+        kpos = jnp.arange(k.shape[2])[None, :]
         visible = kpos <= qpos
         if window > 0:  # keys qpos-window < kpos <= qpos stay visible
             visible = visible & (kpos > qpos - window)
         logits = jnp.where(visible, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
+    return jnp.einsum(pv, probs.astype(v.dtype), v).reshape(b, hq, qlen, d)
 
 
 def _repeat_kv_heads(q, k, v):
@@ -254,13 +261,18 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = FLASH_BLOCK,
                      out_specs=spec, check_vma=False)(q, k, v)
 
 
-def note_choice(impl: str, sq: int, sk: int, mesh: Mesh | None = None) -> None:
+def note_choice(impl: str, sq: int, sk: int, mesh: Mesh | None = None,
+                group: int = 1) -> None:
     """Record — at TRACE time, once per attention call site — which
     implementation a forward compiled with. The record is a zero-length span
     whose name carries the decision (``attention.flash[144x144]+pad[256x256]``),
     so ``/v1/trace`` (and ``MODELX_TRACE=1`` logs) show what ``impl="auto"``
-    chose for each length without a new surface."""
+    chose for each length without a new surface. ``group`` is query heads per
+    KV head: the reference contracts them grouped (``+gqa4``), every other
+    implementation repeats the KV heads."""
     name = f"attention.{impl}[{sq}x{sk}]"
+    if impl == "reference" and group > 1:
+        name += f"+gqa{group}"
     if impl == "flash":
         pq, pk = flash_blocks(sq)[1], flash_blocks(sk)[1]
         if (pq, pk) != (sq, sk):

@@ -40,8 +40,9 @@ def test_cpu_rehearsal_runs_every_phase_then_fails_the_device_gate(tmp_path):
     assert by["serve"]["platform"] == "cpu" and by["serve"]["native_engine"] is True
     assert by["serve"]["weights_bytes_on_device"] == by["checkpoint"]["bytes"]
     assert by["generate"]["engine"]["active_peak"] >= 2
-    # off the TPU impl="auto" is the reference — and says so
-    assert by["forward"]["attention"]["512"] == "reference[512x512]"
+    # off the TPU impl="auto" is the reference — and says so, with the group
+    # it contracts (the smoke model has 2 query heads per KV head)
+    assert by["forward"]["attention"]["512"] == "reference[512x512]+gqa2"
     assert by["restart"]["second_start"]["hits"] > 0
     assert by["restart"]["cache_dir"] == jax.config.jax_compilation_cache_dir
     assert not os.path.exists(tmp_path / "work")  # cleaned up, nothing left running

@@ -138,11 +138,15 @@ class TestAttentionOps:
         attn.note_choice("flash", 512, 512)
         attn.note_choice("flash", 144, 144, mesh)
         attn.note_choice("reference", 16, 16)
+        attn.note_choice("reference", 1, 2048, group=4)  # cached GQA decode
+        attn.note_choice("flash", 256, 256, group=4)  # the kernel repeats heads
         got = {p: n - before.get(p, 0) for p, n in count().items()}
         assert {p for p, n in got.items() if n} == {
             "attention.flash[512x512]",
             "attention.flash[144x144]+pad[256x256]+shard_map",
-            "attention.reference[16x16]"}
+            "attention.reference[16x16]",
+            "attention.reference[1x2048]+gqa4",
+            "attention.flash[256x256]"}
 
     def test_ring_matches_reference(self):
         mesh = make_mesh("sp=8")
