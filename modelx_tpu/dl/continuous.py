@@ -13,12 +13,15 @@ scheduling (the vLLM/Orca idea), built the TPU way:
   requests; per-slot prompt lengths, decode depths, and sampling controls
   are traced VECTOR inputs, never shapes. Prefills compile per 16-bucketed
   prompt length, exactly like the stream/batcher paths.
-- **Paged KV (``page_size`` > 0)**: the per-layer state becomes a POOL of
-  fixed-size pages plus a host-managed block table, so HBM scales with
-  LIVE tokens instead of ``max_slots x max_len`` — slot count can grow
-  (32+) without a quadratic HBM bill, admissions reserve their span's
-  pages up front (waiting FIFO when the pool is full), retirements recycle
-  them. Still one compiled chunk program: the table is a traced input.
+- **Where a slot's KV lives is not the engine's business**
+  (dl/kv_layout.py): every program below is written once against a layout
+  object, and the engine only reserves and releases through it. Dense, a
+  reservation always succeeds. Paged (``page_size`` > 0), the per-layer
+  state is a POOL of fixed-size pages plus a host-managed block table, so
+  HBM scales with LIVE tokens instead of ``max_slots x max_len`` — slot
+  count can grow (32+) without a quadratic HBM bill, admissions reserve
+  their span's pages up front (waiting FIFO when the pool is full),
+  retirements recycle them.
 - **Admission = prefill into a fresh [1, S] cache + one
   dynamic_update_slice of that cache into the slot's rows.** The running
   batch never re-prefills, and the prefill cost is one [S]-length row copy
@@ -96,6 +99,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from modelx_tpu.dl import kv_layout
 from modelx_tpu.dl.serving_errors import (
     DeadlineExceededError,
     EngineBrokenError,
@@ -324,54 +328,46 @@ class ContinuousBatcher:
         self._fwd, self._init_cache = server.family.decode_fns(
             server.cfg, mesh=server.mesh
         )
-        # paged chunk attention: "gather" (default) rebuilds a dense view
-        # per step — bit-identical logits to every other decode path, so
-        # the engine's cross-engine token-exactness guarantee holds
-        # unconditionally; "in-place" reads the page pools directly
-        # (ops/paged_attention.py, per-step transient = one page block —
-        # the long-context/HBM-bound deployment shape) at the cost of
-        # blockwise-softmax numerics: greedy matches in practice, sampled
-        # rows can flip at bf16 near-boundaries (measured on v5e). The
-        # operator picks the trade (--kv-attention).
-        if paged_attention not in ("gather", "in-place"):
-            raise ValueError(f"unknown paged_attention mode {paged_attention!r}")
-        self._fwd_paged = (
-            server.family.paged_decode_fns(server.cfg, mesh=server.mesh)
-            if (
-                page_size > 0
-                and paged_attention == "in-place"
-                and server.family.paged_decode_fns is not None
-            )
-            else None
-        )
-        if (
-            page_size > 0
-            and paged_attention == "in-place"
-            and self._fwd_paged is None
-        ):
-            # an operator asking for in-place did so for the HBM budget;
-            # a silent fallback would surface only as an OOM later
-            logging.getLogger("modelx.serve").warning(
-                "--kv-attention in-place: family %s has no paged decode; "
-                "falling back to the dense-gather chunk (higher per-step "
-                "transient HBM)", server.family.name,
-            )
-        # -- paged KV (page_size > 0): HBM scales with LIVE tokens ----------
-        # The dense engine state is [max_slots, max_len] per layer whether a
-        # slot is used or not, so slot count multiplies straight into HBM.
-        # Paged mode replaces it with a POOL of fixed-size pages
-        # ([num_pages, page_size, ...] per layer) plus a host-managed block
-        # table [max_slots, max_len/page_size]: each admission reserves
-        # exactly the pages its prompt+budget span needs and returns them at
-        # retirement, so 32 slots cost the pool's token budget, not
-        # 32 x max_len. Page 0 is a TRASH page no slot owns: idle table
-        # entries point there, so idle rows' writes land harmlessly and
-        # their reads sit beyond the causal horizon (the dense engine's
-        # idle-row trick, relocated). One chunk program serves every mix of
-        # lengths — the table is a traced input, never a shape.
-        self.page_size = int(page_size)
+        self.mesh = server.mesh
+        self.mesh_devices = int(self.mesh.size)
+        self.stats = {"chunks": 0, "admitted": 0, "active_peak": 0,
+                      "prefill_pieces": 0, "stall_ms_max": 0.0,
+                      "engine_restarts": 0, "shed": 0, "expired": 0,
+                      # admissions decoded from registry-installed prefix
+                      # KV (dl/kv_store.py) rather than local prefill
+                      "prefix_hits_installed": 0,
+                      # pipelined dispatch: device programs launched
+                      # ("chunks" stays chunk-EQUIVALENTS — a depth-D
+                      # program counts D), the deepest program used, the
+                      # worst steady-decode boundary's blocking sync count
+                      # (must stay <= 1: the one lagged token readback),
+                      # and the high-water planned-but-undelivered tokens
+                      "dispatches": 0, "dispatch_depth_max": 1,
+                      "host_syncs_per_boundary": 0,
+                      "tokens_in_flight_peak": 0, "sync_lag_chunks_max": 0,
+                      # pad accounting (ISSUE 17): every dispatched decode
+                      # program computes max_slots rows regardless of how
+                      # many are live — decode_pad_rows / decode_rows is
+                      # the row-padding tax snapshot() exposes as
+                      # pad_fraction (admit_pad_rows covers the admit-side
+                      # pow2 burst rounding separately)
+                      "decode_rows": 0, "decode_pad_rows": 0}
+        # where the slots' KV lives (dl/kv_layout.py): [max_slots, max_len]
+        # rows, or — page_size > 0 — a pool of pages sized by
+        # max_live_tokens and read by gather or in place (paged_attention).
+        # Nothing below asks which it got.
+        self.kv = kv_layout.build(
+            server, self._fwd, self._init_cache, self.stats,
+            max_slots=self.max_slots, max_len=self.max_len,
+            chunk_size=self.chunk_size, page_size=int(page_size),
+            max_live_tokens=max_live_tokens, paged_attention=paged_attention)
+        # allocate=False leaves the device alone: an engine built by a load
+        # while the weights still stream gets its arrays from
+        # ``allocate_device_state`` once they are placed
+        self._cache = self._tok = None
         try:
-            self._alloc_device_state(max_live_tokens, allocate)
+            if allocate:
+                self.allocate_device_state()
         except BaseException:
             # a RESOURCE_EXHAUSTED here may leave SOME per-layer pools
             # already allocated: drop the partial tree before re-raising
@@ -425,41 +421,34 @@ class ContinuousBatcher:
         # every call costs a host dispatch round-trip, so the two-call
         # prefill-then-insert shape would double admission latency.
         # Without a prefix cache the scratch KV stays internal (no output
-        # buffer materialized just to be dropped on the host). Dense and
-        # paged wire identically — only the impls (and the cached variant's
-        # extra page_ids arg before its static trim_len) differ.
-        paged = self.page_size > 0
-        admit_impl = self._admit_paged_impl if paged else self._admit_impl
+        # buffer materialized just to be dropped on the host).
         if prefix_cache is None:
             def _admit_nosmall(*args):
-                return admit_impl(*args)[:3]  # drop the scratch KV output
+                return self._admit_impl(*args)[:3]  # drop the scratch KV output
 
             self._admit_prog = jax.jit(_admit_nosmall, donate_argnums=(2, 3))
         else:
-            self._admit_prog = jax.jit(admit_impl, donate_argnums=(2, 3))
+            self._admit_prog = jax.jit(self._admit_impl, donate_argnums=(2, 3))
         # prefix-hit variant: stored KV rides in as an argument (never
         # donated — the cache entry outlives the admission); trim_len is
         # static so stored entries stay bucketed to the PROMPT's bucket
         # (entries must not grow by a bucket per conversation turn)
         self._admit_cached_prog = jax.jit(
-            self._admit_cached_paged_impl if paged else self._admit_cached_impl,
-            static_argnums=(13 if paged else 12,), donate_argnums=(2, 3),
+            self._admit_cached_impl, static_argnums=(12,), donate_argnums=(2, 3),
         )
         # batched admission (same-bucket burst arrivals -> one program);
         # engaged only without a prefix cache — the cached path's per-row
         # scratch-KV returns would cost k x leaves slice dispatches, and
         # multi-turn conversations rarely arrive as same-instant bursts
         self._admit_many_prog = jax.jit(
-            self._admit_many_paged_impl if paged else self._admit_many_impl,
-            donate_argnums=(2, 3),
+            self._admit_many_impl, donate_argnums=(2, 3),
         )
         # ONE chunk callable for every dispatch depth: n_steps is a STATIC
         # argument (jit caches one compiled variant per depth actually
         # used), so the fault-injection seam (tests/bench wrap self._chunk)
         # and the env-gated chaos wrap below cover deep programs too
         self._chunk_jit = jax.jit(
-            self._chunk_paged_impl if paged else self._chunk_impl,
-            donate_argnums=(1, 2), static_argnames=("n_steps",),
+            self._chunk_impl, donate_argnums=(1, 2), static_argnames=("n_steps",),
         )
         # variants of that jit fetched AHEAD of their first dispatch
         # (chunk_warmer: a load reads them back while the weights stream):
@@ -471,28 +460,17 @@ class ContinuousBatcher:
         # slot's KV (no logits output -> XLA drops the lm_head matmul);
         # the flip (last) piece also samples the row's first token.
         # Compiled once per piece bucket, like every other prompt shape.
-        self._piece_prog = jax.jit(
-            self._piece_paged_impl if paged else self._piece_impl,
-            donate_argnums=(2,),
-        )
+        self._piece_prog = jax.jit(self._piece_impl, donate_argnums=(2,))
         self._piece_flip_prog = jax.jit(
-            self._piece_flip_paged_impl if paged else self._piece_flip_impl,
-            donate_argnums=(2, 3),
+            self._piece_flip_impl, donate_argnums=(2, 3),
         )
-        # prefix-hit fill seeding: copy a stored prefix KV into the slot's
-        # rows/pages so only the suffix chunk-prefills (stored entry never
-        # donated — it outlives the admission)
-        self._seed_prog = jax.jit(
-            self._seed_paged_impl if paged else self._seed_impl,
-            static_argnums=(3,) if paged else (),
-            donate_argnums=(0,),
-        )
+        # prefix-hit fill seeding: copy a stored prefix KV into the slot
+        # so only the suffix chunk-prefills (stored entry never donated —
+        # it outlives the admission)
+        self._seed_prog = jax.jit(self._seed_impl, donate_argnums=(0,))
         # flip-time prefix store: slice the freshly filled prompt KV back
         # out of the slot (a copy — the live row decodes on)
-        self._snap_prog = jax.jit(
-            self._snap_paged_impl if paged else self._snap_impl,
-            static_argnums=(2,),
-        )
+        self._snap_prog = jax.jit(self._snap_impl, static_argnums=(2,))
         # chunks the loop keeps in flight before syncing the oldest: plans
         # are value-independent (budgets only), so depth-D dispatch is
         # exact; it hides the per-chunk fetch round-trip behind device
@@ -516,10 +494,7 @@ class ContinuousBatcher:
         # engine, wait this long for co-arrivals before admitting (burst ->
         # one admit program + aligned decode depths). 0 disables.
         self.burst_window_ms = float(burst_window_ms)
-        self._spec_prog = jax.jit(
-            self._spec_verify_paged_impl if paged else self._spec_verify_impl,
-            donate_argnums=(1,),
-        )
+        self._spec_prog = jax.jit(self._spec_verify_impl, donate_argnums=(1,))
 
         self._q: "queue.Queue" = queue.Queue()
         # FIFO admission backlog: items popped from the queue while no slot
@@ -593,28 +568,6 @@ class ContinuousBatcher:
         self.device_telemetry = bool(device_telemetry)
         # windowed token rate (tokens/s over 1m/5m) fed at delivery time
         self.rate_tokens = tswheel.Wheel()
-        self.stats = {"chunks": 0, "admitted": 0, "active_peak": 0,
-                      "prefill_pieces": 0, "stall_ms_max": 0.0,
-                      "engine_restarts": 0, "shed": 0, "expired": 0,
-                      # admissions decoded from registry-installed prefix
-                      # KV (dl/kv_store.py) rather than local prefill
-                      "prefix_hits_installed": 0,
-                      # pipelined dispatch: device programs launched
-                      # ("chunks" stays chunk-EQUIVALENTS — a depth-D
-                      # program counts D), the deepest program used, the
-                      # worst steady-decode boundary's blocking sync count
-                      # (must stay <= 1: the one lagged token readback),
-                      # and the high-water planned-but-undelivered tokens
-                      "dispatches": 0, "dispatch_depth_max": 1,
-                      "host_syncs_per_boundary": 0,
-                      "tokens_in_flight_peak": 0, "sync_lag_chunks_max": 0,
-                      # pad accounting (ISSUE 17): every dispatched decode
-                      # program computes max_slots rows regardless of how
-                      # many are live — decode_pad_rows / decode_rows is
-                      # the row-padding tax snapshot() exposes as
-                      # pad_fraction (admit_pad_rows covers the admit-side
-                      # pow2 burst rounding separately)
-                      "decode_rows": 0, "decode_pad_rows": 0}
         # per-request latency histograms (ISSUE 13): fed at first-token
         # delivery from the ticket's phase stamps; snapshot() exposes them
         # once populated and the Prometheus exposition renders them as
@@ -630,13 +583,6 @@ class ContinuousBatcher:
             self.stats["prefill_chunk"] = self.prefill_chunk
             self.stats["fill_waits"] = 0  # page-blocked boundaries
             self.stats["fill_preempts"] = 0  # fills restarted for pages
-        if self.page_size > 0:
-            self.stats["page_size"] = self.page_size
-            self.stats["pages_total"] = self.num_pages - 1  # excl. trash
-            self.stats["pages_free"] = len(self._free_pages)
-            self.stats["paged_attention"] = (
-                "in-place" if self._fwd_paged is not None else "gather"
-            )
         if self.boundary_watchdog_s > 0:
             self.stats["watchdog_stalls"] = 0
         self._phases = trace.Phases("continuous.boundary", _PHASES)
@@ -658,85 +604,12 @@ class ContinuousBatcher:
     # chunk_size pieces) and the stop-detection lag stay bounded
     AUTO_DISPATCH_DEPTH = 4
 
-    def _alloc_device_state(self, max_live_tokens: int, allocate: bool) -> None:
-        """The engine's big device allocations — the KV page pool (or
-        dense cache), its mesh placement, and the sampled-token buffer —
-        split out of ``__init__`` so a mid-allocation RESOURCE_EXHAUSTED
-        has one cleanup point there (partial per-layer pools are dropped
-        before the error propagates to the demote-and-retry path).
-        ``allocate=False`` sizes the state and leaves the device alone:
-        an engine built by a load while the weights still stream gets its
-        arrays from ``allocate_device_state`` once they are placed."""
-        if self.page_size > 0:
-            if self.max_len % self.page_size:
-                raise ValueError(
-                    f"max_len {self.max_len} must be a multiple of "
-                    f"page_size {self.page_size}"
-                )
-            budget = int(max_live_tokens) or max(
-                self.max_len + self.chunk_size + self.page_size,
-                self.max_slots * self.max_len // 4,
-            )
-            self.num_pages = 1 + -(-budget // self.page_size)  # +1: trash
-            self._pages_per_slot = self.max_len // self.page_size
-            self._free_pages = list(range(1, self.num_pages))
-            self._table = np.zeros(
-                (self.max_slots, self._pages_per_slot), np.int32
-            )
-            self._row_pages: dict[int, list[int]] = {}  # slot -> owned pages
-        else:
-            self.num_pages = 0
-        self.mesh = self.server.mesh
-        self.mesh_devices = int(self.mesh.size)
-        self._cache = self._tok = None
-        if allocate:
-            self.allocate_device_state()
-
     def allocate_device_state(self) -> None:
-        """Allocate the KV cache (or page pool) and the token buffer,
-        zeroed, on the serving mesh. The engine owns this state and donates
-        it through every program, so HBM holds exactly one copy."""
-        self._cache, self._tok = self._new_device_state()
-
-    def _new_device_state(self) -> tuple:
-        if self.page_size > 0:
-            cache = jax.tree_util.tree_map(
-                lambda leaf: jnp.zeros(
-                    (self.num_pages, self.page_size) + leaf.shape[2:], leaf.dtype
-                ),
-                self._init_cache(1, self.page_size),
-            )
-        else:
-            cache = self._init_cache(self.max_slots, self.max_len)
-        return (self._place_cache(cache),
-                jnp.zeros((self.max_slots, 1), jnp.int32))
-
-    def _cache_sharding(self, shape):
-        """Where one KV leaf lives on the serving mesh (None on a single
-        device — the dp=1 engine stays byte-identical to before). Dense
-        caches shard slots over dp and kv heads over tp; the paged pool
-        shards kv heads over tp only, because its leading dim is a global
-        page index no axis may split."""
-        if self.mesh_devices <= 1:
-            return None
-        from modelx_tpu.dl.sharding import cache_sharding
-
-        return cache_sharding(
-            self.mesh, shape, batch_dim=-1 if self.page_size > 0 else 0,
-            head_dim=len(shape) - 2,
-        )
-
-    def _place_cache(self, cache):
-        """Lay the engine's KV state out on the serving mesh with an
-        explicit GSPMD layout before the first program closes over it.
-        Every program the engine compiles inherits these input layouts, so
-        decode math runs tensor-parallel instead of congealing on device 0."""
-        if self.mesh_devices <= 1:
-            return cache
-        return jax.tree_util.tree_map(
-            lambda leaf: jax.device_put(leaf, self._cache_sharding(leaf.shape)),
-            cache,
-        )
+        """Allocate the KV state and the token buffer, zeroed, on the
+        serving mesh. The engine owns this state and donates it through
+        every program, so HBM holds exactly one copy."""
+        self._cache = self.kv.new_state()
+        self._tok = jnp.zeros((self.max_slots, 1), jnp.int32)
 
     # -- flight recorder ------------------------------------------------------
 
@@ -807,146 +680,52 @@ class ContinuousBatcher:
             top_k=top_k, top_p=top_p, seeds=seed, step=step,
         )
 
-    def _admit_many_impl(self, params, prompts, cache, tok, row_lens, slots,
+    def _admit_many_impl(self, params, prompts, cache, tok, row_lens, where,
                          temp, top_k, top_p, seeds, first_steps):
         """A burst of same-bucket admissions as ONE program: prefill the
-        [max_slots, Sb] block into a fresh scratch cache, sample every
-        row's first token (step 0 of its own seed stream — identical to k
-        single admits), and scatter the scratch rows into their slots.
-        Each program dispatch costs a host round-trip, so k arrivals
-        admitted one-by-one pay k round-trips where this pays one. The host pads the burst to the next POWER OF TWO of its
-        size (pad rows carry an out-of-bounds slot index whose scatter
-        ``mode="drop"`` discards), so small bursts don't pay a full
-        max_slots-row prefill and compiles stay bounded at
-        log2(max_slots) sizes per prompt bucket."""
+        [m, Sb] block into a fresh scratch cache, sample every row's first
+        token (step 0 of its own seed stream — identical to k single
+        admits), and write the scratch rows to their slots. Each program
+        dispatch costs a host round-trip, so k arrivals admitted one-by-one
+        pay k round-trips where this pays one. The host pads the burst to
+        the next POWER OF TWO of its size (pad rows carry an out-of-bounds
+        slot index, which the layout's write and the tok scatter's
+        ``mode="drop"`` discard), so small bursts don't pay a full
+        max_slots-row prefill and compiles stay bounded at log2(max_slots)
+        sizes per prompt bucket."""
         small = self._init_cache(prompts.shape[0], prompts.shape[1])
         logits, small = self._fwd(params, prompts, kv_cache=small, cache_offset=0)
         firsts = self._sample_first(logits, row_lens - 1, temp, top_k, top_p,
                                     seeds, step=first_steps)
-        cache = jax.tree_util.tree_map(
-            lambda big, lit: big.at[slots, : lit.shape[1]].set(lit, mode="drop"),
-            cache, small,
-        )
-        tok = tok.at[slots, 0].set(firsts, mode="drop")
+        cache = self.kv.put_many(cache, small, where)
+        tok = tok.at[self.kv.slot_of(where), 0].set(firsts, mode="drop")
         return cache, tok, firsts
 
-    def _admit_many_paged_impl(self, params, prompts, pool, tok, row_lens,
-                               slots, page_ids, temp, top_k, top_p, seeds,
-                               first_steps):
-        """Paged batched admission: same one-program shape, writing each
-        row's scratch rows into its reserved pages (``page_ids`` is
-        [max_slots, n_prompt_pages] — same bucket means the same page
-        count, so every page column scatters all rows at once). Pad rows'
-        page ids point at the trash page (their writes land harmlessly);
-        their tok scatter drops on the out-of-bounds slot index."""
-        sb = prompts.shape[1]
-        small = self._init_cache(prompts.shape[0], sb)
-        logits, small = self._fwd(params, prompts, kv_cache=small, cache_offset=0)
-        firsts = self._sample_first(logits, row_lens - 1, temp, top_k, top_p,
-                                    seeds, step=first_steps)
-        ps = self.page_size
-
-        def write(pool_leaf, small_leaf):
-            out = pool_leaf
-            for j in range(0, sb, ps):
-                w = min(j + ps, sb) - j
-                blk = jax.lax.slice_in_dim(small_leaf, j, j + w, axis=1)
-                out = out.at[page_ids[:, j // ps], :w].set(blk)
-            return out
-
-        pool = jax.tree_util.tree_map(write, pool, small)
-        tok = tok.at[slots, 0].set(firsts, mode="drop")
-        return pool, tok, firsts
-
-    def _finish_admit(self, small, logits, cache, tok, last_idx, slot,
+    def _finish_admit(self, small, logits, cache, tok, last_idx, where,
                       temp, top_k, top_p, seed, first_step):
-        """Shared admit tail: sample the row's first token and insert the
-        scratch cache + token into ``slot`` of the donated engine state.
-        Returns (cache, tok, first, small) — ``small`` goes back to the
-        host for the prefix cache."""
+        """Shared admit tail: sample the row's first token and write the
+        scratch cache + token to the slot ``where`` names in the donated
+        engine state. Returns (cache, tok, first, small) — ``small`` goes
+        back to the host for the prefix cache."""
         first = self._sample_first(logits, last_idx, temp, top_k, top_p, seed,
                                    step=first_step)
-
-        def put(big, little):
-            return jax.lax.dynamic_update_slice(
-                big, little, (slot,) + (0,) * (big.ndim - 1)
-            )
-
-        cache = jax.tree_util.tree_map(put, cache, small)
-        tok = jax.lax.dynamic_update_slice(tok, first[:, None], (slot, 0))
+        cache = self.kv.put(cache, small, where)
+        tok = jax.lax.dynamic_update_slice(
+            tok, first[:, None], (self.kv.slot_of(where), 0))
         return cache, tok, first, small
 
-    def _finish_admit_paged(self, small, logits, pool, tok, last_idx, slot,
-                            page_ids, temp, top_k, top_p, seed, first_step,
-                            span: int):
-        """Paged admit tail: sample the first token, then write the scratch
-        cache's first ``span`` rows into the slot's reserved pages. ``span``
-        is STATIC (the prompt bucket / trim length), so the write unrolls
-        to ceil(span/page_size) dynamic_update_slices — compiled once per
-        prompt bucket, exactly like the prefill itself."""
-        first = self._sample_first(logits, last_idx, temp, top_k, top_p, seed,
-                                   step=first_step)
-        tok = jax.lax.dynamic_update_slice(tok, first[:, None], (slot, 0))
-        ps = self.page_size
-
-        def write(pool_leaf, small_leaf):
-            out = pool_leaf
-            for j in range(0, span, ps):
-                # the final block may be a partial page (span need not be a
-                # page multiple): the page's tail stays junk past every
-                # query position until decode overwrites it
-                blk = jax.lax.slice_in_dim(small_leaf, j, min(j + ps, span), axis=1)
-                out = jax.lax.dynamic_update_slice(
-                    out, blk, (page_ids[j // ps],) + (0,) * (out.ndim - 1)
-                )
-            return out
-
-        pool = jax.tree_util.tree_map(write, pool, small)
-        return pool, tok, first, small
-
-    def _admit_paged_impl(self, params, prompt, pool, tok, row_len, slot,
-                          page_ids, temp, top_k, top_p, seed, first_step):
-        """Paged admission: prefill into a [1, Sb] scratch cache, then the
-        paged admit tail (pages instead of a slot-row insert)."""
-        small = self._init_cache(1, prompt.shape[1])
-        logits, small = self._fwd(params, prompt, kv_cache=small, cache_offset=0)
-        return self._finish_admit_paged(
-            small, logits, pool, tok, row_len - 1, slot, page_ids,
-            temp, top_k, top_p, seed, first_step, span=prompt.shape[1],
-        )
-
-    def _admit_cached_paged_impl(self, params, suffix, pool, tok, suffix_len,
-                                 plen, slot, stored, page_ids, temp, top_k,
-                                 top_p, seed, trim_len: int, first_step=0):
-        """Prefix-hit paged admission: stored KV + suffix prefill (the
-        dense cached-admit's semantics, see _admit_cached_impl), written
-        out page by page."""
-        sb = suffix.shape[1]
-        small = jax.tree_util.tree_map(
-            lambda s: jnp.concatenate(
-                [s, jnp.zeros((1, sb) + s.shape[2:], s.dtype)], axis=1
-            ),
-            stored,
-        )
-        logits, small = self._fwd(params, suffix, kv_cache=small, cache_offset=plen)
-        small = jax.tree_util.tree_map(lambda c: c[:, :trim_len], small)
-        return self._finish_admit_paged(
-            small, logits, pool, tok, suffix_len - 1, slot, page_ids,
-            temp, top_k, top_p, seed, first_step, span=trim_len,
-        )
-
-    def _admit_impl(self, params, prompt, cache, tok, row_len, slot,
+    def _admit_impl(self, params, prompt, cache, tok, row_len, where,
                     temp, top_k, top_p, seed, first_step):
         """One program per admission: prefill the [1, S] prompt into a
         scratch cache (allocated INSIDE the jit — zeros fuse, no host
         transfer), then the shared admit tail."""
         small = self._init_cache(1, prompt.shape[1])
         logits, small = self._fwd(params, prompt, kv_cache=small, cache_offset=0)
-        return self._finish_admit(small, logits, cache, tok, row_len - 1, slot,
+        return self._finish_admit(small, logits, cache, tok, row_len - 1, where,
                                   temp, top_k, top_p, seed, first_step)
 
     def _admit_cached_impl(self, params, suffix, cache, tok, suffix_len, plen,
-                           slot, stored, temp, top_k, top_p, seed,
+                           where, stored, temp, top_k, top_p, seed,
                            trim_len: int, first_step=0):
         """Prefix-hit admission: the scratch cache starts as the STORED
         prefix KV (extended with zeros for the suffix bucket) and only the
@@ -967,160 +746,61 @@ class ContinuousBatcher:
         )
         logits, small = self._fwd(params, suffix, kv_cache=small, cache_offset=plen)
         small = jax.tree_util.tree_map(lambda c: c[:, :trim_len], small)
-        return self._finish_admit(small, logits, cache, tok, suffix_len - 1, slot,
+        return self._finish_admit(small, logits, cache, tok, suffix_len - 1, where,
                                   temp, top_k, top_p, seed, first_step)
 
     # -- chunked prefill piece programs ---------------------------------------
 
-    def _gather_row(self, cache, slot):
-        """The slot's own [1, max_len] cache rows, sliced out of the
-        engine state — a mid-prompt piece needs the row's earlier KV as
-        attention context, unlike admission's fresh offset-0 scratch."""
-        return jax.tree_util.tree_map(
-            lambda big: jax.lax.dynamic_slice(
-                big, (slot,) + (0,) * (big.ndim - 1), (1,) + big.shape[1:]
-            ),
-            cache,
-        )
+    def _piece(self, params, piece, cache, filled, where):
+        """Land one [1, Sb] prefill piece: view the slot's own [1, max_len]
+        rows — a mid-prompt piece needs the row's earlier KV as attention
+        context, unlike admission's fresh offset-0 scratch — run the block
+        at offset ``filled`` (positions/causality follow the decode
+        contract, so the landed KV is byte-identical to the same span of a
+        monolithic prefill), write back what it wrote."""
+        row = self.kv.view(cache, where, self.max_len)
+        logits, row = self._fwd(params, piece, kv_cache=row, cache_offset=filled)
+        return logits, self.kv.put_piece(cache, row, where)
 
-    def _scatter_row(self, cache, row, slot):
-        return jax.tree_util.tree_map(
-            lambda big, little: jax.lax.dynamic_update_slice(
-                big, little, (slot,) + (0,) * (big.ndim - 1)
-            ),
-            cache, row,
-        )
+    def _piece_impl(self, params, piece, cache, filled, where):
+        """One mid-prompt piece. Logits are not an output — XLA drops the
+        lm_head matmul for mid pieces."""
+        return self._piece(params, piece, cache, filled, where)[1]
 
-    def _gather_pages(self, pool, table_row):
-        """One slot's pages as a dense [1, max_len] view (``table_row`` is
-        the slot's block-table row; unreserved entries point at trash)."""
-        return jax.tree_util.tree_map(
-            lambda p: p[table_row].reshape(1, self.max_len, *p.shape[2:]),
-            pool,
-        )
-
-    def _piece_impl(self, params, piece, cache, filled, slot):
-        """One mid-prompt prefill piece: gather the slot's row, run the
-        [1, Sb] block at offset ``filled`` (positions/causality follow the
-        decode contract, so the landed KV is byte-identical to the same
-        span of a monolithic prefill), write the row back. Logits are not
-        an output — XLA drops the lm_head matmul for mid pieces."""
-        row = self._gather_row(cache, slot)
-        _logits, row = self._fwd(params, piece, kv_cache=row, cache_offset=filled)
-        return self._scatter_row(cache, row, slot)
-
-    def _piece_flip_impl(self, params, piece, cache, tok, filled, slot,
+    def _piece_flip_impl(self, params, piece, cache, tok, filled, where,
                          last_idx, temp, top_k, top_p, seed, first_step):
         """The LAST piece: land its KV and sample the row's first token
         from the piece's final real position — step ``first_step`` of the
         row's (seed, step) stream (0 fresh, k on resume), byte-identical
         to single-program admission."""
-        row = self._gather_row(cache, slot)
-        logits, row = self._fwd(params, piece, kv_cache=row, cache_offset=filled)
-        cache = self._scatter_row(cache, row, slot)
+        logits, cache = self._piece(params, piece, cache, filled, where)
         first = self._sample_first(logits, last_idx, temp, top_k, top_p, seed,
                                    step=first_step)
-        tok = jax.lax.dynamic_update_slice(tok, first[:, None], (slot, 0))
+        tok = jax.lax.dynamic_update_slice(
+            tok, first[:, None], (self.kv.slot_of(where), 0))
         return cache, tok, first
 
-    def _scatter_piece_pages(self, pool, dense, write_page_ids, page_start):
-        """Write back ONLY the pages a piece touched: the forward modifies
-        [filled, filled + Sb), i.e. at most Sb/page_size + 1 pages —
-        scattering the slot's whole max_len span per piece would pay
-        ~max_len/Sb x the useful copy traffic on exactly the long-context
-        shapes chunked prefill targets. ``write_page_ids`` is the touched
-        table entries (STATIC count — compiles per piece bucket x two
-        alignments), ``page_start`` the first touched page's token offset."""
-        ps = self.page_size
-        n_touch = write_page_ids.shape[0]
-
-        def put_back(p, d):
-            out = p
-            for j in range(n_touch):
-                blk = jax.lax.dynamic_slice_in_dim(
-                    d, page_start + j * ps, ps, axis=1
-                )
-                out = jax.lax.dynamic_update_slice(
-                    out, blk, (write_page_ids[j],) + (0,) * (out.ndim - 1)
-                )
-            return out
-
-        return jax.tree_util.tree_map(put_back, pool, dense)
-
-    def _piece_paged_impl(self, params, piece, pool, table_row, filled,
-                          write_page_ids, page_start):
-        dense = self._gather_pages(pool, table_row)
-        _logits, dense = self._fwd(params, piece, kv_cache=dense, cache_offset=filled)
-        return self._scatter_piece_pages(pool, dense, write_page_ids, page_start)
-
-    def _piece_flip_paged_impl(self, params, piece, pool, tok, table_row,
-                               filled, slot, last_idx, temp, top_k, top_p,
-                               seed, write_page_ids, page_start, first_step):
-        dense = self._gather_pages(pool, table_row)
-        logits, dense = self._fwd(params, piece, kv_cache=dense, cache_offset=filled)
-        pool = self._scatter_piece_pages(pool, dense, write_page_ids, page_start)
-        first = self._sample_first(logits, last_idx, temp, top_k, top_p, seed,
-                                   step=first_step)
-        tok = jax.lax.dynamic_update_slice(tok, first[:, None], (slot, 0))
-        return pool, tok, first
-
-    def _seed_impl(self, cache, stored, slot):
+    def _seed_impl(self, cache, stored, where):
         """Prefix-hit fill seeding: the stored [1, plen-bucket] prefix KV
         lands at the slot's offset 0. Bucket junk past the real prefix is
         overwritten by the first suffix piece (each layer writes its k/v
         before attending, and piece >= 16 > bucket - plen)."""
-        return jax.tree_util.tree_map(
-            lambda big, s: jax.lax.dynamic_update_slice(
-                big, s, (slot,) + (0,) * (big.ndim - 1)
-            ),
-            cache, stored,
-        )
+        return self.kv.put(cache, stored, where)
 
-    def _seed_paged_impl(self, pool, stored, page_ids, span: int):
-        """Paged fill seeding: the stored prefix writes into the slot's
-        first reserved pages (``span`` static = the prefix's bucket)."""
-        ps = self.page_size
-
-        def write(pool_leaf, s):
-            out = pool_leaf
-            for j in range(0, span, ps):
-                blk = jax.lax.slice_in_dim(s, j, min(j + ps, span), axis=1)
-                out = jax.lax.dynamic_update_slice(
-                    out, blk, (page_ids[j // ps],) + (0,) * (out.ndim - 1)
-                )
-            return out
-
-        return jax.tree_util.tree_map(write, pool, stored)
-
-    def _snap_impl(self, cache, slot, bucket: int):
+    def _snap_impl(self, cache, where, bucket: int):
         """Copy the slot's freshly filled prompt KV back out (prefix-cache
         store at flip time; the live row decodes on, so this is a copy)."""
-        return jax.tree_util.tree_map(
-            lambda big: jax.lax.dynamic_slice(
-                big, (slot,) + (0,) * (big.ndim - 1),
-                (1, bucket) + big.shape[2:],
-            ),
-            cache,
-        )
+        return self.kv.view(cache, where, bucket)
 
-    def _snap_paged_impl(self, pool, table_row, bucket: int):
-        # gather only the prompt span's pages (``bucket`` is static, so
-        # the page count is too) — densifying the whole max_len row here
-        # would pay ~max_len/bucket x the needed copy at flip time
-        n_pg = -(-bucket // self.page_size)
-        return jax.tree_util.tree_map(
-            lambda p: p[table_row[:n_pg]].reshape(
-                1, n_pg * self.page_size, *p.shape[2:]
-            )[:, :bucket],
-            pool,
-        )
-
-    def _chunk_impl(self, params, cache, tok, offsets, steps, temp, top_k,
-                    top_p, seeds, n_steps=None):
+    def _chunk_impl(self, params, cache, tok, *args, n_steps=None):
         """``n_steps`` decode steps over ALL slots (``n_steps`` is STATIC —
         the default is one ``chunk_size`` chunk, a depth-D dispatch passes
-        D x chunk_size); offsets/steps are per-row (slots joined at
-        different times sit at different depths). ``top_k``/``top_p``
+        D x chunk_size). ``args`` is what ``_chunk_args`` lists: whatever
+        the layout needs to find every slot (dense: nothing), then
+        offsets, steps, temp, top_k, top_p, seeds — offsets/steps are
+        per-row (slots joined at different times sit at different depths),
+        and idle slots decode garbage that the layout keeps harmless (their
+        own unused rows, or the trash page). ``top_k``/``top_p``
         arrive as None when NO active row uses filters — the None variant
         compiles without the per-step full-vocab sort the filters need
         (jit caches both variants; values are identical either way since
@@ -1130,9 +810,11 @@ class ContinuousBatcher:
         learns the lookahead value without a second device sync."""
         from modelx_tpu.ops import sampling as sampling_ops
 
+        *where, offsets, steps, temp, top_k, top_p, seeds = args
+
         def step_fn(carry, _i):
             cache, tok, offsets, steps = carry
-            logits, cache = self._fwd(params, tok, kv_cache=cache, cache_offset=offsets)
+            logits, cache = self.kv.step(params, tok, cache, offsets, *where)
             nxt = sampling_ops.sample(
                 logits[:, -1, :].astype(jnp.float32), jax.random.PRNGKey(0), temp,
                 top_k=top_k, top_p=top_p, seeds=seeds, step=steps,
@@ -1155,16 +837,14 @@ class ContinuousBatcher:
         # mutates the originals (retirement resets, next admissions)
         # possibly BEFORE the in-flight chunk reads them — each dispatch
         # gets private snapshots nobody mutates
-        args = [
+        return [
+            *self.kv.all_slots(),
             jnp.asarray(self._offsets.copy()), jnp.asarray(self._steps.copy()),
             jnp.asarray(self._temp.copy()),
             jnp.asarray(self._top_k.copy()) if filtered else None,
             jnp.asarray(self._top_p.copy()) if filtered else None,
             jnp.asarray(self._seeds.copy()),
         ]
-        if self.page_size > 0:
-            args.insert(0, jnp.asarray(self._table.copy()))
-        return args
 
     def _run_chunk(self, params, cache, tok, *args, n_steps):
         """What ``self._chunk`` is bound to: the variant a load fetched
@@ -1200,19 +880,12 @@ class ContinuousBatcher:
         that dispatch would produce. The work returns how many programs it
         delivered."""
         from concurrent.futures import Future
-        from jax.sharding import NamedSharding, PartitionSpec
 
         # a dispatch meets the engine's state as the admit program returned
         # it — committed to the mesh — not as jnp.zeros left it
-        def as_dispatched(x, sharding=None):
-            return jax.ShapeDtypeStruct(
-                x.shape, x.dtype,
-                sharding=sharding or NamedSharding(self.mesh, PartitionSpec()))
-
-        cache, tok = jax.eval_shape(self._new_device_state)
-        cache = jax.tree_util.tree_map(
-            lambda x: as_dispatched(x, self._cache_sharding(x.shape)), cache)
-        tok = as_dispatched(tok)
+        cache = self.kv.abstract_state()
+        tok = jax.ShapeDtypeStruct((self.max_slots, 1), jnp.int32,
+                                   sharding=kv_layout.replicated(self.mesh))
         n_steps = self.chunk_size
         fut = self._chunk_aot[(n_steps, False)] = Future()
         args = (param_sds, cache, tok, *self._chunk_args(False))
@@ -1232,95 +905,20 @@ class ContinuousBatcher:
 
         return fetch
 
-    def _chunk_paged_impl(self, params, pool, tok, table, offsets, steps,
-                          temp, top_k, top_p, seeds, n_steps=None):
-        """Paged chunk: each step gathers every slot's pages into a dense
-        [max_slots, max_len] view (a TRANSIENT the scheduler frees layer by
-        layer — the persistent state is only the pool), runs the family
-        forward against it unchanged, then scatters the one row each slot
-        wrote back into its current page. Idle slots' table rows are all
-        zeros, so their writes land on the trash page and their reads sit
-        beyond the causal horizon. The table is a traced input: one
-        compiled program serves every page assignment."""
-        from modelx_tpu.ops import sampling as sampling_ops
-
-        def step_fn(carry, _i):
-            pool, tok, offsets, steps = carry
-            if self._fwd_paged is not None:
-                # fast path: the family forward scatters this step's k/v
-                # into the pools and attends over them IN PLACE
-                logits, pool = self._fwd_paged(
-                    params, tok, kv_cache=pool, cache_offset=offsets, table=table
-                )
-            else:
-                dense = jax.tree_util.tree_map(
-                    lambda p: p[table].reshape(
-                        self.max_slots, self.max_len, *p.shape[2:]
-                    ),
-                    pool,
-                )
-                logits, dense = self._fwd(
-                    params, tok, kv_cache=dense, cache_offset=offsets
-                )
-                from modelx_tpu.ops.paged_attention import write_token_kv
-
-                def put_back(p, d):
-                    rows = jax.vmap(
-                        lambda row, o: jax.lax.dynamic_slice_in_dim(row, o, 1, axis=0)
-                    )(d, offsets)  # [slots, 1, ...] — the row each slot wrote
-                    return write_token_kv(p, rows, table, offsets)
-
-                pool = jax.tree_util.tree_map(put_back, pool, dense)
-            nxt = sampling_ops.sample(
-                logits[:, -1, :].astype(jnp.float32), jax.random.PRNGKey(0), temp,
-                top_k=top_k, top_p=top_p, seeds=seeds, step=steps,
-            )
-            return (pool, nxt[:, None], offsets + 1, steps + 1), tok[:, 0]
-
-        (pool, tok, offsets, steps), toks = jax.lax.scan(
-            step_fn, (pool, tok, offsets, steps),
-            jnp.arange(n_steps or self.chunk_size),
-        )
-        # extra trailing column = the lookahead carry, see _chunk_impl
-        return pool, tok, jnp.concatenate([toks.T, tok], axis=1)
-
     # -- speculative verify (single-occupied greedy slot) ---------------------
 
-    def _spec_verify_impl(self, params, cache, block, offsets):
+    def _spec_verify_impl(self, params, cache, block, *args):
         """One verify step over the engine's FULL slot array: ``block`` is
         [max_slots, k+1] (the active slot carries last-token + proposals;
         idle slots carry zeros whose writes land at their offset-0 garbage
-        rows). Returns the model's argmax at every position — position i is
-        its pick for the token AFTER block[:, :i+1]. Rejected positions
-        leave garbage KV; the host rewinds offsets past them, and the
-        causal mask (kpos <= qpos) hides them until overwritten."""
-        logits, cache = self._fwd(params, block, kv_cache=cache, cache_offset=offsets)
+        rows); ``args`` is the layout's every-slot argument, if any, then
+        the offsets. Returns the model's argmax at every position —
+        position i is its pick for the token AFTER block[:, :i+1]. Rejected
+        positions leave garbage KV; the host rewinds offsets past them, and
+        the causal mask (kpos <= qpos) hides them until overwritten."""
+        *where, offsets = args
+        logits, cache = self.kv.step(params, block, cache, offsets, *where)
         return cache, jnp.argmax(logits, axis=-1)  # [max_slots, k+1]
-
-    def _spec_verify_paged_impl(self, params, pool, block, table, offsets):
-        """Paged verify: gather -> forward -> scatter each of the k+1
-        written rows back to its page (static unroll over the block width,
-        like the admit tail's page writes)."""
-        from modelx_tpu.ops.paged_attention import write_token_kv
-
-        dense = jax.tree_util.tree_map(
-            lambda p: p[table].reshape(self.max_slots, self.max_len, *p.shape[2:]),
-            pool,
-        )
-        logits, dense = self._fwd(params, block, kv_cache=dense, cache_offset=offsets)
-        width = block.shape[1]
-
-        def put_back(p, d):
-            for j in range(width):
-                off = offsets + j
-                rows = jax.vmap(
-                    lambda row, o: jax.lax.dynamic_slice_in_dim(row, o, 1, axis=0)
-                )(d, off)
-                p = write_token_kv(p, rows, table, off)
-            return p
-
-        pool = jax.tree_util.tree_map(put_back, pool, dense)
-        return pool, jnp.argmax(logits, axis=-1)
 
     def _spec_ok(self) -> bool:
         """Speculate iff exactly one greedy row is active and nothing is
@@ -1377,13 +975,10 @@ class ContinuousBatcher:
         block[slot, 0] = tok_val
         if prop:
             block[slot, 1:1 + len(prop)] = prop
-        args = [jnp.asarray(block)]
-        if self.page_size > 0:
-            args.append(jnp.asarray(self._table.copy()))
-        args.append(jnp.asarray(self._offsets.copy()))
         with trace.span("continuous.spec_verify", proposed=len(prop)):
             self._cache, argm_dev = self._spec_prog(
-                self.server.params, self._cache, *args
+                self.server.params, self._cache, jnp.asarray(block),
+                *self.kv.all_slots(), jnp.asarray(self._offsets.copy()),
             )
         # THE spec boundary's one blocking readback (verify is inherently
         # synchronous: acceptance decides the next proposal)
@@ -1435,43 +1030,33 @@ class ContinuousBatcher:
 
     # -- engine loop ----------------------------------------------------------
 
-    def _need_pages(self, ids, n: int) -> int:
-        """Pages covering the row's full write span (prompt bucket + budget
-        + the overrun margin — the same ``need`` submit validates)."""
-        need = pad_seq_len(len(ids)) + n + self._overrun
-        return -(-need // self.page_size)
+    def _span(self, ids, n: int) -> int:
+        """The row's full write span in tokens (prompt bucket + budget +
+        the overrun margin — the same ``need`` submit validates)."""
+        return pad_seq_len(len(ids)) + n + self._overrun
 
     def _admits_now(self, item) -> bool:
-        """A free slot — and, in paged mode, enough free pages. A prompt
-        that will single-program-admit needs its whole span up front (a
-        mid-decode pool exhaustion must not strand a half-decoded row); a
-        prompt that will CHUNK-fill needs only its first piece's pages —
-        the rest reserve incrementally as decode rows retire, so a long
-        prompt's admission no longer serializes behind the pool-full FIFO
-        for its full span."""
+        """A free slot — and room in the KV layout. A prompt that will
+        single-program-admit needs its whole span up front (a mid-decode
+        pool exhaustion must not strand a half-decoded row); a prompt that
+        will CHUNK-fill needs only its first piece's — the rest reserves
+        incrementally as decode rows retire, so a long prompt's admission
+        no longer serializes behind the pool-full FIFO for its full span."""
         if not self._free:
             return False
-        if self.page_size > 0 and not item[3].cancelled:
-            ids, n = item[0], item[1]
-            if self.prefill_chunk > 0 and pad_seq_len(len(ids)) > self.prefill_chunk:
-                need = -(-self.prefill_chunk // self.page_size)
-            else:
-                need = self._need_pages(ids, n)
-            if need > len(self._free_pages):
-                return False
-        return True
+        ids, n, _samp, ticket = item
+        if ticket.cancelled:
+            return True  # takes no room: preparation ends it
+        if self.prefill_chunk > 0 and pad_seq_len(len(ids)) > self.prefill_chunk:
+            return self.kv.fits(self.prefill_chunk)
+        return self.kv.fits(self._span(ids, n))
 
     def _release_slot(self, slot: int) -> None:
-        """Return a retired row's slot (and, paged, its pages) to the free
-        sets. Table zeroing points the slot's entries back at the trash
-        page; the chunk possibly still in flight dispatched with a
-        SNAPSHOT of the table, so reuse stays data-ordered."""
+        """Return a retired row's slot, and what it had reserved, to the
+        free sets."""
         self._free.append(slot)
         self._offsets[slot] = 0
-        if self.page_size > 0:
-            self._free_pages.extend(self._row_pages.pop(slot, ()))
-            self._table[slot, :] = 0
-            self.stats["pages_free"] = len(self._free_pages)
+        self.kv.release(slot)
 
     def _gather_prep(self, item, to_admit: list) -> None:
         """Prepare one admissible item into ``to_admit``. If preparation
@@ -1502,7 +1087,7 @@ class ContinuousBatcher:
             to_admit.append(prep)
 
     def _prepare_admit(self, item, memo_hit=_NO_HIT) -> dict | None:
-        """Claim a slot (and, paged, reserve the row's pages) for one
+        """Claim a slot (and reserve the row's span) for one
         admissible item and resolve its prefix-cache hit. Pure host-side
         bookkeeping — the device dispatch happens in ``_admit_one`` /
         ``_admit_group`` so a burst of preparations can share a program.
@@ -1538,38 +1123,18 @@ class ContinuousBatcher:
                 # exceed the slot cache are skipped (shorter fitting
                 # prefixes still win)
                 hit = self.prefix_cache.lookup(ids, max_total=self.max_len)
-        if self.prefill_chunk > 0:
-            to_fill = s - (hit[0] if hit is not None else 0)
-            use_fill = pad_seq_len(to_fill) > self.prefill_chunk
-            if (not use_fill and self.page_size > 0
-                    and self._need_pages(ids, n) > len(self._free_pages)):
-                # the single-program span's pages aren't free (a hit can
-                # shrink a long prompt under one piece after _admits_now
-                # gated on the first-piece estimate): fill incrementally
-                use_fill = True
-            if use_fill:
-                if self.page_size > 0:
-                    self._row_pages[slot] = []
-                    self._table[slot, :] = 0
-                return {"ids": ids, "n": n, "samp": samp, "ticket": ticket,
-                        "slot": slot, "s": s, "hit": hit, "fill": True,
-                        "finished": False}
-        prompt_pages = None
-        if self.page_size > 0:
-            # reserve the row's WHOLE span now; the admit program only
-            # writes the prompt-bucket pages, decode fills the rest
-            need_pages = self._need_pages(ids, n)
-            pages = [self._free_pages.pop() for _ in range(need_pages)]
-            self._row_pages[slot] = pages
-            self._table[slot, :] = 0
-            self._table[slot, :need_pages] = pages
-            self.stats["pages_free"] = len(self._free_pages)
-            n_prompt = -(-pad_seq_len(s) // self.page_size)
-            prompt_pages = np.asarray(pages[:n_prompt], np.int32)
+        to_fill = s - (hit[0] if hit is not None else 0)
+        fill = self.prefill_chunk > 0 and pad_seq_len(to_fill) > self.prefill_chunk
+        # a single-program admission reserves the row's WHOLE span now (the
+        # admit program only writes the prompt bucket, decode fills the
+        # rest). It is refused only with chunked prefill on — a hit can
+        # shrink a long prompt under one piece after _admits_now gated on
+        # the first-piece estimate — and the row then fills incrementally
+        if not fill and not self.kv.reserve(slot, self._span(ids, n)):
+            fill = True
         return {"ids": ids, "n": n, "samp": samp, "ticket": ticket,
-                "slot": slot, "s": s, "prompt_pages": prompt_pages,
-                "hit": hit, "bucket": pad_seq_len(s), "fill": False,
-                "finished": False}
+                "slot": slot, "s": s, "hit": hit, "bucket": pad_seq_len(s),
+                "fill": fill, "finished": False}
 
     def _finish_admit_host(self, prep: dict, first_ref) -> None:
         """Shared post-dispatch bookkeeping: per-slot vectors, the row
@@ -1683,21 +1248,16 @@ class ContinuousBatcher:
             top_p[i] = float(p["samp"].get("top_p", 1.0))
             seeds[i] = int(p["samp"].get("seed", 0))
             first_steps[i] = int(p["samp"].get("resume_step", 0))
-        args = [self.server.params, jnp.asarray(prompts), self._cache,
-                self._tok, jnp.asarray(row_lens), jnp.asarray(slots)]
-        if self.page_size > 0:
-            n_prompt = len(preps[0]["prompt_pages"])
-            page_ids = np.zeros((m, n_prompt), np.int32)  # pads -> trash page
-            for i, p in enumerate(preps):
-                page_ids[i] = p["prompt_pages"]
-            args.append(jnp.asarray(page_ids))
         # top_k/top_p always ride as ARRAYS here (0 / 1.0 = off per row):
         # a None variant would mean two compiles per bucket, and the admit
         # program samples once — the chunk scan's per-step sort-skip
         # optimization has nothing to save on a one-shot program
-        args += [jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
-                 jnp.asarray(seeds), jnp.asarray(first_steps)]
-        self._cache, self._tok, firsts = self._admit_many_prog(*args)
+        self._cache, self._tok, firsts = self._admit_many_prog(
+            self.server.params, jnp.asarray(prompts), self._cache, self._tok,
+            jnp.asarray(row_lens), self.kv.at_many(slots),
+            jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
+            jnp.asarray(seeds), jnp.asarray(first_steps),
+        )
         block = {"dev": firsts, "np": None}
 
         def first_ref(i: int, block=block):
@@ -1730,10 +1290,7 @@ class ContinuousBatcher:
                   prompt_len=s, cached=prep["hit"] is not None,
                   installed_kv=installed)
         hit = prep["hit"]
-        prompt_pages = (
-            jnp.asarray(prep["prompt_pages"])
-            if prep["prompt_pages"] is not None else None
-        )
+        where = self.kv.at(slot)
         temp = np.asarray([samp.get("temperature", 0.0)], np.float32)
         k_val = int(samp.get("top_k", 0))
         p_val = float(samp.get("top_p", 1.0))
@@ -1748,36 +1305,21 @@ class ContinuousBatcher:
             sb = pad_seq_len(len(suffix))
             block = np.zeros((1, sb), np.int32)
             block[0, : len(suffix)] = suffix
-            if self.page_size > 0:
-                self._cache, self._tok, first, small = self._admit_cached_prog(
-                    self.server.params, jnp.asarray(block), self._cache,
-                    self._tok, jnp.asarray([len(suffix)], np.int32),
-                    jnp.int32(plen), jnp.int32(slot), stored, prompt_pages,
-                    temp, top_k, top_p, seed, pad_seq_len(s), first_step,
-                )
-            else:
-                self._cache, self._tok, first, small = self._admit_cached_prog(
-                    self.server.params, jnp.asarray(block), self._cache, self._tok,
-                    jnp.asarray([len(suffix)], np.int32), jnp.int32(plen),
-                    jnp.int32(slot), stored, temp, top_k, top_p, seed,
-                    pad_seq_len(s), first_step,
-                )
+            self._cache, self._tok, first, small = self._admit_cached_prog(
+                self.server.params, jnp.asarray(block), self._cache, self._tok,
+                jnp.asarray([len(suffix)], np.int32), jnp.int32(plen),
+                where, stored, temp, top_k, top_p, seed,
+                pad_seq_len(s), first_step,
+            )
         else:
             pad_s = pad_seq_len(s)
             prompt = np.zeros((1, pad_s), np.int32)
             prompt[0, :s] = ids
-            if self.page_size > 0:
-                admitted = self._admit_prog(
-                    self.server.params, jnp.asarray(prompt), self._cache,
-                    self._tok, jnp.asarray([s], np.int32), jnp.int32(slot),
-                    prompt_pages, temp, top_k, top_p, seed, first_step,
-                )
-            else:
-                admitted = self._admit_prog(
-                    self.server.params, jnp.asarray(prompt), self._cache, self._tok,
-                    jnp.asarray([s], np.int32), jnp.int32(slot), temp, top_k, top_p,
-                    seed, first_step,
-                )
+            admitted = self._admit_prog(
+                self.server.params, jnp.asarray(prompt), self._cache, self._tok,
+                jnp.asarray([s], np.int32), where, temp, top_k, top_p,
+                seed, first_step,
+            )
             if self.prefix_cache is None:
                 self._cache, self._tok, first = admitted
                 small = None
@@ -1795,24 +1337,6 @@ class ContinuousBatcher:
         self._suspect_rid = ""
 
     # -- chunked prefill scheduling -------------------------------------------
-
-    def _reserve_upto(self, slot: int, tokens: int) -> bool:
-        """Grow a filling slot's page reservation to cover ``tokens``
-        positions (incremental per-piece reservation). False = pool
-        short: the caller waits a boundary (retirements free pages) or,
-        if every fill is wedged, preempts the youngest."""
-        need = -(-tokens // self.page_size)
-        pages = self._row_pages.setdefault(slot, [])
-        if need <= len(pages):
-            return True
-        if need - len(pages) > len(self._free_pages):
-            return False
-        for j in range(len(pages), need):
-            pg = self._free_pages.pop()
-            pages.append(pg)
-            self._table[slot, j] = pg
-        self.stats["pages_free"] = len(self._free_pages)
-        return True
 
     def _start_fill(self, prep: dict) -> None:
         """Begin a chunked prefill on a claimed slot. A prefix hit seeds
@@ -1832,26 +1356,16 @@ class ContinuousBatcher:
             # re-prefill as part of the first suffix piece, overwriting
             # the seeded bucket's junk span on the way.
             plen = plen_real // SEQ_BUCKET * SEQ_BUCKET
-            bucket = pad_seq_len(plen_real)
             if plen == 0:
                 pass  # sub-bucket prefix: seeding buys nothing
-            elif self.page_size > 0 and not self._reserve_upto(slot, bucket):
-                # a concurrent preparation raced the seed's pages away:
+            elif not self.kv.reserve(slot, pad_seq_len(plen_real)):
+                # a concurrent preparation raced the seed's room away:
                 # fall back to filling the whole prompt incrementally
                 plen = 0
-            elif self.page_size > 0:
-                n_pg = -(-bucket // self.page_size)
-                page_ids = jnp.asarray(
-                    np.asarray(self._row_pages[slot][:n_pg], np.int32)
-                )
-                with trace.span("continuous.fill_seed", prefix=plen):
-                    self._cache = self._seed_prog(
-                        self._cache, stored, page_ids, bucket
-                    )
             else:
                 with trace.span("continuous.fill_seed", prefix=plen):
                     self._cache = self._seed_prog(
-                        self._cache, stored, jnp.int32(slot)
+                        self._cache, stored, self.kv.at(slot)
                     )
         fill = _Fill(slot, list(ids), prep["n"], dict(prep["samp"]),
                      prep["ticket"], filled=plen, fp=prep.get("fp"))
@@ -1896,16 +1410,13 @@ class ContinuousBatcher:
             if (landed and self.prefill_budget > 0
                     and spent + piece_len > self.prefill_budget):
                 break  # budget spent: later fills wait for the next boundary
-            if self.page_size > 0:
-                # the last piece also reserves the decode span — the flip
-                # must never strand a row that cannot decode
-                upto = (
-                    pad_seq_len(len(fill.ids)) + fill.n + self._overrun
-                    if last else fill.filled + piece_len
-                )
-                if not self._reserve_upto(slot, upto):
-                    self.stats["fill_waits"] += 1
-                    continue
+            # the last piece also reserves the decode span — the flip must
+            # never strand a row that cannot decode
+            upto = (self._span(fill.ids, fill.n) if last
+                    else fill.filled + piece_len)
+            if not self.kv.reserve(slot, upto):
+                self.stats["fill_waits"] += 1
+                continue
             self._land_piece(fill, piece_len, take, last)
             spent += piece_len
             landed += 1
@@ -1928,19 +1439,7 @@ class ContinuousBatcher:
         block[0, :take] = fill.ids[fill.filled: fill.filled + take]
         piece = jnp.asarray(block)
         offset = jnp.int32(fill.filled)
-        table_row = write_page_ids = page_start = None
-        if self.page_size > 0:
-            table_row = jnp.asarray(self._table[slot].copy())
-            # pages the piece's writes touch — [filled, filled+Sb) spans
-            # at most Sb/ps + 1 of them (all reserved by _reserve_upto);
-            # the touched count is static per (bucket, alignment) pair
-            ps = self.page_size
-            start_pg = fill.filled // ps
-            end_pg = (fill.filled + piece_len - 1) // ps
-            write_page_ids = jnp.asarray(
-                self._table[slot, start_pg: end_pg + 1].copy()
-            )
-            page_start = jnp.int32(start_pg * ps)
+        where = self.kv.at(slot, fill.filled, piece_len)
         self.stats["prefill_pieces"] += 1
         fill.ticket.prefill_pieces += 1
         self._rec("fill_piece", slot=slot, request_id=fill.ticket.request_id,
@@ -1951,16 +1450,9 @@ class ContinuousBatcher:
             # id so the piece timeline joins the request's trace
             with trace.request_context(fill.ticket.request_id), \
                     trace.span("continuous.prefill_piece", tokens=take):
-                if self.page_size > 0:
-                    self._cache = self._piece_prog(
-                        self.server.params, piece, self._cache,
-                        table_row, offset, write_page_ids, page_start,
-                    )
-                else:
-                    self._cache = self._piece_prog(
-                        self.server.params, piece, self._cache,
-                        offset, jnp.int32(slot),
-                    )
+                self._cache = self._piece_prog(
+                    self.server.params, piece, self._cache, offset, where,
+                )
             fill.filled += take
             self._offsets[slot] = fill.filled
             self._suspect_fp = None
@@ -1977,31 +1469,18 @@ class ContinuousBatcher:
         last_idx = jnp.asarray([take - 1], jnp.int32)
         with trace.request_context(fill.ticket.request_id), \
                 trace.span("continuous.prefill_flip", tokens=take):
-            if self.page_size > 0:
-                self._cache, self._tok, first = self._piece_flip_prog(
-                    self.server.params, piece, self._cache, self._tok,
-                    table_row, offset, jnp.int32(slot), last_idx,
-                    temp, top_k, top_p, seed, write_page_ids, page_start,
-                    first_step,
-                )
-            else:
-                self._cache, self._tok, first = self._piece_flip_prog(
-                    self.server.params, piece, self._cache, self._tok,
-                    offset, jnp.int32(slot), last_idx,
-                    temp, top_k, top_p, seed, first_step,
-                )
+            self._cache, self._tok, first = self._piece_flip_prog(
+                self.server.params, piece, self._cache, self._tok,
+                offset, where, last_idx, temp, top_k, top_p, seed, first_step,
+            )
         del self._filling[slot]
         self._fill_order.remove(slot)
         if self.prefix_cache is not None:
             # store the freshly landed prompt KV so the conversation's
             # next turn prefills only its new suffix — parity with the
             # single-program admission paths
-            bucket = pad_seq_len(len(fill.ids))
-            if self.page_size > 0:
-                snap = self._snap_prog(self._cache, table_row, bucket)
-            else:
-                snap = self._snap_prog(self._cache, jnp.int32(slot), bucket)
-            self.prefix_cache.put(fill.ids, snap)
+            self.prefix_cache.put(fill.ids, self._snap_prog(
+                self._cache, where, pad_seq_len(len(fill.ids))))
         prep = {"slot": slot, "s": len(fill.ids), "samp": fill.samp,
                 "n": fill.n, "ticket": fill.ticket, "ids": fill.ids,
                 "finished": False}
@@ -2175,22 +1654,7 @@ class ContinuousBatcher:
         self.stats["decode_pad_rows"] += (
             max(self.max_slots - n_live, 0) * depth
         )
-        if self.page_size > 0 and self._table is not None:
-            # ragged paged sweep: the in-place kernel stops at the batch's
-            # actual max page (ops/paged_attention), so the interesting
-            # number is how much of the static table width a dispatch
-            # really walks — pages_swept / pages_swept_possible
-            pps = int(self._table.shape[1])
-            blocks = int(
-                min(pps, (int(self._offsets.max()) + n_steps)
-                    // self.page_size + 1)
-            )
-            self.stats["pages_swept"] = (
-                self.stats.get("pages_swept", 0) + blocks
-            )
-            self.stats["pages_swept_possible"] = (
-                self.stats.get("pages_swept_possible", 0) + pps
-            )
+        self.kv.count_sweep(self._offsets, n_steps)
         self._depth_last = depth
         if depth > self.stats["dispatch_depth_max"]:
             self.stats["dispatch_depth_max"] = depth
@@ -2532,13 +1996,7 @@ class ContinuousBatcher:
         its entries are keyed by token prefix and independent of slot
         state, so multi-turn conversations keep their fast path across a
         restart."""
-        if self.page_size > 0:
-            self._free_pages = list(range(1, self.num_pages))
-            self._table = np.zeros(
-                (self.max_slots, self._pages_per_slot), np.int32
-            )
-            self._row_pages = {}
-            self.stats["pages_free"] = len(self._free_pages)
+        self.kv.reset()
         self.allocate_device_state()
         self._offsets[:] = 0
         self._steps[:] = 0
@@ -2661,7 +2119,7 @@ class ContinuousBatcher:
                         phases.end()
                         return "closed"
                     if not self._admits_now(item):
-                        # no slot (or, paged, not enough free pages): hold in
+                        # no slot (or no room in the KV layout): hold in
                         # the FIFO backlog and decode on — a retire this
                         # chunk frees capacity for it
                         self._backlog_insert(item)
@@ -2981,12 +2439,10 @@ class ContinuousBatcher:
                 f"prompt ({s}) + max_new_tokens ({max_new_tokens}) exceeds the "
                 f"engine's max_len {self.max_len} (margin {self._overrun})"
             )
-        if self.page_size > 0 and self._need_pages(ids, max_new_tokens) > self.num_pages - 1:
+        never = self.kv.never_holds(need)
+        if never:
             raise ValueError(
-                f"prompt ({s}) + max_new_tokens ({max_new_tokens}) needs more "
-                f"pages than the engine's pool holds "
-                f"({self.num_pages - 1} x {self.page_size} tokens)"
-            )
+                f"prompt ({s}) + max_new_tokens ({max_new_tokens}) {never}")
 
     def _check_quarantine(self, ids, n: int) -> None:
         if not self._poison:
@@ -3216,9 +2672,9 @@ class ContinuousBatcher:
         self._thread.join(timeout=30)
 
     def release_device_state(self) -> None:
-        """Drop the engine's device allocations — the KV cache / page pool
-        (the big one: [max_slots, max_len] or [num_pages, page_size] per
-        layer), the token vector, and every compiled-program reference.
+        """Drop the engine's device allocations — the KV state (the big
+        one: dl/kv_layout.py says how big), the token vector, and every
+        compiled-program reference.
         Call AFTER ``close()``: the model-unload path (dl/lifecycle.py)
         must return the HBM to the pool budget immediately, not when the
         garbage collector eventually notices the dead engine."""

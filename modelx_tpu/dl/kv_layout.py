@@ -1,0 +1,400 @@
+"""How the continuous engine's KV state lies on the device.
+
+``dl/continuous.py`` schedules: slots, admissions, fills, dispatches. Where
+a slot's keys and values live is decided here, behind two classes with one
+interface, and the engine never asks which it got:
+
+- ``DenseKV``: one ``[max_slots, max_len]`` cache per leaf; a slot's rows
+  are its own, so a reservation always succeeds and a release does nothing.
+- ``PagedKV``: a POOL of fixed-size pages (``[num_pages, page_size]`` per
+  leaf) plus a host-managed block table ``[max_slots, max_len/page_size]``,
+  so HBM scales with LIVE tokens instead of ``max_slots x max_len``. A row
+  reserves the pages its span needs and returns them at retirement. Page 0
+  is a TRASH page no slot owns: idle table entries point there, so idle
+  rows' writes land harmlessly and their reads sit beyond the causal
+  horizon (the dense engine's idle-row trick, relocated). The table is a
+  traced input, never a shape: one program serves every page assignment.
+
+Three kinds of method. Device state (``new_state``, ``abstract_state``).
+Host bookkeeping (``fits``/``reserve``/``release``/``never_holds``/``reset``)
+plus the builders of WHERE — the one argument a program takes to find a
+slot's rows (``at``, ``at_many``, ``all_slots``): a slot index for
+``DenseKV``, which so adds no argument to any program; the slot with its
+table row for ``PagedKV``. And the traced primitives that unpack it
+(``step``, ``put``, ``put_many``, ``view``, ``put_piece``): the only lines
+in which the engine's programs differ by layout. A scratch cache — what an
+admission prefills into, what the prefix cache stores — is a dense
+``[k, bucket]`` tree for both.
+"""
+
+from __future__ import annotations
+
+import logging
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+def build(server, fwd, init_cache, stats: dict, *, max_slots: int,
+          max_len: int, chunk_size: int, page_size: int, max_live_tokens: int,
+          paged_attention: str):
+    """The layout the engine's arguments ask for. ``fwd`` / ``init_cache``
+    are the family's cached forward and scratch-cache constructor; ``stats``
+    is the engine's counter dict (the pool reports its occupancy there)."""
+    if paged_attention not in ("gather", "in-place"):
+        raise ValueError(f"unknown paged_attention mode {paged_attention!r}")
+    if page_size <= 0:
+        return DenseKV(fwd, init_cache, server.mesh, max_slots, max_len)
+    return PagedKV(server, fwd, init_cache, stats, max_slots, max_len,
+                   chunk_size, page_size, max_live_tokens, paged_attention)
+
+
+class DenseKV:
+    """``[max_slots, max_len]`` per leaf. WHERE is the slot index (a vector
+    of them for a batched admission, nothing for "every slot")."""
+
+    # the dim of a leaf that dp may split: its slots
+    _batch_dim = 0
+
+    def __init__(self, fwd, init_cache, mesh, max_slots: int, max_len: int) -> None:
+        self.fwd, self.init_cache, self.mesh = fwd, init_cache, mesh
+        self.max_slots, self.max_len = max_slots, max_len
+
+    # -- device state ---------------------------------------------------------
+
+    def _zeros(self):
+        return self.init_cache(self.max_slots, self.max_len)
+
+    def sharding(self, shape):
+        """Where one leaf lives on the serving mesh (None on a single
+        device — the dp=1 engine stays byte-identical to before). Dense
+        caches shard slots over dp and kv heads over tp; the paged pool
+        shards kv heads over tp only, because its leading dim is a global
+        page index no axis may split."""
+        if self.mesh.size <= 1:
+            return None
+        from modelx_tpu.dl.sharding import cache_sharding
+
+        return cache_sharding(self.mesh, shape, batch_dim=self._batch_dim,
+                              head_dim=len(shape) - 2)
+
+    def new_state(self):
+        """The KV state, zeroed, laid out on the serving mesh with an
+        explicit GSPMD layout before the first program closes over it.
+        Every program the engine compiles inherits these input layouts, so
+        decode math runs tensor-parallel instead of congealing on device 0."""
+        cache = self._zeros()
+        if self.mesh.size <= 1:
+            return cache
+        return jax.tree_util.tree_map(
+            lambda leaf: jax.device_put(leaf, self.sharding(leaf.shape)), cache)
+
+    def abstract_state(self):
+        """The state as a dispatch meets it — committed to the mesh, as the
+        admit program returned it, not as ``jnp.zeros`` left it — without
+        allocating it (``chunk_warmer`` lowers against this)."""
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype,
+                sharding=self.sharding(x.shape) or replicated(self.mesh)),
+            jax.eval_shape(self._zeros))
+
+    # -- host bookkeeping -----------------------------------------------------
+
+    def reset(self) -> None:
+        """Every slot empty again (a supervised restart)."""
+
+    def fits(self, tokens: int) -> bool:
+        """Could a new row reserve ``tokens`` positions now?"""
+        return True
+
+    def reserve(self, slot: int, tokens: int) -> bool:
+        """Grow ``slot``'s reservation to cover ``tokens`` positions; all
+        or nothing. False = short: the caller waits for retirements."""
+        return True
+
+    def release(self, slot: int) -> None:
+        """A retired row's reservation goes back."""
+
+    def never_holds(self, tokens: int) -> str:
+        """Why a row of ``tokens`` <= max_len positions could NEVER be
+        reserved, for the error a submit raises ("" = it could)."""
+        return ""
+
+    def count_sweep(self, offsets: np.ndarray, n_steps: int) -> None:
+        """Account one decode dispatch over rows at ``offsets``."""
+
+    def all_slots(self) -> tuple:
+        return ()
+
+    def at(self, slot: int, filled: int = 0, piece_len: int = 0):
+        """WHERE for one slot; ``piece_len`` > 0 names the rows a prefill
+        piece landing at ``filled`` writes."""
+        return jnp.int32(slot)
+
+    def at_many(self, slots: np.ndarray):
+        """WHERE for a batched admission (``max_slots`` = a pad row)."""
+        return jnp.asarray(slots)
+
+    # -- traced primitives ----------------------------------------------------
+
+    @staticmethod
+    def slot_of(where):
+        return where
+
+    def step(self, params, block, cache, offsets):
+        """One cached forward over ALL slots, each row at its own offset."""
+        return self.fwd(params, block, kv_cache=cache, cache_offset=offsets)
+
+    def put(self, cache, small, where):
+        """Write a scratch cache to the front of one slot."""
+        return jax.tree_util.tree_map(
+            lambda big, little: jax.lax.dynamic_update_slice(
+                big, little, (where,) + (0,) * (big.ndim - 1)),
+            cache, small)
+
+    def put_many(self, cache, small, where):
+        """Write row i of a scratch cache to the front of slot ``where[i]``
+        (pad rows carry an out-of-bounds slot: ``mode="drop"`` discards)."""
+        return jax.tree_util.tree_map(
+            lambda big, lit: big.at[where, : lit.shape[1]].set(lit, mode="drop"),
+            cache, small)
+
+    def view(self, cache, where, length: int):
+        """A dense ``[1, length]`` copy of the front of one slot."""
+        return jax.tree_util.tree_map(
+            lambda big: jax.lax.dynamic_slice(
+                big, (where,) + (0,) * (big.ndim - 1),
+                (1, length) + big.shape[2:]),
+            cache)
+
+    # a piece's forward ran on the slot's whole row: write the row back
+    put_piece = put
+
+
+class PagedKV(DenseKV):
+    """``[num_pages, page_size]`` pools and a block table. WHERE is
+    ``(slot, table row)`` — plus the touched table entries and the first
+    one's token offset for a prefill piece — and the whole table for "every
+    slot"."""
+
+    _batch_dim = -1
+
+    def __init__(self, server, fwd, init_cache, stats: dict, max_slots: int,
+                 max_len: int, chunk_size: int, page_size: int,
+                 max_live_tokens: int, paged_attention: str) -> None:
+        super().__init__(fwd, init_cache, server.mesh, max_slots, max_len)
+        if max_len % page_size:
+            raise ValueError(
+                f"max_len {max_len} must be a multiple of page_size {page_size}")
+        self.page_size = ps = int(page_size)
+        budget = int(max_live_tokens) or max(
+            max_len + chunk_size + ps, max_slots * max_len // 4)
+        self.num_pages = 1 + -(-budget // ps)  # +1: trash
+        # chunk attention: "gather" (default) rebuilds a dense view per
+        # step — bit-identical logits to every other decode path, so the
+        # engine's cross-engine token-exactness guarantee holds
+        # unconditionally; "in-place" reads the page pools directly
+        # (ops/paged_attention.py, per-step transient = one page block —
+        # the long-context/HBM-bound deployment shape) at the cost of
+        # blockwise-softmax numerics: greedy matches in practice, sampled
+        # rows can flip at bf16 near-boundaries (measured on v5e). The
+        # operator picks the trade (--kv-attention).
+        self.fwd_paged = None
+        if paged_attention == "in-place":
+            family = server.family
+            if family.paged_decode_fns is not None:
+                self.fwd_paged = family.paged_decode_fns(server.cfg, mesh=server.mesh)
+            else:
+                # an operator asking for in-place did so for the HBM budget;
+                # a silent fallback would surface only as an OOM later
+                logging.getLogger("modelx.serve").warning(
+                    "--kv-attention in-place: family %s has no paged decode; "
+                    "falling back to the dense-gather chunk (higher per-step "
+                    "transient HBM)", family.name)
+        self.stats = stats
+        stats["page_size"] = ps
+        stats["pages_total"] = self.num_pages - 1  # excl. trash
+        stats["paged_attention"] = "gather" if self.fwd_paged is None else "in-place"
+        self.reset()
+
+    def _zeros(self):
+        return jax.tree_util.tree_map(
+            lambda leaf: jnp.zeros(
+                (self.num_pages, self.page_size) + leaf.shape[2:], leaf.dtype),
+            self.init_cache(1, self.page_size))
+
+    # -- host bookkeeping -----------------------------------------------------
+
+    def reset(self) -> None:
+        self._free_pages = list(range(1, self.num_pages))
+        self._table = np.zeros(
+            (self.max_slots, self.max_len // self.page_size), np.int32)
+        self._row_pages: dict[int, list[int]] = {}  # slot -> owned pages
+        self.stats["pages_free"] = len(self._free_pages)
+
+    def _pages(self, tokens: int) -> int:
+        return -(-tokens // self.page_size)
+
+    def fits(self, tokens: int) -> bool:
+        return self._pages(tokens) <= len(self._free_pages)
+
+    def reserve(self, slot: int, tokens: int) -> bool:
+        need = self._pages(tokens)
+        pages = self._row_pages.get(slot, [])
+        if need - len(pages) > len(self._free_pages):
+            return False
+        self._row_pages[slot] = pages
+        for j in range(len(pages), need):
+            pg = self._free_pages.pop()
+            pages.append(pg)
+            self._table[slot, j] = pg
+        self.stats["pages_free"] = len(self._free_pages)
+        return True
+
+    def release(self, slot: int) -> None:
+        # table zeroing points the slot's entries back at the trash page;
+        # a program possibly still in flight dispatched with a SNAPSHOT of
+        # the table, so reuse stays data-ordered
+        self._free_pages.extend(self._row_pages.pop(slot, ()))
+        self._table[slot, :] = 0
+        self.stats["pages_free"] = len(self._free_pages)
+
+    def never_holds(self, tokens: int) -> str:
+        if self._pages(tokens) <= self.num_pages - 1:
+            return ""
+        return ("needs more pages than the engine's pool holds "
+                f"({self.num_pages - 1} x {self.page_size} tokens)")
+
+    def count_sweep(self, offsets: np.ndarray, n_steps: int) -> None:
+        # ragged paged sweep: the in-place kernel stops at the batch's
+        # actual max page (ops/paged_attention), so the interesting number
+        # is how much of the static table width a dispatch really walks
+        pps = self._table.shape[1]
+        blocks = min(pps, (int(offsets.max()) + n_steps) // self.page_size + 1)
+        self.stats["pages_swept"] = self.stats.get("pages_swept", 0) + blocks
+        self.stats["pages_swept_possible"] = (
+            self.stats.get("pages_swept_possible", 0) + pps)
+
+    # .copy(): jax zero-copy-aliases host numpy buffers and transfers
+    # lazily, while the engine goes on reserving and releasing — every
+    # dispatch gets a private snapshot of the table nobody mutates
+
+    def all_slots(self) -> tuple:
+        return (jnp.asarray(self._table.copy()),)
+
+    def at(self, slot: int, filled: int = 0, piece_len: int = 0):
+        where = (jnp.int32(slot), jnp.asarray(self._table[slot].copy()))
+        if piece_len:
+            # [filled, filled + piece_len) spans at most piece_len/ps + 1
+            # pages (all reserved); the touched count is static per
+            # (bucket, alignment) pair
+            ps = self.page_size
+            first, last = filled // ps, (filled + piece_len - 1) // ps
+            where += (jnp.asarray(self._table[slot, first: last + 1].copy()),
+                      jnp.int32(first * ps))
+        return where
+
+    def at_many(self, slots: np.ndarray):
+        rows = np.zeros((len(slots), self._table.shape[1]), np.int32)
+        real = slots < self.max_slots  # pad rows keep the trash page
+        rows[real] = self._table[slots[real]]
+        return jnp.asarray(slots), jnp.asarray(rows)
+
+    # -- traced primitives ----------------------------------------------------
+
+    @staticmethod
+    def slot_of(where):
+        return where[0]
+
+    def step(self, params, block, pool, offsets, table):
+        if self.fwd_paged is not None and block.shape[1] == 1:
+            # the family forward scatters this step's k/v into the pools
+            # and attends over them IN PLACE (single-token steps only)
+            return self.fwd_paged(params, block, kv_cache=pool,
+                                  cache_offset=offsets, table=table)
+        # gather every slot's pages into a dense [max_slots, max_len] view
+        # (a TRANSIENT the scheduler frees layer by layer — the persistent
+        # state is only the pool), run the family forward against it
+        # unchanged, then scatter the rows each slot wrote back into their
+        # pages
+        from modelx_tpu.ops.paged_attention import write_token_kv
+
+        dense = jax.tree_util.tree_map(
+            lambda p: p[table].reshape(self.max_slots, self.max_len, *p.shape[2:]),
+            pool)
+        logits, dense = self.fwd(params, block, kv_cache=dense, cache_offset=offsets)
+
+        def put_back(p, d):
+            for j in range(block.shape[1]):
+                rows = jax.vmap(
+                    lambda row, o: jax.lax.dynamic_slice_in_dim(row, o, 1, axis=0)
+                )(d, offsets + j)  # [slots, 1, ...] — the row each slot wrote
+                p = write_token_kv(p, rows, table, offsets + j)
+            return p
+
+        return logits, jax.tree_util.tree_map(put_back, pool, dense)
+
+    def put(self, pool, small, where):
+        # page by page: the scratch's length is static, so the write
+        # unrolls to ceil(length/page_size) dynamic_update_slices. The
+        # final block may be a partial page: the page's tail stays junk
+        # past every query position until decode overwrites it
+        ps, table_row = self.page_size, where[1]
+
+        def write(out, little):
+            for j in range(0, little.shape[1], ps):
+                blk = jax.lax.slice_in_dim(
+                    little, j, min(j + ps, little.shape[1]), axis=1)
+                out = jax.lax.dynamic_update_slice(
+                    out, blk, (table_row[j // ps],) + (0,) * (out.ndim - 1))
+            return out
+
+        return jax.tree_util.tree_map(write, pool, small)
+
+    def put_many(self, pool, small, where):
+        # same bucket means the same page count, so every page column
+        # scatters all rows at once
+        ps, rows = self.page_size, where[1]
+
+        def write(out, little):
+            for j in range(0, little.shape[1], ps):
+                w = min(j + ps, little.shape[1]) - j
+                blk = jax.lax.slice_in_dim(little, j, j + w, axis=1)
+                out = out.at[rows[:, j // ps], :w].set(blk)
+            return out
+
+        return jax.tree_util.tree_map(write, pool, small)
+
+    def view(self, pool, where, length: int):
+        # gather only the span's pages (``length`` is static, so the page
+        # count is too; unreserved entries point at trash)
+        n_pg = self._pages(length)
+        return jax.tree_util.tree_map(
+            lambda p: p[where[1][:n_pg]].reshape(
+                1, n_pg * self.page_size, *p.shape[2:])[:, :length],
+            pool)
+
+    def put_piece(self, pool, dense, where):
+        # write back ONLY the pages the piece touched: scattering the
+        # slot's whole max_len span per piece would pay ~max_len/piece x
+        # the useful copy traffic on exactly the long-context shapes
+        # chunked prefill targets
+        ps, (_slot, _row, touched, start) = self.page_size, where
+
+        def write(out, d):
+            for j in range(touched.shape[0]):
+                blk = jax.lax.dynamic_slice_in_dim(d, start + j * ps, ps, axis=1)
+                out = jax.lax.dynamic_update_slice(
+                    out, blk, (touched[j],) + (0,) * (out.ndim - 1))
+            return out
+
+        return jax.tree_util.tree_map(write, pool, dense)
+
+
+def replicated(mesh):
+    """The sharding of a small array every device holds whole."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    return NamedSharding(mesh, PartitionSpec())

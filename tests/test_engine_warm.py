@@ -54,18 +54,17 @@ def started(monkeypatch):
 
 @pytest.fixture
 def chunk_traces(monkeypatch):
-    """How often jax traced each chunk implementation (its python body runs
+    """How often jax traced the chunk implementation (its python body runs
     only while tracing)."""
     seen = []
-    for name in ("_chunk_impl", "_chunk_paged_impl"):
-        impl = getattr(ContinuousBatcher, name)
+    impl = ContinuousBatcher._chunk_impl
 
-        @functools.wraps(impl)
-        def counted(self, *args, _impl=impl, **kwargs):
-            seen.append(_impl.__name__)
-            return _impl(self, *args, **kwargs)
+    @functools.wraps(impl)
+    def counted(self, *args, **kwargs):
+        seen.append(impl.__name__)
+        return impl(self, *args, **kwargs)
 
-        monkeypatch.setattr(ContinuousBatcher, name, counted)
+    monkeypatch.setattr(ContinuousBatcher, "_chunk_impl", counted)
     return seen
 
 

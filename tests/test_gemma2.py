@@ -306,7 +306,7 @@ class TestServing:
         cb = ContinuousBatcher(server, max_slots=2, chunk_size=4, page_size=16,
                                paged_attention="in-place")
         try:
-            assert cb._fwd_paged is not None  # gemma2 wires the paged fwd
+            assert cb.kv.fwd_paged is not None  # gemma2 wires the paged fwd
             t = np.array([[5, 9, 2]], np.int32)
             # 28 new tokens: crosses page boundaries (ps 16) and decodes
             # past sliding_window 16, so the windowed layer's paged mask

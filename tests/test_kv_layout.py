@@ -1,0 +1,281 @@
+"""The KV layouts behind the continuous engine (dl/kv_layout.py).
+
+Every case runs on both layouts: what the engine may assume of a layout
+is what both give. References are independent of the code under test — a
+page count kept by hand, numpy slices, the family forward against a plain
+dense cache. The last class guards the benchmark's cells: they run
+``DenseKV``, whose programs must stay the ones their compile caches hold.
+"""
+
+import dataclasses
+import types
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from modelx_tpu.dl import kv_layout
+from modelx_tpu.dl import safetensors as st
+from modelx_tpu.dl.continuous import ContinuousBatcher
+from modelx_tpu.dl.families import FAMILIES
+from modelx_tpu.dl.serve import ModelServer
+from modelx_tpu.models import llama
+from modelx_tpu.parallel.mesh import make_mesh
+
+SLOTS, MAX_LEN, PAGE = 4, 64, 16
+LAYOUTS = ["dense", "paged"]
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(vocab_size=64), dtype=jnp.float32)
+    return cfg, llama.init_params(cfg, jax.random.PRNGKey(0))
+
+
+def build(model, layout, stats=None, mesh="dp=1", live_tokens=0):
+    cfg = model[0]
+    server = types.SimpleNamespace(
+        mesh=make_mesh(mesh, jax.devices()[: 4 if "tp" in mesh else 1]),
+        family=FAMILIES["llama"], cfg=cfg)
+    fwd, init_cache = server.family.decode_fns(cfg, mesh=server.mesh)
+    return kv_layout.build(
+        server, fwd, init_cache, {} if stats is None else stats,
+        max_slots=SLOTS, max_len=MAX_LEN, chunk_size=4,
+        page_size=PAGE if layout == "paged" else 0,
+        max_live_tokens=live_tokens, paged_attention="gather")
+
+
+def scratch(kv, seed, length, rows=1):
+    """A scratch cache of random values (what a prefill would leave)."""
+    leaves, tree = jax.tree_util.tree_flatten(kv.init_cache(rows, length))
+    rng = np.random.RandomState(seed)
+    return jax.tree_util.tree_unflatten(
+        tree, [jnp.asarray(rng.standard_normal(x.shape).astype(x.dtype)) for x in leaves])
+
+
+def assert_trees_equal(got, want):
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want),
+                    strict=True):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+class TestReservations:
+    def test_nothing_leaks_and_only_a_short_pool_refuses(self, model, layout):
+        """100 seeded admissions, growths and retirements against a page
+        count kept by hand: a dense reservation never fails, a paged one
+        fails exactly when the pool is short, and all comes back."""
+        stats = {}
+        kv = build(model, layout, stats, live_tokens=6 * PAGE)
+        pages_free = 6 if layout == "paged" else 10**9
+        held: dict[int, int] = {}  # slot -> pages it holds
+        rng = np.random.RandomState(31)
+        refused = 0
+        for _ in range(100):
+            slot = int(rng.randint(SLOTS))
+            if slot in held and rng.rand() < 0.5:
+                kv.release(slot)
+                pages_free += held.pop(slot)
+                continue
+            tokens = int(rng.randint(1, MAX_LEN + 1))
+            grow = max(0, -(-tokens // PAGE) - held.get(slot, 0))
+            if slot not in held:
+                assert kv.fits(tokens) == (grow <= pages_free)
+            ok = kv.reserve(slot, tokens)
+            assert ok == (grow <= pages_free), (slot, tokens, held, pages_free)
+            if ok:
+                held[slot] = held.get(slot, 0) + grow
+                pages_free -= grow
+            refused += not ok
+            if layout == "paged":
+                assert stats["pages_free"] == pages_free
+        assert refused == 0 if layout == "dense" else refused > 0
+        for slot in list(held):
+            kv.release(slot)
+        assert kv.fits(MAX_LEN) and kv.reserve(0, MAX_LEN)
+        kv.release(0)
+        if layout == "paged":
+            assert stats["pages_free"] == stats["pages_total"] == 6
+            assert not kv._row_pages and not kv._table.any()
+            assert sorted(kv._free_pages) == list(range(1, 7))  # never the trash page
+
+    def test_a_row_no_pool_could_hold_is_refused_by_name(self, model, layout):
+        kv = build(model, layout, live_tokens=2 * PAGE)
+        assert kv.never_holds(2 * PAGE) == ""
+        assert ("pages" in kv.never_holds(2 * PAGE + 1)) == (layout == "paged")
+
+    def test_reset_empties_every_slot(self, model, layout):
+        stats = {}
+        kv = build(model, layout, stats, live_tokens=4 * PAGE)
+        assert kv.reserve(1, 3 * PAGE)
+        kv.reset()
+        assert kv.reserve(2, 4 * PAGE)
+        if layout == "paged":
+            assert stats["pages_free"] == 0 and list(kv._row_pages) == [2]
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+class TestRoundTrips:
+    def test_put_then_view_is_bit_for_bit(self, model, layout):
+        """Write a scratch cache to a slot, snap it back; a second slot's
+        rows are not touched by it."""
+        kv = build(model, layout)
+        state = kv.new_state()
+        a, b = scratch(kv, 1, 32), scratch(kv, 2, 24)
+        assert kv.reserve(2, 32) and kv.reserve(0, 24)
+        state = kv.put(state, a, kv.at(2))
+        state = kv.put(state, b, kv.at(0))
+        assert_trees_equal(kv.view(state, kv.at(2), 32), a)
+        assert_trees_equal(kv.view(state, kv.at(0), 24), b)
+        assert_trees_equal(kv.view(state, kv.at(2), 16),
+                           jax.tree_util.tree_map(lambda x: x[:, :16], a))
+
+    def test_put_many_writes_each_row_to_its_slot_and_drops_pad_rows(self, model, layout):
+        kv = build(model, layout)
+        state = kv.new_state()
+        burst = scratch(kv, 3, 32, rows=4)
+        slots = np.array([3, 1, SLOTS, SLOTS], np.int32)  # two real rows, two pads
+        assert kv.reserve(3, 32) and kv.reserve(1, 32)
+        where = kv.at_many(slots)
+        state = kv.put_many(state, burst, where)
+        np.testing.assert_array_equal(np.asarray(kv.slot_of(where)), slots)
+        for row, slot in ((0, 3), (1, 1)):
+            assert_trees_equal(kv.view(state, kv.at(slot), 32),
+                               jax.tree_util.tree_map(lambda x: x[row: row + 1], burst))
+        assert kv.reserve(0, 32)  # a slot the burst did not name is still zeros
+        for leaf in jax.tree_util.tree_leaves(kv.view(state, kv.at(0), 32)):
+            assert not np.asarray(leaf).any()
+
+    @pytest.mark.parametrize("filled", [16, 24])  # page-aligned, and not
+    def test_a_piece_lands_in_the_rows_it_wrote(self, model, layout, filled):
+        kv = build(model, layout)
+        state = kv.new_state()
+        head, piece = scratch(kv, 4, filled), scratch(kv, 5, 16)
+        assert kv.reserve(1, filled)
+        state = kv.put(state, head, kv.at(1))
+        assert kv.reserve(1, filled + 16)
+        where = kv.at(1, filled, 16)
+        row = kv.view(state, where, MAX_LEN)
+        assert_trees_equal(jax.tree_util.tree_map(lambda x: x[:, :filled], row), head)
+        row = jax.tree_util.tree_map(
+            lambda r, p: r.at[:, filled: filled + 16].set(p), row, piece)
+        state = kv.put_piece(state, row, where)
+        want = jax.tree_util.tree_map(
+            lambda h, p: jnp.concatenate([h, p], axis=1), head, piece)
+        assert_trees_equal(kv.view(state, kv.at(1), filled + 16), want)
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_a_cached_step_equals_the_family_forward_on_a_dense_cache(model, layout):
+    """Two live slots at different depths, two idle: logits of the live
+    rows and the k/v they wrote equal a plain [slots, max_len] cache's."""
+    cfg, params = model
+    kv = build(model, layout)
+    fwd, init_cache = kv.fwd, kv.init_cache
+    state, ref = kv.new_state(), init_cache(SLOTS, MAX_LEN)
+    offsets = np.zeros(SLOTS, np.int32)
+    for slot, n in ((0, 5), (2, 19)):
+        prompt = np.zeros((1, 32), np.int32)
+        prompt[0, :n] = np.arange(1, n + 1) % 60 + 1
+        _, small = fwd(params, jnp.asarray(prompt), kv_cache=init_cache(1, 32), cache_offset=0)
+        assert kv.reserve(slot, 48)
+        state = kv.put(state, small, kv.at(slot))
+        ref = jax.tree_util.tree_map(lambda big, s: big.at[slot, :32].set(s[0]), ref, small)
+        offsets[slot] = n
+    tok = jnp.asarray([[7], [0], [9], [0]], jnp.int32)
+    for _ in range(2):  # the second step reads what the first wrote
+        logits, state = kv.step(params, tok, state, jnp.asarray(offsets), *kv.all_slots())
+        want, ref = fwd(params, tok, kv_cache=ref, cache_offset=jnp.asarray(offsets))
+        np.testing.assert_array_equal(np.asarray(logits)[[0, 2]], np.asarray(want)[[0, 2]])
+        offsets[[0, 2]] += 1
+    for slot in (0, 2):
+        assert_trees_equal(
+            kv.view(state, kv.at(slot), 32),
+            jax.tree_util.tree_map(lambda big: big[slot: slot + 1, :32], ref))
+
+
+@pytest.mark.parametrize("mesh", ["dp=1", "dp=2,tp=2"])
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_the_abstract_state_describes_the_allocated_one(model, layout, mesh):
+    """What ``chunk_warmer`` lowers against is what ``allocate_device_state``
+    makes: shapes and dtypes always, the placement where there is a mesh."""
+    kv = build(model, layout, mesh=mesh)
+    described, allocated = kv.abstract_state(), kv.new_state()
+    assert described == jax.tree_util.tree_map(
+        lambda x, d: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=d.sharding),
+        allocated, described)
+    assert jax.tree_util.tree_structure(described) == jax.tree_util.tree_structure(
+        jax.eval_shape(kv.new_state))
+    if kv.mesh.size > 1:
+        for d, x in zip(jax.tree_util.tree_leaves(described),
+                        jax.tree_util.tree_leaves(allocated), strict=True):
+            assert d.sharding.is_equivalent_to(x.sharding, x.ndim)
+    want_rows = 1 + -(-(MAX_LEN + 4 + PAGE) // PAGE) if layout == "paged" else SLOTS
+    assert {x.shape[0] for x in jax.tree_util.tree_leaves(described)} == {want_rows}
+
+
+# -- the cells' programs -------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def server(model, tmp_path_factory):
+    d = tmp_path_factory.mktemp("kv_layout")
+    st.write_safetensors(str(d / "model.safetensors"),
+                         {k: np.asarray(v) for k, v in model[1].items()})
+    srv = ModelServer(str(d), mesh_spec="dp=1", dtype="float32", max_seq_len=MAX_LEN)
+    srv.load()
+    return srv
+
+
+def walk(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs (pjit, scan,
+    while, cond) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from walk(sub)
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_the_dense_programs_take_no_table_and_gather_nothing_from_the_cache(server, layout):
+    """``_chunk_impl`` and ``_admit_nosmall`` keep their names, and on the
+    dense layout take exactly the arguments they took before there was a
+    layout and never index the cache through a table; the paged layout —
+    the proof that the walk sees such a thing — adds the table and the
+    gathers."""
+    cb = ContinuousBatcher(server, max_slots=SLOTS, chunk_size=4,
+                           page_size=PAGE if layout == "paged" else 0)
+    try:
+        state = (cb.server.params, cb._cache, cb._tok)
+        n_state = len(jax.tree_util.tree_leaves(state))
+        kv_shapes = {x.shape for x in jax.tree_util.tree_leaves(cb._cache)}
+        prompt = jnp.zeros((1, 16), jnp.int32)
+        one = lambda v, dt: jnp.asarray([v], dt)  # noqa: E731
+        programs = {
+            # offsets, steps, temp, seeds (no filters: top_k / top_p are None)
+            "_chunk_impl": (jax.make_jaxpr(
+                lambda *a: cb._chunk_jit(*a, n_steps=4))(*state, *cb._chunk_args(False)), 4),
+            # prompt, row_len, slot, temp, seed, first_step
+            "_admit_nosmall": (jax.make_jaxpr(cb._admit_prog)(
+                cb.server.params, prompt, cb._cache, cb._tok, one(5, jnp.int32),
+                cb.kv.at(1), one(0.0, jnp.float32), None, None,
+                one(0, jnp.int32), one(0, jnp.int32)), 6),
+        }
+        for name, (jaxpr, n_inputs) in programs.items():
+            (call,) = jaxpr.eqns  # the jitted program, under its name
+            assert call.params["name"] == name
+            extra = len(jaxpr.jaxpr.invars) - n_state - n_inputs
+            from_cache = [e for e in walk(jaxpr.jaxpr) if e.primitive.name == "gather"
+                          and e.invars[0].aval.shape in kv_shapes]
+            if layout == "dense":
+                assert extra == 0 and not from_cache, (name, extra, from_cache)
+            else:
+                assert extra == 1  # the table, or the slot's row of it
+                assert (name == "_chunk_impl") == bool(from_cache)
+    finally:
+        cb.close()

@@ -101,7 +101,7 @@ class TestPagedExactness:
             engine.generate(tokens, max_new_tokens=7, **sampled),
             server.generate(tokens, max_new_tokens=7, **sampled),
         )
-        assert engine.stats["pages_free"] == engine.num_pages - 1
+        assert engine.stats["pages_free"] == engine.kv.num_pages - 1
 
     def test_greedy_matches_plain(self, server, engine):
         tokens = np.array([[5, 9, 2, 7, 1]], np.int32)
@@ -199,7 +199,7 @@ class TestPagedPool:
             max_live_tokens=4 * 96 // 2,
         )
         try:
-            free0 = len(cb._free_pages)
+            free0 = len(cb.kv._free_pages)
             assert cb.stats["pages_free"] == free0
             for i in range(6):  # sequential requests reuse the same pages
                 t = np.array([[i + 1, i + 2, i + 3]], np.int32)
@@ -210,8 +210,8 @@ class TestPagedPool:
             deadline = time.monotonic() + 10
             while cb._rows and time.monotonic() < deadline:
                 time.sleep(0.01)
-            assert len(cb._free_pages) == free0, "pages leaked across retirements"
-            assert not cb._row_pages
+            assert len(cb.kv._free_pages) == free0, "pages leaked across retirements"
+            assert not cb.kv._row_pages
         finally:
             cb.close()
 
@@ -282,7 +282,7 @@ class TestPagedBatchedAdmission:
             deadline = time.monotonic() + 10
             while cb._rows and time.monotonic() < deadline:
                 time.sleep(0.01)
-            assert len(cb._free_pages) == cb.stats["pages_total"]
+            assert len(cb.kv._free_pages) == cb.stats["pages_total"]
         finally:
             cb.close()
 
@@ -408,7 +408,7 @@ class TestInPlaceFastPath:
         cb = ContinuousBatcher(server, max_slots=4, chunk_size=4, page_size=16,
                                paged_attention="in-place")
         try:
-            assert cb._fwd_paged is not None
+            assert cb.kv.fwd_paged is not None
             t = np.array([[5, 9, 2]], np.int32)
             np.testing.assert_array_equal(
                 cb.generate(t, max_new_tokens=20),
@@ -422,7 +422,7 @@ class TestInPlaceFastPath:
                                max_len=128, page_size=16,
                                paged_attention="in-place")
         try:
-            assert cb._fwd_paged is not None  # gpt2 wires the paged fwd too
+            assert cb.kv.fwd_paged is not None  # gpt2 wires the paged fwd too
             t = np.array([[7, 8, 9]], np.int32)
             np.testing.assert_array_equal(
                 cb.generate(t, max_new_tokens=8),
@@ -452,7 +452,7 @@ class TestMixtralInPlace:
         cb = ContinuousBatcher(srv, max_slots=4, chunk_size=4, page_size=16,
                                paged_attention="in-place")
         try:
-            assert cb._fwd_paged is not None
+            assert cb.kv.fwd_paged is not None
             t = np.array([[5, 9, 2]], np.int32)
             np.testing.assert_array_equal(
                 cb.generate(t, max_new_tokens=14),
@@ -588,6 +588,6 @@ class TestPagedChunkedPrefill:
                 np.concatenate([b, rows[1]], axis=1),
                 server.generate(b, max_new_tokens=8))
             assert cb.stats["fill_preempts"] >= 1
-            assert cb.stats["pages_free"] == cb.num_pages - 1
+            assert cb.stats["pages_free"] == cb.kv.num_pages - 1
         finally:
             cb.close()

@@ -211,7 +211,7 @@ class TestServing:
         cb = ContinuousBatcher(server, max_slots=2, chunk_size=4, page_size=16,
                                paged_attention="in-place")
         try:
-            assert cb._fwd_paged is not None
+            assert cb.kv.fwd_paged is not None
             t = np.array([[5, 9, 2]], np.int32)
             np.testing.assert_array_equal(
                 cb.generate(t, max_new_tokens=28),
