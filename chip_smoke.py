@@ -522,6 +522,10 @@ def phase_logprobs(port: int, ps: list[list[int]], greedy: list[list[int]]) -> l
     return lps
 
 
+# jax's persistent cache, and the engine's executable store beside it
+CACHE_KEYS = ("requests", "hits", "misses", "store_hits", "store_misses")
+
+
 def phase_restart(kids: Children, pod: subprocess.Popen, port: int, model_dir: str,
                   ps: list[list[int]], greedy: list[list[int]], fwd_ids: list[int],
                   fwd512: list[int]) -> tuple[subprocess.Popen, int]:
@@ -538,8 +542,8 @@ def phase_restart(kids: Children, pod: subprocess.Popen, port: int, model_dir: s
           f"the restart used another cache directory: {first} vs {second}")
     check(second.get("hits", 0) > 0, f"the restarted pod hit nothing in {second.get('dir')}: {second}")
     emit("restart", ready_seconds=round(ready_s, 1), cache_dir=second["dir"],
-         first_start={k: first.get(k) for k in ("requests", "hits", "misses")},
-         second_start={k: second.get(k) for k in ("requests", "hits", "misses")},
+         first_start={k: first.get(k) for k in CACHE_KEYS},
+         second_start={k: second.get(k) for k in CACHE_KEYS},
          second_start_compiled_anything=second.get("misses", 0) > 0,
          answers_equal_across_restart=True)
     return pod2, port2

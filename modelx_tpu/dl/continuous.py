@@ -89,6 +89,7 @@ them); this is the serving half of the BASELINE north star. Bench target:
 
 from __future__ import annotations
 
+import functools
 import logging
 import queue
 import threading
@@ -99,7 +100,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from modelx_tpu.dl import kv_layout
+from modelx_tpu.dl import aot_cache, kv_layout
 from modelx_tpu.dl.serving_errors import (
     DeadlineExceededError,
     EngineBrokenError,
@@ -417,6 +418,19 @@ class ContinuousBatcher:
         self._inflight_chunks = 0  # dispatched-but-unsynced chunk equivalents
         self._depth_last = 1
 
+        # every program below is called through the node's executable store
+        # (dl/aot_cache.py): a variant this node has run before is loaded by
+        # a key taken BEFORE tracing — everything the programs share is
+        # hashed here, once; a call adds its name, its static arguments and
+        # the shapes of the arguments that vary
+        from modelx_tpu.dl.serve import compile_cache_dir
+
+        program = functools.partial(aot_cache.StoredProgram, aot_cache.ExecutableStore(
+            compile_cache_dir(), self.mesh, server.family.name, repr(server.cfg),
+            aot_cache.describe_sds(
+                getattr(server, "_param_sds", None) or server.params),
+            self.kv.describe(), self.chunk_size, self.prefill_chunk,
+            self.speculative_k, prefix_cache is not None))
         # admission is ONE program (prefill + first token + insert-at-slot):
         # every call costs a host dispatch round-trip, so the two-call
         # prefill-then-insert shape would double admission latency.
@@ -426,51 +440,51 @@ class ContinuousBatcher:
             def _admit_nosmall(*args):
                 return self._admit_impl(*args)[:3]  # drop the scratch KV output
 
-            self._admit_prog = jax.jit(_admit_nosmall, donate_argnums=(2, 3))
+            admit = jax.jit(_admit_nosmall, donate_argnums=(2, 3))
         else:
-            self._admit_prog = jax.jit(self._admit_impl, donate_argnums=(2, 3))
+            admit = jax.jit(self._admit_impl, donate_argnums=(2, 3))
+        self._admit_prog = program("admit", admit, described=1)
         # prefix-hit variant: stored KV rides in as an argument (never
         # donated — the cache entry outlives the admission); trim_len is
         # static so stored entries stay bucketed to the PROMPT's bucket
         # (entries must not grow by a bucket per conversation turn)
-        self._admit_cached_prog = jax.jit(
+        self._admit_cached_prog = program("admit_cached", jax.jit(
             self._admit_cached_impl, static_argnums=(12,), donate_argnums=(2, 3),
-        )
+        ), static_argnums=(12,), described=1)
         # batched admission (same-bucket burst arrivals -> one program);
         # engaged only without a prefix cache — the cached path's per-row
         # scratch-KV returns would cost k x leaves slice dispatches, and
         # multi-turn conversations rarely arrive as same-instant bursts
-        self._admit_many_prog = jax.jit(
+        self._admit_many_prog = program("admit_many", jax.jit(
             self._admit_many_impl, donate_argnums=(2, 3),
-        )
+        ), described=1)
         # ONE chunk callable for every dispatch depth: n_steps is a STATIC
-        # argument (jit caches one compiled variant per depth actually
-        # used), so the fault-injection seam (tests/bench wrap self._chunk)
-        # and the env-gated chaos wrap below cover deep programs too
-        self._chunk_jit = jax.jit(
+        # argument (one compiled variant per depth actually used), so the
+        # fault-injection seam (tests/bench wrap self._chunk) and the
+        # env-gated chaos wrap below cover deep programs too. A load fetches
+        # the variant every first request runs ahead of it (chunk_warmer).
+        self._chunk_prog = program("chunk", jax.jit(
             self._chunk_impl, donate_argnums=(1, 2), static_argnames=("n_steps",),
-        )
-        # variants of that jit fetched AHEAD of their first dispatch
-        # (chunk_warmer: a load reads them back while the weights stream):
-        # (n_steps, filtered) -> Future of the compiled program, or of None
-        # where fetching it failed and the jit compiles it at first use
-        self._chunk_aot: dict = {}
-        self._chunk = self._run_chunk
+        ), described=1)
+        self._chunk = self._chunk_prog
         # chunked-prefill piece programs: a mid piece only advances the
         # slot's KV (no logits output -> XLA drops the lm_head matmul);
         # the flip (last) piece also samples the row's first token.
         # Compiled once per piece bucket, like every other prompt shape.
-        self._piece_prog = jax.jit(self._piece_impl, donate_argnums=(2,))
-        self._piece_flip_prog = jax.jit(
+        self._piece_prog = program("piece", jax.jit(
+            self._piece_impl, donate_argnums=(2,)), described=1)
+        self._piece_flip_prog = program("piece_flip", jax.jit(
             self._piece_flip_impl, donate_argnums=(2, 3),
-        )
+        ), described=1)
         # prefix-hit fill seeding: copy a stored prefix KV into the slot
         # so only the suffix chunk-prefills (stored entry never donated —
         # it outlives the admission)
-        self._seed_prog = jax.jit(self._seed_impl, donate_argnums=(0,))
+        self._seed_prog = program("seed", jax.jit(
+            self._seed_impl, donate_argnums=(0,)))
         # flip-time prefix store: slice the freshly filled prompt KV back
         # out of the slot (a copy — the live row decodes on)
-        self._snap_prog = jax.jit(self._snap_impl, static_argnums=(2,))
+        self._snap_prog = program("snap", jax.jit(
+            self._snap_impl, static_argnums=(2,)), static_argnums=(2,))
         # chunks the loop keeps in flight before syncing the oldest: plans
         # are value-independent (budgets only), so depth-D dispatch is
         # exact; it hides the per-chunk fetch round-trip behind device
@@ -494,7 +508,8 @@ class ContinuousBatcher:
         # engine, wait this long for co-arrivals before admitting (burst ->
         # one admit program + aligned decode depths). 0 disables.
         self.burst_window_ms = float(burst_window_ms)
-        self._spec_prog = jax.jit(self._spec_verify_impl, donate_argnums=(1,))
+        self._spec_prog = program("spec_verify", jax.jit(
+            self._spec_verify_impl, donate_argnums=(1,)), described=1)
 
         self._q: "queue.Queue" = queue.Queue()
         # FIFO admission backlog: items popped from the queue while no slot
@@ -846,64 +861,24 @@ class ContinuousBatcher:
             jnp.asarray(self._seeds.copy()),
         ]
 
-    def _run_chunk(self, params, cache, tok, *args, n_steps):
-        """What ``self._chunk`` is bound to: the variant a load fetched
-        ahead (``chunk_warmer``) where there is one, else the jit. A
-        dispatch that comes while the side thread still holds its variant
-        waits for that one future — the same program is never compiled
-        twice."""
-        key = (n_steps, args[-2] is not None)
-        warm = self._chunk_aot.get(key)
-        if warm is not None:
-            compiled = warm.result()
-            if compiled is not None:
-                try:
-                    return compiled(params, cache, tok, *args)
-                except (TypeError, ValueError) as e:
-                    # the loader delivered arrays the abstract params did
-                    # not describe: raised before anything ran or was donated
-                    logging.getLogger("modelx.serve").warning(
-                        "warmed chunk program refused its arguments, "
-                        "compiling at first use: %s", e)
-                    del self._chunk_aot[key]
-        return self._chunk_jit(params, cache, tok, *args, n_steps=n_steps)
-
     def chunk_warmer(self, param_sds: dict):
         """Reserve the chunk program every first request runs — one chunk
         deep, no filters: its shapes are ``max_slots``, ``max_len`` and
         ``chunk_size``, nothing a request brings — and return the work that
-        fetches it, for a side thread of the load: trace, lower and compile
-        (on a node that kept its compile cache, read back) from the abstract
-        weights and the abstract state of this engine — which need not be
-        allocated yet — described as a first dispatch meets it, so the
-        lowered module, and with it the persistent cache's key, is the one
-        that dispatch would produce. The work returns how many programs it
-        delivered."""
-        from concurrent.futures import Future
-
+        fetches it, for a side thread of the load: from the node's program
+        store, else traced, lowered and compiled, from the abstract weights
+        and the abstract state of this engine — which need not be allocated
+        yet — described as a first dispatch meets it, so that its key, and
+        the persistent cache's, is the one that dispatch would produce. A
+        dispatch that comes while the side thread still holds the program
+        waits for it. The work returns how many programs it delivered."""
         # a dispatch meets the engine's state as the admit program returned
         # it — committed to the mesh — not as jnp.zeros left it
-        cache = self.kv.abstract_state()
         tok = jax.ShapeDtypeStruct((self.max_slots, 1), jnp.int32,
                                    sharding=kv_layout.replicated(self.mesh))
-        n_steps = self.chunk_size
-        fut = self._chunk_aot[(n_steps, False)] = Future()
-        args = (param_sds, cache, tok, *self._chunk_args(False))
-
-        def fetch() -> int:
-            try:
-                fut.set_result(
-                    self._chunk_jit.lower(*args, n_steps=n_steps).compile())
-                return 1
-            except Exception as e:  # only the warm start is lost
-                logging.getLogger("modelx.serve").warning(
-                    "chunk warm-up failed (cold first request): %s", e)
-                return 0
-            finally:
-                if not fut.done():  # a waiting dispatch must never hang
-                    fut.set_result(None)
-
-        return fetch
+        return self._chunk_prog.prefetch(
+            param_sds, self.kv.abstract_state(), tok, *self._chunk_args(False),
+            n_steps=self.chunk_size)
 
     # -- speculative verify (single-occupied greedy slot) ---------------------
 
@@ -2682,8 +2657,7 @@ class ContinuousBatcher:
             raise RuntimeError("release_device_state requires close() first")
         self._cache = None
         self._tok = None
-        self._chunk_aot.clear()
         for attr in ("_admit_prog", "_admit_cached_prog", "_admit_many_prog",
-                     "_chunk", "_chunk_jit", "_piece_prog", "_piece_flip_prog",
+                     "_chunk", "_chunk_prog", "_piece_prog", "_piece_flip_prog",
                      "_seed_prog", "_snap_prog", "_spec_prog"):
             setattr(self, attr, None)

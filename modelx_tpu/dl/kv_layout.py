@@ -100,6 +100,11 @@ class DenseKV:
                 sharding=self.sharding(x.shape) or replicated(self.mesh)),
             jax.eval_shape(self._zeros))
 
+    def describe(self) -> tuple:
+        """What of this layout shapes the engine's programs: key material
+        for the node's executable store (dl/aot_cache.py)."""
+        return (type(self).__name__, self.max_slots, self.max_len)
+
     # -- host bookkeeping -----------------------------------------------------
 
     def reset(self) -> None:
@@ -218,6 +223,10 @@ class PagedKV(DenseKV):
         stats["pages_total"] = self.num_pages - 1  # excl. trash
         stats["paged_attention"] = "gather" if self.fwd_paged is None else "in-place"
         self.reset()
+
+    def describe(self) -> tuple:
+        return (*super().describe(), self.page_size, self.num_pages,
+                self.fwd_paged is not None)
 
     def _zeros(self):
         return jax.tree_util.tree_map(

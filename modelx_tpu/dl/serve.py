@@ -259,13 +259,19 @@ def compile_cache_dir() -> str:
 
 def compile_cache_stats() -> dict:
     """``{"dir", "requests", "hits", "misses", "programs", "trace_s",
-    "lower_s", "backend_compile_s", "retrieval_s"}`` for /metrics: whether a
-    restart found its programs, and what each cost, is read here, not
-    inferred from timing."""
+    "lower_s", "backend_compile_s", "retrieval_s"}`` — what went through
+    jax — and ``{"store_hits", "store_misses", "store_load_s",
+    "store_write_s", "store_bytes"}`` — what the engine's executable store
+    (dl/aot_cache.py) served without it — for /metrics: whether a restart
+    found its programs, and what each cost, is read here, not inferred from
+    timing."""
+    from modelx_tpu.dl import aot_cache
+
     with _cache_counts_lock:
         return {"dir": _compile_cache_dir,
                 **{k: round(v, 6) if isinstance(v, float) else v
-                   for k, v in _cache_counts.items()}}
+                   for k, v in _cache_counts.items()},
+                **aot_cache.store_stats()}
 
 
 def cold_cache_dir(leg: str) -> str:
@@ -533,7 +539,7 @@ class ModelServer:
                 with trace.span("compile_join"):
                     compile_thread.join()
             # else: the chunk program finishes behind the first admit — a
-            # dispatch waits for it (ContinuousBatcher._run_chunk) — because
+            # dispatch waits for it (aot_cache.StoredProgram) — because
             # beside the load its read-back outlasts the load by seconds that
             # ready would otherwise wait for, and the admit program's own
             # read-back covers them (PERF.md, PR 26)

@@ -1,0 +1,492 @@
+"""The engine's programs are loaded by a key taken before tracing (ISSUE 32):
+a node-local store of loaded executables under ``<compile cache>/programs/``
+(dl/aot_cache.ExecutableStore), reached through the one wrapper every jitted
+program of the continuous engine is called through (StoredProgram). On the
+CPU backend: tokens, counts, keys and files; never a time."""
+
+import copy
+import dataclasses
+import functools
+import json
+import logging
+import os
+import subprocess
+import sys
+import threading
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from modelx_tpu.dl import aot_cache
+from modelx_tpu.dl import safetensors as st
+from modelx_tpu.dl import serve as serve_mod
+from modelx_tpu.dl.continuous import ContinuousBatcher
+from modelx_tpu.dl.serve import ModelServer
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PROMPT = np.array([[5, 9, 2, 7, 11]], np.int32)
+BURST = np.array([[5, 9, 2, 7, 11], [3, 1, 4, 1, 5], [2, 7, 1, 8, 2]], np.int32)
+SAMPLED = dict(temperature=0.8, top_k=8, top_p=0.9, seed=3)
+ENGINE = dict(max_slots=4, chunk_size=4)
+IMPLS = [name for name in vars(ContinuousBatcher) if name.endswith("_impl")]
+
+
+@pytest.fixture(scope="module")
+def model_dir(tmp_path_factory):
+    from modelx_tpu.models import llama
+
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(vocab_size=64), dtype=jnp.float32)
+    params = {k: np.asarray(v) for k, v in
+              llama.init_params(cfg, jax.random.PRNGKey(0)).items()}
+    d = tmp_path_factory.mktemp("program_store")
+    st.write_safetensors(str(d / "model.safetensors"), params)
+    return str(d)
+
+
+@pytest.fixture(scope="module")
+def server(model_dir):
+    server = ModelServer(model_dir, mesh_spec="dp=1", dtype="float32", max_seq_len=96)
+    server.load()
+    return server
+
+
+@pytest.fixture
+def node(tmp_path, monkeypatch):
+    """A node's compile cache directory, as the engine learns of it. jax's
+    own persistent cache is off: what is found again was in the store, and
+    on the CPU the store takes no executable that cache served."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(serve_mod, "_compile_cache_dir", str(tmp_path))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield str(tmp_path)
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def engine_traces(monkeypatch):
+    """Which engine programs jax traced (a program's python body runs only
+    while tracing)."""
+    seen = []
+    for name in IMPLS:
+        impl = getattr(ContinuousBatcher, name)
+
+        @functools.wraps(impl)
+        def counted(self, *args, _impl=impl, **kwargs):
+            seen.append(_impl.__name__)
+            return _impl(self, *args, **kwargs)
+
+        monkeypatch.setattr(ContinuousBatcher, name, counted)
+    return seen
+
+
+def entries(node: str) -> list[str]:
+    try:
+        return sorted(os.listdir(os.path.join(node, "programs")))
+    except FileNotFoundError:
+        return []
+
+
+def growth(before: dict) -> dict:
+    after = aot_cache.store_stats()
+    return {k: after[k] - before[k] for k in after}
+
+
+def chunk_digest(cb, param_sds=None, n_steps=None, filtered=False) -> str:
+    """The store key of a chunk variant, from abstract arguments as
+    ``chunk_warmer`` describes them."""
+    prog = cb._chunk_prog
+    tok = jax.ShapeDtypeStruct((cb.max_slots, 1), jnp.int32)
+    key, _ = prog._key((param_sds or cb.server._param_sds, cb.kv.abstract_state(), tok,
+                        *cb._chunk_args(filtered)), {"n_steps": n_steps or cb.chunk_size})
+    return prog._digest(key)
+
+
+def unallocated(server, **kw) -> ContinuousBatcher:
+    return ContinuousBatcher(server, **{**ENGINE, **kw}, allocate=False)
+
+
+class TestWarmStart:
+    @pytest.mark.parametrize("layout", ["dense", "paged"])
+    @pytest.mark.parametrize("samp", [{}, SAMPLED], ids=["greedy", "sampled"])
+    @pytest.mark.parametrize("prompts", [PROMPT, BURST], ids=["single", "batched"])
+    @pytest.mark.parametrize("new_tokens", [6, 40], ids=["one_depth", "depth_ladder"])
+    def test_a_second_engine_loads_what_the_first_built_and_traces_nothing(
+            self, server, node, engine_traces, layout, samp, prompts, new_tokens):
+        args = dict(ENGINE, page_size=16 if layout == "paged" else 0)
+        first = ContinuousBatcher(server, **args)
+        try:
+            want = first.generate(prompts, max_new_tokens=new_tokens, **samp)
+        finally:
+            first.close()
+        built, stored = list(engine_traces), entries(node)
+        assert built and len(stored) == len(built)  # each variant traced once, written once
+        before = aot_cache.store_stats()
+        second = ContinuousBatcher(server, **args)  # a new pod's engine: its memo is empty
+        try:
+            got = second.generate(prompts, max_new_tokens=new_tokens, **samp)
+        finally:
+            second.close()
+        np.testing.assert_array_equal(got, want)
+        assert engine_traces == built, "a program this node had run before was traced again"
+        moved = growth(before)
+        assert moved["store_hits"] == len(stored) and moved["store_misses"] == 0
+        assert moved["store_bytes"] == sum(
+            os.path.getsize(os.path.join(node, "programs", e)) for e in stored)
+        assert entries(node) == stored
+
+    def test_prefix_cache_prefill_chunk_and_speculation_go_through_the_store(
+            self, server, node, engine_traces):
+        """The programs no benchmark cell runs: cached admit, seed, snap,
+        piece, flip and the speculative verify step."""
+        from modelx_tpu.models.decode import PrefixKVCache
+
+        long = np.arange(1, 41, dtype=np.int32)[None, :] % 60 + 1
+        turn2 = np.concatenate([long, np.array([[7, 3, 9]], np.int32)], axis=1)
+
+        def serve():
+            outs = []
+            for kw in (dict(prefix_cache=PrefixKVCache(8)),
+                       dict(prefix_cache=PrefixKVCache(8), prefill_chunk=16),
+                       dict(speculative_k=3)):
+                cb = ContinuousBatcher(server, **ENGINE, **kw)
+                try:
+                    outs += [cb.generate(long, max_new_tokens=5),
+                             cb.generate(turn2, max_new_tokens=5)]
+                finally:
+                    cb.close()
+            return outs
+
+        want = serve()
+        built = list(engine_traces)
+        assert {"_admit_cached_impl", "_piece_impl", "_piece_flip_impl", "_snap_impl",
+                "_spec_verify_impl"} <= set(built)
+        before = aot_cache.store_stats()
+        for got, ref in zip(serve(), want):
+            np.testing.assert_array_equal(got, ref)
+        assert engine_traces == built
+        assert growth(before)["store_misses"] == 0
+
+    def test_the_donated_state_is_consumed_by_a_stored_program(self, server, node):
+        for _ in range(2):  # the second engine's programs come from the store
+            cb = ContinuousBatcher(server, **ENGINE)
+            try:
+                state = [*jax.tree_util.tree_leaves(cb._cache), cb._tok]
+                before = aot_cache.store_stats()
+                cb.generate(PROMPT, max_new_tokens=6)
+            finally:
+                cb.close()
+            assert all(x.is_deleted() for x in state)
+        assert growth(before)["store_hits"] >= 2
+
+    def test_without_a_compile_cache_there_is_no_store(self, server, tmp_path, monkeypatch):
+        monkeypatch.setattr(serve_mod, "_compile_cache_dir", "")
+        monkeypatch.chdir(tmp_path)
+        before = aot_cache.store_stats()
+        cb = ContinuousBatcher(server, **ENGINE)
+        try:
+            out = cb.generate(PROMPT, max_new_tokens=6)
+            assert cb._chunk_prog.store.dir == ""
+        finally:
+            cb.close()
+        np.testing.assert_array_equal(out, server.generate(PROMPT, max_new_tokens=6))
+        assert not any(growth(before).values())
+        assert not any("programs" in dirs for _, dirs, _ in os.walk(tmp_path))
+
+
+class TestKey:
+    def test_everything_that_shapes_a_program_changes_its_key(self, server, monkeypatch):
+        """A miss, never a stale hit: each of these differs from the base in
+        one thing, and all keys differ from one another."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        sds = server._param_sds
+        name = "model.layers.0.mlp.up_proj.weight"
+        mesh2 = jax.make_mesh((2,), ("tp",), devices=jax.devices()[:2]) \
+            if len(jax.devices()) >= 2 else None
+        other = copy.copy(server)
+        other.cfg = dataclasses.replace(server.cfg, rope_theta=10000.0)
+        engines = {"base": unallocated(server),
+                   "max_slots": unallocated(server, max_slots=2),
+                   "max_len": unallocated(server, max_len=64),
+                   "chunk_size": unallocated(server, chunk_size=8),
+                   "paged": unallocated(server, page_size=16),
+                   "prefix_cache": unallocated(server, prefix_cache=object()),
+                   "cfg": unallocated(other)}
+        try:
+            base = engines["base"]
+            keys = {k: chunk_digest(cb, n_steps=8) for k, cb in engines.items()}
+            keys["n_steps"] = chunk_digest(base, n_steps=16)
+            keys["filters"] = chunk_digest(base, n_steps=8, filtered=True)
+            for what, leaf in (
+                    ("param_dtype", jax.ShapeDtypeStruct(sds[name].shape, jnp.bfloat16)),
+                    ("param_sharding", mesh2 and jax.ShapeDtypeStruct(
+                        sds[name].shape, sds[name].dtype,
+                        sharding=NamedSharding(mesh2, PartitionSpec("tp"))))):
+                if leaf is None:
+                    continue
+                changed = copy.copy(server)
+                changed._param_sds = {**sds, name: leaf}
+                engines[what] = unallocated(changed)
+                keys[what] = chunk_digest(engines[what], n_steps=8)
+            monkeypatch.setattr(aot_cache, "_code_version", "another source tree")
+            engines["source"] = unallocated(server)
+            keys["source"] = chunk_digest(engines["source"], n_steps=8)
+            monkeypatch.undo()
+            engines["again"] = unallocated(server)
+            assert chunk_digest(engines["again"], n_steps=8) == keys["base"]
+            assert len(set(keys.values())) == len(keys), keys
+            # two programs of one engine never share an entry
+            assert base._admit_prog.name != base._admit_many_prog.name
+        finally:
+            for cb in engines.values():
+                cb.close()
+
+    def test_a_committed_argument_and_one_jax_may_place_are_two_variants(self, server):
+        """As for jax.jit: an array committed to a sharding lowers with it."""
+        cb = unallocated(server)
+        try:
+            placed = jnp.zeros((cb.max_slots, 1), jnp.int32)
+            committed = jax.device_put(placed, jax.devices()[0])
+            keys = [cb._chunk_prog._key((None, None, tok), {"n_steps": 4})[0]
+                    for tok in (placed, committed,
+                                jax.ShapeDtypeStruct(placed.shape, placed.dtype),
+                                jax.ShapeDtypeStruct(placed.shape, placed.dtype,
+                                                     sharding=committed.sharding))]
+            digests = [cb._chunk_prog._digest(k) for k in keys]
+            assert digests[0] == digests[2] != digests[1] == digests[3]
+        finally:
+            cb.close()
+
+    def test_the_key_is_equal_in_two_interpreters(self, model_dir):
+        code = (
+            "import json, sys\n"
+            "import jax, jax.numpy as jnp\n"
+            "from modelx_tpu.dl.continuous import ContinuousBatcher\n"
+            "from modelx_tpu.dl.serve import ModelServer\n"
+            "server = ModelServer(sys.argv[1], mesh_spec='dp=2,tp=2', dtype='float32',\n"
+            "                     max_seq_len=96)\n"
+            "server.load()\n"
+            "cb = ContinuousBatcher(server, max_slots=4, chunk_size=4)\n"
+            "seen = {}\n"
+            "for prog in (cb._chunk_prog, cb._admit_prog):\n"
+            "    digest = prog._digest\n"
+            "    prog._digest = lambda key, d=digest, n=prog.name: seen.setdefault(n, d(key))\n"
+            "cb.generate(jnp.array([[5, 9, 2, 7, 11]]), max_new_tokens=3)\n"
+            "cb.close()\n"
+            "print(json.dumps(seen))\n")
+        outs = []
+        for hash_seed in ("1", "2"):
+            env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONHASHSEED=hash_seed,
+                       PYTHONPATH=ROOT,
+                       XLA_FLAGS="--xla_force_host_platform_device_count=8")
+            p = subprocess.run([sys.executable, "-c", code, model_dir], env=env,
+                               capture_output=True, text=True, timeout=300)
+            assert p.returncode == 0, p.stderr[-2000:]
+            outs.append(json.loads(p.stdout.strip().splitlines()[-1]))
+        assert outs[0] == outs[1] and set(outs[0]) == {"chunk", "admit"}
+
+
+class TestNeverLoadBearing:
+    @pytest.mark.parametrize("damage", ["truncated", "garbage"])
+    def test_a_damaged_entry_is_rebuilt_logged_and_replaced(
+            self, server, node, engine_traces, caplog, damage):
+        cb = ContinuousBatcher(server, **ENGINE)
+        try:
+            want = cb.generate(PROMPT, max_new_tokens=6)
+        finally:
+            cb.close()
+        built, stored = len(engine_traces), entries(node)
+        for e in stored:
+            path = os.path.join(node, "programs", e)
+            with open(path, "r+b") as f:
+                if damage == "truncated":
+                    f.truncate(os.path.getsize(path) // 2)
+                else:
+                    f.write(b"\x00not a pickle" * 8)
+        before = aot_cache.store_stats()
+        with caplog.at_level(logging.WARNING, logger="modelx.aot"):
+            cb = ContinuousBatcher(server, **ENGINE)
+            try:
+                got = cb.generate(PROMPT, max_new_tokens=6)
+            finally:
+                cb.close()
+        np.testing.assert_array_equal(got, want)
+        assert len(engine_traces) == 2 * built  # every program was built again
+        assert growth(before)["store_misses"] == len(stored)
+        assert sum("unusable" in r.getMessage() for r in caplog.records) == len(stored)
+        assert entries(node) == stored  # ... and whole again: the next pod loads them
+        before = aot_cache.store_stats()
+        cb = ContinuousBatcher(server, **ENGINE)
+        try:
+            np.testing.assert_array_equal(cb.generate(PROMPT, max_new_tokens=6), want)
+        finally:
+            cb.close()
+        assert growth(before)["store_hits"] == len(stored)
+
+    def test_a_stored_program_that_refuses_its_arguments_falls_back_undonated(
+            self, server, node, engine_traces, caplog):
+        """The weights the loader delivered are not the ones the abstract
+        description promised: the stored executable refuses them before it
+        runs or donates, its entry goes, and the jit serves the request."""
+        described = copy.copy(server)
+        described._param_sds = {k: jax.ShapeDtypeStruct(v.shape, jnp.bfloat16)
+                                for k, v in server._param_sds.items()}
+        cb = ContinuousBatcher(described, **ENGINE)
+        try:
+            assert cb.chunk_warmer(described._param_sds)() == 1  # written as described
+            stored = entries(node)
+            assert len(stored) == 1 and stored[0].startswith("chunk-")
+        finally:
+            cb.close()
+        cb = ContinuousBatcher(described, **ENGINE)
+        try:
+            state, seen = [*jax.tree_util.tree_leaves(cb._cache)], []
+            jit = cb._chunk_prog.jit
+            cb._chunk_prog.jit = lambda *a, **kw: (
+                seen.append([x.is_deleted() for x in jax.tree_util.tree_leaves(a[1])]),
+                jit(*a, **kw))[1]
+            before = aot_cache.store_stats()
+            with caplog.at_level(logging.WARNING, logger="modelx.aot"):
+                out = cb.generate(PROMPT, max_new_tokens=3)
+        finally:
+            cb.close()
+        np.testing.assert_array_equal(out, server.generate(PROMPT, max_new_tokens=3))
+        assert growth(before)["store_hits"] == 1  # it was loaded, then refused
+        assert seen and not any(seen[0]), "the refused call had donated the cache"
+        assert any("refused its arguments" in r.getMessage() for r in caplog.records)
+        assert not [e for e in entries(node) if e.startswith("chunk-")]
+        assert all(x.is_deleted() for x in state)  # ... and the jit's call did donate
+
+    def test_two_threads_writing_one_key_leave_one_valid_file(self, server, node):
+        cb = unallocated(server)
+        try:
+            store = cb._chunk_prog.store
+        finally:
+            cb.close()
+        double = jax.jit(lambda x: x * 2)
+        x = jnp.arange(8.0)
+        compiled = double.lower(x).compile()
+        start = threading.Barrier(16)
+
+        def write():
+            start.wait(10)
+            for _ in range(20):
+                store.save("double", "k" * 32, compiled)
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=write, daemon=True) for _ in range(16)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(120)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(interval)
+        assert entries(node) == ["double-" + "k" * 32 + ".bin"]  # and no temp file
+        loaded = store.load("double", "k" * 32)
+        np.testing.assert_array_equal(loaded(x), np.arange(8.0) * 2)
+
+    def test_a_call_that_comes_first_waits_for_the_prefetch(self, server, node, engine_traces):
+        cb = ContinuousBatcher(server, **ENGINE)
+        try:
+            fetch = cb.chunk_warmer(server._param_sds)
+            assert cb.chunk_warmer(server._param_sds)() == 0  # reserved once
+            result = {}
+            t = threading.Thread(
+                target=lambda: result.update(out=cb.generate(PROMPT, max_new_tokens=3)),
+                daemon=True)
+            t.start()
+            t.join(2.0)
+            assert t.is_alive() and "_chunk_impl" not in engine_traces
+            assert fetch() == 1
+            t.join(60)
+            assert not t.is_alive() and engine_traces.count("_chunk_impl") == 1
+        finally:
+            cb.close()
+
+
+class TestMetrics:
+    def test_metrics_compile_cache_reports_the_store(self, node):
+        stats = serve_mod.compile_cache_stats()
+        assert {"store_hits", "store_misses", "store_load_s", "store_write_s",
+                "store_bytes"} <= set(stats)
+        assert {"requests", "hits", "misses", "trace_s", "programs"} <= set(stats)
+
+    def test_loads_and_builds_are_spans_that_name_the_program(self, server, node):
+        from modelx_tpu.utils import trace
+
+        def count(name):
+            return sum(v["count"] for k, v in trace.tracer().summary().items()
+                       if k.split("/")[-1] == name)
+
+        loads, builds = count("programs.load"), count("programs.build")
+        for _ in range(2):
+            cb = ContinuousBatcher(server, **ENGINE)
+            try:
+                cb.generate(PROMPT, max_new_tokens=3)
+            finally:
+                cb.close()
+        n = len(entries(node))
+        assert count("programs.build") - builds == n
+        assert count("programs.load") - loads == n
+
+
+class TestLayerMetricFiles:
+    """The three per-layer metrics this PR adds are data for a reader the
+    benchmark already had: on a pod's /metrics dumps they read the store's
+    counters, and on a parent's, which has none, they say nothing."""
+
+    NAMES = ("cache.store_hit_share.decode", "cache.store_load_s_per_program.decode",
+             "cache.store_load_s_per_program")
+
+    @staticmethod
+    def read(name, sources):
+        import importlib
+
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            spec = json.load(f)
+        reader = importlib.import_module(f"benchmark.layer_metrics.readers.{spec['reader']}")
+        return reader.read(sources, spec)
+
+    @pytest.mark.parametrize("name, want", [
+        ("cache.store_hit_share.decode", 0.96),
+        ("cache.store_load_s_per_program.decode", 0.5),
+        ("cache.store_load_s_per_program", 0.25),
+    ])
+    def test_reads_the_pods_dumps(self, name, want):
+        warm = {"compile_cache": {"store_hits": 48, "store_misses": 2, "store_load_s": 24.0}}
+        sources = {"metrics_before": warm, "trace_span": {
+            "metrics_before": {"compile_cache": {"store_hits": 1, "store_misses": 0,
+                                                 "store_load_s": 9.0}},
+            "metrics_after": {"compile_cache": {"store_hits": 3, "store_misses": 0,
+                                                "store_load_s": 9.5}}}}
+        assert self.read(name, sources) == pytest.approx(want)
+
+    @pytest.mark.parametrize("name", NAMES)
+    def test_a_parent_without_the_counters_reads_nothing(self, name):
+        parent = {"compile_cache": {"requests": 48, "hits": 48, "trace_s": 12.0}}
+        sources = {"metrics_before": parent, "metrics_after": parent,
+                   "trace_span": {"metrics_before": parent, "metrics_after": parent}}
+        assert self.read(name, sources) is None
+        assert self.read(name, {}) is None
+
+    def test_benchmark_json_lists_them_at_the_end_for_their_cells(self):
+        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+            per_layer = json.load(f)["per_layer"]
+        assert tuple(m["name"] for m in per_layer[-3:]) == self.NAMES
+        share, load_decode, load_deploy = per_layer[-3:]
+        assert all(m["layer"] == "Compile caches" and m["source"] == "program_counter"
+                   for m in per_layer[-3:])
+        assert (share["moves"], share["better"], share["unit"]) == ("setup_s", "higher", "ratio")
+        assert (load_decode["moves"], load_decode["better"]) == ("setup_s", "lower")
+        assert share["workloads"] == load_decode["workloads"] == ["mixtral-8x7b-d4.decode"]
+        assert (load_deploy["moves"], load_deploy["workloads"]) == (
+            "pod_ttft_s", ["phi3-mini-4k.deploy"])

@@ -75,10 +75,10 @@ LAYOUTS = {"dense": ({}, {}),
            "mesh": ({"mesh_spec": "dp=2,tp=2"}, {"max_slots": 4})}
 
 
-def new_set(model_dir, continuous=True, layout="dense"):
+def new_set(model_dir, continuous=True, layout="dense", max_seq_len=96):
     server_args, set_args = LAYOUTS[layout]
     server = ModelServer(model_dir, **{"mesh_spec": "dp=1", **server_args},
-                         dtype="float32", max_seq_len=96, name="m")
+                         dtype="float32", max_seq_len=max_seq_len, name="m")
     return server, ServerSet({"m": server}, continuous_batch=continuous,
                              **{"max_slots": 2, **set_args}, stream_chunk_size=4)
 
@@ -106,7 +106,7 @@ class TestEngineBuiltByTheLoad:
             sset.load_all()
             cb = sset.cbatchers.get("m")
             assert cb is not None and sset.continuous_for(server) is cb
-            assert (cb.chunk_size, False) in cb._chunk_aot  # reserved before the weights moved
+            assert len(cb._chunk_prog._fetched) >= 1  # reserved before the weights moved
             snap = warmed(started)
             assert snap["engine_warm_programs"] == 1
             assert snap["engine_warm_s"] > 0 and snap["engine_init_s"] > 0
@@ -193,7 +193,7 @@ class TestEngineBuiltByTheLoad:
 
         def spy(*args, **kwargs):
             cb = sset.cbatchers.get("m")
-            seen.append((cb is not None, cb._cache, cb._tok, dict(cb._chunk_aot)))
+            seen.append((cb is not None, cb._cache, cb._tok, dict(cb._chunk_prog._fetched)))
             return real(*args, **kwargs)
 
         monkeypatch.setattr(loader, "load_safetensors", spy)
@@ -231,25 +231,44 @@ class TestPersistentCacheKey:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
 
     @pytest.mark.parametrize("layout", LAYOUTS)
-    def test_a_node_that_kept_its_cache_misses_nothing(self, model_dir, tmp_path, layout):
-        """The program the load fetches is, to the persistent cache, the one
-        a lazily built engine's first dispatch compiles: what an older pod
-        left in the cache is found, and nothing is compiled for a new key."""
+    @pytest.mark.parametrize("kept", ["programs", "jax_cache_only"])
+    def test_a_node_that_kept_its_cache_misses_nothing(self, model_dir, tmp_path, layout, kept):
+        """The program the load fetches is, to the node's executable store and
+        to jax's persistent cache, the one a lazily built engine's first
+        dispatch compiles: what an older pod left is found — in the store,
+        before any tracing; where only jax's cache was kept, there, as
+        before ISSUE 32 — and nothing is compiled for a new key."""
+        import shutil
+
         from modelx_tpu.dl import serve as serve_mod
 
         serve_mod.enable_compile_cache(str(tmp_path / "node"))
-        lazy_server, lazy = new_set(model_dir, layout=layout)
-        warm_server, warm = new_set(model_dir, layout=layout)
+        # programs of their own: an earlier test's side thread, still compiling,
+        # would file the same module in this directory, and on the CPU the store
+        # takes no executable that jax's cache served
+        lazy_server, lazy = new_set(model_dir, layout=layout, max_seq_len=80)
+        warm_server, warm = new_set(model_dir, layout=layout, max_seq_len=80)
         try:
             lazy_server.load()
             want = lazy.continuous_for(lazy_server).generate(PROMPT, max_new_tokens=6)
+            stored = len(os.listdir(tmp_path / "node" / "programs"))
+            assert stored >= 2  # the admit and the chunk (over a mesh, two of it)
+            if kept == "jax_cache_only":
+                shutil.rmtree(tmp_path / "node" / "programs")
             before = serve_mod.compile_cache_stats()
             warm.load_all()
             got = warm.continuous_for(warm_server).generate(PROMPT, max_new_tokens=6)
             after = serve_mod.compile_cache_stats()
             np.testing.assert_array_equal(got, want)
             assert after["misses"] == before["misses"]
-            assert after["hits"] > before["hits"]
+            if kept == "programs":
+                assert after["store_hits"] - before["store_hits"] == stored
+                assert after["store_misses"] == before["store_misses"]
+            else:
+                assert after["hits"] - before["hits"] >= stored
+                assert after["store_misses"] - before["store_misses"] == stored
+                # XLA:CPU cannot serialize an executable it deserialized
+                assert not os.path.isdir(tmp_path / "node" / "programs")
         finally:
             close(lazy)
             close(warm)
@@ -284,7 +303,7 @@ class TestChunkWarmer:
 
     def test_a_failed_fetch_falls_back_to_the_jit(self, chunk_traces, engine):
         fetch = engine.chunk_warmer(engine.server._param_sds)
-        jit = engine._chunk_jit
+        jit = engine._chunk_prog.jit
 
         class Refusing:
             def lower(self, *args, **kwargs):
@@ -293,7 +312,7 @@ class TestChunkWarmer:
             def __call__(self, *args, **kwargs):
                 return jit(*args, **kwargs)
 
-        engine._chunk_jit = Refusing()
+        engine._chunk_prog.jit = Refusing()
         assert fetch() == 0
         out = engine.generate(PROMPT, max_new_tokens=6)
         np.testing.assert_array_equal(out, engine.server.generate(PROMPT, max_new_tokens=6))
@@ -305,7 +324,7 @@ class TestChunkWarmer:
         assert engine.chunk_warmer(sds)() == 1
         out = engine.generate(PROMPT, max_new_tokens=6)
         np.testing.assert_array_equal(out, engine.server.generate(PROMPT, max_new_tokens=6))
-        assert not engine._chunk_aot and len(chunk_traces) == 2
+        assert None in engine._chunk_prog._memo.values() and len(chunk_traces) == 2
 
     def test_fault_injection_still_intercepts_dispatches_after_a_warm_start(
             self, model_dir, started):

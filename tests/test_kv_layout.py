@@ -259,9 +259,9 @@ def test_the_dense_programs_take_no_table_and_gather_nothing_from_the_cache(serv
         programs = {
             # offsets, steps, temp, seeds (no filters: top_k / top_p are None)
             "_chunk_impl": (jax.make_jaxpr(
-                lambda *a: cb._chunk_jit(*a, n_steps=4))(*state, *cb._chunk_args(False)), 4),
+                lambda *a: cb._chunk_prog.jit(*a, n_steps=4))(*state, *cb._chunk_args(False)), 4),
             # prompt, row_len, slot, temp, seed, first_step
-            "_admit_nosmall": (jax.make_jaxpr(cb._admit_prog)(
+            "_admit_nosmall": (jax.make_jaxpr(cb._admit_prog.jit)(
                 cb.server.params, prompt, cb._cache, cb._tok, one(5, jnp.int32),
                 cb.kv.at(1), one(0.0, jnp.float32), None, None,
                 one(0, jnp.int32), one(0, jnp.int32)), 6),
