@@ -262,6 +262,39 @@ class _Fill:
         self.fp = fp
 
 
+class _ChunkJit:
+    """The chunk program as ``aot_cache.StoredProgram`` calls it — ``lower``
+    and ``__call__``, ``n_steps`` a keyword — with one ``jax.jit`` a static
+    depth, each named for it: the XLA module of a depth-4 dispatch is
+    ``jit__chunk_impl_d4``, so a device trace says by itself how many decode
+    steps each run of the program made (runs x chunk_size x depth)."""
+
+    def __init__(self, impl, chunk_size: int) -> None:
+        self._impl, self._chunk_size = impl, chunk_size
+        self._jits: dict[int, object] = {}
+
+    def _jit(self, n_steps: int | None):
+        n_steps = n_steps or self._chunk_size
+        jit = self._jits.get(n_steps)
+        if jit is None:
+            impl = self._impl
+
+            def chunk(*args):
+                return impl(*args, n_steps=n_steps)
+
+            depth, rest = divmod(n_steps, self._chunk_size)
+            chunk.__name__ = chunk.__qualname__ = (
+                f"_chunk_impl_d{depth}" if not rest else f"_chunk_impl_s{n_steps}")
+            jit = self._jits[n_steps] = jax.jit(chunk, donate_argnums=(1, 2))
+        return jit
+
+    def lower(self, *args, n_steps: int | None = None):
+        return self._jit(n_steps).lower(*args)
+
+    def __call__(self, *args, n_steps: int | None = None):
+        return self._jit(n_steps)(*args)
+
+
 class ContinuousBatcher:
     """Iteration-level scheduler over a fixed slot array.
 
@@ -361,7 +394,9 @@ class ContinuousBatcher:
             server, self._fwd, self._init_cache, self.stats,
             max_slots=self.max_slots, max_len=self.max_len,
             chunk_size=self.chunk_size, page_size=int(page_size),
-            max_live_tokens=max_live_tokens, paged_attention=paged_attention)
+            max_live_tokens=max_live_tokens, paged_attention=paged_attention,
+            prefix_cache=prefix_cache, prefill_chunk=self.prefill_chunk,
+            speculative_k=self.speculative_k)
         # allocate=False leaves the device alone: an engine built by a load
         # while the weights still stream gets its arrays from
         # ``allocate_device_state`` once they are placed
@@ -463,9 +498,8 @@ class ContinuousBatcher:
         # fault-injection seam (tests/bench wrap self._chunk) and the
         # env-gated chaos wrap below cover deep programs too. A load fetches
         # the variant every first request runs ahead of it (chunk_warmer).
-        self._chunk_prog = program("chunk", jax.jit(
-            self._chunk_impl, donate_argnums=(1, 2), static_argnames=("n_steps",),
-        ), described=1)
+        self._chunk_prog = program("chunk", _ChunkJit(
+            self._chunk_impl, self.chunk_size), described=1)
         self._chunk = self._chunk_prog
         # chunked-prefill piece programs: a mid piece only advances the
         # slot's KV (no logits output -> XLA drops the lm_head matmul);
@@ -840,7 +874,7 @@ class ContinuousBatcher:
             step_fn, (cache, tok, offsets, steps),
             jnp.arange(n_steps or self.chunk_size),
         )
-        return cache, tok, jnp.concatenate([toks.T, tok], axis=1)
+        return cache, tok, self.kv.ride(cache, jnp.concatenate([toks.T, tok], axis=1))
 
     def _chunk_args(self, filtered: bool) -> list:
         """The per-slot inputs of one chunk dispatch, after params, cache
@@ -1762,6 +1796,7 @@ class ContinuousBatcher:
         toks = np.asarray(toks_dev)
         wait_s = time.monotonic() - t0
         self._phases.to(_P_FANOUT)
+        self.kv.landed(toks)  # what the layout sent home below the slots' rows
         self._sync_wait_s += wait_s
         self._boundary_syncs += 1
         self._inflight_chunks = max(0, self._inflight_chunks - depth)

@@ -29,6 +29,7 @@ from modelx_tpu.dl.sharding import (
     BERT_RULES,
     GEMMA2_RULES,
     GPT2_RULES,
+    LAGUNA_RULES,
     PHI3_RULES,
     LLAMA_RULES,
     MIXTRAL_RULES,
@@ -57,6 +58,17 @@ class Family:
     # continuous engine's fast paged chunk path; None = the engine falls
     # back to its generic dense-gather chunk for this family
     paged_decode_fns: Callable[..., Callable] | None = None
+    # (sidecar config.json dict, abstract params) -> cfg, for a family whose
+    # architecture leaves no trace in tensor shapes (layer kinds, per-layer
+    # head counts, rope parameters, top-k, the experts held of those
+    # published): ``infer_config`` then only says so
+    config_from_sidecar: Callable[[dict, dict], Any] | None = None
+    # (cfg, mesh) -> {"fwd": forward over a cache PER LAYER KIND,
+    # "init_state": (slots, max_len) -> that state, "kinds": leaf -> "full" /
+    # "window" / "counter", "counters", "gauges"} — the continuous engine
+    # then keeps full layers [slots, max_len] and window layers as rings
+    # (dl/kv_layout.LayerKindKV); None = every layer's cache is alike
+    layer_kind_decode_fns: Callable[..., dict] | None = None
 
 
 def _shape(params: dict, name: str) -> tuple[int, ...]:
@@ -238,6 +250,78 @@ def _mixtral_paged_decode_fns(cfg, mesh=None):
         )
 
     return fwd
+
+
+# -- laguna -------------------------------------------------------------------
+
+
+def infer_laguna_config(params: dict):
+    raise ValueError(
+        "a laguna checkpoint's layer kinds, per-layer head counts, rope "
+        "parameters, top-k and expert share leave no trace in tensor shapes: "
+        "its config.json must lie beside the weights")
+
+
+def laguna_config_from_sidecar(sidecar: dict, params: dict):
+    from modelx_tpu.models import laguna
+
+    return laguna.config_from_hf(
+        sidecar, dtype=_act_dtype(params, "model.embed_tokens.weight"))
+
+
+def _laguna_forward(params, tokens, cfg, mesh=None):
+    from modelx_tpu.models import laguna
+
+    return laguna.forward(params, tokens, cfg, mesh=mesh)[0]
+
+
+def _laguna_generate(params, tokens, cfg, mesh=None, max_new_tokens=16):
+    from modelx_tpu.models import laguna
+
+    return laguna.greedy_generate(params, tokens, cfg, max_new_tokens=max_new_tokens, mesh=mesh)
+
+
+def _laguna_generate_ragged(params, tokens, row_lens, cfg, mesh=None,
+                            max_new_tokens=16, **sampling):
+    from modelx_tpu.models import laguna
+
+    return laguna.ragged_greedy_generate(
+        params, tokens, row_lens, cfg, max_new_tokens=max_new_tokens, mesh=mesh,
+        **sampling,
+    )
+
+
+def _laguna_decode_fns(cfg, mesh=None):
+    from modelx_tpu.models import laguna
+
+    def fwd(p, t, kv_cache, cache_offset, mesh=mesh):
+        return laguna.forward(
+            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh
+        )
+
+    return fwd, (lambda b, max_len: laguna.init_kv_cache(cfg, b, max_len))
+
+
+def _laguna_layer_kind_decode_fns(cfg, mesh=None):
+    from modelx_tpu.models import laguna
+
+    def fwd(p, t, kv_cache, cache_offset, mesh=mesh):
+        return laguna.forward(
+            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh,
+            ring=True,
+        )
+
+    return {
+        "fwd": fwd,
+        "init_state": lambda slots, max_len: laguna.init_layer_state(cfg, slots, max_len),
+        "kinds": laguna.cache_kinds(cfg),
+        # what the decode step counts of its expert layers, over ALL slots
+        # (idle ones route too), and what those counts are shares of
+        "counters": {"moe_counts": ("moe", laguna.MOE_COUNTERS)},
+        "gauges": {"moe": {"held_experts": cfg.expert_count,
+                           "published_experts": cfg.num_experts,
+                           "sparse_layers": cfg.mlp_layer_types.count("sparse")}},
+    }
 
 
 # -- gpt2 ---------------------------------------------------------------------
@@ -528,6 +612,10 @@ FAMILIES: dict[str, Family] = {
     "mixtral": Family("mixtral", MIXTRAL_RULES, infer_mixtral_config, _mixtral_forward,
                       _mixtral_generate, _mixtral_generate_ragged, _mixtral_decode_fns,
                       _mixtral_paged_decode_fns),
+    "laguna": Family("laguna", LAGUNA_RULES, infer_laguna_config, _laguna_forward,
+                     _laguna_generate, _laguna_generate_ragged, _laguna_decode_fns,
+                     config_from_sidecar=laguna_config_from_sidecar,
+                     layer_kind_decode_fns=_laguna_layer_kind_decode_fns),
     "gpt2": Family("gpt2", GPT2_RULES, infer_gpt2_config, _gpt2_forward,
                    _gpt2_generate, _gpt2_generate_ragged, _gpt2_decode_fns,
                    _gpt2_paged_decode_fns),
@@ -556,6 +644,22 @@ def sidecar_config(model_dir: str) -> dict | None:
 # the original context window — those warn (degraded long-context) but
 # keep previously-deployable checkpoints loadable.
 _ROPE_SCALING_REFUSED = ("longrope", "su", "yarn")
+
+
+def config_for(family: Family, params: dict, model_dir: str):
+    """The config of the checkpoint under ``model_dir``: from tensor shapes,
+    reconciled with the pulled ``config.json`` (rope_theta overrides apply;
+    unimplemented rope_scaling refuses before the weights stream) — or,
+    for a family whose shapes cannot say, from ``config.json`` alone."""
+    sidecar = sidecar_config(model_dir)
+    if family.config_from_sidecar is not None:
+        if sidecar is None:
+            family.infer_config(params)  # raises, saying why
+        return family.config_from_sidecar(sidecar, params)
+    cfg = family.infer_config(params)
+    if sidecar is not None:
+        cfg = apply_sidecar_config(cfg, sidecar, family.name)
+    return cfg
 
 
 def apply_sidecar_config(cfg, sidecar: dict, family_name: str):

@@ -1,7 +1,7 @@
 """How the continuous engine's KV state lies on the device.
 
 ``dl/continuous.py`` schedules: slots, admissions, fills, dispatches. Where
-a slot's keys and values live is decided here, behind two classes with one
+a slot's keys and values live is decided here, behind three classes with one
 interface, and the engine never asks which it got:
 
 - ``DenseKV``: one ``[max_slots, max_len]`` cache per leaf; a slot's rows
@@ -14,6 +14,13 @@ interface, and the engine never asks which it got:
   rows' writes land harmlessly and their reads sit beyond the causal
   horizon (the dense engine's idle-row trick, relocated). The table is a
   traced input, never a shape: one program serves every page assignment.
+- ``LayerKindKV``: a cache per layer KIND, for a family whose layers differ
+  (``Family.layer_kind_decode_fns``): full-attention layers keep
+  ``[max_slots, max_len]``, sliding-window layers a RING of the window plus
+  one 16-token bucket a slot, written at ``position mod ring`` and masked by
+  absolute position — so HBM holds what each kind can ever attend to, not
+  ``max_len`` for all. What a ring cannot give (a view of an overwritten
+  prefix) is refused when the engine is built, with a message.
 
 Three kinds of method. Device state (``new_state``, ``abstract_state``).
 Host bookkeeping (``fits``/``reserve``/``release``/``never_holds``/``reset``)
@@ -36,14 +43,26 @@ import jax.numpy as jnp
 import numpy as np
 
 
+class Refused(ValueError):
+    """An engine option this family's layout cannot serve. Raised when the
+    engine is built — at a pod's start-up, where it ends the load — never
+    swallowed into a lazy retry, never silently wrong."""
+
+
 def build(server, fwd, init_cache, stats: dict, *, max_slots: int,
           max_len: int, chunk_size: int, page_size: int, max_live_tokens: int,
-          paged_attention: str):
+          paged_attention: str, prefix_cache=None, prefill_chunk: int = 0,
+          speculative_k: int = 0):
     """The layout the engine's arguments ask for. ``fwd`` / ``init_cache``
     are the family's cached forward and scratch-cache constructor; ``stats``
     is the engine's counter dict (the pool reports its occupancy there)."""
     if paged_attention not in ("gather", "in-place"):
         raise ValueError(f"unknown paged_attention mode {paged_attention!r}")
+    if server.family.layer_kind_decode_fns is not None:
+        LayerKindKV.refuse(server.family.name, page_size=page_size,
+                           prefix_cache=prefix_cache, prefill_chunk=prefill_chunk,
+                           speculative_k=speculative_k)
+        return LayerKindKV(server, fwd, init_cache, stats, max_slots, max_len)
     if page_size <= 0:
         return DenseKV(fwd, init_cache, server.mesh, max_slots, max_len)
     return PagedKV(server, fwd, init_cache, stats, max_slots, max_len,
@@ -130,6 +149,10 @@ class DenseKV:
     def count_sweep(self, offsets: np.ndarray, n_steps: int) -> None:
         """Account one decode dispatch over rows at ``offsets``."""
 
+    def landed(self, toks: np.ndarray) -> None:
+        """A chunk's token block has reached the host (what ``ride`` added
+        to it is read here; nothing, for this layout)."""
+
     def all_slots(self) -> tuple:
         return ()
 
@@ -151,6 +174,12 @@ class DenseKV:
     def step(self, params, block, cache, offsets):
         """One cached forward over ALL slots, each row at its own offset."""
         return self.fwd(params, block, kv_cache=cache, cache_offset=offsets)
+
+    def ride(self, cache, block):
+        """What of the state goes home with a chunk's token block
+        ``[max_slots, n + 1]`` — rows appended below the slots', so that
+        device-side counters cost no host sync of their own. Nothing here."""
+        return block
 
     def put(self, cache, small, where):
         """Write a scratch cache to the front of one slot."""
@@ -400,6 +429,159 @@ class PagedKV(DenseKV):
             return out
 
         return jax.tree_util.tree_map(write, pool, dense)
+
+
+class LayerKindKV(DenseKV):
+    """A cache per layer kind. The family's ``layer_kind_decode_fns`` gives
+    the forward over it, its constructor and each leaf's kind: ``"full"``
+    leaves are ``[max_slots, max_len]`` as in ``DenseKV``; ``"window"``
+    leaves are rings ``[max_slots, ring]``, position p at index ``p mod
+    ring``; a ``"counter"`` leaf is a small vector the decode step adds to,
+    which goes home with each chunk's tokens (``ride`` / ``landed``).
+
+    A scratch cache (an admission's prefill) is dense for every layer; what
+    of it survives on a window layer is its last ``ring`` positions, rolled
+    to their ring indices. The ring is one 16-bucket longer than the window,
+    so the bucket's padding past the real prompt displaces only positions no
+    later query can see, and the mask by absolute position hides the padding
+    itself until decode overwrites it (models/laguna.ring_len).
+
+    Every slot's rows are its own, so a reservation always succeeds; what is
+    counted is what the live reservations hold, by kind."""
+
+    REFUSED = {
+        "page_size": "--kv-page-size (a ring is not paged)",
+        "prefix_cache": "--prefix-cache, and with it the KV store's bundles and resume "
+                        "from stored KV (a ring cannot give back a prefix it has overwritten)",
+        "prefill_chunk": "--prefill-chunk (a piece needs the slot's earlier rows as a dense "
+                         "view)",
+        "speculative_k": "--speculative-k (a verify block writes several ring positions a "
+                         "step)",
+    }
+
+    @classmethod
+    def refuse(cls, family: str, **asked) -> None:
+        """Raise ``Refused`` naming every engine option in ``asked`` that is
+        set and that this layout does not carry."""
+        bad = [cls.REFUSED[k] for k, v in asked.items() if v]
+        if bad:
+            raise Refused(
+                f"family {family} keeps a cache per layer kind (full layers whole, "
+                f"window layers as rings), which does not carry: {'; '.join(bad)}")
+
+    def __init__(self, server, fwd, init_cache, stats: dict, max_slots: int,
+                 max_len: int) -> None:
+        super().__init__(fwd, init_cache, server.mesh, max_slots, max_len)
+        fns = server.family.layer_kind_decode_fns(server.cfg, mesh=server.mesh)
+        self.fwd_kinds, self.init_state, self.kinds = fns["fwd"], fns["init_state"], fns["kinds"]
+        # counter leaf -> (the stats block it feeds, its entries' names)
+        self.counters: dict[str, tuple[str, tuple]] = fns.get("counters", {})
+        shapes = jax.eval_shape(self._zeros)
+        rings = {shapes[n].shape[1] for n, kind in self.kinds.items() if kind == "window"}
+        self.ring = rings.pop() if rings else max_len
+
+        def bytes_of(kind: str) -> int:
+            return sum(int(np.prod(shapes[n].shape)) * shapes[n].dtype.itemsize
+                       for n, k in self.kinds.items() if k == kind)
+
+        self.stats = stats
+        stats["kv"] = {"bytes_full": bytes_of("full"), "bytes_window": bytes_of("window"),
+                       "window_positions": self.ring, "positions_full": 0,
+                       "positions_window": 0}
+        for block, gauges in fns.get("gauges", {}).items():
+            stats[block] = dict(gauges)
+        self._last: dict[str, np.ndarray] = {}
+        self.reset()
+
+    def describe(self) -> tuple:
+        return (*super().describe(), self.ring, tuple(sorted(self.kinds.items())))
+
+    def _zeros(self):
+        return self.init_state(self.max_slots, self.max_len)
+
+    def sharding(self, shape):
+        if len(shape) < 3:  # a counter leaf: every device holds it whole
+            return None if self.mesh.size <= 1 else replicated(self.mesh)
+        return super().sharding(shape)
+
+    # -- host bookkeeping -----------------------------------------------------
+
+    def reset(self) -> None:
+        self._held: dict[int, int] = {}  # slot -> positions reserved
+        self._count()
+
+    def _count(self) -> None:
+        kv = self.stats["kv"]
+        kv["positions_full"] = sum(self._held.values())
+        kv["positions_window"] = sum(min(t, self.ring) for t in self._held.values())
+
+    def fits(self, tokens: int) -> bool:
+        return tokens <= self.max_len
+
+    def reserve(self, slot: int, tokens: int) -> bool:
+        if tokens > self.max_len:
+            return False
+        self._held[slot] = max(tokens, self._held.get(slot, 0))
+        self._count()
+        return True
+
+    def release(self, slot: int) -> None:
+        self._held.pop(slot, None)
+        self._count()
+
+    def landed(self, toks: np.ndarray) -> None:
+        # the counters wrap at 32 bits on the device: what is added here is
+        # each one's growth since the block before, taken modulo 2**32
+        row = self.max_slots
+        for leaf, (block, names) in self.counters.items():
+            now = toks[row: row + len(names), 0].astype(np.uint32)
+            grown = now - self._last.get(leaf, np.zeros(len(names), np.uint32))
+            self._last[leaf] = now
+            row += len(names)
+            into = self.stats.setdefault(block, {})
+            for key, value in zip(names, grown):
+                into[key] = into.get(key, 0) + int(value)
+
+    # -- traced primitives ----------------------------------------------------
+
+    def step(self, params, block, cache, offsets):
+        return self.fwd_kinds(params, block, kv_cache=cache, cache_offset=offsets)
+
+    def ride(self, cache, block):
+        rows = [jnp.broadcast_to(cache[leaf][:, None], (len(names), block.shape[1]))
+                for leaf, (_, names) in self.counters.items()]
+        return jnp.concatenate([block, *rows], axis=0)
+
+    def _ring_rows(self, little):
+        """The part of a dense scratch leaf ``[k, S, ...]`` a ring keeps, laid
+        out by ring index: all of it when it fits, else its last ``ring``
+        positions, position p rolled to ``p mod ring``."""
+        s, r = little.shape[1], self.ring
+        if s <= r:
+            return little
+        return jnp.roll(little[:, s - r:], (s - r) % r, axis=1)
+
+    def _merge(self, cache, small, write):
+        out = dict(cache)
+        for name, little in small.items():
+            if self.kinds.get(name) == "window":
+                little = self._ring_rows(little)
+            out[name] = write(cache[name], little)
+        return out
+
+    def put(self, cache, small, where):
+        return self._merge(cache, small, lambda big, little: jax.lax.dynamic_update_slice(
+            big, little, (where,) + (0,) * (big.ndim - 1)))
+
+    def put_many(self, cache, small, where):
+        return self._merge(cache, small, lambda big, lit: big.at[
+            where, : lit.shape[1]].set(lit, mode="drop"))
+
+    def view(self, cache, where, length: int):
+        raise Refused("a cache per layer kind gives no dense view of a slot "
+                      "(prefix cache, chunked prefill)")
+
+    put_piece = view
 
 
 def replicated(mesh):

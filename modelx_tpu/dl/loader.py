@@ -576,6 +576,11 @@ def fuse_expert_tensors(
     When ``rules`` are given, a group is fused only if the rules address the
     fused name *more specifically* than the per-expert names — so shard-spec
     annotations written against the on-disk HF names keep working untouched.
+
+    A checkpoint may hold only a share of a layer's experts (experts
+    ``first .. first + count`` of those its router publishes): any unbroken
+    run of indices folds, in index order, and the stacked tensor's leading
+    axis counts the experts held.
     """
     groups: dict[str, dict[int, st.TensorInfo]] = {}
     out: dict[str, st.TensorInfo] = {}
@@ -588,7 +593,9 @@ def fuse_expert_tensors(
     for key, members in groups.items():
         idxs = sorted(members)
         first = members[idxs[0]]
-        uniform = idxs == list(range(len(idxs))) and all(
+        # one unbroken run of expert indices — from 0 for a whole checkpoint,
+        # from anywhere for one that holds a share of the published experts
+        uniform = idxs == list(range(idxs[0], idxs[0] + len(idxs))) and all(
             m.shape == first.shape and m.dtype == first.dtype for m in members.values()
         )
         if rules is not None and uniform:

@@ -41,6 +41,7 @@ see :func:`enable_compile_cache`) so a sidecar restart skips recompilation
 
 from __future__ import annotations
 
+import base64
 import glob
 import itertools
 import json
@@ -57,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from modelx_tpu.dl import families as fam
+from modelx_tpu.dl import kv_layout
 from modelx_tpu.dl.serving_errors import (
     ATTEMPT_HEADER,
     DEADLINE_HEADER,
@@ -450,17 +452,11 @@ class ModelServer:
 
             infos_all = fuse_expert_tensors(infos_all, self.family.rules)
             if self.cfg is None:
-                self.cfg = self.family.infer_config(
-                    fam.abstract_params(infos_all)
-                )
-                # reconcile with the pulled config.json sidecar: rope_theta
+                # reconciled with the pulled config.json sidecar: rope_theta
                 # overrides apply; unimplemented rope_scaling (phi-3-*-128k
                 # longrope etc.) refuses BEFORE the weights stream to HBM
-                sidecar = fam.sidecar_config(self.model_dir)
-                if sidecar is not None:
-                    self.cfg = fam.apply_sidecar_config(
-                        self.cfg, sidecar, self.family.name
-                    )
+                self.cfg = fam.config_for(
+                    self.family, fam.abstract_params(infos_all), self.model_dir)
             # quantized included: abstract_params mirrors the loader's int8
             # transform (QTensor pytrees of structs), so int8 deploys overlap
             # load and compile like bf16 ones
@@ -658,6 +654,15 @@ class ModelServer:
                 return np.asarray(aot(self.params, jnp.asarray(tokens, jnp.int32)))
             out = self._forward(self.params, jnp.asarray(tokens, jnp.int32))
             return np.asarray(jnp.argmax(out, axis=-1))
+
+    def forward_logits(self, tokens: np.ndarray, positions: np.ndarray):
+        """One cache-less forward, read twice: the per-position argmax that
+        ``forward_argmax`` gives, and the float32 logits [B, len(positions),
+        V] at ``positions``, where a reference is to be held against them."""
+        with trace.span("serve.forward_logits", model=self.name, batch=int(tokens.shape[0])):
+            out = self._forward(self.params, jnp.asarray(tokens, jnp.int32))
+            return (np.asarray(jnp.argmax(out, axis=-1)),
+                    np.asarray(out[:, jnp.asarray(positions), :].astype(jnp.float32)))
 
     def generate(
         self,
@@ -1299,7 +1304,7 @@ class ServerSet:
 
     def __init__(self, servers: dict[str, ModelServer], default: str | None = None,
                  trace_dir: str = "", dynamic_batch: bool = False,
-                 max_new_tokens_limit: int = DEFAULT_MAX_NEW_TOKENS_LIMIT,
+                 max_new_tokens_limit: int | None = None,
                  continuous_batch: bool = False, max_slots: int = 8,
                  max_batch: int = 32, batch_window_ms: float = 3.0,
                  stream_chunk_size: int = 8, kv_page_size: int = 0,
@@ -1327,6 +1332,17 @@ class ServerSet:
                  device_telemetry: bool = True) -> None:
         if not servers:
             raise ValueError("no models")
+        if max_new_tokens_limit is None:
+            # the cap bounds KV memory and, on the plain paths, one compiled
+            # decode program per distinct value. The continuous engine
+            # compiles nothing per value and holds every request to its
+            # slot's span (prompt bucket + max_new_tokens + one chunk <=
+            # max_seq_len, ``_validate``), so there the span is the bound:
+            # a reasoning job may ask for thousands of tokens
+            max_new_tokens_limit = DEFAULT_MAX_NEW_TOKENS_LIMIT
+            if continuous_batch:
+                max_new_tokens_limit = max(
+                    max_new_tokens_limit, *(s.max_seq_len for s in servers.values()))
         self.max_new_tokens_limit = max_new_tokens_limit
         self.servers = servers
         # the model set is MUTABLE at runtime (dl/lifecycle.py admin
@@ -1575,6 +1591,8 @@ class ServerSet:
             if allocate:
                 cb.allocate_device_state()
             return cb
+        except kv_layout.Refused:
+            raise  # an option this family's cache cannot serve ends the load
         except Exception as e:
             logger.warning("engine for %s not ready at load (left to the "
                            "first request): %s", server.name, e)
@@ -2607,9 +2625,25 @@ def serve(servers: ModelServer | ServerSet, listen: str = ":8000",
                                       headers=e.headers())
             try:
                 if verb == "forward":
-                    batcher = sset.batcher_for(server)
-                    out = (batcher or server).forward_argmax(tokens)
-                    self._json(200, {"logits_argmax": out.tolist()})
+                    if req.get("logits_at") is None:
+                        batcher = sset.batcher_for(server)
+                        out = (batcher or server).forward_argmax(tokens)
+                        self._json(200, {"logits_argmax": out.tolist()})
+                    else:
+                        # optional: also the float32 logits of the named
+                        # positions, so that the served forward can be held
+                        # against a reference (raw little-endian, base64)
+                        try:
+                            at = np.asarray(req["logits_at"], np.int32).reshape(-1)
+                            if at.size < 1 or at.min() < 0 or at.max() >= tokens.shape[1]:
+                                raise ValueError(f"positions must lie in [0, {tokens.shape[1]})")
+                        except (ValueError, TypeError, OverflowError) as e:
+                            return self._json(400, {"error": f"bad logits_at: {e}"})
+                        out, logits = server.forward_logits(tokens, at)
+                        self._json(200, {"logits_argmax": out.tolist(), "logits": {
+                            "dtype": "float32", "shape": list(logits.shape),
+                            "positions": at.tolist(),
+                            "b64": base64.b64encode(logits.tobytes()).decode("ascii")}})
                 else:
                     try:
                         n = int(req.get("max_new_tokens", 16))

@@ -9,6 +9,7 @@ Multi-tenant:      modelx-serve --model a=/mnt/a --model b=/mnt/b
 from __future__ import annotations
 
 import logging
+import os
 import signal
 import threading
 import time
@@ -18,6 +19,28 @@ import jax
 
 from modelx_tpu.dl.serve import ModelServer, ServerSet, enable_compile_cache, serve
 from modelx_tpu.utils import trace
+
+
+def exit_when_parent_is_gone(poll_s: float = 0.25) -> None:
+    """Watch the process that started this one and leave when it is gone
+    (``--exit-with-parent``): a daemon thread polls the parent pid — the
+    kernel re-parents an orphan, so a change is the parent's death, however
+    it died — and ends the process with ``os._exit``: no drain, no handlers,
+    the chip and the port are free when the kernel has reaped it. A watch,
+    not ``PR_SET_PDEATHSIG``: that signal follows the THREAD that forked,
+    and a harness may start pods from a worker thread that ends early."""
+    parent = os.getppid()
+    if parent <= 1:
+        logging.getLogger("modelx.serve").warning(
+            "--exit-with-parent: started by pid %d (an init): nothing to watch", parent)
+        return
+
+    def watch() -> None:
+        while os.getppid() == parent:
+            time.sleep(poll_s)
+        os._exit(0)
+
+    threading.Thread(target=watch, name="parent-watch", daemon=True).start()
 
 
 @click.command("modelx-serve")
@@ -255,6 +278,12 @@ from modelx_tpu.utils import trace
               help="sample measured device memory (jax memory_stats, "
                    "live-buffer census fallback) into /metrics and "
                    "/admin/models next to the lifecycle estimates")
+@click.option("--exit-with-parent/--no-exit-with-parent", default=False,
+              help="end this pod, at once and without a drain, when the "
+                   "process that started it is gone — for a pod a harness or "
+                   "a test starts, so that a killed driver leaves nothing on "
+                   "the chip. Off by default: a pod in a container has no "
+                   "such parent")
 def main(model_dir: str, models: tuple[str, ...], mesh: str, dtype: str, listen: str,
          max_seq_len: int, compile_cache: bool,
          blob_cache_dir: str, blob_cache_max_bytes: int,
@@ -279,7 +308,10 @@ def main(model_dir: str, models: tuple[str, ...], mesh: str, dtype: str, listen:
          drain_grace: float, boundary_watchdog_s: float,
          access_log: str, access_log_max_bytes: int,
          flight_dump_dir: str, flightrec_capacity: int,
-         flight_recorder: bool, device_telemetry: bool) -> None:
+         flight_recorder: bool, device_telemetry: bool,
+         exit_with_parent: bool) -> None:
+    if exit_with_parent:
+        exit_when_parent_is_gone()
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(name)s %(message)s")
     # `modelx serve-model` arrives with the CLI group's WARNING root already
     # configured (basicConfig above is then a no-op): a pod's start-up lines

@@ -191,6 +191,24 @@ MIXTRAL_RULES: Rules = [
     (r".*", []),
 ]
 
+# Laguna (models/laguna.py): per-head gate rows split with their heads, the
+# router replicated at its published width, stacked experts over ep with
+# their features over tp; the dense MLP and the shared expert fall to the
+# llama projection rules below them.
+LAGUNA_RULES: Rules = [
+    (r"embed_tokens\.weight$", ["tp", None]),
+    (r"lm_head\.weight$", ["tp", None]),
+    (r"(q|k|v|g)_proj\.weight$", ["tp", None]),
+    (r"o_proj\.weight$", [None, "tp"]),
+    (r"mlp\.gate\.weight$", [None, None]),
+    (r"experts\.(gate|up)_proj\.weight$", ["ep", "tp", None]),
+    (r"experts\.down_proj\.weight$", ["ep", None, "tp"]),
+    (r"(gate|up)_proj\.weight$", ["tp", None]),
+    (r"down_proj\.weight$", [None, "tp"]),
+    (r"norm\.weight$", [None]),
+    (r".*", []),
+]
+
 DEFAULT_RULES: dict[str, Rules] = {
     "llama": LLAMA_RULES,
     "qwen2": QWEN2_RULES,
@@ -199,6 +217,7 @@ DEFAULT_RULES: dict[str, Rules] = {
     "gpt2": GPT2_RULES,
     "bert": BERT_RULES,
     "mixtral": MIXTRAL_RULES,
+    "laguna": LAGUNA_RULES,
 }
 
 
@@ -214,6 +233,8 @@ def infer_family(tensor_names: Sequence[str]) -> str:
     joined = "\n".join(names)
     if "block_sparse_moe" in joined:
         return "mixtral"
+    if "self_attn.g_proj" in joined:
+        return "laguna"  # per-head output gate beside q/k/v/o
     if "pre_feedforward_layernorm" in joined:
         # llama layout + sandwich norms: gemma2 — but gemma3 ALSO carries
         # them, adding per-head q_norm/k_norm attention norms (and a

@@ -53,7 +53,7 @@ FLASH_ROW_TILE = 16
 
 def attention_reference(q, k, v, causal: bool = True, q_offset=0,
                         scale: float | None = None, logit_softcap: float = 0.0,
-                        window: int = 0):
+                        window: int = 0, key_positions=None):
     """Plain softmax(QK^T * scale)V. Shapes: [B, H, S, D] (kv may have fewer
     heads than q — GQA — as long as H % Hkv == 0). ``q_offset`` positions the
     queries for cached decode: a scalar for uniform batches, or a [B] vector
@@ -63,7 +63,10 @@ def attention_reference(q, k, v, causal: bool = True, q_offset=0,
     query_pre_attn_scalar**-0.5 instead. ``logit_softcap`` > 0 applies
     cap * tanh(logits / cap) BEFORE masking (the gemma2 convention).
     ``window`` > 0 limits each query to its last ``window`` keys (sliding
-    window attention; needs ``causal``)."""
+    window attention; needs ``causal``). ``key_positions`` ([B, K] int, needs
+    ``causal``) gives each key's absolute position where index and position
+    differ — a ring written at ``position mod K`` — and a negative entry
+    marks a key that holds nothing yet."""
     b, hq, qlen, d = q.shape
     qk, pv = "bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd"
     if k.shape[1] != hq:
@@ -83,8 +86,12 @@ def attention_reference(q, k, v, causal: bool = True, q_offset=0,
         qpos = jnp.arange(qlen)[:, None] + (
             jax.lax.expand_dims(off, range(1, logits.ndim)) if off.ndim else off
         )  # [Q,K] or [B,1,(1,)Q,K]
-        kpos = jnp.arange(k.shape[2])[None, :]
-        visible = kpos <= qpos
+        if key_positions is None:
+            kpos = jnp.arange(k.shape[2])[None, :]
+            visible = kpos <= qpos
+        else:  # [B, K] -> [B, 1, (1,) 1, K] against qpos [B, 1, (1,) Q, 1]
+            kpos = jax.lax.expand_dims(key_positions, range(1, logits.ndim - 1))
+            visible = (kpos <= qpos) & (kpos >= 0)
         if window > 0:  # keys qpos-window < kpos <= qpos stay visible
             visible = visible & (kpos > qpos - window)
         logits = jnp.where(visible, logits, NEG_INF)

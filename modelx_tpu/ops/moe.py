@@ -23,6 +23,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from modelx_tpu.ops.nn import linear as _linear
+
 
 def router_topk(router_logits: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
     """Mixtral-style routing: softmax over experts, take top-k, renormalize.
@@ -99,6 +101,95 @@ def moe_ffn(
         preferred_element_type=jnp.float32,
     ).astype(x.dtype)
     return cons(out, "dp", "sp", None)
+
+
+def route_topk(router_logits: jax.Array, k: int, *, renormalize: bool = True,
+               scale: float = 1.0) -> jax.Array:
+    """Exactly-k routing over the router's whole width: softmax over all E
+    logits in float32, the k largest, their probabilities divided by their
+    own sum (``renormalize``, HF ``norm_topk_prob``) and multiplied by
+    ``scale`` (``moe_routed_scaling_factor``). router_logits: [T, E].
+    Returns the combine weights [T, E], zero off the k chosen. Unlike
+    :func:`router_topk` a tie never admits a (k+1)-th expert."""
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    vals, idx = jax.lax.top_k(probs, k)
+    if renormalize:
+        vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+    rows = jnp.arange(probs.shape[0])[:, None]
+    return jnp.zeros_like(probs).at[rows, idx].set(vals * scale)
+
+
+def moe_share_ffn(
+    x: jax.Array,
+    router_w: jax.Array,
+    w_gate: jax.Array,
+    w_up: jax.Array,
+    w_down: jax.Array,
+    *,
+    top_k: int,
+    held: tuple[int, int] | None = None,
+    renormalize: bool = True,
+    routed_scale: float = 1.0,
+    shared: tuple[jax.Array, jax.Array, jax.Array] | None = None,
+    constrain=None,
+    scopes: tuple[str, str] = ("moe.routed", "moe.shared"),
+) -> tuple[jax.Array, jax.Array]:
+    """An expert layer that is told which experts it holds.
+
+    x: [B, S, D]; router_w: [E_pub, D], the router at its PUBLISHED width;
+    w_gate / w_up: [E_held, F, D], w_down: [E_held, D, F] — the stacked
+    SwiGLU experts this device holds, experts ``first .. first + count`` of
+    the published ``E_pub`` (``held = (first, count)``; None = all of them).
+    Routing is over all ``E_pub`` experts: top-k of the full softmax,
+    normalised over all k chosen, times ``routed_scale``. Only the held
+    experts' part of the sum is computed — what the absent experts would add
+    is another device's, and nothing here stands in for it. ``shared``
+    (gate, up, down in torch Linear layout) is an always-on SwiGLU expert
+    added ungated beside the routed sum.
+
+    Drop-free and exact: every held expert runs on every token and the
+    combine weight (zero where the router did not choose it) picks its part,
+    so no capacity, sort or dynamic shape is involved. At decode the layer is
+    bound by reading the held experts' weights, which this formulation reads
+    once each; at prefill it spends E_held/k times the arithmetic a grouped
+    product over sorted tokens would (ROADMAP R1).
+
+    Returns (out [B, S, D], counts int32 [3]): token-expert pairs routed,
+    those that landed on a held expert, and distinct held experts hit.
+    """
+    b, s, d = x.shape
+    e_pub = router_w.shape[0]
+    first, count = held if held is not None else (0, e_pub)
+    if count != w_gate.shape[0] or first < 0 or first + count > e_pub:
+        raise ValueError(
+            f"held experts {first}..{first + count} of {e_pub} do not match the "
+            f"{w_gate.shape[0]} stacked expert weights given")
+    cons = constrain if constrain is not None else (lambda arr, *spec: arr)
+    t = x.reshape(b * s, d)
+    f32 = jnp.float32
+    with jax.named_scope(scopes[0]):
+        logits = jax.lax.dot_general(t, router_w, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=f32)  # [T, E_pub]
+        combine = route_topk(logits, top_k, renormalize=renormalize, scale=routed_scale)
+        here = jax.lax.slice_in_dim(combine, first, first + count, axis=1)  # [T, E_held]
+        hit = here > 0
+        counts = jnp.stack([jnp.int32(b * s * top_k), jnp.sum(hit, dtype=jnp.int32),
+                            jnp.sum(jnp.any(hit, axis=0), dtype=jnp.int32)])
+        g = jnp.einsum("td,efd->etf", t, w_gate, preferred_element_type=f32).astype(x.dtype)
+        u = jnp.einsum("td,efd->etf", t, w_up, preferred_element_type=f32).astype(x.dtype)
+        # the combine weight goes on the hidden activation, so that the down
+        # projection contracts experts and features at once ([T, E*F] x
+        # [E*F, D]) and no [E, T, D] block of per-expert outputs exists
+        h = (jax.nn.silu(g) * u).astype(f32) * here.T[:, :, None]
+        h = cons(h.astype(x.dtype), "ep", None, "tp")
+        out = jnp.einsum("etf,edf->td", h, w_down, preferred_element_type=f32)
+    if shared is not None:
+        with jax.named_scope(scopes[1]):
+            sg, su, sd = shared
+            hs = jax.nn.silu(_linear(t, sg)) * _linear(t, su)
+            out = out + jax.lax.dot_general(hs, sd, (((1,), (1,)), ((), ())),
+                                            preferred_element_type=f32)
+    return cons(out.astype(x.dtype).reshape(b, s, d), "dp", "sp", None), counts
 
 
 def load_balancing_loss(router_logits: jax.Array, mask: jax.Array) -> jax.Array:

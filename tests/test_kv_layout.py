@@ -268,7 +268,8 @@ def test_the_dense_programs_take_no_table_and_gather_nothing_from_the_cache(serv
         }
         for name, (jaxpr, n_inputs) in programs.items():
             (call,) = jaxpr.eqns  # the jitted program, under its name
-            assert call.params["name"] == name
+            # the chunk program's name also says its static depth (one chunk here)
+            assert call.params["name"] == name + ("_d1" if name == "_chunk_impl" else "")
             extra = len(jaxpr.jaxpr.invars) - n_state - n_inputs
             from_cache = [e for e in walk(jaxpr.jaxpr) if e.primitive.name == "gather"
                           and e.invars[0].aval.shape in kv_shapes]
@@ -279,3 +280,127 @@ def test_the_dense_programs_take_no_table_and_gather_nothing_from_the_cache(serv
                 assert (name == "_chunk_impl") == bool(from_cache)
     finally:
         cb.close()
+
+
+# -- a cache per layer kind ----------------------------------------------------
+
+
+class TestLayerKinds:
+    """``LayerKindKV`` behind the same seam: full layers whole, window layers
+    as rings, counted by kind. References are numpy and counts kept by hand."""
+
+    WINDOW, RING = 16, 32
+
+    @pytest.fixture(scope="class")
+    def kv(self):
+        from modelx_tpu.models import laguna
+
+        cfg = laguna.LagunaConfig.tiny(vocab_size=64)
+        server = types.SimpleNamespace(mesh=make_mesh("dp=1", jax.devices()[:1]),
+                                       family=FAMILIES["laguna"], cfg=cfg)
+        fwd, init_cache = server.family.decode_fns(cfg, mesh=server.mesh)
+        self.stats = {}
+        return kv_layout.build(server, fwd, init_cache, self.stats, max_slots=SLOTS,
+                               max_len=MAX_LEN, chunk_size=4, page_size=0, max_live_tokens=0,
+                               paged_attention="gather"), cfg
+
+    def test_each_kind_gets_its_own_length_and_bytes_are_counted_by_kind(self, kv):
+        kv, cfg = kv
+        state = kv.new_state()
+        lengths = {name: state[name].shape[1] for name, kind in kv.kinds.items() if kind != "counter"}
+        assert lengths == {f"{x}{i}": self.RING if 1 <= i <= 3 else MAX_LEN
+                           for i in range(5) for x in "kv"}
+        leaf = SLOTS * cfg.num_kv_heads * cfg.head_dim * 4
+        assert kv.stats["kv"]["bytes_full"] == 4 * MAX_LEN * leaf       # k and v of layers 0, 4
+        assert kv.stats["kv"]["bytes_window"] == 6 * self.RING * leaf   # k and v of layers 1-3
+        assert kv.stats["kv"]["window_positions"] == self.RING == self.WINDOW + 16
+        assert kv.describe()[:3] == ("LayerKindKV", SLOTS, MAX_LEN)
+        assert state["moe_counts"].shape == (3,) and kv.sharding((3,)) is None
+
+    def test_fits_and_reserve_count_both_kinds(self, kv):
+        kv, _ = kv
+        kv.reset()
+        held = {}
+        rng = np.random.RandomState(5)
+        for _ in range(60):
+            slot = int(rng.randint(SLOTS))
+            if slot in held and rng.rand() < 0.4:
+                kv.release(slot)
+                del held[slot]
+            else:
+                tokens = int(rng.randint(1, MAX_LEN + 20))
+                assert kv.fits(tokens) == (tokens <= MAX_LEN)
+                assert kv.reserve(slot, tokens) == (tokens <= MAX_LEN)
+                if tokens <= MAX_LEN:
+                    held[slot] = max(tokens, held.get(slot, 0))
+            assert kv.stats["kv"]["positions_full"] == sum(held.values())
+            assert kv.stats["kv"]["positions_window"] == sum(min(t, self.RING) for t in held.values())
+        kv.reset()
+        assert kv.stats["kv"]["positions_full"] == kv.stats["kv"]["positions_window"] == 0
+        assert kv.never_holds(MAX_LEN) == ""
+
+    @pytest.mark.parametrize("length", [16, 32, 48, 64])
+    def test_a_scratch_lands_whole_on_full_layers_and_by_ring_index_on_window_layers(self, kv, length):
+        """Position p of a scratch is at index p of a full leaf and at index
+        p mod ring of a window leaf, which keeps the last ``ring`` positions."""
+        kv, _ = kv
+        small = scratch(kv, length, length)
+        state = jax.jit(kv.put)(kv.new_state(), small, kv.at(2))
+        for name, kind in kv.kinds.items():
+            if kind == "counter":
+                continue
+            got, want = np.asarray(state[name]), np.asarray(small[name])[0]
+            assert not got[[0, 1, 3]].any()  # the other slots stay as they were
+            if kind == "full":
+                np.testing.assert_array_equal(got[2, :length], want)
+            else:
+                for p in range(max(0, length - self.RING), length):
+                    np.testing.assert_array_equal(got[2, p % self.RING], want[p])
+
+    def test_put_many_lands_each_row_in_its_slot_and_drops_pad_rows(self, kv):
+        kv, _ = kv
+        small = scratch(kv, 7, 48, rows=4)
+        where = kv.at_many(np.asarray([3, 0, SLOTS, SLOTS]))  # two real rows, two pad rows
+        state = jax.jit(kv.put_many)(kv.new_state(), small, where)
+        one = jax.jit(kv.put)
+        want = kv.new_state()
+        for row, slot in ((0, 3), (1, 0)):
+            want = one(want, jax.tree_util.tree_map(lambda x: x[row:row + 1], small), kv.at(slot))
+        assert_trees_equal(state, want)
+
+    def test_counters_ride_home_below_the_slots_rows_and_wrap_at_32_bits(self, kv):
+        kv, _ = kv
+        state = dict(kv.new_state(), moe_counts=jnp.asarray([7, 5, 3], jnp.int32))
+        block = jnp.arange(SLOTS * 5, dtype=jnp.int32).reshape(SLOTS, 5)
+        out = np.asarray(kv.ride(state, block))
+        assert out.shape == (SLOTS + 3, 5)
+        np.testing.assert_array_equal(out[:SLOTS], np.asarray(block))
+        kv._last.clear()
+        kv.stats["moe"].update(assignments=0, assignments_held=0, experts_hit=0)
+        kv.landed(out)
+        assert [kv.stats["moe"][k] for k in ("assignments", "assignments_held", "experts_hit")] == [7, 5, 3]
+        wrapped = out.copy()
+        wrapped[SLOTS:, 0] = np.asarray([2**31 - 1, 5, 4], np.int64).astype(np.int32)
+        kv.landed(wrapped)
+        wrapped[SLOTS, 0] = np.int32(-(2**31) + 9)  # the device's int32 passed 2**31: +10
+        kv.landed(wrapped)
+        assert kv.stats["moe"]["assignments"] == 2**31 - 1 + 10
+        assert (kv.stats["moe"]["assignments_held"], kv.stats["moe"]["experts_hit"]) == (5, 4)
+        DenseKV = kv_layout.DenseKV
+        assert DenseKV.ride(kv, state, block) is block  # the other layouts add nothing
+
+    def test_the_abstract_state_describes_the_allocated_one(self, kv):
+        kv, _ = kv
+        described, allocated = kv.abstract_state(), kv.new_state()
+        assert jax.tree_util.tree_structure(described) == jax.tree_util.tree_structure(allocated)
+        for d, x in zip(jax.tree_util.tree_leaves(described), jax.tree_util.tree_leaves(allocated),
+                        strict=True):
+            assert (d.shape, d.dtype) == (x.shape, x.dtype)
+
+    def test_a_dense_view_of_a_slot_is_refused(self, kv):
+        kv, _ = kv
+        with pytest.raises(kv_layout.Refused, match="no dense view"):
+            kv.view(kv.new_state(), kv.at(0), 16)
+        with pytest.raises(kv_layout.Refused, match="--kv-page-size.*--speculative-k"):
+            kv_layout.LayerKindKV.refuse("laguna", page_size=16, prefix_cache=None,
+                                         prefill_chunk=0, speculative_k=2)

@@ -101,6 +101,15 @@ FAMILY_SPEC_CASES = {
         ("model.layers.0.block_sparse_moe.experts.w2.weight",
          PartitionSpec(None, None, "tp")),
     ],
+    "laguna": [
+        ("model.layers.1.self_attn.g_proj.weight", PartitionSpec("tp", None)),
+        ("model.layers.1.self_attn.o_proj.weight", PartitionSpec(None, "tp")),
+        ("model.layers.1.mlp.gate.weight", PartitionSpec(None, None)),  # the router
+        ("model.layers.1.mlp.experts.gate_proj.weight", PartitionSpec(None, "tp", None)),
+        ("model.layers.1.mlp.experts.down_proj.weight", PartitionSpec(None, None, "tp")),
+        ("model.layers.1.mlp.shared_expert.up_proj.weight", PartitionSpec("tp", None)),
+        ("model.layers.0.mlp.down_proj.weight", PartitionSpec(None, "tp")),  # the dense layer
+    ],
 }
 
 

@@ -91,3 +91,51 @@ def test_bare_kernel_under_a_mesh_is_refused(topo):
     args = _qkv(NamedSharding(mesh, P("dp", "tp")), 512)
     with pytest.raises(Exception, match="(?i)mosaic|shard_map|partition"):
         attn.flash_attention.lower(*args, causal=True).compile()
+
+
+@pytest.mark.parametrize("heads,window,seq", [(48, 0, 640), (72, 512, 640), (72, 512, 64)])
+def test_flash_kernel_at_lagunas_head_counts_and_window(topo, heads, window, seq):
+    """laguna-s-2.1: 48 (full) and 72 (sliding, window 512) query heads over
+    8 KV heads of 128 — what ``/v1/forward`` compiles, a row longer than the
+    window included."""
+    args = _qkv(SingleDeviceSharding(topo.devices[0]), seq, heads=heads)
+    text = attn.flash_attention.lower(*args, causal=True, window=window).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_lagunas_decode_step_fits_one_chip_with_a_cache_per_layer_kind(topo):
+    """The benchmark's configuration at its published widths — 128 of 256
+    experts held, 64 slots of 4096 positions: one decode step over the
+    per-kind state (full layers whole, window layers as rings of 528)
+    compiles for the chip and, weights and state included, stays under the
+    chip's 15.75 GiB; with ``[slots, max_len]`` for all five layers the
+    arguments alone would not. Shapes only: nothing is allocated or run."""
+    import json
+
+    from modelx_tpu.models import laguna
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "laguna-s-2.1-ep2-d5.json")) as f:
+        cfg = laguna.config_from_hf(json.load(f))
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    params = {k: sds(v, jnp.bfloat16) for k, v in laguna.param_shapes(cfg).items()}
+    state = jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: laguna.init_layer_state(cfg, 64, 4096)))
+
+    def step(params, state, tok, offsets):
+        logits, state = laguna.forward(params, tok, cfg, kv_cache=state,
+                                       cache_offset=offsets, ring=True)
+        return state, jnp.argmax(logits[:, -1], -1)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, state, sds((64, 1), jnp.int32), sds((64,), jnp.int32)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes
+            - m.alias_size_in_bytes)
+    weights_and_kv = 2 * 5_572_076_544 + 2_562_719_744  # the configuration's bytes_predicted
+    assert weights_and_kv <= m.argument_size_in_bytes < weights_and_kv + 4096  # + tok, offsets, counters
+    assert live < 15.75 * 2**30 - 1.5e9  # room for an admission's scratch beside it
+    uniform = 2 * 5_572_076_544 + 5 * 2 * 64 * 4096 * 8 * 128 * 2
+    assert uniform > 15.75 * 2**30 - 1.5e9
