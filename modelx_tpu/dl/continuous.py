@@ -857,24 +857,31 @@ class ContinuousBatcher:
         trailing column: the scan's final carry (each row's next,
         not-yet-delivered token), so the host's lagged readback also
         learns the lookahead value without a second device sync."""
+        from modelx_tpu.ops import attention as attn_ops
         from modelx_tpu.ops import sampling as sampling_ops
 
         *where, offsets, steps, temp, top_k, top_p, seeds = args
 
         def step_fn(carry, _i):
             cache, tok, offsets, steps = carry
-            logits, cache = self.kv.step(params, tok, cache, offsets, *where)
+            with attn_ops.ragged_calls() as ragged:
+                logits, cache = self.kv.step(params, tok, cache, offsets, *where)
+            # where layers of this step took the ragged kernel, the KV
+            # positions their blocks cover and the positions cached, over
+            # all slots; None (nothing is added to the program) where none did
+            kv_read = attn_ops.kv_positions(ragged, offsets + 1) if ragged else None
             nxt = sampling_ops.sample(
                 logits[:, -1, :].astype(jnp.float32), jax.random.PRNGKey(0), temp,
                 top_k=top_k, top_p=top_p, seeds=seeds, step=steps,
             )
-            return (cache, nxt[:, None], offsets + 1, steps + 1), tok[:, 0]
+            return (cache, nxt[:, None], offsets + 1, steps + 1), (tok[:, 0], kv_read)
 
-        (cache, tok, offsets, steps), toks = jax.lax.scan(
+        (cache, tok, offsets, steps), (toks, kv_read) = jax.lax.scan(
             step_fn, (cache, tok, offsets, steps),
             jnp.arange(n_steps or self.chunk_size),
         )
-        return cache, tok, self.kv.ride(cache, jnp.concatenate([toks.T, tok], axis=1))
+        return cache, tok, self.kv.ride(
+            cache, jnp.concatenate([toks.T, tok], axis=1), kv_read)
 
     def _chunk_args(self, filtered: bool) -> list:
         """The per-slot inputs of one chunk dispatch, after params, cache
@@ -1696,6 +1703,9 @@ class ContinuousBatcher:
         self._last_chunk_t = now
         self._offsets += n_steps
         self._steps += n_steps
+        # an idle slot decodes garbage at offset 0 onwards in every program:
+        # left to drift, its context, and what attention reads for it, grows
+        self._offsets[self._free] = 0
         for slot, fill in self._filling.items():
             # filling slots don't decode: their offsets stay pinned at the
             # fill frontier (the chunk's garbage writes land beyond it and

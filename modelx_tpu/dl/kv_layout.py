@@ -64,7 +64,7 @@ def build(server, fwd, init_cache, stats: dict, *, max_slots: int,
                            speculative_k=speculative_k)
         return LayerKindKV(server, fwd, init_cache, stats, max_slots, max_len)
     if page_size <= 0:
-        return DenseKV(fwd, init_cache, server.mesh, max_slots, max_len)
+        return DenseKV(fwd, init_cache, server.mesh, stats, max_slots, max_len)
     return PagedKV(server, fwd, init_cache, stats, max_slots, max_len,
                    chunk_size, page_size, max_live_tokens, paged_attention)
 
@@ -76,8 +76,12 @@ class DenseKV:
     # the dim of a leaf that dp may split: its slots
     _batch_dim = 0
 
-    def __init__(self, fwd, init_cache, mesh, max_slots: int, max_len: int) -> None:
-        self.fwd, self.init_cache, self.mesh = fwd, init_cache, mesh
+    # rows ``ride`` adds below the slots' for the layout's own counters
+    counter_rows = 0
+
+    def __init__(self, fwd, init_cache, mesh, stats: dict, max_slots: int,
+                 max_len: int) -> None:
+        self.fwd, self.init_cache, self.mesh, self.stats = fwd, init_cache, mesh, stats
         self.max_slots, self.max_len = max_slots, max_len
 
     # -- device state ---------------------------------------------------------
@@ -150,8 +154,15 @@ class DenseKV:
         """Account one decode dispatch over rows at ``offsets``."""
 
     def landed(self, toks: np.ndarray) -> None:
-        """A chunk's token block has reached the host (what ``ride`` added
-        to it is read here; nothing, for this layout)."""
+        """A chunk's token block has reached the host: what ``ride`` added
+        to it is read here. Two rows below everything else are the steps'
+        KV positions read and cached by the layers that took the ragged
+        decode kernel (``attn_kv_positions_read`` / ``_cached``); a program
+        none of whose layers did sends none, and the counters do not exist."""
+        if toks.shape[0] > self.max_slots + self.counter_rows:
+            stats, grown = self.stats, toks[-2:, :-1].astype(np.int64).sum(axis=1)
+            for key, n in zip(("attn_kv_positions_read", "attn_kv_positions_cached"), grown):
+                stats[key] = stats.get(key, 0) + int(n)
 
     def all_slots(self) -> tuple:
         return ()
@@ -175,11 +186,16 @@ class DenseKV:
         """One cached forward over ALL slots, each row at its own offset."""
         return self.fwd(params, block, kv_cache=cache, cache_offset=offsets)
 
-    def ride(self, cache, block):
-        """What of the state goes home with a chunk's token block
-        ``[max_slots, n + 1]`` — rows appended below the slots', so that
-        device-side counters cost no host sync of their own. Nothing here."""
-        return block
+    def ride(self, cache, block, kv_read=None):
+        """What goes home with a chunk's token block ``[max_slots, n + 1]`` —
+        rows appended below the slots', so that device-side counters cost no
+        host sync of their own. Of the state, nothing here; ``kv_read``
+        ``[n, 2]`` (each step's KV positions read and cached where layers
+        took the ragged decode kernel, else None) becomes two rows, a step a
+        column."""
+        if kv_read is None:
+            return block
+        return jnp.concatenate([block, jnp.pad(kv_read.T, ((0, 0), (0, 1)))], axis=0)
 
     def put(self, cache, small, where):
         """Write a scratch cache to the front of one slot."""
@@ -218,7 +234,7 @@ class PagedKV(DenseKV):
     def __init__(self, server, fwd, init_cache, stats: dict, max_slots: int,
                  max_len: int, chunk_size: int, page_size: int,
                  max_live_tokens: int, paged_attention: str) -> None:
-        super().__init__(fwd, init_cache, server.mesh, max_slots, max_len)
+        super().__init__(fwd, init_cache, server.mesh, stats, max_slots, max_len)
         if max_len % page_size:
             raise ValueError(
                 f"max_len {max_len} must be a multiple of page_size {page_size}")
@@ -247,7 +263,6 @@ class PagedKV(DenseKV):
                     "--kv-attention in-place: family %s has no paged decode; "
                     "falling back to the dense-gather chunk (higher per-step "
                     "transient HBM)", family.name)
-        self.stats = stats
         stats["page_size"] = ps
         stats["pages_total"] = self.num_pages - 1  # excl. trash
         stats["paged_attention"] = "gather" if self.fwd_paged is None else "in-place"
@@ -471,11 +486,12 @@ class LayerKindKV(DenseKV):
 
     def __init__(self, server, fwd, init_cache, stats: dict, max_slots: int,
                  max_len: int) -> None:
-        super().__init__(fwd, init_cache, server.mesh, max_slots, max_len)
+        super().__init__(fwd, init_cache, server.mesh, stats, max_slots, max_len)
         fns = server.family.layer_kind_decode_fns(server.cfg, mesh=server.mesh)
         self.fwd_kinds, self.init_state, self.kinds = fns["fwd"], fns["init_state"], fns["kinds"]
         # counter leaf -> (the stats block it feeds, its entries' names)
         self.counters: dict[str, tuple[str, tuple]] = fns.get("counters", {})
+        self.counter_rows = sum(len(names) for _, names in self.counters.values())
         shapes = jax.eval_shape(self._zeros)
         rings = {shapes[n].shape[1] for n, kind in self.kinds.items() if kind == "window"}
         self.ring = rings.pop() if rings else max_len
@@ -484,7 +500,6 @@ class LayerKindKV(DenseKV):
             return sum(int(np.prod(shapes[n].shape)) * shapes[n].dtype.itemsize
                        for n, k in self.kinds.items() if k == kind)
 
-        self.stats = stats
         stats["kv"] = {"bytes_full": bytes_of("full"), "bytes_window": bytes_of("window"),
                        "window_positions": self.ring, "positions_full": 0,
                        "positions_window": 0}
@@ -530,6 +545,7 @@ class LayerKindKV(DenseKV):
         self._count()
 
     def landed(self, toks: np.ndarray) -> None:
+        super().landed(toks)
         # the counters wrap at 32 bits on the device: what is added here is
         # each one's growth since the block before, taken modulo 2**32
         row = self.max_slots
@@ -547,10 +563,10 @@ class LayerKindKV(DenseKV):
     def step(self, params, block, cache, offsets):
         return self.fwd_kinds(params, block, kv_cache=cache, cache_offset=offsets)
 
-    def ride(self, cache, block):
+    def ride(self, cache, block, kv_read=None):
         rows = [jnp.broadcast_to(cache[leaf][:, None], (len(names), block.shape[1]))
                 for leaf, (_, names) in self.counters.items()]
-        return jnp.concatenate([block, *rows], axis=0)
+        return super().ride(cache, jnp.concatenate([block, *rows], axis=0), kv_read)
 
     def _ring_rows(self, little):
         """The part of a dense scratch leaf ``[k, S, ...]`` a ring keeps, laid

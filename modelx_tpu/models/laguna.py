@@ -405,15 +405,25 @@ def _write_rows(cache, new, index):
 
 def _attention(q, k, v, cfg: LagunaConfig, layer: int, ctx: ShardingCtx, cache,
                cache_offset, ring: bool, attention_impl: str):
-    """q [B,S,H,D], k/v [B,S,Hkv,D] after rope -> ([B,S,H,D], new cache)."""
+    """q [B,S,H,D], k/v [B,S,Hkv,D] after rope -> ([B,S,H,D], new cache).
+
+    No cache: flash on a TPU, else the reference. A cache: the new keys and
+    values are written, then ``ops.attention.cached_attention`` picks by what
+    it observes — a full layer's decode step (one token a row at per-row
+    offsets over ``[slots, max_len]``, 128-wide heads, on one TPU device) takes
+    the ragged kernel and reads each row's KV blocks up to its own context; a
+    ring (``key_positions``: full after its length, nothing to skip), a window
+    over a dense cache, an admission's prefill and the CPU keep
+    ``attention_reference``. ``attention_impl`` ``"ragged"``
+    (``"ragged+interpret"`` on the CPU) asks for the kernel by name."""
     window = cfg.window(layer)
     t = lambda x: x.transpose(0, 2, 1, 3)
-    group = q.shape[2] // k.shape[2]
     if cache is None:
         impl, _, flag = attention_impl.partition("+")
-        if impl == "auto":
+        if impl in ("auto", "ragged"):  # "ragged" names the cached decode's kernel only
             impl = "flash" if jax.default_backend() == "tpu" else "reference"
-        attn_ops.note_choice(impl, q.shape[1], k.shape[1], ctx.mesh, group=group)
+        attn_ops.note_choice(impl, q.shape[1], k.shape[1], ctx.mesh,
+                             group=q.shape[2] // k.shape[2])
         if impl == "flash":
             out = attn_ops.flash_attention(t(q), t(k), t(v), causal=True, window=window,
                                            mesh=ctx.mesh, interpret=flag == "interpret")
@@ -433,10 +443,9 @@ def _attention(q, k, v, cfg: LagunaConfig, layer: int, ctx: ShardingCtx, cache,
         key_positions = offset[:, None] - (offset[:, None] - jnp.arange(length)[None, :]) % length
     else:
         ck, cv = _write_rows(ck, k, cache_offset), _write_rows(cv, v, cache_offset)
-    attn_ops.note_choice("reference", q.shape[1], ck.shape[1], ctx.mesh, group=group)
-    out = attn_ops.attention_reference(t(q), t(ck), t(cv), causal=True, q_offset=cache_offset,
-                                       window=window, key_positions=key_positions)
-    return t(out), (ck, cv)
+    out = attn_ops.cached_attention(q, ck, cv, cache_offset, impl=attention_impl,
+                                    mesh=ctx.mesh, window=window, key_positions=key_positions)
+    return out, (ck, cv)
 
 
 def decoder_layer(params, p: str, x, positions, cfg: LagunaConfig, layer: int,

@@ -219,7 +219,19 @@ def decoder_layer(
     cache leaves are page pools [P, page_size, Hkv, D], the table maps each
     row to its pages, and attention reads the pool in place
     (ops/paged_attention.py) — single-token steps only (s == 1), the shape
-    the continuous engine's chunk scan drives."""
+    the continuous engine's chunk scan drives.
+
+    Which attention runs, by what the call observes. No cache: ``_attend``
+    (flash on a TPU, ring under an sp axis, else the reference). A dense
+    cache ``[B, L, Hkv, D]``: ``ops.attention.cached_attention`` — one token a
+    row at per-row offsets, head_dim a multiple of 128, KV heads a multiple
+    of 8, ``L`` two blocks or more, on one TPU device takes the ragged kernel
+    that reads each row's KV blocks up to its own context (the engine's decode
+    step for llama-3 or Mixtral shapes); an admission's prefill into its
+    scratch cache, a scalar offset, head_dim 96 (every Phi-3 program), the
+    CPU and a mesh of several devices keep ``attention_reference`` over all
+    ``L`` positions, lowered as before. ``attention_impl`` ``"ragged"``
+    (``"ragged+interpret"`` on the CPU) asks for the kernel by name."""
     b, s = x.shape[:2]
     h = _rms_norm(x, lp["input_layernorm.weight"], cfg.rms_eps)
     q = _linear(h, lp["self_attn.q_proj.weight"], lp.get("self_attn.q_proj.bias"))
@@ -261,10 +273,10 @@ def decoder_layer(
             ck = row_dus(ck, k, cache_offset)
             cv = row_dus(cv, v, cache_offset)
         new_cache = (ck, cv)
-        attn_out = _attend(q, ck, cv, cfg, causal=True,
-                           q_offset=cache_offset, mesh=mesh, impl="reference")
+        attn_out = attn_ops.cached_attention(q, ck, cv, cache_offset, impl=attention_impl,
+                                             mesh=mesh)
     else:
-        attn_out = _attend(q, k, v, cfg, causal=True, q_offset=0, mesh=mesh, impl=attention_impl)
+        attn_out = _attend(q, k, v, cfg, causal=True, mesh=mesh, impl=attention_impl)
 
     attn_out = attn_out.reshape(b, s, cfg.num_heads * cfg.head_dim)
     x = x + _linear(attn_out, lp["self_attn.o_proj.weight"])
@@ -349,9 +361,9 @@ def forward(
     return ctx.constrain(logits, "dp", "sp", None), new_cache
 
 
-def _attend(q, k, v, cfg: LlamaConfig, causal: bool, q_offset, mesh, impl: str):
-    """q: [B,S,H,D], k/v: [B,S(,kv)...]. Transposes to [B,H,S,D] and picks
-    the attention implementation. ``"auto"`` picks from what it can observe
+def _attend(q, k, v, cfg: LlamaConfig, causal: bool, mesh, impl: str):
+    """The cache-less forward's attention. q: [B,S,H,D], k/v: [B,S(,kv)...].
+    Transposes to [B,H,S,D] and picks the implementation. ``"auto"`` picks from what it can observe
     (an sp axis -> ring; the TPU backend -> the pallas kernel; else the jnp
     reference) and the pick is recorded (``attn_ops.note_choice``). A
     ``"+interpret"`` suffix (``"flash+interpret"``) runs the kernel in
@@ -360,7 +372,7 @@ def _attend(q, k, v, cfg: LlamaConfig, causal: bool, q_offset, mesh, impl: str):
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     impl, _, flag = impl.partition("+")
-    if impl == "auto":
+    if impl in ("auto", "ragged"):  # "ragged" names the cached decode's kernel only
         if mesh is not None and "sp" in mesh.axis_names and mesh.shape["sp"] > 1:
             impl = "ring"
         elif jax.default_backend() == "tpu":
@@ -379,7 +391,7 @@ def _attend(q, k, v, cfg: LlamaConfig, causal: bool, q_offset, mesh, impl: str):
         out = attn_ops.flash_attention(qt, kt, vt, causal=causal, mesh=mesh,
                                        interpret=interpret)
     else:
-        out = attn_ops.attention_reference(qt, kt, vt, causal=causal, q_offset=q_offset)
+        out = attn_ops.attention_reference(qt, kt, vt, causal=causal)
     return out.transpose(0, 2, 1, 3)
 
 

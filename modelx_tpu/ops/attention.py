@@ -28,13 +28,17 @@ is recorded at trace time by :func:`note_choice`.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import shard_map
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
 from modelx_tpu.utils import trace
@@ -46,6 +50,14 @@ FLASH_BLOCK = 128  # q and k block: one MXU tile edge
 # statically prove that index in dimension 1 is a multiple of 8" for a
 # 5-token /v1/forward, which interpret mode never shows
 FLASH_ROW_TILE = 16
+# (position, KV head) pairs one step of the ragged decode kernel contracts:
+# 256 positions of 8 KV heads. On the v5e 256, 512 and 1024 positions read a
+# whole cache at the same 0.91 of the HBM peak; rows at a third of the cache
+# cost 0.655, 0.673 and 0.799 ms a layer — a shorter block reads less past a
+# row's length, and a skipped grid step costs 0.27 us (PERF.md, PR 34)
+RAGGED_COLUMNS = 2048
+# fewest positions a block may hold where nobody asked for the kernel by name
+RAGGED_MIN_BLOCK = 128
 
 
 # -- reference (jnp) ----------------------------------------------------------
@@ -268,6 +280,189 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = FLASH_BLOCK,
                      out_specs=spec, check_vma=False)(q, k, v)
 
 
+# -- ragged decode attention (pallas) -----------------------------------------
+
+
+def ragged_block(cache_len: int, kv_heads: int) -> int:
+    """Positions one block of the ragged decode kernel holds for a cache of
+    ``cache_len`` positions and ``kv_heads`` heads: ``RAGGED_COLUMNS //
+    kv_heads``, halved until it cuts ``cache_len`` into two blocks or more;
+    0 where no block does."""
+    block = RAGGED_COLUMNS // kv_heads
+    while block and (cache_len % block or cache_len < 2 * block):
+        block //= 2
+    return block
+
+
+def _ragged_decode_kernel(len_ref, q_ref, k_ref, v_ref, row_head_ref, col_head_ref,
+                          col_pos_ref, o_ref, m_ref, l_ref, acc_ref, *, block: int,
+                          sm_scale: float):
+    """One (row, KV block) program of :func:`decode_attention`.
+
+    q_ref [rows, d]: every query head of the row. k_ref / v_ref [block *
+    kv_heads, d]: one block of the row's cache as it lies, a line a (position,
+    KV head) pair. One contraction gives every query head against every pair;
+    ``row_head == col_head`` keeps a head's own KV head (what a repeat of the
+    KV heads means) and ``col_pos`` the positions below the row's length. The
+    MXU's time here is the loading of the block's tiles, which the lines that
+    are masked away share: folding each group onto its KV head would load the
+    same tiles and need the heads picked apart first. The online-softmax state
+    (m, l, acc) lives in scratch across the row's blocks; a block past the
+    row's last does nothing (its index map pointed at the last one, so nothing
+    was copied for it either)."""
+    row, j = pl.program_id(0), pl.program_id(1)
+    length = len_ref[row]
+    last = (length - 1) // block
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j <= last)
+    def _():
+        s = jax.lax.dot_general(
+            q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # [rows, block * kv_heads]
+        # block j <= last holds position j * block < length under every KV
+        # head, so each real row's running max is real from its first block
+        visible = (row_head_ref[...] == col_head_ref[...]) & (
+            col_pos_ref[...] < length - j * block)
+        s = jnp.where(visible, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == last)
+    def _():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def decode_attention(q, k_cache, v_cache, lengths, scale: float | None = None, *,
+                     block: int = 0, interpret: bool = False):
+    """One decode step's attention, each row over its own context only.
+
+    q [B, 1, H, D]; k_cache / v_cache [B, L, Hkv, D] as the engine keeps them
+    (no transpose, no copy: ``[B, L * Hkv, D]`` is the same bytes); ``lengths``
+    [B] int, the positions each row holds (``cache_offset + 1``, clipped to
+    1..L). Returns [B, 1, H, D] in q's dtype. Row i reads ``ceil(lengths[i] /
+    block)`` blocks of ``block`` positions and folds them with an online
+    softmax — operands as they are, f32 logits, statistics and accumulator —
+    the last one masked by position; what lies past it is neither copied nor
+    computed (the grid spans all ``L / block`` blocks, the index map holds at
+    the row's last). ``block`` 0 takes :func:`ragged_block`'s. Algebraically
+    the softmax of :func:`attention_reference`, not bit-identical to it."""
+    b, _, hq, d = q.shape
+    cache_len, hkv = k_cache.shape[1:3]
+    block = block or ragged_block(cache_len, hkv)
+    if not block or cache_len % block:
+        raise ValueError(f"no block of {block} positions tiles a cache of {cache_len}")
+    rows, cols = -(-hq // FLASH_ROW_TILE) * FLASH_ROW_TILE, block * hkv
+    lengths = jnp.clip(lengths.astype(jnp.int32), 1, cache_len)
+    q = q.reshape(b, hq, d)
+    if rows != hq:  # a packed bf16 tile is 16 rows; pad rows match no KV head
+        q = jnp.pad(q, ((0, 0), (0, rows - hq), (0, 0)))
+    row_head = np.full((rows, 1), -1, np.int32)
+    row_head[:hq, 0] = np.arange(hq) // (hq // hkv)
+    col = np.arange(cols, dtype=np.int32)[None]
+
+    def kv_index(i, j, lens):
+        return i, jnp.minimum(j, (lens[i] - 1) // block), 0
+
+    per_row = pl.BlockSpec((None, rows, d), lambda i, j, lens: (i, 0, 0))
+    kv_block = pl.BlockSpec((None, cols, d), kv_index)
+    whole = lambda *shape: pl.BlockSpec(shape, lambda i, j, lens: (0, 0))
+    out = pl.pallas_call(
+        functools.partial(_ragged_decode_kernel, block=block,
+                          sm_scale=scale if scale is not None else 1.0 / math.sqrt(d)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, cache_len // block),
+            in_specs=[per_row, kv_block, kv_block,
+                      whole(rows, 1), whole(1, cols), whole(1, cols)],
+            out_specs=per_row,
+            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret, name="ragged_decode_attention",
+    )(lengths, q, k_cache.reshape(b, cache_len * hkv, d),
+      v_cache.reshape(b, cache_len * hkv, d), row_head, col % hkv, col // hkv)
+    return out[:, :hq].reshape(b, 1, hq, d)
+
+
+_ragged_calls = threading.local()
+
+
+@contextlib.contextmanager
+def ragged_calls():
+    """Collects ``(block, cache length)`` of every call that
+    :func:`cached_attention` hands to the ragged kernel while a step is
+    traced inside: what the engine counts its KV reads from."""
+    outer, calls = getattr(_ragged_calls, "calls", None), []
+    _ragged_calls.calls = calls
+    try:
+        yield calls
+    finally:
+        _ragged_calls.calls = outer
+
+
+def kv_positions(calls: list, lengths):
+    """[2] int32 for one decode step over rows of ``lengths`` [B]: the
+    positions the ``calls``' blocks cover (``ceil(length / block) * block`` a
+    row and call) and the positions their caches hold."""
+    read = sum(jnp.sum(jnp.minimum(-(-lengths // block) * block, cache_len))
+               for block, cache_len in calls)
+    return jnp.stack([read, lengths.shape[0] * sum(n for _, n in calls)]).astype(jnp.int32)
+
+
+def cached_attention(q, k_cache, v_cache, q_offset, *, impl: str = "auto",
+                     mesh: Mesh | None = None, scale: float | None = None,
+                     logit_softcap: float = 0.0, window: int = 0, key_positions=None):
+    """Causal attention of q [B, S, H, D] (positions ``q_offset`` onwards)
+    against a KV cache [B, L, Hkv, D] that already holds their keys and
+    values. Returns [B, S, H, D]; the pick is recorded (:func:`note_choice`).
+
+    Who takes the ragged kernel (:func:`decode_attention`) is read off the
+    inputs: one query a row, a per-row offset vector, plain causal attention
+    (no ``key_positions`` — a ring is full after its length, nothing to skip —
+    no ``window``, no ``logit_softcap``), heads of a multiple of 128, whole
+    tiles of KV heads, a cache that :func:`ragged_block` cuts in two or more
+    blocks of ``RAGGED_MIN_BLOCK`` positions or more,
+    the TPU backend, one device (a bare Mosaic call cannot be partitioned).
+    Everything else is :func:`attention_reference` as before. ``impl``
+    ``"ragged"`` (``"ragged+interpret"`` on the CPU) asks for the kernel by
+    name wherever it can run at all; any other name leaves the choice here."""
+    name, _, flag = impl.partition("+")
+    (_, qlen, hq, d), (cache_len, hkv) = q.shape, k_cache.shape[1:3]
+    plain = (qlen == 1 and jnp.ndim(q_offset) == 1 and key_positions is None
+             and not window and not logit_softcap)
+    block = ragged_block(cache_len, hkv) if plain else 0
+    if name != "ragged" and not (
+            block >= RAGGED_MIN_BLOCK and d % 128 == 0 and hkv % 8 == 0
+            and jax.default_backend() == "tpu" and (mesh is None or mesh.size == 1)):
+        block = 0
+    note_choice("ragged" if block else "reference", qlen, cache_len, mesh, group=hq // hkv)
+    if block:
+        calls = getattr(_ragged_calls, "calls", None)
+        if calls is not None:
+            calls.append((block, cache_len))
+        return decode_attention(q, k_cache, v_cache, q_offset + 1, scale, block=block,
+                                interpret=flag == "interpret")
+    t = lambda x: x.transpose(0, 2, 1, 3)
+    return t(attention_reference(
+        t(q), t(k_cache), t(v_cache), causal=True, q_offset=q_offset, scale=scale,
+        logit_softcap=logit_softcap, window=window, key_positions=key_positions))
+
+
 def note_choice(impl: str, sq: int, sk: int, mesh: Mesh | None = None,
                 group: int = 1) -> None:
     """Record — at TRACE time, once per attention call site — which
@@ -275,10 +470,11 @@ def note_choice(impl: str, sq: int, sk: int, mesh: Mesh | None = None,
     whose name carries the decision (``attention.flash[144x144]+pad[256x256]``),
     so ``/v1/trace`` (and ``MODELX_TRACE=1`` logs) show what ``impl="auto"``
     chose for each length without a new surface. ``group`` is query heads per
-    KV head: the reference contracts them grouped (``+gqa4``), every other
-    implementation repeats the KV heads."""
+    KV head: the reference contracts them grouped (``+gqa4``) and the ragged
+    decode kernel masks each onto its own KV head; every other implementation
+    repeats the KV heads."""
     name = f"attention.{impl}[{sq}x{sk}]"
-    if impl == "reference" and group > 1:
+    if impl in ("reference", "ragged") and group > 1:
         name += f"+gqa{group}"
     if impl == "flash":
         pq, pk = flash_blocks(sq)[1], flash_blocks(sk)[1]

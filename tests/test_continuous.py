@@ -899,6 +899,103 @@ class TestChunkedPrefillPrefixCache:
         assert stored_len == pad_seq_len(40)
 
 
+class TestRaggedDecodeAttention:
+    """The engine with the ragged decode kernel asked for by name (pallas
+    interpret mode here; on one TPU device the same shapes' bigger cousins
+    take it unasked): rows read only the KV blocks their context reaches,
+    and the tokens are the reference path's."""
+
+    @pytest.fixture(scope="class")
+    def ragged(self, server):
+        import copy
+        import dataclasses
+
+        from modelx_tpu.models import llama
+
+        def decode_fns(cfg, mesh=None):
+            def fwd(p, t, kv_cache, cache_offset, mesh=mesh):
+                return llama.forward(p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset,
+                                     mesh=mesh, attention_impl="ragged+interpret")
+            return fwd, (lambda b, max_len: llama.init_kv_cache(cfg, b, max_len))
+
+        named = copy.copy(server)
+        named.family = dataclasses.replace(server.family, decode_fns=decode_fns)
+        cb = ContinuousBatcher(named, max_slots=4, chunk_size=4)
+        yield cb
+        cb.close()
+
+    @pytest.mark.parametrize("prompt,new", [(5, 11), (16, 50), (40, 40), (3, 70)])
+    def test_greedy_tokens_are_the_reference_paths(self, server, ragged, prompt, new):
+        """Contexts that end in the first 32-position block, cross into the
+        second and reach the third of a 96-position cache."""
+        tokens = np.random.default_rng(prompt).integers(1, 64, (1, prompt)).astype(np.int32)
+        np.testing.assert_array_equal(ragged.generate(tokens, max_new_tokens=new),
+                                      server.generate(tokens, max_new_tokens=new))
+
+    def test_sampled_rows_at_different_depths(self, server, ragged):
+        prompts = [np.array([[3, 4, 5]], np.int32), np.arange(1, 31, dtype=np.int32)[None]]
+        kw = dict(max_new_tokens=24, temperature=0.8, top_k=12, top_p=0.9, seed=41)
+        outs: list = [None, None]
+
+        def run(i):
+            outs[i] = ragged.generate(prompts[i], **kw)
+
+        threads = [threading.Thread(target=run, args=(i,)) for i in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        for tokens, out in zip(prompts, outs):
+            np.testing.assert_array_equal(out, server.generate(tokens, **kw))
+
+    def test_the_engine_counts_the_positions_its_blocks_cover(self, server, ragged, engine):
+        ragged.generate(np.array([[5, 9, 2, 7, 1]], np.int32), max_new_tokens=40)
+        snap = ragged.snapshot()
+        steps = snap["chunks"] * ragged.chunk_size
+        layers, block = server.cfg.num_layers, 32
+        assert snap["attn_kv_positions_cached"] == steps * 4 * 96 * layers
+        read = snap["attn_kv_positions_read"]
+        # idle slots stay at offset 0 onwards (one block a step and layer);
+        # a live row past position 32 reads two
+        assert read % block == 0
+        assert steps * 4 * block * layers < read < snap["attn_kv_positions_cached"] / 2
+        # an engine none of whose layers took the kernel has no such counter
+        engine.generate(np.array([[5, 9, 2]], np.int32), max_new_tokens=6)
+        assert not any(k.startswith("attn_kv") for k in engine.snapshot())
+
+
+def record_idle_offsets(cb) -> list:
+    """Every later dispatch of ``cb`` appends the offsets it hands the
+    device for the slots that hold no row."""
+    seen, chunk_args = [], cb._chunk_args
+
+    def recording(filtered):
+        seen.append(cb._offsets[cb._free].copy())
+        return chunk_args(filtered)
+
+    cb._chunk_args = recording
+    return seen
+
+
+@pytest.mark.parametrize("page_size", [0, 16], ids=["dense", "paged"])
+def test_an_idle_slots_offset_is_held_at_zero(server, page_size):
+    """A slot without a row decodes garbage from offset 0 in every dispatch:
+    its offset does not drift upwards with the live rows' (attention over a
+    cache would read ever more for it), before its first row and after its
+    last."""
+    cb = ContinuousBatcher(server, max_slots=4, chunk_size=4, page_size=page_size)
+    try:
+        seen = record_idle_offsets(cb)
+        tokens = np.array([[5, 9, 2, 7, 1]], np.int32)
+        first = cb.generate(tokens, max_new_tokens=30)
+        np.testing.assert_array_equal(cb.generate(tokens, max_new_tokens=30), first)
+        assert len(seen) >= 4 and all(len(idle) >= 3 for idle in seen)
+        assert not np.concatenate(seen).any()
+        assert not cb._offsets.any()
+    finally:
+        cb.close()
+
+
 class TestOtherFamilies:
     def test_gpt2_engine_clamps_to_n_positions_and_matches(self, tmp_path):
         """ServerSet.continuous_for must cap the engine's max_len at gpt2's

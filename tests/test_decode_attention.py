@@ -1,0 +1,203 @@
+"""The ragged decode-attention kernel (``ops.attention.decode_attention``) and
+the rule that picks it (``cached_attention``). The oracle is
+``attention_reference`` over the whole cache; the kernel runs in pallas
+interpret mode here, asked for by name. What interpret mode cannot see —
+tiling, the cache read as it lies — is in tests/test_tpu_compile.py."""
+
+import importlib
+import json
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from modelx_tpu.ops import attention as attn
+from modelx_tpu.utils.trace import tracer
+
+L, BLOCK, HKV, D = 64, 16, 2, 128
+LENGTHS = {"one": 1, "block-1": BLOCK - 1, "block": BLOCK, "block+1": BLOCK + 1, "all": L}
+
+
+def _qkv(rows, group, dtype=jnp.float32, d=D, hkv=HKV, cache_len=L, qlen=1):
+    rng = np.random.RandomState(group * 10 + rows)
+    q = jnp.asarray(rng.randn(rows, qlen, hkv * group, d), dtype)
+    k, v = (jnp.asarray(rng.randn(rows, cache_len, hkv, d), dtype) for _ in range(2))
+    return q, k, v
+
+
+def reference(q, k, v, offsets, **kwargs):
+    t = lambda x: x.transpose(0, 2, 1, 3)
+    return t(attn.attention_reference(t(q), t(k), t(v), causal=True, q_offset=offsets, **kwargs))
+
+
+@pytest.mark.parametrize("length", LENGTHS)
+@pytest.mark.parametrize("group", [1, 4, 6, 9])
+def test_the_kernel_gives_the_references_values(group, length):
+    """Every group size the families have (MHA, Mixtral's 4, Laguna's 6 and
+    9: 18 query heads pad to two row tiles), rows that end inside the first
+    block, on a block's edge, one past it, and at the cache's end."""
+    q, k, v = _qkv(3, group)
+    lengths = jnp.full((3,), LENGTHS[length], jnp.int32)
+    got = attn.decode_attention(q, k, v, lengths, block=BLOCK, interpret=True)
+    np.testing.assert_allclose(got, reference(q, k, v, lengths - 1), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("group", [1, 4, 6, 9])
+def test_a_ragged_batch_with_an_idle_row(group):
+    """Rows at their own depths in one call, one of them an idle slot at
+    offset 0, and a custom scale."""
+    q, k, v = _qkv(5, group)
+    offsets = jnp.asarray([0, 37, BLOCK - 1, 2 * BLOCK, L - 1], jnp.int32)
+    got = attn.decode_attention(q, k, v, offsets + 1, 0.11, block=BLOCK, interpret=True)
+    np.testing.assert_allclose(got, reference(q, k, v, offsets, scale=0.11),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_bf16_operands_keep_f32_statistics():
+    q, k, v = _qkv(3, 6, jnp.bfloat16)
+    offsets = jnp.asarray([3, 40, L - 1], jnp.int32)
+    got = attn.decode_attention(q, k, v, offsets + 1, block=BLOCK, interpret=True)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(got.astype(jnp.float32),
+                               reference(q, k, v, offsets).astype(jnp.float32), atol=2e-2)
+
+
+def test_a_length_past_the_cache_reads_the_whole_cache_and_no_further():
+    q, k, v = _qkv(2, 4)
+    got = attn.decode_attention(q, k, v, jnp.asarray([L + 9, 0]), block=BLOCK, interpret=True)
+    want = reference(q, k, v, jnp.asarray([L - 1, 0]))
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("cache_len,kv_heads,block", [
+    (4096, 8, 256), (2048, 8, 256), (1024, 8, 256), (384, 8, 128), (256, 8, 128),
+    (128, 8, 64), (4096, 32, 64), (4096, 16, 128), (128, 2, 64), (96, 2, 32), (1, 2, 0)])
+def test_the_block_divides_the_cache_at_least_twice(cache_len, kv_heads, block):
+    assert attn.ragged_block(cache_len, kv_heads) == block
+
+
+# -- who takes the kernel -----------------------------------------------------
+
+ROWS, CACHE = 4, 512
+OFFSETS = jax.ShapeDtypeStruct((ROWS,), jnp.int32)
+
+
+def _shapes(d=128, hkv=8, group=4, qlen=1, cache_len=CACHE):
+    q = jax.ShapeDtypeStruct((ROWS, qlen, hkv * group, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((ROWS, cache_len, hkv, d), jnp.bfloat16)
+    return q, kv, kv
+
+
+# what keeps attention_reference, on a TPU too: (shapes, offsets, keywords)
+KEEPS_THE_REFERENCE = {
+    "phi3_head_dim_96": (_shapes(d=96, hkv=32, group=1), OFFSETS, {}),
+    "a_ring": (_shapes(), OFFSETS,
+               {"key_positions": jax.ShapeDtypeStruct((ROWS, CACHE), jnp.int32)}),
+    "a_query_of_16": (_shapes(qlen=16), OFFSETS, {}),
+    "a_softcap": (_shapes(), OFFSETS, {"logit_softcap": 30.0}),
+    "a_window": (_shapes(), OFFSETS, {"window": 64}),
+    "a_scalar_offset": (_shapes(), jax.ShapeDtypeStruct((), jnp.int32), {}),
+    "two_kv_heads": (_shapes(hkv=2), OFFSETS, {}),
+    "a_cache_of_one_block": (_shapes(cache_len=128), OFFSETS, {}),
+}
+
+
+@pytest.fixture
+def on_a_tpu(monkeypatch):
+    """The rule asks for the backend; a test steers it, no option does."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.parametrize("case", KEEPS_THE_REFERENCE)
+def test_what_is_not_a_plain_decode_step_lowers_as_the_reference_did(on_a_tpu, case):
+    (q, k, v), offsets, kwargs = KEEPS_THE_REFERENCE[case]
+    arrays = {n: a for n, a in kwargs.items() if isinstance(a, jax.ShapeDtypeStruct)}
+    static = {n: a for n, a in kwargs.items() if n not in arrays}
+
+    def picked(q, k, v, off, **arrays):
+        return attn.cached_attention(q, k, v, off, **static, **arrays)
+
+    def direct(q, k, v, off, **arrays):
+        return reference(q, k, v, off, **static, **arrays)
+
+    got = jax.jit(picked).lower(q, k, v, offsets, **arrays).as_text()
+    want = jax.jit(direct).lower(q, k, v, offsets, **arrays).as_text()
+    assert got.replace("jit_picked", "jit_direct") == want
+    assert "custom_call" not in got and "pallas" not in got
+
+
+def test_a_plain_decode_step_takes_the_kernel_on_one_tpu_device_only(on_a_tpu):
+    q, k, v = _shapes(group=6)
+    take = lambda **kw: str(jax.make_jaxpr(
+        lambda q, k, v, off: attn.cached_attention(q, k, v, off, **kw))(q, k, v, OFFSETS))
+    tracer().clear()
+    assert "pallas_call" in take() and "ragged_decode_attention" in take()
+    assert "attention.ragged[1x512]+gqa6" in tracer().summary("attention.")
+    from modelx_tpu.parallel.mesh import make_mesh
+
+    assert "pallas_call" in take(mesh=make_mesh("dp=1", devices=jax.devices()[:1]))
+    if len(jax.devices()) > 1:
+        assert "pallas_call" not in take(mesh=make_mesh("dp=2", devices=jax.devices()[:2]))
+
+
+def test_on_the_cpu_nothing_takes_the_kernel_unless_asked_by_name():
+    q, k, v = _shapes()
+    jaxpr = lambda impl: str(jax.make_jaxpr(lambda q, k, v, off: attn.cached_attention(
+        q, k, v, off, impl=impl))(q, k, v, OFFSETS))
+    assert "pallas_call" not in jaxpr("auto") and "pallas_call" not in jaxpr("flash+interpret")
+    assert "pallas_call" in jaxpr("ragged+interpret")
+
+
+def test_the_engine_counts_what_the_calls_blocks_cover():
+    """``kv_positions``: ceil(length / block) * block a row and call, never
+    more than the cache, beside what the caches hold."""
+    lengths = jnp.asarray([1, 256, 257, 5000], jnp.int32)
+    got = attn.kv_positions([(256, 4096), (128, 512)], lengths)
+    assert got.tolist() == [256 + 256 + 512 + 4096 + 128 + 256 + 384 + 512, 4 * (4096 + 512)]
+    with attn.ragged_calls() as outer:
+        with attn.ragged_calls() as inner:
+            pass
+        assert getattr(attn._ragged_calls, "calls") is outer and inner == []
+
+
+# -- the two per-layer metrics that read the counters -------------------------
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CELLS = {"attn.kv_read_share.reason": "laguna-s-2.1-ep2-d5.reason",
+         "attn.kv_read_share.decode": "mixtral-8x7b-d4.decode"}
+
+
+def read_metric(name, sources):
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+        spec = json.load(f)
+    reader = importlib.import_module(f"benchmark.layer_metrics.readers.{spec['reader']}")
+    return reader.read(sources, spec)
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_the_read_share_is_the_counters_growth_over_the_window(name):
+    dump = lambda read, cached: {"default": {"continuous": {  # noqa: E731
+        "attn_kv_positions_read": read, "attn_kv_positions_cached": cached, "chunks": 9}}}
+    sources = {"metrics_before": dump(1000, 2000), "metrics_after": dump(4000, 12000)}
+    assert read_metric(name, sources) == pytest.approx(0.3)
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_a_pod_without_the_counters_reports_no_read_share(name):
+    """The parent commit, or a model none of whose layers took the kernel:
+    nothing is read, nothing raises, and the line leaves the metric out."""
+    parent = {"default": {"continuous": {"chunks": 9, "decode_rows": 64}}}
+    assert read_metric(name, {"metrics_before": parent, "metrics_after": parent}) is None
+    assert read_metric(name, {}) is None
+
+
+def test_benchmark_json_ends_with_the_two_read_shares():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        last = json.load(f)["per_layer"][-2:]
+    assert {m["name"]: m["workloads"] for m in last} == {n: [c] for n, c in CELLS.items()}
+    for m in last:
+        assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"]) == (
+            "ratio", "lower", "program_counter", "Kernels / model step", "tokens_per_s")

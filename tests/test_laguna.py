@@ -262,6 +262,78 @@ def test_the_engine_counts_its_expert_layers_and_its_caches_by_kind(half, engine
                   "window_positions": 32, "positions_full": 0, "positions_window": 0}
 
 
+@pytest.fixture(scope="module")
+def ragged_engine(half):
+    """The engine with the ragged decode kernel asked for by name (pallas
+    interpret mode): the two full layers read two 64-position blocks of
+    their 128-position caches at most, the three rings keep the reference."""
+    import copy
+
+    srv = half[0]
+
+    def by_name(cfg, mesh=None):
+        fns = srv.family.layer_kind_decode_fns(cfg, mesh=mesh)
+
+        def fwd(p, t, kv_cache, cache_offset, mesh=mesh):
+            return laguna.forward(p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset,
+                                  mesh=mesh, ring=True, attention_impl="ragged+interpret")
+        return {**fns, "fwd": fwd}
+
+    named = copy.copy(srv)
+    named.family = dataclasses.replace(srv.family, layer_kind_decode_fns=by_name)
+    cb = ContinuousBatcher(named, max_slots=SLOTS, chunk_size=4)
+    yield cb
+    cb.close()
+
+
+@pytest.mark.parametrize("prompt_len,new", [(5, 60), (40, 70), (70, 40)])
+def test_the_ragged_kernel_on_the_full_layers_follows_the_reference(half, ragged_engine,
+                                                                    prompt_len, new):
+    """As the engine's own test above: every token is the float32
+    reference's argmax of the full forward, to rounding — through contexts
+    that end in a full layer's first block and in its second."""
+    _, hf, raw, _ = half
+    prompt = np.random.default_rng(prompt_len).integers(1, VOCAB, (1, prompt_len))
+    out = np.asarray(ragged_engine.generate(prompt, max_new_tokens=new))[0][-new:]
+    seq = np.concatenate([prompt[0], out])
+    logits = ref_logits(hf, raw, seq, positions=list(range(prompt_len - 1, len(seq) - 1)))
+    below = logits.max(-1) - logits[np.arange(new), out]
+    assert below.max() < 1e-3, (int(below.argmax()), float(below.max()))
+
+
+def test_the_ragged_engine_counts_its_full_layers_reads_beside_the_expert_counts(
+        half, ragged_engine, engine):
+    ragged_engine.generate(np.ones((1, 8), np.int32), max_new_tokens=12)
+    snap = ragged_engine.snapshot()
+    steps = snap["chunks"] * ragged_engine.chunk_size
+    # the two full layers of five; the rings are not counted
+    assert snap["attn_kv_positions_cached"] == steps * SLOTS * MAX_LEN * 2
+    assert steps * SLOTS * 64 * 2 <= snap["attn_kv_positions_read"] < (
+        snap["attn_kv_positions_cached"])
+    assert snap["moe"]["assignments"] % (SLOTS * half[0].cfg.top_k * 4) == 0
+    assert snap["moe"]["assignments"] > 0
+    assert not any(k.startswith("attn_kv") for k in engine.snapshot())
+
+
+def test_an_idle_slots_offset_is_held_at_zero_on_a_cache_per_layer_kind(engine):
+    """As ``tests/test_continuous.py`` holds for the dense and the paged
+    layout: a slot without a row starts every dispatch at offset 0."""
+    seen, chunk_args = [], engine._chunk_args
+
+    def recording(filtered):
+        seen.append(engine._offsets[engine._free].copy())
+        return chunk_args(filtered)
+
+    engine._chunk_args = recording
+    try:
+        engine.generate(np.ones((1, 8), np.int32), max_new_tokens=60)
+    finally:
+        del engine._chunk_args
+    assert len(seen) >= 3 and all(len(idle) >= SLOTS - 1 for idle in seen)
+    assert not np.concatenate(seen).any()
+    assert not engine._offsets.any()
+
+
 def test_the_chunk_programs_name_carries_its_depth(engine):
     """A device trace must say how many steps a run of the program made."""
     args = (engine.server.params, engine._cache, engine._tok, *engine._chunk_args(False))

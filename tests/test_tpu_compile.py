@@ -139,3 +139,94 @@ def test_lagunas_decode_step_fits_one_chip_with_a_cache_per_layer_kind(topo):
     assert live < 15.75 * 2**30 - 1.5e9  # room for an admission's scratch beside it
     uniform = 2 * 5_572_076_544 + 5 * 2 * 64 * 4096 * 8 * 128 * 2
     assert uniform > 15.75 * 2**30 - 1.5e9
+
+
+# -- the ragged decode kernel (ops.attention.decode_attention) ----------------
+
+# (rows, query heads, cache length): the decode cells' full-attention layers
+DECODE_SHAPES = {"laguna_full_layer": (64, 48, 4096), "mixtral": (32, 32, 2048)}
+
+
+def _decode_args(one, rows, heads, cache_len):
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    kv = sds((rows, cache_len, 8, 128), jnp.bfloat16)
+    return sds((rows, 1, heads, 128), jnp.bfloat16), kv, kv, sds((rows,), jnp.int32)
+
+
+@pytest.mark.parametrize("shape", DECODE_SHAPES)
+def test_ragged_decode_kernel_at_the_cells_widths(topo, shape):
+    """Mosaic takes the kernel at both cells' shapes (48 and 32 query heads
+    over 8 KV heads of 128, 256-position blocks of the cache viewed as
+    ``[rows, L * 8, 128]``), and that view costs nothing: no temporary of a
+    cache leaf's size exists in the program."""
+    rows, heads, cache_len = DECODE_SHAPES[shape]
+    args = _decode_args(SingleDeviceSharding(topo.devices[0]), rows, heads, cache_len)
+    compiled = jax.jit(attn.decode_attention).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("shape", DECODE_SHAPES)
+def test_ragged_decode_kernel_inside_a_scan_reads_the_cache_as_it_lies(topo, shape):
+    """As the engine's chunk program holds it: a ``lax.scan`` whose step
+    writes each row's new key and value into the donated caches and then
+    attends. The caches reach the kernel by bitcast — no copy, no leaf-sized
+    temporary — and leave the program aliased to their inputs."""
+    rows, heads, cache_len = DECODE_SHAPES[shape]
+    q, k, v, offsets = _decode_args(SingleDeviceSharding(topo.devices[0]), rows, heads, cache_len)
+
+    def chunk(q, k, v, offsets):
+        write = jax.vmap(lambda c, u, o: jax.lax.dynamic_update_slice(c, u, (o, 0, 0)))
+
+        def step(carry, _):
+            q, k, v, offsets = carry
+            k, v = write(k, q[:, :, :8], offsets), write(v, q[:, :, 8:16], offsets)
+            out = attn.decode_attention(q, k, v, offsets + 1)
+            return ((q + out).astype(q.dtype), k, v, offsets + 1), None
+
+        return jax.lax.scan(step, (q, k, v, offsets), None, length=8)[0]
+
+    compiled = jax.jit(chunk, donate_argnums=(1, 2)).lower(q, k, v, offsets).compile()
+    text, m = compiled.as_text(), compiled.memory_analysis()
+    assert "tpu_custom_call" in text
+    leaf = rows * cache_len * 8 * 128 * 2
+    assert m.temp_size_in_bytes < 2**20 and m.alias_size_in_bytes == 2 * leaf
+    operands = [line for line in text.splitlines() if f"bf16[{rows},{cache_len * 8},128]" in line
+                and "= bf16" in line and "custom-call" not in line]
+    assert operands and all(" bitcast(" in line for line in operands)
+
+
+@pytest.mark.slow
+def test_lagunas_decode_step_takes_the_ragged_kernel_on_its_full_layers_only(topo, monkeypatch):
+    """The cell's decode step with the rule steered to a TPU (the compile
+    runs where ``default_backend`` says cpu): two Mosaic calls, one a full
+    layer — the three rings keep the reference — and the f32 logits over all
+    4096 positions are gone from the program."""
+    import json
+
+    from modelx_tpu.models import laguna
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "laguna-s-2.1-ep2-d5.json")) as f:
+        cfg = laguna.config_from_hf(json.load(f))
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    params = {k: sds(v, jnp.bfloat16) for k, v in laguna.param_shapes(cfg).items()}
+    state = jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: laguna.init_layer_state(cfg, 64, 4096)))
+
+    def step(params, state, tok, offsets):
+        with attn.ragged_calls() as calls:
+            logits, state = laguna.forward(params, tok, cfg, kv_cache=state,
+                                           cache_offset=offsets, ring=True)
+        assert calls == [(256, 4096), (256, 4096)]
+        return state, jnp.argmax(logits[:, -1], -1)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, state, sds((64, 1), jnp.int32), sds((64,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "f32[64,8,6,4096]" not in text and "f32[64,8,9,528]" in text
