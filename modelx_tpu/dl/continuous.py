@@ -632,6 +632,8 @@ class ContinuousBatcher:
             self.stats["prefill_chunk"] = self.prefill_chunk
             self.stats["fill_waits"] = 0  # page-blocked boundaries
             self.stats["fill_preempts"] = 0  # fills restarted for pages
+            # pieces dispatched and the prompt tokens they landed
+            self.stats["fill"] = {"pieces": 0, "tokens": 0}
         if self.boundary_watchdog_s > 0:
             self.stats["watchdog_stalls"] = 0
         self._phases = trace.Phases("continuous.boundary", _PHASES)
@@ -743,7 +745,8 @@ class ContinuousBatcher:
         max_slots-row prefill and compiles stay bounded at log2(max_slots)
         sizes per prompt bucket."""
         small = self._init_cache(prompts.shape[0], prompts.shape[1])
-        logits, small = self._fwd(params, prompts, kv_cache=small, cache_offset=0)
+        logits, small = self._fwd(params, prompts, kv_cache=small, cache_offset=0,
+                                  **self.kv.block_kwargs(row_lens))
         firsts = self._sample_first(logits, row_lens - 1, temp, top_k, top_p,
                                     seeds, step=first_steps)
         cache = self.kv.put_many(cache, small, where)
@@ -769,7 +772,8 @@ class ContinuousBatcher:
         scratch cache (allocated INSIDE the jit — zeros fuse, no host
         transfer), then the shared admit tail."""
         small = self._init_cache(1, prompt.shape[1])
-        logits, small = self._fwd(params, prompt, kv_cache=small, cache_offset=0)
+        logits, small = self._fwd(params, prompt, kv_cache=small, cache_offset=0,
+                                  **self.kv.block_kwargs(row_len))
         return self._finish_admit(small, logits, cache, tok, row_len - 1, where,
                                   temp, top_k, top_p, seed, first_step)
 
@@ -800,15 +804,17 @@ class ContinuousBatcher:
 
     # -- chunked prefill piece programs ---------------------------------------
 
-    def _piece(self, params, piece, cache, filled, where):
+    def _piece(self, params, piece, cache, filled, where, last_idx=None):
         """Land one [1, Sb] prefill piece: view the slot's own [1, max_len]
         rows — a mid-prompt piece needs the row's earlier KV as attention
         context, unlike admission's fresh offset-0 scratch — run the block
         at offset ``filled`` (positions/causality follow the decode
         contract, so the landed KV is byte-identical to the same span of a
-        monolithic prefill), write back what it wrote."""
+        monolithic prefill), write back what it wrote. ``last_idx``: the
+        piece's last real token where its bucket is padded (the last piece)."""
         row = self.kv.view(cache, where, self.max_len)
-        logits, row = self._fwd(params, piece, kv_cache=row, cache_offset=filled)
+        logits, row = self._fwd(params, piece, kv_cache=row, cache_offset=filled,
+                                **self.kv.block_kwargs(last_idx=last_idx))
         return logits, self.kv.put_piece(cache, row, where)
 
     def _piece_impl(self, params, piece, cache, filled, where):
@@ -822,7 +828,7 @@ class ContinuousBatcher:
         from the piece's final real position — step ``first_step`` of the
         row's (seed, step) stream (0 fresh, k on resume), byte-identical
         to single-program admission."""
-        logits, cache = self._piece(params, piece, cache, filled, where)
+        logits, cache = self._piece(params, piece, cache, filled, where, last_idx)
         first = self._sample_first(logits, last_idx, temp, top_k, top_p, seed,
                                    step=first_step)
         tok = jax.lax.dynamic_update_slice(
@@ -861,11 +867,15 @@ class ContinuousBatcher:
         from modelx_tpu.ops import sampling as sampling_ops
 
         *where, offsets, steps, temp, top_k, top_p, seeds = args
+        # a layout that keeps states is told which rows decode (from the
+        # dispatch's own offsets and steps): an idle or filling slot's state
+        # must come out of the scan as it went in. The others are told nothing
+        told = self.kv.step_kwargs(offsets, steps)
 
         def step_fn(carry, _i):
             cache, tok, offsets, steps = carry
             with attn_ops.ragged_calls() as ragged:
-                logits, cache = self.kv.step(params, tok, cache, offsets, *where)
+                logits, cache = self.kv.step(params, tok, cache, offsets, *where, **told)
             # where layers of this step took the ragged kernel, the KV
             # positions their blocks cover and the positions cached, over
             # all slots; None (nothing is added to the program) where none did
@@ -1457,6 +1467,8 @@ class ContinuousBatcher:
         offset = jnp.int32(fill.filled)
         where = self.kv.at(slot, fill.filled, piece_len)
         self.stats["prefill_pieces"] += 1
+        self.stats["fill"]["pieces"] += 1
+        self.stats["fill"]["tokens"] += take
         fill.ticket.prefill_pieces += 1
         self._rec("fill_piece", slot=slot, request_id=fill.ticket.request_id,
                   tokens=take, last=last)

@@ -30,6 +30,7 @@ from modelx_tpu.dl.sharding import (
     GEMMA2_RULES,
     GPT2_RULES,
     LAGUNA_RULES,
+    MINICPM_SALA_RULES,
     PHI3_RULES,
     LLAMA_RULES,
     MIXTRAL_RULES,
@@ -66,8 +67,10 @@ class Family:
     # (cfg, mesh) -> {"fwd": forward over a cache PER LAYER KIND,
     # "init_state": (slots, max_len) -> that state, "kinds": leaf -> "full" /
     # "window" / "counter", "counters", "gauges"} — the continuous engine
-    # then keeps full layers [slots, max_len] and window layers as rings
-    # (dl/kv_layout.LayerKindKV); None = every layer's cache is alike
+    # then keeps full layers [slots, max_len], window layers as rings, a
+    # sparse layer's index of compressed keys ("index") and a linear-attention
+    # layer's state ("state", no position axis) (dl/kv_layout.LayerKindKV);
+    # None = every layer's cache is alike
     layer_kind_decode_fns: Callable[..., dict] | None = None
 
 
@@ -561,6 +564,86 @@ def _gpt2_paged_decode_fns(cfg, mesh=None):
     return fwd
 
 
+# -- minicpm_sala ---------------------------------------------------------------
+
+
+def infer_minicpm_sala_config(params: dict):
+    raise ValueError(
+        "a minicpm_sala checkpoint's mixer types, sparse settings, muP scales "
+        "and published depth leave no trace in tensor shapes: its config.json "
+        "must lie beside the weights")
+
+
+def minicpm_sala_config_from_sidecar(sidecar: dict, params: dict):
+    from modelx_tpu.models import minicpm_sala
+
+    return minicpm_sala.config_from_hf(
+        sidecar, dtype=_act_dtype(params, "model.embed_tokens.weight"))
+
+
+def _minicpm_sala_forward(params, tokens, cfg, mesh=None):
+    from modelx_tpu.models import minicpm_sala
+
+    return minicpm_sala.forward(params, tokens, cfg, mesh=mesh)[0]
+
+
+def _minicpm_sala_generate(params, tokens, cfg, mesh=None, max_new_tokens=16):
+    from modelx_tpu.models import minicpm_sala
+
+    return minicpm_sala.greedy_generate(
+        params, tokens, cfg, max_new_tokens=max_new_tokens, mesh=mesh)
+
+
+def _minicpm_sala_generate_ragged(params, tokens, row_lens, cfg, mesh=None,
+                                  max_new_tokens=16, **sampling):
+    from modelx_tpu.models import minicpm_sala
+
+    return minicpm_sala.ragged_greedy_generate(
+        params, tokens, row_lens, cfg, max_new_tokens=max_new_tokens, mesh=mesh,
+        **sampling,
+    )
+
+
+_UNTOLD = object()
+
+
+def _minicpm_sala_decode_fns(cfg, mesh=None):
+    from modelx_tpu.models import minicpm_sala
+
+    def fwd(p, t, kv_cache, cache_offset, mesh=mesh, valid_len=_UNTOLD, live=None):
+        # a padded bucket's tail would enter the lightning layers' states for
+        # good: a caller that lands a block of prompt positions says how many
+        # of them are real (None = all), as the continuous engine does
+        if t.shape[1] > 1 and valid_len is _UNTOLD:
+            raise ValueError(
+                "minicpm_sala: a block of prompt positions needs its rows' real "
+                "lengths (a state keeps what a padded tail adds): this family "
+                "streams through --continuous-batch")
+        return minicpm_sala.forward(
+            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh,
+            valid_len=None if valid_len is _UNTOLD else valid_len, live=live)
+
+    return fwd, (lambda b, max_len: minicpm_sala.init_kv_cache(cfg, b, max_len))
+
+
+def _minicpm_sala_layer_kind_decode_fns(cfg, mesh=None):
+    from modelx_tpu.models import minicpm_sala
+
+    fwd, _ = _minicpm_sala_decode_fns(cfg, mesh)
+    sparse_layers = cfg.mixer_types.count(minicpm_sala.SPARSE)
+    return {
+        "fwd": fwd,
+        "init_state": lambda slots, max_len: minicpm_sala.init_layer_state(cfg, slots, max_len),
+        "kinds": minicpm_sala.cache_kinds(cfg),
+        # what the decode step counts of its sparse layers, over the LIVE rows
+        "counters": {"sparse_counts": ("sparse", minicpm_sala.SPARSE_COUNTERS)},
+        "gauges": {"sparse": {"sparse_layers": sparse_layers,
+                              "linear_layers": cfg.num_layers - sparse_layers,
+                              "block_size": cfg.sparse.block_size, "topk": cfg.sparse.topk,
+                              "dense_len": cfg.sparse.dense_len}},
+    }
+
+
 # -- bert ---------------------------------------------------------------------
 
 
@@ -616,6 +699,11 @@ FAMILIES: dict[str, Family] = {
                      _laguna_generate, _laguna_generate_ragged, _laguna_decode_fns,
                      config_from_sidecar=laguna_config_from_sidecar,
                      layer_kind_decode_fns=_laguna_layer_kind_decode_fns),
+    "minicpm_sala": Family("minicpm_sala", MINICPM_SALA_RULES, infer_minicpm_sala_config,
+                           _minicpm_sala_forward, _minicpm_sala_generate,
+                           _minicpm_sala_generate_ragged, _minicpm_sala_decode_fns,
+                           config_from_sidecar=minicpm_sala_config_from_sidecar,
+                           layer_kind_decode_fns=_minicpm_sala_layer_kind_decode_fns),
     "gpt2": Family("gpt2", GPT2_RULES, infer_gpt2_config, _gpt2_forward,
                    _gpt2_generate, _gpt2_generate_ragged, _gpt2_decode_fns,
                    _gpt2_paged_decode_fns),

@@ -18,9 +18,14 @@ interface, and the engine never asks which it got:
   (``Family.layer_kind_decode_fns``): full-attention layers keep
   ``[max_slots, max_len]``, sliding-window layers a RING of the window plus
   one 16-token bucket a slot, written at ``position mod ring`` and masked by
-  absolute position — so HBM holds what each kind can ever attend to, not
-  ``max_len`` for all. What a ring cannot give (a view of an overwritten
-  prefix) is refused when the engine is built, with a message.
+  absolute position, sparse-attention layers an INDEX of compressed keys (one
+  row per ``max_len / n`` positions) beside their keys and values, and
+  linear-attention layers a STATE a slot with no position axis at all — so HBM
+  holds what each kind can ever attend to, not ``max_len`` for all. What a
+  leaf kind cannot give is refused when the engine is built, with a message
+  that names the kind: a ring no view of an overwritten prefix (so no chunked
+  prefill, no prefix cache), a state no cut at a token (so no prefix cache, no
+  speculative verify, no pages).
 
 Three kinds of method. Device state (``new_state``, ``abstract_state``).
 Host bookkeeping (``fits``/``reserve``/``release``/``never_holds``/``reset``)
@@ -59,10 +64,10 @@ def build(server, fwd, init_cache, stats: dict, *, max_slots: int,
     if paged_attention not in ("gather", "in-place"):
         raise ValueError(f"unknown paged_attention mode {paged_attention!r}")
     if server.family.layer_kind_decode_fns is not None:
-        LayerKindKV.refuse(server.family.name, page_size=page_size,
-                           prefix_cache=prefix_cache, prefill_chunk=prefill_chunk,
-                           speculative_k=speculative_k)
-        return LayerKindKV(server, fwd, init_cache, stats, max_slots, max_len)
+        return LayerKindKV(server, fwd, init_cache, stats, max_slots, max_len,
+                           asked={"page_size": page_size, "prefix_cache": prefix_cache,
+                                  "prefill_chunk": prefill_chunk,
+                                  "speculative_k": speculative_k})
     if page_size <= 0:
         return DenseKV(fwd, init_cache, server.mesh, stats, max_slots, max_len)
     return PagedKV(server, fwd, init_cache, stats, max_slots, max_len,
@@ -152,6 +157,19 @@ class DenseKV:
 
     def count_sweep(self, offsets: np.ndarray, n_steps: int) -> None:
         """Account one decode dispatch over rows at ``offsets``."""
+
+    def block_kwargs(self, valid_len=None, last_idx=None) -> dict:
+        """What the family's cached forward is told of a block of prompt
+        positions beyond the cache and the offset — its rows' real lengths,
+        given as such or as the last real index; None of both = the whole
+        block: nothing, where every leaf is addressed by position (a padded
+        bucket's tail is overwritten)."""
+        return {}
+
+    def step_kwargs(self, offsets, steps) -> dict:
+        """What it is told of a decode step over all slots: nothing, where an
+        idle slot's writes are harmless (``_chunk_impl``)."""
+        return {}
 
     def landed(self, toks: np.ndarray) -> None:
         """A chunk's token block has reached the host: what ``ride`` added
@@ -451,44 +469,70 @@ class LayerKindKV(DenseKV):
     the forward over it, its constructor and each leaf's kind: ``"full"``
     leaves are ``[max_slots, max_len]`` as in ``DenseKV``; ``"window"``
     leaves are rings ``[max_slots, ring]``, position p at index ``p mod
-    ring``; a ``"counter"`` leaf is a small vector the decode step adds to,
-    which goes home with each chunk's tokens (``ride`` / ``landed``).
+    ring``; an ``"index"`` leaf ``[max_slots, max_len / n]`` holds one row per
+    n positions (a sparse layer's compressed keys); a ``"state"`` leaf
+    ``[max_slots, ...]`` has no position axis (a linear-attention layer's
+    running sum: ``put`` copies a whole state, ``view`` / ``put_piece`` hand
+    the slot's state to a prefill piece and take it back); a ``"counter"``
+    leaf is a small vector the decode step adds to, which goes home with each
+    chunk's tokens (``ride`` / ``landed``).
 
-    A scratch cache (an admission's prefill) is dense for every layer; what
-    of it survives on a window layer is its last ``ring`` positions, rolled
-    to their ring indices. The ring is one 16-bucket longer than the window,
-    so the bucket's padding past the real prompt displaces only positions no
-    later query can see, and the mask by absolute position hides the padding
-    itself until decode overwrites it (models/laguna.ring_len).
+    A scratch cache (an admission's prefill) has the family's own leaves at
+    the prompt's bucket; what of it survives on a window layer is its last
+    ``ring`` positions, rolled to their ring indices. The ring is one
+    16-bucket longer than the window, so the bucket's padding past the real
+    prompt displaces only positions no later query can see, and the mask by
+    absolute position hides the padding itself until decode overwrites it
+    (models/laguna.ring_len). A state has no such slack — what enters it
+    stays — so a layout with states tells the family's forward how many of a
+    block's positions are real (``block_kwargs``) and which rows of a decode
+    step are live (``step_kwargs``): an idle or filling slot's state is left
+    bit for bit.
 
     Every slot's rows are its own, so a reservation always succeeds; what is
     counted is what the live reservations hold, by kind."""
 
+    # engine option -> (the leaf kinds that cannot carry it, what is refused, why)
     REFUSED = {
-        "page_size": "--kv-page-size (a ring is not paged)",
-        "prefix_cache": "--prefix-cache, and with it the KV store's bundles and resume "
-                        "from stored KV (a ring cannot give back a prefix it has overwritten)",
-        "prefill_chunk": "--prefill-chunk (a piece needs the slot's earlier rows as a dense "
-                         "view)",
-        "speculative_k": "--speculative-k (a verify block writes several ring positions a "
-                         "step)",
+        "page_size": (("window", "state"), "--kv-page-size",
+                      {"window": "a ring is not paged", "state": "a state has no pages"}),
+        "prefix_cache": (("window", "state"), "--prefix-cache, and with it the KV store's "
+                         "bundles and resume from stored KV",
+                         {"window": "a ring cannot give back a prefix it has overwritten",
+                          "state": "a state cannot be cut at a token"}),
+        "prefill_chunk": (("window",), "--prefill-chunk",
+                          {"window": "a piece needs the slot's earlier rows as a dense view, "
+                                     "and a ring has overwritten them"}),
+        "speculative_k": (("window", "state"), "--speculative-k",
+                          {"window": "a verify block writes several ring positions a step",
+                           "state": "a state cannot drop the tokens a verify rejects"}),
     }
 
     @classmethod
-    def refuse(cls, family: str, **asked) -> None:
+    def refuse(cls, family: str, kinds=("full", "window"), **asked) -> None:
         """Raise ``Refused`` naming every engine option in ``asked`` that is
-        set and that this layout does not carry."""
-        bad = [cls.REFUSED[k] for k, v in asked.items() if v]
+        set and that a layout with leaves of ``kinds`` does not carry, each
+        with the leaf kind that is the reason."""
+        bad = []
+        for option, value in asked.items():
+            cannot, what, why = cls.REFUSED[option]
+            reasons = [f"{why[k]} ({k!r} leaves)" for k in cannot if k in kinds]
+            if value and reasons:
+                bad.append(f"{what} ({'; '.join(reasons)})")
         if bad:
             raise Refused(
-                f"family {family} keeps a cache per layer kind (full layers whole, "
-                f"window layers as rings), which does not carry: {'; '.join(bad)}")
+                f"family {family} keeps a cache per layer kind "
+                f"({', '.join(sorted(set(kinds) - {'counter'}))} leaves), which does not "
+                f"carry: {'; '.join(bad)}")
 
     def __init__(self, server, fwd, init_cache, stats: dict, max_slots: int,
-                 max_len: int) -> None:
+                 max_len: int, asked: dict | None = None) -> None:
         super().__init__(fwd, init_cache, server.mesh, stats, max_slots, max_len)
         fns = server.family.layer_kind_decode_fns(server.cfg, mesh=server.mesh)
         self.fwd_kinds, self.init_state, self.kinds = fns["fwd"], fns["init_state"], fns["kinds"]
+        have = set(self.kinds.values())
+        self.refuse(server.family.name, tuple(have), **(asked or {}))
+        self.has_state = "state" in have
         # counter leaf -> (the stats block it feeds, its entries' names)
         self.counters: dict[str, tuple[str, tuple]] = fns.get("counters", {})
         self.counter_rows = sum(len(names) for _, names in self.counters.values())
@@ -503,6 +547,9 @@ class LayerKindKV(DenseKV):
         stats["kv"] = {"bytes_full": bytes_of("full"), "bytes_window": bytes_of("window"),
                        "window_positions": self.ring, "positions_full": 0,
                        "positions_window": 0}
+        if have & {"index", "state"}:
+            stats["kv"].update(bytes_index=bytes_of("index"), bytes_state=bytes_of("state"),
+                               states_live=0)
         for block, gauges in fns.get("gauges", {}).items():
             stats[block] = dict(gauges)
         self._last: dict[str, np.ndarray] = {}
@@ -529,6 +576,8 @@ class LayerKindKV(DenseKV):
         kv = self.stats["kv"]
         kv["positions_full"] = sum(self._held.values())
         kv["positions_window"] = sum(min(t, self.ring) for t in self._held.values())
+        if "states_live" in kv:
+            kv["states_live"] = len(self._held)
 
     def fits(self, tokens: int) -> bool:
         return tokens <= self.max_len
@@ -560,8 +609,18 @@ class LayerKindKV(DenseKV):
 
     # -- traced primitives ----------------------------------------------------
 
-    def step(self, params, block, cache, offsets):
-        return self.fwd_kinds(params, block, kv_cache=cache, cache_offset=offsets)
+    def block_kwargs(self, valid_len=None, last_idx=None) -> dict:
+        if not self.has_state:
+            return {}
+        return {"valid_len": valid_len if last_idx is None else last_idx + 1}
+
+    def step_kwargs(self, offsets, steps) -> dict:
+        # from what the program carries: an idle slot sits at offset 0, a
+        # filling one at its frontier with no step taken yet
+        return {"live": (offsets > 0) & (steps > 0)} if self.has_state else {}
+
+    def step(self, params, block, cache, offsets, **told):
+        return self.fwd_kinds(params, block, kv_cache=cache, cache_offset=offsets, **told)
 
     def ride(self, cache, block, kv_read=None):
         rows = [jnp.broadcast_to(cache[leaf][:, None], (len(names), block.shape[1]))
@@ -594,10 +653,27 @@ class LayerKindKV(DenseKV):
             where, : lit.shape[1]].set(lit, mode="drop"))
 
     def view(self, cache, where, length: int):
-        raise Refused("a cache per layer kind gives no dense view of a slot "
-                      "(prefix cache, chunked prefill)")
+        """The slot's own leaves as a ``[1, ...]`` cache for a prefill piece:
+        the front ``length`` positions of a full leaf, as many rows of an
+        index as cover them, a state whole; the counters stay behind."""
+        if "window" in self.kinds.values():
+            raise Refused("a cache per layer kind with rings gives no dense view of a slot "
+                          "(prefix cache, chunked prefill)")
 
-    put_piece = view
+        def front(big, kind):
+            if kind == "state":
+                size = (1,) + big.shape[1:]
+            else:
+                size = (1, big.shape[1] * length // self.max_len) + big.shape[2:]
+            return jax.lax.dynamic_slice(big, (where,) + (0,) * (big.ndim - 1), size)
+
+        return {name: front(cache[name], kind) for name, kind in self.kinds.items()
+                if kind != "counter"}
+
+    def put_piece(self, cache, row, where):
+        if "window" in self.kinds.values():
+            raise Refused("a cache per layer kind with rings takes no prefill piece")
+        return self.put(cache, row, where)
 
 
 def replicated(mesh):

@@ -209,6 +209,21 @@ LAGUNA_RULES: Rules = [
     (r".*", []),
 ]
 
+# MiniCPM-SALA (models/minicpm_sala.py): the full-width output gate splits
+# with the heads it gates, like q/k/v; the per-head q/k norms and the output
+# norm are replicated; the MLP falls to the llama projection rules.
+MINICPM_SALA_RULES: Rules = [
+    (r"embed_tokens\.weight$", ["tp", None]),
+    (r"lm_head\.weight$", ["tp", None]),
+    (r"(q|k|v)_proj\.weight$", ["tp", None]),
+    (r"o_gate\.weight$", ["tp", None]),
+    (r"o_proj\.weight$", [None, "tp"]),
+    (r"(gate|up)_proj\.weight$", ["tp", None]),
+    (r"down_proj\.weight$", [None, "tp"]),
+    (r"norm\.weight$", [None]),
+    (r".*", []),
+]
+
 DEFAULT_RULES: dict[str, Rules] = {
     "llama": LLAMA_RULES,
     "qwen2": QWEN2_RULES,
@@ -218,6 +233,7 @@ DEFAULT_RULES: dict[str, Rules] = {
     "bert": BERT_RULES,
     "mixtral": MIXTRAL_RULES,
     "laguna": LAGUNA_RULES,
+    "minicpm_sala": MINICPM_SALA_RULES,
 }
 
 
@@ -235,6 +251,8 @@ def infer_family(tensor_names: Sequence[str]) -> str:
         return "mixtral"
     if "self_attn.g_proj" in joined:
         return "laguna"  # per-head output gate beside q/k/v/o
+    if "self_attn.o_gate" in joined:
+        return "minicpm_sala"  # full-width output gate, q/k norms, no rope on some layers
     if "pre_feedforward_layernorm" in joined:
         # llama layout + sandwich norms: gemma2 — but gemma3 ALSO carries
         # them, adding per-head q_norm/k_norm attention norms (and a

@@ -39,10 +39,13 @@ Three shapes of KV state, one forward:
   masked by absolute position. Single-token steps only.
 
 Served through the continuous engine this family **refuses at start-up**
-what a ring cannot give or this layout does not carry: ``--prefix-cache``
-(and with it the KV store's bundles and resume from stored KV),
-``--prefill-chunk``, ``--speculative-k`` and ``--kv-page-size``
-(dl/kv_layout.LayerKindKV.refuse). Rope types other than ``default`` and
+what a ring — its ``"window"`` leaves, not the layout as such — cannot give:
+``--prefix-cache`` (and with it the KV store's bundles and resume from stored
+KV: a ring cannot give back a prefix it has overwritten), ``--prefill-chunk``
+(a piece needs the slot's earlier rows as a dense view; a layout without
+rings carries it), ``--speculative-k`` (a verify block writes several ring
+positions a step) and ``--kv-page-size`` (a ring is not paged)
+(dl/kv_layout.LayerKindKV.refuse names the option, the leaf kind, the reason). Rope types other than ``default`` and
 ``yarn``, a gating other than ``per-head``, router soft-capping and router
 weights applied on the input are refused when the config is read.
 """

@@ -592,8 +592,14 @@ class TestBreakerHTTP:
         flaky.status_script = [500, 500, 200, 200]  # sick, then healed
         backup = FakePod()
         backup.serving = {"default": {"queue_depth": 99}}  # always 2nd
+        # the breaker's own clock, moved by hand: with the wall clock and a
+        # 0.2 s cooldown, a loaded machine took longer than that between the
+        # breaker's opening and the next request, which then went to the
+        # flaky pod as the half-open probe and not to the backup
+        now = [1000.0]
         rt = make_router([flaky.url, backup.url],
-                         breakers=BreakerBoard(threshold=2, cooldown_s=0.2))
+                         breakers=BreakerBoard(threshold=2, cooldown_s=30.0,
+                                               clock=lambda: now[0]))
         try:
             body = {"tokens": [[1, 2, 3, 4]]}
             # two 500s relay verbatim (4xx/5xx are deterministic answers)
@@ -611,7 +617,7 @@ class TestBreakerHTTP:
             assert r.status_code == 200 and r.json()["pod"] == backup.url
             assert rt.router.metrics.snapshot()["breaker_skipped_total"] >= 1
             assert len(flaky.requests) == 2
-            time.sleep(0.25)  # cooldown -> half-open
+            now[0] += 30.5  # cooldown -> half-open
             # the probe goes to the flaky pod, succeeds, and closes it
             # (fresh prompt: the 200 above sticky-pinned `body`'s
             # conversation to the backup pod — which is the point of

@@ -404,3 +404,112 @@ class TestLayerKinds:
         with pytest.raises(kv_layout.Refused, match="--kv-page-size.*--speculative-k"):
             kv_layout.LayerKindKV.refuse("laguna", page_size=16, prefix_cache=None,
                                          prefill_chunk=0, speculative_k=2)
+
+
+class TestStateAndIndexLeaves:
+    """``LayerKindKV`` over a family with states and an index and no ring
+    (minicpm_sala): a state has no position axis, an index one row per n
+    positions; a slot's leaves go to a prefill piece and come back."""
+
+    @pytest.fixture(scope="class")
+    def kv(self):
+        from modelx_tpu.models import minicpm_sala
+
+        cfg = minicpm_sala.SalaConfig.tiny(vocab_size=64)
+        server = types.SimpleNamespace(mesh=make_mesh("dp=1", jax.devices()[:1]),
+                                       family=FAMILIES["minicpm_sala"], cfg=cfg)
+        fwd, init_cache = server.family.decode_fns(cfg, mesh=server.mesh)
+        return kv_layout.build(server, fwd, init_cache, {}, max_slots=SLOTS, max_len=MAX_LEN,
+                               chunk_size=4, page_size=0, max_live_tokens=0,
+                               paged_attention="gather", prefill_chunk=16), cfg
+
+    def test_each_kind_gets_its_own_shape_and_bytes_are_counted_by_kind(self, kv):
+        kv, cfg = kv
+        state = kv.new_state()
+        assert kv.kinds == {"k0": "full", "v0": "full", "c0": "index", "s1": "state", "s2": "state",
+                            "sparse_counts": "counter"}
+        assert state["k0"].shape == (SLOTS, MAX_LEN, 2 * 8) and state["c0"].shape == (SLOTS, MAX_LEN // 2, 2, 8)
+        assert state["s1"].shape == (SLOTS, 4, 8, 8) and state["s1"].dtype == jnp.float32
+        stats = kv.stats["kv"]
+        assert stats["bytes_full"] == 2 * SLOTS * MAX_LEN * 16 * 4
+        assert stats["bytes_index"] == SLOTS * MAX_LEN // 2 * 16 * 4
+        assert stats["bytes_state"] == 2 * SLOTS * 4 * 8 * 8 * 4 and stats["bytes_window"] == 0
+        assert kv.has_state and kv.describe()[-1] == tuple(sorted(kv.kinds.items()))
+
+    def test_the_store_tells_this_layout_from_lagunas(self, kv):
+        from modelx_tpu.models import laguna
+
+        cfg = laguna.LagunaConfig.tiny(vocab_size=64)
+        server = types.SimpleNamespace(mesh=make_mesh("dp=1", jax.devices()[:1]),
+                                       family=FAMILIES["laguna"], cfg=cfg)
+        fwd, init_cache = server.family.decode_fns(cfg, mesh=server.mesh)
+        other = kv_layout.build(server, fwd, init_cache, {}, max_slots=SLOTS, max_len=MAX_LEN,
+                                chunk_size=4, page_size=0, max_live_tokens=0,
+                                paged_attention="gather")
+        assert other.describe() != kv[0].describe() and not other.has_state
+
+    @pytest.mark.parametrize("length", [16, 48])
+    def test_an_admissions_scratch_lands_whole_state_and_all(self, kv, length):
+        kv, _ = kv
+        small = scratch(kv, length, length)
+        state = jax.jit(kv.put)(kv.new_state(), small, kv.at(2))
+        for name, kind in kv.kinds.items():
+            if kind == "counter":
+                continue
+            got, want = np.asarray(state[name]), np.asarray(small[name])[0]
+            assert not got[[0, 1, 3]].any()  # the other slots stay as they were
+            if kind == "state":
+                np.testing.assert_array_equal(got[2], want)
+            else:
+                np.testing.assert_array_equal(got[2, : want.shape[0]], want)
+                assert want.shape[0] == (length // 2 if kind == "index" else length)
+
+    def test_a_piece_is_handed_the_slots_leaves_and_gives_them_back(self, kv):
+        """``view`` of a slot: the front of its keys and values, as many index
+        rows as cover them, its state whole, no counter; ``put_piece`` writes
+        them back where they were, and no other slot moves."""
+        kv, _ = kv
+        rng = np.random.RandomState(3)
+        state = {n: (x if kv.kinds[n] == "counter" else
+                     jnp.asarray(rng.standard_normal(x.shape).astype(x.dtype)))
+                 for n, x in kv.new_state().items()}
+        row = jax.jit(lambda c, w: kv.view(c, w, MAX_LEN))(state, kv.at(1))
+        assert set(row) == set(kv.kinds) - {"sparse_counts"}
+        for name, leaf in row.items():
+            np.testing.assert_array_equal(np.asarray(leaf)[0], np.asarray(state[name])[1])
+        changed = {n: x + 1 for n, x in row.items()}
+        after = jax.jit(kv.put_piece)(state, changed, kv.at(1))
+        for name, kind in kv.kinds.items():
+            got, was = np.asarray(after[name]), np.asarray(state[name])
+            if kind == "counter":
+                np.testing.assert_array_equal(got, was)
+                continue
+            np.testing.assert_array_equal(got[[0, 2, 3]], was[[0, 2, 3]])
+            np.testing.assert_array_equal(got[1], was[1] + 1)
+
+    def test_only_a_layout_with_states_tells_the_family_more(self, kv, model):
+        """What the chunk, admit and piece programs pass beyond cache and
+        offset: real lengths and live rows where a state would keep what a
+        padded tail or an idle slot adds; nothing elsewhere, so the other
+        layouts' programs are the ones their compile caches hold."""
+        kv, _ = kv
+        offsets, steps = jnp.asarray([7, 0, 32, 5]), jnp.asarray([3, 9, 0, 1])
+        np.testing.assert_array_equal(np.asarray(kv.step_kwargs(offsets, steps)["live"]),
+                                      [True, False, False, True])
+        assert int(kv.block_kwargs(last_idx=jnp.int32(11))["valid_len"]) == 12
+        assert kv.block_kwargs(valid_len=None) == {"valid_len": None}
+        for layout in LAYOUTS:
+            other = build(model, layout)
+            assert other.step_kwargs(offsets, steps) == {} == other.block_kwargs(last_idx=3)
+
+    def test_counters_ride_home_as_four_rows(self, kv):
+        kv, _ = kv
+        state = dict(kv.new_state(), sparse_counts=jnp.asarray([24, 100, 1, 2], jnp.int32))
+        block = jnp.zeros((SLOTS, 5), jnp.int32)
+        out = np.asarray(kv.ride(state, block))
+        assert out.shape == (SLOTS + 4, 5)
+        kv._last.clear()
+        kv.landed(out)
+        sparse = kv.stats["sparse"]
+        assert [sparse[k] for k in ("positions_read", "positions_cached", "steps_sparse",
+                                    "steps_all")] == [24, 100, 1, 2]

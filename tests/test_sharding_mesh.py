@@ -110,6 +110,15 @@ FAMILY_SPEC_CASES = {
         ("model.layers.1.mlp.shared_expert.up_proj.weight", PartitionSpec("tp", None)),
         ("model.layers.0.mlp.down_proj.weight", PartitionSpec(None, "tp")),  # the dense layer
     ],
+    "minicpm_sala": [
+        ("model.layers.9.self_attn.o_gate.weight", PartitionSpec("tp", None)),  # with the heads
+        ("model.layers.9.self_attn.o_proj.weight", PartitionSpec(None, "tp")),
+        ("model.layers.10.self_attn.k_proj.weight", PartitionSpec("tp", None)),
+        ("model.layers.10.self_attn.norm.weight", PartitionSpec(None)),  # the output norm
+        ("model.layers.10.self_attn.q_norm.weight", PartitionSpec(None)),
+        ("model.layers.10.mlp.down_proj.weight", PartitionSpec(None, "tp")),
+        ("lm_head.weight", PartitionSpec("tp", None)),
+    ],
 }
 
 

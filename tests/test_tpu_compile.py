@@ -141,6 +141,58 @@ def test_lagunas_decode_step_fits_one_chip_with_a_cache_per_layer_kind(topo):
     assert uniform > 15.75 * 2**30 - 1.5e9
 
 
+def test_minicpm_salas_decode_step_fits_one_chip_and_re_lays_no_cache_leaf(topo):
+    """The benchmark's configuration at its published widths — layers 9-20 of
+    32, 32 slots of 32,768 positions: the decode step over keys, values,
+    compressed keys and lightning states compiles for the chip and, weights
+    and state included, stays under the chip's 15.75 GiB. And it copies no
+    ``[slots, max_len]`` leaf: with 2 KV heads laid as ``[B, L, 2, 128]`` the
+    compiler kept the heads outermost, and the block gather's reshape, the
+    per-row write (a scatter) and the compressed key's window (a gather) each
+    re-laid every leaf whole in every step — 23 of a 45 ms step on the chip
+    (my chip run, PR 35). Shapes only: nothing is allocated or run."""
+    import json
+    import re
+
+    from modelx_tpu.models import minicpm_sala as sala
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "minicpm-sala-d12.json")) as f:
+        raw = json.load(f)
+    cfg = sala.config_from_hf(raw)
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    params = {k: sds(v, jnp.bfloat16) for k, v in sala.param_shapes(cfg).items()}
+    state = jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: sala.init_layer_state(cfg, 32, 32768)))
+
+    def steps(params, state, tok, offsets, live):
+        def one_step(carry, _):
+            state, tok, offsets = carry
+            logits, state = sala.forward(params, tok, cfg, kv_cache=state, cache_offset=offsets,
+                                         live=live)
+            nxt = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+            return (state, nxt, offsets + 1), nxt
+        (state, tok, _), toks = jax.lax.scan(one_step, (state, tok, offsets), None, length=2)
+        return state, toks
+
+    compiled = jax.jit(steps, donate_argnums=(1,)).lower(
+        params, state, sds((32, 1), jnp.int32), sds((32,), jnp.int32), sds((32,), jnp.bool_)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes
+            - m.alias_size_in_bytes)
+    predicted = raw["bytes_predicted"]
+    assert predicted["sum"] == 11_785_885_696
+    assert predicted["sum"] <= m.argument_size_in_bytes < predicted["sum"] + 16384  # + tok, offsets, counters
+    assert live < 15.75 * 2**30 - 2.5e9  # room for the probe's cache-less forward beside it
+    text = compiled.as_text()
+    relaid = [line.strip()[:120] for line in text.splitlines()
+              if re.search(r"= bf16\[32,32768,256\]\S* (copy|transpose)\(", line)]
+    assert not relaid, relaid
+    assert "bf16[32,32768,256]" in text  # the leaves are there under that shape
+
+
 # -- the ragged decode kernel (ops.attention.decode_attention) ----------------
 
 # (rows, query heads, cache length): the decode cells' full-attention layers
