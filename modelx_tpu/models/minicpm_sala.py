@@ -40,8 +40,10 @@ Three forms of each layer over one set of equations:
 - one token a row over the engine's state: a lightning step reads and writes
   the row's state, a sparse step appends its key and value, every
   ``kernel_stride``-th position a compressed key, and below ``dense_len``
-  attends its row densely, from there on GATHERS the selected blocks. ``live``
-  marks the rows that decode: the others keep their state bit for bit.
+  attends its row densely, from there on reads the selected blocks alone — on
+  one TPU device in a kernel that copies each block's own lanes once, elsewhere
+  by a gather (``sparse_ops.decode_takes_kernel``). ``live`` marks the rows
+  that decode: the others keep their state bit for bit.
 
 The cache (``init_kv_cache``; the engine's, ``init_layer_state``, adds the
 counters): per sparse layer ``k<i>``, ``v<i>`` ``[B, L, Hkv * d]`` (a position's
@@ -75,8 +77,10 @@ LIGHTNING, SPARSE = "lightning-attn", "minicpm4"
 # accumulates them, over the sparse layers and the live rows
 # (dl/kv_layout.LayerKindKV reads them back with the tokens): positions whose
 # keys were read, positions the rows hold, row-steps that took the selection,
-# row-steps in all
-SPARSE_COUNTERS = ("positions_read", "positions_cached", "steps_sparse", "steps_all")
+# row-steps in all, row-steps whose selected blocks the kernel read
+# (ops/sparse_attention.decode_attention_kernel; 0 where the gather ran)
+SPARSE_COUNTERS = ("positions_read", "positions_cached", "steps_sparse", "steps_all",
+                   "steps_kernel")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -429,13 +433,21 @@ def _sparse_step(q, k, v, cache, offset, live, cfg: SalaConfig, ctx: ShardingCtx
     blocks = ck.shape[1] // spec.block_size
     with jax.named_scope("sala.sparse.select"):
         chosen = sparse_ops.select_blocks(q, index, context[:, None], spec, blocks)[:, 0]
+    # one algorithm, two ways to fetch its operands: which is read off the inputs
+    kernel, interpret = sparse_ops.decode_takes_kernel(q, ck, spec, attention_impl, ctx.mesh)
     with jax.named_scope("sala.sparse.attend"):
-        o_sparse = sparse_ops.decode_attention(q[:, 0], ck, cv, chosen, offset, spec)[:, None]
+        if kernel:
+            o_sparse = sparse_ops.decode_attention_kernel(q[:, 0], ck, cv, chosen, offset, spec,
+                                                          interpret=interpret)
+        else:
+            o_sparse = sparse_ops.decode_attention(q[:, 0], ck, cv, chosen, offset, spec)
+        o_sparse = o_sparse[:, None]
     o = jnp.where(dense_row[:, None, None, None], o_dense, o_sparse)
     took = decoding & ~dense_row
     read = jnp.where(took, chosen.shape[-1] * spec.block_size, jnp.where(decoding, context, 0))
-    counts = jnp.stack([jnp.sum(read), jnp.sum(jnp.where(decoding, context, 0)),
-                        jnp.sum(took), jnp.sum(decoding)]).astype(jnp.int32)
+    steps_sparse = jnp.sum(took)
+    counts = jnp.stack([jnp.sum(read), jnp.sum(jnp.where(decoding, context, 0)), steps_sparse,
+                        jnp.sum(decoding), steps_sparse if kernel else 0]).astype(jnp.int32)
     return o, (ck, cv, index), counts
 
 

@@ -18,11 +18,15 @@ b .. r b + r``, ``r = block / s``); the first ``init_blocks`` blocks and the
 ``topk`` highest are taken, ties to the lower index. A query whose context
 is below ``dense_len`` attends all of its positions instead.
 
-Two attentions over the selection: :func:`decode_attention` GATHERS the
-selected blocks of a one-token step and reads nothing else of the row;
-:func:`prefill_attention` walks a block of queries over the row's key tiles
-with a running softmax under the selected-block mask (dense arithmetic, no
-``[queries, heads, keys]`` array over a row).
+Two attentions over the selection. A one-token step reads the selected blocks
+and nothing else of the row, in one of two ways to fetch the same operands:
+:func:`decode_attention` GATHERS them with ``jnp.take`` (the reference: the
+CPU, a mesh, narrow heads), :func:`decode_attention_kernel` copies each (row,
+KV head)'s blocks from the cache as it lies into VMEM, once, with the block
+indices as prefetched scalars (:func:`decode_takes_kernel` says which, from
+what it can observe). :func:`prefill_attention` walks a block of queries over
+the row's key tiles with a running softmax under the selected-block mask
+(dense arithmetic, no ``[queries, heads, keys]`` array over a row).
 
 Keys and values lie as ``[B, L, Hkv * D]``, a position's KV heads side by side
 in one row: a block of positions is then one contiguous run of the array as
@@ -35,10 +39,16 @@ run, PR 35: 23 of a 45 ms step).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# rows of a packed bf16 tile: what a block is a multiple of and a query group is padded to
+from modelx_tpu.ops.attention import FLASH_ROW_TILE as ROW_TILE
 
 NEG = -1e30
 Q_TILE, K_TILE = 256, 1024
@@ -138,6 +148,142 @@ def decode_attention(q, k_cache, v_cache, chosen, position, spec: SparseSpec):
         outs.append(jnp.einsum("bgkp,bkpd->bgd", probs.reshape(shape).astype(v_i.dtype), v_i,
                                preferred_element_type=jnp.float32))
     return jnp.stack(outs, axis=1).reshape(b, h, d)
+
+
+def decode_takes_kernel(q, k_cache, spec: SparseSpec, impl: str = "auto", mesh=None):
+    """Whether a one-token step over these operands takes
+    :func:`decode_attention_kernel`, and whether interpreted -> (take,
+    interpret). Read off what the code can observe, no flag: the TPU backend,
+    one device (a bare Mosaic call cannot be partitioned), heads of a multiple
+    of 128 lanes (a head's lanes of a row are then whole tiles), blocks of a
+    multiple of the bf16 tile's 16 rows. ``impl`` ``"sparse"``
+    (``"sparse+interpret"`` on the CPU) asks for the kernel by name; any other
+    name leaves the choice here."""
+    name, _, flag = impl.partition("+")
+    if name == "sparse":
+        return True, flag == "interpret"
+    d = q.shape[-1]
+    return (jax.default_backend() == "tpu" and (mesh is None or mesh.size == 1)
+            and d % 128 == 0 and spec.block_size % ROW_TILE == 0
+            and k_cache.ndim == 3 and k_cache.shape[2] % d == 0), False
+
+
+def _sparse_decode_kernel(chosen_ref, position_ref, q_ref, at_ref, k_hbm, v_hbm, o_ref,
+                          k_buf, v_buf, sems, *, kv_heads: int, size: int, sm_scale: float,
+                          run: tuple[int, int] | None):
+    """One (row, KV head) program of :func:`decode_attention_kernel`.
+
+    k_hbm / v_hbm: the caches as they lie, ``[B, L, Hkv * D]``, never staged
+    whole. ``chosen_ref`` ``[B * Hkv * K]`` in scalar memory names the pair's
+    blocks; each is copied — the head's own ``D`` lanes of ``size`` rows — to
+    its place in ``k_buf`` / ``v_buf`` ``[2, K * size, D]``. ``run`` ``(first,
+    count)``: where the selection's entries ``first .. first + count`` name
+    blocks that lie side by side in the row (the window ending at the query's
+    own, which ``select_blocks`` returns first and in order) they are one
+    copy, checked pair by pair; any other selection copies block by block.
+    The copies of pair ``n + 1`` are started before pair ``n`` is computed
+    (the grid runs in order on one core), so only the first pair's are waited
+    for cold. Then the present arithmetic: the group's queries ``[G, D]``
+    against the ``K * size`` positions at once, float32 logits, ``at_ref``
+    (the positions' places in the row) masked past the row's own, one softmax,
+    probabilities x V."""
+    n, pairs = pl.program_id(0), pl.num_programs(0)
+    d = k_buf.shape[-1]
+    k_blocks = k_buf.shape[1] // size
+    slot = n % 2
+
+    def transfer(pair, slot, wait: bool):
+        """Start, or wait for, every copy of ``pair`` into ``slot``."""
+        row, head = pair // kv_heads, pair % kv_heads
+        base = pair * k_blocks
+        lanes = pl.ds(pl.multiple_of(head * d, d), d)
+
+        def copy(j, blocks=1):
+            first = pl.multiple_of(chosen_ref[base + j] * size, size)
+            into = pl.ds(pl.multiple_of(j * size, size), blocks * size)
+            for i, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                dma = pltpu.make_async_copy(hbm.at[row, pl.ds(first, blocks * size), lanes],
+                                            buf.at[slot, into], sems.at[slot, i])
+                if wait:
+                    dma.wait()
+                else:
+                    dma.start()
+
+        def each(lo, hi):
+            if hi > lo:
+                jax.lax.fori_loop(lo, hi, lambda j, _: copy(j), None)
+
+        if run is None:
+            return each(0, k_blocks)
+        lo, count = run
+        each(0, lo)
+        each(lo + count, k_blocks)
+        first = chosen_ref[base + lo]
+        side_by_side = jax.lax.fori_loop(
+            1, count, lambda j, ok: ok & (chosen_ref[base + lo + j] == first + j), True)
+        pl.when(side_by_side)(lambda: copy(lo, count))
+        pl.when(jnp.logical_not(side_by_side))(lambda: each(lo, lo + count))
+
+    @pl.when(n == 0)
+    def _():
+        transfer(0, 0, wait=False)
+
+    @pl.when(n + 1 < pairs)
+    def _():
+        transfer(n + 1, 1 - slot, wait=False)
+
+    transfer(n, slot, wait=True)
+    logits = jax.lax.dot_general(q_ref[...], k_buf[slot], (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * sm_scale  # [G, K * size]
+    logits = jnp.where(at_ref[...] <= position_ref[n // kv_heads], logits, NEG)
+    p = jnp.exp(logits - jnp.max(logits, axis=-1, keepdims=True))
+    probs = p / jnp.sum(p, axis=-1, keepdims=True)
+    o_ref[...] = jax.lax.dot_general(probs.astype(v_buf.dtype), v_buf[slot],
+                                     (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+
+
+def decode_attention_kernel(q, k_cache, v_cache, chosen, position, spec: SparseSpec, *,
+                            interpret: bool = False):
+    """:func:`decode_attention` with another way to fetch its operands: same
+    inputs, same selection, same precision (operands as they are, float32
+    logits and accumulation, one softmax over the ``K * block`` positions),
+    ``[B, H, D]`` float32 out — algebraically the reference, not bit-identical
+    to it. A Pallas kernel over the (row, KV head) pairs reads each selected
+    block ONCE, the head's own lanes only, from the cache where it lies; the
+    gather reads every block with all KV heads' lanes, writes the copy to HBM
+    and reads it back. Nothing but the output (and ``at``, 4 bytes a position
+    attended) passes through HBM."""
+    b, h, d = q.shape
+    hkv = k_cache.shape[2] // d
+    size, k_blocks = spec.block_size, chosen.shape[-1]
+    group = h // hkv
+    rows = -(-group // ROW_TILE) * ROW_TILE
+    qg = q.reshape(b * hkv, group, d)
+    if rows != group:  # pad rows attend like any other and are cut away
+        qg = jnp.pad(qg, ((0, 0), (0, rows - group), (0, 0)))
+    at = (chosen[..., None] * size + jnp.arange(size)).astype(jnp.int32)
+    window = spec.window_size // size
+    run = (spec.init_blocks, window) if 1 < window <= k_blocks - spec.init_blocks else None
+    per_pair = lambda *shape: pl.BlockSpec((None, *shape), lambda n, *_: (n, 0, 0))  # noqa: E731
+    out = pl.pallas_call(
+        functools.partial(_sparse_decode_kernel, kv_heads=hkv, size=size,
+                          sm_scale=1.0 / math.sqrt(d), run=run),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b * hkv,),
+            in_specs=[per_pair(rows, d), per_pair(1, k_blocks * size),
+                      pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=per_pair(rows, d),
+            scratch_shapes=[pltpu.VMEM((2, k_blocks * size, d), k_cache.dtype),
+                            pltpu.VMEM((2, k_blocks * size, d), v_cache.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=jax.ShapeDtypeStruct((b * hkv, rows, d), jnp.float32),
+        # a pair's program starts the next pair's copies: the grid runs in order
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="sparse_decode_attention",
+    )(chosen.reshape(-1).astype(jnp.int32), position.astype(jnp.int32), qg,
+      at.reshape(b * hkv, 1, k_blocks * size), k_cache, v_cache)
+    return out[:, :group].reshape(b, h, d)
 
 
 def prefill_attention(q, k_row, v_row, index, start, spec: SparseSpec,

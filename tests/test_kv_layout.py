@@ -502,14 +502,14 @@ class TestStateAndIndexLeaves:
             other = build(model, layout)
             assert other.step_kwargs(offsets, steps) == {} == other.block_kwargs(last_idx=3)
 
-    def test_counters_ride_home_as_four_rows(self, kv):
+    def test_counters_ride_home_as_a_row_each(self, kv):
         kv, _ = kv
-        state = dict(kv.new_state(), sparse_counts=jnp.asarray([24, 100, 1, 2], jnp.int32))
+        state = dict(kv.new_state(), sparse_counts=jnp.asarray([24, 100, 1, 2, 1], jnp.int32))
         block = jnp.zeros((SLOTS, 5), jnp.int32)
         out = np.asarray(kv.ride(state, block))
-        assert out.shape == (SLOTS + 4, 5)
+        assert out.shape == (SLOTS + 5, 5)
         kv._last.clear()
         kv.landed(out)
         sparse = kv.stats["sparse"]
         assert [sparse[k] for k in ("positions_read", "positions_cached", "steps_sparse",
-                                    "steps_all")] == [24, 100, 1, 2]
+                                    "steps_all", "steps_kernel")] == [24, 100, 1, 2, 1]

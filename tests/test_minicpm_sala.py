@@ -360,7 +360,8 @@ def test_prefill_then_decode_over_a_cache_gives_the_references_logits(served, pi
                                      kv_cache=cache, cache_offset=jnp.asarray([t], jnp.int32))
         outs.append(np.asarray(logits)[0])
     np.testing.assert_allclose(np.concatenate(outs), ref_logits(params, raw, toks), atol=ATOL)
-    read, cached, took, steps = np.asarray(cache["sparse_counts"])
+    read, cached, took, steps, by_kernel = np.asarray(cache["sparse_counts"])
+    assert by_kernel == 0  # the CPU gathers
     assert steps == total - prompt_len and took == total - max(prompt_len, 31)
     assert cached == sum(range(prompt_len + 1, total + 1))
     assert read == sum(t if t < 32 else 24 for t in range(prompt_len + 1, total + 1))

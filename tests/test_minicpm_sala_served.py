@@ -127,6 +127,8 @@ def test_push_dl_serve_model_with_chunked_prefill_follows_the_reference(checkpoi
         assert engine["fill"]["pieces"] == 3 and engine["fill"]["tokens"] == 45
         assert engine["kv"]["bytes_state"] == 2 * 4 * 4 * 8 * 8 * 4 and engine["kv"]["bytes_index"] > 0
         assert engine["sparse"]["steps_sparse"] > 0 and engine["sparse"]["dense_len"] == 32
+        # who fetched the selected blocks: on the CPU the gather, never the kernel
+        assert engine["sparse"]["steps_kernel"] == 0
         assert engine["sparse"]["positions_read"] < engine["sparse"]["positions_cached"]
     finally:
         stop(procs)

@@ -141,18 +141,10 @@ def test_lagunas_decode_step_fits_one_chip_with_a_cache_per_layer_kind(topo):
     assert uniform > 15.75 * 2**30 - 1.5e9
 
 
-def test_minicpm_salas_decode_step_fits_one_chip_and_re_lays_no_cache_leaf(topo):
-    """The benchmark's configuration at its published widths — layers 9-20 of
-    32, 32 slots of 32,768 positions: the decode step over keys, values,
-    compressed keys and lightning states compiles for the chip and, weights
-    and state included, stays under the chip's 15.75 GiB. And it copies no
-    ``[slots, max_len]`` leaf: with 2 KV heads laid as ``[B, L, 2, 128]`` the
-    compiler kept the heads outermost, and the block gather's reshape, the
-    per-row write (a scatter) and the compressed key's window (a gather) each
-    re-laid every leaf whole in every step — 23 of a 45 ms step on the chip
-    (my chip run, PR 35). Shapes only: nothing is allocated or run."""
+def minicpm_sala_cell(topo):
+    """The ``minicpm-sala-d12`` configuration as the benchmark runs it -> (its
+    file, the config, the weights as shapes on one described device, ``sds``)."""
     import json
-    import re
 
     from modelx_tpu.models import minicpm_sala as sala
 
@@ -163,6 +155,24 @@ def test_minicpm_salas_decode_step_fits_one_chip_and_re_lays_no_cache_leaf(topo)
     one = SingleDeviceSharding(topo.devices[0])
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
     params = {k: sds(v, jnp.bfloat16) for k, v in sala.param_shapes(cfg).items()}
+    return raw, cfg, params, sds
+
+
+def test_minicpm_salas_decode_step_fits_one_chip_and_re_lays_no_cache_leaf(topo):
+    """The benchmark's configuration at its published widths — layers 9-20 of
+    32, 32 slots of 32,768 positions: the decode step over keys, values,
+    compressed keys and lightning states compiles for the chip and, weights
+    and state included, stays under the chip's 15.75 GiB. And it copies no
+    ``[slots, max_len]`` leaf: with 2 KV heads laid as ``[B, L, 2, 128]`` the
+    compiler kept the heads outermost, and the block gather's reshape, the
+    per-row write (a scatter) and the compressed key's window (a gather) each
+    re-laid every leaf whole in every step — 23 of a 45 ms step on the chip
+    (my chip run, PR 35). Shapes only: nothing is allocated or run."""
+    import re
+
+    from modelx_tpu.models import minicpm_sala as sala
+
+    raw, cfg, params, sds = minicpm_sala_cell(topo)
     state = jax.tree_util.tree_map(
         lambda x: sds(x.shape, x.dtype),
         jax.eval_shape(lambda: sala.init_layer_state(cfg, 32, 32768)))
@@ -191,6 +201,68 @@ def test_minicpm_salas_decode_step_fits_one_chip_and_re_lays_no_cache_leaf(topo)
               if re.search(r"= bf16\[32,32768,256\]\S* (copy|transpose)\(", line)]
     assert not relaid, relaid
     assert "bf16[32,32768,256]" in text  # the leaves are there under that shape
+
+
+# -- the sparse layers' one-token kernel (ops.sparse_attention) ----------------
+
+
+def test_sparse_decode_kernel_at_the_cells_widths(topo):
+    """Mosaic takes the kernel at the ``.longctx`` cell's shapes — 32 rows, 32
+    query / 2 KV heads of 128, rows of 32,768 positions laid ``[B, L, 256]``,
+    64 blocks of 64 a KV head: a head's 128 lanes of a block sliced out of the
+    cache where it lies (a dynamic lane offset), 4 MB of double-buffered
+    scratch. The caches stay where they are: no temporary of a block's size."""
+    from modelx_tpu.ops import sparse_attention as sparse
+
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    kv = sds((32, 32768, 256), jnp.bfloat16)
+    compiled = jax.jit(lambda *a: sparse.decode_attention_kernel(*a, sparse.SparseSpec())).lower(
+        sds((32, 32, 128), jnp.bfloat16), kv, kv, sds((32, 2, 64), jnp.int32),
+        sds((32,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text and "sparse_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**21  # the positions' places: 1 MB
+
+
+def test_minicpm_salas_chunk_program_reads_the_selected_blocks_in_the_kernel(topo, monkeypatch):
+    """The engine's OWN depth-4 chunk program at the cell's size (32 slots of
+    32,768 positions, ``--prefill-chunk 2048``), the rule steered to a TPU
+    (the compile runs where ``default_backend`` says cpu): one Mosaic call a
+    sparse layer under ``sala.sparse.attend``, no gather there — the six
+    ``bf16[4096,64,256]`` gathers of 268 MB were 16 % of the device's time
+    (ledger, PR 35); the shape survives only in the dense branch's ``cond``,
+    the rows' fronts below ``dense_len`` — and still no cache leaf re-laid."""
+    import re
+    import types
+
+    from modelx_tpu.dl.continuous import ContinuousBatcher
+    from modelx_tpu.dl.families import FAMILIES
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, cfg, params, sds = minicpm_sala_cell(topo)
+    server = types.SimpleNamespace(
+        family=FAMILIES["minicpm_sala"], cfg=cfg, mesh=make_mesh("dp=1", [topo.devices[0]]),
+        params=params, max_seq_len=32768, stats={})
+    engine = ContinuousBatcher(server, max_slots=32, chunk_size=8, max_len=32768,
+                               prefill_chunk=2048, allocate=False, supervise=False)
+    try:
+        tok = sds((32, 1), jnp.int32)
+        compiled = engine._chunk_prog.jit.lower(
+            params, engine.kv.abstract_state(), tok, *engine._chunk_args(False),
+            n_steps=32).compile()
+    finally:
+        engine.close()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    assert all("sala.sparse.attend" in c and "sparse_decode_attention" in c for c in calls)
+    gathers = [line for line in text.splitlines()
+               if re.search(r"bf16\[(32,2,64,64,256|4096,64,256)\]\S* gather\(", line)]
+    assert gathers and all("sala.attn.dense" in g for g in gathers), gathers
+    relaid = [line.strip()[:120] for line in text.splitlines()
+              if re.search(r"= bf16\[32,32768,256\]\S* (copy|transpose)\(", line)]
+    assert not relaid, relaid
 
 
 # -- the ragged decode kernel (ops.attention.decode_attention) ----------------
