@@ -181,8 +181,11 @@ def _write_atomic(path: str, blob: bytes) -> None:
 # /metrics ``compile_cache``: hits = executables loaded from the store,
 # misses = lookups that found nothing usable (the program was then built and
 # written), bytes = entry bytes read on hits plus written on misses
+# (bytes_read: the hits' alone). A hit's load_s is its file read (read_s),
+# the unpickling, and PJRT's deserialize-and-load (deserialize_s)
 _store_counts = {"store_hits": 0, "store_misses": 0, "store_load_s": 0.0,
-                 "store_write_s": 0.0, "store_bytes": 0}
+                 "store_read_s": 0.0, "store_deserialize_s": 0.0,
+                 "store_write_s": 0.0, "store_bytes": 0, "store_bytes_read": 0}
 _store_counts_lock = threading.Lock()
 
 
@@ -298,30 +301,38 @@ class ExecutableStore:
         from jax.experimental.serialize_executable import deserialize_and_load
 
         path = self.path(name, digest)
-        t0 = time.monotonic()
         try:
-            with open(path, "rb") as f:
-                blob = f.read()
-        except OSError as e:
+            f = open(path, "rb")
+        except OSError as e:  # no entry: a miss, and no span
             if not isinstance(e, FileNotFoundError):
                 logger.warning("stored program %s unreadable (%s); rebuilding", path, e)
             _count_store(store_misses=1)
             return None
+        t0 = time.monotonic()
         try:
-            with trace.span("programs.load", program=name, bytes=len(blob)):
-                # unpickles only what save() below wrote on this node
-                payload, in_tree, out_tree = pickle.loads(blob)
-                compiled = deserialize_and_load(
-                    payload, in_tree, out_tree, backend=self.devices[0].client,
-                    execution_devices=self.devices)
+            with f, trace.span("programs.load", program=name) as rec:
+                with trace.span("read"):
+                    blob = f.read()
+                t_read = time.monotonic()
+                rec["bytes"] = len(blob)
+                with trace.span("unpickle"):
+                    # unpickles only what save() below wrote on this node
+                    payload, in_tree, out_tree = pickle.loads(blob)
+                t_unpickled = time.monotonic()
+                with trace.span("deserialize"):
+                    compiled = deserialize_and_load(
+                        payload, in_tree, out_tree, backend=self.devices[0].client,
+                        execution_devices=self.devices)
         except Exception as e:  # whatever a torn or foreign entry raises
             logger.warning("stored program %s unusable (%s: %s); rebuilding",
                            path, type(e).__name__, e)
             self.discard(name, digest)
             _count_store(store_misses=1)
             return None
-        _count_store(store_hits=1, store_load_s=time.monotonic() - t0,
-                     store_bytes=len(blob))
+        t_loaded = time.monotonic()
+        _count_store(store_hits=1, store_load_s=t_loaded - t0, store_read_s=t_read - t0,
+                     store_deserialize_s=t_loaded - t_unpickled,
+                     store_bytes=len(blob), store_bytes_read=len(blob))
         return compiled
 
     def save(self, name: str, digest: str, compiled, reread: bool = False) -> None:

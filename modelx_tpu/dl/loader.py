@@ -158,40 +158,77 @@ def _aliases_buffer(dev_arrays, host: np.ndarray) -> bool:
 
 
 class _OverlapClock:
-    """Wall-clock accounting of the fetch / device_put pipeline: how long
-    each phase had work in flight, and for how long BOTH did (the overlap
-    the two-pool design exists to create). Entirely host-side counters —
-    a load whose overlap_s ~ 0 on a big checkpoint is running its stages
-    serially and has lost the pipeline."""
+    """Wall-clock accounting of one load's fetch / device_put pipeline.
+    Four activities are counted in and out by the threads that do them:
+    ``fetch`` (a ranged read), ``put`` (a device_put dispatch and its wait),
+    ``assemble`` (a fetch thread between its read's end and its hand-off:
+    stacking experts, a cast, a quantise, the full-tensor fallback's slice)
+    and ``blocked`` (a fetch thread waiting for the byte budget or for a
+    staging buffer). Every instant from ``t0`` to :meth:`stop` falls to
+    exactly one of: a read or a put in flight (``busy``, each phase's own
+    wall seconds), host work alone (``assemble_s``), nothing (``idle_s``) —
+    so ``busy[fetch] + busy[put] - overlap + assemble_s + idle_s`` is the
+    load's wall time, and the overlap of reads and puts (what the two-pool
+    design exists to create: ~ 0 on a big checkpoint means the stages run
+    one after the other) follows from it. Beside the tiling:
+    ``backpressure_s``, a fetch thread blocked and no read in flight (the
+    puts hold the reads up), and ``drain_s``, from the last read's end to
+    the stop. Entirely host-side counters."""
 
-    def __init__(self) -> None:
+    def __init__(self, t0: float) -> None:
         self._lock = threading.Lock()
-        self._n = {"fetch": 0, "put": 0}
-        self._last = time.monotonic()
+        self._n = {"fetch": 0, "put": 0, "assemble": 0, "blocked": 0}
+        self._t0 = self._last = self._last_read_end = t0
         self.busy = {"fetch": 0.0, "put": 0.0}
-        self.overlap_s = 0.0
+        self.idle_s = self.assemble_s = self.backpressure_s = 0.0
+        self.wall_s = self.drain_s = 0.0
 
-    def _tick(self) -> None:
+    def _tick(self) -> float:
         now = time.monotonic()
         dt = now - self._last
         self._last = now
         if dt <= 0:
-            return
-        for kind, n in self._n.items():
-            if n > 0:
-                self.busy[kind] += dt
-        if self._n["fetch"] > 0 and self._n["put"] > 0:
-            self.overlap_s += dt
+            return now
+        n = self._n
+        if n["fetch"] > 0:
+            self.busy["fetch"] += dt
+        elif n["blocked"] > 0:
+            self.backpressure_s += dt
+        if n["put"] > 0:
+            self.busy["put"] += dt
+        elif n["fetch"] <= 0:
+            if n["assemble"] > 0:
+                self.assemble_s += dt
+            else:
+                self.idle_s += dt
+        return now
 
-    def enter(self, kind: str) -> None:
+    @contextlib.contextmanager
+    def during(self, kind: str):
         with self._lock:
             self._tick()
             self._n[kind] += 1
+        try:
+            yield
+        finally:
+            with self._lock:
+                now = self._tick()
+                self._n[kind] -= 1
+                if kind == "fetch":
+                    self._last_read_end = now
 
-    def exit(self, kind: str) -> None:
+    def stop(self) -> float:
+        """Close the books at the call's end; returns the instant."""
         with self._lock:
-            self._tick()
-            self._n[kind] -= 1
+            now = self._tick()
+            self.wall_s = now - self._t0
+            self.drain_s = now - self._last_read_end
+        return now
+
+    @property
+    def overlap_s(self) -> float:
+        union = self.wall_s - self.idle_s - self.assemble_s
+        return max(0.0, self.busy["fetch"] + self.busy["put"] - union)
 
 
 class _ByteBudget:
@@ -541,6 +578,16 @@ class LoadStats:
     fetch_busy_seconds: float = 0.0  # fetch_seconds is thread-seconds
     device_put_seconds: float = 0.0
     overlap_seconds: float = 0.0
+    # the rest of the clock's tiling, in wall seconds: fetch_busy +
+    # device_put - overlap + assemble + idle = total_seconds. Neither a
+    # read nor a put nor host work between them; host work alone. And two
+    # readings beside it: a fetch thread held up while nothing reads (the
+    # puts hold the reads up), and the last read's end -> the call's
+    # (packs, array assembly, the tail of the puts)
+    idle_seconds: float = 0.0
+    assemble_seconds: float = 0.0
+    backpressure_seconds: float = 0.0
+    drain_seconds: float = 0.0
     # staging pool: fresh buffer allocations vs pooled reuses; allocs track
     # concurrency, not shard count (tests assert this stays bounded)
     staging_allocs: int = 0
@@ -706,6 +753,7 @@ def load_safetensors(
     local files never split (pread has no per-stream ceiling to beat).
     """
     t0 = time.monotonic()
+    clock = _OverlapClock(t0)  # the headers and the plan below are its idle time
     # env-gated chaos drills (default off): MODELX_FAULT_PLAN with a
     # "loader.read" schedule wraps the source so operators can rehearse the
     # retry/governor behavior against a real deployment on demand
@@ -748,7 +796,6 @@ def load_safetensors(
     n_transfer = transfer_concurrency
     if n_transfer <= 0:
         n_transfer = max(8, min(16, 2 * len(mesh.local_devices)))
-    clock = _OverlapClock()
     # the outstanding-buffer cap is what makes the pool a PIPELINE gate:
     # one buffer per fetch thread, one per transfer thread, plus slack so a
     # fetch never waits on an about-to-finish put
@@ -770,12 +817,8 @@ def load_safetensors(
         # an exception there would have leaked a governor slot forever
         governor.acquire()
         try:
-            clock.enter("fetch")
-            try:
-                with trace.span("dl.fetch", bytes=length):
-                    return _read_with_retry(source, offset, length, out, timer=timer)
-            finally:
-                clock.exit("fetch")
+            with clock.during("fetch"), trace.span("dl.fetch", bytes=length):
+                return _read_with_retry(source, offset, length, out, timer=timer)
         finally:
             governor.release(sample[0], sample[1])
 
@@ -901,7 +944,8 @@ def load_safetensors(
             staging = None
             out = None
             if pool_ok and staging_min_bytes and length >= staging_min_bytes:
-                staging = staging_pool.acquire(length)
+                with clock.during("blocked"):
+                    staging = staging_pool.acquire(length)
                 out = memoryview(staging)
             try:
                 raw = _fetch_bytes(data_offset + b0, length, out)
@@ -918,7 +962,8 @@ def load_safetensors(
             return arr, length, staging
         raw = _cached_full_tensor(info)
         arr = _as_np(raw, np_dtype, info.shape)
-        sliced = np.ascontiguousarray(arr[full_spec]) if info.shape else arr.reshape(())
+        with clock.during("assemble"):
+            sliced = np.ascontiguousarray(arr[full_spec]) if info.shape else arr.reshape(())
         return sliced, len(raw), None
 
     def fetch_group(info: st.TensorInfo, group: list):
@@ -965,7 +1010,8 @@ def load_safetensors(
             with _full_lock:
                 cached = info.name in _full_cache
             cost = slice_bytes if cached else max(slice_bytes, info.nbytes)
-        cost = inflight.acquire(cost)  # clamped: release exactly this much
+        with clock.during("blocked"):
+            cost = inflight.acquire(cost)  # clamped: release exactly this much
         staging = None
         try:
             tf0 = time.monotonic()
@@ -983,69 +1029,72 @@ def load_safetensors(
                     )
                     parts.append(part)
                     nread += nb
-                arr = np.stack(parts)
+                with clock.during("assemble"):
+                    arr = np.stack(parts)
             else:
                 arr, nread, staging = _fetch_slice(info, full_spec)
             with lock:
                 stats.bytes_fetched += nread
                 stats.fetch_seconds += time.monotonic() - tf0
-            scale = None
-            if _quantized(info.name, info):
-                inner = full_spec[1].start == 0 and full_spec[1].stop == info.shape[1]
-                if inner:
-                    # this group's rows are complete channels: local scales
-                    # ARE the global per-channel scales — fused single-pass
-                    # quantize (native when available)
-                    arr, scale = qt.quantize_fused(arr)
-                else:
-                    # input dim sharded: scales must span the full contraction
-                    # axis — compute once from the cached full tensor
-                    with _full_lock:
-                        scale_full = _scale_cache.get(info.name)
-                    if scale_full is None:
-                        full = _as_np(_cached_full_tensor(info), info.np_dtype(), info.shape)
-                        scale_full = qt.channel_scales(full)
+            # from the read's end to the hand-off: host work the clock names
+            with clock.during("assemble"):
+                scale = None
+                if _quantized(info.name, info):
+                    inner = full_spec[1].start == 0 and full_spec[1].stop == info.shape[1]
+                    if inner:
+                        # this group's rows are complete channels: local scales
+                        # ARE the global per-channel scales — fused single-pass
+                        # quantize (native when available)
+                        arr, scale = qt.quantize_fused(arr)
+                    else:
+                        # input dim sharded: scales must span the full contraction
+                        # axis — compute once from the cached full tensor
                         with _full_lock:
-                            _scale_cache[info.name] = scale_full
-                    scale = np.ascontiguousarray(
-                        scale_full[full_spec[0].start : full_spec[0].stop]
-                    )
-                    arr = qt.quantize_rows(arr, scale)
-            elif dtype is not None and arr.dtype != np.dtype(dtype):
-                arr = arr.astype(dtype)
-            if staging is not None and not np.may_share_memory(arr, staging):
-                # a host-side cast/quantize copied the bytes out: the pooled
-                # buffer is free for the next fetch right now, not after the
-                # transfer
-                staging_pool.release(staging)
-                staging = None
-            if progress:
-                progress(arr.nbytes * len(group))
-            if arr.nbytes < cost:
-                # the parked array is smaller than what the fetch charged
-                # (full-fetch fallback, host-side cast/quantize): give the
-                # difference back so sibling groups stop waiting on bytes
-                # nobody is holding
-                inflight.release(cost - arr.nbytes)
-                cost = arr.nbytes
-            # batched transfer involves plain device_put (same dtype
-            # canonicalization as the unbatched path), so ANY small
-            # unquantized shard qualifies
-            packable = (
-                scale is None and pack_threshold and arr.nbytes < pack_threshold
-            )
-            if packable:
-                # small shard: ride the packed transfer instead of paying a
-                # per-tensor device round-trip. Budget released now: packs
-                # park until every fetch settles, and the packable tail is
-                # bounded by pack_threshold x tensor count, not the budget
-                inflight.release(cost)
-                if staging is not None:
-                    # packs park until load end — copy out so the pooled
-                    # buffer doesn't sit hostage under a small tensor
-                    arr = arr.copy()
+                            scale_full = _scale_cache.get(info.name)
+                        if scale_full is None:
+                            full = _as_np(_cached_full_tensor(info), info.np_dtype(), info.shape)
+                            scale_full = qt.channel_scales(full)
+                            with _full_lock:
+                                _scale_cache[info.name] = scale_full
+                        scale = np.ascontiguousarray(
+                            scale_full[full_spec[0].start : full_spec[0].stop]
+                        )
+                        arr = qt.quantize_rows(arr, scale)
+                elif dtype is not None and arr.dtype != np.dtype(dtype):
+                    arr = arr.astype(dtype)
+                if staging is not None and not np.may_share_memory(arr, staging):
+                    # a host-side cast/quantize copied the bytes out: the pooled
+                    # buffer is free for the next fetch right now, not after the
+                    # transfer
                     staging_pool.release(staging)
-                return ("pack", arr, group)
+                    staging = None
+                if progress:
+                    progress(arr.nbytes * len(group))
+                if arr.nbytes < cost:
+                    # the parked array is smaller than what the fetch charged
+                    # (full-fetch fallback, host-side cast/quantize): give the
+                    # difference back so sibling groups stop waiting on bytes
+                    # nobody is holding
+                    inflight.release(cost - arr.nbytes)
+                    cost = arr.nbytes
+                # batched transfer involves plain device_put (same dtype
+                # canonicalization as the unbatched path), so ANY small
+                # unquantized shard qualifies
+                packable = (
+                    scale is None and pack_threshold and arr.nbytes < pack_threshold
+                )
+                if packable:
+                    # small shard: ride the packed transfer instead of paying a
+                    # per-tensor device round-trip. Budget released now: packs
+                    # park until every fetch settles, and the packable tail is
+                    # bounded by pack_threshold x tensor count, not the budget
+                    inflight.release(cost)
+                    if staging is not None:
+                        # packs park until load end — copy out so the pooled
+                        # buffer doesn't sit hostage under a small tensor
+                        arr = arr.copy()
+                        staging_pool.release(staging)
+                    return ("pack", arr, group)
         except BaseException:
             inflight.release(cost)
             if staging is not None:
@@ -1055,32 +1104,28 @@ def load_safetensors(
         def xfer():
             pooled = staging
             try:
-                clock.enter("put")
-                try:
-                    with trace.span("dl.put", bytes=arr.nbytes):
-                        out = [
-                            (
-                                dev,
-                                jax.device_put(arr, dev),
-                                jax.device_put(scale, dev) if scale is not None else None,
-                            )
-                            for dev, _ in group
-                        ]
-                        if pooled is not None:
-                            # the transfer may still be reading the pooled host
-                            # buffer asynchronously: wait before recycling it —
-                            # and if the backend zero-copied (the device array
-                            # ALIASES the buffer, PJRT CPU with 64-byte-aligned
-                            # hosts), hand the memory over instead of recycling
-                            devs = [t[1] for t in out]
-                            jax.block_until_ready(devs)
-                            if _aliases_buffer(devs, pooled):
-                                staging_pool.forfeit(pooled)
-                            else:
-                                staging_pool.release(pooled)
-                            pooled = None
-                finally:
-                    clock.exit("put")
+                with clock.during("put"), trace.span("dl.put", bytes=arr.nbytes):
+                    out = [
+                        (
+                            dev,
+                            jax.device_put(arr, dev),
+                            jax.device_put(scale, dev) if scale is not None else None,
+                        )
+                        for dev, _ in group
+                    ]
+                    if pooled is not None:
+                        # the transfer may still be reading the pooled host
+                        # buffer asynchronously: wait before recycling it —
+                        # and if the backend zero-copied (the device array
+                        # ALIASES the buffer, PJRT CPU with 64-byte-aligned
+                        # hosts), hand the memory over instead of recycling
+                        devs = [t[1] for t in out]
+                        jax.block_until_ready(devs)
+                        if _aliases_buffer(devs, pooled):
+                            staging_pool.forfeit(pooled)
+                        else:
+                            staging_pool.release(pooled)
+                        pooled = None
                 return out
             finally:
                 inflight.release(cost)
@@ -1127,7 +1172,7 @@ def load_safetensors(
                 else:
                     entries.append(r)
             settled[name] = entries
-        with trace.span("dl.put", packs=len(pack_jobs)):
+        with clock.during("put"), trace.span("dl.put", packs=len(pack_jobs)):
             packed = _transfer_packs(pack_jobs)
         for name, info in tensors.items():
             sharding, _groups = plans[name]
@@ -1164,13 +1209,17 @@ def load_safetensors(
         _scale_cache.clear()
 
     jax.block_until_ready(results)  # QTensor entries are pytrees
-    stats.total_seconds = time.monotonic() - t0
+    stats.total_seconds = clock.stop() - t0
     stats.fetch_width = governor.width
     stats.fetch_backoffs = governor.backoffs
     stats.fetch_growths = governor.growths
     stats.fetch_busy_seconds = clock.busy["fetch"]
     stats.device_put_seconds = clock.busy["put"]
     stats.overlap_seconds = clock.overlap_s
+    stats.idle_seconds = clock.idle_s
+    stats.assemble_seconds = clock.assemble_s
+    stats.backpressure_seconds = clock.backpressure_s
+    stats.drain_seconds = clock.drain_s
     stats.staging_allocs = staging_pool.allocs
     stats.staging_reuses = staging_pool.reuses
     trace.record(

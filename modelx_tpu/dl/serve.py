@@ -263,7 +263,8 @@ def compile_cache_stats() -> dict:
     """``{"dir", "requests", "hits", "misses", "programs", "trace_s",
     "lower_s", "backend_compile_s", "retrieval_s"}`` — what went through
     jax — and ``{"store_hits", "store_misses", "store_load_s",
-    "store_write_s", "store_bytes"}`` — what the engine's executable store
+    "store_read_s", "store_deserialize_s", "store_write_s", "store_bytes",
+    "store_bytes_read"}`` — what the engine's executable store
     (dl/aot_cache.py) served without it — for /metrics: whether a restart
     found its programs, and what each cost, is read here, not inferred from
     timing."""
@@ -414,6 +415,10 @@ class ModelServer:
         from modelx_tpu.dl.loader import LocalFileSource, load_safetensors
         from modelx_tpu.dl.safetensors import read_header_from_file
 
+        # a boot-time load on the thread that opened start-up's `load` stage
+        # splits it (utils/trace.Startup.sub): install, headers, plan, shards,
+        # kv_alloc, finish; any other load's calls return at once
+        trace.startup.sub("install")
         with trace.span("serve.load", model=self.name, dir=self.model_dir):
             t0 = time.monotonic()
             paths = sorted(glob.glob(os.path.join(self.model_dir, "*.safetensors")))
@@ -441,10 +446,12 @@ class ModelServer:
             # detect the family from the headers so the right partition rules
             # apply from the first byte fetched
             infos_all: dict = {}
+            trace.startup.sub("headers")
             with trace.span("headers", files=len(paths)):
                 for path in paths:
                     infos, _ = read_header_from_file(path)
                     infos_all.update(infos)
+            trace.startup.sub("plan")
             self.family = fam.detect(list(infos_all))
             # mirror the loader's expert fusion so header-derived shapes
             # match the params it will deliver (stacked [E, ...] experts)
@@ -475,11 +482,16 @@ class ModelServer:
             compile_thread.start()
             params: dict = {}
             total = 0
-            # the loader's own split, summed over the shards: thread-seconds
-            # in ranged reads, then wall seconds with a read in flight, with
-            # a device_put in flight, and with both
-            fetch_s = fetch_busy_s = put_s = overlap_s = 0.0
-            with trace.span("shards", files=len(paths)):
+            # the loader's clock (dl/loader._OverlapClock), summed over the
+            # files: wall seconds with a read in flight, with a device_put in
+            # flight, with both; with neither nor host work between them;
+            # with a fetch thread held up and no read; with host work alone;
+            # and from the last read's end to the call's
+            split = dict.fromkeys(("fetch_busy", "device_put", "overlap", "idle",
+                                   "backpressure", "assemble", "drain"), 0.0)
+            in_calls = 0.0
+            trace.startup.sub("shards")
+            with trace.span("shards", files=len(paths)) as shards:
                 for path in paths:
                     src = LocalFileSource(path)
                     try:
@@ -490,16 +502,19 @@ class ModelServer:
                         src.close()
                     params.update(arrays)
                     total += stats.bytes_to_device
-                    fetch_s += stats.fetch_seconds
-                    fetch_busy_s += stats.fetch_busy_seconds
-                    put_s += stats.device_put_seconds
-                    overlap_s += stats.overlap_seconds
+                    in_calls += stats.total_seconds
+                    for key in split:
+                        split[key] += getattr(stats, f"{key}_seconds")
+            # between two files' calls nothing is read or put either
+            split["idle"] += max(0.0, shards["duration_s"] - in_calls)
             self.params = params
+            trace.startup.sub("kv_alloc")
             if engine is not None:
                 # behind the weights, where a lazily built engine always had
                 # it: allocated before them, the KV cache moved the decode
                 # cell's tokens/s by -0.6 and -2.1 % (PERF.md, PR 26)
                 engine_at_load(self, True)
+            trace.startup.sub("finish")
             if self.lora_dir:
                 from modelx_tpu.dl import lora
 
@@ -518,10 +533,10 @@ class ModelServer:
             self.stats["weight_shard_factor"] = weight_shard_factor(self.mesh)
             self.stats["family"] = self.family.name
             self.stats["load_seconds"] = round(seconds, 3)
-            self.stats["load_fetch_seconds"] = round(fetch_s, 3)
-            self.stats["load_fetch_busy_seconds"] = round(fetch_busy_s, 3)
-            self.stats["load_device_put_seconds"] = round(put_s, 3)
-            self.stats["load_overlap_seconds"] = round(overlap_s, 3)
+            for key, value in split.items():
+                self.stats[f"load_{key}_seconds"] = round(value, 3)
+            self.stats["load_shards_seconds"] = round(shards["duration_s"], 3)
+            self.stats["load_shard_files"] = len(paths)
             self.stats["load_bytes"] = total
             self.stats["load_gbps"] = round(total / max(seconds, 1e-9) / 1e9, 3)
             from modelx_tpu import native
@@ -1987,6 +2002,13 @@ def serve(servers: ModelServer | ServerSet, listen: str = ":8000",
             self.end_headers()
             self.wfile.write(body)
 
+        def _first_token(self) -> None:
+            """This request has its first token: where it is the pod's
+            first, start-up's clock closes (utils/trace.Startup). One
+            branch a request."""
+            if trace.startup.first_token_s is None:
+                trace.startup.first_token(self._t0)
+
         def _stream_chunks(self, content_type: str, payloads, error_payload) -> None:
             """Commit a 200 + chunked transfer encoding and write each bytes
             payload. A mid-stream error (status already on the wire) writes
@@ -2051,6 +2073,8 @@ def serve(servers: ModelServer | ServerSet, listen: str = ":8000",
                 # stream request still gets its 429 + Retry-After
                 return self._json(e.http_status, {"error": str(e)},
                                   headers=e.headers())
+            if first is not None:
+                self._first_token()
 
             def payloads():
                 emitted = 0
@@ -2163,6 +2187,8 @@ def serve(servers: ModelServer | ServerSet, listen: str = ":8000",
                         first = next(events, None)
                     except ValueError as e:
                         raise oai.APIError(400, str(e)) from e
+                    if first is not None:
+                        self._first_token()
 
                     def payloads():
                         if first is not None:
@@ -2183,9 +2209,11 @@ def serve(servers: ModelServer | ServerSet, listen: str = ":8000",
                             else {"error": {"message": str(e), "type": "server_error"}}
                         ),
                     )
-                return self._json(200, oai.run_completion(
+                done = oai.run_completion(
                     sset, req, chat, timeout_s=timeout_s, priority=priority,
-                    request_id=self._rid, timing=self._timing))
+                    request_id=self._rid, timing=self._timing)
+                self._first_token()  # unstreamed: the first token is the answer
+                return self._json(200, done)
             except oai.APIError as e:
                 # typed lifecycle 503s raised inside the API layer carry
                 # Retry-After like the native surface's (satellite:
@@ -2362,6 +2390,10 @@ def serve(servers: ModelServer | ServerSet, listen: str = ":8000",
                 # plus OpenAI's {object: "list", data: [...]}
                 self._json(200, oai.models_payload(sset))
             elif self.path.split("?", 1)[0] == "/v1/trace":
+                if _query_param(self.path, "startup") == "1":
+                    # process creation -> first token as one list of spans
+                    # in start order (utils/trace.Startup.timeline)
+                    return self._json(200, trace.startup.timeline())
                 # ?request_id= filters the summary to one request's
                 # timeline; ?prefix= narrows by span path (both optional)
                 self._json(200, trace.tracer().summary(
@@ -2794,6 +2826,7 @@ def serve(servers: ModelServer | ServerSet, listen: str = ":8000",
                                               timing=self._timing, **samp)
                     else:
                         out = engine.generate(tokens, max_new_tokens=n, **samp)
+                    self._first_token()  # unstreamed: the first token is the answer
                     rows = out.tolist()
                     if stop_ids:
                         # trim each row's GENERATED portion at the first stop

@@ -322,7 +322,9 @@ def main(model_dir: str, models: tuple[str, ...], mesh: str, dtype: str, listen:
     # process creation -> ready by stage (/metrics "startup"): everything up
     # to here — interpreter, imports, flag parsing — was `imports`
     trace.startup.begin("backend_init")
+    trace.startup.sub("distributed")
     initialize()  # no-op single-process; wires multi-host TPU pods
+    trace.startup.sub("devices")
     devices = jax.devices()  # the backend answers: on a TPU, seconds
     trace.startup.stage("configure")
     if compile_cache:

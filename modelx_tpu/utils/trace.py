@@ -119,6 +119,8 @@ class Tracer:
                 if len(self._spans) == self.max_spans:
                     self._dropped += 1  # deque(maxlen) evicts the oldest in O(1)
                 self._spans.append(span)
+        if startup.collecting:
+            startup.keep(span)
         if logger.isEnabledFor(_LOG_LEVEL):
             logger.log(
                 _LOG_LEVEL,
@@ -334,13 +336,31 @@ def _process_age_s() -> float | None:
 
 
 class Startup:
-    """Process creation -> ready, by stage. Like :class:`Phases` the stages
-    tile: ``begin(name)`` closes ``imports`` (interpreter start and imports,
-    from the process's creation), each ``stage(name)`` closes the one before, and
-    ``ready()`` closes the last, so the stage seconds sum to ``ready_s``.
-    Each closed stage is also a span ``startup.<stage>``. ``note`` keeps
+    """Process creation -> the first token, by stage. Like :class:`Phases`
+    the stages tile: ``begin(name)`` closes ``imports`` (interpreter start and
+    imports, from the process's creation), each ``stage(name)`` closes the one
+    before, and ``ready()`` closes the last, so the stage seconds sum to
+    ``ready_s``. A stage tiles one level down the same way: ``sub(name)``
+    closes the sub-stage before it — the first one of a stage began with the
+    stage, the last one ends with it — so a stage's sub-stages sum to the
+    stage (``<stage>_<sub>_s``). Past ready the tiling goes on with two stages
+    outside ``ready_s``, closed once, by the first request that yields a
+    token (``first_token``): ``first_wait`` (ready -> that request's arrival)
+    and ``first_request`` (arrival -> its first token); ``first_token_s`` is
+    process creation -> that token. Each closed stage and sub-stage is also
+    a span, ``startup.<stage>`` and ``startup.<stage>/<sub>``. ``note`` keeps
     what falls outside the tiling (an engine built lazily by the first
-    request): its seconds, and when it ended."""
+    request): its seconds, and when it ended.
+
+    From ``begin`` to the first token the clock also keeps every span the
+    process closes (:meth:`timeline`): one list, bounded, frozen at the
+    first token — the start as one reads it afterwards, which neither the
+    aggregate (no order) nor the ring (requests only) can give."""
+
+    MAX_TIMELINE = 4096
+    # reads and puts shorter than this are merged into one entry a path
+    MERGE_BELOW_S = 1e-3
+    _MERGED = ("dl.fetch", "dl.put")
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -350,10 +370,19 @@ class Startup:
         self.t0: float | None = None
         self.source = ""
         self._stages: dict[str, float] = {}
+        self._subs: dict[str, float] = {}  # "<stage>_<sub>" -> seconds
         self._extra: dict[str, float] = {}
         self._cur = ""
         self._t = 0.0
+        self._sub = ""  # the open sub-stage of the open stage, and its start
+        self._t_sub = 0.0
+        self._owner = 0  # the thread that opened the stage: only it splits it
         self.ready_s: float | None = None
+        self.first_token_s: float | None = None
+        self.collecting = False  # begin -> first token: spans go to the timeline
+        self._timeline: list[dict[str, Any]] = []
+        self._merged: dict[str, dict[str, Any]] = {}
+        self.timeline_dropped = 0
 
     def begin(self, stage: str) -> None:
         now, first = time.monotonic(), T_FIRST_LINE
@@ -369,23 +398,113 @@ class Startup:
             else:
                 self.t0, self.source = first, "first_line"
             self._cur, self._t = "imports", self.t0
+            # process creation -> the package's first line -> here
+            self._subs["imports_interpreter"] = first - self.t0
+            self._sub, self._t_sub = "modules", first
+            self._owner = threading.get_ident()
+            self.collecting = True
+        record("startup.imports/interpreter", self.t0, first - self.t0)
         self.stage(stage)
+
+    def _close_sub(self, now: float) -> tuple | None:
+        """Under the lock: close the open sub-stage at ``now``."""
+        if not self._sub:
+            return None
+        key, start = f"{self._cur}_{self._sub}", self._t_sub
+        self._subs[key] = self._subs.get(key, 0.0) + now - start
+        closed = (f"startup.{self._cur}/{self._sub}", start, now - start)
+        self._sub = ""
+        return closed
 
     def stage(self, name: str) -> None:
         now = time.monotonic()
         with self._lock:
             if self.t0 is None or self.ready_s is not None:
                 return
+            sub = self._close_sub(now)
             prev, start = self._cur, self._t
             self._stages[prev] = self._stages.get(prev, 0.0) + now - start
             self._cur, self._t = name, now
+            self._owner = threading.get_ident()
+        if sub is not None:
+            record(*sub)
         record(f"startup.{prev}", start, now - start)
+
+    def sub(self, name: str) -> None:
+        """The open stage goes on in sub-stage ``name``: one clock read.
+        Only for the thread that opened the stage (loads side by side on
+        threads of their own would not tile), and not past ready."""
+        now = time.monotonic()
+        with self._lock:
+            if (self.t0 is None or self.ready_s is not None or name == self._sub
+                    or threading.get_ident() != self._owner):
+                return
+            closed = self._close_sub(now)
+            # a stage's first sub-stage began with the stage
+            self._sub, self._t_sub = name, (now if closed else self._t)
+        if closed is not None:
+            record(*closed)
 
     def ready(self) -> None:
         self.stage("")
         with self._lock:
             if self.t0 is not None and self.ready_s is None:
                 self.ready_s = self._t - self.t0
+
+    def first_token(self, arrived: float) -> None:
+        """The first request to yield a token, which arrived at ``arrived``
+        (``time.monotonic``), has it now: closes ``first_wait`` and
+        ``first_request``, once, and freezes the timeline."""
+        now = time.monotonic()
+        with self._lock:
+            if self.ready_s is None or self.first_token_s is not None:
+                return
+            t_ready = self.t0 + self.ready_s
+            arrived = min(max(arrived, t_ready), now)
+            self._stages["first_wait"] = arrived - t_ready
+            self._stages["first_request"] = now - arrived
+            self.first_token_s = now - self.t0
+        record("startup.first_wait", t_ready, arrived - t_ready)
+        record("startup.first_request", arrived, now - arrived)
+        self.collecting = False
+
+    def keep(self, span: dict[str, Any]) -> None:
+        """A span closed while ``collecting``: one entry of the timeline."""
+        path, dur = span["path"], span["duration_s"]
+        with self._lock:
+            if self.t0 is None:
+                return
+            short = dur < self.MERGE_BELOW_S and path.endswith(self._MERGED)
+            entry = self._merged.get(path) if short else None
+            if entry is not None:
+                entry["duration_s"] += dur
+                entry["attrs"]["merged"] += 1
+                entry["attrs"]["bytes"] += span.get("bytes", 0)
+                return
+            if len(self._timeline) >= self.MAX_TIMELINE:
+                self.timeline_dropped += 1
+                return
+            attrs = {k: v if isinstance(v, (int, float, str, bool)) else str(v)
+                     for k, v in span.items()
+                     if k not in ("path", "start_s", "duration_s", "self_s")}
+            entry = {"path": path, "at_s": span["start_s"] - self.t0, "duration_s": dur,
+                     "thread": threading.current_thread().name, "attrs": attrs}
+            if short:
+                attrs.update(merged=1, bytes=span.get("bytes", 0))
+                self._merged[path] = entry
+            self._timeline.append(entry)
+
+    def timeline(self) -> dict:
+        """``{spans: [...], dropped, frozen}``: the spans closed from process
+        creation to the first token, in start order, each with ``at_s``
+        (seconds since process creation), ``duration_s``, the thread's name
+        and its attributes."""
+        with self._lock:
+            spans = [dict(e, at_s=round(e["at_s"], 6), duration_s=round(e["duration_s"], 6),
+                          attrs=dict(e["attrs"])) for e in self._timeline]
+            dropped, frozen = self.timeline_dropped, self.first_token_s is not None
+        spans.sort(key=lambda e: e["at_s"])
+        return {"spans": spans, "dropped": dropped, "frozen": frozen}
 
     def note(self, name: str, seconds: float) -> None:
         """``seconds`` of ``name`` just ended: added up under ``<name>_s``,
@@ -403,15 +522,19 @@ class Startup:
                 self._extra[name] = self._extra.get(name, 0) + n
 
     def snapshot(self) -> dict:
-        """``{<stage>_s..., ready_s, <noted>_s..., <instant>_at_s...,
-        source}``; empty in a process that never called ``begin``."""
+        """``{<stage>_s..., <stage>_<sub>_s..., ready_s, first_token_s,
+        <noted>_s..., <instant>_at_s..., source}``; empty in a process that
+        never called ``begin``."""
         with self._lock:
             if self.t0 is None:
                 return {}
-            out = {f"{k}_s": round(v, 4) for k, v in self._stages.items()}
+            out = {f"{k}_s": round(v, 4) for k, v in (*self._stages.items(),
+                                                      *self._subs.items())}
             out.update({k: round(v, 4) for k, v in self._extra.items()})
             if self.ready_s is not None:
                 out["ready_s"] = round(self.ready_s, 4)
+            if self.first_token_s is not None:
+                out["first_token_s"] = round(self.first_token_s, 4)
             out["source"] = self.source
             return out
 

@@ -25,6 +25,7 @@ from modelx_tpu.dl import safetensors as st
 from modelx_tpu.dl import serve as serve_mod
 from modelx_tpu.dl.continuous import ContinuousBatcher
 from modelx_tpu.dl.serve import ModelServer
+from modelx_tpu.utils import trace
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PROMPT = np.array([[5, 9, 2, 7, 11]], np.int32)
@@ -421,8 +422,6 @@ class TestMetrics:
         assert {"requests", "hits", "misses", "trace_s", "programs"} <= set(stats)
 
     def test_loads_and_builds_are_spans_that_name_the_program(self, server, node):
-        from modelx_tpu.utils import trace
-
         def count(name):
             return sum(v["count"] for k, v in trace.tracer().summary().items()
                        if k.split("/")[-1] == name)
@@ -437,6 +436,80 @@ class TestMetrics:
         n = len(entries(node))
         assert count("programs.build") - builds == n
         assert count("programs.load") - loads == n
+
+
+class TestLoadInThreeParts:
+    """A stored program's load is its file read, the unpickling and PJRT's
+    deserialize-and-load (ISSUE 40): three child spans, two counters beside
+    ``store_load_s``, and the bytes read."""
+
+    PARTS = ("store_load_s", "store_read_s", "store_deserialize_s")
+
+    @staticmethod
+    def spans() -> dict:
+        agg = {}
+        for path, row in trace.tracer().summary().items():
+            for tail in ("programs.load", "programs.load/read", "programs.load/unpickle",
+                         "programs.load/deserialize"):
+                if path.endswith(tail):
+                    cur = agg.setdefault(tail, {"count": 0, "total_s": 0.0})
+                    cur["count"] += row["count"]
+                    cur["total_s"] += row["total_s"]
+        return agg
+
+    @pytest.fixture
+    def stored(self, server, node):
+        cb = ContinuousBatcher(server, **ENGINE)
+        try:
+            cb.generate(PROMPT, max_new_tokens=6)
+        finally:
+            cb.close()
+        return entries(node)
+
+    def test_read_unpickle_and_deserialize_sum_to_the_load_on_a_hit(self, server, node, stored):
+        before, spans0 = aot_cache.store_stats(), self.spans()
+        cb = ContinuousBatcher(server, **ENGINE)
+        try:
+            cb.generate(PROMPT, max_new_tokens=6)
+        finally:
+            cb.close()
+        moved, spans = growth(before), self.spans()
+        assert moved["store_hits"] == len(stored) and moved["store_misses"] == 0
+        grew = {k: spans[k]["total_s"] - spans0.get(k, {"total_s": 0.0})["total_s"] for k in spans}
+        for tail in grew:  # one of each a hit
+            assert spans[tail]["count"] - spans0.get(tail, {"count": 0})["count"] == len(stored)
+        assert all(moved[k] > 0 for k in self.PARTS)
+        unpickle = grew["programs.load/unpickle"]
+        # the counters are cut at three clock reads, so only the spans' own
+        # entry and exit lie between them and the unpickle span
+        assert moved["store_read_s"] + unpickle + moved["store_deserialize_s"] == pytest.approx(
+            moved["store_load_s"], rel=0.01, abs=1e-3 * len(stored))
+        assert moved["store_read_s"] + moved["store_deserialize_s"] <= moved["store_load_s"]
+        size = sum(os.path.getsize(os.path.join(node, "programs", e)) for e in stored)
+        assert moved["store_bytes_read"] == moved["store_bytes"] == size
+
+    def test_a_torn_entry_is_one_miss_and_leaves_the_three_unchanged(self, server, node, stored):
+        for e in stored:
+            path = os.path.join(node, "programs", e)
+            with open(path, "r+b") as f:
+                f.truncate(os.path.getsize(path) // 2)
+        before = aot_cache.store_stats()
+        cb = ContinuousBatcher(server, **ENGINE)
+        try:
+            cb.generate(PROMPT, max_new_tokens=6)
+        finally:
+            cb.close()
+        moved = growth(before)
+        assert moved["store_misses"] == len(stored) and moved["store_hits"] == 0
+        assert all(moved[k] == 0 for k in self.PARTS)
+        assert moved["store_bytes_read"] == 0 and moved["store_bytes"] > 0  # written again
+
+    def test_a_lookup_that_finds_no_file_is_a_miss_without_a_span(self, server, node):
+        before, spans0 = aot_cache.store_stats(), self.spans()
+        store = aot_cache.ExecutableStore(node, server.mesh, "ctx")
+        assert store.load("chunk", "0" * 32) is None
+        assert growth(before)["store_misses"] == 1
+        assert self.spans() == spans0
 
 
 class TestLayerMetricFiles:

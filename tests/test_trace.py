@@ -207,7 +207,8 @@ class TestStartup:
         st.stage("too_late")  # after ready nothing moves
         snap = st.snapshot()
         stages = [k for k in snap if k.endswith("_s") and k != "ready_s"
-                  and not k.startswith("engine_init")]
+                  and not k.startswith(("engine_init", "imports_interpreter",
+                                        "imports_modules"))]
         assert stages == ["imports_s", "backend_init_s", "load_s"]
         assert sum(snap[k] for k in stages) == pytest.approx(snap["ready_s"], abs=1e-3)
         assert snap["imports_s"] > 0 and snap["engine_init_s"] == 0.5
@@ -220,8 +221,156 @@ class TestStartup:
     def test_a_process_that_never_began_reports_nothing(self):
         st = Startup()
         st.stage("load")
+        st.sub("shards")
         st.ready()
+        st.first_token(time.monotonic())
         assert st.snapshot() == {}
+        assert st.timeline() == {"spans": [], "dropped": 0, "frozen": False}
+
+    @staticmethod
+    def started() -> Startup:
+        """imports | backend_init = distributed + devices | configure (not
+        split) | load = install + headers + shards + headers + finish."""
+        st = Startup()
+        st.begin("backend_init")
+        st.sub("distributed")
+        time.sleep(0.002)
+        st.sub("devices")
+        time.sleep(0.004)
+        st.stage("configure")
+        time.sleep(0.002)
+        st.stage("load")
+        time.sleep(0.002)
+        for name in ("install", "headers", "shards", "headers", "headers", "finish"):
+            st.sub(name)  # a second model's headers add up; staying is no entry
+            time.sleep(0.003)
+        return st
+
+    def test_sub_stages_tile_their_stage_and_the_stages_still_tile_ready(self):
+        st = self.started()
+        st.ready()
+        st.sub("too_late")
+        snap = st.snapshot()
+        subs = {"imports": ("interpreter", "modules"),
+                "backend_init": ("distributed", "devices"),
+                "load": ("install", "headers", "shards", "finish")}
+        for stage, names in subs.items():
+            assert [k for k in snap if k.startswith(stage + "_") and k != stage + "_s"] == [
+                f"{stage}_{n}_s" for n in names]
+            assert sum(snap[f"{stage}_{n}_s"] for n in names) == pytest.approx(
+                snap[f"{stage}_s"], abs=1e-3), stage
+        assert [k for k in snap if k.startswith("configure_")] == ["configure_s"]  # not split
+        assert sum(snap[k] for k in ("imports_s", "backend_init_s", "configure_s",
+                                     "load_s")) == pytest.approx(snap["ready_s"], abs=1e-3)
+        # the first sub-stage began with its stage: `install` holds the 2 ms before it
+        assert snap["load_install_s"] >= 0.004 and snap["load_headers_s"] >= 0.005
+        assert snap["backend_init_devices_s"] >= 0.004
+        agg = tracer().summary("startup.")
+        assert {"startup.imports/interpreter", "startup.imports/modules",
+                "startup.backend_init/devices", "startup.load/install",
+                "startup.load/finish", "startup.load"} <= set(agg)
+        assert agg["startup.load/headers"]["count"] == 2
+
+    def test_another_threads_sub_stage_is_not_the_stages(self):
+        import threading
+
+        st = Startup()
+        st.begin("load")
+        t = threading.Thread(target=st.sub, args=("shards",))
+        t.start()
+        t.join()
+        st.ready()
+        assert [k for k in st.snapshot() if k.startswith("load_")] == ["load_s"]
+
+    def test_the_first_token_closes_two_stages_once(self):
+        st = self.started()
+        st.first_token(time.monotonic())  # not ready yet: nothing to close
+        assert st.first_token_s is None
+        st.ready()
+        time.sleep(0.004)
+        arrived = time.monotonic()
+        time.sleep(0.006)
+        st.first_token(arrived)
+        snap = st.snapshot()
+        assert snap["first_wait_s"] >= 0.004 and snap["first_request_s"] >= 0.006
+        assert snap["ready_s"] + snap["first_wait_s"] + snap["first_request_s"] == pytest.approx(
+            snap["first_token_s"], abs=1e-3)
+        time.sleep(0.003)
+        st.first_token(time.monotonic())  # a second request moves nothing
+        assert st.snapshot() == snap
+        assert tracer().summary("startup.first")["startup.first_request"]["count"] == 1
+
+    def test_a_request_that_arrived_before_ready_waited_no_time(self):
+        st = Startup()
+        st.begin("load")
+        early = time.monotonic()
+        st.ready()
+        st.first_token(early)
+        snap = st.snapshot()
+        assert snap["first_wait_s"] == 0.0
+        assert snap["first_request_s"] == pytest.approx(snap["first_token_s"] - snap["ready_s"],
+                                                        abs=1e-3)
+
+
+class TestStartupTimeline:
+    @pytest.fixture
+    def st(self, monkeypatch):
+        fresh = Startup()
+        monkeypatch.setattr(trace, "startup", fresh)  # the tracer feeds this one
+        return fresh
+
+    def test_spans_in_start_order_with_thread_and_attributes(self, st):
+        import threading
+
+        with span("before.begin"):
+            pass  # nothing is kept before the clock has a zero
+        st.begin("load")
+        with span("serve.load", model="m"):
+            t = threading.Thread(target=lambda: trace.record(
+                "dl.fetch", time.monotonic(), 0.25, bytes=7), name="fetcher")
+            t.start()
+            t.join()
+            with span("shards", files=2, where=("a", 1)):
+                pass
+        st.ready()
+        st.first_token(time.monotonic())
+        with span("after.first.token"):
+            pass
+        line = st.timeline()
+        assert line["frozen"] is True and line["dropped"] == 0
+        paths = [e["path"] for e in line["spans"]]
+        assert set(paths[:2]) == {"startup.imports", "startup.imports/interpreter"}
+        assert "before.begin" not in paths and "after.first.token" not in paths
+        assert [e["at_s"] for e in line["spans"]] == sorted(e["at_s"] for e in line["spans"])
+        assert paths.index("serve.load") < paths.index("serve.load/shards")  # by start, not close
+        by = {e["path"]: e for e in line["spans"]}
+        assert by["dl.fetch"]["thread"] == "fetcher" and by["dl.fetch"]["attrs"] == {"bytes": 7}
+        assert by["serve.load"]["attrs"] == {"model": "m"}
+        assert by["serve.load/shards"]["attrs"] == {"files": 2, "where": "('a', 1)"}
+        assert set(by["dl.fetch"]) == {"path", "at_s", "duration_s", "thread", "attrs"}
+        assert {"startup.load", "startup.first_wait", "startup.first_request"} <= set(by)
+        assert by["startup.first_request"]["at_s"] + by["startup.first_request"][
+            "duration_s"] == pytest.approx(st.first_token_s, abs=1e-3)
+        import json
+
+        json.dumps(line)  # what /v1/trace?startup=1 answers with
+
+    def test_bounded_with_its_drops_counted_and_short_reads_merged(self, st, monkeypatch):
+        monkeypatch.setattr(Startup, "MAX_TIMELINE", 8)
+        st.begin("load")  # imports, imports/interpreter, imports/modules: 3 entries
+        for i in range(5):
+            trace.record("dl.fetch", time.monotonic(), 0.0001, bytes=10)  # one merged entry
+        trace.record("dl.fetch", time.monotonic(), 0.5, bytes=99)  # a long read: its own
+        for i in range(6):
+            trace.record("x.filler", time.monotonic(), 0.002)
+        line = st.timeline()
+        assert len(line["spans"]) == 8 and line["dropped"] == 3 and line["frozen"] is False
+        merged = [e for e in line["spans"] if e["attrs"].get("merged")]
+        assert len(merged) == 1 and merged[0]["attrs"] == {"bytes": 50, "merged": 5}
+        assert merged[0]["duration_s"] == pytest.approx(0.0005, abs=1e-6)
+        # a short read still merges into its entry when the list is full
+        trace.record("dl.fetch", time.monotonic(), 0.0001, bytes=10)
+        assert st.timeline()["dropped"] == 3
 
 
 class TestNoJax:
@@ -236,6 +385,10 @@ class TestNoJax:
             "        pass\n"
             "ph = trace.Phases('s', ('x',)); ph.begin(0); ph.end()\n"
             "assert set(trace.tracer().summary()) == {'a', 'a/b', 's', 's/x'}\n"
+            "trace.startup.begin('load'); trace.startup.sub('shards')\n"
+            "trace.startup.ready(); trace.startup.first_token(0.0)\n"
+            "assert trace.startup.snapshot()['first_token_s'] > 0\n"
+            "assert trace.startup.timeline()['frozen']\n"
             "assert 'jax' not in sys.modules, 'jax was imported'\n"
         )
         subprocess.run([sys.executable, "-c", code], check=True, timeout=60)

@@ -153,6 +153,74 @@ class TestProfilerBridge:
         assert ("capture_dir" if path.startswith("/admin") else "trace_dir") in r.json()
 
 
+class TestProfileDuringALoad:
+    def test_a_capture_begun_before_the_load_holds_reads_puts_and_program_loads(
+            self, model_dir, tmp_path, monkeypatch):
+        """The listener is up before the load and /v1/profile is routed
+        before any ready check: a capture begun when the port answers holds
+        the loader's reads and puts and the stored chunk program's load."""
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from modelx_tpu.dl import aot_cache
+        from modelx_tpu.dl import serve as serve_mod
+
+        # a node's compile cache directory with jax's own cache off: on the
+        # CPU the store takes no executable that cache served
+        monkeypatch.setattr(serve_mod, "_compile_cache_dir", str(tmp_path / "node"))
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        engine = dict(continuous_batch=True, max_slots=2, stream_chunk_size=4)
+        try:
+            first = ServerSet({"m": ModelServer(model_dir, mesh_spec="dp=1", dtype="float32",
+                                                max_seq_len=96)}, **engine)
+            first.load_all()  # builds the chunk program beside the load, and stores it
+            for cb in list(first.cbatchers.values()):
+                cb.generate(np.array([[5, 9, 2]], np.int32), max_new_tokens=2)  # waits for it
+                cb.close()
+            assert os.listdir(tmp_path / "node" / "programs")
+            sset = ServerSet({"m": ModelServer(model_dir, mesh_spec="dp=1", dtype="float32",
+                                               max_seq_len=96)}, **engine,
+                             trace_dir=str(tmp_path / "traces"))
+            port = free_port()
+            httpd = serve(sset, listen=f"127.0.0.1:{port}")
+            base = f"http://127.0.0.1:{port}"
+            try:
+                assert requests.get(base + "/healthz").status_code == 503  # loading
+                answer = {}
+                t = threading.Thread(target=lambda: answer.update(r=requests.post(
+                    base + "/v1/profile", json={"seconds": 3.0}, timeout=120)), daemon=True)
+                t.start()
+                for _ in range(500):
+                    if trace._annotate is not None:
+                        break
+                    threading.Event().wait(0.01)
+                assert trace._annotate is not None, "the capture never began"
+                hits = aot_cache.store_stats()["store_hits"]
+                sset.load_all()
+                for _ in range(1000):  # the side thread may outlast the load
+                    if aot_cache.store_stats()["store_hits"] > hits:
+                        break
+                    threading.Event().wait(0.01)
+                assert aot_cache.store_stats()["store_hits"] == hits + 1
+                t.join(120)
+                assert answer["r"].status_code == 200, answer["r"].text
+            finally:
+                for cb in list(sset.cbatchers.values()):
+                    cb.close()
+                httpd.shutdown()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
+        found = glob.glob(os.path.join(sset.trace_dir, "**", "*.xplane.pb"), recursive=True)
+        assert found, "the capture wrote no .xplane.pb"
+        data = jax.profiler.ProfileData.from_file(max(found, key=os.path.getmtime))
+        names = {e.name for plane in data.planes for line in plane.lines for e in line.events}
+        assert {"dl.fetch", "serve.load", "serve.load/headers", "serve.load/shards"} <= names
+        assert any(n.endswith("dl.put") for n in names)
+        for child in ("read", "unpickle", "deserialize"):
+            assert any(n.endswith(f"programs.load/{child}") for n in names), child
+
+
 class TestEnginePhases:
     def test_phases_tile_the_loop(self, server):
         cb = ContinuousBatcher(server, max_slots=2, chunk_size=4)
@@ -211,7 +279,8 @@ class TestMetricsBlocks:
                        'continuous_phase_s_fanout{model="m"}',
                        'continuous_phase_n_chunk_dispatch{model="m"}',
                        'continuous_loop_cpu_s{model="m"}',
-                       'load_fetch_seconds{model="m"}',
+                       'load_idle_seconds{model="m"}',
+                       'load_shards_seconds{model="m"}',
                        'load_device_put_seconds{model="m"}'):
             assert any(needle in line for line in text.splitlines()
                        if not line.startswith("#")), needle
@@ -222,8 +291,63 @@ class TestMetricsBlocks:
         from modelx_tpu.dl import serve as serve_mod
 
         text = promexp.render({"compile_cache": serve_mod.compile_cache_stats()})
-        for key in ("trace_s", "lower_s", "backend_compile_s", "retrieval_s", "programs"):
+        for key in ("trace_s", "lower_s", "backend_compile_s", "retrieval_s", "programs",
+                    "store_load_s", "store_read_s", "store_deserialize_s", "store_bytes_read"):
             assert f"compile_cache_{key} " in text
+
+    def test_the_loaders_tiling_is_on_metrics_and_thread_seconds_are_gone(self, front):
+        _, base = front
+        model = requests.get(base + "/metrics").json()["m"]
+        for key in ("idle", "backpressure", "assemble", "drain", "shards", "fetch_busy",
+                    "device_put", "overlap"):
+            assert model[f"load_{key}_seconds"] >= 0, key
+        assert model["load_shard_files"] == 2
+        assert "load_fetch_seconds" not in model
+        busy = (model["load_fetch_busy_seconds"] + model["load_device_put_seconds"]
+                - model["load_overlap_seconds"] + model["load_assemble_seconds"])
+        # a few milliseconds of load, each term rounded to one
+        assert busy + model["load_idle_seconds"] == pytest.approx(
+            model["load_shards_seconds"], abs=5e-3)
+
+    def test_the_first_token_closes_the_startup_clock_and_freezes_its_timeline(
+            self, front, monkeypatch):
+        _, base = front
+        fresh = trace.Startup()
+        monkeypatch.setattr(trace, "startup", fresh)
+        assert requests.get(base + "/v1/trace?startup=1").json() == {
+            "spans": [], "dropped": 0, "frozen": False}
+        fresh.begin("backend_init")
+        fresh.sub("distributed")
+        fresh.sub("devices")
+        fresh.stage("load")
+        with trace.span("serve.load", model="m"):
+            pass
+        fresh.ready()
+        assert "first_token_s" not in requests.get(base + "/metrics").json()["startup"]
+        assert requests.get(base + "/v1/trace?startup=1").json()["frozen"] is False
+        generate(base, "rid-first")
+        started = requests.get(base + "/metrics").json()["startup"]
+        assert started["ready_s"] + started["first_wait_s"] + started[
+            "first_request_s"] == pytest.approx(started["first_token_s"], abs=1e-3)
+        assert started["backend_init_distributed_s"] + started[
+            "backend_init_devices_s"] == pytest.approx(started["backend_init_s"], abs=1e-3)
+        line = requests.get(base + "/v1/trace?startup=1").json()
+        assert line["frozen"] is True and line["dropped"] == 0
+        paths = [e["path"] for e in line["spans"]]
+        assert {"startup.imports/interpreter", "startup.backend_init/devices", "serve.load",
+                "startup.load", "startup.first_wait", "startup.first_request"} <= set(paths)
+        assert [e["at_s"] for e in line["spans"]] == sorted(e["at_s"] for e in line["spans"])
+        assert all(set(e) == {"path", "at_s", "duration_s", "thread", "attrs"}
+                   for e in line["spans"])
+        # the first request's own spans, up to its first token, are on the line
+        assert any(e["attrs"].get("request_id") == "rid-first" for e in line["spans"])
+        generate(base, "rid-second")  # a second request moves nothing
+        assert requests.get(base + "/metrics").json()["startup"] == started
+        assert requests.get(base + "/v1/trace?startup=1").json() == line
+        # the aggregate and a request's slice answer as before
+        assert "serve.load" in requests.get(base + "/v1/trace").json()
+        assert requests.get(base + "/v1/trace?startup=0&request_id=rid-second").json()[
+            "serve.request"]["count"] == 1
 
 
 class TestCompileCacheDurations:
@@ -276,7 +400,7 @@ class TestLoaderSplit:
         srv = ModelServer(model_dir, mesh_spec="dp=1", dtype="float32", max_seq_len=96)
         stats = srv.load()
         assert len(seen) == 2
-        for key, field in (("load_fetch_seconds", "fetch_seconds"),
+        for key, field in (("load_drain_seconds", "drain_seconds"),
                            ("load_fetch_busy_seconds", "fetch_busy_seconds"),
                            ("load_device_put_seconds", "device_put_seconds"),
                            ("load_overlap_seconds", "overlap_seconds")):
@@ -316,3 +440,100 @@ class TestDevicePeak:
 
     def test_the_cpu_backend_reports_no_peak(self):
         assert "hbm_peak_bytes" not in devmem.raw_sample()
+
+
+class TestLayerMetricFiles:
+    """The sixteen per-layer metrics ISSUE 40 adds are data for readers the
+    benchmark already had: on a pod's dumps they read the new keys, and on a
+    parent's, which has none of them, they say nothing."""
+
+    ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    DEPLOY, DECODE = "phi3-mini-4k.deploy", "mixtral-8x7b-d4.decode"
+    # name -> (what the hand-made dumps below give, cell, layer, moves, better)
+    FRONT, LOADER, CACHE = "CLI + Serving front", "Loader", "Compile caches"
+    WANT = {
+        "front.interpreter_s": (0.4, DEPLOY, FRONT, "pod_ttft_s", "lower"),
+        "front.backend_devices_s": (8.5, DEPLOY, FRONT, "pod_ttft_s", "lower"),
+        "front.first_token_s": (26.5, DEPLOY, FRONT, "pod_ttft_s", "lower"),
+        "loader.shards_share": (0.9, DEPLOY, LOADER, "pod_ttft_s", "higher"),
+        "loader.idle_share": (0.2, DEPLOY, LOADER, "pod_ttft_s", "lower"),
+        "loader.drain_share": (0.1, DEPLOY, LOADER, "pod_ttft_s", "lower"),
+        "loader.backpressure_share": (0.05, DEPLOY, LOADER, "pod_ttft_s", "lower"),
+        "loader.assemble_share": (0.01, DEPLOY, LOADER, "pod_ttft_s", "lower"),
+        "cache.store_deserialize_s_per_program": (3.0, DEPLOY, CACHE, "pod_ttft_s", "lower"),
+        "cache.store_mb_per_program": (20.0, DEPLOY, CACHE, "pod_ttft_s", "lower"),
+        "device.idle_named_share.deploy": (0.75, DEPLOY, "Device", "pod_ttft_s", "higher"),
+        "loader.load_gbps.decode": (0.45, DECODE, LOADER, "setup_s", "higher"),
+        "loader.idle_share.decode": (0.25, DECODE, LOADER, "setup_s", "lower"),
+        "loader.assemble_share.decode": (0.5, DECODE, LOADER, "setup_s", "lower"),
+        "cache.store_deserialize_s_per_program.decode": (0.5, DECODE, CACHE, "setup_s", "lower"),
+        "cache.store_mb_per_program.decode": (13.0, DECODE, CACHE, "setup_s", "lower"),
+    }
+
+    @classmethod
+    def read(cls, name, sources):
+        import importlib
+        import json
+
+        with open(os.path.join(cls.ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            spec = json.load(f)
+        reader = importlib.import_module(f"benchmark.layer_metrics.readers.{spec['reader']}")
+        return reader.read(sources, spec)
+
+    @staticmethod
+    def dumps() -> dict:
+        load = {"load_seconds": 10.0, "load_shards_seconds": 9.0, "load_idle_seconds": 1.8,
+                "load_drain_seconds": 0.9, "load_backpressure_seconds": 0.45,
+                "load_assemble_seconds": 0.09, "load_gbps": 0.76}
+        before = {"default": load, "compile_cache": {
+            "store_hits": 1, "store_deserialize_s": 7.0, "store_bytes_read": 30_000_000}}
+        after = {"default": load, "startup": {
+            "imports_interpreter_s": 0.4, "backend_init_devices_s": 8.5, "first_token_s": 26.5},
+            "compile_cache": {"store_hits": 3, "store_deserialize_s": 13.0,
+                              "store_bytes_read": 70_000_000}}
+        warm = {"default": {"load_gbps": 0.45, "load_shards_seconds": 20.0,
+                            "load_idle_seconds": 5.0, "load_assemble_seconds": 10.0},
+                "compile_cache": {"store_hits": 48, "store_deserialize_s": 24.0,
+                                  "store_bytes_read": 624_000_000}}
+        return {"model": "default", "metrics_before": warm,
+                "trace_span": {"metrics_before": before, "metrics_after": after},
+                "trace": {"idle_gaps": [["continuous.admit/programs.load/deserialize", 3.0],
+                                        ["$pjit.py:123 cache_miss", 1.0]]}}
+
+    @pytest.mark.parametrize("name", list(WANT))
+    def test_reads_the_pods_dumps(self, name):
+        assert self.read(name, self.dumps()) == pytest.approx(self.WANT[name][0])
+
+    @pytest.mark.parametrize("name", list(WANT))
+    def test_a_parent_without_the_keys_reads_nothing(self, name):
+        """The parent's dumps: the keys it has (``load_seconds``, ``load_gbps``,
+        ``store_hits``, the five stages) and none of this PR's."""
+        parent = {"default": {"load_seconds": 10.0, "load_gbps": 0.45,
+                              "load_fetch_busy_seconds": 7.0},
+                  "startup": {"imports_s": 3.8, "backend_init_s": 9.0, "ready_s": 22.0},
+                  "compile_cache": {"store_hits": 3, "store_load_s": 9.0, "store_bytes": 7}}
+        sources = {"model": "default", "metrics_before": parent, "metrics_after": parent,
+                   "trace_span": {"metrics_before": parent, "metrics_after": parent},
+                   "trace": {"idle_gaps": [["continuous.admit/programs.load", 3.0]]}}
+        got = self.read(name, sources)
+        if name in ("device.idle_named_share.deploy", "loader.load_gbps.decode"):
+            # read from what the parent already reports
+            assert got == {"device.idle_named_share.deploy": 1.0,
+                           "loader.load_gbps.decode": 0.45}[name]
+        else:
+            assert got is None
+        assert self.read(name, {}) is None
+
+    def test_benchmark_json_lists_them_at_the_end_for_their_cells(self):
+        import json
+
+        with open(os.path.join(self.ROOT, "BENCHMARK.json")) as f:
+            per_layer = json.load(f)["per_layer"]
+        mine = per_layer[57:73]  # after the 57 that were there, in ISSUE 40's order
+        assert [m["name"] for m in mine] == list(self.WANT)
+        for m in mine:
+            _, cell, layer, moves, better = self.WANT[m["name"]]
+            assert (m["workloads"], m["layer"], m["moves"], m["better"]) == (
+                [cell], layer, moves, better), m["name"]
+            assert m["source"] == ("device_trace" if m["name"].startswith("device.")
+                                   else "program_counter")
