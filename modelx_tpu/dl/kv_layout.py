@@ -41,6 +41,7 @@ admission prefills into, what the prefix cache stores — is a dense
 
 from __future__ import annotations
 
+import functools
 import logging
 
 import jax
@@ -171,16 +172,42 @@ class DenseKV:
         idle slot's writes are harmless (``_chunk_impl``)."""
         return {}
 
+    def _row_written(self) -> list:
+        """The leaves (as shapes) a decode step writes one new position a row
+        into, through ``ops.kv_write.write_rows``: here every one."""
+        return jax.tree_util.tree_leaves(jax.eval_shape(self._zeros))
+
+    @functools.cached_property
+    def row_writes(self) -> tuple[int, int]:
+        """(leaves whose per-row write is the kernel's, leaves written a row
+        at a time) of ONE decode step over all slots. ``write_rows``' rule is
+        static — shapes, the backend, the mesh — so the layout asks it of its
+        own leaves: a chunk program loaded from the executable store is never
+        traced, and a record made while tracing would miss it."""
+        from modelx_tpu.ops import kv_write
+
+        hows = [kv_write.lowering(leaf.shape, (leaf.shape[0], 1, *leaf.shape[2:]), 1, self.mesh)
+                for leaf in self._row_written()]
+        return hows.count("kernel"), len(hows)
+
     def landed(self, toks: np.ndarray) -> None:
-        """A chunk's token block has reached the host: what ``ride`` added
-        to it is read here. Two rows below everything else are the steps'
-        KV positions read and cached by the layers that took the ragged
-        decode kernel (``attn_kv_positions_read`` / ``_cached``); a program
-        none of whose layers did sends none, and the counters do not exist."""
+        """A chunk's token block ``[.., steps + 1]`` has reached the host:
+        what ``ride`` added to it is read here. Two rows below everything else
+        are the steps' KV positions read and cached by the layers that took
+        the ragged decode kernel (``attn_kv_positions_read`` / ``_cached``); a
+        program none of whose layers did sends none, and the counters do not
+        exist. Nothing rides for the cache writes: ``kv_write_rows_kernel`` /
+        ``kv_write_rows`` grow by steps x slots x :attr:`row_writes`, and do
+        not exist where no leaf's write takes the kernel."""
+        stats = self.stats
         if toks.shape[0] > self.max_slots + self.counter_rows:
-            stats, grown = self.stats, toks[-2:, :-1].astype(np.int64).sum(axis=1)
+            grown = toks[-2:, :-1].astype(np.int64).sum(axis=1)
             for key, n in zip(("attn_kv_positions_read", "attn_kv_positions_cached"), grown):
                 stats[key] = stats.get(key, 0) + int(n)
+        if self.row_writes[0]:
+            rows = (toks.shape[1] - 1) * self.max_slots
+            for key, leaves in zip(("kv_write_rows_kernel", "kv_write_rows"), self.row_writes):
+                stats[key] = stats.get(key, 0) + rows * leaves
 
     def all_slots(self) -> tuple:
         return ()
@@ -295,6 +322,9 @@ class PagedKV(DenseKV):
             lambda leaf: jnp.zeros(
                 (self.num_pages, self.page_size) + leaf.shape[2:], leaf.dtype),
             self.init_cache(1, self.page_size))
+
+    def _row_written(self) -> list:
+        return []  # a pool is written page by page (``write_token_kv``)
 
     # -- host bookkeeping -----------------------------------------------------
 
@@ -560,6 +590,10 @@ class LayerKindKV(DenseKV):
 
     def _zeros(self):
         return self.init_state(self.max_slots, self.max_len)
+
+    def _row_written(self) -> list:
+        shapes = jax.eval_shape(self._zeros)
+        return [shapes[name] for name, kind in self.kinds.items() if kind in ("full", "window")]
 
     def sharding(self, shape):
         if len(shape) < 3:  # a counter leaf: every device holds it whole

@@ -42,6 +42,7 @@ from jax.sharding import Mesh
 
 from modelx_tpu.models.llama import ShardingCtx, _rope
 from modelx_tpu.ops import attention as attn_ops
+from modelx_tpu.ops.kv_write import write_rows
 from modelx_tpu.ops.nn import linear as _linear
 
 
@@ -232,15 +233,8 @@ def decoder_layer(
         )[:, None]  # [B, 1, Hq, D]
     elif cache is not None:
         ck, cv = cache
-        if jnp.ndim(cache_offset) == 0:
-            ck = jax.lax.dynamic_update_slice(ck, k, (0, cache_offset, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cv, v, (0, cache_offset, 0, 0))
-        else:
-            row_dus = jax.vmap(
-                lambda c, u, o: jax.lax.dynamic_update_slice(c, u, (o, 0, 0))
-            )
-            ck = row_dus(ck, k, cache_offset)
-            cv = row_dus(cv, v, cache_offset)
+        ck = write_rows(ck, k, cache_offset, ctx.mesh)
+        cv = write_rows(cv, v, cache_offset, ctx.mesh)
         new_cache = (ck, cv)
         attn_out = _attend(q, ck, cv, cfg, q_offset=cache_offset, window=window)
     else:

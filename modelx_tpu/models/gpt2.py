@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from modelx_tpu.ops import attention as attn_ops
+from modelx_tpu.ops.kv_write import write_rows
 from modelx_tpu.ops.nn import conv1d as _conv1d
 from modelx_tpu.ops.nn import layer_norm as _layer_norm
 
@@ -132,17 +133,9 @@ def forward(
             )[:, None]
         else:
             if kv_cache is not None:
-                ck, cv = kv_cache[f"k{i}"], kv_cache[f"v{i}"]
-                if jnp.ndim(cache_offset) == 0:
-                    ck = jax.lax.dynamic_update_slice(ck, k, (0, cache_offset, 0, 0))
-                    cv = jax.lax.dynamic_update_slice(cv, v, (0, cache_offset, 0, 0))
-                else:
-                    # ragged batch: each row appends at its own position
-                    row_dus = jax.vmap(
-                        lambda c, u, o: jax.lax.dynamic_update_slice(c, u, (o, 0, 0))
-                    )
-                    ck = row_dus(ck, k, cache_offset)
-                    cv = row_dus(cv, v, cache_offset)
+                # a ragged batch appends each row at its own position
+                ck = write_rows(kv_cache[f"k{i}"], k, cache_offset, mesh)
+                cv = write_rows(kv_cache[f"v{i}"], v, cache_offset, mesh)
                 new_cache[f"k{i}"], new_cache[f"v{i}"] = ck, cv
                 k_att, v_att = ck, cv
                 q_offset = cache_offset

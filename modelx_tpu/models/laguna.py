@@ -65,6 +65,7 @@ from modelx_tpu.models.decode import SEQ_BUCKET
 from modelx_tpu.models.llama import ShardingCtx, _rms_norm
 from modelx_tpu.ops import attention as attn_ops
 from modelx_tpu.ops import moe as moe_ops
+from modelx_tpu.ops.kv_write import write_rows
 from modelx_tpu.ops.nn import linear as _linear
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -397,21 +398,13 @@ def init_layer_state(cfg: LagunaConfig, slots: int, max_len: int, dtype=None) ->
 # -- forward ------------------------------------------------------------------
 
 
-def _write_rows(cache, new, index):
-    """Write ``new`` [B, S, ...] into ``cache`` [B, L, ...] at ``index`` (a
-    scalar, or one start per row)."""
-    if jnp.ndim(index) == 0:
-        return jax.lax.dynamic_update_slice(cache, new, (0, index, 0, 0))
-    return jax.vmap(lambda c, u, o: jax.lax.dynamic_update_slice(c, u, (o, 0, 0)))(
-        cache, new, index)
-
-
 def _attention(q, k, v, cfg: LagunaConfig, layer: int, ctx: ShardingCtx, cache,
                cache_offset, ring: bool, attention_impl: str):
     """q [B,S,H,D], k/v [B,S,Hkv,D] after rope -> ([B,S,H,D], new cache).
 
     No cache: flash on a TPU, else the reference. A cache: the new keys and
-    values are written, then ``ops.attention.cached_attention`` picks by what
+    values are written (``ops.kv_write.write_rows``, full layers and rings
+    alike), then ``ops.attention.cached_attention`` picks by what
     it observes — a full layer's decode step (one token a row at per-row
     offsets over ``[slots, max_len]``, 128-wide heads, on one TPU device) takes
     the ragged kernel and reads each row's KV blocks up to its own context; a
@@ -442,10 +435,12 @@ def _attention(q, k, v, cfg: LagunaConfig, layer: int, ctx: ShardingCtx, cache,
         # congruent to r; one that would be negative holds nothing yet
         length = ck.shape[1]
         offset = jnp.broadcast_to(jnp.asarray(cache_offset, jnp.int32), (q.shape[0],))
-        ck, cv = _write_rows(ck, k, offset % length), _write_rows(cv, v, offset % length)
+        ck = write_rows(ck, k, offset % length, ctx.mesh)
+        cv = write_rows(cv, v, offset % length, ctx.mesh)
         key_positions = offset[:, None] - (offset[:, None] - jnp.arange(length)[None, :]) % length
     else:
-        ck, cv = _write_rows(ck, k, cache_offset), _write_rows(cv, v, cache_offset)
+        ck = write_rows(ck, k, cache_offset, ctx.mesh)
+        cv = write_rows(cv, v, cache_offset, ctx.mesh)
     out = attn_ops.cached_attention(q, ck, cv, cache_offset, impl=attention_impl,
                                     mesh=ctx.mesh, window=window, key_positions=key_positions)
     return out, (ck, cv)

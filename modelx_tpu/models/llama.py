@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from modelx_tpu.ops import attention as attn_ops
+from modelx_tpu.ops.kv_write import write_rows
 from modelx_tpu.ops.nn import linear as _linear
 
 
@@ -223,7 +224,10 @@ def decoder_layer(
 
     Which attention runs, by what the call observes. No cache: ``_attend``
     (flash on a TPU, ring under an sp axis, else the reference). A dense
-    cache ``[B, L, Hkv, D]``: ``ops.attention.cached_attention`` — one token a
+    cache ``[B, L, Hkv, D]``: the new keys and values are written
+    (``ops.kv_write.write_rows``: a per-row start is a scatter, or on one TPU
+    device where a position's line is whole tiles a kernel that copies each
+    row's line to its place), then ``ops.attention.cached_attention`` — one token a
     row at per-row offsets, head_dim a multiple of 128, KV heads a multiple
     of 8, ``L`` two blocks or more, on one TPU device takes the ragged kernel
     that reads each row's KV blocks up to its own context (the engine's decode
@@ -261,17 +265,9 @@ def decoder_layer(
         )[:, None]  # [B, 1, Hq, D]
     elif cache is not None:
         ck, cv = cache
-        if jnp.ndim(cache_offset) == 0:
-            ck = jax.lax.dynamic_update_slice(ck, k, (0, cache_offset, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cv, v, (0, cache_offset, 0, 0))
-        else:
-            # ragged batch: each row appends at its own position (per-row
-            # dynamic_update_slice via vmap lowers to a scatter)
-            row_dus = jax.vmap(
-                lambda c, u, o: jax.lax.dynamic_update_slice(c, u, (o, 0, 0))
-            )
-            ck = row_dus(ck, k, cache_offset)
-            cv = row_dus(cv, v, cache_offset)
+        # a ragged batch appends each row at its own position
+        ck = write_rows(ck, k, cache_offset, mesh)
+        cv = write_rows(cv, v, cache_offset, mesh)
         new_cache = (ck, cv)
         attn_out = attn_ops.cached_attention(q, ck, cv, cache_offset, impl=attention_impl,
                                              mesh=mesh)

@@ -354,3 +354,107 @@ def test_lagunas_decode_step_takes_the_ragged_kernel_on_its_full_layers_only(top
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "f32[64,8,6,4096]" not in text and "f32[64,8,9,528]" in text
+
+
+# -- the decode step's per-row cache write (ops.kv_write.write_rows_kernel) ------
+
+# (slots, positions) of a written leaf: Laguna's full layers, its rings, Mixtral's layers
+WRITE_SHAPES = {"laguna_full_layer": (64, 4096), "laguna_ring": (64, 528), "mixtral": (32, 2048)}
+
+
+@pytest.mark.parametrize("shape", WRITE_SHAPES)
+def test_kv_write_kernel_at_the_cells_widths(topo, shape):
+    """Mosaic takes the kernel at the cells' leaves (8 KV heads of 128: one
+    position is one 2 KB tile row of ``[B, L, 8, 128]``), the leaf stays
+    where it lies — aliased to the output, no temporary at all."""
+    from modelx_tpu.ops import kv_write
+
+    rows, length = WRITE_SHAPES[shape]
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    compiled = jax.jit(kv_write.write_rows_kernel, donate_argnums=(0,)).lower(
+        sds((rows, length, 8, 128), jnp.bfloat16), sds((rows, 1, 8, 128), jnp.bfloat16),
+        sds((rows,), jnp.int32)).compile()
+    text, m = compiled.as_text(), compiled.memory_analysis()
+    assert 'custom_call_target="tpu_custom_call"' in text and "kv_write_rows" in text
+    assert m.temp_size_in_bytes == 0 and m.alias_size_in_bytes == rows * length * 8 * 128 * 2
+
+
+def _cell_engine(topo, family: str):
+    """The ``.decode`` / ``.reason`` cell's engine over shapes on one described
+    device -> (engine, params, slots, cache positions, written leaves a step)."""
+    import json
+    import types
+
+    from modelx_tpu.dl.continuous import ContinuousBatcher
+    from modelx_tpu.dl.families import FAMILIES
+    from modelx_tpu.models import laguna, mixtral
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    name = {"mixtral": "mixtral-8x7b-d4", "laguna": "laguna-s-2.1-ep2-d5"}[family]
+    with open(os.path.join(root, "benchmark", "configs", name + ".json")) as f:
+        raw = json.load(f)
+    if family == "laguna":
+        cfg, slots, max_len = laguna.config_from_hf(raw), 64, 4096
+        shapes, leaves = laguna.param_shapes(cfg), 2 * cfg.num_layers
+    else:
+        cfg = mixtral.MixtralConfig(
+            vocab_size=raw["vocab_size"], hidden_size=raw["hidden_size"],
+            intermediate_size=raw["intermediate_size"], num_layers=raw["num_hidden_layers"],
+            num_heads=raw["num_attention_heads"], num_kv_heads=raw["num_key_value_heads"],
+            head_dim=raw["head_dim"], num_experts=raw["num_local_experts"],
+            top_k=raw["num_experts_per_tok"], rope_theta=raw["rope_theta"])
+        slots, max_len = 32, 2048
+        shapes, leaves = mixtral.param_shapes(cfg), 2 * cfg.num_layers
+    one = SingleDeviceSharding(topo.devices[0])
+    params = {k: jax.ShapeDtypeStruct(v, jnp.bfloat16, sharding=one) for k, v in shapes.items()}
+    server = types.SimpleNamespace(
+        family=FAMILIES[family], cfg=cfg, mesh=make_mesh("dp=1", [topo.devices[0]]),
+        params=params, max_seq_len=max_len, stats={})
+    engine = ContinuousBatcher(server, max_slots=slots, chunk_size=8, max_len=max_len,
+                               allocate=False, supervise=False)
+    return engine, params, slots, max_len, leaves
+
+
+# temporaries of the parent commit's depth-1 chunk program, compiled the same
+# way (PR 41, no chip): what the change's must not exceed
+PARENT_CHUNK_TEMP = {"mixtral": 4_906_496, "laguna": 11_425_792}
+
+
+@pytest.mark.parametrize("family", ["mixtral", "laguna"])
+def test_the_cells_chunk_program_writes_every_leaf_in_the_kernel_in_place(topo, monkeypatch, family):
+    """The engine's OWN chunk program of the ``.decode`` and ``.reason`` cells,
+    the rule steered to a TPU (the compile runs where ``default_backend`` says
+    cpu): one ``kv_write_rows`` call a written leaf — eight, and ten with
+    Laguna's rings — beside the ragged attention's; the one ``while`` left is
+    the scan (the parent's had one of ``slots`` trips a leaf around a 2 KB
+    update: the scatter); no cache leaf is copied; the state leaves the
+    program aliased to its input; temporaries not above the parent's."""
+    import math
+    import re
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    engine, params, slots, max_len, leaves = _cell_engine(topo, family)
+    try:
+        assert engine.kv.row_writes == (leaves, leaves)
+        state = engine.kv.abstract_state()
+        tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=SingleDeviceSharding(topo.devices[0]))
+        compiled = engine._chunk_prog.jit.lower(
+            params, state, tok, *engine._chunk_args(False), n_steps=8).compile()
+    finally:
+        engine.close()
+    text, m = compiled.as_text(), compiled.memory_analysis()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len([c for c in calls if "kv_write_rows" in c]) == leaves
+    assert len([c for c in calls if "ragged_decode_attention" in c]) == len(calls) - leaves > 0
+    assert len([line for line in text.splitlines() if " while(" in line]) == 1
+    scattered = [line.strip()[:120] for line in text.splitlines()
+                 if "scatter" in line and re.search(rf"bf16\[{slots},(\d\d\d+),8,128\]", line)]
+    assert not scattered, scattered
+    copied = [line.strip()[:120] for line in text.splitlines()
+              if re.search(rf"= bf16\[{slots},(\d\d\d+),8,128\]\S* (copy|transpose)\(", line)]
+    assert not copied, copied
+    kv_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                   for x in jax.tree_util.tree_leaves(state) if len(x.shape) == 4)
+    assert kv_bytes <= m.alias_size_in_bytes < kv_bytes + 4096  # + tok, counters
+    assert m.temp_size_in_bytes <= PARENT_CHUNK_TEMP[family]
