@@ -27,6 +27,7 @@ import numpy as np
 
 from modelx_tpu.dl.sharding import (
     BERT_RULES,
+    DEEPSEEK_V2_RULES,
     GEMMA2_RULES,
     GPT2_RULES,
     LAGUNA_RULES,
@@ -68,8 +69,9 @@ class Family:
     # "init_state": (slots, max_len) -> that state, "kinds": leaf -> "full" /
     # "window" / "counter", "counters", "gauges"} — the continuous engine
     # then keeps full layers [slots, max_len], window layers as rings, a
-    # sparse layer's index of compressed keys ("index") and a linear-attention
-    # layer's state ("state", no position axis) (dl/kv_layout.LayerKindKV);
+    # sparse layer's index of compressed keys ("index"), a linear-attention
+    # layer's state ("state", no position axis) and a latent-attention layer's
+    # one compressed line a position ("latent") (dl/kv_layout.LayerKindKV);
     # None = every layer's cache is alike
     layer_kind_decode_fns: Callable[..., dict] | None = None
 
@@ -644,6 +646,80 @@ def _minicpm_sala_layer_kind_decode_fns(cfg, mesh=None):
     }
 
 
+# -- deepseek_v2 ----------------------------------------------------------------
+
+
+def infer_deepseek_v2_config(params: dict):
+    raise ValueError(
+        "a deepseek_v2 checkpoint's head sizes, routing groups, rope scaling and "
+        "expert share leave no trace in tensor shapes: its config.json must lie "
+        "beside the weights")
+
+
+def deepseek_v2_config_from_sidecar(sidecar: dict, params: dict):
+    from modelx_tpu.models import deepseek_v2
+
+    return deepseek_v2.config_from_hf(
+        sidecar, dtype=_act_dtype(params, "model.embed_tokens.weight"))
+
+
+def _deepseek_v2_forward(params, tokens, cfg, mesh=None):
+    from modelx_tpu.models import deepseek_v2
+
+    return deepseek_v2.forward(params, tokens, cfg, mesh=mesh)[0]
+
+
+def _deepseek_v2_generate(params, tokens, cfg, mesh=None, max_new_tokens=16):
+    from modelx_tpu.models import deepseek_v2
+
+    return deepseek_v2.greedy_generate(
+        params, tokens, cfg, max_new_tokens=max_new_tokens, mesh=mesh)
+
+
+def _deepseek_v2_generate_ragged(params, tokens, row_lens, cfg, mesh=None,
+                                 max_new_tokens=16, **sampling):
+    from modelx_tpu.models import deepseek_v2
+
+    return deepseek_v2.ragged_greedy_generate(
+        params, tokens, row_lens, cfg, max_new_tokens=max_new_tokens, mesh=mesh,
+        **sampling,
+    )
+
+
+def _deepseek_v2_decode_fns(cfg, mesh=None):
+    from modelx_tpu.models import deepseek_v2
+
+    def fwd(p, t, kv_cache, cache_offset, mesh=mesh):
+        return deepseek_v2.forward(
+            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh
+        )
+
+    return fwd, (lambda b, max_len: deepseek_v2.init_kv_cache(cfg, b, max_len))
+
+
+def _deepseek_v2_layer_kind_decode_fns(cfg, mesh=None):
+    from modelx_tpu.models import deepseek_v2
+
+    fwd, _ = _deepseek_v2_decode_fns(cfg, mesh)
+    return {
+        "fwd": fwd,
+        "init_state": lambda slots, max_len: deepseek_v2.init_layer_state(cfg, slots, max_len),
+        "kinds": deepseek_v2.cache_kinds(cfg),
+        # what the decode step counts: of its expert layers over ALL slots (idle
+        # ones route too), of its latent layers over the rows that hold a context
+        "counters": {"moe_counts": ("moe", deepseek_v2.MOE_COUNTERS),
+                     "mla_counts": ("mla", deepseek_v2.MLA_COUNTERS)},
+        "gauges": {"moe": {"held_experts": cfg.expert_count,
+                           "published_experts": cfg.num_experts,
+                           "sparse_layers": cfg.num_layers - cfg.first_k_dense_replace,
+                           "groups": cfg.n_group, "groups_kept": cfg.topk_group},
+                   "mla": {"layers": cfg.num_layers, "heads": cfg.num_heads,
+                           "kv_lora_rank": cfg.kv_lora_rank,
+                           "rope_dim": cfg.qk_rope_head_dim,
+                           "line_width": cfg.line_width}},
+    }
+
+
 # -- bert ---------------------------------------------------------------------
 
 
@@ -704,6 +780,11 @@ FAMILIES: dict[str, Family] = {
                            _minicpm_sala_generate_ragged, _minicpm_sala_decode_fns,
                            config_from_sidecar=minicpm_sala_config_from_sidecar,
                            layer_kind_decode_fns=_minicpm_sala_layer_kind_decode_fns),
+    "deepseek_v2": Family("deepseek_v2", DEEPSEEK_V2_RULES, infer_deepseek_v2_config,
+                          _deepseek_v2_forward, _deepseek_v2_generate,
+                          _deepseek_v2_generate_ragged, _deepseek_v2_decode_fns,
+                          config_from_sidecar=deepseek_v2_config_from_sidecar,
+                          layer_kind_decode_fns=_deepseek_v2_layer_kind_decode_fns),
     "gpt2": Family("gpt2", GPT2_RULES, infer_gpt2_config, _gpt2_forward,
                    _gpt2_generate, _gpt2_generate_ragged, _gpt2_decode_fns,
                    _gpt2_paged_decode_fns),

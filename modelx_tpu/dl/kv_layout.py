@@ -20,7 +20,8 @@ interface, and the engine never asks which it got:
   one 16-token bucket a slot, written at ``position mod ring`` and masked by
   absolute position, sparse-attention layers an INDEX of compressed keys (one
   row per ``max_len / n`` positions) beside their keys and values, and
-  linear-attention layers a STATE a slot with no position axis at all — so HBM
+  linear-attention layers a STATE a slot with no position axis at all, and
+  latent-attention layers one compressed LINE a position — so HBM
   holds what each kind can ever attend to, not ``max_len`` for all. What a
   leaf kind cannot give is refused when the engine is built, with a message
   that names the kind: a ring no view of an overwritten prefix (so no chunked
@@ -503,7 +504,12 @@ class LayerKindKV(DenseKV):
     n positions (a sparse layer's compressed keys); a ``"state"`` leaf
     ``[max_slots, ...]`` has no position axis (a linear-attention layer's
     running sum: ``put`` copies a whole state, ``view`` / ``put_piece`` hand
-    the slot's state to a prefill piece and take it back); a ``"counter"``
+    the slot's state to a prefill piece and take it back); a ``"latent"`` leaf
+    ``[max_slots, max_len, W]`` holds one compressed line a position in place
+    of per-head keys and values (latent attention: laid and addressed as a
+    full leaf — it carries ``--prefill-chunk`` — but written a row at a time
+    by the family itself, and a kind of its own so that what no test holds
+    over it yet is refused by name); a ``"counter"``
     leaf is a small vector the decode step adds to, which goes home with each
     chunk's tokens (``ride`` / ``landed``).
 
@@ -524,18 +530,24 @@ class LayerKindKV(DenseKV):
 
     # engine option -> (the leaf kinds that cannot carry it, what is refused, why)
     REFUSED = {
-        "page_size": (("window", "state"), "--kv-page-size",
-                      {"window": "a ring is not paged", "state": "a state has no pages"}),
-        "prefix_cache": (("window", "state"), "--prefix-cache, and with it the KV store's "
-                         "bundles and resume from stored KV",
+        "page_size": (("window", "state", "latent"), "--kv-page-size",
+                      {"window": "a ring is not paged", "state": "a state has no pages",
+                       "latent": "the page pool's in-place decode reads per-head keys and "
+                                 "values, and a latent line has neither"}),
+        "prefix_cache": (("window", "state", "latent"), "--prefix-cache, and with it the KV "
+                         "store's bundles and resume from stored KV",
                          {"window": "a ring cannot give back a prefix it has overwritten",
-                          "state": "a state cannot be cut at a token"}),
+                          "state": "a state cannot be cut at a token",
+                          "latent": "a suffix landing at an unbucketed offset over stored "
+                                    "latent lines is held by no test yet"}),
         "prefill_chunk": (("window",), "--prefill-chunk",
                           {"window": "a piece needs the slot's earlier rows as a dense view, "
                                      "and a ring has overwritten them"}),
-        "speculative_k": (("window", "state"), "--speculative-k",
+        "speculative_k": (("window", "state", "latent"), "--speculative-k",
                           {"window": "a verify block writes several ring positions a step",
-                           "state": "a state cannot drop the tokens a verify rejects"}),
+                           "state": "a state cannot drop the tokens a verify rejects",
+                           "latent": "a verify block is neither the one-token absorbed form "
+                                     "nor a prompt block at one offset, and no test holds it"}),
     }
 
     @classmethod
@@ -580,6 +592,8 @@ class LayerKindKV(DenseKV):
         if have & {"index", "state"}:
             stats["kv"].update(bytes_index=bytes_of("index"), bytes_state=bytes_of("state"),
                                states_live=0)
+        if "latent" in have:
+            stats["kv"]["bytes_latent"] = bytes_of("latent")
         for block, gauges in fns.get("gauges", {}).items():
             stats[block] = dict(gauges)
         self._last: dict[str, np.ndarray] = {}

@@ -224,6 +224,27 @@ MINICPM_SALA_RULES: Rules = [
     (r".*", []),
 ]
 
+# DeepSeek-V2 (models/deepseek_v2.py): the low-rank pairs' down-projections
+# (q_a, kv_a) and their norms replicated — a latent line has one "KV head" —
+# the up-projections and the output split by head, the router replicated at
+# its published width, stacked experts over ep with their features over tp;
+# the dense MLP and the shared experts (``mlp.shared_experts.*``, which the
+# stacked experts' patterns must not catch) fall to the llama projection rules.
+DEEPSEEK_V2_RULES: Rules = [
+    (r"embed_tokens\.weight$", ["tp", None]),
+    (r"lm_head\.weight$", ["tp", None]),
+    (r"(q_a_proj|kv_a_proj_with_mqa)\.weight$", [None, None]),
+    (r"(q_b|kv_b)_proj\.weight$", ["tp", None]),
+    (r"o_proj\.weight$", [None, "tp"]),
+    (r"mlp\.gate\.weight$", [None, None]),
+    (r"mlp\.experts\.(gate|up)_proj\.weight$", ["ep", "tp", None]),
+    (r"mlp\.experts\.down_proj\.weight$", ["ep", None, "tp"]),
+    (r"(gate|up)_proj\.weight$", ["tp", None]),
+    (r"down_proj\.weight$", [None, "tp"]),
+    (r"norm\.weight$", [None]),
+    (r".*", []),
+]
+
 DEFAULT_RULES: dict[str, Rules] = {
     "llama": LLAMA_RULES,
     "qwen2": QWEN2_RULES,
@@ -234,6 +255,7 @@ DEFAULT_RULES: dict[str, Rules] = {
     "mixtral": MIXTRAL_RULES,
     "laguna": LAGUNA_RULES,
     "minicpm_sala": MINICPM_SALA_RULES,
+    "deepseek_v2": DEEPSEEK_V2_RULES,
 }
 
 
@@ -249,6 +271,8 @@ def infer_family(tensor_names: Sequence[str]) -> str:
     joined = "\n".join(names)
     if "block_sparse_moe" in joined:
         return "mixtral"
+    if "self_attn.kv_a_proj_with_mqa" in joined:
+        return "deepseek_v2"  # one compressed key-value line a position (latent attention)
     if "self_attn.g_proj" in joined:
         return "laguna"  # per-head output gate beside q/k/v/o
     if "self_attn.o_gate" in joined:

@@ -67,6 +67,7 @@ from modelx_tpu.ops import attention as attn_ops
 from modelx_tpu.ops import moe as moe_ops
 from modelx_tpu.ops.kv_write import write_rows
 from modelx_tpu.ops.nn import linear as _linear
+from modelx_tpu.ops.rope import yarn_inv_freq, yarn_mscale
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 # the engine's counters of the expert layers, in the order the decode step
@@ -319,26 +320,18 @@ def rope_frequencies(spec: RopeSpec, head_dim: int) -> tuple[np.ndarray, float, 
     dims) of one layer kind. ``yarn`` follows HF ``_compute_yarn_parameters``:
     interpolated and extrapolated frequencies blended by a linear ramp
     between the dimensions that turn ``beta_fast`` and ``beta_slow`` times
-    over the original context."""
+    over the original context (``ops/rope.yarn_inv_freq``, which DeepSeek-V2's
+    family shares)."""
     dim = int(head_dim * spec.partial_rotary_factor)
-    pos_freqs = spec.theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
     if spec.rope_type == "default":
+        pos_freqs = spec.theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
         return (1.0 / pos_freqs).astype(np.float32), 1.0, dim
 
-    def correction_dim(rotations: float) -> float:
-        return (dim * math.log(spec.original_max_position_embeddings
-                               / (rotations * 2 * math.pi))) / (2 * math.log(spec.theta))
-
-    low = max(math.floor(correction_dim(spec.beta_fast)), 0)
-    high = min(math.ceil(correction_dim(spec.beta_slow)), dim - 1)
-    if low == high:
-        high += 0.001
-    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
-    extrapolation = 1.0 - ramp
-    inv_freq = (1.0 / (spec.factor * pos_freqs)) * ramp + (1.0 / pos_freqs) * extrapolation
+    inv_freq = yarn_inv_freq(spec.theta, dim, spec.factor, spec.original_max_position_embeddings,
+                             spec.beta_fast, spec.beta_slow)
     factor = spec.attention_factor
     if factor is None:
-        factor = 0.1 * math.log(spec.factor) + 1.0 if spec.factor > 1 else 1.0
+        factor = yarn_mscale(spec.factor)
     return inv_freq.astype(np.float32), float(factor), dim
 
 

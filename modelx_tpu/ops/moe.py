@@ -104,15 +104,37 @@ def moe_ffn(
 
 
 def route_topk(router_logits: jax.Array, k: int, *, renormalize: bool = True,
-               scale: float = 1.0) -> jax.Array:
+               scale: float = 1.0, groups: tuple[int, int] | None = None) -> jax.Array:
     """Exactly-k routing over the router's whole width: softmax over all E
     logits in float32, the k largest, their probabilities divided by their
     own sum (``renormalize``, HF ``norm_topk_prob``) and multiplied by
     ``scale`` (``moe_routed_scaling_factor``). router_logits: [T, E].
     Returns the combine weights [T, E], zero off the k chosen. Unlike
-    :func:`router_topk` a tie never admits a (k+1)-th expert."""
+    :func:`router_topk` a tie never admits a (k+1)-th expert.
+
+    ``groups`` ``(n_group, topk_group)`` is the group-limited routing of a
+    deployment that keeps each group on one device (HF ``topk_method``
+    ``group_limited_greedy``): the E experts are ``n_group`` runs of ``E /
+    n_group`` neighbours (expert e lies in group ``e // (E / n_group)``), a
+    group's score is the LARGEST probability among its experts, only the
+    ``topk_group`` best groups stay eligible (a tie between groups goes to the
+    lower index, as ``top_k``'s does), and the k experts are the best inside
+    them — a token whose best expert lies in a dropped group does without it.
+    The probabilities are those of the softmax over all E either way."""
     probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    vals, idx = jax.lax.top_k(probs, k)
+    eligible = probs
+    if groups is not None:
+        n_group, topk_group = groups
+        t, e = probs.shape
+        if e % n_group or not 0 < topk_group <= n_group:
+            raise ValueError(f"{e} experts do not fall into {n_group} groups of which "
+                             f"{topk_group} are kept")
+        best = jnp.max(probs.reshape(t, n_group, e // n_group), axis=-1)  # [T, n_group]
+        _, kept = jax.lax.top_k(best, topk_group)
+        keep = jnp.zeros((t, n_group), bool).at[jnp.arange(t)[:, None], kept].set(True)
+        # an ineligible expert sorts below every probability, zero included
+        eligible = jnp.where(jnp.repeat(keep, e // n_group, axis=1), probs, -1.0)
+    vals, idx = jax.lax.top_k(eligible, k)
     if renormalize:
         vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
     rows = jnp.arange(probs.shape[0])[:, None]
@@ -132,7 +154,8 @@ def moe_share_ffn(
     routed_scale: float = 1.0,
     shared: tuple[jax.Array, jax.Array, jax.Array] | None = None,
     constrain=None,
-    scopes: tuple[str, str] = ("moe.routed", "moe.shared"),
+    scopes: tuple[str, ...] = ("moe.routed", "moe.shared"),
+    groups: tuple[int, int] | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """An expert layer that is told which experts it holds.
 
@@ -141,7 +164,9 @@ def moe_share_ffn(
     SwiGLU experts this device holds, experts ``first .. first + count`` of
     the published ``E_pub`` (``held = (first, count)``; None = all of them).
     Routing is over all ``E_pub`` experts: top-k of the full softmax,
-    normalised over all k chosen, times ``routed_scale``. Only the held
+    normalised over all k chosen, times ``routed_scale``; ``groups`` limits
+    the choice to the best groups of neighbours (:func:`route_topk`) — the
+    held run of experts is then usually one of them. Only the held
     experts' part of the sum is computed — what the absent experts would add
     is another device's, and nothing here stands in for it. ``shared``
     (gate, up, down in torch Linear layout) is an always-on SwiGLU expert
@@ -167,14 +192,17 @@ def moe_share_ffn(
     cons = constrain if constrain is not None else (lambda arr, *spec: arr)
     t = x.reshape(b * s, d)
     f32 = jnp.float32
-    with jax.named_scope(scopes[0]):
+    # a third scope, where given, names the routing apart from the experts' products
+    with jax.named_scope(scopes[2] if len(scopes) > 2 else scopes[0]):
         logits = jax.lax.dot_general(t, router_w, (((1,), (1,)), ((), ())),
                                      preferred_element_type=f32)  # [T, E_pub]
-        combine = route_topk(logits, top_k, renormalize=renormalize, scale=routed_scale)
+        combine = route_topk(logits, top_k, renormalize=renormalize, scale=routed_scale,
+                             groups=groups)
         here = jax.lax.slice_in_dim(combine, first, first + count, axis=1)  # [T, E_held]
         hit = here > 0
         counts = jnp.stack([jnp.int32(b * s * top_k), jnp.sum(hit, dtype=jnp.int32),
                             jnp.sum(jnp.any(hit, axis=0), dtype=jnp.int32)])
+    with jax.named_scope(scopes[0]):
         g = jnp.einsum("td,efd->etf", t, w_gate, preferred_element_type=f32).astype(x.dtype)
         u = jnp.einsum("td,efd->etf", t, w_up, preferred_element_type=f32).astype(x.dtype)
         # the combine weight goes on the hidden activation, so that the down

@@ -567,3 +567,89 @@ class TestLayerMetricFiles:
         assert share["workloads"] == load_decode["workloads"] == ["mixtral-8x7b-d4.decode"]
         assert (load_deploy["moves"], load_deploy["workloads"]) == (
             "pod_ttft_s", ["phi3-mini-4k.deploy"])
+
+
+# -- the other families' programs are the parent's (PR 43) ---------------------------
+
+# sha-256 of the lowered text of each family's engine programs at its tiny preset on the
+# CPU, taken on the parent commit of PR 43 (a705d9f) by this same code: what the change
+# shares with them (``ops/moe.moe_share_ffn``'s third scope and ``groups``, YaRN lifted
+# into ``ops/rope``, the ragged decode kernel's ``value_lanes``, ``kv_layout``'s new leaf
+# kind) must leave them byte for byte. A jax upgrade changes the text too: then take the
+# table anew on a checkout of the commit before it.
+PARENT_PROGRAMS = {
+    "llama.chunk": "5856132ebf1b5af530ef582acf5631631bbdc7a9a20762dba84ccbf434e36793",
+    "llama.admit": "66c126a4c51da1404639bb4d97da6181814cef4298958db4244315827025f764",
+    "llama.piece": "c862c3c73c093888ab865c00577bbc7d88f11f489d50ec87312569556eed74fe",
+    "mixtral.chunk": "f0b8f15b06fb6dd7111eeac9e5c2e29724792f6053e86dd34d2d9a7c3d505558",
+    "mixtral.admit": "4742a17bb4e300902560f2726f0aad29a0ae368dcd9c894a7021771d1ddfc6ee",
+    "mixtral.piece": "d0680983f8ad7fbaedfd19f26bfefd133bdd6478470a27dc2b61256d54218ea2",
+    "laguna.chunk": "388893aa6957420df20cdc8f9ec2d9e31e6c62bd5af96c617d6b48873350c5d2",
+    "laguna.admit": "f6a82086b4e9ddcbf57ad3296dfe4ff948d1d238abede5215532d2e7d5df554d",
+    "minicpm_sala.chunk": "64ea55ee3d9fbb83b82171dc550b32793182bfe0aa7ffdbf99691c339a1da1f5",
+    "minicpm_sala.admit": "35f181545364bfaab19c9e0d278596198a9823fdb55c9e651084f3a8ac5c3900",
+    "minicpm_sala.piece": "0fb1c8cab7f7886f3b7fbad41c46cc35a291368ded4bbe8a2450ecd2774497ee"
+}
+# the ragged decode kernel's own jaxpr (the Mosaic body's source; it names no file) at
+# the two decode cells' widths: (rows, query heads, cache length)
+PARENT_RAGGED_KERNEL = {
+    (32, 32, 2048): "4c68e22feba6bbd98962d59eab53513e16446567c9c8604dbab12f17e52f83e4",
+    (64, 48, 4096): "54e39aa294df09bdd78a8b3addfc790d20278cddb0e95aa7d055cb638a72858f",
+}
+
+
+def tiny_family(family: str):
+    import importlib
+
+    module = importlib.import_module("modelx_tpu.models." + family)
+    name = {"llama": "LlamaConfig", "mixtral": "MixtralConfig", "laguna": "LagunaConfig",
+            "minicpm_sala": "SalaConfig"}[family]
+    return module, getattr(module, name).tiny(vocab_size=64)
+
+
+@pytest.mark.parametrize("family", ["llama", "mixtral", "laguna", "minicpm_sala"])
+def test_the_other_families_programs_lower_to_the_parents_text(family):
+    import hashlib
+    import types
+
+    from modelx_tpu.dl.families import FAMILIES
+    from modelx_tpu.parallel.mesh import make_mesh
+
+    module, cfg = tiny_family(family)
+    params = jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                                    module.init_params(cfg, jax.random.PRNGKey(0)))
+    server = types.SimpleNamespace(
+        family=FAMILIES[family], cfg=cfg, mesh=make_mesh("dp=1", jax.devices()[:1]),
+        params=params, max_seq_len=64, stats={})
+    pieces = {"prefill_chunk": 16} if family != "laguna" else {}  # a ring takes no piece
+    engine = ContinuousBatcher(server, max_slots=4, chunk_size=4, max_len=64, allocate=False,
+                               supervise=False, **pieces)
+    sha = lambda lowered: hashlib.sha256(lowered.as_text().encode()).hexdigest()  # noqa: E731
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    try:
+        state, tok = engine.kv.abstract_state(), i32(4, 1)
+        got = {family + ".chunk": sha(engine._chunk_prog.jit.lower(
+            params, state, tok, *engine._chunk_args(False), n_steps=4))}
+        got[family + ".admit"] = sha(jax.jit(engine._admit_impl).lower(
+            params, i32(1, 16), state, tok, i32(1), engine.kv.at(0),
+            jax.ShapeDtypeStruct((1,), jnp.float32), None, None, i32(1), i32(1)))
+        if pieces:
+            got[family + ".piece"] = sha(jax.jit(engine._piece_impl).lower(
+                params, i32(1, 16), state, i32(), engine.kv.at(0, 16, 16)))
+    finally:
+        engine.close()
+    assert got == {k: v for k, v in PARENT_PROGRAMS.items() if k.startswith(family + ".")}
+
+
+@pytest.mark.parametrize("shape", sorted(PARENT_RAGGED_KERNEL))
+def test_the_ragged_decode_kernel_traces_to_the_parents_jaxpr(shape):
+    import hashlib
+
+    from modelx_tpu.ops import attention as attn
+
+    rows, heads, length = shape
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d)  # noqa: E731
+    kv = sds((rows, length, 8, 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, n: attn.decode_attention(q, k, v, n))(
+        sds((rows, 1, heads, 128), jnp.bfloat16), kv, kv, sds((rows,), jnp.int32))
+    assert hashlib.sha256(str(jaxpr).encode()).hexdigest() == PARENT_RAGGED_KERNEL[shape]

@@ -513,3 +513,77 @@ class TestStateAndIndexLeaves:
         sparse = kv.stats["sparse"]
         assert [sparse[k] for k in ("positions_read", "positions_cached", "steps_sparse",
                                     "steps_all", "steps_kernel")] == [24, 100, 1, 2, 1]
+
+
+class TestLatentLeaves:
+    """``LayerKindKV`` over a family whose layers cache one compressed line a
+    position (deepseek_v2): laid and addressed as a full leaf — it carries
+    ``--prefill-chunk`` — under a kind of its own, so that what no test holds
+    over it is refused by name."""
+
+    @pytest.fixture(scope="class")
+    def kv(self):
+        from modelx_tpu.models import deepseek_v2
+
+        cfg = deepseek_v2.DeepseekV2Config.tiny(vocab_size=64)
+        server = types.SimpleNamespace(mesh=make_mesh("dp=1", jax.devices()[:1]),
+                                       family=FAMILIES["deepseek_v2"], cfg=cfg)
+        fwd, init_cache = server.family.decode_fns(cfg, mesh=server.mesh)
+        return kv_layout.build(server, fwd, init_cache, {}, max_slots=SLOTS, max_len=MAX_LEN,
+                               chunk_size=4, page_size=0, max_live_tokens=0,
+                               paged_attention="gather", prefill_chunk=16), cfg
+
+    def test_a_line_a_position_a_layer_and_its_bytes_under_their_own_name(self, kv):
+        kv, cfg = kv
+        state = kv.new_state()
+        assert kv.kinds == {"c0": "latent", "c1": "latent", "c2": "latent",
+                            "moe_counts": "counter", "mla_counts": "counter"}
+        assert state["c0"].shape == (SLOTS, MAX_LEN, 128)  # 32 + 8 values in one lane tile
+        stats = kv.stats["kv"]
+        assert stats["bytes_latent"] == 3 * SLOTS * MAX_LEN * 128 * 4
+        assert stats["bytes_full"] == 0 == stats["bytes_window"] and "bytes_state" not in stats
+        assert not kv.has_state and kv.ring == MAX_LEN and kv.counter_rows == 7
+        assert kv.row_writes == (0, 0)  # the family writes a row's line itself: nothing to count
+        assert kv.stats["mla"]["kv_lora_rank"] == 32 and kv.stats["moe"]["groups"] == 4
+        assert kv.step_kwargs(jnp.asarray([1]), jnp.asarray([1])) == {} == kv.block_kwargs(last_idx=3)
+
+    def test_a_piece_is_handed_the_slots_lines_and_gives_them_back(self, kv):
+        kv, _ = kv
+        rng = np.random.RandomState(4)
+        state = {n: (x if kv.kinds[n] == "counter" else
+                     jnp.asarray(rng.standard_normal(x.shape).astype(x.dtype)))
+                 for n, x in kv.new_state().items()}
+        row = jax.jit(lambda c, w: kv.view(c, w, MAX_LEN))(state, kv.at(1))
+        assert set(row) == {"c0", "c1", "c2"} and row["c0"].shape == (1, MAX_LEN, 128)
+        after = jax.jit(kv.put_piece)(state, {n: x + 1 for n, x in row.items()}, kv.at(1))
+        for name in row:
+            got, was = np.asarray(after[name]), np.asarray(state[name])
+            np.testing.assert_array_equal(got[[0, 2, 3]], was[[0, 2, 3]])
+            np.testing.assert_array_equal(got[1], was[1] + 1)
+        small = scratch(kv, 5, 32)
+        landed = jax.jit(kv.put)(kv.new_state(), small, kv.at(2))
+        np.testing.assert_array_equal(np.asarray(landed["c1"])[2, :32], np.asarray(small["c1"])[0])
+        assert not np.asarray(landed["c1"])[[0, 1, 3]].any()
+
+    def test_both_counter_leaves_ride_home_in_their_order(self, kv):
+        kv, _ = kv
+        state = dict(kv.new_state(), moe_counts=jnp.asarray([12, 3, 2], jnp.int32),
+                     mla_counts=jnp.asarray([64, 40, 6, 6], jnp.int32))
+        out = np.asarray(kv.ride(state, jnp.zeros((SLOTS, 5), jnp.int32)))
+        assert out.shape == (SLOTS + 7, 5)
+        kv._last.clear()
+        kv.landed(out)
+        assert [kv.stats["moe"][k] for k in ("assignments", "assignments_held", "experts_hit")] \
+            == [12, 3, 2]
+        assert [kv.stats["mla"][k] for k in ("positions_read", "positions_cached",
+                                             "steps_absorbed", "steps_all")] == [64, 40, 6, 6]
+
+    @pytest.mark.parametrize("asked,what", [
+        (dict(page_size=16), "--kv-page-size"), (dict(speculative_k=2), "--speculative-k"),
+        (dict(prefix_cache=object()), "--prefix-cache")])
+    def test_what_no_test_holds_over_a_latent_line_is_refused_by_name(self, asked, what):
+        base = dict(page_size=0, prefix_cache=None, prefill_chunk=16, speculative_k=0)
+        with pytest.raises(kv_layout.Refused) as err:
+            kv_layout.LayerKindKV.refuse("deepseek_v2", ("latent", "counter"), **dict(base, **asked))
+        assert what in str(err.value) and "'latent' leaves" in str(err.value)
+        kv_layout.LayerKindKV.refuse("deepseek_v2", ("latent", "counter"), **base)  # the cell's own

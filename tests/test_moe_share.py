@@ -111,3 +111,69 @@ def test_mixtrals_layer_is_the_one_it_was():
             probs, top, -1).sum(-1)
         want = want + y * wgt[..., None]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+# -- group-limited routing (route_topk's ``groups``) --------------------------------
+
+
+def grouped_by_hand(probs: np.ndarray, k: int, n_group: int, topk_group: int, scale: float):
+    """The published rule, a token at a time, written out: a group's score is
+    its best expert's; the ``topk_group`` best groups stay (ties to the lower
+    index); the k best experts inside them (ties to the lower index) get
+    ``scale`` times their own probability, not renormalised."""
+    t, e = probs.shape
+    size, out = e // n_group, np.zeros_like(probs)
+    for row in range(t):
+        best = [probs[row, g * size:(g + 1) * size].max() for g in range(n_group)]
+        kept = sorted(range(n_group), key=lambda g: (-best[g], g))[:topk_group]
+        eligible = [x for g in kept for x in range(g * size, (g + 1) * size)]
+        for x in sorted(eligible, key=lambda x: (-probs[row, x], x))[:k]:
+            out[row, x] = scale * probs[row, x]
+    return out
+
+
+def test_group_limited_routing_is_the_loop_written_out():
+    logits = np.array(jax.random.normal(jax.random.PRNGKey(7), (64, 16)) * 2.0)
+    logits[0, 5] = logits[0, 9]  # two experts of different groups tie
+    logits[1, :] = 0.0  # every expert and every group ties: the lowest indices win
+    # token 3: groups 1 and 2 hold the two best experts; expert 0, the third best of all,
+    # lies in group 0, which is dropped; group 3 holds the next three
+    logits[3] = np.array([3.0, 0, 0, 0, 4.0, 0, 0, 0, 5.0, 0, 0, 0, 2.0, 2.9, 2.8, 2.7])
+    probs = np.asarray(jax.nn.softmax(jnp.asarray(logits), -1))
+    got = np.asarray(moe.route_topk(jnp.asarray(logits), 3, renormalize=False, scale=4.0,
+                                    groups=(4, 2)))
+    np.testing.assert_allclose(got, grouped_by_hand(probs, 3, 4, 2, 4.0), rtol=1e-6)
+    assert ((got > 0).sum(-1) == 3).all()
+    # token 3: groups 2 and 1 are kept (5.0, 4.0); expert 0 (3.0), the third best of all,
+    # lies in dropped group 0 and is done without; the third pick comes from a kept group
+    assert got[3, 8] > 0 and got[3, 4] > 0 and got[3, 0] == 0 and got[3, 12:].sum() == 0
+    assert set(np.nonzero(got[1])[0]) == {0, 1, 2}
+    # without groups the same token takes expert 0
+    plain = np.asarray(moe.route_topk(jnp.asarray(logits), 3, renormalize=False, scale=4.0))
+    assert plain[3, 0] > 0
+
+
+def test_groups_that_do_not_tile_the_experts_are_refused():
+    logits = jnp.zeros((2, 10))
+    with pytest.raises(ValueError, match="groups"):
+        moe.route_topk(logits, 2, groups=(4, 2))
+    with pytest.raises(ValueError, match="groups"):
+        moe.route_topk(logits, 2, groups=(5, 6))
+
+
+def test_the_share_layer_passes_the_groups_on_and_names_its_routing_scope(layer):
+    """``moe_share_ffn(groups=...)`` routes as ``route_topk`` does, and a third
+    scope names the routing apart from the experts' products; without it the
+    two-scope callers' programs are what they were."""
+    cfg, params, _, m, _ = layer
+    sl = slice(0, 8)
+    args = (m, params[P + "mlp.gate.weight"], params[P + "mlp.experts.gate_proj.weight"][sl],
+            params[P + "mlp.experts.up_proj.weight"][sl], params[P + "mlp.experts.down_proj.weight"][sl])
+    kw = dict(top_k=cfg.top_k, held=(0, 8), renormalize=False, routed_scale=2.0)
+    grouped, counts = moe.moe_share_ffn(*args, groups=(4, 2), **kw)
+    plain, _ = moe.moe_share_ffn(*args, **kw)
+    assert np.abs(np.asarray(grouped) - np.asarray(plain)).max() > 1e-3
+    assert int(counts[0]) == m.shape[0] * m.shape[1] * cfg.top_k
+    text = jax.jit(lambda *a: moe.moe_share_ffn(*a, groups=(4, 2), scopes=("x.routed", "x.shared", "x.route"), **kw)
+                   ).lower(*args).as_text(debug_info=True)
+    assert "x.route/" in text and "x.routed/" in text

@@ -119,6 +119,24 @@ FAMILY_SPEC_CASES = {
         ("model.layers.10.mlp.down_proj.weight", PartitionSpec(None, "tp")),
         ("lm_head.weight", PartitionSpec("tp", None)),
     ],
+    "deepseek_v2": [
+        # the low-rank pairs' down-projections whole (a latent line has one "KV head"),
+        # their up-projections and the output by head
+        ("model.layers.0.self_attn.q_a_proj.weight", PartitionSpec(None, None)),
+        ("model.layers.0.self_attn.kv_a_proj_with_mqa.weight", PartitionSpec(None, None)),
+        ("model.layers.0.self_attn.kv_a_layernorm.weight", PartitionSpec(None)),
+        ("model.layers.0.self_attn.q_b_proj.weight", PartitionSpec("tp", None)),
+        ("model.layers.0.self_attn.kv_b_proj.weight", PartitionSpec("tp", None)),
+        ("model.layers.0.self_attn.o_proj.weight", PartitionSpec(None, "tp")),
+        ("model.layers.1.mlp.gate.weight", PartitionSpec(None, None)),  # the router, whole
+        ("model.layers.1.mlp.experts.gate_proj.weight", PartitionSpec(None, "tp", None)),  # no ep axis here
+        ("model.layers.1.mlp.experts.down_proj.weight", PartitionSpec(None, None, "tp")),
+        # two dimensions: the stacked experts' patterns must not catch them
+        ("model.layers.1.mlp.shared_experts.gate_proj.weight", PartitionSpec("tp", None)),
+        ("model.layers.1.mlp.shared_experts.down_proj.weight", PartitionSpec(None, "tp")),
+        ("model.layers.0.mlp.up_proj.weight", PartitionSpec("tp", None)),
+        ("lm_head.weight", PartitionSpec("tp", None)),
+    ],
 }
 
 

@@ -8,6 +8,7 @@ partition. Interpret-mode tests cannot see either. Kernel level only
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -458,3 +459,110 @@ def test_the_cells_chunk_program_writes_every_leaf_in_the_kernel_in_place(topo, 
                    for x in jax.tree_util.tree_leaves(state) if len(x.shape) == 4)
     assert kv_bytes <= m.alias_size_in_bytes < kv_bytes + 4096  # + tok, counters
     assert m.temp_size_in_bytes <= PARENT_CHUNK_TEMP[family]
+
+
+# -- deepseek_v2: the latent cache, the absorbed kernel, the piece program ----------
+
+
+def deepseek_v2_cell(topo, monkeypatch):
+    """The ``deepseek-v2-ep8-d5.longdoc`` cell's engine over shapes on one
+    described device, the rules steered to a TPU -> (its file, engine, params,
+    ``sds``)."""
+    import json
+    import types
+
+    from modelx_tpu.dl.continuous import ContinuousBatcher
+    from modelx_tpu.dl.families import FAMILIES
+    from modelx_tpu.models import deepseek_v2
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "deepseek-v2-ep8-d5.json")) as f:
+        raw = json.load(f)
+    cfg = deepseek_v2.config_from_hf(raw)
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    params = {k: sds(v, jnp.bfloat16) for k, v in deepseek_v2.param_shapes(cfg).items()}
+    server = types.SimpleNamespace(
+        family=FAMILIES["deepseek_v2"], cfg=cfg, mesh=make_mesh("dp=1", [topo.devices[0]]),
+        params=params, max_seq_len=32768, stats={})
+    engine = ContinuousBatcher(server, max_slots=32, chunk_size=8, max_len=32768,
+                               prefill_chunk=2048, allocate=False, supervise=False)
+    return raw, engine, params, sds
+
+
+def test_the_absorbed_kernel_at_the_cells_widths(topo):
+    """Mosaic takes the widened ragged kernel at the ``.longdoc`` cell's
+    shapes — 32 rows, 128 query heads over ONE line of 640 lanes a position,
+    blocks of 1,024 positions, the values the line's first 512 lanes: no
+    second operand, the cache where it lies, a temporary for nothing."""
+    from modelx_tpu.ops import latent_attention as latent
+
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    compiled = jax.jit(lambda q, c, o: latent.absorbed(q, c, o, 0.1147, 512, impl="ragged")).lower(
+        sds((32, 128, 640), jnp.bfloat16), sds((32, 32768, 640), jnp.bfloat16),
+        sds((32,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text and "latent_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+    assert not [line for line in text.splitlines()
+                if re.search(r"= bf16\[32,32768,(1,)?640\]\S* (copy|transpose)\(", line)]
+
+
+def test_deepseek_v2s_chunk_program_reads_each_latent_line_once_where_it_lies(topo, monkeypatch):
+    """The engine's OWN chunk program at the cell's size: one absorbed kernel
+    call a layer under ``dsv2.attn.attend``; no latent leaf copied or re-laid
+    (``[slots, L, 576]`` the compiler lays with the positions minor: the
+    padded 640-lane line is what keeps the leaf as the kernel reads it); no
+    per-head keys or values of cached positions (68 GB at this size) and no
+    ``[32, 128, 32768]`` score tensor; the state leaves aliased to its input;
+    weights and cache are the configuration's ``bytes_predicted``."""
+    raw, engine, params, sds = deepseek_v2_cell(topo, monkeypatch)
+    try:
+        compiled = engine._chunk_prog.jit.lower(
+            params, engine.kv.abstract_state(), sds((32, 1), jnp.int32),
+            *engine._chunk_args(False), n_steps=8).compile()
+    finally:
+        engine.close()
+    text, m = compiled.as_text(), compiled.memory_analysis()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 5
+    assert all("dsv2.attn.attend" in c and "latent_decode_attention" in c for c in calls)
+    relaid = [line.strip()[:120] for line in text.splitlines()
+              if re.search(r"= bf16\[32,32768,640\]\S* (copy|transpose)\(", line)]
+    assert not relaid, relaid
+    assert "bf16[32,32768,640]" in text  # the leaves are there under that shape
+    expanded = [line.strip()[:120] for line in text.splitlines()
+                if re.search(r"\[32,32768,128,\d+\]|\[32,128,32768(,\d+)?\]", line)]
+    assert not expanded, expanded
+    predicted = raw["bytes_predicted"]
+    assert predicted["sum"] == 2 * 3_145_466_880 + 32 * 32768 * 640 * 2 * 5
+    assert predicted["sum"] <= m.argument_size_in_bytes < predicted["sum"] + 16384
+    cache = predicted["latent_cache_32_slots_x_32768_positions_x_5_layers"]
+    assert cache <= m.alias_size_in_bytes < cache + 4096
+    assert m.temp_size_in_bytes < 64 * 2**20  # 16 MB: nothing of a leaf's or the scores' size
+    assert len([line for line in text.splitlines() if " while(" in line]) == 1  # the scan
+
+
+def test_deepseek_v2s_piece_program_expands_a_key_block_at_a_time(topo, monkeypatch):
+    """A 2,048-token piece over the slot's 32,768 positions: keys and values
+    are expanded 1,024 positions at a time and scores held a query tile at a
+    time, so the temporaries stay near a gigabyte where the whole context's
+    per-head keys and values (2.7 GB at 16 k) and ``[128, 2048, 16384]``
+    scores (17 GB) would not fit; no latent leaf is copied whole."""
+    _, engine, params, sds = deepseek_v2_cell(topo, monkeypatch)
+    try:
+        compiled = jax.jit(engine._piece_impl, donate_argnums=(2,)).lower(
+            params, sds((1, 2048), jnp.int32), engine.kv.abstract_state(), sds((), jnp.int32),
+            sds((), jnp.int32)).compile()
+    finally:
+        engine.close()
+    text, m = compiled.as_text(), compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 1.25 * 2**30
+    relaid = [line.strip()[:120] for line in text.splitlines()
+              if re.search(r"= bf16\[32,32768,640\]\S* (copy|transpose)\(", line)]
+    assert not relaid, relaid
+    whole = [line.strip()[:120] for line in text.splitlines()
+             if re.search(r"\[1,(32768|16384),128,\d+\]|\[1,128,2048,(32768|16384)\]", line)]
+    assert not whole, whole
