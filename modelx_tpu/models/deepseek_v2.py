@@ -461,7 +461,7 @@ def decoder_layer(params, p: str, x, positions, cfg: DeepseekV2Config, layer: in
             params[p + "mlp.experts.down_proj.weight"],
             top_k=cfg.top_k, held=cfg.held, renormalize=cfg.norm_topk_prob,
             routed_scale=1.0 if cfg.norm_topk_prob else cfg.routed_scale, shared=shared,
-            constrain=ctx.constrain, groups=cfg.groups,
+            constrain=ctx.constrain, groups=cfg.groups, mesh=ctx.mesh,
             scopes=("dsv2.moe.routed", "dsv2.moe.shared", "dsv2.moe.route"))
 
     b, s, d = m.shape

@@ -72,7 +72,7 @@ from modelx_tpu.ops.rope import yarn_inv_freq, yarn_mscale
 FULL, SLIDING = "full_attention", "sliding_attention"
 # the engine's counters of the expert layers, in the order the decode step
 # accumulates them (dl/kv_layout.LayerKindKV reads them back with the tokens)
-MOE_COUNTERS = ("assignments", "assignments_held", "experts_hit")
+MOE_COUNTERS = ("assignments", "assignments_held", "experts_hit", "experts_read")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -480,7 +480,7 @@ def decoder_layer(params, p: str, x, positions, cfg: LagunaConfig, layer: int,
         params[p + "mlp.experts.up_proj.weight"], params[p + "mlp.experts.down_proj.weight"],
         top_k=cfg.top_k, held=cfg.held, renormalize=cfg.norm_topk_prob,
         routed_scale=cfg.routed_scale, shared=shared, constrain=ctx.constrain,
-        scopes=("laguna.moe.routed", "laguna.moe.shared"))
+        scopes=("laguna.moe.routed", "laguna.moe.shared"), mesh=ctx.mesh)
     return ctx.constrain(x + y, "dp", "sp", None), new_cache, counts
 
 

@@ -13,6 +13,18 @@ routing (tests use drop-free capacity), the math is exactly Mixtral's
 renormalized top-k MoE; under pressure, overflow tokens are dropped
 (combine weight 0) which is the standard capacity trade.
 
+A device that holds a SHARE of a layer's experts runs
+:func:`moe_share_ffn` instead: routing over the published width, the held
+experts' part of the sum only. Its routed sum has two lowerings of one
+algorithm whose cost differs with the shape (:func:`lowering`: no flag, no
+option, no model's name): thousands of rows hit every held expert and are
+bound by arithmetic — the dense einsums, every expert on every token; a decode
+step's handful of rows is bound by reading the experts' weights, of which some
+no row chose — :func:`hit_experts`, a Pallas kernel that takes the hit
+experts' indices as prefetched scalars and reads those experts only (what a
+decode step reads: ``counts[3]`` of them, the hit ones, where the einsums read
+every held one).
+
 Reference parity note: the reference registry (kubegems/modelx) has no
 models at all (SURVEY §2.2); this module exists for the TPU serving/training
 path the build brief makes first-class.
@@ -20,10 +32,26 @@ path the build brief makes first-class.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from modelx_tpu.ops.nn import linear as _linear
+from modelx_tpu.utils import trace
+
+# a matrix of an expert is whole tiles where both its sides are multiples of a
+# tile's 128 lanes (and so of its sublanes); the rows of a step, of 8 sublanes
+LANES, SUBLANES = 128, 8
+# rows x [F, D] in bf16 is T FLOP a byte of weights: under the v5e's ridge (197
+# TFLOP/s over 819 GB/s = 240) the product waits for the weights, and the
+# kernel's row blocks and float32 sum [T, D] fit in VMEM beside them
+ROWS_MAX = 256
+# the kernel's blocks: the largest run of a matrix's rows under this many bytes.
+# Three operands, double-buffered: six such blocks lie in VMEM (of 128 MiB)
+BLOCK_BYTES = 8 << 20
 
 
 def router_topk(router_logits: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
@@ -141,6 +169,25 @@ def route_topk(router_logits: jax.Array, k: int, *, renormalize: bool = True,
     return jnp.zeros_like(probs).at[rows, idx].set(vals * scale)
 
 
+def lowering(x_shape: tuple, w_shape: tuple, mesh=None) -> str:
+    """``"kernel"`` or ``"einsum"`` for the routed part of
+    :func:`moe_share_ffn` on ``x`` ``[B, S, D]`` over stacked experts
+    ``[E_held, F, D]`` — from shapes, the backend and the mesh alone, as
+    ``ops.kv_write.lowering``. The kernel (:func:`hit_experts`): a decode step
+    (``S == 1``) of at most :data:`ROWS_MAX` rows (bound by reading the
+    experts, of which some are unhit), ``D`` and ``F`` whole lane tiles and
+    the rows whole sublane tiles, the TPU backend, one device (a bare Mosaic
+    call cannot be partitioned). Everything else — an admission, a prefill
+    piece, a teacher-forced forward, hundreds or thousands of rows that hit
+    every expert and are bound by arithmetic, a mesh, the CPU — is the
+    einsums, lowered as ever."""
+    (b, s, d), f = x_shape, w_shape[1]
+    if (s == 1 and b <= ROWS_MAX and b % SUBLANES == 0 and d % LANES == 0 and f % LANES == 0
+            and jax.default_backend() == "tpu" and (mesh is None or mesh.size == 1)):
+        return "kernel"
+    return "einsum"
+
+
 def moe_share_ffn(
     x: jax.Array,
     router_w: jax.Array,
@@ -156,6 +203,7 @@ def moe_share_ffn(
     constrain=None,
     scopes: tuple[str, ...] = ("moe.routed", "moe.shared"),
     groups: tuple[int, int] | None = None,
+    mesh=None,
 ) -> tuple[jax.Array, jax.Array]:
     """An expert layer that is told which experts it holds.
 
@@ -172,15 +220,20 @@ def moe_share_ffn(
     (gate, up, down in torch Linear layout) is an always-on SwiGLU expert
     added ungated beside the routed sum.
 
-    Drop-free and exact: every held expert runs on every token and the
-    combine weight (zero where the router did not choose it) picks its part,
-    so no capacity, sort or dynamic shape is involved. At decode the layer is
-    bound by reading the held experts' weights, which this formulation reads
-    once each; at prefill it spends E_held/k times the arithmetic a grouped
-    product over sorted tokens would (ROADMAP R1).
+    Drop-free and exact, with no capacity, sort or dynamic shape, in two
+    lowerings of one sum (:func:`lowering` picks, from the shapes, the backend
+    and ``mesh``). The einsums run every held expert on every token and the
+    combine weight (zero where the router did not choose it) picks its part:
+    at prefill that spends E_held/k times the arithmetic a grouped product
+    over sorted tokens would (ROADMAP R1). A decode step on one TPU device is
+    bound by reading the experts' weights and takes :func:`hit_experts`, which
+    reads only the held experts some row of the step chose: one no row chose
+    has weight zero on every token and adds exactly zero.
 
-    Returns (out [B, S, D], counts int32 [3]): token-expert pairs routed,
-    those that landed on a held expert, and distinct held experts hit.
+    Returns (out [B, S, D], counts int32 [4]): token-expert pairs routed,
+    those that landed on a held expert, distinct held experts hit, and held
+    experts whose weights the step read (the hit ones in the kernel, all of
+    them in the einsums).
     """
     b, s, d = x.shape
     e_pub = router_w.shape[0]
@@ -192,6 +245,9 @@ def moe_share_ffn(
     cons = constrain if constrain is not None else (lambda arr, *spec: arr)
     t = x.reshape(b * s, d)
     f32 = jnp.float32
+    how = lowering(x.shape, w_gate.shape, mesh)
+    with trace.span(f"moe.{how}[{b * s}x{count}]"):
+        pass  # at TRACE time, once a call site: which lowering a program compiled with
     # a third scope, where given, names the routing apart from the experts' products
     with jax.named_scope(scopes[2] if len(scopes) > 2 else scopes[0]):
         logits = jax.lax.dot_general(t, router_w, (((1,), (1,)), ((), ())),
@@ -200,17 +256,16 @@ def moe_share_ffn(
                              groups=groups)
         here = jax.lax.slice_in_dim(combine, first, first + count, axis=1)  # [T, E_held]
         hit = here > 0
-        counts = jnp.stack([jnp.int32(b * s * top_k), jnp.sum(hit, dtype=jnp.int32),
-                            jnp.sum(jnp.any(hit, axis=0), dtype=jnp.int32)])
+        n_hit = jnp.sum(jnp.any(hit, axis=0), dtype=jnp.int32)
+        counts = jnp.stack([jnp.int32(b * s * top_k), jnp.sum(hit, dtype=jnp.int32), n_hit,
+                            n_hit if how == "kernel" else jnp.int32(count)])
     with jax.named_scope(scopes[0]):
-        g = jnp.einsum("td,efd->etf", t, w_gate, preferred_element_type=f32).astype(x.dtype)
-        u = jnp.einsum("td,efd->etf", t, w_up, preferred_element_type=f32).astype(x.dtype)
-        # the combine weight goes on the hidden activation, so that the down
-        # projection contracts experts and features at once ([T, E*F] x
-        # [E*F, D]) and no [E, T, D] block of per-expert outputs exists
-        h = (jax.nn.silu(g) * u).astype(f32) * here.T[:, :, None]
-        h = cons(h.astype(x.dtype), "ep", None, "tp")
-        out = jnp.einsum("etf,edf->td", h, w_down, preferred_element_type=f32)
+        if how == "kernel":
+            # off the TPU only a test that steers ``lowering`` comes here
+            out = hit_experts(t, here, w_gate, w_up, w_down,
+                              interpret=jax.default_backend() != "tpu")
+        else:
+            out = every_expert(t, here, w_gate, w_up, w_down, cons)
     if shared is not None:
         with jax.named_scope(scopes[1]):
             sg, su, sd = shared
@@ -218,6 +273,136 @@ def moe_share_ffn(
             out = out + jax.lax.dot_general(hs, sd, (((1,), (1,)), ((), ())),
                                             preferred_element_type=f32)
     return cons(out.astype(x.dtype).reshape(b, s, d), "dp", "sp", None), counts
+
+
+def every_expert(t, here, w_gate, w_up, w_down, constrain=None):
+    """The routed sum of :func:`moe_share_ffn` as dense einsums: every held
+    expert on every token, the combine weights ``here`` ``[T, E_held]`` (zero
+    where a row did not choose an expert) picking its part. Operands as
+    :func:`hit_experts`'; returns ``[T, D]`` float32."""
+    f32 = jnp.float32
+    g = jnp.einsum("td,efd->etf", t, w_gate, preferred_element_type=f32).astype(t.dtype)
+    u = jnp.einsum("td,efd->etf", t, w_up, preferred_element_type=f32).astype(t.dtype)
+    # the combine weight goes on the hidden activation, so that the down
+    # projection contracts experts and features at once ([T, E*F] x
+    # [E*F, D]) and no [E, T, D] block of per-expert outputs exists
+    h = ((jax.nn.silu(g) * u).astype(f32) * here.T[:, :, None]).astype(t.dtype)
+    if constrain is not None:
+        h = constrain(h, "ep", None, "tp")
+    return jnp.einsum("etf,edf->td", h, w_down, preferred_element_type=f32)
+
+
+def _chunks(rows: int, row_bytes: int) -> int:
+    """Into how many equal runs of whole lane tiles a matrix's ``rows`` go so
+    that one run is at most :data:`BLOCK_BYTES` (or one tile of rows)."""
+    tiles = max(rows // LANES, 1)
+    return next(n for n in range(1, tiles + 1)
+                if n == tiles or tiles % n == 0 and rows // n * row_bytes <= BLOCK_BYTES)
+
+
+def _hit_experts_kernel(ids_ref, n_ref, x_ref, here_ref, gate_ref, up_ref, down_ref, out_ref,
+                        g_ref, h_ref, *, n_f: int, n_d: int):
+    """Grid ``(place, step)``: place ``i`` is the ``i``-th hit expert
+    (``ids_ref[i]``), its steps the ``n_f`` runs of the gate's rows, the
+    ``n_f`` of the up's, the ``n_d`` of the down's — one block of weights a
+    step, the next one on its way meanwhile. ``out_ref`` ``[T, D]`` float32
+    stays in VMEM over the whole grid and is written home once."""
+    place, step = pl.program_id(0), pl.program_id(1)
+    live = place < n_ref[0]
+    nt = (((1,), (1,)), ((), ()))  # rows x [N, K]: both contract their last axis
+    fc, dc = g_ref.shape[1] // n_f, out_ref.shape[1] // n_d
+    f32 = jnp.float32
+
+    @pl.when((place == 0) & (step == 0))
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    for c in range(n_f):
+        @pl.when(live & (step == c))
+        def _(c=c):
+            g = jax.lax.dot_general(x_ref[...], gate_ref[0], nt, preferred_element_type=f32)
+            g_ref[:, c * fc:(c + 1) * fc] = g.astype(g_ref.dtype)
+
+        @pl.when(live & (step == n_f + c))
+        def _(c=c):
+            u = jax.lax.dot_general(x_ref[...], up_ref[0], nt, preferred_element_type=f32)
+            # the expert's column of the combine weights, picked by a mask: [T, 1]
+            lane = jax.lax.broadcasted_iota(jnp.int32, here_ref.shape, 1)
+            w = jnp.sum(jnp.where(lane == ids_ref[place], here_ref[...], 0.0), axis=1,
+                        keepdims=True)
+            # the einsums' roundings, one an operation: g, u, silu(g), their product
+            dtype = h_ref.dtype
+            act = jax.nn.silu(g_ref[:, c * fc:(c + 1) * fc].astype(f32)).astype(dtype)
+            gated = (act.astype(f32) * u.astype(dtype).astype(f32)).astype(dtype)
+            h_ref[:, c * fc:(c + 1) * fc] = (gated.astype(f32) * w).astype(dtype)
+
+    for c in range(n_d):
+        @pl.when(live & (step == 2 * n_f + c))
+        def _(c=c):
+            out_ref[:, c * dc:(c + 1) * dc] += jax.lax.dot_general(
+                h_ref[...], down_ref[0], nt, preferred_element_type=f32)
+
+
+def hit_experts(t, here, w_gate, w_up, w_down, *, interpret: bool = False):
+    """The routed sum of :func:`moe_share_ffn` over the held experts some row
+    chose, as one Pallas kernel. t ``[T, D]``; here ``[T, E_held]`` float32,
+    the combine weights (zero where a row did not choose an expert); w_gate /
+    w_up ``[E_held, F, D]``, w_down ``[E_held, D, F]``. Returns ``[T, D]``
+    float32: sum over experts of ``(silu(t g^T) * (t u^T) * here[:, e]) d^T``,
+    bf16 operands and float32 accumulation as the einsums have them, the
+    experts' parts added in float32 in the order of their indices.
+
+    The hit experts' indices go first in a list of ``E_held`` places (the
+    tail repeats the last one) and reach the kernel as prefetched scalars
+    beside their count: the weights' index maps read a place's expert from
+    the list, so a place past the count asks for the block that is already
+    there — no copy — and ``pl.when`` skips its arithmetic. Blocks are runs
+    of a matrix's rows, contiguous in HBM, of up to :data:`BLOCK_BYTES`,
+    double-buffered by the pipeline; each operand's index moves one step
+    before the step that needs it, so one copy is in flight at any time."""
+    rows, d = t.shape
+    e, f = w_gate.shape[:2]
+    item = w_gate.dtype.itemsize
+    n_f, n_d = _chunks(f, d * item), _chunks(d, f * item)
+    steps = 2 * n_f + n_d
+    hit = jnp.any(here > 0, axis=0)
+    n_hit = jnp.sum(hit, dtype=jnp.int32)
+    order = jnp.argsort(~hit, stable=True).astype(jnp.int32)
+    ids = jnp.where(jnp.arange(e) < n_hit, order, order[jnp.maximum(n_hit - 1, 0)])
+
+    def block(first_step: int, n: int):
+        """The index map of an operand used at steps ``first_step ..
+        first_step + n`` of each place: before them it stays where the place
+        before left it (at place 0, where it will start), so that its copy
+        for this place is asked for at the step before the first use; past the
+        live places, where the last live step left it."""
+        def index(place, step, ids, n_hit):
+            step = jnp.where(place < n_hit[0], step, steps - 1)
+            early = step < first_step
+            expert = ids[jnp.where(early, jnp.maximum(place - 1, 0), place)]
+            chunk = jnp.where(early, jnp.where(place == 0, 0, n - 1),
+                              jnp.minimum(step - first_step, n - 1))
+            return expert, chunk, 0
+        return index
+
+    whole = lambda place, step, ids, n_hit: (0, 0)  # noqa: E731
+    block_bytes = max(f // n_f * d, d // n_d * f) * item
+    return pl.pallas_call(
+        functools.partial(_hit_experts_kernel, n_f=n_f, n_d=n_d),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(e, steps),
+            in_specs=[pl.BlockSpec((rows, d), whole), pl.BlockSpec((rows, e), whole),
+                      pl.BlockSpec((1, f // n_f, d), block(0, n_f)),
+                      pl.BlockSpec((1, f // n_f, d), block(n_f, n_f)),
+                      pl.BlockSpec((1, d // n_d, f), block(2 * n_f, n_d))],
+            out_specs=pl.BlockSpec((rows, d), whole),
+            scratch_shapes=[pltpu.VMEM((rows, f), t.dtype), pltpu.VMEM((rows, f), t.dtype)]),
+        out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=6 * block_bytes + (16 << 20)),
+        interpret=interpret, name="moe_hit_experts",
+    )(ids, n_hit[None], t, here.astype(jnp.float32), w_gate, w_up, w_down)
 
 
 def load_balancing_loss(router_logits: jax.Array, mask: jax.Array) -> jax.Array:

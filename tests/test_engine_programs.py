@@ -576,7 +576,10 @@ class TestLayerMetricFiles:
 # shares with them (``ops/moe.moe_share_ffn``'s third scope and ``groups``, YaRN lifted
 # into ``ops/rope``, the ragged decode kernel's ``value_lanes``, ``kv_layout``'s new leaf
 # kind) must leave them byte for byte. A jax upgrade changes the text too: then take the
-# table anew on a checkout of the commit before it.
+# table anew on a checkout of the commit before it. PR 44 (the expert layer's decode step
+# reads only the hit experts, a fourth counter beside the three) leaves the table's three
+# families as they stand and takes Laguna's two entries out: its programs carry the new
+# counter, and are held to the parent's expert layer line for line below instead.
 PARENT_PROGRAMS = {
     "llama.chunk": "5856132ebf1b5af530ef582acf5631631bbdc7a9a20762dba84ccbf434e36793",
     "llama.admit": "66c126a4c51da1404639bb4d97da6181814cef4298958db4244315827025f764",
@@ -584,8 +587,6 @@ PARENT_PROGRAMS = {
     "mixtral.chunk": "f0b8f15b06fb6dd7111eeac9e5c2e29724792f6053e86dd34d2d9a7c3d505558",
     "mixtral.admit": "4742a17bb4e300902560f2726f0aad29a0ae368dcd9c894a7021771d1ddfc6ee",
     "mixtral.piece": "d0680983f8ad7fbaedfd19f26bfefd133bdd6478470a27dc2b61256d54218ea2",
-    "laguna.chunk": "388893aa6957420df20cdc8f9ec2d9e31e6c62bd5af96c617d6b48873350c5d2",
-    "laguna.admit": "f6a82086b4e9ddcbf57ad3296dfe4ff948d1d238abede5215532d2e7d5df554d",
     "minicpm_sala.chunk": "64ea55ee3d9fbb83b82171dc550b32793182bfe0aa7ffdbf99691c339a1da1f5",
     "minicpm_sala.admit": "35f181545364bfaab19c9e0d278596198a9823fdb55c9e651084f3a8ac5c3900",
     "minicpm_sala.piece": "0fb1c8cab7f7886f3b7fbad41c46cc35a291368ded4bbe8a2450ecd2774497ee"
@@ -603,13 +604,14 @@ def tiny_family(family: str):
 
     module = importlib.import_module("modelx_tpu.models." + family)
     name = {"llama": "LlamaConfig", "mixtral": "MixtralConfig", "laguna": "LagunaConfig",
-            "minicpm_sala": "SalaConfig"}[family]
+            "minicpm_sala": "SalaConfig", "deepseek_v2": "DeepseekV2Config"}[family]
     return module, getattr(module, name).tiny(vocab_size=64)
 
 
-@pytest.mark.parametrize("family", ["llama", "mixtral", "laguna", "minicpm_sala"])
-def test_the_other_families_programs_lower_to_the_parents_text(family):
-    import hashlib
+def lowered_programs(family: str) -> dict:
+    """``{family.chunk, .admit[, .piece]: lowered text}`` of a NEW engine over
+    the family's tiny preset on the CPU (new jits: nothing traced before is
+    reused)."""
     import types
 
     from modelx_tpu.dl.families import FAMILIES
@@ -624,21 +626,46 @@ def test_the_other_families_programs_lower_to_the_parents_text(family):
     pieces = {"prefill_chunk": 16} if family != "laguna" else {}  # a ring takes no piece
     engine = ContinuousBatcher(server, max_slots=4, chunk_size=4, max_len=64, allocate=False,
                                supervise=False, **pieces)
-    sha = lambda lowered: hashlib.sha256(lowered.as_text().encode()).hexdigest()  # noqa: E731
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     try:
         state, tok = engine.kv.abstract_state(), i32(4, 1)
-        got = {family + ".chunk": sha(engine._chunk_prog.jit.lower(
-            params, state, tok, *engine._chunk_args(False), n_steps=4))}
-        got[family + ".admit"] = sha(jax.jit(engine._admit_impl).lower(
+        got = {family + ".chunk": engine._chunk_prog.jit.lower(
+            params, state, tok, *engine._chunk_args(False), n_steps=4).as_text()}
+        got[family + ".admit"] = jax.jit(engine._admit_impl).lower(
             params, i32(1, 16), state, tok, i32(1), engine.kv.at(0),
-            jax.ShapeDtypeStruct((1,), jnp.float32), None, None, i32(1), i32(1)))
+            jax.ShapeDtypeStruct((1,), jnp.float32), None, None, i32(1), i32(1)).as_text()
         if pieces:
-            got[family + ".piece"] = sha(jax.jit(engine._piece_impl).lower(
-                params, i32(1, 16), state, i32(), engine.kv.at(0, 16, 16)))
+            got[family + ".piece"] = jax.jit(engine._piece_impl).lower(
+                params, i32(1, 16), state, i32(), engine.kv.at(0, 16, 16)).as_text()
     finally:
         engine.close()
+    return got
+
+
+@pytest.mark.parametrize("family", ["llama", "mixtral", "minicpm_sala"])
+def test_the_other_families_programs_lower_to_the_parents_text(family):
+    import hashlib
+
+    got = {k: hashlib.sha256(v.encode()).hexdigest() for k, v in lowered_programs(family).items()}
     assert got == {k: v for k, v in PARENT_PROGRAMS.items() if k.startswith(family + ".")}
+
+
+@pytest.mark.parametrize("family,programs", [("laguna", 2), ("deepseek_v2", 3)])
+def test_the_expert_families_programs_are_the_parents_where_the_rule_says_einsum(
+        monkeypatch, family, programs):
+    """PR 44: on the CPU ``ops.moe.lowering`` answers "einsum" for every call,
+    and the two families' chunk, admit and piece programs then lower to the
+    text they lower to with the parent's expert layer in ``moe_share_ffn``'s
+    place (``test_moe_share.parents_layer``: the parent's lines and the fourth
+    counter) — operation for operation, the decode step's included."""
+    from test_moe_share import parents_layer
+
+    from modelx_tpu.ops import moe
+
+    got = lowered_programs(family)
+    assert len(got) == programs and all("pallas" not in text for text in got.values())
+    monkeypatch.setattr(moe, "moe_share_ffn", parents_layer)
+    assert lowered_programs(family) == got
 
 
 @pytest.mark.parametrize("shape", sorted(PARENT_RAGGED_KERNEL))

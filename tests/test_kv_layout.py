@@ -315,7 +315,7 @@ class TestLayerKinds:
         assert kv.stats["kv"]["bytes_window"] == 6 * self.RING * leaf   # k and v of layers 1-3
         assert kv.stats["kv"]["window_positions"] == self.RING == self.WINDOW + 16
         assert kv.describe()[:3] == ("LayerKindKV", SLOTS, MAX_LEN)
-        assert state["moe_counts"].shape == (3,) and kv.sharding((3,)) is None
+        assert state["moe_counts"].shape == (4,) and kv.sharding((4,)) is None
 
     def test_fits_and_reserve_count_both_kinds(self, kv):
         kv, _ = kv
@@ -370,22 +370,24 @@ class TestLayerKinds:
 
     def test_counters_ride_home_below_the_slots_rows_and_wrap_at_32_bits(self, kv):
         kv, _ = kv
-        state = dict(kv.new_state(), moe_counts=jnp.asarray([7, 5, 3], jnp.int32))
+        state = dict(kv.new_state(), moe_counts=jnp.asarray([7, 5, 3, 16], jnp.int32))
         block = jnp.arange(SLOTS * 5, dtype=jnp.int32).reshape(SLOTS, 5)
         out = np.asarray(kv.ride(state, block))
-        assert out.shape == (SLOTS + 3, 5)
+        assert out.shape == (SLOTS + 4, 5)
         np.testing.assert_array_equal(out[:SLOTS], np.asarray(block))
         kv._last.clear()
-        kv.stats["moe"].update(assignments=0, assignments_held=0, experts_hit=0)
+        kv.stats["moe"].update(assignments=0, assignments_held=0, experts_hit=0, experts_read=0)
         kv.landed(out)
-        assert [kv.stats["moe"][k] for k in ("assignments", "assignments_held", "experts_hit")] == [7, 5, 3]
+        assert [kv.stats["moe"][k] for k in ("assignments", "assignments_held", "experts_hit",
+                                             "experts_read")] == [7, 5, 3, 16]
         wrapped = out.copy()
-        wrapped[SLOTS:, 0] = np.asarray([2**31 - 1, 5, 4], np.int64).astype(np.int32)
+        wrapped[SLOTS:, 0] = np.asarray([2**31 - 1, 5, 4, 32], np.int64).astype(np.int32)
         kv.landed(wrapped)
         wrapped[SLOTS, 0] = np.int32(-(2**31) + 9)  # the device's int32 passed 2**31: +10
         kv.landed(wrapped)
         assert kv.stats["moe"]["assignments"] == 2**31 - 1 + 10
         assert (kv.stats["moe"]["assignments_held"], kv.stats["moe"]["experts_hit"]) == (5, 4)
+        assert kv.stats["moe"]["experts_read"] == 32
         DenseKV = kv_layout.DenseKV
         assert DenseKV.ride(kv, state, block) is block  # the other layouts add nothing
 
@@ -542,7 +544,7 @@ class TestLatentLeaves:
         stats = kv.stats["kv"]
         assert stats["bytes_latent"] == 3 * SLOTS * MAX_LEN * 128 * 4
         assert stats["bytes_full"] == 0 == stats["bytes_window"] and "bytes_state" not in stats
-        assert not kv.has_state and kv.ring == MAX_LEN and kv.counter_rows == 7
+        assert not kv.has_state and kv.ring == MAX_LEN and kv.counter_rows == 8
         assert kv.row_writes == (0, 0)  # the family writes a row's line itself: nothing to count
         assert kv.stats["mla"]["kv_lora_rank"] == 32 and kv.stats["moe"]["groups"] == 4
         assert kv.step_kwargs(jnp.asarray([1]), jnp.asarray([1])) == {} == kv.block_kwargs(last_idx=3)
@@ -567,14 +569,14 @@ class TestLatentLeaves:
 
     def test_both_counter_leaves_ride_home_in_their_order(self, kv):
         kv, _ = kv
-        state = dict(kv.new_state(), moe_counts=jnp.asarray([12, 3, 2], jnp.int32),
+        state = dict(kv.new_state(), moe_counts=jnp.asarray([12, 3, 2, 8], jnp.int32),
                      mla_counts=jnp.asarray([64, 40, 6, 6], jnp.int32))
         out = np.asarray(kv.ride(state, jnp.zeros((SLOTS, 5), jnp.int32)))
-        assert out.shape == (SLOTS + 7, 5)
+        assert out.shape == (SLOTS + 8, 5)
         kv._last.clear()
         kv.landed(out)
-        assert [kv.stats["moe"][k] for k in ("assignments", "assignments_held", "experts_hit")] \
-            == [12, 3, 2]
+        assert [kv.stats["moe"][k] for k in ("assignments", "assignments_held", "experts_hit",
+                                             "experts_read")] == [12, 3, 2, 8]
         assert [kv.stats["mla"][k] for k in ("positions_read", "positions_cached",
                                              "steps_absorbed", "steps_all")] == [64, 40, 6, 6]
 

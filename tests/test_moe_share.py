@@ -1,14 +1,21 @@
 """The expert layer that is told which experts it holds (ops/moe.moe_share_ffn),
 against the plain reference layer (models/laguna_reference.py)."""
 
+import dataclasses
+import json
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from modelx_tpu.dl import safetensors as st
+from modelx_tpu.dl.continuous import ContinuousBatcher
+from modelx_tpu.dl.serve import ModelServer
 from modelx_tpu.models import laguna, laguna_reference as reference
 from modelx_tpu.ops import moe
+from modelx_tpu.parallel.mesh import make_mesh
 
 P = "model.layers.1."
 
@@ -67,7 +74,9 @@ def test_the_counters_count_pairs_routed_pairs_held_and_experts_hit(layer):
     for first, count in [(0, 16), (0, 8), (8, 8), (5, 3)]:
         counts = np.asarray(share(cfg, params, m, first, count, with_shared=False)[1])
         here = combine[:, first:first + count] > 0
-        assert counts.tolist() == [tokens * cfg.top_k, int(here.sum()), int(here.any(0).sum())]
+        # off a decode step on one TPU device every held expert's weights are read
+        assert counts.tolist() == [tokens * cfg.top_k, int(here.sum()), int(here.any(0).sum()),
+                                   count]
 
 
 @pytest.mark.parametrize("renormalize,scale", [(True, 2.5), (True, 1.0), (False, 1.0)])
@@ -177,3 +186,262 @@ def test_the_share_layer_passes_the_groups_on_and_names_its_routing_scope(layer)
     text = jax.jit(lambda *a: moe.moe_share_ffn(*a, groups=(4, 2), scopes=("x.routed", "x.shared", "x.route"), **kw)
                    ).lower(*args).as_text(debug_info=True)
     assert "x.route/" in text and "x.routed/" in text
+
+
+# -- a decode step reads only the hit experts (hit_experts, ISSUE 44) ----------------
+# On the CPU the kernel runs in Pallas's interpret mode: values, the counters, the scan,
+# and which callers the rule leaves on the einsums; never a time.
+
+
+def parents_layer(x, router_w, w_gate, w_up, w_down, *, top_k, held=None, renormalize=True,
+                  routed_scale=1.0, shared=None, constrain=None, groups=None,
+                  scopes=("moe.routed", "moe.shared"), mesh=None):
+    """What ``moe_share_ffn`` was at the parent commit, line for line (its
+    check of ``held`` apart; ``mesh`` is taken and not looked at), with the
+    fourth counter its einsums earn: every held expert read."""
+    from modelx_tpu.ops.nn import linear
+
+    b, s, d = x.shape
+    first, count = held if held is not None else (0, router_w.shape[0])
+    cons = constrain if constrain is not None else (lambda arr, *spec: arr)
+    t = x.reshape(b * s, d)
+    f32 = jnp.float32
+    with jax.named_scope(scopes[2] if len(scopes) > 2 else scopes[0]):
+        logits = jax.lax.dot_general(t, router_w, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=f32)
+        combine = moe.route_topk(logits, top_k, renormalize=renormalize, scale=routed_scale,
+                                 groups=groups)
+        here = jax.lax.slice_in_dim(combine, first, first + count, axis=1)
+        hit = here > 0
+        n_hit = jnp.sum(jnp.any(hit, axis=0), dtype=jnp.int32)
+        counts = jnp.stack([jnp.int32(b * s * top_k), jnp.sum(hit, dtype=jnp.int32), n_hit,
+                            jnp.int32(count)])
+    with jax.named_scope(scopes[0]):
+        g = jnp.einsum("td,efd->etf", t, w_gate, preferred_element_type=f32).astype(x.dtype)
+        u = jnp.einsum("td,efd->etf", t, w_up, preferred_element_type=f32).astype(x.dtype)
+        h = (jax.nn.silu(g) * u).astype(f32) * here.T[:, :, None]
+        h = cons(h.astype(x.dtype), "ep", None, "tp")
+        out = jnp.einsum("etf,edf->td", h, w_down, preferred_element_type=f32)
+    if shared is not None:
+        with jax.named_scope(scopes[1]):
+            sg, su, sd = shared
+            hs = jax.nn.silu(linear(t, sg)) * linear(t, su)
+            out = out + jax.lax.dot_general(hs, sd, (((1,), (1,)), ((), ())),
+                                            preferred_element_type=f32)
+    return cons(out.astype(x.dtype).reshape(b, s, d), "dp", "sp", None), counts
+
+
+# the two cells' shapes cut small and lane-whole: rows, held experts, F, D
+# (laguna-s-2.1-ep2-d5.reason 64 x 128 x 1024 x 3072; deepseek-v2-ep8-d5.longdoc 32 x 20 x
+# 1536 x 5120, whose matrices go in two blocks each: here under a budget that cuts them so)
+CUT = {"reason": (16, 12, 128, 384, None), "longdoc": (8, 5, 256, 640, 256 * 640 * 2 // 2)}
+HITS = {"all": lambda e: list(range(e)), "some": lambda e: [1, e - 2, e // 2],
+        "one": lambda e: [e - 1], "none": lambda e: []}
+
+
+def routed_operands(cell: str, hits: str, dtype, seed=0):
+    rows, e, f, d, _ = CUT[cell]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    t = jax.random.normal(keys[0], (rows, d), jnp.float32).astype(dtype)
+    w_gate = (jax.random.normal(keys[1], (e, f, d)) * d ** -0.5).astype(dtype)
+    w_up = (jax.random.normal(keys[2], (e, f, d)) * d ** -0.5).astype(dtype)
+    w_down = (jax.random.normal(keys[3], (e, d, f)) * f ** -0.5).astype(dtype)
+    chosen = np.zeros((rows, e), bool)
+    rng = np.random.default_rng(seed)
+    for expert in HITS[hits](e):
+        chosen[rng.choice(rows, 3, replace=False), expert] = True
+    here = jnp.where(chosen, jax.random.uniform(keys[4], (rows, e), minval=0.05), 0.0)
+    return t, here, w_gate, w_up, w_down
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("hits", HITS)
+@pytest.mark.parametrize("cell", CUT)
+def test_the_kernel_gives_the_einsums_sum(monkeypatch, cell, hits, dtype):
+    """Every held expert hit, some unhit, ONE hit, none at all (the routed
+    sum is then exactly zero): the kernel's float32 sum is the einsums' to the
+    tolerance of float32 accumulation in another order (bf16: of one rounding
+    of the hidden activation, which the kernel takes from a float32 silu)."""
+    if CUT[cell][4]:
+        monkeypatch.setattr(moe, "BLOCK_BYTES", CUT[cell][4] * (2 if dtype == "float32" else 1))
+    args = routed_operands(cell, hits, jnp.dtype(dtype))
+    rows, e, f, d, _ = CUT[cell]
+    item = jnp.dtype(dtype).itemsize
+    assert (moe._chunks(f, d * item), moe._chunks(d, f * item)) == (
+        (2, 5) if cell == "longdoc" else (1, 1))
+    got, want = moe.hit_experts(*args, interpret=True), moe.every_expert(*args)
+    assert got.dtype == jnp.float32 and got.shape == want.shape
+    if hits == "none":
+        assert not np.asarray(got).any() and not np.asarray(want).any()
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol, rtol=tol)
+
+
+def test_the_kernel_inside_a_scan_whose_carry_is_donated_state():
+    """As the chunk program holds it: the rows and a counter leaf are the
+    scan's carry, donated; each step routes the rows it was left, sums the
+    hit experts and counts them."""
+    t, here, *weights = routed_operands("reason", "some", jnp.float32)
+    pattern = here > 0
+
+    def chunk(routed, t, counted):
+        def step(carry, i):
+            t, counted = carry
+            # another three experts a step, the weights hung on the rows
+            now = jnp.where(jnp.roll(pattern, i, axis=1), 0.1 + jnp.abs(t[:, :1]), 0.0)
+            out = routed(t, now, *weights)
+            hit = jnp.sum(jnp.any(now > 0, axis=0), dtype=jnp.int32)
+            return ((t * 0.5 + out).astype(t.dtype), counted + hit), out[:, 0]
+        return jax.lax.scan(step, (t, counted), jnp.arange(6))
+
+    kernel = lambda *a: moe.hit_experts(*a, interpret=True)  # noqa: E731
+    want = chunk(moe.every_expert, t, jnp.zeros((), jnp.int32))
+    got = jax.jit(lambda *a: chunk(kernel, *a), donate_argnums=(0, 1))(
+        t + 0, jnp.zeros((), jnp.int32))
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+    assert int(got[0][1]) == 18
+
+
+def forced(x_shape, w_shape, mesh=None):
+    """The rule with everything but the tiling and the backend: what a tiny
+    model on the CPU needs to reach the kernel (interpreted)."""
+    return "kernel" if x_shape[1] == 1 and (mesh is None or mesh.size == 1) else "einsum"
+
+
+def test_experts_read_is_the_hit_experts_with_the_kernel_and_every_held_one_without(
+        layer, monkeypatch):
+    """The fourth counter, computed IN the step: ``experts_hit`` where the
+    kernel ran, the held count where the einsums did; the layer's answer is
+    the same either way. A share no row routes to (held experts whose router
+    rows are zeroed against rows that all score above zero elsewhere) gives
+    the shared expert alone."""
+    cfg, params, _, m, _ = layer
+    step = m.reshape(-1, 1, cfg.hidden_size)  # 18 rows, one token each: a decode step
+    want, counts = share(cfg, params, step, 4, 8, with_shared=True)
+    assert counts.tolist()[2:] == [int(counts[2]), 8] and 0 < int(counts[2]) <= 8
+    monkeypatch.setattr(moe, "lowering", forced)
+    got, kernel_counts = share(cfg, params, step, 4, 8, with_shared=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-5)
+    assert kernel_counts.tolist() == counts.tolist()[:3] + [int(counts[2])]
+    # no held expert hit: the router prefers every other expert on every row
+    router = jnp.abs(params[P + "mlp.gate.weight"]).at[4:12].multiply(-1.0)
+    unhit = dict(params, **{P + "mlp.gate.weight": router})
+    alone, none = share(cfg, unhit, jnp.abs(step), 4, 8, with_shared=True)
+    assert none.tolist() == [18 * cfg.top_k, 0, 0, 0]
+    shared_only = share(cfg, unhit, jnp.abs(step), 4, 8, with_shared=False)[0]
+    assert not np.asarray(shared_only).any() and np.abs(np.asarray(alone)).max() > 1e-3
+
+
+def test_the_rule_picks_the_kernel_for_the_two_cells_decode_steps_on_one_tpu_device(monkeypatch):
+    cells = [((64, 1, 3072), (128, 1024, 3072)), ((32, 1, 5120), (20, 1536, 5120))]
+    assert [moe.lowering(*c) for c in cells] == ["einsum", "einsum"]  # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert [moe.lowering(*c) for c in cells] == ["kernel", "kernel"]
+    one = make_mesh("dp=1", jax.devices()[:1])
+    assert [moe.lowering(*c, one) for c in cells] == ["kernel", "kernel"]
+    # up to the ridge's rows, where the step stops waiting for the weights
+    assert moe.lowering((256, 1, 3072), (128, 1024, 3072)) == "kernel"
+    assert moe.lowering((512, 1, 3072), (128, 1024, 3072)) == "einsum"
+
+
+NOT_THE_KERNEL = {
+    "an_admission": ((1, 16, 256), False),
+    "a_prefill_piece": ((1, 2048, 256), False),
+    "a_teacher_forced_forward": ((2, 9, 256), False),
+    "a_width_that_is_not_whole_tiles": ((8, 1, 192), False),
+    "rows_that_are_not_whole_tiles": ((3, 1, 256), False),
+    "more_rows_than_the_ridge": ((264, 1, 256), False),
+    "a_mesh_of_two_devices": ((8, 1, 256), True),
+}
+
+
+@pytest.mark.parametrize("case", NOT_THE_KERNEL)
+def test_every_other_caller_traces_the_parents_primitives_exactly(monkeypatch, case):
+    """With the backend steered to a TPU — the one condition a CPU run cannot
+    meet — each shape the rule leaves out traces to the same jaxpr, equation
+    for equation, as the parent's lines (with the counter they earn)."""
+    (b, s, d), meshed = NOT_THE_KERNEL[case]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = make_mesh("dp=2", jax.devices()[:2]) if meshed else None
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa: E731
+    args = (sds(b, s, d), sds(16, d), sds(6, 128, d), sds(6, 128, d), sds(6, d, 128))
+    kw = dict(top_k=3, held=(4, 6), routed_scale=2.5,
+              shared=(sds(128, d), sds(128, d), sds(d, 128)))
+    assert moe.lowering((b, s, d), (6, 128, d), mesh) == "einsum"
+    got = jax.make_jaxpr(lambda *a: moe.moe_share_ffn(*a[:5], **dict(kw, shared=a[5:]), mesh=mesh))(
+        *args, *kw["shared"])
+    want = jax.make_jaxpr(lambda *a: parents_layer(*a[:5], **dict(kw, shared=a[5:])))(
+        *args, *kw["shared"])
+    assert "pallas_call" not in str(got) and str(got) == str(want)
+    # the guard: the same call one condition nearer IS the kernel
+    if case == "a_mesh_of_two_devices":
+        assert "pallas_call" in str(jax.make_jaxpr(
+            lambda *a: moe.moe_share_ffn(*a[:5], **dict(kw, shared=a[5:])))(*args, *kw["shared"]))
+
+
+def test_the_pick_is_recorded_at_trace_time():
+    """Beside ``kv_write.*`` in ``/v1/trace``: a zero-length span a call
+    site, named for the lowering and rows x held experts."""
+    from modelx_tpu.utils.trace import tracer
+
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    args = (sds(7, 1, 24), sds(9, 24), sds(5, 8, 24), sds(5, 8, 24), sds(5, 24, 8))
+    jax.make_jaxpr(lambda *a: moe.moe_share_ffn(*a, top_k=2, held=(2, 5)))(*args)
+    assert tracer().summary("moe.")["moe.einsum[7x5]"]["count"] == 1
+
+
+# -- the engines, either lowering -------------------------------------------------------
+
+
+def laguna_dir(path):
+    cfg = laguna.LagunaConfig.tiny(vocab_size=64)
+    params = laguna.init_params(cfg, jax.random.PRNGKey(0))
+    st.write_safetensors(str(path / "model.safetensors"),
+                         laguna.to_hf_state_dict(params, first=cfg.expert_first))
+    (path / "config.json").write_text(json.dumps(laguna.to_hf_config(cfg)))
+    return cfg.mlp_layer_types.count("sparse"), cfg.expert_count
+
+
+def deepseek_v2_dir(path):
+    from modelx_tpu.models import deepseek_v2 as ds
+
+    # a held share (experts 4..12 of 16: groups 1 and 2 of 4), as the cell's chip holds one
+    cfg = dataclasses.replace(ds.DeepseekV2Config.tiny(vocab_size=64), expert_first=4,
+                              expert_count=8)
+    params = ds.init_params(cfg, jax.random.PRNGKey(0))
+    st.write_safetensors(str(path / "model.safetensors"),
+                         ds.to_hf_state_dict(params, first=cfg.expert_first))
+    (path / "config.json").write_text(json.dumps(ds.to_hf_config(cfg)))
+    return cfg.num_layers - cfg.first_k_dense_replace, cfg.expert_count
+
+
+@pytest.mark.parametrize("family,write", [("laguna", laguna_dir),
+                                          ("deepseek_v2", deepseek_v2_dir)])
+def test_a_tiny_engine_gives_the_same_greedy_tokens_with_either_lowering(
+        tmp_path, monkeypatch, family, write):
+    """Prompts through the engine's admit and chunk programs: the einsums'
+    tokens, then the kernel's from a new engine (its programs trace anew).
+    The engine that took the kernel read exactly the experts its steps hit;
+    the one that did not, every held expert of every sparse layer a step."""
+    layers, held = write(tmp_path)
+    server = ModelServer(str(tmp_path), mesh_spec="dp=1", dtype="float32", max_seq_len=64)
+    server.load()
+    assert server.family.name == family
+    prompts = np.random.default_rng(1).integers(1, 60, (3, 9)).astype(np.int32)
+
+    def run():
+        cb = ContinuousBatcher(server, max_slots=4, chunk_size=4)
+        try:
+            return np.asarray(cb.generate(prompts, max_new_tokens=20)), dict(cb.stats["moe"])
+        finally:
+            cb.close()
+
+    want, plain = run()
+    assert plain["experts_read"] % (layers * held * 4) == 0  # whole chunks of four steps
+    assert 0 < plain["experts_hit"] < plain["experts_read"]
+    monkeypatch.setattr(moe, "lowering", forced)
+    got, stats = run()
+    np.testing.assert_array_equal(got, want)
+    assert stats["experts_read"] == stats["experts_hit"] == plain["experts_hit"]
+    assert stats["assignments_held"] == plain["assignments_held"]

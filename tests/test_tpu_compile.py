@@ -40,6 +40,17 @@ def topo():
     compilation_cache.reset_cache()
 
 
+def _mosaic_calls(text: str) -> dict:
+    """Kernel name -> its Mosaic calls in a compiled program's text (the
+    compiler names the instruction for the kernel: ``%kv_write_rows.3 = ``)."""
+    calls: dict = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.search(r"%([A-Za-z_]\w*?)(?:\.\d+)* = ", line).group(1)
+            calls[name] = calls.get(name, 0) + 1
+    return calls
+
+
 def _qkv(sharding, seq, heads=32, kv_heads=8, head_dim=128, batch=1):
     q = jax.ShapeDtypeStruct((batch, heads, seq, head_dim), jnp.bfloat16, sharding=sharding)
     kv = jax.ShapeDtypeStruct((batch, kv_heads, seq, head_dim), jnp.bfloat16, sharding=sharding)
@@ -324,10 +335,12 @@ def test_ragged_decode_kernel_inside_a_scan_reads_the_cache_as_it_lies(topo, sha
 
 @pytest.mark.slow
 def test_lagunas_decode_step_takes_the_ragged_kernel_on_its_full_layers_only(topo, monkeypatch):
-    """The cell's decode step with the rule steered to a TPU (the compile
-    runs where ``default_backend`` says cpu): two Mosaic calls, one a full
-    layer — the three rings keep the reference — and the f32 logits over all
-    4096 positions are gone from the program."""
+    """The cell's decode step with the rules steered to a TPU (the compile
+    runs where ``default_backend`` says cpu): by the kernels' names, two
+    ragged attention calls, one a full layer — the three rings keep the
+    reference — ten cache writes, one a leaf, and the hit experts' kernel on
+    each of the four expert layers; the f32 logits over all 4096 positions
+    are gone from the program."""
     import json
 
     from modelx_tpu.models import laguna
@@ -353,7 +366,9 @@ def test_lagunas_decode_step_takes_the_ragged_kernel_on_its_full_layers_only(top
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, state, sds((64, 1), jnp.int32), sds((64,), jnp.int32)).compile()
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert _mosaic_calls(text) == {"ragged_decode_attention": 2, "kv_write_rows": 10,
+                                   "moe_hit_experts": cfg.mlp_layer_types.count("sparse")}
+    assert cfg.mlp_layer_types.count("sparse") == 4
     assert "f32[64,8,6,4096]" not in text and "f32[64,8,9,528]" in text
 
 
@@ -429,8 +444,10 @@ def test_the_cells_chunk_program_writes_every_leaf_in_the_kernel_in_place(topo, 
     cpu): one ``kv_write_rows`` call a written leaf — eight, and ten with
     Laguna's rings — beside the ragged attention's; the one ``while`` left is
     the scan (the parent's had one of ``slots`` trips a leaf around a 2 KB
-    update: the scatter); no cache leaf is copied; the state leaves the
-    program aliased to its input; temporaries not above the parent's."""
+    update: the scatter), and in Laguna's the hit experts' kernel on its four
+    expert layers; no cache leaf is copied; the state leaves the
+    program aliased to its input; temporaries not above the parent's (but for
+    Laguna's routed sum, a float32 ``[64, 3072]``)."""
     import math
     import re
 
@@ -445,9 +462,11 @@ def test_the_cells_chunk_program_writes_every_leaf_in_the_kernel_in_place(topo, 
     finally:
         engine.close()
     text, m = compiled.as_text(), compiled.memory_analysis()
-    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    assert len([c for c in calls if "kv_write_rows" in c]) == leaves
-    assert len([c for c in calls if "ragged_decode_attention" in c]) == len(calls) - leaves > 0
+    calls = _mosaic_calls(text)
+    # Mixtral's expert layer is ``moe_ffn``: no hit-experts kernel there
+    experts = {"moe_hit_experts": 4} if family == "laguna" else {}
+    assert calls == {"kv_write_rows": leaves, "ragged_decode_attention": leaves // 2 - (
+        3 if family == "laguna" else 0), **experts}
     assert len([line for line in text.splitlines() if " while(" in line]) == 1
     scattered = [line.strip()[:120] for line in text.splitlines()
                  if "scatter" in line and re.search(rf"bf16\[{slots},(\d\d\d+),8,128\]", line)]
@@ -458,7 +477,37 @@ def test_the_cells_chunk_program_writes_every_leaf_in_the_kernel_in_place(topo, 
     kv_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
                    for x in jax.tree_util.tree_leaves(state) if len(x.shape) == 4)
     assert kv_bytes <= m.alias_size_in_bytes < kv_bytes + 4096  # + tok, counters
-    assert m.temp_size_in_bytes <= PARENT_CHUNK_TEMP[family]
+    # the hit experts' kernel hands its float32 sum [64, 3072] to the shared expert's
+    # through HBM, where the einsums' fused into it: 0.8 MB beside 14.5 GB
+    routed_sum = 64 * 3072 * 4 if family == "laguna" else 0
+    assert m.temp_size_in_bytes <= PARENT_CHUNK_TEMP[family] + routed_sum
+
+
+# -- the expert layer's decode step (ops.moe.hit_experts) ---------------------------
+
+# rows, held experts, F, D -> blocks a matrix (gate and up, down)
+EXPERT_SHAPES = {"laguna-s-2.1-ep2-d5.reason": ((64, 128, 1024, 3072), (1, 1)),
+                 "deepseek-v2-ep8-d5.longdoc": ((32, 20, 1536, 5120), (2, 2)),
+                 "the_most_rows_the_rule_admits": ((256, 20, 1536, 5120), (2, 2))}
+
+
+@pytest.mark.parametrize("cell", EXPERT_SHAPES)
+def test_hit_experts_kernel_at_the_cells_widths(topo, cell):
+    """Mosaic takes the kernel at both cells' shapes: blocks of whole matrices
+    (6.3 MB) for Laguna, of half ones (7.9 MB) for DeepSeek-V2, six of them in
+    VMEM under the limit the call asks for; the stacks stay where they lie —
+    the program's only temporaries are the scalars' and the combine weights'."""
+    from modelx_tpu.ops import moe
+
+    (rows, e, f, d), blocks = EXPERT_SHAPES[cell]
+    assert (moe._chunks(f, d * 2), moe._chunks(d, f * 2)) == blocks and rows <= moe.ROWS_MAX
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    compiled = jax.jit(moe.hit_experts).lower(
+        sds((rows, d)), sds((rows, e), jnp.float32), sds((e, f, d)), sds((e, f, d)),
+        sds((e, d, f))).compile()
+    assert _mosaic_calls(compiled.as_text()) == {"moe_hit_experts": 1}
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20  # ... and not a row's more
 
 
 # -- deepseek_v2: the latent cache, the absorbed kernel, the piece program ----------
@@ -526,9 +575,10 @@ def test_deepseek_v2s_chunk_program_reads_each_latent_line_once_where_it_lies(to
     finally:
         engine.close()
     text, m = compiled.as_text(), compiled.memory_analysis()
-    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 5
-    assert all("dsv2.attn.attend" in c and "latent_decode_attention" in c for c in calls)
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line
+             and "latent_decode_attention" in line]
+    assert len(calls) == 5 and all("dsv2.attn.attend" in c for c in calls)
+    assert _mosaic_calls(text) == {"latent_decode_attention": 5, "moe_hit_experts": 4}
     relaid = [line.strip()[:120] for line in text.splitlines()
               if re.search(r"= bf16\[32,32768,640\]\S* (copy|transpose)\(", line)]
     assert not relaid, relaid
@@ -543,6 +593,32 @@ def test_deepseek_v2s_chunk_program_reads_each_latent_line_once_where_it_lies(to
     assert cache <= m.alias_size_in_bytes < cache + 4096
     assert m.temp_size_in_bytes < 64 * 2**20  # 16 MB: nothing of a leaf's or the scores' size
     assert len([line for line in text.splitlines() if " while(" in line]) == 1  # the scan
+
+
+def test_deepseek_v2s_chunk_program_reads_the_hit_experts_where_they_lie(topo, monkeypatch):
+    """The same program's expert layers (ISSUE 44): ``moe_hit_experts`` under
+    ``dsv2.moe.routed`` on each of the four, its three operands the held
+    stacks ``[20, 1536, 5120]`` / ``[20, 5120, 1536]`` as the checkpoint laid
+    them — none copied, transposed or re-laid whole — and no ``[20, 32, 1536]``
+    activation of every held expert left beside it."""
+    _, engine, params, sds = deepseek_v2_cell(topo, monkeypatch)
+    try:
+        compiled = engine._chunk_prog.jit.lower(
+            params, engine.kv.abstract_state(), sds((32, 1), jnp.int32),
+            *engine._chunk_args(False), n_steps=8).compile()
+    finally:
+        engine.close()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line
+             and "moe_hit_experts" in line]
+    assert len(calls) == 4 and all("dsv2.moe.routed" in c for c in calls)
+    assert all(c.count("bf16[20,1536,5120]") >= 2 and "bf16[20,5120,1536]" in c for c in calls)
+    moved = [line.strip()[:120] for line in text.splitlines()
+             if re.search(r"= bf16\[20,(1536,5120|5120,1536)\]\S* (copy|transpose|fusion)\(", line)]
+    assert not moved, moved
+    every = [line.strip()[:120] for line in text.splitlines()
+             if re.search(r"= bf16\[20,32,1536\]", line)]
+    assert not every, every
 
 
 def test_deepseek_v2s_piece_program_expands_a_key_block_at_a_time(topo, monkeypatch):
