@@ -35,6 +35,7 @@ from modelx_tpu.dl.sharding import (
     PHI3_RULES,
     LLAMA_RULES,
     MIXTRAL_RULES,
+    NEMOTRON_H_RULES,
     QWEN2_RULES,
     Rules,
     infer_family,
@@ -609,23 +610,30 @@ def _minicpm_sala_generate_ragged(params, tokens, row_lens, cfg, mesh=None,
 _UNTOLD = object()
 
 
-def _minicpm_sala_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import minicpm_sala
+def _state_decode_fns(family, cfg, mesh):
+    """``decode_fns`` of a family (its module) some of whose layers keep a
+    STATE in place of keys and values: a padded bucket's tail would enter the
+    states for good, so a caller that lands a block of prompt positions says
+    how many of them are real (None = all), as the continuous engine does."""
+    name = family.__name__.rpartition(".")[2]
 
     def fwd(p, t, kv_cache, cache_offset, mesh=mesh, valid_len=_UNTOLD, live=None):
-        # a padded bucket's tail would enter the lightning layers' states for
-        # good: a caller that lands a block of prompt positions says how many
-        # of them are real (None = all), as the continuous engine does
         if t.shape[1] > 1 and valid_len is _UNTOLD:
             raise ValueError(
-                "minicpm_sala: a block of prompt positions needs its rows' real "
+                f"{name}: a block of prompt positions needs its rows' real "
                 "lengths (a state keeps what a padded tail adds): this family "
                 "streams through --continuous-batch")
-        return minicpm_sala.forward(
+        return family.forward(
             p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh,
             valid_len=None if valid_len is _UNTOLD else valid_len, live=live)
 
-    return fwd, (lambda b, max_len: minicpm_sala.init_kv_cache(cfg, b, max_len))
+    return fwd, (lambda b, max_len: family.init_kv_cache(cfg, b, max_len))
+
+
+def _minicpm_sala_decode_fns(cfg, mesh=None):
+    from modelx_tpu.models import minicpm_sala
+
+    return _state_decode_fns(minicpm_sala, cfg, mesh)
 
 
 def _minicpm_sala_layer_kind_decode_fns(cfg, mesh=None):
@@ -720,6 +728,75 @@ def _deepseek_v2_layer_kind_decode_fns(cfg, mesh=None):
     }
 
 
+# -- nemotron_h -----------------------------------------------------------------
+
+
+def infer_nemotron_h_config(params: dict):
+    raise ValueError(
+        "a nemotron_h checkpoint's layer pattern, Mamba head and group counts, "
+        "routing and expert share leave no trace in tensor shapes: its "
+        "config.json must lie beside the weights")
+
+
+def nemotron_h_config_from_sidecar(sidecar: dict, params: dict):
+    from modelx_tpu.models import nemotron_h
+
+    return nemotron_h.config_from_hf(
+        sidecar, dtype=_act_dtype(params, "backbone.embeddings.weight"))
+
+
+def _nemotron_h_forward(params, tokens, cfg, mesh=None):
+    from modelx_tpu.models import nemotron_h
+
+    return nemotron_h.forward(params, tokens, cfg, mesh=mesh)[0]
+
+
+def _nemotron_h_generate(params, tokens, cfg, mesh=None, max_new_tokens=16):
+    from modelx_tpu.models import nemotron_h
+
+    return nemotron_h.greedy_generate(
+        params, tokens, cfg, max_new_tokens=max_new_tokens, mesh=mesh)
+
+
+def _nemotron_h_generate_ragged(params, tokens, row_lens, cfg, mesh=None,
+                                max_new_tokens=16, **sampling):
+    from modelx_tpu.models import nemotron_h
+
+    return nemotron_h.ragged_greedy_generate(
+        params, tokens, row_lens, cfg, max_new_tokens=max_new_tokens, mesh=mesh,
+        **sampling,
+    )
+
+
+def _nemotron_h_decode_fns(cfg, mesh=None):
+    from modelx_tpu.models import nemotron_h
+
+    return _state_decode_fns(nemotron_h, cfg, mesh)
+
+
+def _nemotron_h_layer_kind_decode_fns(cfg, mesh=None):
+    from modelx_tpu.models import nemotron_h
+
+    fwd, _ = _nemotron_h_decode_fns(cfg, mesh)
+    return {
+        "fwd": fwd,
+        "init_state": lambda slots, max_len: nemotron_h.init_layer_state(cfg, slots, max_len),
+        "kinds": nemotron_h.cache_kinds(cfg),
+        # what the decode step counts: of its expert layers over ALL slots (idle
+        # ones route too); of its rows, live ones and all, once a step
+        "counters": {"moe_counts": ("moe", nemotron_h.MOE_COUNTERS),
+                     "ssm_counts": ("ssm", nemotron_h.SSM_COUNTERS)},
+        "gauges": {"moe": {"held_experts": cfg.expert_count,
+                           "published_experts": cfg.num_experts,
+                           "sparse_layers": cfg.pattern.count(nemotron_h.EXPERTS),
+                           "latent_size": cfg.moe_latent_size},
+                   "ssm": {"layers": cfg.pattern.count(nemotron_h.MAMBA),
+                           "heads": cfg.mamba_heads, "head_dim": cfg.mamba_head_dim,
+                           "state_size": cfg.ssm_state_size, "groups": cfg.n_groups,
+                           "conv_kernel": cfg.conv_kernel}},
+    }
+
+
 # -- bert ---------------------------------------------------------------------
 
 
@@ -785,6 +862,11 @@ FAMILIES: dict[str, Family] = {
                           _deepseek_v2_generate_ragged, _deepseek_v2_decode_fns,
                           config_from_sidecar=deepseek_v2_config_from_sidecar,
                           layer_kind_decode_fns=_deepseek_v2_layer_kind_decode_fns),
+    "nemotron_h": Family("nemotron_h", NEMOTRON_H_RULES, infer_nemotron_h_config,
+                         _nemotron_h_forward, _nemotron_h_generate,
+                         _nemotron_h_generate_ragged, _nemotron_h_decode_fns,
+                         config_from_sidecar=nemotron_h_config_from_sidecar,
+                         layer_kind_decode_fns=_nemotron_h_layer_kind_decode_fns),
     "gpt2": Family("gpt2", GPT2_RULES, infer_gpt2_config, _gpt2_forward,
                    _gpt2_generate, _gpt2_generate_ragged, _gpt2_decode_fns,
                    _gpt2_paged_decode_fns),

@@ -503,8 +503,9 @@ class LayerKindKV(DenseKV):
     ring``; an ``"index"`` leaf ``[max_slots, max_len / n]`` holds one row per
     n positions (a sparse layer's compressed keys); a ``"state"`` leaf
     ``[max_slots, ...]`` has no position axis (a linear-attention layer's
-    running sum: ``put`` copies a whole state, ``view`` / ``put_piece`` hand
-    the slot's state to a prefill piece and take it back); a ``"latent"`` leaf
+    running sum, or a state-space layer's two — its recurrence's state and its
+    convolution's tail: ``put`` copies a whole state, ``view`` / ``put_piece``
+    hand the slot's state to a prefill piece and take it back); a ``"latent"`` leaf
     ``[max_slots, max_len, W]`` holds one compressed line a position in place
     of per-head keys and values (latent attention: laid and addressed as a
     full leaf — it carries ``--prefill-chunk`` — but written a row at a time

@@ -245,6 +245,30 @@ DEEPSEEK_V2_RULES: Rules = [
     (r".*", []),
 ]
 
+# Nemotron-H (models/nemotron_h.py): a Mamba layer's fused input projection
+# is three runs of rows (gate, convolved channels, step sizes) that a split of
+# its rows would cut across, so the Mamba projections, the convolution and the
+# per-head vectors are replicated (the deployment runs them data-parallel);
+# attention by head; the router at its published width, its choice bias and
+# the latent projections replicated; stacked experts over ep with their
+# features over tp; the shared expert and a dense layer's MLP (which the
+# stacked experts' patterns must not catch) by feature.
+NEMOTRON_H_RULES: Rules = [
+    (r"embeddings\.weight$", ["tp", None]),
+    (r"lm_head\.weight$", ["tp", None]),
+    (r"mixer\.(in_proj|out_proj)\.weight$", [None, None]),
+    (r"mixer\.(q|k|v)_proj\.weight$", ["tp", None]),
+    (r"mixer\.o_proj\.weight$", [None, "tp"]),
+    (r"mixer\.gate\.weight$", [None, None]),
+    (r"fc[12]_latent_proj\.weight$", [None, None]),
+    (r"mixer\.experts\.up_proj\.weight$", ["ep", "tp", None]),
+    (r"mixer\.experts\.down_proj\.weight$", ["ep", None, "tp"]),
+    (r"up_proj\.weight$", ["tp", None]),
+    (r"down_proj\.weight$", [None, "tp"]),
+    (r"norm(_f)?\.weight$", [None]),
+    (r".*", []),
+]
+
 DEFAULT_RULES: dict[str, Rules] = {
     "llama": LLAMA_RULES,
     "qwen2": QWEN2_RULES,
@@ -256,6 +280,7 @@ DEFAULT_RULES: dict[str, Rules] = {
     "laguna": LAGUNA_RULES,
     "minicpm_sala": MINICPM_SALA_RULES,
     "deepseek_v2": DEEPSEEK_V2_RULES,
+    "nemotron_h": NEMOTRON_H_RULES,
 }
 
 
@@ -271,6 +296,8 @@ def infer_family(tensor_names: Sequence[str]) -> str:
     joined = "\n".join(names)
     if "block_sparse_moe" in joined:
         return "mixtral"
+    if "mixer.in_proj" in joined or "mixer.experts" in joined:
+        return "nemotron_h"  # one mixer a layer: state-space, experts or attention
     if "self_attn.kv_a_proj_with_mqa" in joined:
         return "deepseek_v2"  # one compressed key-value line a position (latent attention)
     if "self_attn.g_proj" in joined:

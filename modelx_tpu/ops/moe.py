@@ -14,8 +14,11 @@ renormalized top-k MoE; under pressure, overflow tokens are dropped
 (combine weight 0) which is the standard capacity trade.
 
 A device that holds a SHARE of a layer's experts runs
-:func:`moe_share_ffn` instead: routing over the published width, the held
-experts' part of the sum only. Its routed sum has two lowerings of one
+:func:`moe_share_ffn` instead: routing over the published width (softmax
+scores, or sigmoid ones with a bias that only chooses), the held experts' part
+of the sum only — gated SwiGLU experts or two matrices with a squared relu
+between them, in the hidden width or in a latent one between a down- and an
+up-projection. Its routed sum has two lowerings of one
 algorithm whose cost differs with the shape (:func:`lowering`: no flag, no
 option, no model's name): thousands of rows hit every held expert and are
 bound by arithmetic — the dense einsums, every expert on every token; a decode
@@ -132,13 +135,22 @@ def moe_ffn(
 
 
 def route_topk(router_logits: jax.Array, k: int, *, renormalize: bool = True,
-               scale: float = 1.0, groups: tuple[int, int] | None = None) -> jax.Array:
+               scale: float = 1.0, groups: tuple[int, int] | None = None,
+               scoring: str = "softmax", choice_bias: jax.Array | None = None) -> jax.Array:
     """Exactly-k routing over the router's whole width: softmax over all E
     logits in float32, the k largest, their probabilities divided by their
     own sum (``renormalize``, HF ``norm_topk_prob``) and multiplied by
     ``scale`` (``moe_routed_scaling_factor``). router_logits: [T, E].
     Returns the combine weights [T, E], zero off the k chosen. Unlike
-    :func:`router_topk` a tie never admits a (k+1)-th expert.
+    :func:`router_topk` a tie never admits a (k+1)-th expert (the lower index
+    wins, ``top_k``'s rule).
+
+    ``scoring`` ``"sigmoid"`` scores each expert by itself (``sigmoid`` of its
+    logit, in float32) in place of the softmax over all. ``choice_bias``
+    ``[E]`` (HF ``e_score_correction_bias``) is added to the scores for the
+    CHOICE of the k alone: the combine weights are the unbiased scores of the
+    chosen, so a bias moves which experts a token gets and never how much of
+    them.
 
     ``groups`` ``(n_group, topk_group)`` is the group-limited routing of a
     deployment that keeps each group on one device (HF ``topk_method``
@@ -149,9 +161,16 @@ def route_topk(router_logits: jax.Array, k: int, *, renormalize: bool = True,
     lower index, as ``top_k``'s does), and the k experts are the best inside
     them — a token whose best expert lies in a dropped group does without it.
     The probabilities are those of the softmax over all E either way."""
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    eligible = probs
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"scoring {scoring!r} is neither softmax nor sigmoid")
+    if scoring == "softmax":
+        probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    else:
+        probs = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+    eligible = probs if choice_bias is None else probs + choice_bias.astype(jnp.float32)
     if groups is not None:
+        if scoring != "softmax" or choice_bias is not None:
+            raise ValueError("group-limited routing is implemented over plain softmax scores")
         n_group, topk_group = groups
         t, e = probs.shape
         if e % n_group or not 0 < topk_group <= n_group:
@@ -163,6 +182,8 @@ def route_topk(router_logits: jax.Array, k: int, *, renormalize: bool = True,
         # an ineligible expert sorts below every probability, zero included
         eligible = jnp.where(jnp.repeat(keep, e // n_group, axis=1), probs, -1.0)
     vals, idx = jax.lax.top_k(eligible, k)
+    if choice_bias is not None:
+        vals = jnp.take_along_axis(probs, idx, axis=-1)
     if renormalize:
         vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
     rows = jnp.arange(probs.shape[0])[:, None]
@@ -204,6 +225,10 @@ def moe_share_ffn(
     scopes: tuple[str, ...] = ("moe.routed", "moe.shared"),
     groups: tuple[int, int] | None = None,
     mesh=None,
+    scoring: str = "softmax",
+    choice_bias: jax.Array | None = None,
+    form: str = "swiglu",
+    latent: tuple[jax.Array, jax.Array] | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """An expert layer that is told which experts it holds.
 
@@ -219,6 +244,18 @@ def moe_share_ffn(
     is another device's, and nothing here stands in for it. ``shared``
     (gate, up, down in torch Linear layout) is an always-on SwiGLU expert
     added ungated beside the routed sum.
+
+    Three static arguments say what kind of layer it is (the defaults are the
+    layer above). ``scoring`` / ``choice_bias``: the router's scores
+    (:func:`route_topk`: ``"sigmoid"``, and a bias that only chooses).
+    ``form`` ``"relu2"``: an expert is TWO matrices with a squared relu
+    between them, ``w_down relu(w_up x)^2`` — ``w_gate`` is None, and so is
+    ``shared``'s gate. ``latent`` ``(down [L, D], up [D, L])``: the experts
+    live in a width ``L`` of their own — every token goes through ``down``
+    before them and the routed sum through ``up`` after them (``w_up``
+    ``[E_held, F, L]``, ``w_down`` ``[E_held, L, F]``), while the router and
+    the shared expert read the hidden state itself. The chip up-projects its
+    own partial sum; scopes four and five name the two projections.
 
     Drop-free and exact, with no capacity, sort or dynamic shape, in two
     lowerings of one sum (:func:`lowering` picks, from the shapes, the backend
@@ -238,14 +275,17 @@ def moe_share_ffn(
     b, s, d = x.shape
     e_pub = router_w.shape[0]
     first, count = held if held is not None else (0, e_pub)
-    if count != w_gate.shape[0] or first < 0 or first + count > e_pub:
+    if count != w_up.shape[0] or first < 0 or first + count > e_pub:
         raise ValueError(
             f"held experts {first}..{first + count} of {e_pub} do not match the "
-            f"{w_gate.shape[0]} stacked expert weights given")
+            f"{w_up.shape[0]} stacked expert weights given")
+    if form not in ("swiglu", "relu2") or (w_gate is None) != (form == "relu2"):
+        raise ValueError(f"expert form {form!r} with{'out' if w_gate is None else ''} a gate")
     cons = constrain if constrain is not None else (lambda arr, *spec: arr)
     t = x.reshape(b * s, d)
     f32 = jnp.float32
-    how = lowering(x.shape, w_gate.shape, mesh)
+    # the width the experts read is their matrices': a latent's, else the tokens' own
+    how = lowering((b, s, w_up.shape[2]), w_up.shape, mesh)
     with trace.span(f"moe.{how}[{b * s}x{count}]"):
         pass  # at TRACE time, once a call site: which lowering a program compiled with
     # a third scope, where given, names the routing apart from the experts' products
@@ -253,23 +293,34 @@ def moe_share_ffn(
         logits = jax.lax.dot_general(t, router_w, (((1,), (1,)), ((), ())),
                                      preferred_element_type=f32)  # [T, E_pub]
         combine = route_topk(logits, top_k, renormalize=renormalize, scale=routed_scale,
-                             groups=groups)
+                             groups=groups, scoring=scoring, choice_bias=choice_bias)
         here = jax.lax.slice_in_dim(combine, first, first + count, axis=1)  # [T, E_held]
         hit = here > 0
         n_hit = jnp.sum(jnp.any(hit, axis=0), dtype=jnp.int32)
         counts = jnp.stack([jnp.int32(b * s * top_k), jnp.sum(hit, dtype=jnp.int32), n_hit,
                             n_hit if how == "kernel" else jnp.int32(count)])
+    tokens = t
+    if latent is not None:
+        with jax.named_scope(scopes[3] if len(scopes) > 3 else scopes[0]):
+            tokens = _linear(t, latent[0])  # [T, L]
     with jax.named_scope(scopes[0]):
         if how == "kernel":
             # off the TPU only a test that steers ``lowering`` comes here
-            out = hit_experts(t, here, w_gate, w_up, w_down,
+            out = hit_experts(tokens, here, w_gate, w_up, w_down,
                               interpret=jax.default_backend() != "tpu")
         else:
-            out = every_expert(t, here, w_gate, w_up, w_down, cons)
+            out = every_expert(tokens, here, w_gate, w_up, w_down, cons)
+    if latent is not None:
+        with jax.named_scope(scopes[4] if len(scopes) > 4 else scopes[0]):
+            out = jax.lax.dot_general(out.astype(x.dtype), latent[1], (((1,), (1,)), ((), ())),
+                                      preferred_element_type=f32)
     if shared is not None:
         with jax.named_scope(scopes[1]):
             sg, su, sd = shared
-            hs = jax.nn.silu(_linear(t, sg)) * _linear(t, su)
+            if form == "relu2":
+                hs = jnp.square(jax.nn.relu(_linear(t, su)))
+            else:
+                hs = jax.nn.silu(_linear(t, sg)) * _linear(t, su)
             out = out + jax.lax.dot_general(hs, sd, (((1,), (1,)), ((), ())),
                                             preferred_element_type=f32)
     return cons(out.astype(x.dtype).reshape(b, s, d), "dp", "sp", None), counts
@@ -279,14 +330,17 @@ def every_expert(t, here, w_gate, w_up, w_down, constrain=None):
     """The routed sum of :func:`moe_share_ffn` as dense einsums: every held
     expert on every token, the combine weights ``here`` ``[T, E_held]`` (zero
     where a row did not choose an expert) picking its part. Operands as
-    :func:`hit_experts`'; returns ``[T, D]`` float32."""
+    :func:`hit_experts`'; ``w_gate`` None: experts of two matrices with a
+    squared relu between them. Returns ``[T, D]`` float32."""
     f32 = jnp.float32
-    g = jnp.einsum("td,efd->etf", t, w_gate, preferred_element_type=f32).astype(t.dtype)
+    if w_gate is not None:
+        g = jnp.einsum("td,efd->etf", t, w_gate, preferred_element_type=f32).astype(t.dtype)
     u = jnp.einsum("td,efd->etf", t, w_up, preferred_element_type=f32).astype(t.dtype)
+    act = jnp.square(jax.nn.relu(u)) if w_gate is None else jax.nn.silu(g) * u
     # the combine weight goes on the hidden activation, so that the down
     # projection contracts experts and features at once ([T, E*F] x
     # [E*F, D]) and no [E, T, D] block of per-expert outputs exists
-    h = ((jax.nn.silu(g) * u).astype(f32) * here.T[:, :, None]).astype(t.dtype)
+    h = (act.astype(f32) * here.T[:, :, None]).astype(t.dtype)
     if constrain is not None:
         h = constrain(h, "ep", None, "tp")
     return jnp.einsum("etf,edf->td", h, w_down, preferred_element_type=f32)
@@ -300,17 +354,23 @@ def _chunks(rows: int, row_bytes: int) -> int:
                 if n == tiles or tiles % n == 0 and rows // n * row_bytes <= BLOCK_BYTES)
 
 
-def _hit_experts_kernel(ids_ref, n_ref, x_ref, here_ref, gate_ref, up_ref, down_ref, out_ref,
-                        g_ref, h_ref, *, n_f: int, n_d: int):
+def _hit_experts_kernel(ids_ref, n_ref, x_ref, here_ref, *refs, n_f: int, n_d: int,
+                        gated: bool):
     """Grid ``(place, step)``: place ``i`` is the ``i``-th hit expert
-    (``ids_ref[i]``), its steps the ``n_f`` runs of the gate's rows, the
-    ``n_f`` of the up's, the ``n_d`` of the down's — one block of weights a
-    step, the next one on its way meanwhile. ``out_ref`` ``[T, D]`` float32
-    stays in VMEM over the whole grid and is written home once."""
+    (``ids_ref[i]``), its steps the ``n_f`` runs of the gate's rows (a gated
+    expert's only), the ``n_f`` of the up's, the ``n_d`` of the down's — one
+    block of weights a step, the next one on its way meanwhile. ``out_ref``
+    ``[T, D]`` float32 stays in VMEM over the whole grid and is written home
+    once."""
+    if gated:
+        gate_ref, up_ref, down_ref, out_ref, g_ref, h_ref = refs
+    else:
+        up_ref, down_ref, out_ref, h_ref = refs
     place, step = pl.program_id(0), pl.program_id(1)
     live = place < n_ref[0]
     nt = (((1,), (1,)), ((), ()))  # rows x [N, K]: both contract their last axis
-    fc, dc = g_ref.shape[1] // n_f, out_ref.shape[1] // n_d
+    fc, dc = h_ref.shape[1] // n_f, out_ref.shape[1] // n_d
+    ups = n_f if gated else 0  # the step the up's runs start at
     f32 = jnp.float32
 
     @pl.when((place == 0) & (step == 0))
@@ -318,12 +378,13 @@ def _hit_experts_kernel(ids_ref, n_ref, x_ref, here_ref, gate_ref, up_ref, down_
         out_ref[...] = jnp.zeros_like(out_ref)
 
     for c in range(n_f):
-        @pl.when(live & (step == c))
-        def _(c=c):
-            g = jax.lax.dot_general(x_ref[...], gate_ref[0], nt, preferred_element_type=f32)
-            g_ref[:, c * fc:(c + 1) * fc] = g.astype(g_ref.dtype)
+        if gated:
+            @pl.when(live & (step == c))
+            def _(c=c):
+                g = jax.lax.dot_general(x_ref[...], gate_ref[0], nt, preferred_element_type=f32)
+                g_ref[:, c * fc:(c + 1) * fc] = g.astype(g_ref.dtype)
 
-        @pl.when(live & (step == n_f + c))
+        @pl.when(live & (step == ups + c))
         def _(c=c):
             u = jax.lax.dot_general(x_ref[...], up_ref[0], nt, preferred_element_type=f32)
             # the expert's column of the combine weights, picked by a mask: [T, 1]
@@ -331,13 +392,18 @@ def _hit_experts_kernel(ids_ref, n_ref, x_ref, here_ref, gate_ref, up_ref, down_
             w = jnp.sum(jnp.where(lane == ids_ref[place], here_ref[...], 0.0), axis=1,
                         keepdims=True)
             # the einsums' roundings, one an operation: g, u, silu(g), their product
+            # (two matrices: u, relu(u), its square)
             dtype = h_ref.dtype
-            act = jax.nn.silu(g_ref[:, c * fc:(c + 1) * fc].astype(f32)).astype(dtype)
-            gated = (act.astype(f32) * u.astype(dtype).astype(f32)).astype(dtype)
-            h_ref[:, c * fc:(c + 1) * fc] = (gated.astype(f32) * w).astype(dtype)
+            if gated:
+                act = jax.nn.silu(g_ref[:, c * fc:(c + 1) * fc].astype(f32)).astype(dtype)
+                act = (act.astype(f32) * u.astype(dtype).astype(f32)).astype(dtype)
+            else:
+                act = jax.nn.relu(u.astype(dtype)).astype(f32)
+                act = (act * act).astype(dtype)
+            h_ref[:, c * fc:(c + 1) * fc] = (act.astype(f32) * w).astype(dtype)
 
     for c in range(n_d):
-        @pl.when(live & (step == 2 * n_f + c))
+        @pl.when(live & (step == ups + n_f + c))
         def _(c=c):
             out_ref[:, c * dc:(c + 1) * dc] += jax.lax.dot_general(
                 h_ref[...], down_ref[0], nt, preferred_element_type=f32)
@@ -348,9 +414,10 @@ def hit_experts(t, here, w_gate, w_up, w_down, *, interpret: bool = False):
     chose, as one Pallas kernel. t ``[T, D]``; here ``[T, E_held]`` float32,
     the combine weights (zero where a row did not choose an expert); w_gate /
     w_up ``[E_held, F, D]``, w_down ``[E_held, D, F]``. Returns ``[T, D]``
-    float32: sum over experts of ``(silu(t g^T) * (t u^T) * here[:, e]) d^T``,
-    bf16 operands and float32 accumulation as the einsums have them, the
-    experts' parts added in float32 in the order of their indices.
+    float32: sum over experts of ``(silu(t g^T) * (t u^T) * here[:, e]) d^T``
+    — ``w_gate`` None: of ``(relu(t u^T)^2 * here[:, e]) d^T``, experts of two
+    matrices — bf16 operands and float32 accumulation as the einsums have
+    them, the experts' parts added in float32 in the order of their indices.
 
     The hit experts' indices go first in a list of ``E_held`` places (the
     tail repeats the last one) and reach the kernel as prefetched scalars
@@ -361,10 +428,12 @@ def hit_experts(t, here, w_gate, w_up, w_down, *, interpret: bool = False):
     double-buffered by the pipeline; each operand's index moves one step
     before the step that needs it, so one copy is in flight at any time."""
     rows, d = t.shape
-    e, f = w_gate.shape[:2]
-    item = w_gate.dtype.itemsize
+    e, f = w_up.shape[:2]
+    item = w_up.dtype.itemsize
+    gated = w_gate is not None
     n_f, n_d = _chunks(f, d * item), _chunks(d, f * item)
-    steps = 2 * n_f + n_d
+    ups = n_f if gated else 0
+    steps = ups + n_f + n_d
     hit = jnp.any(here > 0, axis=0)
     n_hit = jnp.sum(hit, dtype=jnp.int32)
     order = jnp.argsort(~hit, stable=True).astype(jnp.int32)
@@ -387,22 +456,24 @@ def hit_experts(t, here, w_gate, w_up, w_down, *, interpret: bool = False):
 
     whole = lambda place, step, ids, n_hit: (0, 0)  # noqa: E731
     block_bytes = max(f // n_f * d, d // n_d * f) * item
+    weights = [pl.BlockSpec((1, f // n_f, d), block(ups, n_f)),
+               pl.BlockSpec((1, d // n_d, f), block(ups + n_f, n_d))]
+    if gated:
+        weights.insert(0, pl.BlockSpec((1, f // n_f, d), block(0, n_f)))
     return pl.pallas_call(
-        functools.partial(_hit_experts_kernel, n_f=n_f, n_d=n_d),
+        functools.partial(_hit_experts_kernel, n_f=n_f, n_d=n_d, gated=gated),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(e, steps),
-            in_specs=[pl.BlockSpec((rows, d), whole), pl.BlockSpec((rows, e), whole),
-                      pl.BlockSpec((1, f // n_f, d), block(0, n_f)),
-                      pl.BlockSpec((1, f // n_f, d), block(n_f, n_f)),
-                      pl.BlockSpec((1, d // n_d, f), block(2 * n_f, n_d))],
+            in_specs=[pl.BlockSpec((rows, d), whole), pl.BlockSpec((rows, e), whole), *weights],
             out_specs=pl.BlockSpec((rows, d), whole),
-            scratch_shapes=[pltpu.VMEM((rows, f), t.dtype), pltpu.VMEM((rows, f), t.dtype)]),
+            scratch_shapes=[pltpu.VMEM((rows, f), t.dtype)] * (2 if gated else 1)),
         out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=6 * block_bytes + (16 << 20)),
         interpret=interpret, name="moe_hit_experts",
-    )(ids, n_hit[None], t, here.astype(jnp.float32), w_gate, w_up, w_down)
+    )(ids, n_hit[None], t, here.astype(jnp.float32),
+      *((w_gate, w_up, w_down) if gated else (w_up, w_down)))
 
 
 def load_balancing_loss(router_logits: jax.Array, mask: jax.Array) -> jax.Array:

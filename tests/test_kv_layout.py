@@ -517,6 +517,89 @@ class TestStateAndIndexLeaves:
                                     "steps_all", "steps_kernel")] == [24, 100, 1, 2, 1]
 
 
+class TestTwoStateLeavesALayer:
+    """``LayerKindKV`` over a family whose state-space layers keep TWO leaves
+    a row with no position axis (nemotron_h: the recurrence's float32 state and
+    the convolution's tail), whose one attention layer keeps keys and values,
+    and whose expert layers cache nothing."""
+
+    @pytest.fixture(scope="class")
+    def kv(self):
+        from modelx_tpu.models import nemotron_h
+
+        cfg = nemotron_h.NemotronHConfig.tiny(vocab_size=64)  # M E M * E
+        server = types.SimpleNamespace(mesh=make_mesh("dp=1", jax.devices()[:1]),
+                                       family=FAMILIES["nemotron_h"], cfg=cfg)
+        fwd, init_cache = server.family.decode_fns(cfg, mesh=server.mesh)
+        return kv_layout.build(server, fwd, init_cache, {}, max_slots=SLOTS, max_len=MAX_LEN,
+                               chunk_size=4, page_size=0, max_live_tokens=0,
+                               paged_attention="gather", prefill_chunk=16)
+
+    def test_both_state_leaves_are_counted_and_the_expert_layers_have_none(self, kv):
+        state = kv.new_state()
+        assert kv.kinds == {"s0": "state", "t0": "state", "s2": "state", "t2": "state",
+                            "k3": "full", "v3": "full", "moe_counts": "counter",
+                            "ssm_counts": "counter"}
+        assert state["s0"].shape == (SLOTS, 8, 4, 8) and state["s0"].dtype == jnp.float32
+        assert state["t2"].shape == (SLOTS, 3, 64) and state["k3"].shape == (SLOTS, MAX_LEN, 16)
+        stats = kv.stats["kv"]
+        assert stats["bytes_state"] == 2 * SLOTS * (8 * 4 * 8 + 3 * 64) * 4  # states AND tails
+        assert stats["bytes_full"] == 2 * SLOTS * MAX_LEN * 16 * 4 and stats["bytes_window"] == 0
+        assert kv.has_state and kv.counter_rows == 4 + 3
+        assert kv.stats["ssm"] == {"layers": 2, "heads": 8, "head_dim": 4, "state_size": 8,
+                                   "groups": 2, "conv_kernel": 4}
+        assert kv.stats["moe"]["latent_size"] == 16
+
+    def test_an_admissions_scratch_lands_state_and_tail_whole(self, kv):
+        small = scratch(kv, 5, 32)
+        state = jax.jit(kv.put)(kv.new_state(), small, kv.at(2))
+        for name, kind in kv.kinds.items():
+            if kind == "counter":
+                continue
+            got, want = np.asarray(state[name]), np.asarray(small[name])[0]
+            assert not got[[0, 1, 3]].any()
+            if kind == "state":
+                np.testing.assert_array_equal(got[2], want)
+            else:
+                np.testing.assert_array_equal(got[2, :32], want)
+
+    def test_a_piece_is_handed_state_and_tail_and_gives_them_back(self, kv):
+        rng = np.random.RandomState(3)
+        state = {n: (x if kv.kinds[n] == "counter" else
+                     jnp.asarray(rng.standard_normal(x.shape).astype(x.dtype)))
+                 for n, x in kv.new_state().items()}
+        row = jax.jit(lambda c, w: kv.view(c, w, 32))(state, kv.at(1))
+        assert set(row) == set(kv.kinds) - {"moe_counts", "ssm_counts"}
+        assert row["s0"].shape == (1, 8, 4, 8) and row["t0"].shape == (1, 3, 64)
+        assert row["k3"].shape == (1, 32, 16)
+        after = jax.jit(kv.put_piece)(state, {n: x + 1 for n, x in row.items()}, kv.at(1))
+        for name in ("s0", "t0", "s2", "t2"):
+            got, was = np.asarray(after[name]), np.asarray(state[name])
+            np.testing.assert_array_equal(got[[0, 2, 3]], was[[0, 2, 3]])
+            np.testing.assert_array_equal(got[1], was[1] + 1)
+
+    def test_both_counter_leaves_ride_home_a_row_an_entry(self, kv):
+        state = dict(kv.new_state(), moe_counts=jnp.asarray([66, 16, 7, 8], jnp.int32),
+                     ssm_counts=jnp.asarray([3, 4, 90], jnp.int32))
+        out = np.asarray(kv.ride(state, jnp.zeros((SLOTS, 5), jnp.int32)))
+        assert out.shape == (SLOTS + 7, 5)
+        kv._last.clear()
+        kv.landed(out)
+        assert [kv.stats["ssm"][k] for k in ("steps_live", "steps_all", "positions_live")] == [3, 4, 90]
+        assert [kv.stats["moe"][k] for k in ("assignments", "assignments_held", "experts_hit",
+                                             "experts_read")] == [66, 16, 7, 8]
+
+    @pytest.mark.parametrize("asked,message", [
+        ({"page_size": 16}, "--kv-page-size"), ({"prefix_cache": 4}, "--prefix-cache"),
+        ({"speculative_k": 2}, "--speculative-k")])
+    def test_what_a_state_cannot_carry_is_refused_and_chunked_prefill_is_not(self, asked, message):
+        base = dict(page_size=0, prefix_cache=None, prefill_chunk=16, speculative_k=0)
+        kinds = ("state", "full", "counter")
+        with pytest.raises(kv_layout.Refused, match=message):
+            kv_layout.LayerKindKV.refuse("nemotron_h", kinds, **dict(base, **asked))
+        kv_layout.LayerKindKV.refuse("nemotron_h", kinds, **base)
+
+
 class TestLatentLeaves:
     """``LayerKindKV`` over a family whose layers cache one compressed line a
     position (deepseek_v2): laid and addressed as a full leaf — it carries

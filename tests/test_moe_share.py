@@ -277,6 +277,24 @@ def test_the_kernel_gives_the_einsums_sum(monkeypatch, cell, hits, dtype):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol, rtol=tol)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("hits", HITS)
+def test_the_kernel_gives_the_einsums_sum_for_experts_of_two_matrices(hits, dtype):
+    """``w_gate`` None: up, a squared relu, down (PR 46) — the same places,
+    two steps a place where a gated expert takes three."""
+    t, here, _, w_up, w_down = routed_operands("reason", hits, jnp.dtype(dtype))
+    got = moe.hit_experts(t, here, None, w_up, w_down, interpret=True)
+    want = moe.every_expert(t, here, None, w_up, w_down)
+    gated = moe.every_expert(t, here, w_up, w_up, w_down)
+    assert got.dtype == jnp.float32 and got.shape == want.shape
+    if hits == "none":
+        assert not np.asarray(got).any() and not np.asarray(want).any()
+    else:
+        assert np.abs(np.asarray(want) - np.asarray(gated)).max() > 1e-2  # another function
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol, rtol=tol)
+
+
 def test_the_kernel_inside_a_scan_whose_carry_is_donated_state():
     """As the chunk program holds it: the rows and a counter leaf are the
     scan's carry, donated; each step routes the rows it was left, sums the
@@ -341,6 +359,8 @@ def test_the_rule_picks_the_kernel_for_the_two_cells_decode_steps_on_one_tpu_dev
     one = make_mesh("dp=1", jax.devices()[:1])
     assert [moe.lowering(*c, one) for c in cells] == ["kernel", "kernel"]
     # up to the ridge's rows, where the step stops waiting for the weights
+    # a latent expert layer is asked at the width its experts read (PR 46)
+    assert moe.lowering((64, 1, 1024), (128, 2688, 1024)) == "kernel"
     assert moe.lowering((256, 1, 3072), (128, 1024, 3072)) == "kernel"
     assert moe.lowering((512, 1, 3072), (128, 1024, 3072)) == "einsum"
 

@@ -137,6 +137,25 @@ FAMILY_SPEC_CASES = {
         ("model.layers.0.mlp.up_proj.weight", PartitionSpec("tp", None)),
         ("lm_head.weight", PartitionSpec("tp", None)),
     ],
+    "nemotron_h": [
+        # a Mamba layer's fused projection is three runs of rows: whole, with its per-head vectors
+        ("backbone.layers.0.mixer.in_proj.weight", PartitionSpec(None, None)),
+        ("backbone.layers.0.mixer.out_proj.weight", PartitionSpec(None, None)),
+        ("backbone.layers.0.mixer.conv1d.weight", PartitionSpec()),
+        ("backbone.layers.0.mixer.A_log", PartitionSpec()),
+        ("backbone.layers.0.mixer.norm.weight", PartitionSpec(None)),
+        ("backbone.layers.1.mixer.gate.weight", PartitionSpec(None, None)),  # the router, whole
+        ("backbone.layers.1.mixer.fc1_latent_proj.weight", PartitionSpec(None, None)),
+        ("backbone.layers.1.mixer.experts.up_proj.weight", PartitionSpec(None, "tp", None)),  # no ep axis here
+        ("backbone.layers.1.mixer.experts.down_proj.weight", PartitionSpec(None, None, "tp")),
+        # two dimensions: the stacked experts' patterns must not catch them
+        ("backbone.layers.1.mixer.shared_experts.up_proj.weight", PartitionSpec("tp", None)),
+        ("backbone.layers.1.mixer.shared_experts.down_proj.weight", PartitionSpec(None, "tp")),
+        ("backbone.layers.7.mixer.q_proj.weight", PartitionSpec("tp", None)),
+        ("backbone.layers.7.mixer.o_proj.weight", PartitionSpec(None, "tp")),
+        ("backbone.embeddings.weight", PartitionSpec("tp", None)),
+        ("lm_head.weight", PartitionSpec("tp", None)),
+    ],
 }
 
 

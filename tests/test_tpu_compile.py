@@ -488,7 +488,9 @@ def test_the_cells_chunk_program_writes_every_leaf_in_the_kernel_in_place(topo, 
 # rows, held experts, F, D -> blocks a matrix (gate and up, down)
 EXPERT_SHAPES = {"laguna-s-2.1-ep2-d5.reason": ((64, 128, 1024, 3072), (1, 1)),
                  "deepseek-v2-ep8-d5.longdoc": ((32, 20, 1536, 5120), (2, 2)),
-                 "the_most_rows_the_rule_admits": ((256, 20, 1536, 5120), (2, 2))}
+                 "the_most_rows_the_rule_admits": ((256, 20, 1536, 5120), (2, 2)),
+                 # experts of two matrices (no gate) in a latent width: up, relu2, down
+                 "nemotron-3-super-ep4-d11.agent": ((64, 128, 2688, 1024), (1, 1))}
 
 
 @pytest.mark.parametrize("cell", EXPERT_SHAPES)
@@ -503,8 +505,9 @@ def test_hit_experts_kernel_at_the_cells_widths(topo, cell):
     assert (moe._chunks(f, d * 2), moe._chunks(d, f * 2)) == blocks and rows <= moe.ROWS_MAX
     one = SingleDeviceSharding(topo.devices[0])
     sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    gate = None if cell.startswith("nemotron") else sds((e, f, d))
     compiled = jax.jit(moe.hit_experts).lower(
-        sds((rows, d)), sds((rows, e), jnp.float32), sds((e, f, d)), sds((e, f, d)),
+        sds((rows, d)), sds((rows, e), jnp.float32), gate, sds((e, f, d)),
         sds((e, d, f))).compile()
     assert _mosaic_calls(compiled.as_text()) == {"moe_hit_experts": 1}
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20  # ... and not a row's more
