@@ -294,11 +294,10 @@ def ragged_block(cache_len: int, kv_heads: int) -> int:
     return block
 
 
-def _ragged_decode_kernel(len_ref, q_ref, k_ref, *refs, block: int, sm_scale: float,
-                          value_lanes: int = 0):
-    """One (row, KV block) program of :func:`decode_attention`. ``refs``: v_ref
-    (absent where ``value_lanes`` says the values are the keys' first lanes),
-    row_head_ref, col_head_ref, col_pos_ref, o_ref, m_ref, l_ref, acc_ref.
+def _ragged_decode_kernel(len_ref, q_ref, k_ref, v_ref, row_head_ref, col_head_ref,
+                          col_pos_ref, o_ref, m_ref, l_ref, acc_ref, *, block: int,
+                          sm_scale: float):
+    """One (row, KV block) program of :func:`decode_attention`.
 
     q_ref [rows, d]: every query head of the row. k_ref / v_ref [block *
     kv_heads, d]: one block of the row's cache as it lies, a line a (position,
@@ -311,8 +310,6 @@ def _ragged_decode_kernel(len_ref, q_ref, k_ref, *refs, block: int, sm_scale: fl
     (m, l, acc) lives in scratch across the row's blocks; a block past the
     row's last does nothing (its index map pointed at the last one, so nothing
     was copied for it either)."""
-    v_ref, *refs = (None, *refs) if value_lanes else refs
-    row_head_ref, col_head_ref, col_pos_ref, o_ref, m_ref, l_ref, acc_ref = refs
     row, j = pl.program_id(0), pl.program_id(1)
     length = len_ref[row]
     last = (length - 1) // block
@@ -338,10 +335,8 @@ def _ragged_decode_kernel(len_ref, q_ref, k_ref, *refs, block: int, sm_scale: fl
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # a latent line is key and value at once: copied once, contracted twice
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(k_ref.dtype if v_ref is None else v_ref.dtype),
-            k_ref[:, :value_lanes] if v_ref is None else v_ref[...], (((1,), (0,)), ((), ())),
+            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -351,7 +346,7 @@ def _ragged_decode_kernel(len_ref, q_ref, k_ref, *refs, block: int, sm_scale: fl
 
 
 def decode_attention(q, k_cache, v_cache, lengths, scale: float | None = None, *,
-                     block: int = 0, interpret: bool = False, value_lanes: int = 0):
+                     block: int = 0, interpret: bool = False):
     """One decode step's attention, each row over its own context only.
 
     q [B, 1, H, D]; k_cache / v_cache [B, L, Hkv, D] as the engine keeps them
@@ -363,14 +358,7 @@ def decode_attention(q, k_cache, v_cache, lengths, scale: float | None = None, *
     the last one masked by position; what lies past it is neither copied nor
     computed (the grid spans all ``L / block`` blocks, the index map holds at
     the row's last). ``block`` 0 takes :func:`ragged_block`'s. Algebraically
-    the softmax of :func:`attention_reference`, not bit-identical to it.
-
-    ``v_cache`` None with ``value_lanes`` n: the values are the first n lanes
-    of the keys (a latent-attention cache in the absorbed form: one line a
-    position is key and value, ``ops/latent_attention``) — each block is
-    copied once and contracted twice, and the result is [B, 1, H, n]."""
-    if (v_cache is None) != bool(value_lanes):
-        raise ValueError("values are either a cache of their own or the keys' first lanes")
+    the softmax of :func:`attention_reference`, not bit-identical to it."""
     b, _, hq, d = q.shape
     cache_len, hkv = k_cache.shape[1:3]
     block = block or ragged_block(cache_len, hkv)
@@ -388,30 +376,27 @@ def decode_attention(q, k_cache, v_cache, lengths, scale: float | None = None, *
     def kv_index(i, j, lens):
         return i, jnp.minimum(j, (lens[i] - 1) // block), 0
 
-    d_out = value_lanes or d
-    per_row = lambda width: pl.BlockSpec((None, rows, width), lambda i, j, lens: (i, 0, 0))
+    per_row = pl.BlockSpec((None, rows, d), lambda i, j, lens: (i, 0, 0))
     kv_block = pl.BlockSpec((None, cols, d), kv_index)
     whole = lambda *shape: pl.BlockSpec(shape, lambda i, j, lens: (0, 0))
-    caches = [c.reshape(b, cache_len * hkv, d) for c in (k_cache, v_cache) if c is not None]
     out = pl.pallas_call(
         functools.partial(_ragged_decode_kernel, block=block,
-                          sm_scale=scale if scale is not None else 1.0 / math.sqrt(d),
-                          **({"value_lanes": value_lanes} if value_lanes else {})),
+                          sm_scale=scale if scale is not None else 1.0 / math.sqrt(d)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b, cache_len // block),
-            in_specs=[per_row(d), *[kv_block] * len(caches),
+            in_specs=[per_row, kv_block, kv_block,
                       whole(rows, 1), whole(1, cols), whole(1, cols)],
-            out_specs=per_row(d_out),
+            out_specs=per_row,
             scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
                             pltpu.VMEM((rows, 1), jnp.float32),
-                            pltpu.VMEM((rows, d_out), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, rows, d_out), q.dtype),
+                            pltpu.VMEM((rows, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-        name="latent_decode_attention" if value_lanes else "ragged_decode_attention",
-    )(lengths, q, *caches, row_head, col % hkv, col // hkv)
-    return out[:, :hq].reshape(b, 1, hq, d_out)
+        interpret=interpret, name="ragged_decode_attention",
+    )(lengths, q, k_cache.reshape(b, cache_len * hkv, d),
+      v_cache.reshape(b, cache_len * hkv, d), row_head, col % hkv, col // hkv)
+    return out[:, :hq].reshape(b, 1, hq, d)
 
 
 _ragged_calls = threading.local()

@@ -1,15 +1,24 @@
 #!/usr/bin/env python
-"""Time the ragged decode-attention kernel against ``attention_reference`` on
-the chip, alone: one layer's cached attention at a cell's shapes, rows at the
-contexts the cell's traffic gives, inside a ``lax.scan`` as the engine's chunk
-program holds it. One JSON line a case; nothing here is an end-to-end number.
+"""Time the decode-attention kernels against their ``jnp`` forms on the chip,
+alone: one layer's cached attention at a cell's shapes, rows at the contexts
+the cell's traffic gives, inside a ``lax.scan`` as the engine's chunk program
+holds it. One JSON line a case; nothing here is an end-to-end number.
 
     chiprun -- python3 scripts/bench_decode_attention.py
     chiprun -- python3 scripts/bench_decode_attention.py --blocks 256,512,1024
+    chiprun -- python3 scripts/bench_decode_attention.py --shapes deepseek-latent \
+        --blocks 1024,2048 --parent .parent
 
 ``ms`` is one call's device time by the host's clock (a scan of ``--steps``
 calls, divided); ``gbps`` the bytes of the blocks the rows' contexts reach (the
-reference: of the whole cache) over it. Refuses to run without a TPU.
+reference: of the whole cache) over it. The ``deepseek-latent`` shape is the
+latent decode kernel (``ops.latent_attention.decode_kernel``) against
+``absorbed_reference``: ``hbm_share`` the bytes of the 640-lane lines its
+blocks read over the HBM peak, ``mxu_share`` the operations
+``benchmark.bytes_deepseek_v2.latent_attention_step`` counts for the rows'
+contexts over the bf16 peak; ``--parent DIR`` adds the kernel of a checkout
+that still widens the ragged one (``decode_attention(value_lanes=)``, PRs
+43–46) as a line. Refuses to run without a TPU.
 """
 
 from __future__ import annotations
@@ -41,8 +50,104 @@ def contexts(rng, rows, live, prompts, outputs, cache_len):
     return np.minimum(lengths, cache_len)
 
 
+# rows, heads, line width, value lanes, cache length, contexts: the ``.longdoc``
+# cell's one layer (32 rows that all decode 17-24 k into a 32 k cache)
+LATENT = {"deepseek-latent": (32, 128, 640, 512, 32768, (17 * 1024, 24 * 1024))}
+
+
+def timed(fn, carry, operands, steps, reps):
+    """ms a call of ``fn(carry, *operands) -> carry's shape``: a scan of
+    ``steps`` calls, each one's first operand hanging on the one before."""
+    import jax
+
+    @jax.jit
+    def run(carry, *operands):
+        def body(carry, _):
+            return (carry + fn(carry, *operands) * 1e-3).astype(carry.dtype), None
+        return jax.lax.scan(body, carry, None, length=steps)[0]
+
+    run(carry, *operands).block_until_ready()
+    best = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        run(carry, *operands).block_until_ready()
+        best = min(best, time.perf_counter() - t0)
+    return best / steps * 1e3
+
+
+def parents_kernel(root):
+    """``decode_attention`` of the checkout at ``root``, which takes
+    ``value_lanes``: the latent kernel as PRs 43-46 ran it."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "parents_attention", os.path.join(root, "modelx_tpu", "ops", "attention.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.decode_attention
+
+
+def bench_latent(args, name, device, rng) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import bytes_deepseek_v2
+    from modelx_tpu.ops import latent_attention as latent
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "peaks.json")) as f:
+        peaks = json.load(f)[device.device_kind]
+    rows, heads, width, rank, cache_len, (lo, hi) = LATENT[name]
+    cfg = {"num_attention_heads": heads, "kv_lora_rank": rank, "qk_rope_head_dim": 64}
+    scale = 0.1147
+    keys = jax.random.split(jax.random.PRNGKey(args.seed), 2)
+    q = jax.random.normal(keys[0], (rows, heads, width), jnp.bfloat16)
+    cache = jax.random.normal(keys[1], (rows, cache_len, width), jnp.bfloat16)
+    pad = lambda out: jnp.pad(out, ((0, 0), (0, 0), (0, width - rank)))  # noqa: E731
+    impls = {}
+    for block in (int(b) for b in args.blocks.split(",")):
+        if cache_len % block or cache_len < 2 * block:
+            continue
+        impls[f"kernel[{block}]"] = (block, lambda q, c, n, block=block: latent.decode_kernel(
+            q, c, n, scale, rank, block=block))
+    if args.parent:
+        old = parents_kernel(args.parent)
+        impls["parent[1024]"] = (1024, lambda q, c, n: old(
+            q[:, None], c.reshape(rows, cache_len, 1, width), None, n, scale, block=1024,
+            value_lanes=rank)[:, 0])
+    ref = lambda q, c, n: latent.absorbed_reference(q, c, n - 1, scale, rank)  # noqa: E731
+    cases = {"traffic": rng.integers(lo, hi + 1, rows).astype(np.int32),
+             "full": np.full(rows, cache_len, np.int32), "one": np.ones(rows, np.int32)}
+    for case, lengths in cases.items():
+        lens = jnp.asarray(lengths)
+        want = jax.jit(ref)(q, cache, lens).astype(jnp.float32)
+        need = bytes_deepseek_v2.latent_attention_step(cfg, rows, float(lengths.mean()))
+        line = {"shape": name, "case": case, "mean_context": float(lengths.mean()),
+                "device_kind": device.device_kind}
+
+        def shares(ms, positions):
+            return {"ms": round(ms, 4),
+                    "hbm_share": round(positions * width * 2 / (ms * 1e-3)
+                                       / peaks["hbm_bytes_per_s"], 4),
+                    "mxu_share": round(need["flops"] / (ms * 1e-3) / peaks["bf16_flops"], 4)}
+
+        ms = timed(lambda q, c, n: pad(ref(q, c, n)), q, (cache, lens), args.steps, args.reps)
+        print(json.dumps({**line, "impl": "jnp", **shares(ms, rows * cache_len)}), flush=True)
+        for impl, (block, fn) in impls.items():
+            err = float(jnp.abs(jax.jit(fn)(q, cache, lens).astype(jnp.float32) - want).max())
+            ms = timed(lambda q, c, n: pad(fn(q, c, n)), q, (cache, lens), args.steps, args.reps)
+            steps = int((-(-lengths // block)).sum())
+            print(json.dumps({**line, "impl": impl, **shares(ms, steps * block), "steps": steps,
+                              "read_share": round(steps * block / int(lengths.sum()), 4),
+                              "max_abs_err": err}), flush=True)
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--shapes", default=",".join([*SHAPES, *LATENT]))
+    ap.add_argument("--parent", default="", help="a checkout whose decode_attention takes "
+                    "value_lanes: its latent kernel becomes a line of the latent shape")
     ap.add_argument("--blocks", default="256,512,1024")
     ap.add_argument("--steps", type=int, default=64)
     ap.add_argument("--reps", type=int, default=5)
@@ -62,23 +167,13 @@ def main() -> int:
     rng = np.random.default_rng(args.seed)
     t = lambda x: x.transpose(0, 2, 1, 3)
 
-    def timed(fn, q, k, v, lengths):
-        @jax.jit
-        def run(q, k, v, lengths):
-            def body(q, _):  # each call's query hangs on the one before
-                out = fn(q, k, v, lengths)
-                return (q + out * 1e-3).astype(q.dtype), None
-            return jax.lax.scan(body, q, None, length=args.steps)[0]
-
-        run(q, k, v, lengths).block_until_ready()
-        best = float("inf")
-        for _ in range(args.reps):
-            t0 = time.perf_counter()
-            run(q, k, v, lengths).block_until_ready()
-            best = min(best, time.perf_counter() - t0)
-        return best / args.steps * 1e3
-
+    shapes = args.shapes.split(",")
+    for name in shapes:
+        if name in LATENT:
+            bench_latent(args, name, device, rng)
     for name, (rows, hq, hkv, d, cache_len, live, prompts, outputs) in SHAPES.items():
+        if name not in shapes:
+            continue
         keys = jax.random.split(jax.random.PRNGKey(args.seed), 3)
         q = jax.random.normal(keys[0], (rows, 1, hq, d), jnp.bfloat16)
         k = jax.random.normal(keys[1], (rows, cache_len, hkv, d), jnp.bfloat16)
@@ -94,7 +189,7 @@ def main() -> int:
             ref = lambda q, k, v, n: t(attn.attention_reference(
                 t(q), t(k), t(v), causal=True, q_offset=n - 1))
             want = ref(q, k, v, lens).astype(jnp.float32)
-            ms = timed(ref, q, k, v, lens)
+            ms = timed(ref, q, (k, v, lens), args.steps, args.reps)
             line = {"shape": name, "case": case, "impl": "reference", "ms": round(ms, 4),
                     "mean_context": float(lengths.mean()),
                     "gbps": round(rows * cache_len * position_bytes / ms / 1e6, 1),
@@ -105,7 +200,7 @@ def main() -> int:
                     continue
                 fn = lambda q, k, v, n: attn.decode_attention(q, k, v, n, block=block)
                 err = float(jnp.abs(fn(q, k, v, lens).astype(jnp.float32) - want).max())
-                ms = timed(fn, q, k, v, lens)
+                ms = timed(fn, q, (k, v, lens), args.steps, args.reps)
                 read = int((-(-lengths // block) * block).sum())
                 print(json.dumps({**line, "impl": f"ragged[{block}]", "ms": round(ms, 4),
                                   "gbps": round(read * position_bytes / ms / 1e6, 1),

@@ -152,7 +152,7 @@ def test_the_ops_agree_at_rows_of_different_depths():
         p /= p.sum(-1, keepdims=True)
         assert np.abs(np.einsum("hk,khd->hd", p, kv[..., dn:]) - np.asarray(want[row, 0])).max() < 1e-5
     assert latent_ops.absorbed_takes_kernel(cache.shape, r) == (0, False)  # the CPU, narrow lanes
-    assert latent_ops.absorbed_takes_kernel((32, 32768, 640), 512, "ragged")[0] == 1024
+    assert latent_ops.absorbed_takes_kernel((32, 32768, 640), 512, "ragged")[0] == 2048
 
 
 def test_a_prompt_landed_in_pieces_is_the_prompt_landed_whole(model):

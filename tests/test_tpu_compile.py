@@ -544,10 +544,11 @@ def deepseek_v2_cell(topo, monkeypatch):
 
 
 def test_the_absorbed_kernel_at_the_cells_widths(topo):
-    """Mosaic takes the widened ragged kernel at the ``.longdoc`` cell's
+    """Mosaic takes the latent decode kernel at the ``.longdoc`` cell's
     shapes — 32 rows, 128 query heads over ONE line of 640 lanes a position,
-    blocks of 1,024 positions, the values the line's first 512 lanes: no
-    second operand, the cache where it lies, a temporary for nothing."""
+    blocks of 2,048 positions on a flat grid whose bound is traced, the values
+    the line's first 512 lanes: no second operand, the cache where it lies, a
+    temporary for nothing but the two tables of 512 steps."""
     from modelx_tpu.ops import latent_attention as latent
 
     one = SingleDeviceSharding(topo.devices[0])
