@@ -579,6 +579,8 @@ class LayerKindKV(DenseKV):
         # counter leaf -> (the stats block it feeds, its entries' names)
         self.counters: dict[str, tuple[str, tuple]] = fns.get("counters", {})
         self.counter_rows = sum(len(names) for _, names in self.counters.values())
+        # the name the family's forward asks the decode kernels by (a test's)
+        self.attention_impl: str = fns.get("attention_impl", "auto")
         shapes = jax.eval_shape(self._zeros)
         rings = {shapes[n].shape[1] for n, kind in self.kinds.items() if kind == "window"}
         self.ring = rings.pop() if rings else max_len
@@ -642,8 +644,30 @@ class LayerKindKV(DenseKV):
         self._held.pop(slot, None)
         self._count()
 
+    @functools.cached_property
+    def ring_reads(self) -> tuple[int, int]:
+        """(window layers whose decode step reads its ring in the ring decode
+        kernel, window layers) — asked of the ring leaves as :attr:`row_writes`
+        is of the written ones: ``ops.attention.decode_block``'s rule is
+        static, and the engine's step is one token a row at an offset a row."""
+        from modelx_tpu.ops import attention
+
+        shapes = jax.eval_shape(self._zeros)
+        rings = [shapes[name] for name, kind in self.kinds.items() if kind == "window"]
+        kernel = [leaf for leaf in rings if attention.decode_block(
+            leaf.shape, leaf.dtype.itemsize, ring=True, impl=self.attention_impl,
+            mesh=self.mesh)]
+        return len(kernel) // 2, len(rings) // 2  # a layer's ring is two leaves
+
     def landed(self, toks: np.ndarray) -> None:
+        """Beside ``DenseKV``'s: ``attn_ring_kernel_calls`` / ``attn_ring_calls``
+        grow by steps x :attr:`ring_reads` (nothing rides for them either; they
+        do not exist where no ring takes the kernel), then the counter leaves."""
         super().landed(toks)
+        if self.ring_reads[0]:
+            for key, layers in zip(("attn_ring_kernel_calls", "attn_ring_calls"),
+                                   self.ring_reads):
+                self.stats[key] = self.stats.get(key, 0) + (toks.shape[1] - 1) * layers
         # the counters wrap at 32 bits on the device: what is added here is
         # each one's growth since the block before, taken modulo 2**32
         row = self.max_slots

@@ -398,13 +398,14 @@ def _attention(q, k, v, cfg: LagunaConfig, layer: int, ctx: ShardingCtx, cache,
     No cache: flash on a TPU, else the reference. A cache: the new keys and
     values are written (``ops.kv_write.write_rows``, full layers and rings
     alike), then ``ops.attention.cached_attention`` picks by what
-    it observes — a full layer's decode step (one token a row at per-row
-    offsets over ``[slots, max_len]``, 128-wide heads, on one TPU device) takes
-    the ragged kernel and reads each row's KV blocks up to its own context; a
-    ring (``key_positions``: full after its length, nothing to skip), a window
-    over a dense cache, an admission's prefill and the CPU keep
+    it observes — a decode step (one token a row at per-row offsets, 128-wide
+    heads, on one TPU device) takes a kernel: a full layer's over ``[slots,
+    max_len]`` the ragged one, which reads each row's KV blocks up to its own
+    context, a window layer's over its ring the ring one, which reads each
+    ring once where it lies and masks by each index's age; a window over a
+    dense cache, an admission's prefill and the CPU keep
     ``attention_reference``. ``attention_impl`` ``"ragged"``
-    (``"ragged+interpret"`` on the CPU) asks for the kernel by name."""
+    (``"ragged+interpret"`` on the CPU) asks for the kernels by name."""
     window = cfg.window(layer)
     t = lambda x: x.transpose(0, 2, 1, 3)
     if cache is None:
@@ -420,22 +421,17 @@ def _attention(q, k, v, cfg: LagunaConfig, layer: int, ctx: ShardingCtx, cache,
             out = attn_ops.attention_reference(t(q), t(k), t(v), causal=True, window=window)
         return t(out), None
     ck, cv = cache
-    key_positions = None
-    if ring and window:
-        if q.shape[1] != 1:  # static shape: fails clearly at trace time
-            raise ValueError(f"a ring cache decodes one token a step (got {q.shape[1]})")
-        # ring index r holds the newest position <= the query's that is
-        # congruent to r; one that would be negative holds nothing yet
+    rings = ring and bool(window)  # a full layer's leaf is dense under ``ring`` too
+    if rings:  # one token a step: ``cached_attention`` refuses a longer block
         length = ck.shape[1]
         offset = jnp.broadcast_to(jnp.asarray(cache_offset, jnp.int32), (q.shape[0],))
         ck = write_rows(ck, k, offset % length, ctx.mesh)
         cv = write_rows(cv, v, offset % length, ctx.mesh)
-        key_positions = offset[:, None] - (offset[:, None] - jnp.arange(length)[None, :]) % length
     else:
         ck = write_rows(ck, k, cache_offset, ctx.mesh)
         cv = write_rows(cv, v, cache_offset, ctx.mesh)
     out = attn_ops.cached_attention(q, ck, cv, cache_offset, impl=attention_impl,
-                                    mesh=ctx.mesh, window=window, key_positions=key_positions)
+                                    mesh=ctx.mesh, window=window, ring=rings)
     return out, (ck, cv)
 
 

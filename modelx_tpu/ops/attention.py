@@ -58,6 +58,12 @@ FLASH_ROW_TILE = 16
 RAGGED_COLUMNS = 2048
 # fewest positions a block may hold where nobody asked for the kernel by name
 RAGGED_MIN_BLOCK = 128
+# most bytes of keys and values one ring may hold for the ring decode kernel,
+# whose block is the whole ring: compiled for a described v5e under 72 query
+# heads, 4.26 MB (1,040 positions of 8 KV heads of 128 in bf16) fits Mosaic's
+# own VMEM limit — both double-buffered beside the f32 logits — and 8.45 MB
+# (2,064) does not. Laguna's 528 positions are 2.16 MB
+RING_BLOCK_BYTES = 9 << 19
 
 
 # -- reference (jnp) ----------------------------------------------------------
@@ -294,6 +300,29 @@ def ragged_block(cache_len: int, kv_heads: int) -> int:
     return block
 
 
+def _fold_block(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, sm_scale: float, visible):
+    """One block of a row's cache folded into its online-softmax state: the
+    arithmetic both decode kernels share. q_ref [rows, d]: every query head of
+    the row. k_ref / v_ref [columns, d]: the block as it lies, a line a
+    (position, KV head) pair. One contraction gives every query head against
+    every pair — operands as they are, f32 logits — ``visible()`` [rows,
+    columns] says which of them count, and (m, l, acc), f32 in scratch, take
+    the block in; ``p`` meets the values in their own dtype."""
+    s = jax.lax.dot_general(
+        q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale  # [rows, columns]
+    s = jnp.where(visible(), s, NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
 def _ragged_decode_kernel(len_ref, q_ref, k_ref, v_ref, row_head_ref, col_head_ref,
                           col_pos_ref, o_ref, m_ref, l_ref, acc_ref, *, block: int,
                           sm_scale: float):
@@ -322,27 +351,31 @@ def _ragged_decode_kernel(len_ref, q_ref, k_ref, v_ref, row_head_ref, col_head_r
 
     @pl.when(j <= last)
     def _():
-        s = jax.lax.dot_general(
-            q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [rows, block * kv_heads]
         # block j <= last holds position j * block < length under every KV
         # head, so each real row's running max is real from its first block
-        visible = (row_head_ref[...] == col_head_ref[...]) & (
-            col_pos_ref[...] < length - j * block)
-        s = jnp.where(visible, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        _fold_block(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, sm_scale, lambda: (
+            row_head_ref[...] == col_head_ref[...]) & (col_pos_ref[...] < length - j * block))
 
     @pl.when(j == last)
     def _():
         o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _head_rows(q, hkv: int, block: int):
+    """What both decode kernels make of q [B, 1, H, D] over blocks of ``block``
+    positions of ``hkv`` KV heads: q as [B, rows, D], H padded to whole packed
+    bf16 tiles of 16 rows; ``rows``; and the three index vectors of a block's
+    mask — each row's KV head [rows, 1] (a pad row matches none), each
+    column's KV head and its position in the block [1, block * hkv]."""
+    b, _, hq, d = q.shape
+    rows = -(-hq // FLASH_ROW_TILE) * FLASH_ROW_TILE
+    q = q.reshape(b, hq, d)
+    if rows != hq:  # a packed bf16 tile is 16 rows; pad rows match no KV head
+        q = jnp.pad(q, ((0, 0), (0, rows - hq), (0, 0)))
+    row_head = np.full((rows, 1), -1, np.int32)
+    row_head[:hq, 0] = np.arange(hq) // (hq // hkv)
+    col = np.arange(block * hkv, dtype=np.int32)[None]
+    return q, rows, (row_head, col % hkv, col // hkv)
 
 
 def decode_attention(q, k_cache, v_cache, lengths, scale: float | None = None, *,
@@ -364,14 +397,9 @@ def decode_attention(q, k_cache, v_cache, lengths, scale: float | None = None, *
     block = block or ragged_block(cache_len, hkv)
     if not block or cache_len % block:
         raise ValueError(f"no block of {block} positions tiles a cache of {cache_len}")
-    rows, cols = -(-hq // FLASH_ROW_TILE) * FLASH_ROW_TILE, block * hkv
+    cols = block * hkv
     lengths = jnp.clip(lengths.astype(jnp.int32), 1, cache_len)
-    q = q.reshape(b, hq, d)
-    if rows != hq:  # a packed bf16 tile is 16 rows; pad rows match no KV head
-        q = jnp.pad(q, ((0, 0), (0, rows - hq), (0, 0)))
-    row_head = np.full((rows, 1), -1, np.int32)
-    row_head[:hq, 0] = np.arange(hq) // (hq // hkv)
-    col = np.arange(cols, dtype=np.int32)[None]
+    q, rows, heads = _head_rows(q, hkv, block)
 
     def kv_index(i, j, lens):
         return i, jnp.minimum(j, (lens[i] - 1) // block), 0
@@ -395,7 +423,80 @@ def decode_attention(q, k_cache, v_cache, lengths, scale: float | None = None, *
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret, name="ragged_decode_attention",
     )(lengths, q, k_cache.reshape(b, cache_len * hkv, d),
-      v_cache.reshape(b, cache_len * hkv, d), row_head, col % hkv, col // hkv)
+      v_cache.reshape(b, cache_len * hkv, d), *heads)
+    return out[:, :hq].reshape(b, 1, hq, d)
+
+
+def ring_key_positions(offsets, length: int):
+    """[B, length] int: the absolute position each index of a ring of
+    ``length`` holds for a query at ``offsets`` [B], position p written at ``p
+    mod length`` — index r holds the newest position <= the query's that is
+    congruent to r, and one that would be negative holds nothing yet. Index r
+    is ``(offset - r) mod length`` positions old: what
+    :func:`_ring_decode_kernel` masks by, from the same two numbers."""
+    return offsets[:, None] - (offsets[:, None] - jnp.arange(length)[None, :]) % length
+
+
+def _ring_decode_kernel(off_ref, q_ref, k_ref, v_ref, row_head_ref, col_head_ref,
+                        col_pos_ref, o_ref, m_ref, l_ref, acc_ref, *, length: int,
+                        window: int, sm_scale: float):
+    """One row of :func:`ring_decode_attention`: the whole ring is the row's
+    one block, folded by :func:`_fold_block` into a fresh state. Ring index r
+    is ``age = (offset - r) mod length`` positions old; it counts iff ``age <
+    window`` (inside the window) and ``age <= offset`` (it has been written:
+    ``key_positions >= 0``). The modulo is taken once, on the scalar."""
+    offset = off_ref[pl.program_id(0)]
+    newest = jax.lax.rem(offset, length)  # the ring index of the query's own position
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def visible():
+        age = newest - col_pos_ref[...]
+        age = jnp.where(age < 0, age + length, age)  # [1, columns]
+        return (row_head_ref[...] == col_head_ref[...]) & (
+            (age < window) & (age <= offset))
+
+    _fold_block(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, sm_scale, visible)
+    o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def ring_decode_attention(q, k_ring, v_ring, offsets, window: int,
+                          scale: float | None = None, *, interpret: bool = False):
+    """One decode step's sliding-window attention over ring caches, each ring
+    read once where it lies.
+
+    q [B, 1, H, D] at positions ``offsets`` [B]; k_ring / v_ring [B, L, Hkv, D],
+    position p at index ``p mod L``, the query's own already written (viewed
+    ``[B, L * Hkv, D]``: the same bytes, no transpose, no copy). A grid step a
+    row, the row's whole ring its one block — a ring is full after L positions,
+    there is nothing to skip, and 528 = 16 x 33 has no power-of-two block for
+    :func:`ragged_block` to find — masked by each index's age
+    (:func:`_ring_decode_kernel`): :func:`attention_reference` under ``window``
+    and :func:`ring_key_positions`, algebraically, not bit for bit. Returns
+    [B, 1, H, D] in q's dtype."""
+    b, _, hq, d = q.shape
+    length, hkv = k_ring.shape[1:3]
+    q, rows, heads = _head_rows(q, hkv, length)
+    cols = length * hkv
+    per_row = lambda n: pl.BlockSpec((None, n, d), lambda i, offs: (i, 0, 0))  # noqa: E731
+    whole = lambda *shape: pl.BlockSpec(shape, lambda i, offs: (0, 0))  # noqa: E731
+    out = pl.pallas_call(
+        functools.partial(_ring_decode_kernel, length=length, window=window,
+                          sm_scale=scale if scale is not None else 1.0 / math.sqrt(d)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b,),
+            in_specs=[per_row(rows), per_row(cols), per_row(cols),
+                      whole(rows, 1), whole(1, cols), whole(1, cols)],
+            out_specs=per_row(rows),
+            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret, name="ring_decode_attention",
+    )(offsets.astype(jnp.int32), q, k_ring.reshape(b, cols, d), v_ring.reshape(b, cols, d),
+      *heads)
     return out[:, :hq].reshape(b, 1, hq, d)
 
 
@@ -424,39 +525,80 @@ def kv_positions(calls: list, lengths):
     return jnp.stack([read, lengths.shape[0] * sum(n for _, n in calls)]).astype(jnp.int32)
 
 
+def decode_block(cache_shape: tuple, itemsize: int = 2, *, ring: bool = False,
+                 impl: str = "auto", mesh: Mesh | None = None) -> int:
+    """Positions a block of the Pallas kernel that takes a decode step — one
+    query a row at an offset a row, no ``logit_softcap`` — over a cache ``[B,
+    L, Hkv, D]``; 0 where the step is :func:`attention_reference`'s. From
+    shapes, the backend and the mesh alone, so that the engine's layout can
+    ask it of its leaves without tracing (a stored program is never traced).
+
+    Plain causal attention over a dense cache (:func:`decode_attention`):
+    :func:`ragged_block`'s block where it is ``RAGGED_MIN_BLOCK`` positions or
+    more. A window over a ring, ``ring`` (:func:`ring_decode_attention`): the
+    whole ring, where its keys and values fit ``RING_BLOCK_BYTES``. Both ask
+    for heads of a multiple of 128, whole tiles of KV heads, the TPU backend
+    and one device (a bare Mosaic call cannot be partitioned); ``impl``
+    ``"ragged"`` (``"ragged+interpret"`` on the CPU) asks for the kernel by
+    name wherever it can run at all."""
+    cache_len, hkv, d = cache_shape[1:]
+    if ring:
+        block, fits = cache_len, 2 * cache_len * hkv * d * itemsize <= RING_BLOCK_BYTES
+    else:
+        block = ragged_block(cache_len, hkv)
+        fits = block >= RAGGED_MIN_BLOCK
+    if impl.partition("+")[0] == "ragged":
+        return block
+    if (fits and d % 128 == 0 and hkv % 8 == 0 and jax.default_backend() == "tpu"
+            and (mesh is None or mesh.size == 1)):
+        return block
+    return 0
+
+
 def cached_attention(q, k_cache, v_cache, q_offset, *, impl: str = "auto",
                      mesh: Mesh | None = None, scale: float | None = None,
-                     logit_softcap: float = 0.0, window: int = 0, key_positions=None):
+                     logit_softcap: float = 0.0, window: int = 0, ring: bool = False):
     """Causal attention of q [B, S, H, D] (positions ``q_offset`` onwards)
     against a KV cache [B, L, Hkv, D] that already holds their keys and
-    values. Returns [B, S, H, D]; the pick is recorded (:func:`note_choice`).
+    values. ``ring``: the cache is a ring under ``window`` — position p at
+    index ``p mod L``, one query a row. Returns [B, S, H, D]; the pick is
+    recorded (:func:`note_choice`).
 
-    Who takes the ragged kernel (:func:`decode_attention`) is read off the
-    inputs: one query a row, a per-row offset vector, plain causal attention
-    (no ``key_positions`` — a ring is full after its length, nothing to skip —
-    no ``window``, no ``logit_softcap``), heads of a multiple of 128, whole
-    tiles of KV heads, a cache that :func:`ragged_block` cuts in two or more
-    blocks of ``RAGGED_MIN_BLOCK`` positions or more,
-    the TPU backend, one device (a bare Mosaic call cannot be partitioned).
-    Everything else is :func:`attention_reference` as before. ``impl``
-    ``"ragged"`` (``"ragged+interpret"`` on the CPU) asks for the kernel by
-    name wherever it can run at all; any other name leaves the choice here."""
-    name, _, flag = impl.partition("+")
-    (_, qlen, hq, d), (cache_len, hkv) = q.shape, k_cache.shape[1:3]
-    plain = (qlen == 1 and jnp.ndim(q_offset) == 1 and key_positions is None
-             and not window and not logit_softcap)
-    block = ragged_block(cache_len, hkv) if plain else 0
-    if name != "ragged" and not (
-            block >= RAGGED_MIN_BLOCK and d % 128 == 0 and hkv % 8 == 0
-            and jax.default_backend() == "tpu" and (mesh is None or mesh.size == 1)):
-        block = 0
-    note_choice("ragged" if block else "reference", qlen, cache_len, mesh, group=hq // hkv)
-    if block:
+    Who takes a kernel is read off the inputs (:func:`decode_block`): one
+    query a row, a per-row offset vector, no ``logit_softcap``, and either
+    plain causal attention over a dense cache — the ragged kernel,
+    :func:`decode_attention`, each row's blocks up to its own context — or a
+    window over a ring, :func:`ring_decode_attention`, each ring once where it
+    lies. Everything else — a window over a dense cache, an admission's
+    prefill, a mesh, the CPU — is :func:`attention_reference` as before, a
+    ring's ``key_positions`` from :func:`ring_key_positions`. ``impl``
+    ``"ragged"`` (``"ragged+interpret"`` on the CPU) asks for the kernels by
+    name wherever they can run at all; any other name leaves the choice here."""
+    (b, qlen, hq, d), (cache_len, hkv) = q.shape, k_cache.shape[1:3]
+    if ring and (qlen != 1 or not window):  # static: fails clearly at trace time
+        raise ValueError(f"a ring cache decodes one token a step under a window "
+                         f"(got {qlen} under {window})")
+    block = 0
+    # a window is the ring kernel's over a ring, nobody's over a dense cache
+    if qlen == 1 and jnp.ndim(q_offset) == 1 and not logit_softcap and (ring or not window):
+        block = decode_block(k_cache.shape, k_cache.dtype.itemsize, ring=ring, impl=impl,
+                             mesh=mesh)
+    kernel = "reference" if not block else "ring" if ring else "ragged"
+    note_choice(kernel, qlen, cache_len, mesh, group=hq // hkv)
+    interpret = impl.partition("+")[2] == "interpret"
+    if kernel == "ring":
+        return ring_decode_attention(q, k_cache, v_cache, q_offset, window, scale,
+                                     interpret=interpret)
+    if kernel == "ragged":
         calls = getattr(_ragged_calls, "calls", None)
         if calls is not None:
             calls.append((block, cache_len))
         return decode_attention(q, k_cache, v_cache, q_offset + 1, scale, block=block,
-                                interpret=flag == "interpret")
+                                interpret=interpret)
+    key_positions = None
+    if ring:
+        key_positions = ring_key_positions(
+            jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (b,)), cache_len)
     t = lambda x: x.transpose(0, 2, 1, 3)
     return t(attention_reference(
         t(q), t(k_cache), t(v_cache), causal=True, q_offset=q_offset, scale=scale,
@@ -471,10 +613,10 @@ def note_choice(impl: str, sq: int, sk: int, mesh: Mesh | None = None,
     so ``/v1/trace`` (and ``MODELX_TRACE=1`` logs) show what ``impl="auto"``
     chose for each length without a new surface. ``group`` is query heads per
     KV head: the reference contracts them grouped (``+gqa4``) and the ragged
-    decode kernel masks each onto its own KV head; every other implementation
-    repeats the KV heads."""
+    and ring decode kernels mask each onto its own KV head; every other
+    implementation repeats the KV heads."""
     name = f"attention.{impl}[{sq}x{sk}]"
-    if impl in ("reference", "ragged") and group > 1:
+    if impl in ("reference", "ragged", "ring") and group > 1:
         name += f"+gqa{group}"
     if impl == "flash":
         pq, pk = flash_blocks(sq)[1], flash_blocks(sk)[1]

@@ -13,7 +13,13 @@ holds it. One JSON line a case; nothing here is an end-to-end number.
 calls, divided); ``gbps`` the bytes of the blocks the rows' contexts reach (the
 reference: of the whole cache) over it. The ``deepseek-latent`` shape is the
 latent decode kernel (``ops.latent_attention.decode_kernel``) against
-``absorbed_reference``: ``hbm_share`` the bytes of the 640-lane lines its
+``absorbed_reference``; the ``laguna-ring`` shape is the window layers' kernel
+(``ops.attention.ring_decode_attention``: 64 rings of 528 positions under 72
+query heads) against ``attention_reference`` under the ring's ``key_positions``
+— rings, query and offsets ALL on the scan's carry, each step writing its new
+line first as the engine's does (or the compiler lifts the reference's
+transposes out of the loop), ``hbm_share`` both leaves' bytes over the HBM
+peak. For the latent shape ``hbm_share`` is the bytes of the 640-lane lines its
 blocks read over the HBM peak, ``mxu_share`` the operations
 ``benchmark.bytes_deepseek_v2.latent_attention_step`` counts for the rows'
 contexts over the bf16 peak; ``--parent DIR`` adds the kernel of a checkout
@@ -53,6 +59,71 @@ def contexts(rng, rows, live, prompts, outputs, cache_len):
 # rows, heads, line width, value lanes, cache length, contexts: the ``.longdoc``
 # cell's one layer (32 rows that all decode 17-24 k into a 32 k cache)
 LATENT = {"deepseek-latent": (32, 128, 640, 512, 32768, (17 * 1024, 24 * 1024))}
+
+
+# rows, query heads, KV heads, head dim, ring length, window: ``.reason``'s three
+# window layers (a ring is the window plus one 16-token bucket)
+RINGS = {"laguna-ring": (64, 72, 8, 128, 528, 512)}
+
+
+def bench_ring(args, name, device, rng) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from modelx_tpu.ops import attention as attn
+    from modelx_tpu.ops.kv_write import write_rows
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "peaks.json")) as f:
+        peak = json.load(f)[device.device_kind]["hbm_bytes_per_s"]
+    rows, hq, hkv, d, length, window = RINGS[name]
+    keys = jax.random.split(jax.random.PRNGKey(args.seed), 3)
+    q = jax.random.normal(keys[0], (rows, 1, hq, d), jnp.bfloat16)
+    k, v = (jax.random.normal(key, (rows, length, hkv, d), jnp.bfloat16) for key in keys[1:])
+    t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+
+    def reference(q, k, v, offsets):
+        return t(attn.attention_reference(
+            t(q), t(k), t(v), causal=True, q_offset=offsets, window=window,
+            key_positions=attn.ring_key_positions(offsets, length)))
+
+    def kernel(q, k, v, offsets):
+        return attn.ring_decode_attention(q, k, v, offsets, window)
+
+    def chunk(fn):
+        """``steps`` decode steps of one window layer: the new line written,
+        then the ring attended, everything on the carry."""
+        def step(carry, _):
+            q, k, v, offsets = carry
+            k = write_rows(k, q[:, :, :hkv], offsets % length)
+            v = write_rows(v, q[:, :, hkv:2 * hkv], offsets % length)
+            out = fn(q, k, v, offsets)
+            return ((q + out * 1e-3).astype(q.dtype), k, v, offsets + 1), None
+        return jax.jit(lambda *carry: jax.lax.scan(step, carry, None, length=args.steps)[0],
+                       donate_argnums=(1, 2))
+
+    # offsets below the window, between window and ring, and past several wraps
+    cases = {"traffic": rng.integers(64, 3328, rows), "filling": rng.integers(0, window, rows),
+             "idle": [0] * rows}
+    for case, offsets in cases.items():
+        offsets = jnp.asarray(offsets, jnp.int32)
+        want = jax.jit(reference)(q, k, v, offsets).astype(jnp.float32)
+        err = float(jnp.abs(jax.jit(kernel)(q, k, v, offsets).astype(jnp.float32) - want).max())
+        for impl, fn in (("reference", reference), ("ring_kernel", kernel)):
+            run, best = chunk(fn), float("inf")
+            carry = run(q, k + 0, v + 0, offsets)
+            for _ in range(args.reps):
+                t0 = time.perf_counter()
+                carry = run(*carry)
+                jax.block_until_ready(carry)
+                best = min(best, time.perf_counter() - t0)
+            ms = best / args.steps * 1e3
+            line = {"shape": name, "case": case, "impl": impl, "ms": round(ms, 4),
+                    "hbm_share": round(2 * k.size * 2 / (ms * 1e-3) / peak, 4),
+                    "device_kind": device.device_kind}
+            if impl == "ring_kernel":
+                line["max_abs_err"] = err
+            print(json.dumps(line), flush=True)
 
 
 def timed(fn, carry, operands, steps, reps):
@@ -145,7 +216,7 @@ def bench_latent(args, name, device, rng) -> None:
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--shapes", default=",".join([*SHAPES, *LATENT]))
+    ap.add_argument("--shapes", default=",".join([*SHAPES, *LATENT, *RINGS]))
     ap.add_argument("--parent", default="", help="a checkout whose decode_attention takes "
                     "value_lanes: its latent kernel becomes a line of the latent shape")
     ap.add_argument("--blocks", default="256,512,1024")
@@ -171,6 +242,8 @@ def main() -> int:
     for name in shapes:
         if name in LATENT:
             bench_latent(args, name, device, rng)
+        if name in RINGS:
+            bench_ring(args, name, device, rng)
     for name, (rows, hq, hkv, d, cache_len, live, prompts, outputs) in SHAPES.items():
         if name not in shapes:
             continue

@@ -1,6 +1,7 @@
-"""The ragged decode-attention kernel (``ops.attention.decode_attention``) and
-the rule that picks it (``cached_attention``). The oracle is
-``attention_reference`` over the whole cache; the kernel runs in pallas
+"""The two decode-attention kernels (``ops.attention.decode_attention``, the
+ragged one over a dense cache, and ``ring_decode_attention`` over a window
+layer's ring) and the rule that picks them (``cached_attention``). The oracle
+is ``attention_reference`` over the whole cache; the kernels run in pallas
 interpret mode here, asked for by name. What interpret mode cannot see —
 tiling, the cache read as it lies — is in tests/test_tpu_compile.py."""
 
@@ -72,6 +73,54 @@ def test_a_length_past_the_cache_reads_the_whole_cache_and_no_further():
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
+# -- the ring kernel ----------------------------------------------------------
+
+RING, WINDOW = 48, 32  # a ring is its window plus one 16-token bucket
+RING_OFFSETS = {
+    "below_the_window": [3, 17, WINDOW - 2],
+    "the_windows_edge": [WINDOW - 1, WINDOW, WINDOW + 1],
+    "between_window_and_ring": [WINDOW + 5, RING - 2, RING - 1],
+    "the_first_wrap": [RING, RING + 1, RING + WINDOW],
+    "past_several_wraps": [3 * RING - 1, 5 * RING + 7, 1000],
+    "rows_at_different_depths_and_an_idle_slot": [0, 9, RING - 1, RING, 4 * RING + 20],
+}
+
+
+def ring_reference(q, k, v, offsets, window=WINDOW, **kwargs):
+    return reference(q, k, v, offsets, window=window,
+                     key_positions=attn.ring_key_positions(offsets, k.shape[1]), **kwargs)
+
+
+@pytest.mark.parametrize("offsets", RING_OFFSETS)
+@pytest.mark.parametrize("group", [6, 9])
+def test_the_ring_kernel_gives_the_references_values(group, offsets):
+    """Laguna's two group sizes (9 under its window layers: 18 query heads pad
+    to two row tiles), rings not yet full, full, and overwritten several times:
+    the kernel's mask by age is the reference's by ``key_positions``."""
+    offsets = jnp.asarray(RING_OFFSETS[offsets], jnp.int32)
+    q, k, v = _qkv(len(offsets), group, cache_len=RING)
+    got = attn.ring_decode_attention(q, k, v, offsets, WINDOW, interpret=True)
+    np.testing.assert_allclose(got, ring_reference(q, k, v, offsets), rtol=2e-5, atol=2e-5)
+
+
+def test_the_ring_kernel_in_bf16_with_a_scale_and_a_window_as_long_as_the_ring():
+    q, k, v = _qkv(4, 9, jnp.bfloat16, cache_len=RING)
+    offsets = jnp.asarray([0, 20, RING + 3, 7 * RING], jnp.int32)
+    got = attn.ring_decode_attention(q, k, v, offsets, RING, 0.11, interpret=True)
+    assert got.dtype == jnp.bfloat16
+    want = ring_reference(q, k, v, offsets, window=RING, scale=0.11)
+    np.testing.assert_allclose(got.astype(jnp.float32), want.astype(jnp.float32), atol=2e-2)
+
+
+def test_an_index_is_as_old_as_its_distance_behind_the_querys_modulo_the_ring():
+    """``ring_key_positions``: index r holds the newest position <= the
+    query's congruent to r; negative = never written."""
+    got = np.asarray(attn.ring_key_positions(jnp.asarray([0, 5, 11, 12, 30]), 12))
+    for row, offset in zip(got, [0, 5, 11, 12, 30]):
+        want = [max(p for p in range(offset, offset - 12, -1) if p % 12 == r) for r in range(12)]
+        assert row.tolist() == want
+
+
 @pytest.mark.parametrize("cache_len,kv_heads,block", [
     (4096, 8, 256), (2048, 8, 256), (1024, 8, 256), (384, 8, 128), (256, 8, 128),
     (128, 8, 64), (4096, 32, 64), (4096, 16, 128), (128, 2, 64), (96, 2, 32), (1, 2, 0)])
@@ -94,14 +143,23 @@ def _shapes(d=128, hkv=8, group=4, qlen=1, cache_len=CACHE):
 # what keeps attention_reference, on a TPU too: (shapes, offsets, keywords)
 KEEPS_THE_REFERENCE = {
     "phi3_head_dim_96": (_shapes(d=96, hkv=32, group=1), OFFSETS, {}),
-    "a_ring": (_shapes(), OFFSETS,
-               {"key_positions": jax.ShapeDtypeStruct((ROWS, CACHE), jnp.int32)}),
     "a_query_of_16": (_shapes(qlen=16), OFFSETS, {}),
     "a_softcap": (_shapes(), OFFSETS, {"logit_softcap": 30.0}),
-    "a_window": (_shapes(), OFFSETS, {"window": 64}),
+    "a_window_over_a_dense_cache": (_shapes(), OFFSETS, {"window": 64}),
     "a_scalar_offset": (_shapes(), jax.ShapeDtypeStruct((), jnp.int32), {}),
     "two_kv_heads": (_shapes(hkv=2), OFFSETS, {}),
     "a_cache_of_one_block": (_shapes(cache_len=128), OFFSETS, {}),
+    "a_ring_with_a_softcap": (_shapes(group=9, cache_len=528), OFFSETS,
+                              {"window": 512, "ring": True, "logit_softcap": 30.0}),
+    "a_ring_of_two_kv_heads": (_shapes(hkv=2, cache_len=528), OFFSETS,
+                               {"window": 512, "ring": True}),
+    "a_ring_of_heads_of_96": (_shapes(d=96, cache_len=528), OFFSETS,
+                              {"window": 512, "ring": True}),
+    "a_ring_at_a_scalar_offset": (_shapes(group=9, cache_len=528),
+                                  jax.ShapeDtypeStruct((), jnp.int32),
+                                  {"window": 512, "ring": True}),
+    "a_ring_too_long_for_one_block": (_shapes(group=9, cache_len=2064), OFFSETS,
+                                      {"window": 2048, "ring": True}),
 }
 
 
@@ -114,19 +172,75 @@ def on_a_tpu(monkeypatch):
 @pytest.mark.parametrize("case", KEEPS_THE_REFERENCE)
 def test_what_is_not_a_plain_decode_step_lowers_as_the_reference_did(on_a_tpu, case):
     (q, k, v), offsets, kwargs = KEEPS_THE_REFERENCE[case]
-    arrays = {n: a for n, a in kwargs.items() if isinstance(a, jax.ShapeDtypeStruct)}
-    static = {n: a for n, a in kwargs.items() if n not in arrays}
 
-    def picked(q, k, v, off, **arrays):
-        return attn.cached_attention(q, k, v, off, **static, **arrays)
+    def picked(q, k, v, off):
+        return attn.cached_attention(q, k, v, off, **kwargs)
 
-    def direct(q, k, v, off, **arrays):
-        return reference(q, k, v, off, **static, **arrays)
+    def direct(q, k, v, off):
+        told = {n: a for n, a in kwargs.items() if n != "ring"}
+        if kwargs.get("ring"):  # what Laguna's own lines built before the rule took rings
+            told["key_positions"] = attn.ring_key_positions(
+                jnp.broadcast_to(off, k.shape[:1]), k.shape[1])
+        return reference(q, k, v, off, **told)
 
-    got = jax.jit(picked).lower(q, k, v, offsets, **arrays).as_text()
-    want = jax.jit(direct).lower(q, k, v, offsets, **arrays).as_text()
+    got = jax.jit(picked).lower(q, k, v, offsets).as_text()
+    want = jax.jit(direct).lower(q, k, v, offsets).as_text()
     assert got.replace("jit_picked", "jit_direct") == want
     assert "custom_call" not in got and "pallas" not in got
+
+
+@pytest.mark.parametrize("qlen,window", [(16, 512), (1, 0)])
+def test_a_ring_takes_one_query_a_row_under_a_window_or_nothing(qlen, window):
+    with pytest.raises(ValueError, match="a ring cache decodes one token a step"):
+        jax.eval_shape(lambda q, k, v, off: attn.cached_attention(
+            q, k, v, off, window=window, ring=True), *_shapes(qlen=qlen, cache_len=528), OFFSETS)
+
+
+def test_a_windows_ring_takes_the_ring_kernel_on_one_tpu_device_only(on_a_tpu):
+    """Laguna's window layers: 72 query heads over a ring of 528 positions of
+    8 KV heads. The ring's calls are not the ragged kernel's: nothing enters
+    ``ragged_calls`` (``attn.kv_read_share`` stays the full layers')."""
+    q, k, v = _shapes(group=9, cache_len=528)
+    take = lambda **kw: str(jax.make_jaxpr(lambda q, k, v, off: attn.cached_attention(
+        q, k, v, off, window=512, ring=True, **kw))(q, k, v, OFFSETS))
+    tracer().clear()
+    with attn.ragged_calls() as calls:
+        assert "ring_decode_attention" in take() and "ragged_decode_attention" not in take()
+    assert calls == []
+    assert "attention.ring[1x528]+gqa9" in tracer().summary("attention.")
+    from modelx_tpu.parallel.mesh import make_mesh
+
+    assert "pallas_call" in take(mesh=make_mesh("dp=1", devices=jax.devices()[:1]))
+    if len(jax.devices()) > 1:
+        assert "pallas_call" not in take(mesh=make_mesh("dp=2", devices=jax.devices()[:2]))
+
+
+@pytest.mark.parametrize("length,told,block", [
+    (4096, {}, 256), (528, {"ring": True}, 528), (528, {}, 0), (4096, {"impl": "ragged"}, 256),
+    (4096, {"mesh": "dp=2"}, 0), (528, {"ring": True, "mesh": "dp=2"}, 0),
+    (528, {"ring": True, "impl": "ragged+interpret", "mesh": "dp=2"}, 528)])
+def test_the_rule_is_a_function_of_shapes_backend_and_mesh(on_a_tpu, length, told, block):
+    """``decode_block``: what the engine's layout asks of its leaves without
+    tracing. A dense leaf's block is ``ragged_block``'s, a ring's the ring."""
+    from modelx_tpu.parallel.mesh import make_mesh
+
+    told = dict(told)
+    if "mesh" in told:
+        if len(jax.devices()) < 2:
+            pytest.skip("one device")
+        told["mesh"] = make_mesh(told["mesh"], devices=jax.devices()[:2])
+    assert attn.decode_block((64, length, 8, 128), 2, **told) == block
+
+
+def test_a_rings_block_is_bounded_by_bytes_and_off_the_tpu_by_the_name():
+    ring = lambda n, **kw: attn.decode_block((64, n, 8, 128), 2, ring=True, **kw)  # noqa: E731
+    assert ring(528) == 0 and ring(528, impl="ragged+interpret") == 528  # the CPU
+    import unittest.mock
+
+    with unittest.mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        assert (ring(528), ring(1040), ring(2064)) == (528, 1040, 0)
+        assert attn.decode_block((64, 528, 8, 128), 4, ring=True) == 528  # 4.3 MB in float32
+        assert attn.decode_block((64, 1040, 8, 128), 4, ring=True) == 0
 
 
 def test_a_plain_decode_step_takes_the_kernel_on_one_tpu_device_only(on_a_tpu):
@@ -149,6 +263,11 @@ def test_on_the_cpu_nothing_takes_the_kernel_unless_asked_by_name():
         q, k, v, off, impl=impl))(q, k, v, OFFSETS))
     assert "pallas_call" not in jaxpr("auto") and "pallas_call" not in jaxpr("flash+interpret")
     assert "pallas_call" in jaxpr("ragged+interpret")
+    q, k, v = _shapes(group=9, cache_len=528)
+    ring = lambda impl: str(jax.make_jaxpr(lambda q, k, v, off: attn.cached_attention(  # noqa: E731
+        q, k, v, off, impl=impl, window=512, ring=True))(q, k, v, OFFSETS))
+    assert "pallas_call" not in ring("auto") and "pallas_call" not in ring("flash+interpret")
+    assert "ring_decode_attention" in ring("ragged+interpret")
 
 
 def test_the_engine_counts_what_the_calls_blocks_cover():
@@ -203,3 +322,30 @@ def test_benchmark_json_ends_with_the_two_read_shares():
     for m in last:
         assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"]) == (
             "ratio", "lower", "program_counter", "Kernels / model step", "tokens_per_s")
+
+
+# -- the ring kernel's share (PR 48) ---------------------------------------------------
+
+
+def test_the_ring_kernel_share_is_the_layouts_two_counters_growth():
+    """``attn.ring_kernel_share.reason``: window layers' steps that read their
+    ring in the kernel over window layers' steps; a pod without the counters
+    (the parent commit, the CPU) reports nothing and nothing raises."""
+    dump = lambda kernel, calls: {"default": {"continuous": {  # noqa: E731
+        "attn_ring_kernel_calls": kernel, "attn_ring_calls": calls, "chunks": 9}}}
+    name = "attn.ring_kernel_share.reason"
+    assert read_metric(name, {"metrics_before": dump(300, 300),
+                              "metrics_after": dump(3300, 3300)}) == 1.0
+    assert read_metric(name, {"metrics_before": dump(0, 300),
+                              "metrics_after": dump(0, 3300)}) == 0.0
+    parent = {"default": {"continuous": {"chunks": 9, "attn_kv_positions_read": 5}}}
+    assert read_metric(name, {"metrics_before": parent, "metrics_after": parent}) is None
+    assert read_metric(name, {}) is None
+
+
+def test_benchmark_json_ends_with_the_ring_kernel_share():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        last = json.load(f)["per_layer"][104]  # PR 48 put it after the 104 that were there
+    assert last == {"name": "attn.ring_kernel_share.reason", "unit": "ratio", "better": "higher",
+                    "source": "program_counter", "layer": "Kernels / model step",
+                    "moves": "tokens_per_s", "workloads": ["laguna-s-2.1-ep2-d5.reason"]}

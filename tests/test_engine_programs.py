@@ -591,6 +591,21 @@ PARENT_PROGRAMS = {
     "minicpm_sala.admit": "35f181545364bfaab19c9e0d278596198a9823fdb55c9e651084f3a8ac5c3900",
     "minicpm_sala.piece": "0fb1c8cab7f7886f3b7fbad41c46cc35a291368ded4bbe8a2450ecd2774497ee"
 }
+# PR 48 (a window layer's decode step over its ring takes ``ring_decode_attention`` by
+# ``cached_attention``'s rule, which also builds a ring's ``key_positions`` now): the same
+# texts taken on ITS parent (41bbc73) for the three families that call ``cached_attention``
+# or sit beside it and were not in the table — on the CPU the rule says reference for
+# every call, so Laguna's programs too are the parent's, the rings' lines included
+PARENT_PROGRAMS.update({
+    "laguna.chunk": "7ebf731e20387c12c808d2f5f8d9f86159dae73341f2b23e98a3739ea3f1553c",
+    "laguna.admit": "5f7d0d4902bdc463925d3faad3d1429049c3dd28e323d81d2fbc7178966cd8db",
+    "deepseek_v2.chunk": "4adc8e5c98608b4b96bd0dabd8f18ea0f93a74d12dab76f358cb41cd99e60d36",
+    "deepseek_v2.admit": "0236fc1059a5b59cc7820de04679a031dacbb44df4ac744bfe9333c7557e7683",
+    "deepseek_v2.piece": "237e44aa41d333c8ef2b0f02378e94c2e2ec63df9baae26fdd7ce19c68afbd24",
+    "nemotron_h.chunk": "33731f75909de194601e995f7f91704b07f30d8f1cf99960fc456e9ca9476d59",
+    "nemotron_h.admit": "193f324e542b3f064c2cdfda82185dcb079b16a2199d4789333a563be544de5e",
+    "nemotron_h.piece": "38a1bd0cf07ef3251bef61edeb029b49bc6242db560184ca0e291c221c7db483",
+})
 # the ragged decode kernel's own jaxpr (the Mosaic body's source; it names no file) at
 # the two decode cells' widths: (rows, query heads, cache length)
 PARENT_RAGGED_KERNEL = {
@@ -604,7 +619,8 @@ def tiny_family(family: str):
 
     module = importlib.import_module("modelx_tpu.models." + family)
     name = {"llama": "LlamaConfig", "mixtral": "MixtralConfig", "laguna": "LagunaConfig",
-            "minicpm_sala": "SalaConfig", "deepseek_v2": "DeepseekV2Config"}[family]
+            "minicpm_sala": "SalaConfig", "deepseek_v2": "DeepseekV2Config",
+            "nemotron_h": "NemotronHConfig"}[family]
     return module, getattr(module, name).tiny(vocab_size=64)
 
 
@@ -642,7 +658,8 @@ def lowered_programs(family: str) -> dict:
     return got
 
 
-@pytest.mark.parametrize("family", ["llama", "mixtral", "minicpm_sala"])
+@pytest.mark.parametrize("family", ["llama", "mixtral", "minicpm_sala", "laguna", "deepseek_v2",
+                                    "nemotron_h"])
 def test_the_other_families_programs_lower_to_the_parents_text(family):
     import hashlib
 

@@ -223,9 +223,12 @@ def test_prefill_then_decode_through_the_engine_follows_the_reference(half, engi
     assert below.max() < 1e-3, (int(below.argmax()), float(below.max()))
 
 
-def test_rows_at_different_depths_share_the_rings(half, engine):
+@pytest.mark.parametrize("which", ["engine", "ragged_engine"])
+def test_rows_at_different_depths_share_the_rings(half, which, request):
     """Four requests of different lengths at once: each row's ring is its
-    own, whatever the others' offsets are."""
+    own, whatever the others' offsets are — under the reference, and with the
+    ring kernel named (one call, rows at their own depths, idle slots at 0)."""
+    engine = request.getfixturevalue(which)
     _, hf, raw, _ = half
     rng = np.random.default_rng(9)
     prompts = [rng.integers(1, VOCAB, (1, n)) for n in (3, 18, 35, 50)]
@@ -264,9 +267,12 @@ def test_the_engine_counts_its_expert_layers_and_its_caches_by_kind(half, engine
 
 @pytest.fixture(scope="module")
 def ragged_engine(half):
-    """The engine with the ragged decode kernel asked for by name (pallas
-    interpret mode): the two full layers read two 64-position blocks of
-    their 128-position caches at most, the three rings keep the reference."""
+    """The engine with the decode kernels asked for by name (pallas interpret
+    mode): the two full layers read two 64-position blocks of their
+    128-position caches at most in the ragged kernel, the three window layers
+    their rings of 32 whole in the ring kernel. The family says what name its
+    forward asks by (``attention_impl``), so that the layout's count of ring
+    reads — from the rule, never from a trace — follows it."""
     import copy
 
     srv = half[0]
@@ -277,7 +283,7 @@ def ragged_engine(half):
         def fwd(p, t, kv_cache, cache_offset, mesh=mesh):
             return laguna.forward(p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset,
                                   mesh=mesh, ring=True, attention_impl="ragged+interpret")
-        return {**fns, "fwd": fwd}
+        return {**fns, "fwd": fwd, "attention_impl": "ragged+interpret"}
 
     named = copy.copy(srv)
     named.family = dataclasses.replace(srv.family, layer_kind_decode_fns=by_name)
@@ -286,12 +292,13 @@ def ragged_engine(half):
     cb.close()
 
 
-@pytest.mark.parametrize("prompt_len,new", [(5, 60), (40, 70), (70, 40)])
+@pytest.mark.parametrize("prompt_len,new", [(5, 60), (16, 40), (40, 70), (33, 20), (70, 40)])
 def test_the_ragged_kernel_on_the_full_layers_follows_the_reference(half, ragged_engine,
                                                                     prompt_len, new):
-    """As the engine's own test above: every token is the float32
-    reference's argmax of the full forward, to rounding — through contexts
-    that end in a full layer's first block and in its second."""
+    """As the engine's own test above, kernels named: every token is the
+    float32 reference's argmax of the full forward, to rounding — through
+    contexts that end in a full layer's first block and in its second, and
+    through rings not yet full, full, and wrapped more than once."""
     _, hf, raw, _ = half
     prompt = np.random.default_rng(prompt_len).integers(1, VOCAB, (1, prompt_len))
     out = np.asarray(ragged_engine.generate(prompt, max_new_tokens=new))[0][-new:]
@@ -306,13 +313,15 @@ def test_the_ragged_engine_counts_its_full_layers_reads_beside_the_expert_counts
     ragged_engine.generate(np.ones((1, 8), np.int32), max_new_tokens=12)
     snap = ragged_engine.snapshot()
     steps = snap["chunks"] * ragged_engine.chunk_size
-    # the two full layers of five; the rings are not counted
+    # the two full layers of five; the rings' reads are whole by nature and
+    # have a count of their own: every step, each of the three in the kernel
+    assert snap["attn_ring_kernel_calls"] == snap["attn_ring_calls"] == steps * 3
     assert snap["attn_kv_positions_cached"] == steps * SLOTS * MAX_LEN * 2
     assert steps * SLOTS * 64 * 2 <= snap["attn_kv_positions_read"] < (
         snap["attn_kv_positions_cached"])
     assert snap["moe"]["assignments"] % (SLOTS * half[0].cfg.top_k * 4) == 0
     assert snap["moe"]["assignments"] > 0
-    assert not any(k.startswith("attn_kv") for k in engine.snapshot())
+    assert not any(k.startswith("attn_") for k in engine.snapshot())
 
 
 def test_an_idle_slots_offset_is_held_at_zero_on_a_cache_per_layer_kind(engine):

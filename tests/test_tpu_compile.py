@@ -302,6 +302,21 @@ def test_ragged_decode_kernel_at_the_cells_widths(topo, shape):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
+def test_ring_decode_kernel_at_the_cells_widths(topo):
+    """Mosaic takes the ring kernel at the ``.reason`` cell's window layers
+    (64 rings of 528 positions of 8 KV heads of 128 under 72 query heads: the
+    whole ring one block of 4,224 lines, keys and values double-buffered
+    beside ``[80, 4224]`` float32 logits, inside Mosaic's own VMEM limit), the
+    scalar modulo included; the rings reach it by bitcast — no temporary."""
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    ring = sds((64, 528, 8, 128), jnp.bfloat16)
+    compiled = jax.jit(lambda q, k, v, n: attn.ring_decode_attention(q, k, v, n, 512)).lower(
+        sds((64, 1, 72, 128), jnp.bfloat16), ring, ring, sds((64,), jnp.int32)).compile()
+    assert _mosaic_calls(compiled.as_text()) == {"ring_decode_attention": 1}
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("shape", DECODE_SHAPES)
 def test_ragged_decode_kernel_inside_a_scan_reads_the_cache_as_it_lies(topo, shape):
@@ -337,10 +352,10 @@ def test_ragged_decode_kernel_inside_a_scan_reads_the_cache_as_it_lies(topo, sha
 def test_lagunas_decode_step_takes_the_ragged_kernel_on_its_full_layers_only(topo, monkeypatch):
     """The cell's decode step with the rules steered to a TPU (the compile
     runs where ``default_backend`` says cpu): by the kernels' names, two
-    ragged attention calls, one a full layer — the three rings keep the
-    reference — ten cache writes, one a leaf, and the hit experts' kernel on
-    each of the four expert layers; the f32 logits over all 4096 positions
-    are gone from the program."""
+    ragged attention calls, one a full layer, three ring attention calls, one
+    a window layer, ten cache writes, one a leaf, and the hit experts' kernel
+    on each of the four expert layers; the f32 logits over all 4096 positions
+    and over the rings' 528 are gone from the program."""
     import json
 
     from modelx_tpu.models import laguna
@@ -366,10 +381,11 @@ def test_lagunas_decode_step_takes_the_ragged_kernel_on_its_full_layers_only(top
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, state, sds((64, 1), jnp.int32), sds((64,), jnp.int32)).compile()
     text = compiled.as_text()
-    assert _mosaic_calls(text) == {"ragged_decode_attention": 2, "kv_write_rows": 10,
+    assert _mosaic_calls(text) == {"ragged_decode_attention": 2, "ring_decode_attention": 3,
+                                   "kv_write_rows": 10,
                                    "moe_hit_experts": cfg.mlp_layer_types.count("sparse")}
     assert cfg.mlp_layer_types.count("sparse") == 4
-    assert "f32[64,8,6,4096]" not in text and "f32[64,8,9,528]" in text
+    assert "f32[64,8,6,4096]" not in text and "f32[64,8,9,528]" not in text
 
 
 # -- the decode step's per-row cache write (ops.kv_write.write_rows_kernel) ------
@@ -445,9 +461,13 @@ def test_the_cells_chunk_program_writes_every_leaf_in_the_kernel_in_place(topo, 
     Laguna's rings — beside the ragged attention's; the one ``while`` left is
     the scan (the parent's had one of ``slots`` trips a leaf around a 2 KB
     update: the scatter), and in Laguna's the hit experts' kernel on its four
-    expert layers; no cache leaf is copied; the state leaves the
-    program aliased to its input; temporaries not above the parent's (but for
-    Laguna's routed sum, a float32 ``[64, 3072]``)."""
+    expert layers and ``ring_decode_attention`` on its three window layers
+    (PR 48: the layout counts them from the same rule); no cache leaf is
+    copied, transposed or, a ring whole, prefetched to another memory space
+    and back (the ``copy-start`` / ``copy-done`` pairs the reference's operand
+    cost, six a step); the state leaves the program aliased to its input;
+    temporaries not above the parent's (but for Laguna's routed sum, a float32
+    ``[64, 3072]``)."""
     import math
     import re
 
@@ -455,6 +475,8 @@ def test_the_cells_chunk_program_writes_every_leaf_in_the_kernel_in_place(topo, 
     engine, params, slots, max_len, leaves = _cell_engine(topo, family)
     try:
         assert engine.kv.row_writes == (leaves, leaves)
+        if family == "laguna":
+            assert engine.kv.ring_reads == (3, 3)
         state = engine.kv.abstract_state()
         tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=SingleDeviceSharding(topo.devices[0]))
         compiled = engine._chunk_prog.jit.lower(
@@ -464,15 +486,15 @@ def test_the_cells_chunk_program_writes_every_leaf_in_the_kernel_in_place(topo, 
     text, m = compiled.as_text(), compiled.memory_analysis()
     calls = _mosaic_calls(text)
     # Mixtral's expert layer is ``moe_ffn``: no hit-experts kernel there
-    experts = {"moe_hit_experts": 4} if family == "laguna" else {}
+    laguna = {"moe_hit_experts": 4, "ring_decode_attention": 3} if family == "laguna" else {}
     assert calls == {"kv_write_rows": leaves, "ragged_decode_attention": leaves // 2 - (
-        3 if family == "laguna" else 0), **experts}
+        3 if family == "laguna" else 0), **laguna}
     assert len([line for line in text.splitlines() if " while(" in line]) == 1
     scattered = [line.strip()[:120] for line in text.splitlines()
                  if "scatter" in line and re.search(rf"bf16\[{slots},(\d\d\d+),8,128\]", line)]
     assert not scattered, scattered
-    copied = [line.strip()[:120] for line in text.splitlines()
-              if re.search(rf"= bf16\[{slots},(\d\d\d+),8,128\]\S* (copy|transpose)\(", line)]
+    copied = [line.strip()[:120] for line in text.splitlines() if re.search(
+        rf"bf16\[{slots},(\d\d\d+),8,128\][^=]* (copy|transpose|copy-start|copy-done)\(", line)]
     assert not copied, copied
     kv_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
                    for x in jax.tree_util.tree_leaves(state) if len(x.shape) == 4)
