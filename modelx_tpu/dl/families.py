@@ -1,22 +1,55 @@
 """Model-family registry for the serving path: checkpoint tensor names ->
-(config inference, partition rules, forward/generate adapters).
+(config, partition rules, forward / generate / decode adapters).
 
 The reference stores models without understanding them; the TPU serving
-sidecar has to *execute* them, so each supported family contributes:
+sidecar has to *execute* them. A ``Family`` is what the serving stack reads of
+a family (dl/serve.py, continuous.py, kv_layout.py, kv_store.py,
+openai_api.py, program_store.py, ttft.py read nothing else), and every field
+of one is built by ONE adapter (``_causal``) from the family's model module,
+imported at a field's first call and never with this file.
 
-- ``infer_config(params)``: recover the architecture from tensor shapes
-  (no config.json required — the checkpoint is self-describing);
-- ``rules``: GSPMD partition rules (dl/sharding.py);
-- ``forward(params, tokens, cfg, mesh)`` -> logits/features;
-- ``generate`` (causal families only).
+**The module interface.** A servable causal family is a module under
+``modelx_tpu/models/`` with
 
-``detect(params)`` picks the family from tensor names, mirroring
-dl/sharding.infer_family but over loaded params.
+- ``forward(params, tokens, cfg, *, kv_cache, cache_offset, mesh, ...) ->
+  (logits, cache)``: a prefill where ``kv_cache`` is None, else a block of
+  prompt positions or a decode step over the cache at ``cache_offset`` (a
+  scalar, or ``[B]`` for ragged rows);
+- ``init_kv_cache(cfg, batch, max_len)``: that cache, every layer's alike;
+
+and, where it applies,
+
+- ``config_from_hf(raw, dtype)``: its ``config.json`` is the source of its
+  config (the row gives no shape reader; a checkpoint without one is refused);
+- ``init_layer_state(cfg, slots, max_len)``, ``cache_kinds(cfg)`` (leaf ->
+  "full" / "window" / "index" / "state" / "latent" / "counter") and
+  ``published(cfg)`` (the ``"counters"`` its decode step accumulates, by the
+  module's own ``*_COUNTERS`` tuples, and the ``"gauges"`` beside them, as
+  /metrics names them): its layers keep caches of more than one kind
+  (dl/kv_layout.LayerKindKV);
+- ``check_context(cfg, last_pos)``: its positions are a learned table, and a
+  generate loop that would run past it is refused up front.
+
+What a module cannot be asked before it is imported — whether its forward
+takes ``paged_table``, or ``valid_len`` / ``live``, what its per-kind forward
+is passed, which tensor's dtype is the activation dtype, whether ``mesh`` is
+handed on — is data in its row of ``FAMILIES``; nothing branches on a
+family's name. A new architecture costs ``models/<family>.py`` and its
+reference, its rules and one detection line in dl/sharding.py, one row here,
+and its tests.
+
+``infer_<family>_config(params)`` recovers an architecture from tensor shapes
+for the families whose shapes say it (llama, qwen2, phi3, gemma2, mixtral,
+gpt2, bert; ``config_for`` reconciles it with a pulled config.json); laguna,
+minicpm_sala, deepseek_v2 and nemotron_h read ``config.json`` alone.
+``detect(tensor_names)`` picks the family from tensor NAMES
+(dl/sharding.infer_family) — a header's index is enough, no weight is read.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import json
 import logging
 import os
@@ -136,51 +169,6 @@ def infer_llama_config(params: dict):
     )
 
 
-def _llama_forward(params, tokens, cfg, mesh=None):
-    from modelx_tpu.models import llama
-
-    return llama.forward(params, tokens, cfg, mesh=mesh)[0]
-
-
-def _llama_generate(params, tokens, cfg, mesh=None, max_new_tokens=16):
-    from modelx_tpu.models import llama
-
-    return llama.greedy_generate(params, tokens, cfg, max_new_tokens=max_new_tokens, mesh=mesh)
-
-
-def _llama_generate_ragged(params, tokens, row_lens, cfg, mesh=None,
-                            max_new_tokens=16, **sampling):
-    from modelx_tpu.models import llama
-
-    return llama.ragged_greedy_generate(
-        params, tokens, row_lens, cfg, max_new_tokens=max_new_tokens, mesh=mesh,
-        **sampling,
-    )
-
-
-def _llama_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import llama
-
-    def fwd(p, t, kv_cache, cache_offset, mesh=mesh):
-        return llama.forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh
-        )
-
-    return fwd, (lambda b, max_len: llama.init_kv_cache(cfg, b, max_len))
-
-
-def _llama_paged_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import llama
-
-    def fwd(p, t, kv_cache, cache_offset, table, mesh=mesh):
-        return llama.forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset,
-            mesh=mesh, paged_table=table,
-        )
-
-    return fwd
-
-
 # -- mixtral ------------------------------------------------------------------
 
 
@@ -209,125 +197,6 @@ def infer_mixtral_config(params: dict):
         num_experts=num_experts,
         dtype=_act_dtype(params, "model.embed_tokens.weight"),
     )
-
-
-def _mixtral_forward(params, tokens, cfg, mesh=None):
-    from modelx_tpu.models import mixtral
-
-    return mixtral.forward(params, tokens, cfg, mesh=mesh)[0]
-
-
-def _mixtral_generate(params, tokens, cfg, mesh=None, max_new_tokens=16):
-    from modelx_tpu.models import mixtral
-
-    return mixtral.greedy_generate(
-        params, tokens, cfg, max_new_tokens=max_new_tokens, mesh=mesh
-    )
-
-
-def _mixtral_generate_ragged(params, tokens, row_lens, cfg, mesh=None,
-                            max_new_tokens=16, **sampling):
-    from modelx_tpu.models import mixtral
-
-    return mixtral.ragged_greedy_generate(
-        params, tokens, row_lens, cfg, max_new_tokens=max_new_tokens, mesh=mesh,
-        **sampling,
-    )
-
-
-def _mixtral_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import mixtral
-
-    def fwd(p, t, kv_cache, cache_offset, mesh=mesh):
-        return mixtral.forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh
-        )
-
-    return fwd, (lambda b, max_len: mixtral.init_kv_cache(cfg, b, max_len))
-
-
-def _mixtral_paged_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import mixtral
-
-    def fwd(p, t, kv_cache, cache_offset, table, mesh=mesh):
-        return mixtral.forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset,
-            mesh=mesh, paged_table=table,
-        )
-
-    return fwd
-
-
-# -- laguna -------------------------------------------------------------------
-
-
-def infer_laguna_config(params: dict):
-    raise ValueError(
-        "a laguna checkpoint's layer kinds, per-layer head counts, rope "
-        "parameters, top-k and expert share leave no trace in tensor shapes: "
-        "its config.json must lie beside the weights")
-
-
-def laguna_config_from_sidecar(sidecar: dict, params: dict):
-    from modelx_tpu.models import laguna
-
-    return laguna.config_from_hf(
-        sidecar, dtype=_act_dtype(params, "model.embed_tokens.weight"))
-
-
-def _laguna_forward(params, tokens, cfg, mesh=None):
-    from modelx_tpu.models import laguna
-
-    return laguna.forward(params, tokens, cfg, mesh=mesh)[0]
-
-
-def _laguna_generate(params, tokens, cfg, mesh=None, max_new_tokens=16):
-    from modelx_tpu.models import laguna
-
-    return laguna.greedy_generate(params, tokens, cfg, max_new_tokens=max_new_tokens, mesh=mesh)
-
-
-def _laguna_generate_ragged(params, tokens, row_lens, cfg, mesh=None,
-                            max_new_tokens=16, **sampling):
-    from modelx_tpu.models import laguna
-
-    return laguna.ragged_greedy_generate(
-        params, tokens, row_lens, cfg, max_new_tokens=max_new_tokens, mesh=mesh,
-        **sampling,
-    )
-
-
-def _laguna_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import laguna
-
-    def fwd(p, t, kv_cache, cache_offset, mesh=mesh):
-        return laguna.forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh
-        )
-
-    return fwd, (lambda b, max_len: laguna.init_kv_cache(cfg, b, max_len))
-
-
-def _laguna_layer_kind_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import laguna
-
-    def fwd(p, t, kv_cache, cache_offset, mesh=mesh):
-        return laguna.forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh,
-            ring=True,
-        )
-
-    return {
-        "fwd": fwd,
-        "init_state": lambda slots, max_len: laguna.init_layer_state(cfg, slots, max_len),
-        "kinds": laguna.cache_kinds(cfg),
-        # what the decode step counts of its expert layers, over ALL slots
-        # (idle ones route too), and what those counts are shares of
-        "counters": {"moe_counts": ("moe", laguna.MOE_COUNTERS)},
-        "gauges": {"moe": {"held_experts": cfg.expert_count,
-                           "published_experts": cfg.num_experts,
-                           "sparse_layers": cfg.mlp_layer_types.count("sparse")}},
-    }
 
 
 # -- gpt2 ---------------------------------------------------------------------
@@ -396,51 +265,6 @@ def infer_phi3_config(params: dict):
     )
 
 
-def _phi3_forward(params, tokens, cfg, mesh=None):
-    from modelx_tpu.models import phi3
-
-    return phi3.forward(params, tokens, cfg, mesh=mesh)[0]
-
-
-def _phi3_generate(params, tokens, cfg, mesh=None, max_new_tokens=16):
-    from modelx_tpu.models import phi3
-
-    return phi3.greedy_generate(params, tokens, cfg, max_new_tokens=max_new_tokens, mesh=mesh)
-
-
-def _phi3_generate_ragged(params, tokens, row_lens, cfg, mesh=None,
-                          max_new_tokens=16, **sampling):
-    from modelx_tpu.models import phi3
-
-    return phi3.ragged_greedy_generate(
-        params, tokens, row_lens, cfg, max_new_tokens=max_new_tokens, mesh=mesh,
-        **sampling,
-    )
-
-
-def _phi3_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import phi3
-
-    def fwd(p, t, kv_cache, cache_offset, mesh=mesh):
-        return phi3.forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh
-        )
-
-    return fwd, (lambda b, max_len: phi3.init_kv_cache(cfg, b, max_len))
-
-
-def _phi3_paged_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import phi3
-
-    def fwd(p, t, kv_cache, cache_offset, table, mesh=mesh):
-        return phi3.forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset,
-            mesh=mesh, paged_table=table,
-        )
-
-    return fwd
-
-
 # -- gemma2 -------------------------------------------------------------------
 
 
@@ -479,324 +303,6 @@ def infer_gemma2_config(params: dict):
     )
 
 
-def _gemma2_forward(params, tokens, cfg, mesh=None):
-    from modelx_tpu.models import gemma2
-
-    return gemma2.forward(params, tokens, cfg, mesh=mesh)[0]
-
-
-def _gemma2_generate(params, tokens, cfg, mesh=None, max_new_tokens=16):
-    from modelx_tpu.models import gemma2
-
-    return gemma2.greedy_generate(params, tokens, cfg, max_new_tokens=max_new_tokens, mesh=mesh)
-
-
-def _gemma2_generate_ragged(params, tokens, row_lens, cfg, mesh=None,
-                            max_new_tokens=16, **sampling):
-    from modelx_tpu.models import gemma2
-
-    return gemma2.ragged_greedy_generate(
-        params, tokens, row_lens, cfg, max_new_tokens=max_new_tokens, mesh=mesh,
-        **sampling,
-    )
-
-
-def _gemma2_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import gemma2
-
-    def fwd(p, t, kv_cache, cache_offset, mesh=mesh):
-        return gemma2.forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh
-        )
-
-    return fwd, (lambda b, max_len: gemma2.init_kv_cache(cfg, b, max_len))
-
-
-def _gemma2_paged_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import gemma2
-
-    def fwd(p, t, kv_cache, cache_offset, table, mesh=mesh):
-        return gemma2.forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset,
-            mesh=mesh, paged_table=table,
-        )
-
-    return fwd
-
-
-def _gpt2_forward(params, tokens, cfg, mesh=None):
-    from modelx_tpu.models import gpt2
-
-    return gpt2.forward(params, tokens, cfg)[0]
-
-
-def _gpt2_generate(params, tokens, cfg, mesh=None, max_new_tokens=16):
-    from modelx_tpu.models import gpt2
-
-    return gpt2.greedy_generate(params, tokens, cfg, max_new_tokens=max_new_tokens, mesh=mesh)
-
-
-def _gpt2_generate_ragged(params, tokens, row_lens, cfg, mesh=None,
-                          max_new_tokens=16, **sampling):
-    from modelx_tpu.models import gpt2
-
-    return gpt2.ragged_greedy_generate(
-        params, tokens, row_lens, cfg, max_new_tokens=max_new_tokens, mesh=mesh,
-        **sampling,
-    )
-
-
-def _gpt2_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import gpt2
-
-    def fwd(p, t, kv_cache, cache_offset, mesh=mesh):
-        return gpt2.forward(p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset)
-
-    return fwd, (lambda b, max_len: gpt2.init_kv_cache(cfg, b, max_len))
-
-
-def _gpt2_paged_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import gpt2
-
-    def fwd(p, t, kv_cache, cache_offset, table, mesh=mesh):
-        return gpt2.forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset,
-            paged_table=table,
-        )
-
-    return fwd
-
-
-# -- minicpm_sala ---------------------------------------------------------------
-
-
-def infer_minicpm_sala_config(params: dict):
-    raise ValueError(
-        "a minicpm_sala checkpoint's mixer types, sparse settings, muP scales "
-        "and published depth leave no trace in tensor shapes: its config.json "
-        "must lie beside the weights")
-
-
-def minicpm_sala_config_from_sidecar(sidecar: dict, params: dict):
-    from modelx_tpu.models import minicpm_sala
-
-    return minicpm_sala.config_from_hf(
-        sidecar, dtype=_act_dtype(params, "model.embed_tokens.weight"))
-
-
-def _minicpm_sala_forward(params, tokens, cfg, mesh=None):
-    from modelx_tpu.models import minicpm_sala
-
-    return minicpm_sala.forward(params, tokens, cfg, mesh=mesh)[0]
-
-
-def _minicpm_sala_generate(params, tokens, cfg, mesh=None, max_new_tokens=16):
-    from modelx_tpu.models import minicpm_sala
-
-    return minicpm_sala.greedy_generate(
-        params, tokens, cfg, max_new_tokens=max_new_tokens, mesh=mesh)
-
-
-def _minicpm_sala_generate_ragged(params, tokens, row_lens, cfg, mesh=None,
-                                  max_new_tokens=16, **sampling):
-    from modelx_tpu.models import minicpm_sala
-
-    return minicpm_sala.ragged_greedy_generate(
-        params, tokens, row_lens, cfg, max_new_tokens=max_new_tokens, mesh=mesh,
-        **sampling,
-    )
-
-
-_UNTOLD = object()
-
-
-def _state_decode_fns(family, cfg, mesh):
-    """``decode_fns`` of a family (its module) some of whose layers keep a
-    STATE in place of keys and values: a padded bucket's tail would enter the
-    states for good, so a caller that lands a block of prompt positions says
-    how many of them are real (None = all), as the continuous engine does."""
-    name = family.__name__.rpartition(".")[2]
-
-    def fwd(p, t, kv_cache, cache_offset, mesh=mesh, valid_len=_UNTOLD, live=None):
-        if t.shape[1] > 1 and valid_len is _UNTOLD:
-            raise ValueError(
-                f"{name}: a block of prompt positions needs its rows' real "
-                "lengths (a state keeps what a padded tail adds): this family "
-                "streams through --continuous-batch")
-        return family.forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh,
-            valid_len=None if valid_len is _UNTOLD else valid_len, live=live)
-
-    return fwd, (lambda b, max_len: family.init_kv_cache(cfg, b, max_len))
-
-
-def _minicpm_sala_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import minicpm_sala
-
-    return _state_decode_fns(minicpm_sala, cfg, mesh)
-
-
-def _minicpm_sala_layer_kind_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import minicpm_sala
-
-    fwd, _ = _minicpm_sala_decode_fns(cfg, mesh)
-    sparse_layers = cfg.mixer_types.count(minicpm_sala.SPARSE)
-    return {
-        "fwd": fwd,
-        "init_state": lambda slots, max_len: minicpm_sala.init_layer_state(cfg, slots, max_len),
-        "kinds": minicpm_sala.cache_kinds(cfg),
-        # what the decode step counts of its sparse layers, over the LIVE rows
-        "counters": {"sparse_counts": ("sparse", minicpm_sala.SPARSE_COUNTERS)},
-        "gauges": {"sparse": {"sparse_layers": sparse_layers,
-                              "linear_layers": cfg.num_layers - sparse_layers,
-                              "block_size": cfg.sparse.block_size, "topk": cfg.sparse.topk,
-                              "dense_len": cfg.sparse.dense_len}},
-    }
-
-
-# -- deepseek_v2 ----------------------------------------------------------------
-
-
-def infer_deepseek_v2_config(params: dict):
-    raise ValueError(
-        "a deepseek_v2 checkpoint's head sizes, routing groups, rope scaling and "
-        "expert share leave no trace in tensor shapes: its config.json must lie "
-        "beside the weights")
-
-
-def deepseek_v2_config_from_sidecar(sidecar: dict, params: dict):
-    from modelx_tpu.models import deepseek_v2
-
-    return deepseek_v2.config_from_hf(
-        sidecar, dtype=_act_dtype(params, "model.embed_tokens.weight"))
-
-
-def _deepseek_v2_forward(params, tokens, cfg, mesh=None):
-    from modelx_tpu.models import deepseek_v2
-
-    return deepseek_v2.forward(params, tokens, cfg, mesh=mesh)[0]
-
-
-def _deepseek_v2_generate(params, tokens, cfg, mesh=None, max_new_tokens=16):
-    from modelx_tpu.models import deepseek_v2
-
-    return deepseek_v2.greedy_generate(
-        params, tokens, cfg, max_new_tokens=max_new_tokens, mesh=mesh)
-
-
-def _deepseek_v2_generate_ragged(params, tokens, row_lens, cfg, mesh=None,
-                                 max_new_tokens=16, **sampling):
-    from modelx_tpu.models import deepseek_v2
-
-    return deepseek_v2.ragged_greedy_generate(
-        params, tokens, row_lens, cfg, max_new_tokens=max_new_tokens, mesh=mesh,
-        **sampling,
-    )
-
-
-def _deepseek_v2_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import deepseek_v2
-
-    def fwd(p, t, kv_cache, cache_offset, mesh=mesh):
-        return deepseek_v2.forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh
-        )
-
-    return fwd, (lambda b, max_len: deepseek_v2.init_kv_cache(cfg, b, max_len))
-
-
-def _deepseek_v2_layer_kind_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import deepseek_v2
-
-    fwd, _ = _deepseek_v2_decode_fns(cfg, mesh)
-    return {
-        "fwd": fwd,
-        "init_state": lambda slots, max_len: deepseek_v2.init_layer_state(cfg, slots, max_len),
-        "kinds": deepseek_v2.cache_kinds(cfg),
-        # what the decode step counts: of its expert layers over ALL slots (idle
-        # ones route too), of its latent layers over the rows that hold a context
-        "counters": {"moe_counts": ("moe", deepseek_v2.MOE_COUNTERS),
-                     "mla_counts": ("mla", deepseek_v2.MLA_COUNTERS)},
-        "gauges": {"moe": {"held_experts": cfg.expert_count,
-                           "published_experts": cfg.num_experts,
-                           "sparse_layers": cfg.num_layers - cfg.first_k_dense_replace,
-                           "groups": cfg.n_group, "groups_kept": cfg.topk_group},
-                   "mla": {"layers": cfg.num_layers, "heads": cfg.num_heads,
-                           "kv_lora_rank": cfg.kv_lora_rank,
-                           "rope_dim": cfg.qk_rope_head_dim,
-                           "line_width": cfg.line_width}},
-    }
-
-
-# -- nemotron_h -----------------------------------------------------------------
-
-
-def infer_nemotron_h_config(params: dict):
-    raise ValueError(
-        "a nemotron_h checkpoint's layer pattern, Mamba head and group counts, "
-        "routing and expert share leave no trace in tensor shapes: its "
-        "config.json must lie beside the weights")
-
-
-def nemotron_h_config_from_sidecar(sidecar: dict, params: dict):
-    from modelx_tpu.models import nemotron_h
-
-    return nemotron_h.config_from_hf(
-        sidecar, dtype=_act_dtype(params, "backbone.embeddings.weight"))
-
-
-def _nemotron_h_forward(params, tokens, cfg, mesh=None):
-    from modelx_tpu.models import nemotron_h
-
-    return nemotron_h.forward(params, tokens, cfg, mesh=mesh)[0]
-
-
-def _nemotron_h_generate(params, tokens, cfg, mesh=None, max_new_tokens=16):
-    from modelx_tpu.models import nemotron_h
-
-    return nemotron_h.greedy_generate(
-        params, tokens, cfg, max_new_tokens=max_new_tokens, mesh=mesh)
-
-
-def _nemotron_h_generate_ragged(params, tokens, row_lens, cfg, mesh=None,
-                                max_new_tokens=16, **sampling):
-    from modelx_tpu.models import nemotron_h
-
-    return nemotron_h.ragged_greedy_generate(
-        params, tokens, row_lens, cfg, max_new_tokens=max_new_tokens, mesh=mesh,
-        **sampling,
-    )
-
-
-def _nemotron_h_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import nemotron_h
-
-    return _state_decode_fns(nemotron_h, cfg, mesh)
-
-
-def _nemotron_h_layer_kind_decode_fns(cfg, mesh=None):
-    from modelx_tpu.models import nemotron_h
-
-    fwd, _ = _nemotron_h_decode_fns(cfg, mesh)
-    return {
-        "fwd": fwd,
-        "init_state": lambda slots, max_len: nemotron_h.init_layer_state(cfg, slots, max_len),
-        "kinds": nemotron_h.cache_kinds(cfg),
-        # what the decode step counts: of its expert layers over ALL slots (idle
-        # ones route too); of its rows, live ones and all, once a step
-        "counters": {"moe_counts": ("moe", nemotron_h.MOE_COUNTERS),
-                     "ssm_counts": ("ssm", nemotron_h.SSM_COUNTERS)},
-        "gauges": {"moe": {"held_experts": cfg.expert_count,
-                           "published_experts": cfg.num_experts,
-                           "sparse_layers": cfg.pattern.count(nemotron_h.EXPERTS),
-                           "latent_size": cfg.moe_latent_size},
-                   "ssm": {"layers": cfg.pattern.count(nemotron_h.MAMBA),
-                           "heads": cfg.mamba_heads, "head_dim": cfg.mamba_head_dim,
-                           "state_size": cfg.ssm_state_size, "groups": cfg.n_groups,
-                           "conv_kernel": cfg.conv_kernel}},
-    }
-
-
 # -- bert ---------------------------------------------------------------------
 
 
@@ -829,49 +335,149 @@ def _bert_forward(params, tokens, cfg, mesh=None):
     return bert.forward(params, tokens, cfg)[0]
 
 
-FAMILIES: dict[str, Family] = {
-    "llama": Family("llama", LLAMA_RULES, infer_llama_config, _llama_forward,
-                    _llama_generate, _llama_generate_ragged, _llama_decode_fns,
-                    _llama_paged_decode_fns),
+# -- the adapter: a family is its model module -----------------------------------
+
+
+def _causal(name: str, rules: Rules, infer_config: Callable[[dict], Any] | None = None, *,
+            module: str = "", paged_table: bool = False, told_lengths: bool = False,
+            kind_forward: dict | None = None, hands_mesh: bool = True,
+            embedding: str = "model.embed_tokens.weight") -> Family:
+    """Every field of a generative ``Family`` from its model module
+    (``modelx_tpu/models/<module or name>.py``, the interface in this file's
+    docstring), imported at a field's first call. What differs between
+    families is said here, by the row:
+
+    - ``infer_config``: the shape reader; None = shapes cannot say, the config
+      is ``config_from_hf`` of the ``config.json`` beside the weights, with the
+      (float) dtype of ``embedding`` as the activation dtype;
+    - ``paged_table``: its forward reads page pools through a ``paged_table``;
+    - ``told_lengths``: some layers keep a STATE in place of keys and values —
+      its forward takes ``valid_len`` / ``live``, and a block of prompt
+      positions must come with its rows' real lengths;
+    - ``kind_forward``: it keeps a cache per layer kind (``init_layer_state``,
+      ``cache_kinds``, ``published``), and its forward over that state takes
+      these keywords beside the dense one's;
+    - ``hands_mesh``: False = its forward is never handed the mesh."""
+
+    def mod():
+        return importlib.import_module("modelx_tpu.models." + (module or name))
+
+    def handed(mesh) -> dict:
+        return {"mesh": mesh} if hands_mesh else {}
+
+    def forward(params, tokens, cfg, mesh=None):
+        return mod().forward(params, tokens, cfg, **handed(mesh))[0]
+
+    def cached(cfg, mesh, **extra):
+        """The module's forward as a decode closure. A state keeps what a
+        padded bucket's tail adds, so a caller that lands a block of prompt
+        positions on a ``told_lengths`` family says how many of them are real
+        (``valid_len``; None = all), as the continuous engine does."""
+        m = mod()
+
+        def fwd(p, t, kv_cache, cache_offset, mesh=mesh, **told):
+            if told_lengths and t.shape[1] > 1 and "valid_len" not in told:
+                raise ValueError(
+                    f"{name}: a block of prompt positions needs its rows' real "
+                    "lengths (a state keeps what a padded tail adds): this family "
+                    "streams through --continuous-batch")
+            return m.forward(p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset,
+                             **handed(mesh), **extra, **told)
+
+        return fwd
+
+    def decode_fns(cfg, mesh=None):
+        m = mod()
+        return cached(cfg, mesh), (lambda b, max_len: m.init_kv_cache(cfg, b, max_len))
+
+    def paged_decode_fns(cfg, mesh=None):
+        fwd = cached(cfg, mesh)
+        return lambda p, t, kv_cache, cache_offset, table, mesh=mesh: fwd(
+            p, t, kv_cache, cache_offset, mesh, paged_table=table)
+
+    def layer_kind_decode_fns(cfg, mesh=None):
+        m = mod()
+        return {
+            "fwd": cached(cfg, mesh, **kind_forward),
+            "init_state": lambda slots, max_len: m.init_layer_state(cfg, slots, max_len),
+            "kinds": m.cache_kinds(cfg),
+            **m.published(cfg),  # "counters" and "gauges": what /metrics names
+        }
+
+    def loop_fns(cfg, mesh, last_pos: Callable[[], int], row_lens=None):
+        """``decode_fns`` for the generic generate loops (models/decode.py): a
+        ``told_lengths`` family's prompt block is told ``row_lens`` (None: the
+        whole block), over a cache of whole buckets; a module with a
+        ``check_context`` is asked first whether it has ``last_pos()`` positions."""
+        check = getattr(mod(), "check_context", None)
+        if check is not None:
+            check(cfg, last_pos())
+        fwd, init = decode_fns(cfg, mesh)
+        if not told_lengths:
+            return fwd, init
+        from modelx_tpu.models.decode import pad_seq_len
+
+        def told(p, t, kv_cache, cache_offset, mesh):
+            block = {"valid_len": row_lens} if t.shape[1] > 1 else {}
+            return fwd(p, t, kv_cache, cache_offset, mesh, **block)
+
+        return told, (lambda b, max_len: init(b, pad_seq_len(max_len)))
+
+    def generate(params, tokens, cfg, mesh=None, max_new_tokens=16):
+        from modelx_tpu.models import decode
+
+        fwd, init = loop_fns(cfg, mesh, lambda: tokens.shape[1] + max_new_tokens)
+        return decode.greedy_generate(
+            fwd, init, params, tokens, max_new_tokens=max_new_tokens, mesh=mesh)
+
+    def generate_ragged(params, tokens, row_lens, cfg, mesh=None, max_new_tokens=16,
+                        **sampling):
+        from modelx_tpu.models import decode
+
+        # prefill touches positions [0, S); each row then decodes to row_len +
+        # max_new (the batcher's bucket rounding can make this conservative by
+        # less than one bucket at the very context edge)
+        fwd, init = loop_fns(cfg, mesh, lambda: max(
+            tokens.shape[1], int(np.max(np.asarray(row_lens))) + max_new_tokens), row_lens)
+        return decode.ragged_greedy_generate(
+            fwd, init, params, tokens, row_lens, max_new_tokens=max_new_tokens, mesh=mesh,
+            **sampling)
+
+    def refuse(params: dict):
+        raise ValueError(
+            f"tensor shapes do not say a {name} checkpoint's architecture: its "
+            "config.json must lie beside the weights")
+
+    def config_from_sidecar(sidecar: dict, params: dict):
+        return mod().config_from_hf(sidecar, dtype=_act_dtype(params, embedding))
+
+    return Family(
+        name, rules, infer_config or refuse, forward, generate, generate_ragged, decode_fns,
+        paged_decode_fns=paged_decode_fns if paged_table else None,
+        config_from_sidecar=None if infer_config else config_from_sidecar,
+        layer_kind_decode_fns=None if kind_forward is None else layer_kind_decode_fns)
+
+
+# one row a family: what the adapter cannot ask of a module it has not imported
+FAMILIES: dict[str, Family] = {f.name: f for f in (
+    _causal("llama", LLAMA_RULES, infer_llama_config, paged_table=True),
     # same decoder implementation as llama — the bias params flow through
     # the param dict, so every llama entry point serves qwen2 unchanged
-    "qwen2": Family("qwen2", QWEN2_RULES, infer_qwen2_config, _llama_forward,
-                    _llama_generate, _llama_generate_ragged, _llama_decode_fns,
-                    _llama_paged_decode_fns),
-    "phi3": Family("phi3", PHI3_RULES, infer_phi3_config, _phi3_forward,
-                  _phi3_generate, _phi3_generate_ragged, _phi3_decode_fns,
-                  _phi3_paged_decode_fns),
-    "gemma2": Family("gemma2", GEMMA2_RULES, infer_gemma2_config,
-                     _gemma2_forward, _gemma2_generate,
-                     _gemma2_generate_ragged, _gemma2_decode_fns,
-                     _gemma2_paged_decode_fns),
-    "mixtral": Family("mixtral", MIXTRAL_RULES, infer_mixtral_config, _mixtral_forward,
-                      _mixtral_generate, _mixtral_generate_ragged, _mixtral_decode_fns,
-                      _mixtral_paged_decode_fns),
-    "laguna": Family("laguna", LAGUNA_RULES, infer_laguna_config, _laguna_forward,
-                     _laguna_generate, _laguna_generate_ragged, _laguna_decode_fns,
-                     config_from_sidecar=laguna_config_from_sidecar,
-                     layer_kind_decode_fns=_laguna_layer_kind_decode_fns),
-    "minicpm_sala": Family("minicpm_sala", MINICPM_SALA_RULES, infer_minicpm_sala_config,
-                           _minicpm_sala_forward, _minicpm_sala_generate,
-                           _minicpm_sala_generate_ragged, _minicpm_sala_decode_fns,
-                           config_from_sidecar=minicpm_sala_config_from_sidecar,
-                           layer_kind_decode_fns=_minicpm_sala_layer_kind_decode_fns),
-    "deepseek_v2": Family("deepseek_v2", DEEPSEEK_V2_RULES, infer_deepseek_v2_config,
-                          _deepseek_v2_forward, _deepseek_v2_generate,
-                          _deepseek_v2_generate_ragged, _deepseek_v2_decode_fns,
-                          config_from_sidecar=deepseek_v2_config_from_sidecar,
-                          layer_kind_decode_fns=_deepseek_v2_layer_kind_decode_fns),
-    "nemotron_h": Family("nemotron_h", NEMOTRON_H_RULES, infer_nemotron_h_config,
-                         _nemotron_h_forward, _nemotron_h_generate,
-                         _nemotron_h_generate_ragged, _nemotron_h_decode_fns,
-                         config_from_sidecar=nemotron_h_config_from_sidecar,
-                         layer_kind_decode_fns=_nemotron_h_layer_kind_decode_fns),
-    "gpt2": Family("gpt2", GPT2_RULES, infer_gpt2_config, _gpt2_forward,
-                   _gpt2_generate, _gpt2_generate_ragged, _gpt2_decode_fns,
-                   _gpt2_paged_decode_fns),
-    "bert": Family("bert", BERT_RULES, infer_bert_config, _bert_forward, None),
-}
+    _causal("qwen2", QWEN2_RULES, infer_qwen2_config, module="llama", paged_table=True),
+    _causal("phi3", PHI3_RULES, infer_phi3_config, paged_table=True),
+    _causal("gemma2", GEMMA2_RULES, infer_gemma2_config, paged_table=True),
+    _causal("mixtral", MIXTRAL_RULES, infer_mixtral_config, paged_table=True),
+    # its window layers' caches are rings where the state is per layer kind
+    _causal("laguna", LAGUNA_RULES, kind_forward={"ring": True}),
+    _causal("minicpm_sala", MINICPM_SALA_RULES, told_lengths=True, kind_forward={}),
+    _causal("deepseek_v2", DEEPSEEK_V2_RULES, kind_forward={}),
+    _causal("nemotron_h", NEMOTRON_H_RULES, told_lengths=True, kind_forward={},
+            embedding="backbone.embeddings.weight"),
+    # models/gpt2.forward reads ``mesh`` (its cache write's kernel rule looks
+    # at it) and has never been handed one
+    _causal("gpt2", GPT2_RULES, infer_gpt2_config, paged_table=True, hands_mesh=False),
+    Family("bert", BERT_RULES, infer_bert_config, _bert_forward),
+)}
 
 
 logger = logging.getLogger("modelx.serve")

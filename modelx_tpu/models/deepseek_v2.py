@@ -335,6 +335,25 @@ def apply_rope(x, positions, cfg: DeepseekV2Config):
 # -- kv state -----------------------------------------------------------------
 
 
+def published(cfg: DeepseekV2Config) -> dict:
+    """What a pod's /metrics names of this family: the counter leaves the
+    decode step accumulates (leaf -> (stats block, its entries' names)) — of
+    its expert layers over ALL slots (idle ones route too), of its latent
+    layers over the rows that hold a context — and the gauges beside them."""
+    return {
+        "counters": {"moe_counts": ("moe", MOE_COUNTERS),
+                     "mla_counts": ("mla", MLA_COUNTERS)},
+        "gauges": {"moe": {"held_experts": cfg.expert_count,
+                           "published_experts": cfg.num_experts,
+                           "sparse_layers": cfg.num_layers - cfg.first_k_dense_replace,
+                           "groups": cfg.n_group, "groups_kept": cfg.topk_group},
+                   "mla": {"layers": cfg.num_layers, "heads": cfg.num_heads,
+                           "kv_lora_rank": cfg.kv_lora_rank,
+                           "rope_dim": cfg.qk_rope_head_dim,
+                           "line_width": cfg.line_width}},
+    }
+
+
 def cache_kinds(cfg: DeepseekV2Config) -> dict[str, str]:
     """Leaf name -> its kind in the engine's state (dl/kv_layout.LayerKindKV):
     a ``"latent"`` line a position a layer, the two ``"counter"`` vectors."""
@@ -517,27 +536,3 @@ def forward(params, tokens, cfg: DeepseekV2Config, positions=None,
     x = _rms_norm(x, params["model.norm.weight"], cfg.rms_eps)
     logits = _linear(x, params["lm_head.weight"])
     return ctx.constrain(logits, "dp", "sp", None), new_cache
-
-
-def greedy_generate(params, prompt, cfg: DeepseekV2Config, max_new_tokens: int = 16,
-                    mesh: Mesh | None = None) -> jax.Array:
-    from modelx_tpu.models import decode
-
-    return decode.greedy_generate(
-        lambda p, t, kv_cache, cache_offset, mesh: forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh),
-        lambda b, max_len: init_kv_cache(cfg, b, max_len),
-        params, prompt, max_new_tokens=max_new_tokens, mesh=mesh)
-
-
-def ragged_greedy_generate(params, prompt, row_lens, cfg: DeepseekV2Config,
-                           max_new_tokens: int = 16, mesh: Mesh | None = None,
-                           temperature=None, top_k=None, top_p=None, seeds=None) -> jax.Array:
-    from modelx_tpu.models import decode
-
-    return decode.ragged_greedy_generate(
-        lambda p, t, kv_cache, cache_offset, mesh: forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh),
-        lambda b, max_len: init_kv_cache(cfg, b, max_len),
-        params, prompt, row_lens, max_new_tokens=max_new_tokens, mesh=mesh,
-        temperature=temperature, top_k=top_k, top_p=top_p, seeds=seeds)

@@ -161,11 +161,12 @@ def forward(
     return logits, new_cache
 
 
-def _check_context(cfg: GPT2Config, last_pos: int) -> None:
+def check_context(cfg: GPT2Config, last_pos: int) -> None:
     """Positions past the learned wpe table CLAMP inside jit and return
-    plausible garbage; decode entry points refuse up front instead. The
-    bound is on positions actually decoded — bucketed paths deliberately
-    over-allocate CACHE beyond prompt+max_new, which is harmless."""
+    plausible garbage; the generate loops (dl/families) ask this first and
+    refuse up front instead. The bound is on positions actually decoded —
+    bucketed paths deliberately over-allocate CACHE beyond prompt+max_new,
+    which is harmless."""
     if last_pos > cfg.n_positions:
         raise ValueError(
             f"prompt + max_new_tokens needs {last_pos} positions, but this "
@@ -182,37 +183,3 @@ def init_kv_cache(cfg: GPT2Config, batch: int, max_len: int, dtype=None) -> dict
         for i in range(cfg.num_layers)
         for kind in ("k", "v")
     }
-
-
-def greedy_generate(params, prompt, cfg: GPT2Config, max_new_tokens: int = 16, mesh=None):
-    from modelx_tpu.models import decode
-
-    _check_context(cfg, prompt.shape[1] + max_new_tokens)
-    return decode.greedy_generate(
-        lambda p, t, kv_cache=None, cache_offset=0, mesh=None: forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset
-        ),
-        lambda b, n: init_kv_cache(cfg, b, n),
-        params, prompt, max_new_tokens=max_new_tokens, mesh=mesh,
-    )
-
-
-def ragged_greedy_generate(params, prompt, row_lens, cfg: GPT2Config,
-                           max_new_tokens: int = 16, mesh=None, **sampling):
-    import numpy as _np
-
-    from modelx_tpu.models import decode
-
-    # prefill touches positions [0, S); each row then decodes to
-    # row_len + max_new. (The serving batcher's bucket rounding can make
-    # this conservative by < one bucket at the very context edge.)
-    _check_context(cfg, max(prompt.shape[1],
-                            int(_np.max(_np.asarray(row_lens))) + max_new_tokens))
-    return decode.ragged_greedy_generate(
-        lambda p, t, kv_cache=None, cache_offset=0, mesh=None: forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset
-        ),
-        lambda b, n: init_kv_cache(cfg, b, n),
-        params, prompt, row_lens, max_new_tokens=max_new_tokens, mesh=mesh,
-        **sampling,
-    )

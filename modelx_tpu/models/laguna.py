@@ -366,6 +366,19 @@ def init_kv_cache(cfg: LagunaConfig, batch: int, max_len: int, dtype=None) -> di
     return {f"{kv}{i}": jnp.zeros(shape, dtype) for i in range(cfg.num_layers) for kv in "kv"}
 
 
+def published(cfg: LagunaConfig) -> dict:
+    """What a pod's /metrics names of this family: the counter leaves the
+    decode step accumulates (leaf -> (stats block, its entries' names)) — of
+    its expert layers, over ALL slots (idle ones route too) — and the gauges
+    those counts are shares of."""
+    return {
+        "counters": {"moe_counts": ("moe", MOE_COUNTERS)},
+        "gauges": {"moe": {"held_experts": cfg.expert_count,
+                           "published_experts": cfg.num_experts,
+                           "sparse_layers": cfg.mlp_layer_types.count("sparse")}},
+    }
+
+
 def cache_kinds(cfg: LagunaConfig) -> dict[str, str]:
     """Leaf name -> ``"full"`` / ``"window"`` / ``"counter"`` of the engine's
     state (:func:`init_layer_state`)."""
@@ -512,27 +525,3 @@ def forward(params, tokens, cfg: LagunaConfig, positions=None, kv_cache: dict | 
     x = _rms_norm(x, params["model.norm.weight"], cfg.rms_eps)
     logits = _linear(x, params["lm_head.weight"])
     return ctx.constrain(logits, "dp", "sp", None), new_cache
-
-
-def greedy_generate(params, prompt, cfg: LagunaConfig, max_new_tokens: int = 16,
-                    mesh: Mesh | None = None) -> jax.Array:
-    from modelx_tpu.models import decode
-
-    return decode.greedy_generate(
-        lambda p, t, kv_cache, cache_offset, mesh: forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh),
-        lambda b, max_len: init_kv_cache(cfg, b, max_len),
-        params, prompt, max_new_tokens=max_new_tokens, mesh=mesh)
-
-
-def ragged_greedy_generate(params, prompt, row_lens, cfg: LagunaConfig,
-                           max_new_tokens: int = 16, mesh: Mesh | None = None,
-                           temperature=None, top_k=None, top_p=None, seeds=None) -> jax.Array:
-    from modelx_tpu.models import decode
-
-    return decode.ragged_greedy_generate(
-        lambda p, t, kv_cache, cache_offset, mesh: forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh),
-        lambda b, max_len: init_kv_cache(cfg, b, max_len),
-        params, prompt, row_lens, max_new_tokens=max_new_tokens, mesh=mesh,
-        temperature=temperature, top_k=top_k, top_p=top_p, seeds=seeds)

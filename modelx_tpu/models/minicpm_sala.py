@@ -264,6 +264,20 @@ def init_params(cfg: SalaConfig, key: jax.Array, dtype=None) -> dict[str, jax.Ar
 # -- kv state -----------------------------------------------------------------
 
 
+def published(cfg: SalaConfig) -> dict:
+    """What a pod's /metrics names of this family: the counter leaf the decode
+    step accumulates (leaf -> (stats block, its entries' names)) — of its
+    sparse layers, over the LIVE rows — and the gauges beside it."""
+    sparse_layers = cfg.mixer_types.count(SPARSE)
+    return {
+        "counters": {"sparse_counts": ("sparse", SPARSE_COUNTERS)},
+        "gauges": {"sparse": {"sparse_layers": sparse_layers,
+                              "linear_layers": cfg.num_layers - sparse_layers,
+                              "block_size": cfg.sparse.block_size, "topk": cfg.sparse.topk,
+                              "dense_len": cfg.sparse.dense_len}},
+    }
+
+
 def cache_kinds(cfg: SalaConfig) -> dict[str, str]:
     """Leaf name -> its kind in the engine's state (dl/kv_layout.LayerKindKV):
     ``"full"`` keys and values, the ``"index"`` of compressed keys, a
@@ -562,34 +576,3 @@ def forward(params, tokens, cfg: SalaConfig, positions=None, kv_cache: dict | No
     x = (x.astype(jnp.float32) / (cfg.hidden_size / cfg.dim_model_base)).astype(x.dtype)
     logits = _linear(x, params["lm_head.weight"])
     return ctx.constrain(logits, "dp", "sp", None), new_cache
-
-
-def _cached(cfg: SalaConfig, row_lens=None):
-    """The forward the generic generate loops call: a prompt block's real
-    lengths (``row_lens``; the whole block when None) reach the states."""
-    def fwd(p, t, kv_cache, cache_offset, mesh):
-        valid = row_lens if t.shape[1] > 1 else None
-        return forward(p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh,
-                       valid_len=valid)
-    return fwd
-
-
-def greedy_generate(params, prompt, cfg: SalaConfig, max_new_tokens: int = 16,
-                    mesh: Mesh | None = None) -> jax.Array:
-    from modelx_tpu.models import decode
-
-    return decode.greedy_generate(
-        _cached(cfg), lambda b, max_len: init_kv_cache(cfg, b, decode.pad_seq_len(max_len)),
-        params, prompt, max_new_tokens=max_new_tokens, mesh=mesh)
-
-
-def ragged_greedy_generate(params, prompt, row_lens, cfg: SalaConfig,
-                           max_new_tokens: int = 16, mesh: Mesh | None = None,
-                           temperature=None, top_k=None, top_p=None, seeds=None) -> jax.Array:
-    from modelx_tpu.models import decode
-
-    return decode.ragged_greedy_generate(
-        _cached(cfg, row_lens),
-        lambda b, max_len: init_kv_cache(cfg, b, decode.pad_seq_len(max_len)),
-        params, prompt, row_lens, max_new_tokens=max_new_tokens, mesh=mesh,
-        temperature=temperature, top_k=top_k, top_p=top_p, seeds=seeds)

@@ -198,48 +198,3 @@ def init_kv_cache(cfg: MixtralConfig, batch: int, max_len: int, dtype=None) -> d
         cache[f"k{i}"] = jnp.zeros(shape, dtype)
         cache[f"v{i}"] = jnp.zeros(shape, dtype)
     return cache
-
-
-def greedy_generate(
-    params: dict[str, jax.Array],
-    prompt: jax.Array,  # [B, S]
-    cfg: MixtralConfig,
-    max_new_tokens: int = 16,
-    mesh: Mesh | None = None,
-) -> jax.Array:
-    """Greedy decode with a static-shape KV cache; expert routing runs per
-    decoded token. Shared scan implementation: models/decode.py."""
-    from modelx_tpu.models import decode
-
-    return decode.greedy_generate(
-        lambda p, t, kv_cache, cache_offset, mesh: forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh
-        ),
-        lambda b, max_len: init_kv_cache(cfg, b, max_len),
-        params, prompt, max_new_tokens=max_new_tokens, mesh=mesh,
-    )
-
-
-def ragged_greedy_generate(
-    params: dict[str, jax.Array],
-    prompt: jax.Array,  # [B, S] right-padded
-    row_lens: jax.Array,  # [B]
-    cfg: MixtralConfig,
-    max_new_tokens: int = 16,
-    mesh: Mesh | None = None,
-    temperature=None,
-    top_k=None,
-    top_p=None,
-    seeds=None,
-) -> jax.Array:
-    """Ragged-batch decode, greedy or per-row-sampled; returns generated tokens [B, max_new]."""
-    from modelx_tpu.models import decode
-
-    return decode.ragged_greedy_generate(
-        lambda p, t, kv_cache, cache_offset, mesh: forward(
-            p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh
-        ),
-        lambda b, max_len: init_kv_cache(cfg, b, max_len),
-        params, prompt, row_lens, max_new_tokens=max_new_tokens, mesh=mesh,
-        temperature=temperature, top_k=top_k, top_p=top_p, seeds=seeds,
-    )

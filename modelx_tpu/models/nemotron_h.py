@@ -341,6 +341,25 @@ def to_hf_state_dict(params: Mapping[str, Any], first: int = 0) -> dict[str, np.
 _LEAVES = {MAMBA: ("s", "t"), ATTENTION: ("k", "v"), EXPERTS: (), DENSE: ()}
 
 
+def published(cfg: NemotronHConfig) -> dict:
+    """What a pod's /metrics names of this family: the counter leaves the
+    decode step accumulates (leaf -> (stats block, its entries' names)) — of
+    its expert layers over ALL slots (idle ones route too); of its rows, live
+    ones and all, once a step — and the gauges beside them."""
+    return {
+        "counters": {"moe_counts": ("moe", MOE_COUNTERS),
+                     "ssm_counts": ("ssm", SSM_COUNTERS)},
+        "gauges": {"moe": {"held_experts": cfg.expert_count,
+                           "published_experts": cfg.num_experts,
+                           "sparse_layers": cfg.pattern.count(EXPERTS),
+                           "latent_size": cfg.moe_latent_size},
+                   "ssm": {"layers": cfg.pattern.count(MAMBA),
+                           "heads": cfg.mamba_heads, "head_dim": cfg.mamba_head_dim,
+                           "state_size": cfg.ssm_state_size, "groups": cfg.n_groups,
+                           "conv_kernel": cfg.conv_kernel}},
+    }
+
+
 def cache_kinds(cfg: NemotronHConfig) -> dict[str, str]:
     """Leaf name -> its kind in the engine's state (dl/kv_layout.LayerKindKV):
     a Mamba layer's two ``"state"`` leaves (the recurrence's state and the
@@ -603,34 +622,3 @@ def forward(params, tokens, cfg: NemotronHConfig, kv_cache: dict | None = None,
     x = _rms_norm(x, params["backbone.norm_f.weight"], cfg.rms_eps)
     logits = _linear(x, params["lm_head.weight"])
     return ctx.constrain(logits, "dp", "sp", None), new_cache
-
-
-def _cached(cfg: NemotronHConfig, row_lens=None):
-    """The forward the generic generate loops call: a prompt block's real
-    lengths (``row_lens``; the whole block when None) reach the states."""
-    def fwd(p, t, kv_cache, cache_offset, mesh):
-        valid = row_lens if t.shape[1] > 1 else None
-        return forward(p, t, cfg, kv_cache=kv_cache, cache_offset=cache_offset, mesh=mesh,
-                       valid_len=valid)
-    return fwd
-
-
-def greedy_generate(params, prompt, cfg: NemotronHConfig, max_new_tokens: int = 16,
-                    mesh: Mesh | None = None) -> jax.Array:
-    from modelx_tpu.models import decode
-
-    return decode.greedy_generate(
-        _cached(cfg), lambda b, max_len: init_kv_cache(cfg, b, decode.pad_seq_len(max_len)),
-        params, prompt, max_new_tokens=max_new_tokens, mesh=mesh)
-
-
-def ragged_greedy_generate(params, prompt, row_lens, cfg: NemotronHConfig,
-                           max_new_tokens: int = 16, mesh: Mesh | None = None,
-                           temperature=None, top_k=None, top_p=None, seeds=None) -> jax.Array:
-    from modelx_tpu.models import decode
-
-    return decode.ragged_greedy_generate(
-        _cached(cfg, row_lens),
-        lambda b, max_len: init_kv_cache(cfg, b, decode.pad_seq_len(max_len)),
-        params, prompt, row_lens, max_new_tokens=max_new_tokens, mesh=mesh,
-        temperature=temperature, top_k=top_k, top_p=top_p, seeds=seeds)

@@ -6,6 +6,7 @@ import pytest
 
 from modelx_tpu.client.convert import _apply_renames, _flatten
 from modelx_tpu.dl import safetensors as st
+from modelx_tpu.dl.families import FAMILIES
 
 
 class TestFlatten:
@@ -138,7 +139,7 @@ class TestTorch:
         server = ModelServer(str(dst), mesh_spec="dp=1", dtype="float32", name="t")
         server.load()
         out = server.generate(np.asarray([[1, 2, 3]], np.int32), max_new_tokens=4)
-        want = llama.greedy_generate(
+        want = FAMILIES["llama"].generate(
             params, jnp.asarray([[1, 2, 3]], jnp.int32), cfg, max_new_tokens=4
         )
         np.testing.assert_array_equal(out, np.asarray(want))

@@ -606,6 +606,21 @@ PARENT_PROGRAMS.update({
     "nemotron_h.admit": "193f324e542b3f064c2cdfda82185dcb079b16a2199d4789333a563be544de5e",
     "nemotron_h.piece": "38a1bd0cf07ef3251bef61edeb029b49bc6242db560184ca0e291c221c7db483",
 })
+# PR 49 (every field of a ``Family`` is built from its model module by one adapter in
+# ``dl/families.py``; the per-family closures and the modules' generate wrappers went):
+# the three families the table left out, taken on ITS parent (9742c99) before the first
+# edit — phi3 is the deploy cell's family
+PARENT_PROGRAMS.update({
+    "phi3.chunk": "42e4f7cc5d75ecda0cdc854c9549f383cfff5c66026591d002f0ce45ef1007b1",
+    "phi3.admit": "b1d9d9413dfea319d381e50493457eb8755ebc85d60b540020d12154599f357c",
+    "phi3.piece": "6db48b13bee5ae573dddfa31ed60541904d5e36632c73a900adc1e199397706f",
+    "gemma2.chunk": "60b785d68f8cbfcaef65dddb6ba3214fdeffce9ad20ee6ac803d6b9832aa3b8d",
+    "gemma2.admit": "ad2a1412f6dc9d03a21d1d153b5633a241fab8dd98cb7880d3a8153825729290",
+    "gemma2.piece": "1b62535665256298725e5ccc52b8c6b568d2cca7460aeb00f2492f4bab391ea2",
+    "gpt2.chunk": "9f9d94e30162a6a868eb44e73bf88847f98dca19ace2edd51fe7221c3c55b439",
+    "gpt2.admit": "8f3a159318a95acac721e3dd7161e1224c907e0d9db4918a37697c76937704d2",
+    "gpt2.piece": "227ad4135b141c5394221db2e78831e8ab8f5202baaed1d1e50e9a1ba1403457",
+})
 # the ragged decode kernel's own jaxpr (the Mosaic body's source; it names no file) at
 # the two decode cells' widths: (rows, query heads, cache length)
 PARENT_RAGGED_KERNEL = {
@@ -620,8 +635,11 @@ def tiny_family(family: str):
     module = importlib.import_module("modelx_tpu.models." + family)
     name = {"llama": "LlamaConfig", "mixtral": "MixtralConfig", "laguna": "LagunaConfig",
             "minicpm_sala": "SalaConfig", "deepseek_v2": "DeepseekV2Config",
-            "nemotron_h": "NemotronHConfig"}[family]
-    return module, getattr(module, name).tiny(vocab_size=64)
+            "nemotron_h": "NemotronHConfig", "phi3": "LlamaConfig", "gemma2": "Gemma2Config",
+            "gpt2": "GPT2Config"}[family]
+    # phi3 is llama's decoder under fused weights (its config is llama's, which its
+    # module names too); gpt2's tiny preset has one vocabulary
+    return module, getattr(module, name).tiny(**({} if family == "gpt2" else {"vocab_size": 64}))
 
 
 def lowered_programs(family: str) -> dict:
@@ -659,7 +677,7 @@ def lowered_programs(family: str) -> dict:
 
 
 @pytest.mark.parametrize("family", ["llama", "mixtral", "minicpm_sala", "laguna", "deepseek_v2",
-                                    "nemotron_h"])
+                                    "nemotron_h", "phi3", "gemma2", "gpt2"])
 def test_the_other_families_programs_lower_to_the_parents_text(family):
     import hashlib
 

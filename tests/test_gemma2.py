@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from modelx_tpu.dl import families as fam
+from modelx_tpu.dl.families import FAMILIES
 from modelx_tpu.parallel.mesh import make_mesh
 
 transformers = pytest.importorskip("transformers")
@@ -174,7 +175,7 @@ class TestDecode:
         cfg = _tiny_cfg()
         params = gemma2.init_params(cfg, jax.random.PRNGKey(2))
         prompt = jnp.asarray([[5, 9, 2]], jnp.int32)
-        got = gemma2.greedy_generate(params, prompt, cfg, max_new_tokens=9)
+        got = FAMILIES["gemma2"].generate(params, prompt, cfg, max_new_tokens=9)
         # naive: full re-forward per step
         toks = prompt
         for _ in range(9):
@@ -272,7 +273,7 @@ class TestServing:
         # inferred config (not the constructor's) drives serving, so this
         # also pins tiny-shape inference to the tiny() constants
         icfg = server.family.infer_config(params)
-        want = gemma2.greedy_generate(params, jnp.asarray(prompt), icfg, max_new_tokens=6)
+        want = FAMILIES["gemma2"].generate(params, jnp.asarray(prompt), icfg, max_new_tokens=6)
         np.testing.assert_array_equal(got, np.asarray(want))
 
         cb = ContinuousBatcher(server, max_slots=2, chunk_size=4)

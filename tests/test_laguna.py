@@ -195,7 +195,8 @@ def test_a_half_held_checkpoint_folds_and_gives_the_references_logits(half):
 def test_decode_through_a_dense_cache_follows_the_reference(whole):
     srv, hf, raw = whole
     prompt = np.random.default_rng(3).integers(1, VOCAB, (1, 20))
-    out = np.asarray(laguna.greedy_generate(srv.params, jnp.asarray(prompt), srv.cfg, 30))
+    out = np.asarray(FAMILIES["laguna"].generate(
+        srv.params, jnp.asarray(prompt), srv.cfg, max_new_tokens=30))
     seq = np.concatenate([prompt[0], out[0, -30:]])
     assert (ref_logits(hf, raw, seq).argmax(-1)[19:-1] == seq[20:]).all()
 

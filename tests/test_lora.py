@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from modelx_tpu.dl import safetensors as st
+from modelx_tpu.dl.families import FAMILIES
 from modelx_tpu.dl.lora import merge_adapter, parse_adapter_dir
 
 
@@ -156,7 +157,7 @@ class TestServeIntegration:
 
         merged = dict(params)
         merged[target] = params[target] + 2.0 * jnp.asarray(b @ a)
-        want = llama.greedy_generate(
+        want = FAMILIES["llama"].generate(
             merged, jnp.asarray(prompt), cfg, max_new_tokens=4
         )
         np.testing.assert_array_equal(got, np.asarray(want))

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from modelx_tpu.dl import kv_layout
 from modelx_tpu.dl import safetensors as st
 from modelx_tpu.dl.continuous import ContinuousBatcher
-from modelx_tpu.dl.families import detect
+from modelx_tpu.dl.families import FAMILIES, detect
 from modelx_tpu.dl.serve import ModelServer, ServerSet
 from modelx_tpu.dl.sharding import MINICPM_SALA_RULES, spec_for
 from modelx_tpu.models import minicpm_sala as sala, minicpm_sala_reference as reference
@@ -377,7 +377,8 @@ def test_a_block_of_prompt_positions_must_say_how_many_are_real(served):
 def test_decode_through_the_plain_generate_loop_follows_the_reference(served):
     srv, params, raw, _ = served
     prompt = np.random.default_rng(3).integers(1, VOCAB, (1, 20))
-    out = np.asarray(sala.greedy_generate(srv.params, jnp.asarray(prompt), srv.cfg, 30))
+    out = np.asarray(FAMILIES["minicpm_sala"].generate(
+        srv.params, jnp.asarray(prompt), srv.cfg, max_new_tokens=30))
     seq = np.concatenate([prompt[0], out[0, -30:]])
     logits = ref_logits(params, raw, seq)[19:-1]
     assert (logits.max(-1) - logits[np.arange(30), seq[20:]]).max() < 1e-3

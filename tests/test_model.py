@@ -8,6 +8,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from modelx_tpu.dl.families import FAMILIES
 from modelx_tpu.dl.sharding import LLAMA_RULES
 from modelx_tpu.models import llama
 from modelx_tpu.models.train import (
@@ -275,7 +276,7 @@ class TestLlama:
         )
 
     def test_greedy_generate(self, cfg, params, tokens):
-        out = llama.greedy_generate(params, tokens[:, :8], cfg, max_new_tokens=4)
+        out = FAMILIES["llama"].generate(params, tokens[:, :8], cfg, max_new_tokens=4)
         assert out.shape == (2, 12)
         full, _ = llama.forward(params, tokens[:, :8], cfg)
         np.testing.assert_array_equal(
@@ -347,12 +348,12 @@ class TestRaggedDecode:
         batch = np.zeros((len(lens), S), np.int32)
         for i, p in enumerate(prompts):
             batch[i, : lens[i]] = np.asarray(p[0])
-        got = llama.ragged_greedy_generate(
+        got = FAMILIES["llama"].generate_ragged(
             params, jnp.asarray(batch), jnp.asarray(lens), cfg, max_new_tokens=new
         )
         assert got.shape == (len(lens), new)
         for i, p in enumerate(prompts):
-            solo = llama.greedy_generate(params, p, cfg, max_new_tokens=new)
+            solo = FAMILIES["llama"].generate(params, p, cfg, max_new_tokens=new)
             np.testing.assert_array_equal(
                 np.asarray(got[i]), np.asarray(solo[0, lens[i]:]), err_msg=f"row {i}"
             )
@@ -363,16 +364,16 @@ class TestRaggedDecode:
         rng = np.random.RandomState(8)
         prompt = jnp.array(rng.randint(1, cfg.vocab_size, (3, 9)), jnp.int32)
         new = 5
-        got = llama.ragged_greedy_generate(
+        got = FAMILIES["llama"].generate_ragged(
             params, prompt, jnp.full((3,), 9, jnp.int32), cfg, max_new_tokens=new
         )
-        plain = llama.greedy_generate(params, prompt, cfg, max_new_tokens=new)
+        plain = FAMILIES["llama"].generate(params, prompt, cfg, max_new_tokens=new)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(plain[:, 9:]))
 
     def test_zero_new_tokens(self):
         cfg = self._f32_cfg()
         params = llama.init_params(cfg, jax.random.PRNGKey(5))
-        out = llama.ragged_greedy_generate(
+        out = FAMILIES["llama"].generate_ragged(
             params, jnp.ones((2, 4), jnp.int32), jnp.array([2, 4]), cfg, max_new_tokens=0
         )
         assert out.shape == (2, 0)
@@ -395,11 +396,11 @@ class TestRaggedDecode:
             p = rng.randint(1, cfg.vocab_size, (1, n)).astype(np.int32)
             prompts.append(jnp.asarray(p))
             batch[i, :n] = p[0]
-        got = mixtral.ragged_greedy_generate(
+        got = FAMILIES["mixtral"].generate_ragged(
             params, jnp.asarray(batch), jnp.asarray(lens), cfg, max_new_tokens=new
         )
         for i, p in enumerate(prompts):
-            solo = mixtral.greedy_generate(params, p, cfg, max_new_tokens=new)
+            solo = FAMILIES["mixtral"].generate(params, p, cfg, max_new_tokens=new)
             np.testing.assert_array_equal(
                 np.asarray(got[i]), np.asarray(solo[0, lens[i]:]), err_msg=f"row {i}"
             )
@@ -489,14 +490,14 @@ class TestRaggedSampling:
         kw = dict(max_new_tokens=6, temperature=jnp.array([0.9]),
                   top_k=jnp.array([0], jnp.int32), top_p=jnp.array([1.0]),
                   seeds=jnp.array([42], jnp.int32))
-        solo = llama.ragged_greedy_generate(
+        solo = FAMILIES["llama"].generate_ragged(
             params, jnp.asarray(prompt), jnp.array([5]), cfg, **kw)
         # same row inside a 3-row ragged batch with different neighbors
         other = rng.randint(1, cfg.vocab_size, (2, 9)).astype(np.int32)
         batch = np.zeros((3, 9), np.int32)
         batch[0, :5] = prompt[0]
         batch[1:] = other
-        out = llama.ragged_greedy_generate(
+        out = FAMILIES["llama"].generate_ragged(
             params, jnp.asarray(batch), jnp.array([5, 9, 9]), cfg,
             max_new_tokens=6,
             temperature=jnp.array([0.9, 0.0, 1.5]),
@@ -506,6 +507,6 @@ class TestRaggedSampling:
         )
         np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(solo[0]))
         # the greedy row matches plain greedy decoding
-        greedy = llama.ragged_greedy_generate(
+        greedy = FAMILIES["llama"].generate_ragged(
             params, jnp.asarray(other), jnp.array([9, 9]), cfg, max_new_tokens=6)
         np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(greedy[0]))

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from modelx_tpu.dl import families as fam
+from modelx_tpu.dl.families import FAMILIES
 from modelx_tpu.dl.sharding import BERT_RULES, GPT2_RULES
 from modelx_tpu.models import bert, gpt2
 from modelx_tpu.parallel.mesh import make_mesh
@@ -73,7 +74,7 @@ class TestGPT2:
             naive = jnp.concatenate(
                 [naive, jnp.argmax(logits[:, -1:, :], axis=-1).astype(naive.dtype)], axis=1
             )
-        cached = gpt2.greedy_generate(params, prompt, cfg, max_new_tokens=n)
+        cached = FAMILIES["gpt2"].generate(params, prompt, cfg, max_new_tokens=n)
         np.testing.assert_array_equal(np.asarray(cached), np.asarray(naive))
 
     def test_ragged_decode_matches_unbatched(self):
@@ -82,7 +83,7 @@ class TestGPT2:
         rows = [[3, 14, 15], [9, 2, 6, 5, 3]]
         n = 6
         want = [
-            np.asarray(gpt2.greedy_generate(
+            np.asarray(FAMILIES["gpt2"].generate(
                 params, jnp.asarray([r], jnp.int32), cfg, max_new_tokens=n
             ))[0, len(r):]
             for r in rows
@@ -91,7 +92,7 @@ class TestGPT2:
         padded = np.zeros((2, s), np.int32)
         for i, r in enumerate(rows):
             padded[i, :len(r)] = r
-        got = gpt2.ragged_greedy_generate(
+        got = FAMILIES["gpt2"].generate_ragged(
             params, jnp.asarray(padded), np.asarray([len(r) for r in rows], np.int32),
             cfg, max_new_tokens=n,
         )
@@ -292,7 +293,7 @@ class TestQwen2:
         assert server.family.name == "qwen2"
         prompt = np.asarray([[1, 2, 3]], np.int32)
         got = server.generate(prompt, max_new_tokens=4)
-        want = llama.greedy_generate(params, jnp.asarray(prompt), cfg, max_new_tokens=4)
+        want = FAMILIES["llama"].generate(params, jnp.asarray(prompt), cfg, max_new_tokens=4)
         np.testing.assert_array_equal(got, np.asarray(want))
 
 
@@ -412,7 +413,7 @@ class TestMixtralGenerate:
         cfg = dataclasses.replace(mixtral.MixtralConfig.tiny(vocab_size=64), dtype=jnp.float32)
         params = mixtral.init_params(cfg, jax.random.PRNGKey(5))
         prompt = jnp.array([[3, 9, 12, 7]], jnp.int32)
-        out = mixtral.greedy_generate(params, prompt, cfg, max_new_tokens=5)
+        out = FAMILIES["mixtral"].generate(params, prompt, cfg, max_new_tokens=5)
 
         naive = prompt
         for _ in range(5):
@@ -420,3 +421,89 @@ class TestMixtralGenerate:
             nxt = jnp.argmax(logits[:, -1:, :], axis=-1).astype(naive.dtype)
             naive = jnp.concatenate([naive, nxt], axis=1)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(naive))
+
+
+# -- a family is its model module behind one adapter (PR 49) ------------------------
+
+
+def _module_and_tiny(name: str):
+    from test_engine_programs import tiny_family
+
+    # qwen2 is llama's module under its own rules and shape reader
+    return tiny_family({"qwen2": "llama"}.get(name, name))
+
+
+@pytest.mark.parametrize("name", list(FAMILIES))
+def test_every_family_is_its_module_behind_the_adapter(name):
+    """The module interface ``dl/families.py`` states, held to each row of the
+    table: what a row says of its module (``paged_table``, ``told_lengths``,
+    a cache per layer kind, config.json as the source) is what the module's
+    own signatures and functions say."""
+    import inspect
+
+    family = FAMILIES[name]
+    assert family.name == name
+    if name == "bert":  # an encoder: its one forward, nothing to decode
+        assert family.generate is family.generate_ragged is family.decode_fns is None
+        assert family.paged_decode_fns is family.layer_kind_decode_fns is None
+        return
+    module, cfg = _module_and_tiny(name)
+    takes = inspect.signature(module.forward).parameters
+    assert list(takes)[:3] == ["params", "tokens", "cfg"]
+    assert {"kv_cache", "cache_offset", "mesh"} <= set(takes)
+    assert list(inspect.signature(module.init_kv_cache).parameters)[:3] == [
+        "cfg", "batch", "max_len"]
+    assert None not in (family.generate, family.generate_ragged, family.decode_fns)
+    assert (family.paged_decode_fns is not None) == ("paged_table" in takes)
+
+    # config.json is the source where shapes cannot say, and a checkpoint
+    # without one is refused in words that name the family
+    assert (family.config_from_sidecar is not None) == (name in (
+        "laguna", "minicpm_sala", "deepseek_v2", "nemotron_h"))
+    if family.config_from_sidecar is not None:
+        assert callable(module.config_from_hf)
+        with pytest.raises(ValueError, match=f"{name} checkpoint.*config.json must lie beside"):
+            family.infer_config({})
+
+    # a block of prompt positions lands untold exactly where no layer keeps a state
+    fwd, init = family.decode_fns(cfg)
+    block = jax.ShapeDtypeStruct((1, 16), jnp.int32)
+    params = jax.eval_shape(lambda: module.init_params(cfg, jax.random.PRNGKey(0)))
+    lands = lambda **told: jax.eval_shape(  # noqa: E731
+        lambda p, t: fwd(p, t, init(1, 32), 0, **told)[0], params, block)
+    if {"valid_len", "live"} <= set(takes):
+        with pytest.raises(ValueError, match="real lengths"):
+            lands()
+        assert lands(valid_len=None).shape == (1, 16, cfg.vocab_size)
+    else:
+        assert not {"valid_len", "live"} & set(takes)
+        assert lands().shape == (1, 16, cfg.vocab_size)
+
+    # a cache per layer kind: the state's leaves are the kinds', and what
+    # /metrics names is the module's own
+    kinds = family.layer_kind_decode_fns
+    assert (kinds is not None) == hasattr(module, "init_layer_state") == hasattr(
+        module, "cache_kinds") == hasattr(module, "published")
+    if kinds is not None:
+        fns = kinds(cfg)
+        assert set(fns) == {"fwd", "init_state", "kinds", "counters", "gauges"}
+        assert fns["kinds"] == module.cache_kinds(cfg)
+        assert set(jax.eval_shape(lambda: fns["init_state"](2, 32))) == set(fns["kinds"])
+        own = [v for k, v in vars(module).items() if k.endswith("_COUNTERS")]
+        for leaf, (block, names) in fns["counters"].items():
+            assert fns["kinds"][leaf] == "counter" and any(names is t for t in own)
+            assert block in fns["gauges"]
+        assert {k: fns[k] for k in ("counters", "gauges")} == module.published(cfg)
+
+
+def test_importing_the_table_imports_no_model_module():
+    """A pod's start pays for the family it serves, at its first call."""
+    import subprocess
+    import sys
+
+    code = ("import sys, modelx_tpu.dl.families\n"
+            "print([m for m in sys.modules if m.startswith('modelx_tpu.models')])\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                         timeout=240, env={**__import__("os").environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == "[]"

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from modelx_tpu.dl import kv_layout
 from modelx_tpu.dl import safetensors as st
 from modelx_tpu.dl.continuous import ContinuousBatcher
-from modelx_tpu.dl.families import detect
+from modelx_tpu.dl.families import FAMILIES, detect
 from modelx_tpu.dl.serve import ModelServer
 from modelx_tpu.dl.sharding import NEMOTRON_H_RULES, spec_for
 from modelx_tpu.models import nemotron_h as nh, nemotron_h_reference as reference
@@ -495,8 +495,8 @@ def test_a_block_of_prompt_positions_must_say_how_many_are_real(served):
 def test_decode_through_the_plain_generate_loop_follows_the_reference(served):
     srv, hf, raw, cfg = served
     prompt = np.random.default_rng(3).integers(1, VOCAB, (1, 13))
-    out = np.asarray(nh.ragged_greedy_generate(srv.params, jnp.asarray(prompt), jnp.asarray([13]),
-                                               cfg, max_new_tokens=10))[0][-10:]
+    out = np.asarray(FAMILIES["nemotron_h"].generate_ragged(
+        srv.params, jnp.asarray(prompt), jnp.asarray([13]), cfg, max_new_tokens=10))[0][-10:]
     follows_the_reference(hf, raw, prompt[0], out)
 
 

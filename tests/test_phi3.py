@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from modelx_tpu.dl import families as fam
+from modelx_tpu.dl.families import FAMILIES
 from modelx_tpu.parallel.mesh import make_mesh
 
 transformers = pytest.importorskip("transformers")
@@ -160,7 +161,7 @@ class TestServing:
         prompt = np.asarray([[1, 2, 3]], np.int32)
         got = server.generate(prompt, max_new_tokens=6)
         icfg = server.family.infer_config(params)
-        want = phi3.greedy_generate(params, jnp.asarray(prompt), icfg,
+        want = FAMILIES["phi3"].generate(params, jnp.asarray(prompt), icfg,
                                     max_new_tokens=6)
         np.testing.assert_array_equal(got, np.asarray(want))
         cb = ContinuousBatcher(server, max_slots=2, chunk_size=4)

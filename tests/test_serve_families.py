@@ -11,6 +11,7 @@ import requests
 import jax.numpy as jnp
 
 from modelx_tpu.dl import families as fam
+from modelx_tpu.dl.families import FAMILIES
 from modelx_tpu.dl import safetensors as st
 from modelx_tpu.dl.serve import ModelServer, ServerSet, serve
 from modelx_tpu.registry.server import free_port
@@ -651,9 +652,9 @@ class TestGPT2PositionBound:
         params = gpt2.init_params(cfg, _jax.random.PRNGKey(0))
         prompt = np.ones((1, 60), np.int32)
         with pytest.raises(ValueError, match="position context"):
-            gpt2.greedy_generate(params, prompt, cfg, max_new_tokens=5)
+            FAMILIES["gpt2"].generate(params, prompt, cfg, max_new_tokens=5)
         with pytest.raises(ValueError, match="position context"):
-            gpt2.ragged_greedy_generate(
+            FAMILIES["gpt2"].generate_ragged(
                 params, prompt, np.asarray([60], np.int32), cfg, max_new_tokens=5
             )
         # over-allocated cache alone is fine (bucketing does this)

@@ -9,6 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from modelx_tpu.dl.families import FAMILIES
 from modelx_tpu.models import llama
 from modelx_tpu.models.speculative import (
     SpeculativeDecoder,
@@ -72,7 +73,7 @@ class TestNgramPropose:
 class TestExactness:
     def _plain(self, model, prompt, n):
         params, cfg, _fwd, _init = model
-        return llama.greedy_generate(params, jnp.asarray(prompt), cfg, max_new_tokens=n)
+        return FAMILIES["llama"].generate(params, jnp.asarray(prompt), cfg, max_new_tokens=n)
 
     # tier-1 wall: k=4 carries tier-1, the k sweep rides `make slow`
     @pytest.mark.parametrize(
@@ -251,7 +252,7 @@ class TestCacheConsistency:
         prompt = np.asarray([[1, 2, 3, 1, 2, 3, 9, 1, 2]], np.int32)
         n = 24
         want = np.asarray(
-            llama.greedy_generate(params, jnp.asarray(prompt), cfg, max_new_tokens=n)
+            FAMILIES["llama"].generate(params, jnp.asarray(prompt), cfg, max_new_tokens=n)
         )
         dec = SpeculativeDecoder(fwd, init, k=5, max_ngram=2)
         new, stats = dec.generate(params, prompt[0].tolist(), n)
