@@ -236,7 +236,10 @@ DEEPSEEK_V2_RULES: Rules = [
     (r"(q_a_proj|kv_a_proj_with_mqa)\.weight$", [None, None]),
     (r"(q_b|kv_b)_proj\.weight$", ["tp", None]),
     (r"o_proj\.weight$", [None, "tp"]),
+    (r"indexer\.(wq_b|wk|weights_proj)\.weight$", [None, None]),
+    (r"indexer\.k_norm\.(weight|bias)$", [None]),
     (r"mlp\.gate\.weight$", [None, None]),
+    (r"mlp\.gate\.e_score_correction_bias$", [None]),
     (r"mlp\.experts\.(gate|up)_proj\.weight$", ["ep", "tp", None]),
     (r"mlp\.experts\.down_proj\.weight$", ["ep", None, "tp"]),
     (r"(gate|up)_proj\.weight$", ["tp", None]),

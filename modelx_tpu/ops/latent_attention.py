@@ -246,12 +246,16 @@ def _query_tile(b: int, h: int, s: int, kb: int) -> int:
 
 
 def expanded(q_nope, q_pe, cache, offset, w_kvb, scale: float, rank: int, *,
-             key_block: int = EXPAND_BLOCK):
+             key_block: int = EXPAND_BLOCK, selected=None):
     """A block of query positions against the lines, keys and values expanded
     a key block at a time. q_nope ``[B, S, H, dn]``, q_pe ``[B, S, H, dr]``
     (roped), cache ``[B, L, W]`` already holding the block's own lines at
     ``offset`` (a scalar, or one start a row), w_kvb ``[H, dn + dv, rank]``.
-    Causal by absolute position. Returns ``[B, S, H, dv]`` in q's dtype.
+    Causal by absolute position; ``selected`` (bool ``[B, S, L]``, a learned
+    selector's choice of positions a query, ``ops/index_select.py``) hides what
+    it leaves out as well; a query that may see nothing of the first key blocks
+    folds them at the finite ``NEG_INF``, and its first real maximum wipes
+    that. Returns ``[B, S, H, dv]`` in q's dtype.
 
     Key blocks past the last query are not visited (the loop's bound follows
     ``offset``); the first block holds position 0, which every query sees, so
@@ -275,15 +279,19 @@ def expanded(q_nope, q_pe, cache, offset, w_kvb, scale: float, rank: int, *,
                         preferred_element_type=jnp.float32).astype(cache.dtype)
         k_nope, v, k_pe = kv[..., :dn], kv[..., dn:], lines[..., rank: rank + dr]
         kpos = i * kb + jnp.arange(kb)
+        chosen = () if selected is None else (
+            split(jax.lax.dynamic_slice_in_dim(selected, i * kb, kb, axis=2)),)
 
         def one_tile(args):
-            q_n, q_p, start, acc, m_prev, l_prev = args
+            q_n, q_p, start, acc, m_prev, l_prev, *keep = args
             scores = (jnp.einsum("bqhd,bkhd->bhqk", q_n, k_nope,
                                  preferred_element_type=jnp.float32)
                       + jnp.einsum("bqhd,bkd->bhqk", q_p, k_pe,
                                    preferred_element_type=jnp.float32)) * scale
             qpos = offset[:, None] + start + jnp.arange(tile)[None, :]  # [B, tile]
             visible = kpos[None, None, :] <= qpos[:, :, None]  # [B, tile, kb]
+            if keep:
+                visible = visible & keep[0]
             scores = jnp.where(visible[:, None], scores, NEG_INF)
             m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1))
             alpha = jnp.exp(m_prev - m_new)
@@ -293,7 +301,7 @@ def expanded(q_nope, q_pe, cache, offset, w_kvb, scale: float, rank: int, *,
                 "bhqk,bkhd->bhqd", p.astype(v.dtype), v, preferred_element_type=jnp.float32)
             return acc, m_new, l_new
 
-        return jax.lax.map(one_tile, (qn, qp, starts, *carry))
+        return jax.lax.map(one_tile, (qn, qp, starts, *carry, *chosen))
 
     carry = (jnp.zeros((tiles, b, h, tile, dv), jnp.float32),
              jnp.full((tiles, b, h, tile), NEG_INF, jnp.float32),

@@ -160,7 +160,11 @@ def route_topk(router_logits: jax.Array, k: int, *, renormalize: bool = True,
     ``topk_group`` best groups stay eligible (a tie between groups goes to the
     lower index, as ``top_k``'s does), and the k experts are the best inside
     them — a token whose best expert lies in a dropped group does without it.
-    The probabilities are those of the softmax over all E either way."""
+    The probabilities are those of the softmax over all E either way.
+    Sigmoid scores with a choice bias group otherwise (HF ``topk_method``
+    ``noaux_tc``): a group's score is the SUM of its two largest BIASED
+    scores, and the k are the largest biased scores inside the groups kept.
+    Softmax with a bias, or sigmoid without one, is nobody's grouping: refused."""
     if scoring not in ("softmax", "sigmoid"):
         raise ValueError(f"scoring {scoring!r} is neither softmax nor sigmoid")
     if scoring == "softmax":
@@ -169,18 +173,27 @@ def route_topk(router_logits: jax.Array, k: int, *, renormalize: bool = True,
         probs = jax.nn.sigmoid(router_logits.astype(jnp.float32))
     eligible = probs if choice_bias is None else probs + choice_bias.astype(jnp.float32)
     if groups is not None:
-        if scoring != "softmax" or choice_bias is not None:
-            raise ValueError("group-limited routing is implemented over plain softmax scores")
+        noaux = scoring == "sigmoid" and choice_bias is not None
+        if not noaux and (scoring != "softmax" or choice_bias is not None):
+            raise ValueError("group-limited routing is implemented over plain softmax scores "
+                             "(a group's best expert) and over sigmoid scores with a choice "
+                             "bias (the sum of a group's two best)")
         n_group, topk_group = groups
         t, e = probs.shape
         if e % n_group or not 0 < topk_group <= n_group:
             raise ValueError(f"{e} experts do not fall into {n_group} groups of which "
                              f"{topk_group} are kept")
-        best = jnp.max(probs.reshape(t, n_group, e // n_group), axis=-1)  # [T, n_group]
+        if noaux:
+            best = jnp.sum(jax.lax.top_k(
+                eligible.reshape(t, n_group, e // n_group), 2)[0], axis=-1)
+        else:
+            best = jnp.max(probs.reshape(t, n_group, e // n_group), axis=-1)  # [T, n_group]
         _, kept = jax.lax.top_k(best, topk_group)
         keep = jnp.zeros((t, n_group), bool).at[jnp.arange(t)[:, None], kept].set(True)
-        # an ineligible expert sorts below every probability, zero included
-        eligible = jnp.where(jnp.repeat(keep, e // n_group, axis=1), probs, -1.0)
+        # an ineligible expert sorts below every score: a probability is zero
+        # at least, a biased score anything
+        eligible = jnp.where(jnp.repeat(keep, e // n_group, axis=1), eligible,
+                             -jnp.inf if noaux else -1.0)
     vals, idx = jax.lax.top_k(eligible, k)
     if choice_bias is not None:
         vals = jnp.take_along_axis(probs, idx, axis=-1)
@@ -247,7 +260,8 @@ def moe_share_ffn(
 
     Three static arguments say what kind of layer it is (the defaults are the
     layer above). ``scoring`` / ``choice_bias``: the router's scores
-    (:func:`route_topk`: ``"sigmoid"``, and a bias that only chooses).
+    (:func:`route_topk`: ``"sigmoid"``, and a bias that only chooses; with
+    ``groups`` the two together group as ``noaux_tc``).
     ``form`` ``"relu2"``: an expert is TWO matrices with a squared relu
     between them, ``w_down relu(w_up x)^2`` — ``w_gate`` is None, and so is
     ``shared``'s gate. ``latent`` ``(down [L, D], up [D, L])``: the experts

@@ -672,3 +672,81 @@ class TestLatentLeaves:
             kv_layout.LayerKindKV.refuse("deepseek_v2", ("latent", "counter"), **dict(base, **asked))
         assert what in str(err.value) and "'latent' leaves" in str(err.value)
         kv_layout.LayerKindKV.refuse("deepseek_v2", ("latent", "counter"), **base)  # the cell's own
+
+
+class TestLatentAndIndexLeaves:
+    """``LayerKindKV`` over deepseek_v2 with an indexer (DeepSeek-V3.2): two
+    position-addressed leaves a layer — the latent line ``c<i>`` (kind
+    ``"latent"``) and the index key ``i<i>`` (kind ``"index"`` at one row a
+    position) — viewed, landed and handed to a piece together; the refusals
+    are the latent line's."""
+
+    @pytest.fixture(scope="class")
+    def kv(self):
+        from modelx_tpu.models import deepseek_v2
+
+        cfg = deepseek_v2.DeepseekV2Config.tiny_v32(vocab_size=64)
+        server = types.SimpleNamespace(mesh=make_mesh("dp=1", jax.devices()[:1]),
+                                       family=FAMILIES["deepseek_v2"], cfg=cfg)
+        fwd, init_cache = server.family.decode_fns(cfg, mesh=server.mesh)
+        return kv_layout.build(server, fwd, init_cache, {}, max_slots=SLOTS, max_len=MAX_LEN,
+                               chunk_size=4, page_size=0, max_live_tokens=0,
+                               paged_attention="gather", prefill_chunk=16), cfg
+
+    def test_two_leaves_a_layer_and_each_kinds_bytes_under_its_own_name(self, kv):
+        kv, cfg = kv
+        state = kv.new_state()
+        assert [kv.kinds[f"c{i}"] for i in range(3)] == ["latent"] * 3
+        assert [kv.kinds[f"i{i}"] for i in range(3)] == ["index"] * 3
+        assert {n for n, k in kv.kinds.items() if k == "counter"} == {
+            "moe_counts", "mla_counts", "dsa_counts"}
+        assert state["c0"].shape == (SLOTS, MAX_LEN, 128) and state["i0"].shape == (
+            SLOTS, MAX_LEN, cfg.index_dim)
+        stats = kv.stats["kv"]
+        assert stats["bytes_latent"] == 3 * SLOTS * MAX_LEN * 128 * 4
+        assert stats["bytes_index"] == 3 * SLOTS * MAX_LEN * cfg.index_dim * 4
+        assert stats["bytes_full"] == 0 == stats["bytes_state"]
+        assert not kv.has_state and kv.counter_rows == 12
+        assert kv.stats["dsa"] == {"layers": 3, "index_topk": 8, "index_heads": 4, "index_dim": 16}
+        assert kv.step_kwargs(jnp.asarray([1]), jnp.asarray([1])) == {} == kv.block_kwargs(last_idx=3)
+
+    def test_a_piece_is_handed_both_leaves_and_gives_both_back(self, kv):
+        kv, _ = kv
+        rng = np.random.RandomState(4)
+        state = {n: (x if kv.kinds[n] == "counter" else
+                     jnp.asarray(rng.standard_normal(x.shape).astype(x.dtype)))
+                 for n, x in kv.new_state().items()}
+        row = jax.jit(lambda c, w: kv.view(c, w, 32))(state, kv.at(1))
+        assert set(row) == {f"{k}{i}" for k in "ci" for i in range(3)}
+        assert row["c0"].shape == (1, 32, 128) and row["i2"].shape == (1, 32, 16)
+        np.testing.assert_array_equal(np.asarray(row["i2"])[0], np.asarray(state["i2"])[1, :32])
+        after = jax.jit(kv.put_piece)(state, {n: x + 1 for n, x in row.items()}, kv.at(1))
+        for name in row:
+            got, was = np.asarray(after[name]), np.asarray(state[name])
+            np.testing.assert_array_equal(got[[0, 2, 3]], was[[0, 2, 3]])
+            np.testing.assert_array_equal(got[1, :32], was[1, :32] + 1)
+            np.testing.assert_array_equal(got[1, 32:], was[1, 32:])
+
+    def test_the_three_counter_leaves_ride_home_in_their_order(self, kv):
+        kv, _ = kv
+        state = dict(kv.new_state(), moe_counts=jnp.asarray([12, 3, 2, 8], jnp.int32),
+                     mla_counts=jnp.asarray([16, 40, 6, 6], jnp.int32),
+                     dsa_counts=jnp.asarray([40, 16, 2, 6], jnp.int32))
+        out = np.asarray(kv.ride(state, jnp.zeros((SLOTS, 5), jnp.int32)))
+        assert out.shape == (SLOTS + 12, 5)
+        kv._last.clear()
+        kv.landed(out)
+        assert [kv.stats["dsa"][k] for k in ("positions_scored", "lines_selected",
+                                             "steps_selecting", "steps_all")] == [40, 16, 2, 6]
+        assert kv.stats["mla"]["positions_read"] == 16 and kv.stats["moe"]["experts_read"] == 8
+
+    @pytest.mark.parametrize("asked,what", [
+        (dict(page_size=16), "--kv-page-size"), (dict(speculative_k=2), "--speculative-k"),
+        (dict(prefix_cache=object()), "--prefix-cache")])
+    def test_the_latent_lines_refusals_hold_with_an_index_beside_them(self, asked, what):
+        base = dict(page_size=0, prefix_cache=None, prefill_chunk=2048, speculative_k=0)
+        kinds = ("latent", "index", "counter")
+        with pytest.raises(kv_layout.Refused) as err:
+            kv_layout.LayerKindKV.refuse("deepseek_v2", kinds, **dict(base, **asked))
+        assert what in str(err.value) and "'latent' leaves" in str(err.value)
+        kv_layout.LayerKindKV.refuse("deepseek_v2", kinds, **base)  # the cell's own

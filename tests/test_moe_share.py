@@ -162,6 +162,81 @@ def test_group_limited_routing_is_the_loop_written_out():
     assert plain[3, 0] > 0
 
 
+def noaux_by_hand(logits: np.ndarray, bias: np.ndarray, k: int, n_group: int, topk_group: int,
+                  scale: float):
+    """``noaux_tc`` a token at a time, from the equations: s = sigmoid(logit),
+    c = s + bias; a group's score the sum of its two largest c; the
+    ``topk_group`` best groups stay; the k largest c inside them are chosen
+    (ties to the lower index, both times); g = s / sum(s of the chosen) x scale."""
+    s = 1.0 / (1.0 + np.exp(-logits.astype(np.float64)))
+    c = s + bias
+    t, e = s.shape
+    size, out = e // n_group, np.zeros_like(s)
+    for row in range(t):
+        score = [np.sort(c[row, g * size:(g + 1) * size])[-2:].sum() for g in range(n_group)]
+        kept = sorted(range(n_group), key=lambda g: (-score[g], g))[:topk_group]
+        eligible = [x for g in kept for x in range(g * size, (g + 1) * size)]
+        chosen = sorted(eligible, key=lambda x: (-c[row, x], x))[:k]
+        total = sum(s[row, x] for x in chosen)
+        for x in chosen:
+            out[row, x] = s[row, x] / total * scale
+    return out
+
+
+def test_sigmoid_bias_and_groups_by_the_sum_of_two_is_the_loop_written_out():
+    logits = np.array(jax.random.normal(jax.random.PRNGKey(11), (64, 32)) * 2.0)
+    bias = np.array(jax.random.normal(jax.random.PRNGKey(12), (32,)) * 0.4)
+    logits[1, :] = 0.0  # every expert's score ties: the bias alone chooses
+    # token 2: group 0 holds the single best expert and nothing else, groups 1 and 2 two
+    # good ones each: by the sum of two, group 0 is dropped and its best expert with it
+    logits[2] = -6.0
+    logits[2, 0], logits[2, 8:10], logits[2, 16:18] = 6.0, 3.0, 2.5
+    got = np.asarray(moe.route_topk(jnp.asarray(logits), 3, renormalize=True, scale=2.5,
+                                    groups=(4, 2), scoring="sigmoid",
+                                    choice_bias=jnp.asarray(bias)))
+    want = noaux_by_hand(logits, bias, 3, 4, 2, 2.5)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
+    assert ((got > 0).sum(-1) == 3).all()
+    np.testing.assert_allclose(got.sum(-1), 2.5, rtol=1e-5)
+    flat = np.asarray(moe.route_topk(jnp.asarray(logits), 3, renormalize=True, scale=2.5,
+                                     groups=(4, 2), scoring="sigmoid",
+                                     choice_bias=jnp.zeros(32)))
+    np.testing.assert_allclose(flat, noaux_by_hand(logits, np.zeros(32), 3, 4, 2, 2.5),
+                               rtol=1e-5, atol=1e-7)
+    assert flat[2, 0] == 0 and flat[2, 8] > 0 and flat[2, 16] > 0
+    # a group's best expert alone (V2's rule) would have kept group 0
+    assert (1 / (1 + np.exp(-logits[2]))).reshape(4, 8).max(-1).argmax() == 0
+    # the bias moves which experts a token gets and never how much of them
+    chosen = got[5] > 0
+    s5 = 1 / (1 + np.exp(-logits[5]))
+    np.testing.assert_allclose(got[5, chosen], s5[chosen] / s5[chosen].sum() * 2.5, rtol=1e-5)
+
+
+def test_the_old_routings_are_what_they_were_and_half_a_noaux_is_refused():
+    """Softmax groups by the best expert and sigmoid with a bias and no
+    groups: the same numbers as before sigmoid scores could be grouped;
+    groups over sigmoid scores WITHOUT a bias, or over softmax WITH one, are
+    nobody's routing and still refused."""
+    logits = jnp.asarray(np.array(jax.random.normal(jax.random.PRNGKey(7), (32, 16)) * 2.0))
+    bias = jnp.asarray(np.linspace(-0.2, 0.2, 16, dtype=np.float32))
+    probs = np.asarray(jax.nn.softmax(logits, -1))
+    grouped = np.asarray(moe.route_topk(logits, 3, renormalize=False, scale=4.0, groups=(4, 2)))
+    np.testing.assert_allclose(grouped, grouped_by_hand(probs, 3, 4, 2, 4.0), rtol=1e-6)
+    plain = np.asarray(moe.route_topk(logits, 3, scoring="sigmoid", choice_bias=bias))
+    s = np.asarray(jax.nn.sigmoid(logits))
+    for row in range(32):
+        chosen = sorted(range(16), key=lambda x: (-(s[row, x] + float(bias[x])), x))[:3]
+        want = np.zeros(16)
+        want[chosen] = s[row, chosen] / s[row, chosen].sum()
+        np.testing.assert_allclose(plain[row], want, rtol=1e-5)
+    for kw in (dict(scoring="sigmoid"), dict(choice_bias=bias)):
+        with pytest.raises(ValueError, match="group-limited routing"):
+            moe.route_topk(logits, 3, groups=(4, 2), **kw)
+    # the unchanged calls trace to the programs they did: one top_k without groups, two with
+    text = str(jax.make_jaxpr(lambda x: moe.route_topk(x, 3, groups=(4, 2)))(logits))
+    assert text.count(" top_k[") == 2 and "logistic" not in text
+
+
 def test_groups_that_do_not_tile_the_experts_are_refused():
     logits = jnp.zeros((2, 10))
     with pytest.raises(ValueError, match="groups"):

@@ -129,6 +129,15 @@ FAMILY_SPEC_CASES = {
         ("model.layers.0.self_attn.kv_b_proj.weight", PartitionSpec("tp", None)),
         ("model.layers.0.self_attn.o_proj.weight", PartitionSpec(None, "tp")),
         ("model.layers.1.mlp.gate.weight", PartitionSpec(None, None)),  # the router, whole
+        # DeepSeek-V3 / V3.2 in the same family: the router's choice bias and the lightning
+        # indexer (one key a position for all heads, data-parallel in the deployment) whole —
+        # ``wq_b`` must not fall to the q_b / kv_b rule, ``weights_proj`` not to a projection's
+        ("model.layers.1.mlp.gate.e_score_correction_bias", PartitionSpec(None)),
+        ("model.layers.0.self_attn.indexer.wq_b.weight", PartitionSpec(None, None)),
+        ("model.layers.0.self_attn.indexer.wk.weight", PartitionSpec(None, None)),
+        ("model.layers.0.self_attn.indexer.weights_proj.weight", PartitionSpec(None, None)),
+        ("model.layers.0.self_attn.indexer.k_norm.weight", PartitionSpec(None)),
+        ("model.layers.0.self_attn.indexer.k_norm.bias", PartitionSpec(None)),
         ("model.layers.1.mlp.experts.gate_proj.weight", PartitionSpec(None, "tp", None)),  # no ep axis here
         ("model.layers.1.mlp.experts.down_proj.weight", PartitionSpec(None, None, "tp")),
         # two dimensions: the stacked experts' patterns must not catch them
@@ -606,3 +615,15 @@ class TestMeshEngine:
                 srv.generate(tokens, max_new_tokens=8))
         finally:
             cb.close()
+
+
+def test_a_deepseek_v32_checkpoints_names_detect_the_deepseek_v2_family():
+    """One module, one row: the indexer's tensors and the router's bias beside
+    ``kv_a_proj_with_mqa`` are still the deepseek_v2 family's."""
+    from modelx_tpu.dl.sharding import infer_family
+
+    names = ["model.embed_tokens.weight", "model.layers.0.self_attn.kv_a_proj_with_mqa.weight",
+             "model.layers.0.self_attn.indexer.wq_b.weight",
+             "model.layers.1.mlp.gate.e_score_correction_bias",
+             "model.layers.1.mlp.experts.3.gate_proj.weight"]
+    assert infer_family(names) == "deepseek_v2"

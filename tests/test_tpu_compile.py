@@ -538,8 +538,9 @@ def test_hit_experts_kernel_at_the_cells_widths(topo, cell):
 # -- deepseek_v2: the latent cache, the absorbed kernel, the piece program ----------
 
 
-def deepseek_v2_cell(topo, monkeypatch):
-    """The ``deepseek-v2-ep8-d5.longdoc`` cell's engine over shapes on one
+def deepseek_v2_cell(topo, monkeypatch, config="deepseek-v2-ep8-d5", slots=32):
+    """The ``deepseek-v2-ep8-d5.longdoc`` cell's engine (or, of the same
+    module, ``deepseek-v3.2-exp-ep16-d5.sparsedoc``'s) over shapes on one
     described device, the rules steered to a TPU -> (its file, engine, params,
     ``sds``)."""
     import json
@@ -551,7 +552,7 @@ def deepseek_v2_cell(topo, monkeypatch):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs", "deepseek-v2-ep8-d5.json")) as f:
+    with open(os.path.join(root, "benchmark", "configs", config + ".json")) as f:
         raw = json.load(f)
     cfg = deepseek_v2.config_from_hf(raw)
     one = SingleDeviceSharding(topo.devices[0])
@@ -560,7 +561,7 @@ def deepseek_v2_cell(topo, monkeypatch):
     server = types.SimpleNamespace(
         family=FAMILIES["deepseek_v2"], cfg=cfg, mesh=make_mesh("dp=1", [topo.devices[0]]),
         params=params, max_seq_len=32768, stats={})
-    engine = ContinuousBatcher(server, max_slots=32, chunk_size=8, max_len=32768,
+    engine = ContinuousBatcher(server, max_slots=slots, chunk_size=8, max_len=32768,
                                prefill_chunk=2048, allocate=False, supervise=False)
     return raw, engine, params, sds
 
@@ -668,3 +669,73 @@ def test_deepseek_v2s_piece_program_expands_a_key_block_at_a_time(topo, monkeypa
     whole = [line.strip()[:120] for line in text.splitlines()
              if re.search(r"\[1,(32768|16384),128,\d+\]|\[1,128,2048,(32768|16384)\]", line)]
     assert not whole, whole
+
+
+# -- deepseek_v32: the same module with an indexer — two leaves a layer, the selection --
+
+
+def test_deepseek_v32s_chunk_program_selects_gathers_and_attends_over_2048_lines(topo, monkeypatch):
+    """The engine's OWN chunk program at the ``.sparsedoc`` cell's size, 16
+    slots of 32,768 positions: every layer scores its ``[16, 32768, 128]``
+    index leaf, sorts (``lax.top_k`` of 2,048 is a sort on the TPU), gathers
+    ``[16, 2048, 640]`` lines and runs the absorbed kernel over THEM under
+    ``dsa.attend`` (five calls; the four expert layers' ``moe_hit_experts``
+    beside them, at 16 experts of 2,048); neither leaf copied or re-laid
+    whole, no ``[16, 64, 32768]`` score tensor left (the head sum fuses into
+    the product); both leaves aliased to the input; weights and cache are the
+    configuration's ``bytes_predicted``. DeepSeek-V2's program, two tests up,
+    is what it was: five kernels under ``dsv2.attn.attend`` and no sort."""
+    raw, engine, params, sds = deepseek_v2_cell(topo, monkeypatch, "deepseek-v3.2-exp-ep16-d5", 16)
+    try:
+        assert sorted(set(engine.kv.kinds.values())) == ["counter", "index", "latent"]
+        compiled = engine._chunk_prog.jit.lower(
+            params, engine.kv.abstract_state(), sds((16, 1), jnp.int32),
+            *engine._chunk_args(False), n_steps=8).compile()
+    finally:
+        engine.close()
+    text, m = compiled.as_text(), compiled.memory_analysis()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line
+             and "latent_decode_attention" in line]
+    assert len(calls) == 5 and all("dsa.attend" in c for c in calls)
+    assert all("bf16[16,2048,640]" in c for c in calls)  # over the gathered lines
+    assert _mosaic_calls(text) == {"latent_decode_attention": 5, "moe_hit_experts": 4}
+    sorts = [line for line in text.splitlines() if re.search(r" sort\(", line) and "dsa.select" in line]
+    assert len(sorts) >= 5
+    relaid = [line.strip()[:120] for line in text.splitlines()
+              if re.search(r"= bf16\[16,32768,(640|128)\]\S* (copy|transpose)\(", line)]
+    assert not relaid, relaid
+    assert "bf16[16,32768,640]" in text and "bf16[16,32768,128]" in text
+    scores = [line.strip()[:120] for line in text.splitlines()
+              if re.search(r"= f32\[16,64,32768\]", line)]
+    assert not scores, scores
+    predicted = raw["bytes_predicted"]
+    assert predicted["sum"] == 2 * 4_635_518_208 + 16 * 32768 * (640 + 128) * 2 * 5
+    assert predicted["sum"] <= m.argument_size_in_bytes < predicted["sum"] + 16384
+    cache = predicted["cache_16_slots_x_32768_positions_x_5_layers"]
+    assert cache <= m.alias_size_in_bytes < cache + 4096
+    assert m.temp_size_in_bytes < 256 * 2**20  # the gathered lines of a layer are 42 MB
+
+
+def test_deepseek_v32s_piece_program_selects_a_query_tile_at_a_time(topo, monkeypatch):
+    """A 2,048-token piece over the slot's 32,768 positions: index scores and
+    the bisected threshold 1,024 queries at a time (``[1, 1024, 32768]``
+    float32, 134 MB), the mask ``[1, 2048, 32768]`` bool, then V2's expansion
+    under it — no ``[64, 2048, 32768]`` scores (17 GB), no sort of ``[2048,
+    32768]``, neither leaf copied whole."""
+    _, engine, params, sds = deepseek_v2_cell(topo, monkeypatch, "deepseek-v3.2-exp-ep16-d5", 16)
+    try:
+        compiled = jax.jit(engine._piece_impl, donate_argnums=(2,)).lower(
+            params, sds((1, 2048), jnp.int32), engine.kv.abstract_state(), sds((), jnp.int32),
+            sds((), jnp.int32)).compile()
+    finally:
+        engine.close()
+    text, m = compiled.as_text(), compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 1.5 * 2**30
+    relaid = [line.strip()[:120] for line in text.splitlines()
+              if re.search(r"= bf16\[16,32768,(640|128)\]\S* (copy|transpose)\(", line)]
+    assert not relaid, relaid
+    whole = [line.strip()[:120] for line in text.splitlines()
+             if re.search(r"\[1,2048,64,32768\]|\[1,64,2048,32768\]|f32\[1,2048,32768\]", line)]
+    assert not whole, whole
+    assert not [line for line in text.splitlines()
+                if re.search(r" sort\(", line) and "32768" in line and "dsa." in line]

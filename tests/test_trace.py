@@ -116,21 +116,61 @@ class TestTracer:
         # one request's slice still comes from the ring
         assert t.summary(request_id="r9999")["serve.request"]["count"] == 1
 
-    def test_self_seconds_on_a_hand_made_nest(self):
+    def test_self_seconds_on_a_hand_made_nest(self, monkeypatch):
+        """Self time = total - children, exactly — on a clock the test sets
+        (three ``time.sleep`` calls under an upper bound overran on a loaded
+        host: six xdist workers, PR 49's run)."""
+        now = [100.0]
+        monkeypatch.setattr(time, "monotonic", lambda: now[0])
+
+        def passes(seconds: float) -> None:
+            now[0] += seconds
+
         with span("outer"):
-            time.sleep(0.02)
+            passes(0.02)
             with span("a"):
-                time.sleep(0.03)
+                passes(0.03)
             with span("b"):
                 with span("c"):
-                    time.sleep(0.01)
+                    passes(0.01)
         agg = tracer().summary()
         outer, a, b, c = (agg[p] for p in ("outer", "outer/a", "outer/b", "outer/b/c"))
         assert a["self_s"] == a["total_s"] and c["self_s"] == c["total_s"]
         assert b["self_s"] == pytest.approx(b["total_s"] - c["total_s"], abs=1e-9)
         assert outer["self_s"] == pytest.approx(
             outer["total_s"] - a["total_s"] - b["total_s"], abs=1e-9)
-        assert 0.02 <= outer["self_s"] < 0.03
+        assert outer["self_s"] == pytest.approx(0.02, abs=1e-9)
+        assert (a["total_s"], c["total_s"]) == (pytest.approx(0.03), pytest.approx(0.01))
+        assert b["self_s"] == pytest.approx(0.0, abs=1e-9)
+
+    def test_a_step_that_selects_says_so_under_the_steps_span_path(self):
+        """The learned selection's spans (models/deepseek_v2.py with an
+        indexer): a decode step over a cache longer than ``index_topk`` leaves
+        ``dsa.select[rows x cache -> kept]`` under the span it was traced in,
+        once a layer, and its program names the four ``dsa.*`` scopes; a cache
+        of at most ``index_topk`` positions selects nothing and says nothing."""
+        import jax
+        import jax.numpy as jnp
+
+        from modelx_tpu.models import deepseek_v2 as ds
+
+        cfg = ds.DeepseekV2Config.tiny_v32(vocab_size=64)
+        params = ds.init_params(cfg, jax.random.PRNGKey(0))
+        tok, at = jnp.ones((2, 1), jnp.int32), jnp.asarray([9, 20], jnp.int32)
+
+        def step(cache):
+            return ds.forward(params, tok, cfg, kv_cache=cache, cache_offset=at)[0]
+
+        with span("continuous.step"):
+            text = jax.jit(step).lower(ds.init_layer_state(cfg, 2, 32)).as_text(debug_info=True)
+        got = tracer().summary("continuous.step/dsa.")
+        assert got["continuous.step/dsa.select[2x32->8]"]["count"] == cfg.num_layers
+        for scope in ("dsa.index/", "dsa.score/", "dsa.select/", "dsa.gather/", "dsa.attend/"):
+            assert scope in text, scope
+        with span("continuous.short"):
+            text = jax.jit(step).lower(ds.init_layer_state(cfg, 2, 8)).as_text(debug_info=True)
+        assert not tracer().summary("continuous.short/dsa.")
+        assert "dsa.select/" not in text and "dsv2.attn.attend/" in text
 
     def test_a_shape_or_a_decision_in_the_name_is_a_path_of_its_own(self):
         for name in ("attention.flash[144x144]+pad[256x256]", "attention.reference[16x16]"):
