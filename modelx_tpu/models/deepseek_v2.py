@@ -103,8 +103,10 @@ MLA_COUNTERS = ("positions_read", "positions_cached", "steps_absorbed", "steps_a
 # the indexer's, over the same rows and all layers: positions whose index keys
 # a step scored (the rows' contexts), lines the selection kept (min(context,
 # index_topk) a row), row-steps whose context exceeded index_topk (the
-# selection chose), row-steps in all
-DSA_COUNTERS = ("positions_scored", "lines_selected", "steps_selecting", "steps_all")
+# selection chose), row-steps in all, and of the selecting ones those whose
+# selection ran the kernels (ops/index_select.takes_kernel: all of them or none)
+DSA_COUNTERS = ("positions_scored", "lines_selected", "steps_selecting", "steps_all",
+                "steps_kernel")
 # the indexer's LayerNorm (the published inference code's default; not in config.json)
 INDEX_NORM_EPS = 1e-6
 # tokens one call of the expert layer takes whole, and the chunk a longer block
@@ -577,14 +579,20 @@ def _attention(params, p: str, u, positions, cfg: DeepseekV2Config, ctx: Shardin
                                preferred_element_type=jnp.float32).astype(q.dtype)
             q_cat = jnp.concatenate(
                 [q_lat, q_pe[:, 0], jnp.zeros((b, h, width - r - dr), q.dtype)], axis=-1)
+        key_block = 0
         if selecting:
             # the best index_topk of each row's context, their lines gathered:
             # the absorbed form then reads min(context, index_topk) lines a row
             # (the span: at TRACE time, once a layer — a program that selects says so)
+            key_block, interpret = select_ops.takes_kernel(
+                rows.shape, r, cfg.index_topk, attention_impl, ctx.mesh)
             with trace.span(f"dsa.select[{b}x{rows.shape[1]}->{cfg.index_topk}]"):
                 with jax.named_scope("dsa.score"):
-                    scores = select_ops.step_scores(q_idx[:, 0], w_idx[:, 0], index_rows)
+                    scores = select_ops.step_scores(q_idx[:, 0], w_idx[:, 0], index_rows,
+                                                    offsets + 1, block=key_block,
+                                                    interpret=interpret)
                 with jax.named_scope("dsa.select"):
+                    # through the module: a harness wraps this to see what a step chose
                     chosen = select_ops.select(scores, offsets + 1, cfg.index_topk)
                 with jax.named_scope("dsa.gather"):
                     rows = select_ops.gather_lines(rows, chosen)
@@ -603,9 +611,10 @@ def _attention(params, p: str, u, positions, cfg: DeepseekV2Config, ctx: Shardin
             jnp.sum(latent_ops.positions_read(rows.shape, r, kept, attention_impl, ctx.mesh)),
             jnp.sum(lengths), jnp.sum(holds), jnp.sum(holds)]).astype(jnp.int32)
         if cfg.index_topk:
+            choosing = jnp.sum(lengths > cfg.index_topk)
             dsa = jnp.stack([
-                jnp.sum(lengths), jnp.sum(kept), jnp.sum(lengths > cfg.index_topk),
-                jnp.sum(holds)]).astype(jnp.int32)
+                jnp.sum(lengths), jnp.sum(kept), choosing, jnp.sum(holds),
+                choosing if key_block else 0]).astype(jnp.int32)
     else:
         selected = None
         if selecting:

@@ -147,8 +147,9 @@ def test_prefill_then_decode_through_both_leaves_is_the_references_full_forward(
         return
     # what the steps counted, over three layers: contexts 6..17 and 21..32
     contexts = [int(s) + 1 + k for s in starts for k in range(12)]
-    scored, kept, selecting, steps = np.asarray(cache["dsa_counts"])
+    scored, kept, selecting, steps, by_kernel = np.asarray(cache["dsa_counts"])
     assert (scored, steps) == (3 * sum(contexts), 3 * 24)
+    assert by_kernel == 0  # no chunk of 128 positions tiles these toy caches: the sort
     assert kept == 3 * sum(min(c, TOPK) for c in contexts)
     assert selecting == 3 * sum(c > TOPK for c in contexts)
     read, cached, absorbed, all_steps = np.asarray(cache["mla_counts"])
@@ -208,7 +209,11 @@ def test_the_selection_is_a_plain_sort_ties_included():
     got = np.asarray(select_ops.select(jnp.asarray(scores[:, 0]), jnp.asarray(lengths), 8))
     for row, n in enumerate(lengths):
         want = _plain_selection(scores[row, 0], int(n), 8)
-        assert got[row, : len(want)].tolist() == want  # best first, the row's own before the rest
+        # the SET is the contract (a kernel's order is ascending, the sort's best first)
+        assert sorted(got[row, : len(want)].tolist()) == sorted(want)
+        # a short row's own positions come first, all of them; what follows is in range
+        assert set(got[row, : len(want)].tolist()) <= set(range(int(n)))
+        assert 0 <= got[row].min() and got[row].max() < 40
     qpos = np.stack([np.arange(6) + off for off in (0, 5, 20, 34)])
     mask = np.asarray(select_ops.selection_mask(jnp.asarray(scores), jnp.asarray(qpos), 8))
     for b in range(4):

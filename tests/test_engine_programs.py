@@ -621,6 +621,16 @@ PARENT_PROGRAMS.update({
     "gpt2.admit": "8f3a159318a95acac721e3dd7161e1224c907e0d9db4918a37697c76937704d2",
     "gpt2.piece": "227ad4135b141c5394221db2e78831e8ab8f5202baaed1d1e50e9a1ba1403457",
 })
+# PR 51 (V3.2's decode step chooses without a sort where ``index_select.takes_kernel``
+# says so, a fifth ``dsa`` counter): V3.2's own three, ``tiny_v32`` in V2's module, taken
+# on PR 51's FINAL tree — the parent's decode step counted four — so that the next change
+# to the shared module sees when V3.2's text moves, as V2's three above show for V2. On
+# the CPU the rule says sort: the step holds ``select_reference``'s ``top_k``, no kernel
+PARENT_PROGRAMS.update({
+    "deepseek_v32.chunk": "987b4dc2b688efa84aeb6302f7274f0722c71d50c2de3488a3e90cee7c90303c",
+    "deepseek_v32.admit": "c24085b90c25f277c976db7641b7103fe0b763307e9ca01389874372a2ecbbd5",
+    "deepseek_v32.piece": "1212ecf122e69f3b463d0995b5775c5dc0ba1ea40f9d76a7dba08f06483c04a5",
+})
 # the ragged decode kernel's own jaxpr (the Mosaic body's source; it names no file) at
 # the two decode cells' widths: (rows, query heads, cache length)
 PARENT_RAGGED_KERNEL = {
@@ -632,6 +642,9 @@ PARENT_RAGGED_KERNEL = {
 def tiny_family(family: str):
     import importlib
 
+    if family == "deepseek_v32":  # V2's module and row, the indexer and ``noaux_tc`` on
+        module = importlib.import_module("modelx_tpu.models.deepseek_v2")
+        return module, module.DeepseekV2Config.tiny_v32(vocab_size=64)
     module = importlib.import_module("modelx_tpu.models." + family)
     name = {"llama": "LlamaConfig", "mixtral": "MixtralConfig", "laguna": "LagunaConfig",
             "minicpm_sala": "SalaConfig", "deepseek_v2": "DeepseekV2Config",
@@ -655,7 +668,8 @@ def lowered_programs(family: str) -> dict:
     params = jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                                     module.init_params(cfg, jax.random.PRNGKey(0)))
     server = types.SimpleNamespace(
-        family=FAMILIES[family], cfg=cfg, mesh=make_mesh("dp=1", jax.devices()[:1]),
+        family=FAMILIES[module.__name__.rpartition(".")[2]], cfg=cfg,
+        mesh=make_mesh("dp=1", jax.devices()[:1]),
         params=params, max_seq_len=64, stats={})
     pieces = {"prefill_chunk": 16} if family != "laguna" else {}  # a ring takes no piece
     engine = ContinuousBatcher(server, max_slots=4, chunk_size=4, max_len=64, allocate=False,
@@ -677,7 +691,7 @@ def lowered_programs(family: str) -> dict:
 
 
 @pytest.mark.parametrize("family", ["llama", "mixtral", "minicpm_sala", "laguna", "deepseek_v2",
-                                    "nemotron_h", "phi3", "gemma2", "gpt2"])
+                                    "deepseek_v32", "nemotron_h", "phi3", "gemma2", "gpt2"])
 def test_the_other_families_programs_lower_to_the_parents_text(family):
     import hashlib
 

@@ -706,7 +706,7 @@ class TestLatentAndIndexLeaves:
         assert stats["bytes_latent"] == 3 * SLOTS * MAX_LEN * 128 * 4
         assert stats["bytes_index"] == 3 * SLOTS * MAX_LEN * cfg.index_dim * 4
         assert stats["bytes_full"] == 0 == stats["bytes_state"]
-        assert not kv.has_state and kv.counter_rows == 12
+        assert not kv.has_state and kv.counter_rows == 13
         assert kv.stats["dsa"] == {"layers": 3, "index_topk": 8, "index_heads": 4, "index_dim": 16}
         assert kv.step_kwargs(jnp.asarray([1]), jnp.asarray([1])) == {} == kv.block_kwargs(last_idx=3)
 
@@ -731,13 +731,14 @@ class TestLatentAndIndexLeaves:
         kv, _ = kv
         state = dict(kv.new_state(), moe_counts=jnp.asarray([12, 3, 2, 8], jnp.int32),
                      mla_counts=jnp.asarray([16, 40, 6, 6], jnp.int32),
-                     dsa_counts=jnp.asarray([40, 16, 2, 6], jnp.int32))
+                     dsa_counts=jnp.asarray([40, 16, 2, 6, 2], jnp.int32))
         out = np.asarray(kv.ride(state, jnp.zeros((SLOTS, 5), jnp.int32)))
-        assert out.shape == (SLOTS + 12, 5)
+        assert out.shape == (SLOTS + 13, 5)
         kv._last.clear()
         kv.landed(out)
-        assert [kv.stats["dsa"][k] for k in ("positions_scored", "lines_selected",
-                                             "steps_selecting", "steps_all")] == [40, 16, 2, 6]
+        assert [kv.stats["dsa"][k] for k in (
+            "positions_scored", "lines_selected", "steps_selecting", "steps_all",
+            "steps_kernel")] == [40, 16, 2, 6, 2]
         assert kv.stats["mla"]["positions_read"] == 16 and kv.stats["moe"]["experts_read"] == 8
 
     @pytest.mark.parametrize("asked,what", [

@@ -674,17 +674,55 @@ def test_deepseek_v2s_piece_program_expands_a_key_block_at_a_time(topo, monkeypa
 # -- deepseek_v32: the same module with an indexer — two leaves a layer, the selection --
 
 
-def test_deepseek_v32s_chunk_program_selects_gathers_and_attends_over_2048_lines(topo, monkeypatch):
+def test_the_selections_kernels_at_the_cells_widths(topo):
+    """Mosaic takes the decode step's two selection kernels at the
+    ``.sparsedoc`` cell's shapes: ``dsa_step_scores`` — 16 rows, 64 index heads
+    of 128 lanes, blocks of 2,048 keys on ``latent_decode_attention``'s flat
+    grid, the leaf where it lies, a chunk of 128 scores a sublane of the output —
+    and ``dsa_chosen_mask`` — the ``[16, 256, 128]`` float32 scores, their
+    integer keys and the mask resident in VMEM (8 MB with the two buffers of
+    a grid's operands) for 47 passes, sixteen rows a step at any number of
+    slots; the compaction behind them is XLA's, a one-hot product and no sort."""
+    from modelx_tpu.ops import index_select as sel
+
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    assert sel.takes_kernel((16, 32768, 640), 512, 2048, "ragged") == (2048, False)
+    scores = jax.jit(lambda q, w, k, n: sel.step_scores(q, w, k, n, block=2048)).lower(
+        sds((16, 64, 128), jnp.bfloat16), sds((16, 64), jnp.float32),
+        sds((16, 32768, 128), jnp.bfloat16), sds((16,), jnp.int32)).compile()
+    text = scores.as_text()
+    assert _mosaic_calls(text) == {"dsa_step_scores": 1}
+    assert scores.memory_analysis().temp_size_in_bytes < 2**20  # the heads' weights a lane each
+    assert not [line for line in text.splitlines()
+                if re.search(r"= bf16\[16,32768,128\]\S* (copy|transpose)\(", line)]
+    select = jax.jit(lambda x, n: sel.compact(sel.chosen_mask(x, n, 2048), 2048)).lower(
+        sds((16, 256, 128), jnp.float32), sds((16,), jnp.int32)).compile()
+    text = select.as_text()
+    assert _mosaic_calls(text) == {"dsa_chosen_mask": 1}
+    assert not re.search(r" sort\(", text) and "s32[16,2048]" in text
+    assert select.memory_analysis().temp_size_in_bytes < 8 * 2**20
+    # thirty-two rows are two steps of sixteen: the same VMEM at any number of slots
+    assert sel.mask_group(32, 32768) == 16
+    more = jax.jit(lambda x, n: sel.chosen_mask(x, n, 2048)).lower(
+        sds((32, 256, 128), jnp.float32), sds((32,), jnp.int32)).compile()
+    assert _mosaic_calls(more.as_text()) == {"dsa_chosen_mask": 1}
+
+
+def test_deepseek_v32s_chunk_program_scores_what_rows_hold_and_selects_without_a_sort(
+        topo, monkeypatch):
     """The engine's OWN chunk program at the ``.sparsedoc`` cell's size, 16
     slots of 32,768 positions: every layer scores its ``[16, 32768, 128]``
-    index leaf, sorts (``lax.top_k`` of 2,048 is a sort on the TPU), gathers
-    ``[16, 2048, 640]`` lines and runs the absorbed kernel over THEM under
-    ``dsa.attend`` (five calls; the four expert layers' ``moe_hit_experts``
-    beside them, at 16 experts of 2,048); neither leaf copied or re-laid
-    whole, no ``[16, 64, 32768]`` score tensor left (the head sum fuses into
-    the product); both leaves aliased to the input; weights and cache are the
-    configuration's ``bytes_predicted``. DeepSeek-V2's program, two tests up,
-    is what it was: five kernels under ``dsv2.attn.attend`` and no sort."""
+    index leaf in ``dsa_step_scores`` under ``dsa.score`` (the key blocks a row
+    holds), finds its 2,048 positions in ``dsa_chosen_mask`` and a compaction
+    under ``dsa.select`` — NO ``sort`` anywhere in the program, no ``[16, 64,
+    32768]`` score tensor — gathers ``[16, 2048, 640]`` lines and runs the
+    absorbed kernel over THEM under ``dsa.attend`` (five calls each; the four
+    expert layers' ``moe_hit_experts`` beside them, at 16 experts of 2,048);
+    neither leaf copied or re-laid whole; both leaves aliased to the input;
+    weights and cache are the configuration's ``bytes_predicted``. DeepSeek-V2's
+    program, two tests up, is what it was: five kernels under
+    ``dsv2.attn.attend`` and nothing of these."""
     raw, engine, params, sds = deepseek_v2_cell(topo, monkeypatch, "deepseek-v3.2-exp-ep16-d5", 16)
     try:
         assert sorted(set(engine.kv.kinds.values())) == ["counter", "index", "latent"]
@@ -694,13 +732,20 @@ def test_deepseek_v32s_chunk_program_selects_gathers_and_attends_over_2048_lines
     finally:
         engine.close()
     text, m = compiled.as_text(), compiled.memory_analysis()
-    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line
-             and "latent_decode_attention" in line]
-    assert len(calls) == 5 and all("dsa.attend" in c for c in calls)
-    assert all("bf16[16,2048,640]" in c for c in calls)  # over the gathered lines
-    assert _mosaic_calls(text) == {"latent_decode_attention": 5, "moe_hit_experts": 4}
-    sorts = [line for line in text.splitlines() if re.search(r" sort\(", line) and "dsa.select" in line]
-    assert len(sorts) >= 5
+    kernels = {name: [line for line in text.splitlines()
+                      if 'custom_call_target="tpu_custom_call"' in line
+                      and re.search(rf"%{name}[.\d]* = ", line)]
+               for name in ("latent_decode_attention", "dsa_step_scores", "dsa_chosen_mask")}
+    assert all(len(calls) == 5 for calls in kernels.values())
+    assert all("dsa.attend" in c and "bf16[16,2048,640]" in c  # over the gathered lines
+               for c in kernels["latent_decode_attention"])
+    assert all("dsa.score" in c for c in kernels["dsa_step_scores"])
+    assert all("dsa.select" in c for c in kernels["dsa_chosen_mask"])
+    assert _mosaic_calls(text) == {"latent_decode_attention": 5, "dsa_step_scores": 5,
+                                   "dsa_chosen_mask": 5, "moe_hit_experts": 4}
+    # the routers sort their 256 experts in groups; nothing of the selection sorts
+    assert not [line for line in text.splitlines()
+                if re.search(r" sort\(", line) and ("dsa." in line or "32768" in line)]
     relaid = [line.strip()[:120] for line in text.splitlines()
               if re.search(r"= bf16\[16,32768,(640|128)\]\S* (copy|transpose)\(", line)]
     assert not relaid, relaid
