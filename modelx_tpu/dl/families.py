@@ -41,7 +41,7 @@ and its tests.
 ``infer_<family>_config(params)`` recovers an architecture from tensor shapes
 for the families whose shapes say it (llama, qwen2, phi3, gemma2, mixtral,
 gpt2, bert; ``config_for`` reconciles it with a pulled config.json); laguna,
-minicpm_sala, deepseek_v2 and nemotron_h read ``config.json`` alone.
+minicpm_sala, deepseek_v2, nemotron_h and mimo_v2 read ``config.json`` alone.
 ``detect(tensor_names)`` picks the family from tensor NAMES
 (dl/sharding.infer_family) — a header's index is enough, no weight is read.
 """
@@ -64,6 +64,7 @@ from modelx_tpu.dl.sharding import (
     GEMMA2_RULES,
     GPT2_RULES,
     LAGUNA_RULES,
+    MIMO_V2_RULES,
     MINICPM_SALA_RULES,
     PHI3_RULES,
     LLAMA_RULES,
@@ -473,6 +474,8 @@ FAMILIES: dict[str, Family] = {f.name: f for f in (
     _causal("deepseek_v2", DEEPSEEK_V2_RULES, kind_forward={}),
     _causal("nemotron_h", NEMOTRON_H_RULES, told_lengths=True, kind_forward={},
             embedding="backbone.embeddings.weight"),
+    # rings as laguna's; every leaf a line of a position's KV heads side by side
+    _causal("mimo_v2", MIMO_V2_RULES, kind_forward={"ring": True}),
     # models/gpt2.forward reads ``mesh`` (its cache write's kernel rule looks
     # at it) and has never been handed one
     _causal("gpt2", GPT2_RULES, infer_gpt2_config, paged_table=True, hands_mesh=False),

@@ -24,9 +24,10 @@ interface, and the engine never asks which it got:
   latent-attention layers one compressed LINE a position — so HBM
   holds what each kind can ever attend to, not ``max_len`` for all. What a
   leaf kind cannot give is refused when the engine is built, with a message
-  that names the kind: a ring no view of an overwritten prefix (so no chunked
-  prefill, no prefix cache), a state no cut at a token (so no prefix cache, no
-  speculative verify, no pages).
+  that names the kind: a ring no view of an overwritten prefix (so no prefix
+  cache), a state no cut at a token (so no prefix cache, no speculative
+  verify, no pages). A prefill piece needs of a ring only what the ring still
+  holds: the slot's last positions, handed over in position order.
 
 Three kinds of method. Device state (``new_state``, ``abstract_state``).
 Host bookkeeping (``fits``/``reserve``/``release``/``never_holds``/``reset``)
@@ -520,7 +521,12 @@ class LayerKindKV(DenseKV):
     16-bucket longer than the window, so the bucket's padding past the real
     prompt displaces only positions no later query can see, and the mask by
     absolute position hides the padding itself until decode overwrites it
-    (models/laguna.ring_len). A state has no such slack — what enters it
+    (models/laguna.ring_len). A long prompt lands in pieces: a piece sees a
+    ring as its slot's last ``ring`` positions in position order (``view``),
+    the family's forward attends those and the piece together and hands back
+    the last ``ring`` of them, which ``put_piece`` rolls to their indices — the
+    last piece's padded tail displaces what the bucket's slack covers, as an
+    admission's does. A state has no such slack — what enters it
     stays — so a layout with states tells the family's forward how many of a
     block's positions are real (``block_kwargs``) and which rows of a decode
     step are live (``step_kwargs``): an idle or filling slot's state is left
@@ -541,9 +547,6 @@ class LayerKindKV(DenseKV):
                           "state": "a state cannot be cut at a token",
                           "latent": "a suffix landing at an unbucketed offset over stored "
                                     "latent lines is held by no test yet"}),
-        "prefill_chunk": (("window",), "--prefill-chunk",
-                          {"window": "a piece needs the slot's earlier rows as a dense view, "
-                                     "and a ring has overwritten them"}),
         "speculative_k": (("window", "state", "latent"), "--speculative-k",
                           {"window": "a verify block writes several ring positions a step",
                            "state": "a state cannot drop the tokens a verify rejects",
@@ -558,6 +561,8 @@ class LayerKindKV(DenseKV):
         with the leaf kind that is the reason."""
         bad = []
         for option, value in asked.items():
+            if option not in cls.REFUSED:
+                continue  # every leaf kind carries it (``prefill_chunk``)
             cannot, what, why = cls.REFUSED[option]
             reasons = [f"{why[k]} ({k!r} leaves)" for k in cannot if k in kinds]
             if value and reasons:
@@ -584,6 +589,7 @@ class LayerKindKV(DenseKV):
         shapes = jax.eval_shape(self._zeros)
         rings = {shapes[n].shape[1] for n, kind in self.kinds.items() if kind == "window"}
         self.ring = rings.pop() if rings else max_len
+        self.has_rings = "window" in have
 
         def bytes_of(kind: str) -> int:
             return sum(int(np.prod(shapes[n].shape)) * shapes[n].dtype.itemsize
@@ -700,6 +706,19 @@ class LayerKindKV(DenseKV):
                 for leaf, (_, names) in self.counters.items()]
         return super().ride(cache, jnp.concatenate([block, *rows], axis=0), kv_read)
 
+    def at(self, slot: int, filled: int = 0, piece_len: int = 0):
+        """WHERE for one slot; for a prefill piece over rings also where the
+        piece starts and where it ends: ``view`` unrolls a ring from the one,
+        ``put_piece`` rolls the piece's last positions back in from the other."""
+        if not (piece_len and self.has_rings):
+            return jnp.int32(slot)
+        self.stats["kv_ring_pieces"] = self.stats.get("kv_ring_pieces", 0) + 1
+        return jnp.int32(slot), jnp.int32(filled), jnp.int32(filled + piece_len)
+
+    @staticmethod
+    def slot_of(where):
+        return where[0] if isinstance(where, tuple) else where
+
     def _ring_rows(self, little):
         """The part of a dense scratch leaf ``[k, S, ...]`` a ring keeps, laid
         out by ring index: all of it when it fits, else its last ``ring``
@@ -728,25 +747,48 @@ class LayerKindKV(DenseKV):
     def view(self, cache, where, length: int):
         """The slot's own leaves as a ``[1, ...]`` cache for a prefill piece:
         the front ``length`` positions of a full leaf, as many rows of an
-        index as cover them, a state whole; the counters stay behind."""
-        if "window" in self.kinds.values():
+        index as cover them, a state whole; the counters stay behind. A ring
+        is handed over UNROLLED — the slot's last ``ring`` positions in
+        position order, index j holding position ``ring_start + j`` with
+        ``ring_start = filled - ring`` a leaf of its own beside them (a
+        negative position holds nothing): what of the prompt a ring still has
+        is all a window layer's piece can see (``where`` from :meth:`at`)."""
+        if self.has_rings and not isinstance(where, tuple):
             raise Refused("a cache per layer kind with rings gives no dense view of a slot "
-                          "(prefix cache, chunked prefill)")
+                          "(prefix cache)")
+        slot = self.slot_of(where)
 
         def front(big, kind):
-            if kind == "state":
+            if kind in ("state", "window"):
                 size = (1,) + big.shape[1:]
             else:
                 size = (1, big.shape[1] * length // self.max_len) + big.shape[2:]
-            return jax.lax.dynamic_slice(big, (where,) + (0,) * (big.ndim - 1), size)
+            mine = jax.lax.dynamic_slice(big, (slot,) + (0,) * (big.ndim - 1), size)
+            # index j of the view is ring index (filled + j) mod ring
+            return jnp.roll(mine, -(where[1] % self.ring), axis=1) if kind == "window" else mine
 
-        return {name: front(cache[name], kind) for name, kind in self.kinds.items()
-                if kind != "counter"}
+        row = {name: front(cache[name], kind) for name, kind in self.kinds.items()
+               if kind != "counter"}
+        if self.has_rings:
+            row["ring_start"] = where[1] - self.ring
+        return row
 
     def put_piece(self, cache, row, where):
-        if "window" in self.kinds.values():
-            raise Refused("a cache per layer kind with rings takes no prefill piece")
-        return self.put(cache, row, where)
+        """Write a piece's row back. The family's forward hands a ring's leaf
+        back as the last ``ring`` positions up to the piece's end, in position
+        order: index j goes to ring index ``(end + j) mod ring``."""
+        if not self.has_rings:
+            return self.put(cache, row, where)
+        slot, _, end = where
+        out = dict(cache)
+        for name, little in row.items():
+            if name not in self.kinds:
+                continue  # ``ring_start``: the view's, not the state's
+            if self.kinds[name] == "window":
+                little = jnp.roll(little, end % self.ring, axis=1)
+            out[name] = jax.lax.dynamic_update_slice(
+                cache[name], little, (slot,) + (0,) * (little.ndim - 1))
+        return out
 
 
 def replicated(mesh):

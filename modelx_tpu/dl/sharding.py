@@ -248,6 +248,26 @@ DEEPSEEK_V2_RULES: Rules = [
     (r".*", []),
 ]
 
+# MiMo-V2-Flash (models/mimo_v2.py): attention by head (keys of 192 and values
+# of 128 split with their heads), the sinks with the query heads they belong
+# to; the router at its published width and its choice bias replicated; the
+# experts as Laguna's.
+MIMO_V2_RULES: Rules = [
+    (r"embed_tokens\.weight$", ["tp", None]),
+    (r"lm_head\.weight$", ["tp", None]),
+    (r"(q|k|v)_proj\.weight$", ["tp", None]),
+    (r"o_proj\.weight$", [None, "tp"]),
+    (r"attention_sink_bias$", ["tp"]),
+    (r"mlp\.gate\.weight$", [None, None]),
+    (r"mlp\.gate\.e_score_correction_bias$", [None]),
+    (r"experts\.(gate|up)_proj\.weight$", ["ep", "tp", None]),
+    (r"experts\.down_proj\.weight$", ["ep", None, "tp"]),
+    (r"(gate|up)_proj\.weight$", ["tp", None]),
+    (r"down_proj\.weight$", [None, "tp"]),
+    (r"norm\.weight$", [None]),
+    (r".*", []),
+]
+
 # Nemotron-H (models/nemotron_h.py): a Mamba layer's fused input projection
 # is three runs of rows (gate, convolved channels, step sizes) that a split of
 # its rows would cut across, so the Mamba projections, the convolution and the
@@ -284,6 +304,7 @@ DEFAULT_RULES: dict[str, Rules] = {
     "minicpm_sala": MINICPM_SALA_RULES,
     "deepseek_v2": DEEPSEEK_V2_RULES,
     "nemotron_h": NEMOTRON_H_RULES,
+    "mimo_v2": MIMO_V2_RULES,
 }
 
 
@@ -303,6 +324,8 @@ def infer_family(tensor_names: Sequence[str]) -> str:
         return "nemotron_h"  # one mixer a layer: state-space, experts or attention
     if "self_attn.kv_a_proj_with_mqa" in joined:
         return "deepseek_v2"  # one compressed key-value line a position (latent attention)
+    if "self_attn.attention_sink_bias" in joined:
+        return "mimo_v2"  # a learned sink a query head on the window layers
     if "self_attn.g_proj" in joined:
         return "laguna"  # per-head output gate beside q/k/v/o
     if "self_attn.o_gate" in joined:

@@ -41,10 +41,10 @@ Three shapes of KV state, one forward:
 Served through the continuous engine this family **refuses at start-up**
 what a ring — its ``"window"`` leaves, not the layout as such — cannot give:
 ``--prefix-cache`` (and with it the KV store's bundles and resume from stored
-KV: a ring cannot give back a prefix it has overwritten), ``--prefill-chunk``
-(a piece needs the slot's earlier rows as a dense view; a layout without
-rings carries it), ``--speculative-k`` (a verify block writes several ring
-positions a step) and ``--kv-page-size`` (a ring is not paged)
+KV: a ring cannot give back a prefix it has overwritten), ``--speculative-k``
+(a verify block writes several ring positions a step) and ``--kv-page-size``
+(a ring is not paged); ``--prefill-chunk`` it carries: a piece sees a ring as
+its slot's last positions in position order
 (dl/kv_layout.LayerKindKV.refuse names the option, the leaf kind, the reason). Rope types other than ``default`` and
 ``yarn``, a gating other than ``per-head``, router soft-capping and router
 weights applied on the input are refused when the config is read.
@@ -405,7 +405,7 @@ def init_layer_state(cfg: LagunaConfig, slots: int, max_len: int, dtype=None) ->
 
 
 def _attention(q, k, v, cfg: LagunaConfig, layer: int, ctx: ShardingCtx, cache,
-               cache_offset, ring: bool, attention_impl: str):
+               cache_offset, ring: bool, attention_impl: str, ring_start=None):
     """q [B,S,H,D], k/v [B,S,Hkv,D] after rope -> ([B,S,H,D], new cache).
 
     No cache: flash on a TPU, else the reference. A cache: the new keys and
@@ -418,7 +418,11 @@ def _attention(q, k, v, cfg: LagunaConfig, layer: int, ctx: ShardingCtx, cache,
     ring once where it lies and masks by each index's age; a window over a
     dense cache, an admission's prefill and the CPU keep
     ``attention_reference``. ``attention_impl`` ``"ragged"``
-    (``"ragged+interpret"`` on the CPU) asks for the kernels by name."""
+    (``"ragged+interpret"`` on the CPU) asks for the kernels by name.
+    ``ring_start`` (a prefill piece over the engine's state): a window layer's
+    cache is its slot's last positions in position order from there on, and
+    the piece attends those and itself
+    (``ops.attention.ring_context_attention``)."""
     window = cfg.window(layer)
     t = lambda x: x.transpose(0, 2, 1, 3)
     if cache is None:
@@ -434,6 +438,9 @@ def _attention(q, k, v, cfg: LagunaConfig, layer: int, ctx: ShardingCtx, cache,
             out = attn_ops.attention_reference(t(q), t(k), t(v), causal=True, window=window)
         return t(out), None
     ck, cv = cache
+    if window and ring_start is not None:
+        return attn_ops.ring_context_attention(q, ck, cv, k, v, ring_start, cache_offset,
+                                               window)
     rings = ring and bool(window)  # a full layer's leaf is dense under ``ring`` too
     if rings:  # one token a step: ``cached_attention`` refuses a longer block
         length = ck.shape[1]
@@ -450,7 +457,7 @@ def _attention(q, k, v, cfg: LagunaConfig, layer: int, ctx: ShardingCtx, cache,
 
 def decoder_layer(params, p: str, x, positions, cfg: LagunaConfig, layer: int,
                   ctx: ShardingCtx, cache=None, cache_offset=0, ring: bool = False,
-                  attention_impl: str = "auto"):
+                  attention_impl: str = "auto", ring_start=None):
     """One block. Returns (x, updated (k, v) or None, the expert layer's
     counts or None)."""
     b, s = x.shape[:2]
@@ -467,7 +474,7 @@ def decoder_layer(params, p: str, x, positions, cfg: LagunaConfig, layer: int,
         k = ctx.constrain(apply_rope(k, positions, cfg.rope(layer)), "dp", "sp", "tp", None)
         v = ctx.constrain(v, "dp", "sp", "tp", None)
         attn, new_cache = _attention(q, k, v, cfg, layer, ctx, cache, cache_offset, ring,
-                                     attention_impl)
+                                     attention_impl, ring_start)
         # the per-head gate: one scalar a query head, outside the contraction
         attn = (attn.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
         x = x + _linear(attn.reshape(b, s, heads * hd), params[p + "self_attn.o_proj.weight"])
@@ -498,8 +505,11 @@ def forward(params, tokens, cfg: LagunaConfig, positions=None, kv_cache: dict | 
             attention_impl: str = "auto", ring: bool = False):
     """Returns (logits [B,S,V], updated kv_cache). ``kv_cache`` None: one
     cache-less pass. A dense cache for every layer (:func:`init_kv_cache`):
-    prefill and decode as the other families do them. ``ring=True``: the
-    engine's per-kind state (:func:`init_layer_state`), one token a step;
+    prefill and decode as the other families do them; with a ``ring_start``
+    leaf beside the layers' (a prefill piece over the engine's state,
+    dl/kv_layout.LayerKindKV.view) a window layer's leaves are its slot's last
+    positions in position order from there on, and come back as the last of
+    those and the block's together. ``ring=True``: the engine's per-kind state (:func:`init_layer_state`), one token a step;
     its ``moe_counts`` leaf grows by what the step's expert layers counted."""
     ctx = ShardingCtx(mesh)
     b, s = tokens.shape
@@ -515,7 +525,8 @@ def forward(params, tokens, cfg: LagunaConfig, positions=None, kv_cache: dict | 
         cache = (kv_cache[f"k{i}"], kv_cache[f"v{i}"]) if kv_cache is not None else None
         x, updated, counts = decoder_layer(
             params, f"model.layers.{i}.", x, positions, cfg, i, ctx, cache=cache,
-            cache_offset=cache_offset, ring=ring, attention_impl=attention_impl)
+            cache_offset=cache_offset, ring=ring, attention_impl=attention_impl,
+            ring_start=kv_cache.get("ring_start") if kv_cache is not None else None)
         if updated is not None:
             new_cache[f"k{i}"], new_cache[f"v{i}"] = updated
         if counts is not None:

@@ -64,6 +64,10 @@ RAGGED_MIN_BLOCK = 128
 # own VMEM limit — both double-buffered beside the f32 logits — and 8.45 MB
 # (2,064) does not. Laguna's 528 positions are 2.16 MB
 RING_BLOCK_BYTES = 9 << 19
+# the block of a ``[B, L, Hkv * D]`` leaf (a position's heads in one line) is
+# what four KV heads' would be: RAGGED_COLUMNS // 4 = 512 positions, 0.79 MB of
+# 768-wide keys — a line is one column there, whatever heads it holds
+FLAT_KV_HEADS = 4
 
 
 # -- reference (jnp) ----------------------------------------------------------
@@ -71,7 +75,7 @@ RING_BLOCK_BYTES = 9 << 19
 
 def attention_reference(q, k, v, causal: bool = True, q_offset=0,
                         scale: float | None = None, logit_softcap: float = 0.0,
-                        window: int = 0, key_positions=None):
+                        window: int = 0, key_positions=None, sinks=None):
     """Plain softmax(QK^T * scale)V. Shapes: [B, H, S, D] (kv may have fewer
     heads than q — GQA — as long as H % Hkv == 0). ``q_offset`` positions the
     queries for cached decode: a scalar for uniform batches, or a [B] vector
@@ -84,7 +88,11 @@ def attention_reference(q, k, v, causal: bool = True, q_offset=0,
     window attention; needs ``causal``). ``key_positions`` ([B, K] int, needs
     ``causal``) gives each key's absolute position where index and position
     differ — a ring written at ``position mod K`` — and a negative entry
-    marks a key that holds nothing yet."""
+    marks a key that holds nothing yet. The values may be narrower or wider
+    than the keys (``v`` ``[B, Hkv, K, Dv]``: the output is ``[B, H, S, Dv]``).
+    ``sinks`` ``[H]``: one learned logit a query head that joins the softmax's
+    denominator and carries no value — ``a_j = exp(s_j) / (exp(sink) + sum_i
+    exp(s_i))``; it is neither scaled nor masked."""
     b, hq, qlen, d = q.shape
     qk, pv = "bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd"
     if k.shape[1] != hq:
@@ -113,8 +121,14 @@ def attention_reference(q, k, v, causal: bool = True, q_offset=0,
         if window > 0:  # keys qpos-window < kpos <= qpos stay visible
             visible = visible & (kpos > qpos - window)
         logits = jnp.where(visible, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum(pv, probs.astype(v.dtype), v).reshape(b, hq, qlen, d)
+    if sinks is not None:
+        sink = jnp.broadcast_to(
+            sinks.astype(jnp.float32).reshape(logits.shape[1:-2] + (1, 1)),
+            logits.shape[:-1] + (1,))
+        probs = jax.nn.softmax(jnp.concatenate([logits, sink], axis=-1), axis=-1)[..., :-1]
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum(pv, probs.astype(v.dtype), v).reshape(b, hq, qlen, v.shape[-1])
 
 
 def _repeat_kv_heads(q, k, v):
@@ -128,19 +142,23 @@ def _repeat_kv_heads(q, k, v):
 # -- pallas flash kernel ------------------------------------------------------
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
+def _flash_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, causal: bool,
                   sm_scale: float, logit_softcap: float = 0.0, window: int = 0,
                   kv_len: int = 0):
     """One (batch*head, q-block) program: online softmax over k/v blocks.
 
-    q_ref: [block_q, d], k_ref/v_ref: [seq_k, d], o_ref: [block_q, d].
+    q_ref: [block_q, d], k_ref: [seq_k, d], v_ref: [seq_k, dv], o_ref:
+    [block_q, dv]. With a sink (``rest`` = sink_ref [1, 1], o_ref) the online
+    softmax starts from the head's sink logit — (m, l, acc) = (sink, 1, 0) in
+    place of (-inf, 0, 0): mass in the denominator that carries no value.
     ``logit_softcap`` > 0 tanh-caps the scaled scores before masking and
     ``window`` > 0 limits each query to its last ``window`` keys (gemma2);
     both default off, preserving the plain flash semantics. ``kv_len`` > 0
     says only the first ``kv_len`` keys are real (the rest is block
     padding) and masks the tail.
     """
-    block_q, d = q_ref.shape
+    *sink_ref, o_ref = rest
+    block_q, dv = o_ref.shape
     seq_k = k_ref.shape[0]
     q_idx = pl.program_id(1)
     q = q_ref[:].astype(jnp.float32) * sm_scale
@@ -191,9 +209,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
             # ...and the fully-below-window blocks before it: the earliest
             # key any query in this block can see is q_idx*bq - window + 1
             lo = jnp.maximum(0, (q_idx * block_q - window + 1) // block_k)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
+    acc0 = jnp.zeros((block_q, dv), jnp.float32)
+    if sink_ref:
+        m0 = jnp.broadcast_to(sink_ref[0][...].reshape(1), (block_q,))
+        l0 = jnp.ones((block_q,), jnp.float32)
+    else:
+        m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((block_q,), jnp.float32)
     acc, _m, l = jax.lax.fori_loop(lo, num_k, body, (acc0, m0, l0))
     o_ref[:] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
 
@@ -206,9 +228,10 @@ def flash_blocks(seq: int, block: int = FLASH_BLOCK) -> tuple[int, int]:
     return block, -(-seq // block) * block
 
 
-def _flash_local(q, k, v, *, causal, block_q, block_k, interpret, scale,
+def _flash_local(q, k, v, sinks=None, *, causal, block_q, block_k, interpret, scale,
                  logit_softcap, window):
-    """The kernel on ONE device's share: q [B, H, Sq, D], k/v [B, Hkv, Sk, D].
+    """The kernel on ONE device's share: q [B, H, Sq, D], k [B, Hkv, Sk, D], v
+    [B, Hkv, Sk, Dv], ``sinks`` [H] or None (a sink logit a query head).
     Ragged lengths are padded up to the block (padded keys masked in the
     kernel, padded query rows sliced off) — never handed to another
     implementation."""
@@ -219,10 +242,16 @@ def _flash_local(q, k, v, *, causal, block_q, block_k, interpret, scale,
     block_k, pk = flash_blocks(sk, block_k)
     sm_scale = scale if scale is not None else 1.0 / math.sqrt(d)
 
+    dv = v.shape[-1]
+
     def rows(x, s, padded):
-        x = x.reshape(b * h, s, d)
+        x = x.reshape(b * h, s, x.shape[-1])
         return jnp.pad(x, ((0, 0), (0, padded - s), (0, 0))) if padded != s else x
 
+    sink_spec, sink_arg = [], []
+    if sinks is not None:
+        sink_spec = [pl.BlockSpec((None, 1, 1), lambda i, j: (i, 0, 0))]
+        sink_arg = [jnp.tile(sinks.astype(jnp.float32), b).reshape(b * h, 1, 1)]
     out = pl.pallas_call(
         functools.partial(_flash_kernel, block_k=block_k, causal=causal,
                           sm_scale=sm_scale, logit_softcap=logit_softcap,
@@ -231,13 +260,14 @@ def _flash_local(q, k, v, *, causal, block_q, block_k, interpret, scale,
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, pk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, pk, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, pk, dv), lambda i, j: (i, 0, 0)),
+            *sink_spec,
         ],
-        out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, pq, d), q.dtype),
+        out_specs=pl.BlockSpec((None, block_q, dv), lambda i, j: (i, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * h, pq, dv), q.dtype),
         interpret=interpret,
-    )(rows(q, sq, pq), rows(k, sk, pk), rows(v, sk, pk))
-    return out[:, :sq].reshape(b, h, sq, d)
+    )(rows(q, sq, pq), rows(k, sk, pk), rows(v, sk, pk), *sink_arg)
+    return out[:, :sq].reshape(b, h, sq, dv)
 
 
 def _axes_dividing(mesh: Mesh, names: tuple[str, ...], dim: int):
@@ -255,8 +285,10 @@ def _axes_dividing(mesh: Mesh, names: tuple[str, ...], dim: int):
 def flash_attention(q, k, v, causal: bool = True, block_q: int = FLASH_BLOCK,
                     block_k: int = FLASH_BLOCK, interpret: bool = False,
                     scale: float | None = None, logit_softcap: float = 0.0,
-                    window: int = 0, mesh: Mesh | None = None):
-    """Flash attention via pallas. q/k/v: [B, H, S, D] (GQA allowed).
+                    window: int = 0, mesh: Mesh | None = None, sinks=None):
+    """Flash attention via pallas. q/k/v: [B, H, S, D] (GQA allowed; the
+    values' width may differ from the keys'). ``sinks`` [H]: a sink logit a
+    query head, the online softmax's starting state (:func:`attention_reference`).
 
     The kernel compiles for the backend it runs on; ``interpret=True`` is
     for callers on the CPU that ask for it (tests, the virtual-device dry
@@ -274,7 +306,9 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = FLASH_BLOCK,
         interpret=interpret, scale=scale, logit_softcap=logit_softcap,
         window=window)
     if mesh is None or mesh.size == 1:
-        return local(q, k, v)
+        return local(q, k, v, sinks)
+    if sinks is not None:
+        raise ValueError("attention sinks under a mesh are not implemented")
     batch = _axes_dividing(mesh, ("dp", "fsdp"), q.shape[0])
     heads = _axes_dividing(mesh, ("tp",), k.shape[1])
     if heads is None and _axes_dividing(mesh, ("tp",), q.shape[1]) is not None:
@@ -323,9 +357,22 @@ def _fold_block(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, sm_scale: float, vis
     m_ref[...] = m_new
 
 
+def _start_state(sink_ref, m_ref, l_ref, acc_ref):
+    """A row's online-softmax state before its first block: (-inf, 0, 0), or
+    with sinks ``(sink, 1, 0)`` — each query head's sink logit already in the
+    denominator, with no value behind it (sink_ref: a list of none or one ref
+    ``[rows, 1]``)."""
+    if sink_ref:
+        m_ref[...] = sink_ref[0][...]
+        l_ref[...] = jnp.ones_like(l_ref)
+    else:
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
 def _ragged_decode_kernel(len_ref, q_ref, k_ref, v_ref, row_head_ref, col_head_ref,
-                          col_pos_ref, o_ref, m_ref, l_ref, acc_ref, *, block: int,
-                          sm_scale: float):
+                          col_pos_ref, *rest, block: int, sm_scale: float):
     """One (row, KV block) program of :func:`decode_attention`.
 
     q_ref [rows, d]: every query head of the row. k_ref / v_ref [block *
@@ -338,16 +385,16 @@ def _ragged_decode_kernel(len_ref, q_ref, k_ref, v_ref, row_head_ref, col_head_r
     same tiles and need the heads picked apart first. The online-softmax state
     (m, l, acc) lives in scratch across the row's blocks; a block past the
     row's last does nothing (its index map pointed at the last one, so nothing
-    was copied for it either)."""
+    was copied for it either). ``rest``: (sink_ref,) o_ref, m_ref, l_ref,
+    acc_ref (:func:`_start_state`)."""
+    *sink_ref, o_ref, m_ref, l_ref, acc_ref = rest
     row, j = pl.program_id(0), pl.program_id(1)
     length = len_ref[row]
     last = (length - 1) // block
 
     @pl.when(j == 0)
     def _():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _start_state(sink_ref, m_ref, l_ref, acc_ref)
 
     @pl.when(j <= last)
     def _():
@@ -361,70 +408,130 @@ def _ragged_decode_kernel(len_ref, q_ref, k_ref, v_ref, row_head_ref, col_head_r
         o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-def _head_rows(q, hkv: int, block: int):
+def _head_rows(q, hkv: int, block: int, flat: bool = False):
     """What both decode kernels make of q [B, 1, H, D] over blocks of ``block``
     positions of ``hkv`` KV heads: q as [B, rows, D], H padded to whole packed
     bf16 tiles of 16 rows; ``rows``; and the three index vectors of a block's
     mask — each row's KV head [rows, 1] (a pad row matches none), each
-    column's KV head and its position in the block [1, block * hkv]."""
+    column's KV head and its position in the block [1, block * hkv].
+
+    ``flat``: the cache keeps a position's KV heads side by side in ONE line
+    ``[B, L, hkv * D]``. q then goes ``[B, rows, hkv * D]``, each head's query
+    in its own KV head's lanes and zero in the others', so that one contraction
+    over the whole line is the head against its own KV head; a column is a
+    position, and no head is masked."""
     b, _, hq, d = q.shape
     rows = -(-hq // FLASH_ROW_TILE) * FLASH_ROW_TILE
     q = q.reshape(b, hq, d)
+    row_head = np.full((rows, 1), -1, np.int32)
+    if flat:
+        q = (q[:, :, None, :] * jnp.asarray(_own_kv_head(hq, hkv), q.dtype)[None, :, :, None]
+             ).reshape(b, hq, hkv * d)
+        row_head[:hq, 0] = 0
+        col = np.arange(block, dtype=np.int32)[None]
+        heads = (row_head, np.zeros_like(col), col)
+    else:
+        row_head[:hq, 0] = np.arange(hq) // (hq // hkv)
+        col = np.arange(block * hkv, dtype=np.int32)[None]
+        heads = (row_head, col % hkv, col // hkv)
     if rows != hq:  # a packed bf16 tile is 16 rows; pad rows match no KV head
         q = jnp.pad(q, ((0, 0), (0, rows - hq), (0, 0)))
-    row_head = np.full((rows, 1), -1, np.int32)
-    row_head[:hq, 0] = np.arange(hq) // (hq // hkv)
-    col = np.arange(block * hkv, dtype=np.int32)[None]
-    return q, rows, (row_head, col % hkv, col // hkv)
+    return q, rows, heads
+
+
+def _own_kv_head(hq: int, hkv: int) -> np.ndarray:
+    """[hq, hkv] 0/1: query head h reads KV head ``h // (hq / hkv)``."""
+    return (np.arange(hq)[:, None] // (hq // hkv) == np.arange(hkv)[None, :]).astype(np.float32)
+
+
+def _kv_lines(q, k_cache, v_cache):
+    """How a decode kernel sees its caches: (flat, hkv, line width of the keys,
+    of the values, lines a position). ``[B, L, Hkv, D]`` leaves are read as
+    lines of one (position, KV head) pair (:func:`_as_lines`); ``[B, L, Hkv *
+    D]`` leaves (``flat``) as they are, a line a position. The values' width is
+    their own (``Dv``)."""
+    d = q.shape[-1]
+    if k_cache.ndim == 3:
+        return True, k_cache.shape[2] // d, k_cache.shape[2], v_cache.shape[2], 1
+    return False, k_cache.shape[2], d, v_cache.shape[3], k_cache.shape[2]
+
+
+def _as_lines(cache):
+    """A cache leaf as ``[B, lines, width]``: the same bytes, no copy."""
+    return cache if cache.ndim == 3 else cache.reshape(
+        cache.shape[0], cache.shape[1] * cache.shape[2], cache.shape[3])
+
+
+def _pick_heads(out, hq: int, hkv: int, flat: bool):
+    """A decode kernel's output ``[B, rows, width]`` as ``[B, 1, H, Dv]``: the
+    real rows, and of a ``flat`` cache's line each head's own KV head's lanes
+    (exact: a sum of one value and zeros)."""
+    b = out.shape[0]
+    out = out[:, :hq]
+    if flat:
+        out = jnp.einsum("bhgd,hg->bhd", out.reshape(b, hq, hkv, -1),
+                         jnp.asarray(_own_kv_head(hq, hkv), out.dtype))
+    return out.reshape(b, 1, hq, -1)
+
+
+def _sink_rows(sinks, rows: int):
+    """``sinks`` [H] as the kernels' operand ``[rows, 1]`` float32 (pad rows 0)."""
+    return jnp.pad(sinks.astype(jnp.float32), (0, rows - sinks.shape[0]))[:, None]
 
 
 def decode_attention(q, k_cache, v_cache, lengths, scale: float | None = None, *,
-                     block: int = 0, interpret: bool = False):
+                     block: int = 0, interpret: bool = False, sinks=None):
     """One decode step's attention, each row over its own context only.
 
-    q [B, 1, H, D]; k_cache / v_cache [B, L, Hkv, D] as the engine keeps them
-    (no transpose, no copy: ``[B, L * Hkv, D]`` is the same bytes); ``lengths``
+    q [B, 1, H, D]; k_cache [B, L, Hkv, D] and v_cache [B, L, Hkv, Dv] as the
+    engine keeps them (no transpose, no copy: ``[B, L * Hkv, D]`` is the same
+    bytes), or ``[B, L, Hkv * D]`` / ``[B, L, Hkv * Dv]``, a position's heads
+    side by side in one line (:func:`_head_rows`, ``flat`` — what a leaf whose
+    head is not whole lane tiles, 192 say, is kept as); ``lengths``
     [B] int, the positions each row holds (``cache_offset + 1``, clipped to
-    1..L). Returns [B, 1, H, D] in q's dtype. Row i reads ``ceil(lengths[i] /
+    1..L). Returns [B, 1, H, Dv] in q's dtype. Row i reads ``ceil(lengths[i] /
     block)`` blocks of ``block`` positions and folds them with an online
     softmax — operands as they are, f32 logits, statistics and accumulator —
     the last one masked by position; what lies past it is neither copied nor
     computed (the grid spans all ``L / block`` blocks, the index map holds at
-    the row's last). ``block`` 0 takes :func:`ragged_block`'s. Algebraically
+    the row's last). ``block`` 0 takes :func:`ragged_block`'s. ``sinks`` [H]:
+    a sink logit a query head, the softmax's starting state. Algebraically
     the softmax of :func:`attention_reference`, not bit-identical to it."""
     b, _, hq, d = q.shape
-    cache_len, hkv = k_cache.shape[1:3]
-    block = block or ragged_block(cache_len, hkv)
+    cache_len = k_cache.shape[1]
+    flat, hkv, wk, wv, lines = _kv_lines(q, k_cache, v_cache)
+    block = block or ragged_block(cache_len, FLAT_KV_HEADS if flat else hkv)
     if not block or cache_len % block:
         raise ValueError(f"no block of {block} positions tiles a cache of {cache_len}")
-    cols = block * hkv
+    cols = block * lines
     lengths = jnp.clip(lengths.astype(jnp.int32), 1, cache_len)
-    q, rows, heads = _head_rows(q, hkv, block)
+    q, rows, heads = _head_rows(q, hkv, block, flat)
 
     def kv_index(i, j, lens):
         return i, jnp.minimum(j, (lens[i] - 1) // block), 0
 
-    per_row = pl.BlockSpec((None, rows, d), lambda i, j, lens: (i, 0, 0))
-    kv_block = pl.BlockSpec((None, cols, d), kv_index)
+    per_row = lambda w: pl.BlockSpec((None, rows, w), lambda i, j, lens: (i, 0, 0))  # noqa: E731
     whole = lambda *shape: pl.BlockSpec(shape, lambda i, j, lens: (0, 0))
+    sink = [] if sinks is None else [_sink_rows(sinks, rows)]
     out = pl.pallas_call(
         functools.partial(_ragged_decode_kernel, block=block,
                           sm_scale=scale if scale is not None else 1.0 / math.sqrt(d)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b, cache_len // block),
-            in_specs=[per_row, kv_block, kv_block,
-                      whole(rows, 1), whole(1, cols), whole(1, cols)],
-            out_specs=per_row,
+            in_specs=[per_row(wk), pl.BlockSpec((None, cols, wk), kv_index),
+                      pl.BlockSpec((None, cols, wv), kv_index),
+                      whole(rows, 1), whole(1, cols), whole(1, cols),
+                      *[whole(rows, 1) for _ in sink]],
+            out_specs=per_row(wv),
             scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
                             pltpu.VMEM((rows, 1), jnp.float32),
-                            pltpu.VMEM((rows, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
+                            pltpu.VMEM((rows, wv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, rows, wv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret, name="ragged_decode_attention",
-    )(lengths, q, k_cache.reshape(b, cache_len * hkv, d),
-      v_cache.reshape(b, cache_len * hkv, d), *heads)
-    return out[:, :hq].reshape(b, 1, hq, d)
+    )(lengths, q, _as_lines(k_cache), _as_lines(v_cache), *heads, *sink)
+    return _pick_heads(out, hq, hkv, flat)
 
 
 def ring_key_positions(offsets, length: int):
@@ -438,18 +545,17 @@ def ring_key_positions(offsets, length: int):
 
 
 def _ring_decode_kernel(off_ref, q_ref, k_ref, v_ref, row_head_ref, col_head_ref,
-                        col_pos_ref, o_ref, m_ref, l_ref, acc_ref, *, length: int,
-                        window: int, sm_scale: float):
+                        col_pos_ref, *rest, length: int, window: int, sm_scale: float):
     """One row of :func:`ring_decode_attention`: the whole ring is the row's
     one block, folded by :func:`_fold_block` into a fresh state. Ring index r
     is ``age = (offset - r) mod length`` positions old; it counts iff ``age <
     window`` (inside the window) and ``age <= offset`` (it has been written:
-    ``key_positions >= 0``). The modulo is taken once, on the scalar."""
+    ``key_positions >= 0``). The modulo is taken once, on the scalar.
+    ``rest``: (sink_ref,) o_ref, m_ref, l_ref, acc_ref."""
+    *sink_ref, o_ref, m_ref, l_ref, acc_ref = rest
     offset = off_ref[pl.program_id(0)]
     newest = jax.lax.rem(offset, length)  # the ring index of the query's own position
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+    _start_state(sink_ref, m_ref, l_ref, acc_ref)
 
     def visible():
         age = newest - col_pos_ref[...]
@@ -462,42 +568,46 @@ def _ring_decode_kernel(off_ref, q_ref, k_ref, v_ref, row_head_ref, col_head_ref
 
 
 def ring_decode_attention(q, k_ring, v_ring, offsets, window: int,
-                          scale: float | None = None, *, interpret: bool = False):
+                          scale: float | None = None, *, interpret: bool = False,
+                          sinks=None):
     """One decode step's sliding-window attention over ring caches, each ring
     read once where it lies.
 
-    q [B, 1, H, D] at positions ``offsets`` [B]; k_ring / v_ring [B, L, Hkv, D],
+    q [B, 1, H, D] at positions ``offsets`` [B]; k_ring [B, L, Hkv, D] and
+    v_ring [B, L, Hkv, Dv] (or both ``flat``, :func:`decode_attention`),
     position p at index ``p mod L``, the query's own already written (viewed
     ``[B, L * Hkv, D]``: the same bytes, no transpose, no copy). A grid step a
     row, the row's whole ring its one block — a ring is full after L positions,
     there is nothing to skip, and 528 = 16 x 33 has no power-of-two block for
     :func:`ragged_block` to find — masked by each index's age
     (:func:`_ring_decode_kernel`): :func:`attention_reference` under ``window``
-    and :func:`ring_key_positions`, algebraically, not bit for bit. Returns
-    [B, 1, H, D] in q's dtype."""
+    and :func:`ring_key_positions`, algebraically, not bit for bit. ``sinks``
+    [H]: a sink logit a query head. Returns [B, 1, H, Dv] in q's dtype."""
     b, _, hq, d = q.shape
-    length, hkv = k_ring.shape[1:3]
-    q, rows, heads = _head_rows(q, hkv, length)
-    cols = length * hkv
-    per_row = lambda n: pl.BlockSpec((None, n, d), lambda i, offs: (i, 0, 0))  # noqa: E731
+    length = k_ring.shape[1]
+    flat, hkv, wk, wv, lines = _kv_lines(q, k_ring, v_ring)
+    q, rows, heads = _head_rows(q, hkv, length, flat)
+    cols = length * lines
+    per_row = lambda n, w: pl.BlockSpec((None, n, w), lambda i, offs: (i, 0, 0))  # noqa: E731
     whole = lambda *shape: pl.BlockSpec(shape, lambda i, offs: (0, 0))  # noqa: E731
+    sink = [] if sinks is None else [_sink_rows(sinks, rows)]
     out = pl.pallas_call(
         functools.partial(_ring_decode_kernel, length=length, window=window,
                           sm_scale=scale if scale is not None else 1.0 / math.sqrt(d)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b,),
-            in_specs=[per_row(rows), per_row(cols), per_row(cols),
-                      whole(rows, 1), whole(1, cols), whole(1, cols)],
-            out_specs=per_row(rows),
+            in_specs=[per_row(rows, wk), per_row(cols, wk), per_row(cols, wv),
+                      whole(rows, 1), whole(1, cols), whole(1, cols),
+                      *[whole(rows, 1) for _ in sink]],
+            out_specs=per_row(rows, wv),
             scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
                             pltpu.VMEM((rows, 1), jnp.float32),
-                            pltpu.VMEM((rows, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
+                            pltpu.VMEM((rows, wv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, rows, wv), q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret, name="ring_decode_attention",
-    )(offsets.astype(jnp.int32), q, k_ring.reshape(b, cols, d), v_ring.reshape(b, cols, d),
-      *heads)
-    return out[:, :hq].reshape(b, 1, hq, d)
+    )(offsets.astype(jnp.int32), q, _as_lines(k_ring), _as_lines(v_ring), *heads, *sink)
+    return _pick_heads(out, hq, hkv, flat)
 
 
 _ragged_calls = threading.local()
@@ -540,16 +650,24 @@ def decode_block(cache_shape: tuple, itemsize: int = 2, *, ring: bool = False,
     for heads of a multiple of 128, whole tiles of KV heads, the TPU backend
     and one device (a bare Mosaic call cannot be partitioned); ``impl``
     ``"ragged"`` (``"ragged+interpret"`` on the CPU) asks for the kernel by
-    name wherever it can run at all."""
-    cache_len, hkv, d = cache_shape[1:]
+    name wherever it can run at all. A leaf ``[B, L, W]`` keeps a position's
+    heads side by side in one line (``flat``, :func:`decode_attention`): the
+    same two kernels, lines of a multiple of 128 lanes in place of the heads'
+    two conditions, blocks of :data:`FLAT_KV_HEADS`' positions."""
+    if len(cache_shape) == 4:
+        cache_len, hkv, d = cache_shape[1:]
+        whole_tiles, line_bytes = d % 128 == 0 and hkv % 8 == 0, hkv * d * itemsize
+    else:
+        (cache_len, width), hkv = cache_shape[1:], FLAT_KV_HEADS
+        whole_tiles, line_bytes = width % 128 == 0, width * itemsize
     if ring:
-        block, fits = cache_len, 2 * cache_len * hkv * d * itemsize <= RING_BLOCK_BYTES
+        block, fits = cache_len, 2 * cache_len * line_bytes <= RING_BLOCK_BYTES
     else:
         block = ragged_block(cache_len, hkv)
         fits = block >= RAGGED_MIN_BLOCK
     if impl.partition("+")[0] == "ragged":
         return block
-    if (fits and d % 128 == 0 and hkv % 8 == 0 and jax.default_backend() == "tpu"
+    if (fits and whole_tiles and jax.default_backend() == "tpu"
             and (mesh is None or mesh.size == 1)):
         return block
     return 0
@@ -557,7 +675,8 @@ def decode_block(cache_shape: tuple, itemsize: int = 2, *, ring: bool = False,
 
 def cached_attention(q, k_cache, v_cache, q_offset, *, impl: str = "auto",
                      mesh: Mesh | None = None, scale: float | None = None,
-                     logit_softcap: float = 0.0, window: int = 0, ring: bool = False):
+                     logit_softcap: float = 0.0, window: int = 0, ring: bool = False,
+                     sinks=None):
     """Causal attention of q [B, S, H, D] (positions ``q_offset`` onwards)
     against a KV cache [B, L, Hkv, D] that already holds their keys and
     values. ``ring``: the cache is a ring under ``window`` — position p at
@@ -573,8 +692,12 @@ def cached_attention(q, k_cache, v_cache, q_offset, *, impl: str = "auto",
     prefill, a mesh, the CPU — is :func:`attention_reference` as before, a
     ring's ``key_positions`` from :func:`ring_key_positions`. ``impl``
     ``"ragged"`` (``"ragged+interpret"`` on the CPU) asks for the kernels by
-    name wherever they can run at all; any other name leaves the choice here."""
-    (b, qlen, hq, d), (cache_len, hkv) = q.shape, k_cache.shape[1:3]
+    name wherever they can run at all; any other name leaves the choice here.
+    The caches may be ``flat`` (``[B, L, Hkv * D]``, :func:`decode_attention`),
+    the values of a width of their own, and ``sinks`` [H] a sink logit a query
+    head: every form takes all three."""
+    (b, qlen, hq, d), cache_len = q.shape, k_cache.shape[1]
+    hkv = k_cache.shape[2] if k_cache.ndim == 4 else k_cache.shape[2] // d
     if ring and (qlen != 1 or not window):  # static: fails clearly at trace time
         raise ValueError(f"a ring cache decodes one token a step under a window "
                          f"(got {qlen} under {window})")
@@ -588,21 +711,123 @@ def cached_attention(q, k_cache, v_cache, q_offset, *, impl: str = "auto",
     interpret = impl.partition("+")[2] == "interpret"
     if kernel == "ring":
         return ring_decode_attention(q, k_cache, v_cache, q_offset, window, scale,
-                                     interpret=interpret)
+                                     interpret=interpret, sinks=sinks)
     if kernel == "ragged":
         calls = getattr(_ragged_calls, "calls", None)
         if calls is not None:
             calls.append((block, cache_len))
         return decode_attention(q, k_cache, v_cache, q_offset + 1, scale, block=block,
-                                interpret=interpret)
+                                interpret=interpret, sinks=sinks)
     key_positions = None
     if ring:
         key_positions = ring_key_positions(
             jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (b,)), cache_len)
     t = lambda x: x.transpose(0, 2, 1, 3)
+    if k_cache.ndim == 3:  # a position's heads side by side: the same numbers, by head
+        k_cache = k_cache.reshape(b, cache_len, hkv, d)
+        v_cache = v_cache.reshape(b, cache_len, hkv, -1)
     return t(attention_reference(
         t(q), t(k_cache), t(v_cache), causal=True, q_offset=q_offset, scale=scale,
-        logit_softcap=logit_softcap, window=window, key_positions=key_positions))
+        logit_softcap=logit_softcap, window=window, key_positions=key_positions, sinks=sinks))
+
+
+# keys a step of :func:`blocked_attention` holds: 64 query heads of a piece of
+# 2,048 positions against 512 keys are 268 MB of float32 logits
+BLOCKED_KEYS = 512
+
+
+def blocked_attention(q, k, v, q_offset, *, window: int = 0, sinks=None,
+                      key_positions=None, scale: float | None = None,
+                      block_k: int = BLOCKED_KEYS):
+    """Causal attention of a BLOCK of queries q [B, S, H, D] (positions
+    ``q_offset`` onwards; a scalar or [B]) against keys and values that hold
+    their own already — k [B, K, Hkv, D], v [B, K, Hkv, Dv] or ``flat`` — a key
+    block at a time under an online softmax, so that a prompt piece of 2,048
+    positions over a cache of 32,768 never holds ``[H, S, K]`` logits (17 GB
+    in float32): what :func:`attention_reference` computes, in ``jax.numpy``.
+    Key index is position unless ``key_positions`` [B, K] says otherwise (a
+    negative entry holds nothing); then every block is visited, else only
+    those that hold a key some query sees (below the last query, inside the
+    first one's ``window``). ``sinks`` [H]: the softmax's starting state.
+    Returns [B, S, H, Dv]."""
+    b, qlen, hq, d = q.shape
+    n_keys = k.shape[1]
+    hkv = k.shape[2] if k.ndim == 4 else k.shape[2] // d
+    dv = v.shape[-1] if v.ndim == 4 else v.shape[2] // hkv
+    group = hq // hkv
+    bk = min(block_k, -(-n_keys // FLASH_ROW_TILE) * FLASH_ROW_TILE)
+    padded = -(-n_keys // bk) * bk
+    if padded != n_keys:
+        pad = lambda x: jnp.pad(x, ((0, 0), (0, padded - n_keys)) + ((0, 0),) * (x.ndim - 2))
+        k, v = pad(k), pad(v)
+    sm_scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    offset = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (b,))
+    qpos = (offset[:, None] + jnp.arange(qlen)[None, :])[:, None, None, :, None]  # [B,1,1,S,1]
+    q5 = q.reshape(b, qlen, hkv, group, d).transpose(0, 2, 3, 1, 4)  # [B, Hkv, G, S, D]
+    n_blocks, first = padded // bk, 0
+    if key_positions is None:
+        n_blocks = jnp.minimum(n_blocks, (jnp.max(offset) + qlen + bk - 1) // bk)
+        if window > 0:
+            first = jnp.maximum(0, (jnp.min(offset) - window + 1) // bk)
+    elif padded != n_keys:
+        key_positions = jnp.pad(key_positions, ((0, 0), (0, padded - n_keys)),
+                                constant_values=-1)
+
+    def fold(i, carry):
+        acc, m_prev, l_prev = carry
+        k_blk = jax.lax.dynamic_slice_in_dim(k, i * bk, bk, axis=1).reshape(b, bk, hkv, d)
+        v_blk = jax.lax.dynamic_slice_in_dim(v, i * bk, bk, axis=1).reshape(b, bk, hkv, dv)
+        s = jnp.einsum("bhgsd,bjhd->bhgsj", q5, k_blk,
+                       preferred_element_type=jnp.float32) * sm_scale
+        if key_positions is None:
+            index = i * bk + jnp.arange(bk)
+            kpos = jnp.where(index < n_keys, index, -1)[None, None, None, None, :]
+        else:
+            kpos = jax.lax.dynamic_slice_in_dim(key_positions, i * bk, bk, axis=1)[
+                :, None, None, None, :]
+        visible = (kpos <= qpos) & (kpos >= 0)
+        if window > 0:
+            visible = visible & (kpos > qpos - window)
+        s = jnp.where(visible, s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        alpha = jnp.exp(m_prev - m_new)
+        # a query may see nothing of a block: exp(NEG_INF - NEG_INF) = 1 otherwise
+        p = jnp.where(visible, jnp.exp(s - m_new[..., None]), 0.0)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bhgsj,bjhd->bhgsd", p.astype(v.dtype), v_blk, preferred_element_type=jnp.float32)
+        return acc, m_new, l_prev * alpha + jnp.sum(p, axis=-1)
+
+    stat = (b, hkv, group, qlen)
+    if sinks is None:
+        m0, l0 = jnp.full(stat, NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32)
+    else:
+        m0 = jnp.broadcast_to(sinks.astype(jnp.float32).reshape(1, hkv, group, 1), stat)
+        l0 = jnp.ones(stat, jnp.float32)
+    acc, _m, l = jax.lax.fori_loop(
+        first, n_blocks, fold, (jnp.zeros(stat + (dv,), jnp.float32), m0, l0))
+    out = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, qlen, hq, dv)
+
+
+def ring_context_attention(q, k_ctx, v_ctx, k, v, start, q_offset, window: int, *,
+                           sinks=None, scale: float | None = None):
+    """A prompt piece over a window layer whose cache is a ring: q [B, S, H, D]
+    at positions ``q_offset`` onwards, its own keys and values k / v [B, S, ...],
+    and the slot's last R positions k_ctx / v_ctx [B, R, ...] IN POSITION ORDER
+    from ``start`` on (``q_offset - R``: dl/kv_layout.LayerKindKV.view unrolls
+    the ring; a negative position holds nothing). Attends ``[context ++ piece]``
+    under the window, the causal mask and the sinks
+    (:func:`blocked_attention`), and returns (out [B, S, H, Dv], the last R
+    positions of the two together, in order from ``q_offset + S - R`` on):
+    what the ring keeps of them. Leaves of either form, ``flat`` or by head."""
+    b, r, s = q.shape[0], k_ctx.shape[1], k.shape[1]
+    keys, values = jnp.concatenate([k_ctx, k], axis=1), jnp.concatenate([v_ctx, v], axis=1)
+    first = lambda at, n: jnp.broadcast_to(  # noqa: E731
+        jnp.asarray(at, jnp.int32), (b,))[:, None] + jnp.arange(n)[None, :]
+    out = blocked_attention(
+        q, keys, values, q_offset, window=window, sinks=sinks, scale=scale,
+        key_positions=jnp.concatenate([first(start, r), first(q_offset, s)], axis=1))
+    return out, (keys[:, s:], values[:, s:])
 
 
 def note_choice(impl: str, sq: int, sk: int, mesh: Mesh | None = None,

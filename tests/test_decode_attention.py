@@ -318,7 +318,8 @@ def test_benchmark_json_ends_with_the_two_read_shares():
         # PR 34 put them at the end, after the forty-three that were there; what
         # later PRs append follows them (a count from the end would break with each)
         last = json.load(f)["per_layer"][43:45]
-    assert {m["name"]: m["workloads"] for m in last} == {n: [c] for n, c in CELLS.items()}
+    # a later cell whose pod has the counters is appended to a metric's list (PR 54)
+    assert {m["name"]: m["workloads"][0] for m in last} == dict(CELLS)
     for m in last:
         assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"]) == (
             "ratio", "lower", "program_counter", "Kernels / model step", "tokens_per_s")
@@ -346,6 +347,154 @@ def test_the_ring_kernel_share_is_the_layouts_two_counters_growth():
 def test_benchmark_json_ends_with_the_ring_kernel_share():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         last = json.load(f)["per_layer"][104]  # PR 48 put it after the 104 that were there
-    assert last == {"name": "attn.ring_kernel_share.reason", "unit": "ratio", "better": "higher",
-                    "source": "program_counter", "layer": "Kernels / model step",
-                    "moves": "tokens_per_s", "workloads": ["laguna-s-2.1-ep2-d5.reason"]}
+    assert last["workloads"][0] == "laguna-s-2.1-ep2-d5.reason"  # later rings are appended
+    assert dict(last, workloads=None) == {
+        "name": "attn.ring_kernel_share.reason", "unit": "ratio", "better": "higher",
+        "source": "program_counter", "layer": "Kernels / model step",
+        "moves": "tokens_per_s", "workloads": None}
+
+
+# -- keys wider than values, sinks, and lines of a position's heads side by side ----------
+# (MiMo-V2-Flash: keys of 192 over values of 128, a sink a query head on the window layers)
+
+
+def _wide(rows, group, cache_len, d=24, dv=16, hkv=2, dtype=jnp.float32, seed=0):
+    rng = np.random.RandomState(seed + rows + group)
+    q = jnp.asarray(rng.randn(rows, 1, hkv * group, d), dtype)
+    k = jnp.asarray(rng.randn(rows, cache_len, hkv, d), dtype)
+    v = jnp.asarray(rng.randn(rows, cache_len, hkv, dv), dtype)
+    sinks = jnp.asarray(rng.randn(hkv * group), jnp.float32)
+    return q, k, v, sinks
+
+
+def _flat(x):
+    return x.reshape(*x.shape[:2], -1)
+
+
+FORMS = {"by_head": lambda k, v: (k, v), "flat": lambda k, v: (_flat(k), _flat(v))}
+
+
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("sunk", [False, True])
+@pytest.mark.parametrize("group", [4, 9])
+def test_the_ragged_kernel_takes_wider_keys_sinks_and_flat_lines(group, sunk, form):
+    """``dv != d``, with sinks and without, over ``[B, L, Hkv, D]`` leaves and
+    over ``[B, L, Hkv * D]`` ones (each query in its own KV head's lanes): rows
+    inside a block, on its edge, at the cache's end, one idle at offset 0."""
+    q, k, v, sinks = _wide(5, group, L)
+    sinks = sinks if sunk else None
+    offsets = jnp.asarray([0, 37, BLOCK - 1, 2 * BLOCK, L - 1], jnp.int32)
+    got = attn.decode_attention(q, *FORMS[form](k, v), offsets + 1, block=BLOCK,
+                                interpret=True, sinks=sinks)
+    assert got.shape == (5, 1, 2 * group, 16)
+    want = reference(q, k, v, offsets, sinks=sinks)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("sunk", [False, True])
+def test_the_ring_kernel_takes_wider_keys_sinks_and_flat_lines(sunk, form):
+    """Rings not yet full, full and overwritten several times; a sink is the
+    softmax's starting state, so a ring that holds one position gives that
+    position's value times ``e^s / (e^s + e^sink)``."""
+    offsets = jnp.asarray([0, 5, RING - 1, RING + 3, 7 * RING + 11], jnp.int32)
+    q, k, v, sinks = _wide(len(offsets), 4, RING)
+    sinks = sinks if sunk else None
+    got = attn.ring_decode_attention(q, *FORMS[form](k, v), offsets, WINDOW, interpret=True,
+                                     sinks=sinks)
+    want = ring_reference(q, k, v, offsets, sinks=sinks)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    if sunk:  # row 0 sees its own position only
+        s = jnp.einsum("hd,hd->h", q[0, 0].reshape(2, 4, -1).reshape(8, -1),
+                       jnp.repeat(k[0, 0], 4, axis=0)) / np.sqrt(24)
+        share = jnp.exp(s) / (jnp.exp(s) + jnp.exp(sinks))
+        np.testing.assert_allclose(got[0, 0], jnp.repeat(v[0, 0], 4, axis=0) * share[:, None],
+                                   rtol=2e-5, atol=2e-5)
+
+
+def test_sinks_and_wider_keys_in_bf16_keep_f32_statistics():
+    q, k, v, sinks = _wide(3, 8, L, d=192, dv=128, hkv=2, dtype=jnp.bfloat16)
+    offsets = jnp.asarray([3, 40, L - 1], jnp.int32)
+    got = attn.decode_attention(q, _flat(k), _flat(v), offsets + 1, block=BLOCK, interpret=True,
+                                sinks=sinks)
+    assert got.dtype == jnp.bfloat16 and got.shape == (3, 1, 16, 128)
+    want = reference(q, k, v, offsets, sinks=sinks)
+    np.testing.assert_allclose(got.astype(jnp.float32), want.astype(jnp.float32), atol=3e-2)
+
+
+@pytest.mark.parametrize("window", [0, WINDOW])
+@pytest.mark.parametrize("form", FORMS)
+def test_a_block_of_queries_a_key_block_at_a_time_is_the_reference(form, window):
+    """``blocked_attention``: a prompt piece over a cache that holds it, rows
+    at their own offsets, keys 16 at a time — the blocks past the last query
+    and below the first one's window are not visited."""
+    rng = np.random.RandomState(3)
+    _, k, v, sinks = _wide(3, 4, L)
+    q = jnp.asarray(rng.randn(3, 16, 8, 24), jnp.float32)
+    offsets = jnp.asarray([0, 20, L - 16], jnp.int32)
+    for sk in (None, sinks):
+        got = attn.blocked_attention(q, *FORMS[form](k, v), offsets, window=window, sinks=sk,
+                                     block_k=16)
+        want = reference(q, k, v, offsets, window=window, sinks=sk)
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("filled", [0, 16, 48, 200])
+def test_a_piece_over_an_unrolled_ring_is_the_window_over_the_whole_sequence(filled):
+    """``ring_context_attention``: the slot's last RING positions in position
+    order from ``filled - RING`` on (negative: nothing) and the piece's own
+    give what the window gives over the whole sequence, and the last RING of
+    the two come back."""
+    piece, total = 16, 216
+    rng = np.random.RandomState(filled)
+    _, k, v, sinks = _wide(1, 4, total)
+    q = jnp.asarray(rng.randn(1, piece, 8, 24), jnp.float32)
+    want = reference(q, k[:, :filled + piece], v[:, :filled + piece], filled, window=WINDOW,
+                     sinks=sinks)
+    at = np.arange(filled - RING, filled)
+    held = lambda x: jnp.where((at >= 0)[None, :, None, None], x[:, np.maximum(at, 0)], 7.0)
+    new = lambda x: x[:, filled: filled + piece]
+    got, (ck, cv) = attn.ring_context_attention(
+        q, _flat(held(k)), _flat(held(v)), _flat(new(k)), _flat(new(v)), filled - RING, filled,
+        WINDOW, sinks=sinks)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    last = np.arange(filled + piece - RING, filled + piece)
+    np.testing.assert_array_equal(np.asarray(ck)[0, last >= 0], np.asarray(_flat(k))[0, last[last >= 0]])
+    np.testing.assert_array_equal(np.asarray(cv)[0, last >= 0], np.asarray(_flat(v))[0, last[last >= 0]])
+
+
+def test_the_flash_kernel_takes_wider_keys_and_a_sink_a_head():
+    rng = np.random.RandomState(5)
+    q = jnp.asarray(rng.randn(2, 8, 40, 24), jnp.float32)
+    k = jnp.asarray(rng.randn(2, 2, 40, 24), jnp.float32)
+    v = jnp.asarray(rng.randn(2, 2, 40, 16), jnp.float32)
+    sinks = jnp.asarray(rng.randn(8), jnp.float32)
+    for sk in (None, sinks):
+        got = attn.flash_attention(q, k, v, causal=True, window=WINDOW, interpret=True, sinks=sk)
+        want = attn.attention_reference(q, k, v, causal=True, window=WINDOW, sinks=sk)
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def test_without_sinks_and_with_one_width_the_kernels_trace_as_they_did():
+    """The existing families' programs must not move: ``sinks=None`` over
+    ``[B, L, Hkv, D]`` leaves of one width adds no operand and no operation."""
+    q, k, v = _shapes(group=6)
+    plain = str(jax.make_jaxpr(lambda q, k, v, n: attn.decode_attention(
+        q, k, v, n, interpret=True))(q, k, v, OFFSETS))
+    told = str(jax.make_jaxpr(lambda q, k, v, n: attn.decode_attention(
+        q, k, v, n, interpret=True, sinks=None))(q, k, v, OFFSETS))
+    assert plain == told
+    sunk = str(jax.make_jaxpr(lambda q, k, v, n, s: attn.decode_attention(
+        q, k, v, n, interpret=True, sinks=s))(q, k, v, OFFSETS, jax.ShapeDtypeStruct((48,), jnp.float32)))
+    assert sunk != plain
+
+
+@pytest.mark.parametrize("shape,told,block", [
+    ((32, 32768, 768), {}, 512), ((32, 32768, 512), {}, 512), ((32, 144, 1536), {"ring": True}, 144),
+    ((32, 144, 1024), {"ring": True}, 144), ((32, 32768, 96), {}, 0), ((32, 144, 96), {"ring": True}, 0),
+    ((32, 512, 768), {}, 256), ((32, 128, 768), {}, 0), ((32, 128, 768), {"impl": "ragged"}, 64)])
+def test_a_flat_leafs_rule_asks_whole_lane_tiles_of_its_line(on_a_tpu, shape, told, block):
+    """``decode_block`` of ``[B, L, W]``: MiMo-V2-Flash's four leaf shapes take
+    the kernels (blocks of 512 positions; a ring whole), a line that is not
+    whole 128-lane tiles does not, a short cache only by name."""
+    assert attn.decode_block(shape, 2, **told) == block

@@ -649,7 +649,7 @@ def tiny_family(family: str):
     name = {"llama": "LlamaConfig", "mixtral": "MixtralConfig", "laguna": "LagunaConfig",
             "minicpm_sala": "SalaConfig", "deepseek_v2": "DeepseekV2Config",
             "nemotron_h": "NemotronHConfig", "phi3": "LlamaConfig", "gemma2": "Gemma2Config",
-            "gpt2": "GPT2Config"}[family]
+            "gpt2": "GPT2Config", "mimo_v2": "MimoV2Config"}[family]
     # phi3 is llama's decoder under fused weights (its config is llama's, which its
     # module names too); gpt2's tiny preset has one vocabulary
     return module, getattr(module, name).tiny(**({} if family == "gpt2" else {"vocab_size": 64}))
@@ -671,7 +671,8 @@ def lowered_programs(family: str) -> dict:
         family=FAMILIES[module.__name__.rpartition(".")[2]], cfg=cfg,
         mesh=make_mesh("dp=1", jax.devices()[:1]),
         params=params, max_seq_len=64, stats={})
-    pieces = {"prefill_chunk": 16} if family != "laguna" else {}  # a ring takes no piece
+    # laguna's two hashes were taken without a piece program (a ring took none before PR 54)
+    pieces = {"prefill_chunk": 16} if family != "laguna" else {}
     engine = ContinuousBatcher(server, max_slots=4, chunk_size=4, max_len=64, allocate=False,
                                supervise=False, **pieces)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731

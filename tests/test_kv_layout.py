@@ -407,6 +407,50 @@ class TestLayerKinds:
             kv_layout.LayerKindKV.refuse("laguna", page_size=16, prefix_cache=None,
                                          prefill_chunk=0, speculative_k=2)
 
+    @pytest.mark.parametrize("asked,what", [
+        ({"page_size": 16}, "--kv-page-size.*a ring is not paged"),
+        ({"prefix_cache": object()}, "--prefix-cache.*a ring cannot give back a prefix"),
+        ({"speculative_k": 2}, "--speculative-k.*several ring positions"),
+    ])
+    def test_a_ring_still_refuses_pages_prefix_cache_and_speculation_by_name(self, asked, what):
+        base = dict(page_size=0, prefix_cache=None, prefill_chunk=16, speculative_k=0)
+        with pytest.raises(kv_layout.Refused, match=what):
+            kv_layout.LayerKindKV.refuse("laguna", ("full", "window"), **{**base, **asked})
+        kv_layout.LayerKindKV.refuse("laguna", ("full", "window"), **base)  # chunked prefill: carried
+
+    @pytest.mark.parametrize("filled", [0, 16, 48, 80])
+    def test_a_piece_sees_a_ring_unrolled_and_its_last_positions_roll_back_in(self, kv, filled):
+        """``view`` hands a window leaf over as the slot's last ``ring``
+        positions in position order from ``filled - ring`` on, a full leaf's
+        front as it is; ``put_piece`` takes the last ``ring`` positions up to
+        the piece's end and puts position p at ``p mod ring``."""
+        kv, cfg = kv
+        ring, piece = self.RING, 16
+        state = kv.new_state()
+        position = lambda leaf: jnp.broadcast_to(  # each ring index holds its position's number
+            (filled - 1 - (filled - 1 - jnp.arange(ring)) % ring).astype(leaf.dtype)[
+                None, :, None, None], leaf.shape)
+        state = {name: position(leaf) if kv.kinds[name] == "window" else leaf
+                 for name, leaf in state.items()}
+        where = kv.at(2, filled, piece)
+        assert kv.slot_of(where) == 2 and kv.stats["kv_ring_pieces"] >= 1
+        row = jax.jit(lambda c, w: kv.view(c, w, MAX_LEN))(state, where)
+        assert int(row["ring_start"]) == filled - ring
+        assert row["k0"].shape[:2] == (1, MAX_LEN) and row["k1"].shape[:2] == (1, ring)
+        held = np.asarray(row["k1"][0, :, 0, 0])
+        want = filled - ring + np.arange(ring)
+        np.testing.assert_array_equal(held[want >= 0], want[want >= 0])
+        # the forward hands back the last ``ring`` positions up to the piece's end
+        end = filled + piece
+        back = {name: jnp.broadcast_to((end - ring + jnp.arange(ring)).astype(leaf.dtype)[
+            None, :, None, None], leaf.shape) if kv.kinds[name] == "window" else leaf
+            for name, leaf in row.items() if name != "ring_start"}
+        out = jax.jit(kv.put_piece)(state, back, where)
+        ringed = np.asarray(out["k1"][2, :, 0, 0])
+        for p in range(max(0, end - ring), end):
+            assert ringed[p % ring] == p
+        np.testing.assert_array_equal(out["k1"][1], state["k1"][1])  # the other slots' stand
+
 
 class TestStateAndIndexLeaves:
     """``LayerKindKV`` over a family with states and an index and no ring

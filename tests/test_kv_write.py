@@ -62,6 +62,22 @@ def test_the_kernel_writes_what_the_scatter_writes_bit_for_bit(rows, length, dty
     assert got.dtype == cache.dtype and bool(jnp.array_equal(got, scatter(cache, new, index)))
 
 
+@pytest.mark.parametrize("kind", ["edges", "equal", "spread"])
+@pytest.mark.parametrize("rows,length,width,dtype", [
+    (32, 2048, 768, "bfloat16"), (32, 144, 1536, "bfloat16"), (8, 144, 1024, "float32"),
+    (8, 64, 512, "bfloat16")])
+def test_the_line_kernel_writes_what_the_scatter_writes_bit_for_bit(rows, length, width, dtype, kind):
+    """A leaf that keeps a position in ONE line (MiMo-V2-Flash's four leaf
+    shapes, cut in length): the row's group of 16 positions in, the line
+    replaced, the group out — the scatter's result, its clamp included."""
+    keys = jax.random.split(jax.random.PRNGKey(width), 2)
+    cache = jax.random.normal(keys[0], (rows, length, width), jnp.float32).astype(dtype)
+    new = jax.random.normal(keys[1], (rows, 1, width), jnp.float32).astype(dtype)
+    index = jnp.asarray(indices(kind, rows, length))
+    got = kv_write.write_rows_kernel(cache, new, index, interpret=True)
+    assert got.dtype == cache.dtype and bool(jnp.array_equal(got, scatter(cache, new, index)))
+
+
 def test_the_kernel_inside_a_scan_whose_carry_is_the_donated_cache():
     """As the chunk program holds it: the cache is the scan's carry, donated,
     each step writes every row's next position and reads the line back."""
@@ -93,6 +109,11 @@ def test_the_rule_picks_the_kernel_for_the_two_cells_leaves_on_one_tpu_device(mo
         assert kv_write.lowering((rows, length, 8, 128), (rows, 1, 8, 128), 1) == "kernel"
     one = make_mesh("dp=1", jax.devices()[:1])
     assert kv_write.lowering((64, 4096, 8, 128), (64, 1, 8, 128), 1, one) == "kernel"
+    # lines a position (PR 54): whole lane tiles, 512 lanes at least, whole groups of 16
+    for shape in ((32, 32768, 768), (32, 32768, 512), (32, 144, 1536), (32, 144, 1024)):
+        assert kv_write.lowering(shape, (32, 1, shape[2]), 1) == "kernel"
+    for shape in ((32, 32768, 256), (32, 32768, 576), (32, 150, 1024)):
+        assert kv_write.lowering(shape, (32, 1, shape[2]), 1) == "scatter"
 
 
 NOT_THE_KERNEL = {

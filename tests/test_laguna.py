@@ -358,7 +358,7 @@ def test_the_chunk_programs_name_carries_its_depth(engine):
 @pytest.mark.parametrize("option,message", [
     ({"page_size": 16}, "--kv-page-size"),
     ({"prefix_cache": object()}, "--prefix-cache"),
-    ({"prefill_chunk": 32}, "--prefill-chunk"),
+    ({"prefix_cache": object(), "prefill_chunk": 32}, "--prefix-cache"),
     ({"speculative_k": 4}, "--speculative-k"),
 ])
 def test_an_engine_option_the_layout_cannot_serve_is_refused_with_its_name(half, option, message):
@@ -366,9 +366,37 @@ def test_an_engine_option_the_layout_cannot_serve_is_refused_with_its_name(half,
         ContinuousBatcher(half[0], max_slots=SLOTS, chunk_size=4, allocate=False, **option)
 
 
+@pytest.fixture(scope="module")
+def piece_engine(half):
+    cb = ContinuousBatcher(half[0], max_slots=SLOTS, chunk_size=4, prefill_chunk=16)
+    yield cb
+    cb.close()
+
+
+@pytest.mark.parametrize("prompt_len,new", [(17, 40), (40, 70), (70, 40), (100, 8)])
+def test_a_prompt_landed_in_pieces_over_the_rings_follows_the_reference(
+        half, engine, piece_engine, prompt_len, new):
+    """``--prefill-chunk 16`` over window 16, ring 32: a piece sees its slot's
+    ring unrolled, attends it and itself, and hands back the last 32 positions
+    (dl/kv_layout.LayerKindKV.view / put_piece) — two to seven pieces, the last
+    one padded, prompts past the ring's wrap. Every token is the reference's
+    argmax and the one the same prompt gives landed in one piece."""
+    _, hf, raw, _ = half
+    prompt = np.random.default_rng(prompt_len).integers(1, VOCAB, (1, prompt_len))
+    before = piece_engine.snapshot().get("kv_ring_pieces", 0)
+    out = np.asarray(piece_engine.generate(prompt, max_new_tokens=new))[0][-new:]
+    assert piece_engine.snapshot()["kv_ring_pieces"] - before == -(-prompt_len // 16)
+    whole = np.asarray(engine.generate(prompt, max_new_tokens=new))[0][-new:]
+    np.testing.assert_array_equal(out, whole)
+    seq = np.concatenate([prompt[0], out])
+    logits = ref_logits(hf, raw, seq, positions=list(range(prompt_len - 1, len(seq) - 1)))
+    below = logits.max(-1) - logits[np.arange(new), out]
+    assert below.max() < 1e-3, (int(below.argmax()), float(below.max()))
+
+
 @pytest.mark.parametrize("flags,message", [
     ({"kv_page_size": 16}, "--kv-page-size"),
-    ({"prefill_chunk": 32}, "--prefill-chunk"),
+    ({"kv_page_size": 16, "prefill_chunk": 32}, "--kv-page-size"),
 ])
 def test_a_refused_option_ends_the_load_of_a_continuous_pod(half, flags, message):
     """At start-up, not at the first request: ``engine_at_load`` lets every

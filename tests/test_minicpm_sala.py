@@ -520,9 +520,11 @@ def test_the_refusals_name_the_leaf_kind_that_is_the_reason():
     with pytest.raises(kv_layout.Refused) as ring:
         kv_layout.LayerKindKV.refuse("laguna", ("full", "window", "counter"), page_size=16,
                                      prefix_cache=None, prefill_chunk=32, speculative_k=0)
-    assert "--prefill-chunk" in str(ring.value) and "'window' leaves" in str(ring.value)
+    assert "--kv-page-size" in str(ring.value) and "'window' leaves" in str(ring.value)
     assert "'state'" not in str(ring.value)
-    # a layout with states and no ring carries chunked prefill
+    # chunked prefill is carried by every leaf kind, a ring too since PR 54
+    assert "--prefill-chunk" not in str(ring.value)
+    kv_layout.LayerKindKV.refuse("laguna", ("full", "window", "counter"), prefill_chunk=32)
     kv_layout.LayerKindKV.refuse("minicpm_sala", ("full", "index", "state"), prefill_chunk=32)
 
 

@@ -459,7 +459,7 @@ def test_every_family_is_its_module_behind_the_adapter(name):
     # config.json is the source where shapes cannot say, and a checkpoint
     # without one is refused in words that name the family
     assert (family.config_from_sidecar is not None) == (name in (
-        "laguna", "minicpm_sala", "deepseek_v2", "nemotron_h"))
+        "laguna", "minicpm_sala", "deepseek_v2", "nemotron_h", "mimo_v2"))
     if family.config_from_sidecar is not None:
         assert callable(module.config_from_hf)
         with pytest.raises(ValueError, match=f"{name} checkpoint.*config.json must lie beside"):

@@ -540,3 +540,43 @@ def test_a_tiny_engine_gives_the_same_greedy_tokens_with_either_lowering(
     np.testing.assert_array_equal(got, want)
     assert stats["experts_read"] == stats["experts_hit"] == plain["experts_hit"]
     assert stats["assignments_held"] == plain["assignments_held"]
+
+
+# -- MiMo-V2-Flash's layer: sigmoid scores, a choice bias, no groups, no shared expert ---------
+
+
+@pytest.fixture(scope="module")
+def mimo_layer():
+    """One expert layer of a 256-expert router at small widths (top-8, as
+    published), its input, and the reference's uncut answer."""
+    from modelx_tpu.models import mimo_v2, mimo_v2_reference
+
+    cfg = mimo_v2.MimoV2Config.tiny(vocab_size=64, num_experts=256, expert_count=256, top_k=8,
+                                    hidden_size=32, moe_intermediate_size=16)
+    params = mimo_v2.init_params(cfg, jax.random.PRNGKey(5))
+    raw = mimo_v2.to_hf_config(cfg)
+    m = jax.random.normal(jax.random.PRNGKey(6), (2, 9, cfg.hidden_size), jnp.float32)
+    w = mimo_v2_reference.Weights(mimo_v2.to_hf_state_dict(params))
+    with jax.default_matmul_precision("highest"):
+        whole = mimo_v2_reference.routed_experts(w, P, raw, m.reshape(-1, cfg.hidden_size))
+    return cfg, params, m, np.asarray(whole).reshape(m.shape)
+
+
+def test_the_sixteen_shares_of_a_256_expert_layer_add_up_to_the_uncut_reference(mimo_layer):
+    """One chip of sixteen holds 16 experts of 256 under the router's 256
+    outputs and its choice bias: the sixteen chips' parts are the whole layer
+    (no shared expert to count once), and the held assignments add up to all."""
+    cfg, params, m, whole = mimo_layer
+    total, held = 0.0, 0
+    for chip in range(16):
+        sl = slice(16 * chip, 16 * chip + 16)
+        part, counts = moe.moe_share_ffn(
+            m, params[P + "mlp.gate.weight"], params[P + "mlp.experts.gate_proj.weight"][sl],
+            params[P + "mlp.experts.up_proj.weight"][sl],
+            params[P + "mlp.experts.down_proj.weight"][sl], top_k=cfg.top_k,
+            held=(16 * chip, 16), renormalize=True, routed_scale=1.0, scoring="sigmoid",
+            choice_bias=params[P + "mlp.gate.e_score_correction_bias"])
+        total, held = total + part, held + int(counts[1])
+        assert int(counts[0]) == 18 * 8
+    np.testing.assert_allclose(np.asarray(total), whole, atol=2e-5, rtol=1e-5)
+    assert held == 18 * 8  # every assignment lands on exactly one chip

@@ -110,6 +110,16 @@ FAMILY_SPEC_CASES = {
         ("model.layers.1.mlp.shared_expert.up_proj.weight", PartitionSpec("tp", None)),
         ("model.layers.0.mlp.down_proj.weight", PartitionSpec(None, "tp")),  # the dense layer
     ],
+    "mimo_v2": [
+        ("model.layers.1.self_attn.k_proj.weight", PartitionSpec("tp", None)),  # keys of 192 by head
+        ("model.layers.1.self_attn.o_proj.weight", PartitionSpec(None, "tp")),
+        ("model.layers.1.self_attn.attention_sink_bias", PartitionSpec("tp")),  # with its heads
+        ("model.layers.1.mlp.gate.weight", PartitionSpec(None, None)),  # the router, whole
+        ("model.layers.1.mlp.gate.e_score_correction_bias", PartitionSpec(None)),
+        ("model.layers.1.mlp.experts.up_proj.weight", PartitionSpec(None, "tp", None)),  # no ep axis here
+        ("model.layers.1.mlp.experts.down_proj.weight", PartitionSpec(None, None, "tp")),
+        ("model.layers.0.mlp.down_proj.weight", PartitionSpec(None, "tp")),  # the dense layer
+    ],
     "minicpm_sala": [
         ("model.layers.9.self_attn.o_gate.weight", PartitionSpec("tp", None)),  # with the heads
         ("model.layers.9.self_attn.o_proj.weight", PartitionSpec(None, "tp")),

@@ -784,3 +784,106 @@ def test_deepseek_v32s_piece_program_selects_a_query_tile_at_a_time(topo, monkey
     assert not whole, whole
     assert not [line for line in text.splitlines()
                 if re.search(r" sort\(", line) and "32768" in line and "dsa." in line]
+
+
+# -- mimo_v2: lines of a position's heads, sinks, pieces over rings ------------------
+
+
+def test_the_decode_kernels_at_mimo_v2s_widths(topo):
+    """Mosaic takes both decode kernels at the ``.longcode`` cell's leaves —
+    lines a position, not whole 128-lane heads: 64 query heads over a full
+    layer's 768-lane keys and 512-lane values in blocks of 512 positions, and
+    over a window layer's ring of 144 positions of 1,536 and 1,024 lanes with
+    the sinks as the softmax's starting state — and the leaves reach them as
+    they lie: no temporary."""
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    q, rows = sds((32, 1, 64, 192)), sds((32,), jnp.int32)
+    full = jax.jit(attn.decode_attention).lower(
+        q, sds((32, 32768, 768)), sds((32, 32768, 512)), rows).compile()
+    assert _mosaic_calls(full.as_text()) == {"ragged_decode_attention": 1}
+    ring = jax.jit(lambda q, k, v, n, s: attn.ring_decode_attention(q, k, v, n, 128, sinks=s)).lower(
+        q, sds((32, 144, 1536)), sds((32, 144, 1024)), rows, sds((64,), jnp.float32)).compile()
+    assert _mosaic_calls(ring.as_text()) == {"ring_decode_attention": 1}
+    for compiled in (full, ring):
+        assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def mimo_v2_cell(topo, monkeypatch):
+    """The ``mimo-v2-flash-ep16-d7.longcode`` cell's engine over shapes on one
+    described device, the rules steered to a TPU -> (its file, engine, params, ``sds``)."""
+    import json
+    import types
+
+    from modelx_tpu.dl.continuous import ContinuousBatcher
+    from modelx_tpu.dl.families import FAMILIES
+    from modelx_tpu.models import mimo_v2
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "mimo-v2-flash-ep16-d7.json")) as f:
+        raw = json.load(f)
+    cfg = mimo_v2.config_from_hf(raw)
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    params = {k: sds(v, jnp.bfloat16) for k, v in mimo_v2.param_shapes(cfg).items()}
+    server = types.SimpleNamespace(
+        family=FAMILIES["mimo_v2"], cfg=cfg, mesh=make_mesh("dp=1", [topo.devices[0]]),
+        params=params, max_seq_len=32768, stats={})
+    engine = ContinuousBatcher(server, max_slots=32, chunk_size=8, max_len=32768,
+                               prefill_chunk=2048, allocate=False, supervise=False)
+    return raw, engine, params, sds
+
+
+def test_mimo_v2s_chunk_program_reads_lines_and_rings_where_they_lie(topo, monkeypatch):
+    """The engine's OWN chunk program of the ``.longcode`` cell at its
+    published widths: the ragged kernel on the two full layers, the ring kernel
+    on the five window layers, the hit experts' kernel on the six expert
+    layers, the line-write kernel on all fourteen leaves (a line a position is
+    one row of a packed tile: a read-modify-write of its group of 16) — the one
+    ``while`` left is the scan; no full leaf is copied or transposed (2 x 2.7
+    GB a step); weights and cache are what ISSUE 54 predicted to the byte, the
+    cache leaves the program aliased to its input, temporaries of a decode
+    step stay small."""
+    raw, engine, params, sds = mimo_v2_cell(topo, monkeypatch)
+    try:
+        assert engine.kv.row_writes == (14, 14) and engine.kv.ring_reads == (5, 5)
+        state = engine.kv.abstract_state()
+        compiled = engine._chunk_prog.jit.lower(
+            params, state, sds((32, 1), jnp.int32), *engine._chunk_args(False), n_steps=8).compile()
+    finally:
+        engine.close()
+    text, m = compiled.as_text(), compiled.memory_analysis()
+    assert _mosaic_calls(text) == {"ragged_decode_attention": 2, "ring_decode_attention": 5,
+                                   "moe_hit_experts": 6, "kv_write_rows": 14}
+    assert len([line for line in text.splitlines() if " while(" in line]) == 1
+    relaid = [line.strip()[:120] for line in text.splitlines() if re.search(
+        r"bf16\[32,32768,(768|512)\][^=]* (copy|transpose|copy-start|copy-done)\(", line)]
+    assert not relaid, relaid
+    predicted = raw["bytes_predicted"]
+    assert predicted["sum"] <= m.argument_size_in_bytes < predicted["sum"] + 16384
+    assert predicted["kv_bytes"] <= m.alias_size_in_bytes < predicted["kv_bytes"] + 4096
+    assert m.temp_size_in_bytes < 64 * 2**20
+
+
+def test_mimo_v2s_piece_program_attends_a_key_block_at_a_time(topo, monkeypatch):
+    """A 2,048-token piece over the slot's 32,768 positions and its unrolled
+    rings: 64 heads' scores against 512 keys at a time (268 MB of float32), no
+    ``[64, 2048, 32768]`` scores (17 GB), neither full leaf copied whole."""
+    _, engine, params, sds = mimo_v2_cell(topo, monkeypatch)
+    try:
+        where = engine.kv.at(3, 4096, 2048)
+        assert isinstance(where, tuple) and engine.kv.stats["kv_ring_pieces"] == 1
+        compiled = jax.jit(engine._piece_impl, donate_argnums=(2,)).lower(
+            params, sds((1, 2048), jnp.int32), engine.kv.abstract_state(), sds((), jnp.int32),
+            tuple(sds((), jnp.int32) for _ in where)).compile()
+    finally:
+        engine.close()
+    text, m = compiled.as_text(), compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 2**30
+    relaid = [line.strip()[:120] for line in text.splitlines()
+              if re.search(r"= bf16\[32,32768,(768|512)\]\S* (copy|transpose)\(", line)]
+    assert not relaid, relaid
+    whole = [line.strip()[:120] for line in text.splitlines()
+             if re.search(r"\[1,4,16,2048,32768\]|\[1,64,2048,32768\]", line)]
+    assert not whole, whole
