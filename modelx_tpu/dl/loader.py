@@ -15,7 +15,16 @@ Fetch planning:
   with shard slices so each host fetches exactly its bytes once');
 - tensors sharded on inner axes or replicated fetch once per host and are
   sliced in memory (an inner-axis shard is byte-strided; one contiguous read
-  beats thousands of tiny ranged reads).
+  beats thousands of tiny ranged reads);
+- per-expert tensors of a stock HF checkpoint fold into one virtual stacked
+  tensor (`fuse_expert_tensors`), and **a fold has one destination**: a
+  shard-group takes ONE host buffer for its ``[members, ...]`` slice (pooled
+  under a plain tensor's rule) and reads each member's rows straight into
+  their place in it — no list of parts, no ``np.stack``: an expert byte is
+  written once on the host, where its put reads it. Only a member whose
+  inner dims are strided is cut from its whole tensor by a copy, as a plain
+  tensor's slice is (``LoadStats.assemble_copied_bytes`` counts the bytes
+  the host wrote twice; 0 for a clean fold).
 
 Reference parity: this replaces cmd/modelxdl's "download files into a pod
 volume, let a GPU container mmap them" with "bytes land in HBM, laid out for
@@ -162,7 +171,8 @@ class _OverlapClock:
     Four activities are counted in and out by the threads that do them:
     ``fetch`` (a ranged read), ``put`` (a device_put dispatch and its wait),
     ``assemble`` (a fetch thread between its read's end and its hand-off:
-    stacking experts, a cast, a quantise, the full-tensor fallback's slice)
+    a cast, a quantise, the full-tensor fallback's slice — a fold's members
+    are read where they are put, so stacking experts is no work of its own)
     and ``blocked`` (a fetch thread waiting for the byte budget or for a
     staging buffer). Every instant from ``t0`` to :meth:`stop` falls to
     exactly one of: a read or a put in flight (``busy``, each phase's own
@@ -588,6 +598,12 @@ class LoadStats:
     assemble_seconds: float = 0.0
     backpressure_seconds: float = 0.0
     drain_seconds: float = 0.0
+    # bytes that took a second host copy between their read and their put:
+    # an inner-strided slice cut from its whole tensor (a plain tensor's or
+    # a fold's member's), a host-side cast's or quantise's result, a small
+    # shard copied out of its pooled buffer for a pack. 0 for a load whose
+    # every byte was read where it was put from — a clean fold included
+    assemble_copied_bytes: int = 0
     # staging pool: fresh buffer allocations vs pooled reuses; allocs track
     # concurrency, not shard count (tests assert this stays bounded)
     staging_allocs: int = 0
@@ -690,6 +706,12 @@ def _transfer_packs(pack_jobs: dict) -> dict:
                 for (name, gi, _arr, _group), shard in zip(chunk, shards):
                     out.setdefault((name, gi), []).append((dev, shard))
     return out
+
+
+def _inner_whole(spec: tuple, shape: tuple) -> bool:
+    """True when a slice takes every axis after the first whole: its bytes
+    are one contiguous run of the tensor's rows."""
+    return all(s.start == 0 and s.stop == dim for s, dim in zip(spec[1:], shape[1:]))
 
 
 def _leading_axis_only(spec: PartitionSpec) -> bool:
@@ -919,36 +941,39 @@ def load_safetensors(
                     _full_events.pop(info.name, None)
                 ev.set()
 
+    def _stage(nbytes: int) -> np.ndarray | None:
+        """A pooled host buffer for a read of ``nbytes``, or None where the
+        allocator is cheaper (``staging_min_bytes``). Whoever takes one
+        releases or forfeits it, once, on every path."""
+        if not staging_min_bytes or nbytes < staging_min_bytes:
+            return None
+        with clock.during("blocked"):
+            return staging_pool.acquire(nbytes)
+
+    def _copied(nbytes: int) -> None:
+        with lock:
+            stats.assemble_copied_bytes += nbytes
+
     def _fetch_slice(
-        info: st.TensorInfo, full_spec: tuple, pool_ok: bool = True
+        info: st.TensorInfo, full_spec: tuple
     ) -> tuple[np.ndarray, int, np.ndarray | None]:
         """Fetch one tensor's slice. Contiguous row blocks (inner dims full)
         are fetched with one exact ranged read; byte-strided inner-axis
         slices fetch the whole tensor once (cached) and slice in memory.
         Returns (array, bytes_read, staging): ``staging`` is the pooled host
         buffer backing the array when one was used — the caller must release
-        it to the pool once the bytes are on device (or copied).
-        ``pool_ok=False`` skips the pool: a caller that accumulates SEVERAL
-        slices before releasing any (stacked-expert assembly) would
-        hold-and-wait against the pool's bounded occupancy — the classic
-        deadlock shape — so it allocates fresh instead."""
+        it to the pool once the bytes are on device (or copied)."""
         np_dtype = info.np_dtype()
-        inner_full = all(
-            s.start == 0 and s.stop == dim
-            for s, dim in zip(full_spec[1:], info.shape[1:])
-        )
-        if info.shape and inner_full:
+        if info.shape and _inner_whole(full_spec, info.shape):
             lead = full_spec[0]
             b0, b1 = st.row_range(info, lead.start, lead.stop)
             length = b1 - b0
-            staging = None
-            out = None
-            if pool_ok and staging_min_bytes and length >= staging_min_bytes:
-                with clock.during("blocked"):
-                    staging = staging_pool.acquire(length)
-                out = memoryview(staging)
+            staging = _stage(length)
             try:
-                raw = _fetch_bytes(data_offset + b0, length, out)
+                raw = _fetch_bytes(
+                    data_offset + b0, length,
+                    None if staging is None else memoryview(staging),
+                )
             except BaseException:
                 # a leaked buffer starves the pool's outstanding cap — the
                 # sibling fetch workers would deadlock behind a dead load
@@ -964,7 +989,28 @@ def load_safetensors(
         arr = _as_np(raw, np_dtype, info.shape)
         with clock.during("assemble"):
             sliced = np.ascontiguousarray(arr[full_spec]) if info.shape else arr.reshape(())
+        _copied(sliced.nbytes)
         return sliced, len(raw), None
+
+    def _fetch_member(info: st.TensorInfo, spec: tuple, dest: np.ndarray) -> int:
+        """Fetch one member of a fold to where the put will read it: ``dest``
+        is the bytes of its place in the group's one buffer. Its row range
+        (inner dims whole) lands there by one exact ranged read; a
+        byte-strided inner-axis slice is cut from the whole tensor (fetched
+        once, cached) by the one copy a plain tensor's slice pays too.
+        Returns the bytes read."""
+        if info.shape and _inner_whole(spec, info.shape):
+            b0, b1 = st.row_range(info, spec[0].start, spec[0].stop)
+            _fetch_bytes(data_offset + b0, b1 - b0, memoryview(dest))
+            return b1 - b0
+        raw = _cached_full_tensor(info)
+        np_dtype = info.np_dtype()
+        whole = _as_np(raw, np_dtype, info.shape)
+        with clock.during("assemble"):
+            np.copyto(_as_np(dest, np_dtype, tuple(s.stop - s.start for s in spec)),
+                      whole[spec])
+        _copied(dest.nbytes)
+        return len(raw)
 
     def fetch_group(info: st.TensorInfo, group: list):
         """Fetch one shard-group's bytes; hand the host array to the transfer
@@ -980,28 +1026,26 @@ def load_safetensors(
         # arrays pile up uncounted. The cost is the bytes this group will
         # materialize: its slice, or the whole tensor when a byte-strided
         # inner-axis slice forces a (cached) full fetch.
-        itemsize = info.np_dtype().itemsize
+        np_dtype = info.np_dtype()
+        itemsize = np_dtype.itemsize
         if dtype is not None:
             # a host-side upcast parks the POST-cast bytes; charge for those
             itemsize = max(itemsize, np.dtype(dtype).itemsize)
-        slice_bytes = itemsize * int(
-            np.prod([s.stop - s.start for s in full_spec], initial=1)
-        )
+        shape = tuple(s.stop - s.start for s in full_spec)
+        slice_bytes = itemsize * int(np.prod(shape, initial=1))
         if info.members is not None:
             # stacked expert tensor: fetched per member against
             # full_spec[1:], so the full-fetch fallback triggers only when
             # the MEMBER's inner dims (full_spec[2:]) are strided — charging
             # the whole E-stacked tensor here would serialize MoE loads
-            if all(s.start == 0 and s.stop == dim
-                   for s, dim in zip(full_spec[2:], info.shape[2:])):
+            if _inner_whole(full_spec[1:], info.shape[1:]):
                 cost = slice_bytes
             else:
                 lead = full_spec[0]
                 cost = max(slice_bytes, sum(
                     info.members[e].nbytes for e in range(lead.start, lead.stop)
                 ))
-        elif all(s.start == 0 and s.stop == dim
-                 for s, dim in zip(full_spec[1:], info.shape[1:])):
+        elif _inner_whole(full_spec, info.shape):
             cost = slice_bytes
         else:
             # strided inner-axis slice -> whole-tensor fetch, but only the
@@ -1016,21 +1060,23 @@ def load_safetensors(
         try:
             tf0 = time.monotonic()
             if info.members is not None:
-                # virtual stacked tensor: assemble the shard from the member
-                # tensors (per-expert ranges) this group owns. pool_ok=False:
-                # holding E pooled buffers at once while siblings do the
-                # same would hold-and-wait against the pool's bounded
-                # occupancy (np.stack copies anyway)
+                # virtual stacked tensor: the fold has ONE destination. The
+                # group's [members, ...] slice is one host buffer, pooled
+                # under a plain tensor's rule (one buffer a group, so no
+                # hold-and-wait against the pool's cap), and each member
+                # this group owns is read straight into its place in it —
+                # no list of parts, no second copy of every expert byte
+                nbytes = np_dtype.itemsize * int(np.prod(shape))
+                staging = _stage(nbytes)
+                buf = staging if staging is not None else np.empty(nbytes, np.uint8)
+                each = nbytes // shape[0]
                 lead = full_spec[0]
-                parts, nread = [], 0
-                for e in range(lead.start, lead.stop):
-                    part, nb, _stg = _fetch_slice(
-                        info.members[e], full_spec[1:], pool_ok=False
+                nread = 0
+                for i, e in enumerate(range(lead.start, lead.stop)):
+                    nread += _fetch_member(
+                        info.members[e], full_spec[1:], buf[i * each:(i + 1) * each]
                     )
-                    parts.append(part)
-                    nread += nb
-                with clock.during("assemble"):
-                    arr = np.stack(parts)
+                arr = _as_np(buf, np_dtype, shape)
             else:
                 arr, nread, staging = _fetch_slice(info, full_spec)
             with lock:
@@ -1040,8 +1086,7 @@ def load_safetensors(
             with clock.during("assemble"):
                 scale = None
                 if _quantized(info.name, info):
-                    inner = full_spec[1].start == 0 and full_spec[1].stop == info.shape[1]
-                    if inner:
+                    if _inner_whole(full_spec, info.shape):
                         # this group's rows are complete channels: local scales
                         # ARE the global per-channel scales — fused single-pass
                         # quantize (native when available)
@@ -1060,8 +1105,10 @@ def load_safetensors(
                             scale_full[full_spec[0].start : full_spec[0].stop]
                         )
                         arr = qt.quantize_rows(arr, scale)
+                    _copied(arr.nbytes)
                 elif dtype is not None and arr.dtype != np.dtype(dtype):
                     arr = arr.astype(dtype)
+                    _copied(arr.nbytes)
                 if staging is not None and not np.may_share_memory(arr, staging):
                     # a host-side cast/quantize copied the bytes out: the pooled
                     # buffer is free for the next fetch right now, not after the
@@ -1093,6 +1140,7 @@ def load_safetensors(
                         # packs park until load end — copy out so the pooled
                         # buffer doesn't sit hostage under a small tensor
                         arr = arr.copy()
+                        _copied(arr.nbytes)
                         staging_pool.release(staging)
                     return ("pack", arr, group)
         except BaseException:
@@ -1229,6 +1277,8 @@ def load_safetensors(
         bytes_to_device=stats.bytes_to_device,
         fetch_thread_s=round(stats.fetch_seconds, 3),
         overlap_s=round(stats.overlap_seconds, 3),
+        assemble_s=round(stats.assemble_seconds, 3),
+        assemble_copied_bytes=stats.assemble_copied_bytes,
         staging_allocs=stats.staging_allocs,
         gbps=round(stats.gbps, 3),
     )

@@ -490,6 +490,7 @@ class ModelServer:
             split = dict.fromkeys(("fetch_busy", "device_put", "overlap", "idle",
                                    "backpressure", "assemble", "drain"), 0.0)
             in_calls = 0.0
+            copied = 0  # bytes the host wrote twice on their way (LoadStats)
             trace.startup.sub("shards")
             with trace.span("shards", files=len(paths)) as shards:
                 for path in paths:
@@ -503,6 +504,7 @@ class ModelServer:
                     params.update(arrays)
                     total += stats.bytes_to_device
                     in_calls += stats.total_seconds
+                    copied += stats.assemble_copied_bytes
                     for key in split:
                         split[key] += getattr(stats, f"{key}_seconds")
             # between two files' calls nothing is read or put either
@@ -535,6 +537,7 @@ class ModelServer:
             self.stats["load_seconds"] = round(seconds, 3)
             for key, value in split.items():
                 self.stats[f"load_{key}_seconds"] = round(value, 3)
+            self.stats["load_assemble_copied_bytes"] = copied
             self.stats["load_shards_seconds"] = round(shards["duration_s"], 3)
             self.stats["load_shard_files"] = len(paths)
             self.stats["load_bytes"] = total

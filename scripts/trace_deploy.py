@@ -49,7 +49,7 @@ from benchmark.run import META_KEYS, load_json  # noqa: E402
 
 LOADER_KEYS = ("seconds", "shards_seconds", "shard_files", "fetch_busy_seconds",
                "device_put_seconds", "overlap_seconds", "assemble_seconds", "idle_seconds",
-               "backpressure_seconds", "drain_seconds", "gbps")
+               "backpressure_seconds", "drain_seconds", "assemble_copied_bytes", "gbps")
 STORE_KEYS = ("store_hits", "store_misses", "store_load_s", "store_read_s",
               "store_deserialize_s", "store_bytes_read")
 

@@ -349,6 +349,154 @@ class TestExpertFusionGate:
         assert np.asarray(arrays["model.norm.weight"]).shape == (16,)
 
 
+class TestFoldHasOneDestination:
+    """A folded expert tensor is read where it will be put (ISSUE 55): one
+    host buffer a group, each member's rows read straight into their place in
+    it. Whatever the mesh, the source or the dtype, the stacked array is
+    ``np.stack`` of the members bit for bit, and only a member whose inner
+    dims are strided — or a cast — writes a byte twice on the host."""
+
+    W1 = "model.layers.0.block_sparse_moe.experts.{e}.w1.weight"  # [ep, tp, None]
+    W2 = "model.layers.0.block_sparse_moe.experts.{e}.w2.weight"  # [ep, None, tp]
+    STACKED_W1 = "model.layers.0.block_sparse_moe.experts.w1.weight"
+    STACKED_W2 = "model.layers.0.block_sparse_moe.experts.w2.weight"
+
+    class RangeSource:
+        """What a registry-backed source looks like to the loader: no file
+        behind it, ranged reads that land in ``out`` when one is given."""
+
+        def __init__(self, path):
+            with open(path, "rb") as f:
+                self.blob = f.read()
+            self.reads_with_out = self.reads_without = 0
+
+        def read_range(self, offset, length, out=None):
+            data = self.blob[offset:offset + length]
+            if out is None:
+                self.reads_without += 1
+                return data
+            self.reads_with_out += 1
+            memoryview(out)[:] = data
+            return out
+
+        def size(self):
+            return len(self.blob)
+
+    @staticmethod
+    def members(first: int = 0, count: int = 4, dtype=np.float32) -> dict:
+        rng = np.random.RandomState(first + count)
+        out = {}
+        for e in range(first, first + count):
+            out[TestFoldHasOneDestination.W1.format(e=e)] = rng.rand(16, 8).astype(dtype)
+            out[TestFoldHasOneDestination.W2.format(e=e)] = rng.rand(8, 16).astype(dtype)
+        return out
+
+    CASES = {
+        # name: (mesh, first expert, load_safetensors arguments, bytes copied twice)
+        "whole_fold": ("dp=1", 0, {"staging_min_bytes": 1024}, 0),
+        "ep_share_on_a_mesh": ("ep=4,tp=1", 0, {"staging_min_bytes": 256}, 0),
+        # w2's last axis over tp: each member is cut from its whole tensor,
+        # once a tp group — every w2 byte is written once more, no w1 byte
+        "strided_inner_slice": ("ep=2,tp=4", 0, {"staging_min_bytes": 256}, 4 * 8 * 16 * 4),
+        "host_side_cast": ("dp=1", 0, {"staging_min_bytes": 1024, "dtype": np.float16},
+                           2 * 4 * 16 * 8 * 2),
+        "run_not_from_zero": ("ep=2,tp=1", 3, {"staging_min_bytes": 256}, 0),
+        "range_source_honouring_out": ("dp=1", 0, {"staging_min_bytes": 1024,
+                                                   "split_read_bytes": 128}, 0),
+        "below_staging_min": ("ep=2,tp=2", 0, {}, 2 * 2 * 8 * 8 * 4 * 2),
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_stacked_array_is_np_stack_of_the_members(self, tmp_path, case):
+        from modelx_tpu.dl.sharding import MIXTRAL_RULES
+
+        mesh_spec, first, kwargs, copied = self.CASES[case]
+        tensors = self.members(first)
+        path = str(tmp_path / "experts.safetensors")
+        st.write_safetensors(path, tensors)
+        source = (self.RangeSource(path) if case == "range_source_honouring_out"
+                  else LocalFileSource(path))
+        mesh = make_mesh(mesh_spec)  # a prefix of the eight virtual devices
+        arrays, stats = load_safetensors(source, mesh, MIXTRAL_RULES, pack_threshold=0, **kwargs)
+        assert sorted(arrays) == [self.STACKED_W1, self.STACKED_W2]
+        for name, member in ((self.STACKED_W1, self.W1), (self.STACKED_W2, self.W2)):
+            want = np.stack([tensors[member.format(e=e)] for e in range(first, first + 4)])
+            want = want.astype(kwargs.get("dtype", want.dtype))
+            got = arrays[name]
+            assert got.dtype == want.dtype and got.shape == want.shape
+            assert np.asarray(got).tobytes() == want.tobytes(), name
+            for shard in got.addressable_shards:  # and each device holds its own share
+                assert np.asarray(shard.data).tobytes() == want[shard.index].tobytes()
+        assert stats.assemble_copied_bytes == copied
+        assert stats.bytes_to_device == sum(
+            int(np.prod(a.shape)) * a.dtype.itemsize for a in arrays.values())
+        pooled = stats.staging_allocs + stats.staging_reuses
+        if case == "below_staging_min":
+            assert pooled == 0
+        elif case == "range_source_honouring_out":
+            # one pooled buffer a fold; every member's read — split in
+            # subranges of 128 bytes — landed in its place in it
+            assert pooled == 2 and source.reads_with_out == 2 * 4 * 4
+            assert source.reads_without == 2  # the header's two
+        else:
+            assert pooled >= 2  # a buffer a group
+
+    @pytest.mark.parametrize("failing", [None, 2])
+    def test_a_groups_pooled_buffer_goes_back_exactly_once(self, tmp_path, monkeypatch, failing):
+        from modelx_tpu.dl import loader
+        from modelx_tpu.dl.sharding import MIXTRAL_RULES
+
+        pools = []
+
+        class Recording(loader._StagingPool):
+            def __init__(self, *args, **kwargs):
+                super().__init__(*args, **kwargs)
+                self.taken, self.back = [], []
+                pools.append(self)
+
+            def acquire(self, nbytes):
+                view = super().acquire(nbytes)
+                self.taken.append(view)
+                return view
+
+            def release(self, view):
+                self.back.append(view)
+                super().release(view)
+
+            def forfeit(self, view):
+                self.back.append(view)
+                super().forfeit(view)
+
+        monkeypatch.setattr(loader, "_StagingPool", Recording)
+        tensors = self.members()
+        path = str(tmp_path / "experts.safetensors")
+        st.write_safetensors(path, tensors)
+        infos, data_offset = st.read_header_from_file(path)
+        bad = infos[self.W1.format(e=failing)] if failing is not None else None
+
+        class Source(LocalFileSource):
+            def read_range(self, offset, length, out=None):
+                if bad is not None and offset == data_offset + bad.start:
+                    raise OSError("injected: this member cannot be read")
+                return super().read_range(offset, length, out)
+
+        def load():
+            return load_safetensors(
+                Source(path), make_mesh("dp=1", jax.devices()[:1]), MIXTRAL_RULES,
+                tensors=infos, data_offset=data_offset, pack_threshold=0,
+                staging_min_bytes=1024)
+
+        if failing is None:
+            load()
+        else:
+            with pytest.raises(OSError, match="injected"):
+                load()
+        (pool,) = pools
+        assert len(pool.taken) == 2  # one buffer a fold: w1's and w2's
+        assert sorted(map(id, pool.back)) == sorted(map(id, pool.taken))
+        assert pool._out == 0
+
+
 class TestPackedTransfer:
     """Small tensors ride one packed uint8 buffer + on-device bitcast; the
     result must be bit-identical to per-tensor device_put for every dtype,

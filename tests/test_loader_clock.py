@@ -153,14 +153,23 @@ class TestWhatWaits:
         assert stats.backpressure_seconds < 0.02 and stats.assemble_seconds < 0.02
         assert tiled(stats) == pytest.approx(stats.total_seconds, rel=0.02)
 
-    def test_fused_experts_show_as_assemble(self, tmp_path, mesh, monkeypatch):
+    def test_fused_experts_are_read_where_they_are_put(self, tmp_path, mesh, monkeypatch):
         experts = {f"model.layers.0.block_sparse_moe.experts.{e}.w1.weight":
                    np.full((32, 16), e, np.float32) for e in range(4)}
-        slow(monkeypatch, loader.np, "stack", 0.05)
+
+        def no_stack(*args, **kwargs):
+            raise AssertionError("a fold is not stacked: its members are read in place")
+
+        monkeypatch.setattr(loader.np, "stack", no_stack)
         arrays, stats = loader.load_safetensors(one_file(tmp_path, experts), mesh, [])
+        monkeypatch.undo()
         (name,) = arrays  # the four members came back as one stacked tensor
         assert arrays[name].shape == (4, 32, 16)
-        assert stats.assemble_seconds >= 0.05
+        np.testing.assert_array_equal(
+            np.asarray(arrays[name]), np.stack(list(experts.values())))
+        # no host work between the reads and the put, and no byte written twice
+        assert stats.assemble_seconds < 0.02
+        assert stats.assemble_copied_bytes == 0
         assert stats.backpressure_seconds < 0.02
         assert tiled(stats) == pytest.approx(stats.total_seconds, rel=0.02)
 
@@ -169,4 +178,5 @@ class TestWhatWaits:
         arrays, stats = loader.load_safetensors(src, mesh, [], dtype=np.float32)
         assert arrays["big"].dtype == np.float32
         assert stats.assemble_seconds > 0.001  # 4 M elements widened on the host
+        assert stats.assemble_copied_bytes == 2048 * 2048 * 4  # the cast's result
         assert tiled(stats) == pytest.approx(stats.total_seconds, rel=0.02)

@@ -197,6 +197,27 @@ class TestIntegration:
         (s,) = tracer().spans("dl.load")
         assert s["tensors"] == 1 and s["bytes_to_device"] == 16
 
+    @pytest.mark.parametrize("mesh_spec", ["dp=1", "ep=1,tp=2"])
+    def test_the_load_span_says_which_bytes_the_host_copied_twice(self, tmp_path, mesh_spec):
+        import numpy as np
+
+        from modelx_tpu.dl import safetensors as st
+        from modelx_tpu.dl.loader import LocalFileSource, load_safetensors
+        from modelx_tpu.dl.sharding import MIXTRAL_RULES
+        from modelx_tpu.parallel.mesh import make_mesh
+
+        path = str(tmp_path / "experts.safetensors")
+        st.write_safetensors(path, {
+            f"model.layers.0.block_sparse_moe.experts.{e}.w2.weight":
+            np.full((8, 16), e, np.float32) for e in range(4)})
+        _, stats = load_safetensors(LocalFileSource(path), make_mesh(mesh_spec), MIXTRAL_RULES)
+        (s,) = tracer().spans("dl.load")
+        # a clean fold: 0; w2's last axis over tp: every member cut from its
+        # whole tensor, all 4 x 8 x 16 float32 written once more
+        assert s["assemble_copied_bytes"] == stats.assemble_copied_bytes
+        assert stats.assemble_copied_bytes == (0 if mesh_spec == "dp=1" else 4 * 8 * 16 * 4)
+        assert s["assemble_s"] == round(stats.assemble_seconds, 3)
+
     # tier-1 wall (ISSUE 16): failure-path profile drill; `make slow` is the home
     @pytest.mark.slow
     def test_jax_profile_noop_on_failure(self, tmp_path):

@@ -281,7 +281,8 @@ class TestMetricsBlocks:
                        'continuous_loop_cpu_s{model="m"}',
                        'load_idle_seconds{model="m"}',
                        'load_shards_seconds{model="m"}',
-                       'load_device_put_seconds{model="m"}'):
+                       'load_device_put_seconds{model="m"}',
+                       'load_assemble_copied_bytes{model="m"}'):
             assert any(needle in line for line in text.splitlines()
                        if not line.startswith("#")), needle
         for line in text.splitlines():  # still the 0.0.4 exposition: comment or sample
@@ -302,6 +303,7 @@ class TestMetricsBlocks:
                     "device_put", "overlap"):
             assert model[f"load_{key}_seconds"] >= 0, key
         assert model["load_shard_files"] == 2
+        assert model["load_assemble_copied_bytes"] == 0  # float32 as stored: nothing copied twice
         assert "load_fetch_seconds" not in model
         busy = (model["load_fetch_busy_seconds"] + model["load_device_put_seconds"]
                 - model["load_overlap_seconds"] + model["load_assemble_seconds"])
