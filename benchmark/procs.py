@@ -141,9 +141,11 @@ def post_ok(port: int, path: str, body: dict) -> dict:
 
 
 def wait_ready(port: int, proc: subprocess.Popen, log_dir: str, timeout: float,
-               poll_s: float = 0.05) -> float:
+               poll_s: float = 0.05, seen: dict | None = None) -> float:
     """Seconds until ``/healthz`` answers 200. Polled every 50 ms: the wait
-    is part of a timed deploy, and a coarser poll would show as noise."""
+    is part of a timed deploy, and a coarser poll would show as noise.
+    ``seen["listen_at"]`` is the clock when the port first answered at all
+    (a pod answers 503 from the moment it listens, before it loads)."""
     t0 = time.monotonic()
     log = os.path.join(log_dir, f"{proc.log_name}.log")
     while time.monotonic() - t0 < timeout:
@@ -151,6 +153,8 @@ def wait_ready(port: int, proc: subprocess.Popen, log_dir: str, timeout: float,
             raise Fail(f"{proc.log_name} exited {proc.returncode} while starting\n{tail(log)}")
         try:
             status, _ = http_json(port, "GET", "/healthz", timeout=5.0)
+            if seen is not None:
+                seen.setdefault("listen_at", time.monotonic())
             if status == 200:
                 return time.monotonic() - t0
         except OSError:

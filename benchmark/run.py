@@ -9,8 +9,10 @@ on, through the program's console entry points (``python -m modelx_tpu.cli
 serve | push | dl | serve-model``), and prints as its LAST line of stdout the
 object the contract names: ``correct``, ``attempted``, ``failed``, ``metrics``
 (``--trace 0``: the cell's end-to-end metrics; ``--trace 1``: its per-layer
-metrics), ``device`` and, traced, ``breakdown``. Earlier lines are one JSON
-object per phase: medians, counts, stage times, the generator's lateness.
+metrics), ``device``, traced ``breakdown``, and last ``compared``: every number
+``correct`` was decided from beside its limit, which are also the last lines of
+stderr. Earlier lines are one JSON object per phase: medians, counts, stage
+times, the generator's lateness.
 
 The parent never imports jax: a chip belongs to one process at a time, so
 every process that may touch it is a child, one at a time, and children that
@@ -52,12 +54,15 @@ from benchmark.procs import (CLI, Children, Fail, check, emit, free_port, http_j
 # comparison to (TP_MIN_ARGMAX_AGREEMENT), for the same reason; a wrong
 # program (bad cache offset, wrong rope, dropped expert) agrees on far fewer.
 # A configuration may state another share with its reason
-# (``min_argmax_agreement``): a sparse-expert model does, see its file.
+# (``min_argmax_agreement``): a sparse-expert model does, see its file. It may
+# also state how many prompts the probe sends (``probes``, with its reason): the
+# share is held over ``probes`` x ``new_tokens`` tokens, and over few of them a
+# sound pod's near-ties alone cross the tolerance now and then.
 MIN_ARGMAX_AGREEMENT = 0.9
 PROBES = 4
 META_KEYS = {"source", "family", "reduced", "reduced_from", "assumed", "deployment", "chips",
              "serve_args", "bytes_predicted", "bytes_measured", "rehearse", "checkpoint_dtype",
-             "min_argmax_agreement", "min_argmax_agreement_why"}
+             "min_argmax_agreement", "min_argmax_agreement_why", "probes", "probes_why"}
 
 
 def load_json(*parts: str):
@@ -151,7 +156,9 @@ class Run:
             argv += ["--trace-dir", trace_dir]
         t0 = time.monotonic()
         pod = self.kids.start(name, argv, jax_child=True)
-        wait_ready(port, pod, self.kids.log_dir, 1100)
+        seen: dict = {}
+        wait_ready(port, pod, self.kids.log_dir, 1100, seen=seen)
+        self.listen_s = seen["listen_at"] - t0  # spawn -> the port answers (503: loading)
         return pod, port, time.monotonic() - t0
 
     def pod_report(self, port: int) -> dict:
@@ -195,8 +202,10 @@ class Run:
 
         rng = np.random.default_rng([self.args.seed, 4])
         vocab, n = self.config["vocab_size"], spec["new_tokens"]
+        count = self.config.get("probes", PROBES)  # one stream: the first PROBES prompts are the same
         agree = first = 0
-        for _ in range(PROBES):
+        t0 = time.monotonic()
+        for _ in range(count):
             prompt = [int(t) for t in rng.integers(1, vocab, spec["prompt_tokens"])]
             rec = loadgen.stream_request(port, prompt, n)
             check(rec["done"] and not rec["error"], f"probe request failed: {rec['error']}")
@@ -207,10 +216,11 @@ class Run:
             hits = [fwd[len(prompt) - 1 + i] == toks[i] for i in range(n)]
             agree += sum(hits)
             first += hits[0]
-        share = agree / (PROBES * n)
+        share = agree / (count * n)
         tolerance = self.config.get("min_argmax_agreement", MIN_ARGMAX_AGREEMENT)
-        out = {"probes": PROBES, "tokens": PROBES * n, "argmax_agreement": share,
-               "first_token_agrees": first, "tolerance": tolerance, "ok": share >= tolerance}
+        out = {"probes": count, "tokens": count * n, "argmax_agreement": share,
+               "first_token_agrees": first, "tolerance": tolerance, "ok": share >= tolerance,
+               "seconds": time.monotonic() - t0}
         emit("probes", **out)
         return out
 
@@ -239,18 +249,28 @@ class Run:
 
     def profile(self, port: int, seconds: float) -> None:
         """The pod's own POST /v1/profile, with the engine's counters read
-        just before and just after it."""
+        just before it is sent and ``seconds`` after, by the clock: the
+        profiler's stop can outlast the load by a minute and more, so the POST
+        goes on a thread of its own and its return (``post_seconds``) is
+        waited for only after the second read."""
+        answer: list = []
         _, before = http_json(port, "GET", "/metrics")
         t0 = time.monotonic()
-        status, data = http_json(port, "POST", "/v1/profile", {"seconds": seconds},
-                                 timeout=seconds + 300)
-        span = time.monotonic() - t0
+        post = threading.Thread(target=lambda: answer.append(http_json(
+            port, "POST", "/v1/profile", {"seconds": seconds}, timeout=seconds + 300)))
+        post.start()
+        post.join(seconds)
         _, after = http_json(port, "GET", "/metrics")
+        span = time.monotonic() - t0
+        post.join()
+        status, data = answer[0] if answer else (None, "the POST raised")
         if status != 200:
             emit("profile", error=f"{status}: {data}")
             return
+        post_s = time.monotonic() - t0
         self.sources["trace_span"] = {"metrics_before": before, "metrics_after": after,
-                                      "seconds": span}
+                                      "seconds": span, "post_seconds": post_s}
+        emit("profile", seconds=round(span, 3), post_seconds=round(post_s, 3))
 
     def reduce_trace(self, path: str) -> None:
         """After the pod has stopped: a child under JAX_PLATFORMS=cpu reads
@@ -303,7 +323,12 @@ class Run:
             emit("rehearsed_on_a_cpu_trace_not_device_metrics", **rehearsed)
         return out
 
-    def result(self, correct: bool, attempted: int, failed: int, end_to_end: dict) -> dict:
+    def result(self, attempted: int, failed: int, end_to_end: dict, compared: dict) -> dict:
+        """The last line. ``compared`` is every number ``correct`` is decided from,
+        ``name_min`` or ``name_max`` -> (value, limit): the run is correct where each
+        holds, and the line carries them all, last."""
+        correct = all(v >= lim if name.endswith("_min") else v <= lim
+                      for name, (v, lim) in compared.items())
         units = {m["name"]: m["unit"] for m in self.metric_defs("end_to_end")}
         missing = sorted(set(units) - set(end_to_end))
         check(not missing, f"the run measured no {missing}")
@@ -313,6 +338,8 @@ class Run:
                "failed": failed}
         if self.args.trace:
             emit("end_to_end", **{k: v["value"] for k, v in e2e.items()})
+            check("trace_span" in self.sources,
+                  "the traced run has no trace_span: /v1/profile failed or did not return")
             trace = self.sources.get("trace") or {}
             check(trace.get("busy_s", 0) > 0, "the trace shows no operation on the device")
             out["metrics"] = self.layer_metrics()
@@ -325,6 +352,7 @@ class Run:
         out["device"] = device
         if self.args.rehearse:
             out["rehearsal"] = True
+        out["compared"] = {k: {"value": v, "limit": lim} for k, (v, lim) in compared.items()}
         return out
 
 
@@ -439,7 +467,8 @@ def serve_mode(run: Run, schedule: dict) -> dict | None:
                                               schedule["stagger_s"], run.seconds)
     setup_s = t0 - run.t_start
     if tracer is not None:
-        tracer.join(120)
+        # as long as the POST's own timeout: a pod stopped under its profiler loses the span
+        tracer.join(run.traffic["trace_seconds"] + 300)
     _, after = http_json(port, "GET", "/metrics")
     run.memory_peak = max(run.memory_peak, poller.peak)
     run.note_memory(after)
@@ -479,9 +508,10 @@ def serve_mode(run: Run, schedule: dict) -> dict | None:
         emit("throughput", tokens_in_window=tokens, tokens_per_s=end_to_end["tokens_per_s"],
              requests_completed=completed)
         attempted = completed + len(failed)
-    correct = (not failed and not wrong and completed > 0
-               and probe["ok"])
-    return run.result(correct, attempted, len(failed), end_to_end)
+    compared = {"argmax_agreement_min": (probe["argmax_agreement"], probe["tolerance"]),
+                "requests_failed_max": (len(failed), 0), "answers_wrong_max": (len(wrong), 0),
+                "requests_completed_min": (completed, 1)}
+    return run.result(attempted, len(failed), end_to_end, compared)
 
 
 def deploy_mode(run: Run, schedule: dict) -> dict:
@@ -543,6 +573,10 @@ def deploy_mode(run: Run, schedule: dict) -> dict:
                 pull_wall_s=t_pull - t0,
                 pull_seconds=summary.get("pull_seconds"), pulled_bytes=summary.get("bytes"),
                 ready_s=ready_s, first_request_s=rec["times"][0] - t_ready,
+                listen_s=run.listen_s,
+                pod_listen_ttft_s=rec["times"][0] - t_pull - run.listen_s,
+                imports_s=metrics.get("startup", {}).get("imports_s"),
+                backend_devices_s=metrics.get("startup", {}).get("backend_init_devices_s"),
                 load_seconds=model.get("load_seconds"), load_bytes=model.get("load_bytes"),
                 cache_requests=cc.get("requests"), cache_hits=cc.get("hits"),
                 cache_misses=cc.get("misses"),
@@ -571,11 +605,13 @@ def deploy_mode(run: Run, schedule: dict) -> dict:
     t_window = time.monotonic()
     setup_s = t_window - run.t_start
     deploys: list[dict] = []
-    while not deploys or time.monotonic() - t_window < run.seconds:
+    probing_s = 0.0  # the window's clock stands while the probes run
+    while not deploys or time.monotonic() - t_window - probing_s < run.seconds:
         # the first deploy of the window is the traced one and carries the
         # probes — both after its first token, outside what is timed
         deploys.append(deploy(f"deploy{len(deploys)}", traced=bool(run.args.trace) and not deploys,
                               probe=not deploys))
+        probing_s += deploys[-1].get("probe", {}).get("seconds", 0.0)
     kids.stop(reg)
     shutil.rmtree(volume, ignore_errors=True)  # 7.6 GB the next run would delete anyway
     check("probe" in deploys[0], f"the first deploy failed: {deploys[0]['error']}")
@@ -586,22 +622,26 @@ def deploy_mode(run: Run, schedule: dict) -> dict:
     answers = [d["tokens"] for d in good] + ([reference["tokens"]] if reference else [])
     asked, vocab = req["max_new_tokens"], run.config["vocab_size"]
     probe = deploys[0]["probe"]
-    correct = (len(good) == len(deploys)
-               and all(a == answers[0] for a in answers)
-               and all(len(a) == asked and all(0 <= t < vocab for t in a) for a in answers)
-               and all(d["hbm_bytes_in_use"] >= run.ckpt_bytes for d in good)
-               and probe["ok"])
     end_to_end = {"setup_s": setup_s}
     if good:
-        # dl start -> first token, and pod spawn -> first token (the same without
-        # the pull); BENCHMARK.json says which of them the cell reports end to end
-        for k in ("deploy_ttft_s", "pod_ttft_s"):
+        # dl start -> first token, pod spawn -> first token (the same without the
+        # pull) and the pod's port answers -> first token (the same without the
+        # interpreter, the imports and the chip's bring-up, whose seconds are the
+        # machine's); BENCHMARK.json says which of them the cell reports end to end
+        for k in ("deploy_ttft_s", "pod_ttft_s", "pod_listen_ttft_s"):
             end_to_end[k] = stats.median([d[k] for d in good])
     emit("window", seconds=round(time.monotonic() - t_window, 2), deploys=len(deploys),
          reached_first_token=len(good), answers_equal=all(a == answers[0] for a in answers),
          compared_with_setup_deploy=reference is not None,
          memory_peak_bytes=run.memory_peak)
-    return run.result(correct, len(deploys), len(deploys) - len(good), end_to_end)
+    compared = {"argmax_agreement_min": (probe["argmax_agreement"], probe["tolerance"]),
+                "deploys_failed_max": (len(deploys) - len(good), 0),
+                "answers_unlike_the_first_max": (sum(a != answers[0] for a in answers), 0),
+                "answers_malformed_max": (sum(not (len(a) == asked and all(0 <= t < vocab for t in a))
+                                              for a in answers), 0),
+                "hbm_bytes_in_use_min": (min((d["hbm_bytes_in_use"] for d in good), default=0),
+                                         run.ckpt_bytes)}
+    return run.result(len(deploys), len(deploys) - len(good), end_to_end, compared)
 
 
 MODES = {"open": serve_mode, "closed": serve_mode, "deploy": deploy_mode}
@@ -642,6 +682,9 @@ def main() -> int:
         if run is not None:
             run.kids.stop_all()
     if result is not None:
+        for name, c in result["compared"].items():
+            print(f"benchmark/run.py: compared {name}: {c['value']} (limit {c['limit']})",
+                  file=sys.stderr)
         print(json.dumps(result), flush=True)
     return 0
 

@@ -191,15 +191,17 @@ def test_every_name_the_cell_adds_has_its_files():
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, "longdoc", 1)
     assert load(BENCH, "workloads", CELL + ".json")["config"] == CONFIG
-    mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
+    # an entry is a reading and the cells that report it are its ``workloads``: this
+    # cell's entries are those that list it, under a name of its own or one it shares
+    mine = [m for m in bench["per_layer"] if CELL in m.get("workloads", ())]
     # at least, not exactly: a later PR may add a metric to this cell
-    assert len(mine) >= 14 and all(m["name"].endswith(".longdoc") for m in mine)
+    assert len(mine) >= 14
     assert {m["name"] for m in mine} >= {
         "model.decode_step_ms.longdoc", "model.decode_hbm_share.longdoc",
         "mla.attn_roofline_share.longdoc", "mla.kv_read_share.longdoc",
         "mla.absorbed_share.longdoc", "moe.held_hit_share.longdoc",
         "moe.held_assignment_share.longdoc", "latent.cache_gb.longdoc",
-        "device.hbm_peak_gb.longdoc", "device.idle_share.longdoc", "engine.pad_fraction.longdoc",
+        "device.hbm_peak_gb", "device.idle_share", "engine.pad_fraction",
         "engine.wait_ms.longdoc", "engine.fill_pieces.longdoc", "cache.store_hit_share.longdoc"}
     for m in mine:
         reader, spec = reader_of(m["name"])
@@ -207,7 +209,8 @@ def test_every_name_the_cell_adds_has_its_files():
         assert m["moves"] == ("setup_s" if m["name"].startswith("cache.") else "tokens_per_s")
     reported = [m["name"] for m in bench["end_to_end"] if "workloads" not in m or CELL in m["workloads"]]
     assert reported == ["tokens_per_s", "setup_s"]
-    assert next(m for m in bench["end_to_end"] if m["name"] == "tokens_per_s")["workloads"][-1] == CELL
+    # "in", not "last": a later configuration's cell is appended after this one
+    assert CELL in next(m for m in bench["end_to_end"] if m["name"] == "tokens_per_s")["workloads"]
 
 
 # -- the byte count and the readers, by hand -------------------------------------
@@ -290,8 +293,8 @@ def test_the_new_readers_on_a_hand_made_trace(config):
     want = {"mla.kv_read_share.longdoc": 20480 / 20000, "mla.absorbed_share.longdoc": 1.0,
             "moe.held_hit_share.longdoc": 14 / 20, "moe.held_assignment_share.longdoc": 0.125,
             "latent.cache_gb.longdoc": 6.7108864, "engine.fill_pieces.longdoc": 0.0,
-            "engine.pad_fraction.longdoc": 0.0, "engine.wait_ms.longdoc": 0.4 * 80 / 20 * 1e3,
-            "device.idle_share.longdoc": 0.001, "device.hbm_peak_gb.longdoc": 14.2,
+            "engine.pad_fraction": 0.0, "engine.wait_ms.longdoc": 0.4 * 80 / 20 * 1e3,
+            "device.idle_share": 0.001, "device.hbm_peak_gb": 14.2,
             "cache.store_hit_share.longdoc": 1.0}
     for name, value in want.items():
         reader, spec = reader_of(name)

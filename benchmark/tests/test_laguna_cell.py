@@ -118,8 +118,11 @@ def test_every_name_the_cell_adds_has_its_files():
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, "reason", 1)
     assert load(BENCH, "workloads", CELL + ".json")["config"] == CONFIG
-    mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
-    assert len(mine) == 10 and all(m["name"].endswith(".reason") for m in mine)
+    # an entry is a reading and the cells that report it are its ``workloads``: this
+    # cell's entries are those that list it, under a name of its own or one it shares
+    mine = [m for m in bench["per_layer"] if CELL in m.get("workloads", ())]
+    # at least, not exactly: later PRs added metrics to this cell (14 at PR 48)
+    assert len(mine) >= 10
     for m in mine:
         spec = load(BENCH, "layer_metrics", m["name"] + ".json")
         reader = importlib.import_module(f"benchmark.layer_metrics.readers.{spec['reader']}")

@@ -196,8 +196,8 @@ def test_every_name_the_cell_adds_has_its_files():
     assert {m["name"] for m in reported} >= {
         "model.decode_step_ms.longdoc", "moe.held_hit_share.longdoc",
         "moe.held_assignment_share.longdoc", "moe.read_hit_share.longdoc",
-        "device.hbm_peak_gb.longdoc", "device.idle_share.longdoc", "engine.pad_fraction.longdoc",
-        "engine.wait_ms.longdoc", "engine.fill_pieces.longdoc", "cache.store_hit_share.longdoc",
+        "device.hbm_peak_gb", "device.idle_share", "engine.pad_fraction",
+        "engine.wait_ms", "engine.fill_pieces.longdoc", "cache.store_hit_share.longdoc",
         "attn.kv_read_share.reason", "attn.ring_kernel_share.reason",
         "kv.write_kernel_share.reason"}
     assert len(reported) >= 16
@@ -302,9 +302,9 @@ def test_the_new_readers_on_a_hand_made_trace(config, monkeypatch):
             "attn.kv_read_share.reason": 22016 / 32768, "kv.write_kernel_share.reason": 1.0,
             "moe.held_hit_share.longdoc": 10 / 16,
             "moe.held_assignment_share.longdoc": 0.0625, "moe.read_hit_share.longdoc": 1.0,
-            "engine.fill_pieces.longdoc": 0.0, "engine.pad_fraction.longdoc": 0.0,
-            "engine.wait_ms.longdoc": 0.3 * 80 / 20 * 1e3, "device.idle_share.longdoc": 0.002,
-            "device.hbm_peak_gb.longdoc": 13.4, "cache.store_hit_share.longdoc": 1.0}
+            "engine.fill_pieces.longdoc": 0.0, "engine.pad_fraction": 0.0,
+            "engine.wait_ms.longdoc": 0.3 * 80 / 20 * 1e3, "device.idle_share": 0.002,
+            "device.hbm_peak_gb": 13.4, "cache.store_hit_share.longdoc": 1.0}
     for name, value in want.items():
         reader, spec = reader_of(name)
         assert reader.read(sources, spec) == pytest.approx(value), name

@@ -29,19 +29,19 @@ def engine(**cont) -> dict:
 
 def test_a_value_with_a_scale():
     sources = {"metrics_after": {"device": {"hbm_peak_bytes": 15_500_000_000}}}
-    assert metrics_path.read(sources, spec("device.hbm_peak_gb.decode")) == pytest.approx(15.5)
+    assert metrics_path.read(sources, spec("device.hbm_peak_gb")) == pytest.approx(15.5)
     sources = {"trace_span": {"metrics_after": {"startup": {"imports_s": 4.25}}}}
     assert metrics_path.read(sources, spec("front.imports_s")) == 4.25
 
 
 def test_a_ratio_of_two_values():
     sources = {"trace_span": {"metrics_after": {"default": {
-        "load_seconds": 10.0, "load_fetch_busy_seconds": 9.0, "load_device_put_seconds": 4.0}}}}
-    assert metrics_path.read(sources, spec("loader.fetch_busy_share")) == pytest.approx(0.9)
-    assert metrics_path.read(sources, spec("loader.put_busy_share")) == pytest.approx(0.4)
+        "load_seconds": 10.0, "load_shards_seconds": 9.0, "load_idle_seconds": 3.6}}}}
+    assert metrics_path.read(sources, spec("loader.shards_share")) == pytest.approx(0.9)
+    assert metrics_path.read(sources, spec("loader.idle_share")) == pytest.approx(0.4)
     sources = {"metrics_before": {"compile_cache": {
-        "retrieval_s": 24.5, "hits": 49, "requests": 49, "trace_s": 30.0, "lower_s": 19.0}}}
-    assert metrics_path.read(sources, spec("cache.retrieval_s_per_program.decode")) == 0.5
+        "store_load_s": 24.5, "store_hits": 49, "requests": 49, "trace_s": 30.0, "lower_s": 19.0}}}
+    assert metrics_path.read(sources, spec("cache.store_load_s_per_program.decode")) == 0.5
     assert metrics_path.read(sources, spec("cache.trace_lower_s_per_program.decode")) == 1.0
 
 
@@ -53,11 +53,16 @@ def test_a_ratio_of_two_differences_sums_and_subtracts_its_terms():
                    phase_s={"admit_prep": 1.5, "admit_dispatch": 2.7, "chunk_dispatch": 1.4,
                             "fanout": 4.1, "wait_tokens": 55.0, "firsts_wait": 1.5, "idle": 9.0},
                    queue_ms_hist={"sum": 900.0, "count": 30, "buckets": {}})
-    sources = {"metrics_before": before, "metrics_after": after}
+    # the window's two dumps, and the traced span's (PR 57: the idle rows and the waits
+    # of every token cell are read over the traced span)
+    sources = {"metrics_before": before, "metrics_after": after,
+               "trace_span": {"metrics_before": before, "metrics_after": after}}
+    assert metrics_path.read({"metrics_before": before, "metrics_after": after},
+                             spec("engine.wait_ms")) is None
     assert metrics_path.read(sources, spec("engine.admit_ms.decode")) == pytest.approx(12.0)
     assert metrics_path.read(sources, spec("engine.dispatch_ms.decode")) == pytest.approx(4.0)
     assert metrics_path.read(sources, spec("engine.fanout_ms.decode")) == pytest.approx(11.0)
-    assert metrics_path.read(sources, spec("engine.wait_ms.decode")) == pytest.approx(255.0)
+    assert metrics_path.read(sources, spec("engine.wait_ms")) == pytest.approx(255.0)
     # 2 s of CPU over 30 s of wall less 25.5 s of waiting: 2 / 4.5
     assert metrics_path.read(sources, spec("engine.cpu_share.decode")) == pytest.approx(2 / 4.5)
     # the histogram was not there before its first sample: it counts from 0
@@ -67,9 +72,9 @@ def test_a_ratio_of_two_differences_sums_and_subtracts_its_terms():
 def test_the_first_request_is_the_span_between_the_profile_calls_dumps():
     cc = lambda **kw: {"compile_cache": kw}
     sources = {"trace_span": {
-        "metrics_before": cc(requests=1, hits=1, retrieval_s=0.2, trace_s=0.1, lower_s=0.1),
-        "metrics_after": cc(requests=9, hits=9, retrieval_s=4.2, trace_s=2.1, lower_s=2.1)}}
-    assert metrics_path.read(sources, spec("cache.retrieval_s_per_program")) == pytest.approx(0.5)
+        "metrics_before": cc(requests=1, store_hits=1, store_load_s=0.2, trace_s=0.1, lower_s=0.1),
+        "metrics_after": cc(requests=9, store_hits=9, store_load_s=4.2, trace_s=2.1, lower_s=2.1)}}
+    assert metrics_path.read(sources, spec("cache.store_load_s_per_program")) == pytest.approx(0.5)
     assert metrics_path.read(sources, spec("cache.trace_lower_s_per_program")) == pytest.approx(0.5)
 
 
@@ -84,11 +89,11 @@ def test_idle_named_share_counts_the_programs_own_spans():
     trace = {"idle_gaps": [["continuous.boundary/fanout", 0.5], ["continuous.boundary", 0.2],
                            ["startup.engine_init", 0.1], ["_threading.py:323_wait", 0.1],
                            ["PjitFunction(f)", 0.05], ["(no host event)", 0.05]]}
-    share = idle_named_share.read({"trace": trace}, spec("device.idle_named_share.decode"))
+    share = idle_named_share.read({"trace": trace}, spec("device.idle_named_share.deploy"))
     assert share == pytest.approx(0.8)
     only_frames = {"idle_gaps": [["_threading.py:323_wait", 0.63], ["_pjit.py:250_cache_miss", 0.06]]}
     assert idle_named_share.read({"trace": only_frames},
-                                 spec("device.idle_named_share.decode")) == 0.0
+                                 spec("device.idle_named_share.deploy")) == 0.0
 
 
 def new_specs() -> list[str]:
@@ -117,4 +122,4 @@ def test_a_zero_denominator_gives_nothing():
     same = {"compile_cache": {"requests": 8, "hits": 8, "retrieval_s": 1.0,
                               "trace_s": 1.0, "lower_s": 1.0}}
     sources = {"trace_span": {"metrics_before": same, "metrics_after": same}}
-    assert metrics_path.read(sources, spec("cache.retrieval_s_per_program")) is None
+    assert metrics_path.read(sources, spec("cache.trace_lower_s_per_program")) is None
