@@ -385,7 +385,13 @@ class ContinuousBatcher:
                       # the row-padding tax snapshot() exposes as
                       # pad_fraction (admit_pad_rows covers the admit-side
                       # pow2 burst rounding separately)
-                      "decode_rows": 0, "decode_pad_rows": 0}
+                      "decode_rows": 0, "decode_pad_rows": 0,
+                      # the row-step ledger: every one of the max_slots x
+                      # n_steps row-steps a chunk program computes, in exactly
+                      # one of the five (_count_row_steps); total is their sum
+                      "row_steps": dict.fromkeys(
+                          ("tokens", "edge", "filling", "vacant_queued",
+                           "vacant_idle", "total"), 0)}
         # where the slots' KV lives (dl/kv_layout.py): [max_slots, max_len]
         # rows, or — page_size > 0 — a pool of pages sized by
         # max_live_tokens and read by gather or in place (paged_attention).
@@ -1661,6 +1667,10 @@ class ContinuousBatcher:
         depth = self._pick_depth()
         n_steps = depth * self.chunk_size
         active = list(self._rows)
+        # a request the engine holds that wants a slot (what _pick_depth
+        # asks): a vacant slot's steps are then the engine's to mend, else
+        # the clients' turnaround
+        queued = bool(self._waiting or self._preempted or not self._q.empty())
         filtered = bool(self._use_filters[active].any())
         self._rec("dispatch", depth=depth, n_steps=n_steps,
                   active=len(self._rows), devices=self.mesh_devices)
@@ -1743,7 +1753,29 @@ class ContinuousBatcher:
         self._tokens_in_flight += taken
         if self._tokens_in_flight > self.stats["tokens_in_flight_peak"]:
             self.stats["tokens_in_flight_peak"] = self._tokens_in_flight
+        # a live row's steps are tokens or edge (its skip, what lies past its
+        # budget); a slot with neither row nor fill is vacant
+        vacant = (self.max_slots - n_live) * n_steps
+        self._count_row_steps(
+            tokens=taken, edge=len(active) * n_steps - taken,
+            filling=len(self._filling) * n_steps,
+            vacant_queued=vacant if queued else 0,
+            vacant_idle=0 if queued else vacant,
+            total=self.max_slots * n_steps)
         return toks_dev, plan, depth
+
+    def _count_row_steps(self, **more: int) -> None:
+        """Add to the row-step ledger. The dict is replaced whole, never
+        updated in place: ``snapshot()`` runs on other threads, and what it
+        copies must be five counters that sum to ``total``."""
+        self.stats["row_steps"] = {
+            k: v + more.get(k, 0) for k, v in self.stats["row_steps"].items()}
+
+    def _tokens_to_edge(self, n: int) -> None:
+        """``n`` tokens a dispatch planned for a client were not handed over
+        (a stop token, a cancel, a dead engine): their steps gave nothing."""
+        if n > 0:
+            self._count_row_steps(tokens=-n, edge=n)
 
     def _deliver_firsts(self) -> None:
         """Hand this iteration's admitted rows their prefill tokens. Blocks
@@ -1831,12 +1863,14 @@ class ContinuousBatcher:
         for slot, row, skip, take, done in plan:
             self._tokens_in_flight = max(0, self._tokens_in_flight - max(take, 0))
             if row.closed:
+                self._tokens_to_edge(take)
                 continue  # stop token already ended the row (and its queue)
             if row.ticket.cancelled:
                 # client disconnected mid-stream: stop piling tokens into a
                 # queue nobody drains; the sweep frees the slot next round
                 row.out.put(_DONE)
                 row.closed = True
+                self._tokens_to_edge(take)
                 continue
             piece = toks[slot : slot + 1, skip : skip + take] if take > 0 else None
             if piece is not None and row.seq is not None:
@@ -1847,6 +1881,7 @@ class ContinuousBatcher:
                 cut = stop_cut(piece[0].tolist(), row.stops)
                 if cut is not None:
                     self._put_pieces(row, piece[:, :cut])  # include the stop
+                    self._tokens_to_edge(take - cut)
                     row.out.put(_DONE)
                     row.closed = True
                     self._rec("eos", slot=slot,
@@ -2276,8 +2311,9 @@ class ContinuousBatcher:
             row.out.put(err)
         self._first_pending = []
         for _toks_dev, plan, _depth in pending:
-            for _slot, row, _skip, _take, _done in plan:
+            for _slot, row, _skip, take, _done in plan:
                 row.out.put(err)
+                self._tokens_to_edge(take)
         self._tokens_in_flight = 0
         self._inflight_chunks = 0
 
@@ -2363,7 +2399,10 @@ class ContinuousBatcher:
         stall_ms_max, spec_* when speculating, pages_* when paged) plus
         the instantaneous active/filling/waiting row counts — operators
         and the bench read THIS, not engine internals."""
-        snap = dict(self.stats)
+        # a block of counters (row_steps, the layout's moe / ssm / ...) is
+        # copied too: what is handed out must not grow under its reader
+        snap = {k: dict(v) if isinstance(v, dict) else v
+                for k, v in self.stats.items()}
         snap["active"] = len(self._rows)
         snap["filling"] = len(self._filling)
         snap["waiting"] = len(self._waiting) + len(self._preempted)

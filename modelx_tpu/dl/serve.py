@@ -1395,6 +1395,9 @@ class ServerSet:
         self.admin_tokens = tuple(admin_tokens)
         self.trace_dir = trace_dir or os.path.join(os.getcwd(), "jax-trace")
         self._profiling = threading.Lock()
+        # the newest capture as the pod itself saw it (Handler._profile):
+        # /metrics serves it under "profile" once there is one
+        self.profile_capture: dict | None = None
         # on-demand profiler captures (POST /admin/profile) land in
         # numbered subdirs under trace_dir; only the newest
         # MAX_PROFILE_CAPTURES survive (the capture dir is CAPPED — an
@@ -2352,11 +2355,19 @@ def serve(servers: ModelServer | ServerSet, listen: str = ":8000",
                     # process creation -> ready by stage (utils/trace.py):
                     # the stages sum to ready_s
                     payload["startup"] = started
+                fmt = _query_param(self.path, "format")
+                text = promexp.wants_prometheus(self.headers.get("Accept"), fmt)
+                if sset.profile_capture is not None:
+                    # the newest profiler capture by the pod's own clock, with
+                    # the engines' counters at its two edges; the text view
+                    # leaves the two dumps out (a second series a counter)
+                    payload["profile"] = {
+                        k: v for k, v in sset.profile_capture.items()
+                        if not (text and k in ("at_start", "at_stop"))}
                 # content negotiation (ISSUE 13): the SAME tree renders
                 # as Prometheus text on Accept: text/plain or
                 # ?format=prometheus; the default JSON is byte-unchanged
-                fmt = _query_param(self.path, "format")
-                if promexp.wants_prometheus(self.headers.get("Accept"), fmt):
+                if text:
                     # the second rule labels the per-device HBM breakdown
                     # (payload["device"]["devices"][i]) with device="<i>"
                     # instead of minting one metric name per device index
@@ -2484,13 +2495,32 @@ def serve(servers: ModelServer | ServerSet, listen: str = ":8000",
                     {"error": f"{key} must be a number in {'(' if admin else '['}0, "
                               f"{MAX_PROFILE_SECONDS}]"},
                 )
+            def engines() -> dict:
+                # the shape of /metrics itself, the engines' counters alone
+                return {n: {"continuous": cb.snapshot()}
+                        for n, cb in list(sset.cbatchers.items())}
+
             if not sset._profiling.acquire(blocking=False):
                 return self._json(409, {"error": "profile already running"})
             try:
                 path = out_dir()
+                # the pod alone knows where the device trace begins and ends:
+                # the engines' counters are read at both edges, inside the
+                # profiler's own start and stop, and the two calls are timed
+                t_call = time.monotonic()
                 with trace.jax_profile(
                         path, python_tracer=req.get("python_tracer") is True):
+                    t_on = time.monotonic()
+                    at_start = engines()
                     time.sleep(seconds)
+                    at_stop = engines()
+                    t_off = time.monotonic()
+                captures = (sset.profile_capture or {"captures": 0})["captures"] + 1
+                sset.profile_capture = {
+                    "captures": captures, "start_s": round(t_on - t_call, 6),
+                    "stop_s": round(time.monotonic() - t_off, 6),
+                    "traced_s": round(t_off - t_on, 6),
+                    "at_start": at_start, "at_stop": at_stop}
             finally:
                 sset._profiling.release()
             return self._json(200, {dir_key: path, **({key: seconds} if admin else {})})

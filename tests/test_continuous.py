@@ -1027,3 +1027,141 @@ class TestOtherFamilies:
         finally:
             for c in sset.cbatchers.values():
                 c.close()
+
+
+FIVE = ("tokens", "edge", "filling", "vacant_queued", "vacant_idle")
+
+
+class TestRowStepLedger:
+    """``stats["row_steps"]``: every one of the ``max_slots x n_steps``
+    row-steps a chunk program computes stands in exactly one of five
+    counters, and ``tokens`` plus the admissions' first tokens is what the
+    clients were handed."""
+
+    @pytest.fixture(scope="class")
+    def churned(self, server):
+        """One run with a case for each counter: a lone row beside vacant
+        slots, a request that reaches the queue between the admission sweep
+        and the dispatch, a prompt that lands in pieces beside a decoding
+        row, more requests than slots, budgets that end mid-program, and a
+        stop token that cuts a take at delivery."""
+        import concurrent.futures
+
+        cb = ContinuousBatcher(server, max_slots=3, chunk_size=4, prefill_chunk=16)
+        seen: list[tuple] = []  # (the ledger, the steps dispatched) after every dispatch
+        handed: list[int] = []  # tokens each request's client got
+        steps = [0]
+        late: list = []
+        orig_dispatch, orig_depth = cb._dispatch_chunk, cb._pick_depth
+
+        def dispatch():
+            out = orig_dispatch()
+            steps[0] += out[2] * cb.chunk_size
+            seen.append((dict(cb.stats["row_steps"]), steps[0]))
+            return out
+
+        def depth():
+            picked = orig_depth()
+            if late and len(cb._rows) == 1 and not cb._filling:
+                # held in the queue while two slots stand vacant
+                late.pop()()
+            return picked
+
+        def run(tokens, budget, **kw):
+            out = cb.generate(tokens, max_new_tokens=budget, **kw)
+            handed.append(out.shape[1] - tokens.shape[1])
+            return out
+
+        cb._dispatch_chunk, cb._pick_depth = dispatch, depth
+        try:
+            rng = np.random.RandomState(3)
+            with concurrent.futures.ThreadPoolExecutor(8) as pool:
+                lone = rng.randint(1, 64, (1, 5)).astype(np.int32)
+                futs = [pool.submit(run, lone, 60)]
+                deadline = time.monotonic() + 60
+                while cb.stats["chunks"] < 2 and time.monotonic() < deadline:
+                    time.sleep(0.005)
+                idle_then = cb.stats["row_steps"]["vacant_idle"]
+                queued = rng.randint(1, 64, (1, 3)).astype(np.int32)
+                late.append(lambda: futs.append(pool.submit(run, queued, 6)))
+                while late and time.monotonic() < deadline:
+                    time.sleep(0.005)
+                # a prompt of three pieces beside the decoding rows
+                futs.append(pool.submit(
+                    run, rng.randint(1, 64, (1, 40)).astype(np.int32), 7))
+                # more requests than slots, budgets that end mid-program
+                for budget in (3, 5, 9, 10, 13):
+                    futs.append(pool.submit(
+                        run, rng.randint(1, 64, (1, 4)).astype(np.int32), budget))
+                # a stop token six steps in: the rest of its take gives nothing
+                stopped = np.array([[7, 8, 9]], np.int32)
+                gen = server.generate(stopped, max_new_tokens=24)[0, 3:].tolist()
+                futs.append(pool.submit(run, stopped, 24, stop_token_ids=[gen[5]]))
+                for f in list(futs):
+                    f.result(timeout=120)
+            deadline = time.monotonic() + 30
+            while ((cb._rows or cb._tokens_in_flight or cb._inflight_chunks)
+                   and time.monotonic() < deadline):
+                time.sleep(0.01)  # what was in flight past a stop is delivered too
+            yield {"cb": cb, "seen": seen, "handed": handed, "idle_then": idle_then,
+                   "snapshot": cb.snapshot()}
+        finally:
+            cb._dispatch_chunk, cb._pick_depth = orig_dispatch, orig_depth
+            cb.close()
+
+    def test_the_five_sum_to_every_row_step_after_every_dispatch(self, churned):
+        assert len(churned["seen"]) > 10
+        for ledger, steps in churned["seen"]:
+            assert sum(ledger[k] for k in FIVE) == ledger["total"] == 3 * steps
+            assert min(ledger.values()) >= 0
+
+    def test_tokens_and_first_tokens_are_what_the_clients_were_handed(self, churned):
+        snap = churned["snapshot"]
+        assert snap["admitted"] == len(churned["handed"]) == 9
+        assert snap["row_steps"]["tokens"] + snap["admitted"] == sum(churned["handed"])
+        # the stop cut its request short of its budget, so tokens moved to edge
+        assert sum(churned["handed"]) < 60 + 6 + 7 + 3 + 5 + 9 + 10 + 13 + 24
+
+    @pytest.mark.parametrize("name", FIVE[1:])
+    def test_each_kind_of_step_without_a_token_is_counted_in_its_case(self, churned, name):
+        assert churned["snapshot"]["row_steps"][name] > 0
+        if name == "vacant_idle":
+            # the lone row's first programs: two vacant slots and nothing waiting
+            assert churned["idle_then"] >= 2 * 4
+
+    def test_pad_rows_are_counted_as_before_and_leave_the_edge_out(self, churned):
+        snap = churned["snapshot"]
+        ledger = snap["row_steps"]
+        vacant = ledger["vacant_queued"] + ledger["vacant_idle"]
+        assert snap["decode_rows"] * 4 == ledger["total"]
+        assert snap["decode_pad_rows"] * 4 == vacant  # no filling slot, no edge in it
+        assert snap["pad_fraction"] == round(vacant / ledger["total"], 4)
+
+    def test_a_snapshot_is_a_copy_that_does_not_grow_under_its_reader(self, churned):
+        cb = churned["cb"]
+        snap = cb.snapshot()
+        held = dict(snap["row_steps"])
+        cb.generate(np.array([[1, 2, 3]], np.int32), max_new_tokens=6)
+        assert snap["row_steps"] == held
+        assert cb.snapshot()["row_steps"]["total"] > held["total"]
+        assert set(held) == set(FIVE) | {"total"}
+
+
+@pytest.mark.parametrize("attr, name", [
+    ("_admit_prog", "admit"), ("_admit_cached_prog", "admit_cached"),
+    ("_admit_many_prog", "admit_many"), ("_piece_prog", "piece"),
+    ("_piece_flip_prog", "piece_flip"), ("_seed_prog", "seed"),
+    ("_snap_prog", "snap"), ("_spec_prog", "spec_verify")])
+def test_a_programs_xla_module_is_named_for_the_engine_program_it_is(engine, attr, name):
+    """A device trace's ``XLA Modules`` line shows ``jit_<function name>``:
+    each program the engine jits says there which one it is, as the chunk
+    says its depth (``benchmark``'s ``module_share`` tells them by
+    ``chunk_impl_``)."""
+    prog = getattr(engine, attr)
+    assert prog.name == name
+    jitted = prog.jit.__name__
+    assert jitted.startswith("_" + name) and "lambda" not in jitted
+    others = {getattr(engine, a).jit.__name__ for a in (
+        "_admit_prog", "_admit_cached_prog", "_admit_many_prog", "_piece_prog",
+        "_piece_flip_prog", "_seed_prog", "_snap_prog", "_spec_prog") if a != attr}
+    assert jitted not in others and "chunk_impl_" not in jitted

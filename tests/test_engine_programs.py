@@ -521,11 +521,15 @@ class TestLayerMetricFiles:
              "cache.store_load_s_per_program")
 
     @staticmethod
-    def read(name, sources):
+    def spec(name):
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            return json.load(f)
+
+    @classmethod
+    def read(cls, name, sources):
         import importlib
 
-        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
-            spec = json.load(f)
+        spec = cls.spec(name)
         reader = importlib.import_module(f"benchmark.layer_metrics.readers.{spec['reader']}")
         return reader.read(sources, spec)
 
@@ -554,19 +558,20 @@ class TestLayerMetricFiles:
     def test_benchmark_json_lists_them_at_the_end_for_their_cells(self):
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             per_layer = json.load(f)["per_layer"]
-        # PR 32 put them at the end, after the thirty that were there; what
-        # later PRs add follows them (a count of those would break with each)
-        mine = [m for m in per_layer if m["name"] in self.NAMES]
-        assert tuple(m["name"] for m in mine) == self.NAMES
-        assert [per_layer.index(m) for m in mine] == [30, 31, 32]
+        # found by name and cell, never by place: the table may be merged and reordered
+        by_name = {m["name"]: m for m in per_layer}
+        assert len(by_name) == len(per_layer)
+        mine = [by_name[name] for name in self.NAMES]
         share, load_decode, load_deploy = mine
         assert all(m["layer"] == "Compile caches" and m["source"] == "program_counter"
                    for m in mine)
+        assert all(self.spec(name)["reader"] == "metrics_path" for name in self.NAMES)
         assert (share["moves"], share["better"], share["unit"]) == ("setup_s", "higher", "ratio")
         assert (load_decode["moves"], load_decode["better"]) == ("setup_s", "lower")
-        assert share["workloads"] == load_decode["workloads"] == ["mixtral-8x7b-d4.decode"]
-        assert (load_deploy["moves"], load_deploy["workloads"]) == (
-            "pod_ttft_s", ["phi3-mini-4k.deploy"])
+        assert "mixtral-8x7b-d4.decode" in share["workloads"]
+        assert "mixtral-8x7b-d4.decode" in load_decode["workloads"]
+        assert load_deploy["moves"] == "pod_listen_ttft_s"
+        assert "phi3-mini-4k.deploy" in load_deploy["workloads"]
 
 
 # -- the other families' programs are the parent's (PR 43) ---------------------------

@@ -302,12 +302,12 @@ def test_a_pod_without_the_counter_reports_no_kernel_share():
 def test_benchmark_json_lists_the_kernel_share_for_the_longctx_cell():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = [m for m in bench["per_layer"] if m["name"] == METRIC]
-    assert entry == [{"name": METRIC, "unit": "ratio", "better": "higher",
-                      "source": "program_counter", "layer": "Kernels / model step",
-                      "moves": "tokens_per_s", "workloads": [CELL]}]
-    # appended after the fifty-six that were there (what later PRs append follows it)
+    entry = [m for m in bench["per_layer"] if m["name"] == METRIC]  # by name, not by place
+    assert len(entry) == 1 and CELL in entry[0]["workloads"]
+    assert dict(entry[0], workloads=None) == {
+        "name": METRIC, "unit": "ratio", "better": "higher", "source": "program_counter",
+        "layer": "Kernels / model step", "moves": "tokens_per_s", "workloads": None}
     names = [m["name"] for m in bench["per_layer"]]
-    assert names.index(METRIC) == 56 and len(set(names)) == len(names)
+    assert len(set(names)) == len(names)
     assert os.path.exists(os.path.join(ROOT, "benchmark", "layer_metrics", "readers",
                                        "metrics_path.py"))

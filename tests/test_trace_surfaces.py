@@ -6,7 +6,10 @@ backend. A CPU run checks counts, names and that sums close; never a time."""
 
 import dataclasses
 import glob
+import json
 import os
+import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -454,17 +457,17 @@ class TestLayerMetricFiles:
     # name -> (what the hand-made dumps below give, cell, layer, moves, better)
     FRONT, LOADER, CACHE = "CLI + Serving front", "Loader", "Compile caches"
     WANT = {
-        "front.interpreter_s": (0.4, DEPLOY, FRONT, "pod_ttft_s", "lower"),
-        "front.backend_devices_s": (8.5, DEPLOY, FRONT, "pod_ttft_s", "lower"),
-        "front.first_token_s": (26.5, DEPLOY, FRONT, "pod_ttft_s", "lower"),
-        "loader.shards_share": (0.9, DEPLOY, LOADER, "pod_ttft_s", "higher"),
-        "loader.idle_share": (0.2, DEPLOY, LOADER, "pod_ttft_s", "lower"),
-        "loader.drain_share": (0.1, DEPLOY, LOADER, "pod_ttft_s", "lower"),
-        "loader.backpressure_share": (0.05, DEPLOY, LOADER, "pod_ttft_s", "lower"),
-        "loader.assemble_share": (0.01, DEPLOY, LOADER, "pod_ttft_s", "lower"),
-        "cache.store_deserialize_s_per_program": (3.0, DEPLOY, CACHE, "pod_ttft_s", "lower"),
-        "cache.store_mb_per_program": (20.0, DEPLOY, CACHE, "pod_ttft_s", "lower"),
-        "device.idle_named_share.deploy": (0.75, DEPLOY, "Device", "pod_ttft_s", "higher"),
+        "front.interpreter_s": (0.4, DEPLOY, FRONT, "pod_listen_ttft_s", "lower"),
+        "front.backend_devices_s": (8.5, DEPLOY, FRONT, "pod_listen_ttft_s", "lower"),
+        "front.first_token_s": (26.5, DEPLOY, FRONT, "pod_listen_ttft_s", "lower"),
+        "loader.shards_share": (0.9, DEPLOY, LOADER, "pod_listen_ttft_s", "higher"),
+        "loader.idle_share": (0.2, DEPLOY, LOADER, "pod_listen_ttft_s", "lower"),
+        "loader.drain_share": (0.1, DEPLOY, LOADER, "pod_listen_ttft_s", "lower"),
+        "loader.backpressure_share": (0.05, DEPLOY, LOADER, "pod_listen_ttft_s", "lower"),
+        "loader.assemble_share": (0.01, DEPLOY, LOADER, "pod_listen_ttft_s", "lower"),
+        "cache.store_deserialize_s_per_program": (3.0, DEPLOY, CACHE, "pod_listen_ttft_s", "lower"),
+        "cache.store_mb_per_program": (20.0, DEPLOY, CACHE, "pod_listen_ttft_s", "lower"),
+        "device.idle_named_share.deploy": (0.75, DEPLOY, "Device", "pod_listen_ttft_s", "higher"),
         "loader.load_gbps.decode": (0.45, DECODE, LOADER, "setup_s", "higher"),
         "loader.idle_share.decode": (0.25, DECODE, LOADER, "setup_s", "lower"),
         "loader.assemble_share.decode": (0.5, DECODE, LOADER, "setup_s", "lower"),
@@ -531,11 +534,209 @@ class TestLayerMetricFiles:
 
         with open(os.path.join(self.ROOT, "BENCHMARK.json")) as f:
             per_layer = json.load(f)["per_layer"]
-        mine = per_layer[57:73]  # after the 57 that were there, in ISSUE 40's order
-        assert [m["name"] for m in mine] == list(self.WANT)
-        for m in mine:
-            _, cell, layer, moves, better = self.WANT[m["name"]]
-            assert (m["workloads"], m["layer"], m["moves"], m["better"]) == (
-                [cell], layer, moves, better), m["name"]
-            assert m["source"] == ("device_trace" if m["name"].startswith("device.")
+        by_name = {m["name"]: m for m in per_layer}  # found by name and cell, never by place
+        assert len(by_name) == len(per_layer)
+        for name, (_, cell, layer, moves, better) in self.WANT.items():
+            m = by_name[name]
+            assert cell in m["workloads"], name
+            assert (m["layer"], m["moves"], m["better"]) == (layer, moves, better), name
+            assert m["source"] == ("device_trace" if name.startswith("device.")
                                    else "program_counter")
+
+
+class TestProfileEdges:
+    """The pod alone knows where a capture begins and ends: ``/metrics`` carries
+    the newest one under ``profile``, with the engines' counters read inside the
+    profiler's own start and stop."""
+
+    @pytest.fixture(scope="class")
+    def captured(self, server, tmp_path_factory):
+        d = tmp_path_factory.mktemp("profile_edges")
+        sset = ServerSet({"m": server}, continuous_batch=True, max_slots=2,
+                         stream_chunk_size=4, trace_dir=str(d / "traces"))
+        port = free_port()
+        httpd = serve(sset, listen=f"127.0.0.1:{port}")
+        base = f"http://127.0.0.1:{port}"
+        try:
+            generate(base)
+            never = requests.get(base + "/metrics").json()
+            never_text = requests.get(base + "/metrics?format=prometheus").text
+            first = requests.post(base + "/v1/profile", json={"seconds": 0}, timeout=300)
+            once = requests.get(base + "/metrics").json()
+            generate(base, n=12)
+            second = requests.post(base + "/v1/profile", json={"seconds": 0.2}, timeout=300)
+            yield {"never": never, "never_text": never_text, "first": first, "once": once,
+                   "second": second, "twice": requests.get(base + "/metrics").json(),
+                   "text": requests.get(base + "/metrics?format=prometheus").text}
+        finally:
+            for cb in list(sset.cbatchers.values()):
+                cb.close()
+            httpd.shutdown()
+
+    def test_a_pod_never_profiled_has_no_profile_key(self, captured):
+        assert "profile" not in captured["never"]
+        assert "profile" not in captured["never_text"]
+        assert captured["never"]["m"]["continuous"]["row_steps"]["total"] > 0
+
+    def test_the_response_keeps_its_keys(self, captured):
+        assert captured["first"].status_code == captured["second"].status_code == 200
+        assert set(captured["first"].json()) == {"trace_dir"}
+
+    def test_the_block_holds_the_two_calls_times_and_the_engines_at_both_edges(self, captured):
+        profile = captured["once"]["profile"]
+        assert set(profile) == {"captures", "start_s", "stop_s", "traced_s", "at_start", "at_stop"}
+        assert profile["captures"] == 1
+        assert profile["start_s"] >= 0 and profile["stop_s"] >= 0 and profile["traced_s"] >= 0
+        for edge in ("at_start", "at_stop"):
+            # the shape of /metrics itself: the benchmark's metrics_path reads it as it is
+            assert set(profile[edge]) == {"m"} and set(profile[edge]["m"]) == {"continuous"}
+            ledger = profile[edge]["m"]["continuous"]["row_steps"]
+            assert sum(ledger[k] for k in ledger if k != "total") == ledger["total"] > 0
+
+    def test_at_stop_is_not_before_at_start_and_the_newest_capture_stands(self, captured):
+        profile = captured["twice"]["profile"]
+        assert profile["captures"] == 2 and profile["traced_s"] >= 0.2
+        start, stop = (profile[e]["m"]["continuous"] for e in ("at_start", "at_stop"))
+        for key in ("dispatches", "chunks", "admitted", "decode_rows"):
+            assert stop[key] >= start[key], key
+        assert all(stop["row_steps"][k] >= start["row_steps"][k] for k in stop["row_steps"])
+        # the second capture's first edge lies after the first capture's last
+        earlier = captured["once"]["profile"]["at_stop"]["m"]["continuous"]
+        assert start["row_steps"]["total"] > earlier["row_steps"]["total"]
+
+    def test_the_text_view_renders_the_times_and_leaves_the_dumps_out(self, captured):
+        text = captured["text"]
+        # a top-level block renders as its keys under model="<block>", like ``startup``
+        lines = [l for l in text.splitlines() if 'model="profile"' in l]
+        assert {l.split("{")[0] for l in lines} == {
+            "modelx_captures", "modelx_start_s", "modelx_stop_s", "modelx_traced_s"}
+        assert 'modelx_captures{model="profile"} 2' in lines
+        assert not any("at_start" in l or "at_stop" in l for l in text.splitlines())
+        # the engines' own series stand once
+        assert sum(1 for l in text.splitlines()
+                   if "row_steps_total" in l and not l.startswith("#")) == 1
+
+
+class TestRowStepMetricFiles:
+    """The seven per-layer metrics ISSUE 58 adds: six are data for
+    ``metrics_path`` over the profile block's two dumps, the seventh reads the
+    reduced trace's modules; a parent's sources give nothing."""
+
+    ROOT = TestLayerMetricFiles.ROOT
+    TOKEN_CELLS = ["mixtral-8x7b-d4.decode", "laguna-s-2.1-ep2-d5.reason",
+                   "minicpm-sala-d12.longctx", "deepseek-v2-ep8-d5.longdoc",
+                   "nemotron-3-super-ep4-d11.agent", "deepseek-v3.2-exp-ep16-d5.sparsedoc",
+                   "mimo-v2-flash-ep16-d7.longcode"]
+    # the cells whose traced window never lacks an admission (6 and 3 at the least over 4,000
+    # draws of the generator): four are primed in the lead-in, .reason's 4 s hold 3-13 and may
+    # hold none, and a traced line may not lack a metric its cell is listed for
+    ADMITTING_CELLS = ["mixtral-8x7b-d4.decode", "nemotron-3-super-ep4-d11.agent"]
+    # name -> (what the hand-made sources give, unit, better, source)
+    WANT = {
+        "engine.token_share": (0.9, "ratio", "higher", "program_counter"),
+        "engine.edge_share": (0.02, "ratio", "lower", "program_counter"),
+        "engine.filling_share": (0.01, "ratio", "lower", "program_counter"),
+        "engine.vacant_queued_share": (0.03, "ratio", "lower", "program_counter"),
+        "engine.vacant_idle_share": (0.04, "ratio", "lower", "program_counter"),
+        "engine.turnover_row_steps": (25.0, "row-steps", "lower", "program_counter"),
+        "engine.nonchunk_device_share": (0.125, "ratio", "lower", "device_trace"),
+    }
+
+    @staticmethod
+    def sources() -> dict:
+        def dump(tokens, edge, filling, queued, idle, admitted):
+            return {"default": {"continuous": {"admitted": admitted, "row_steps": {
+                "tokens": tokens, "edge": edge, "filling": filling, "vacant_queued": queued,
+                "vacant_idle": idle, "total": tokens + edge + filling + queued + idle}}}}
+        profile = {"captures": 1, "start_s": 0.5, "stop_s": 90.0, "traced_s": 4.0,
+                   "at_start": dump(5000, 700, 100, 100, 100, 40),
+                   "at_stop": dump(14000, 900, 200, 400, 500, 80)}
+        return {"model": "default", "metrics_after": {"profile": profile},
+                "trace": {"window_s": 4.0, "modules": {
+                    "jit__chunk_impl_d4": {"seconds": 2.5, "count": 5},
+                    "jit__chunk_impl_s12": {"seconds": 1.0, "count": 9},
+                    "jit__admit_nosmall": {"seconds": 0.3, "count": 7},
+                    "jit__piece_impl": {"seconds": 0.2, "count": 2}}}}
+
+    @pytest.mark.parametrize("name", list(WANT))
+    def test_reads_the_growth_between_the_profilers_edges(self, name):
+        assert TestLayerMetricFiles.read(name, self.sources()) == pytest.approx(self.WANT[name][0])
+
+    def test_the_five_shares_sum_to_one(self):
+        shares = [TestLayerMetricFiles.read(name, self.sources()) for name in list(self.WANT)[:5]]
+        assert sum(shares) == pytest.approx(1.0, abs=1e-9)
+
+    @pytest.mark.parametrize("name", list(WANT))
+    def test_a_parent_an_untraced_pod_and_a_window_without_admissions_read_nothing(self, name):
+        counters = {"default": {"continuous": {"admitted": 9, "decode_rows": 64,
+                                               "decode_pad_rows": 3}}}
+        parent = {"model": "default", "metrics_before": counters, "metrics_after": counters,
+                  "trace_span": {"metrics_before": counters, "metrics_after": counters},
+                  "trace": {"window_s": 4.0, "modules": {}}}
+        assert TestLayerMetricFiles.read(name, parent) is None  # no profile block, no module
+        # a pod of this PR whose dumps lack the ledger (another engine's): nothing raises
+        bare = dict(parent, metrics_after={"profile": {"at_start": counters, "at_stop": counters}})
+        assert TestLayerMetricFiles.read(name, bare) is None
+        assert TestLayerMetricFiles.read(name, {}) is None
+        if name == "engine.turnover_row_steps":
+            primed = self.sources()
+            edges = primed["metrics_after"]["profile"]
+            edges["at_stop"]["default"]["continuous"]["admitted"] = 40  # nothing admitted
+            assert TestLayerMetricFiles.read(name, primed) is None
+
+    @pytest.mark.parametrize("name", list(WANT))
+    def test_benchmark_json_lists_each_for_the_token_cells_that_give_it_a_reading(self, name):
+        import json
+
+        with open(os.path.join(self.ROOT, "BENCHMARK.json")) as f:
+            bench = json.load(f)
+        found = [m for m in bench["per_layer"] if m["name"] == name]  # by name, never by place
+        assert len(found) == 1
+        _, unit, better, source = self.WANT[name]
+        assert {k: v for k, v in found[0].items() if k != "workloads"} == {
+            "name": name, "unit": unit, "better": better, "source": source,
+            "layer": "Engine", "moves": "tokens_per_s"}
+        listed = self.ADMITTING_CELLS if name == "engine.turnover_row_steps" else self.TOKEN_CELLS
+        for cell in self.TOKEN_CELLS:
+            assert (cell in found[0]["workloads"]) == (cell in listed)
+        assert "phi3-mini-4k.deploy" not in found[0]["workloads"]
+        with open(os.path.join(self.ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            spec = json.load(f)
+        if source == "device_trace":
+            assert spec == {"reader": "module_share", "excluding": "chunk_impl_"}
+        else:
+            assert (spec["reader"], spec["before"], spec["after"]) == (
+                "metrics_path", "metrics_after.profile.at_start", "metrics_after.profile.at_stop")
+
+
+def test_a_rehearsed_traced_cells_last_line_carries_the_six_counter_metrics():
+    """``mixtral-8x7b-d4.decode`` walked at its tiny preset with ``--trace 1``: the pod's
+    profile block reaches the benchmark's readers through ``metrics_after`` as it is."""
+    root = TestLayerMetricFiles.ROOT
+    out = subprocess.run(
+        [sys.executable, os.path.join(root, "benchmark", "run.py"), "--workload",
+         "mixtral-8x7b-d4.decode", "--rehearse", "--trace", "1"],
+        # one CPU device, as a pod finds it (the suite's eight virtual ones slow the window)
+        env=dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS="",
+                 PYTHONPATH=root + os.pathsep + os.environ.get("PYTHONPATH", "")),
+        capture_output=True, text=True, timeout=900)
+    assert out.returncode == 0, out.stderr[-3000:]
+    lines = [json.loads(line) for line in out.stdout.strip().splitlines()]
+    last = lines[-1]
+    assert last["rehearsal"] and last["failed"] == 0 and last["attempted"] > 0
+    metrics = {k: v["value"] for k, v in last["metrics"].items()}
+    shares = [metrics["engine." + k + "_share"]
+              for k in ("token", "edge", "filling", "vacant_queued", "vacant_idle")]
+    assert sum(shares) == pytest.approx(1.0, abs=1e-6) and min(shares) >= 0
+    assert 0.3 < metrics["engine.token_share"] < 1  # four clients, requests of 24-48 tokens
+    assert metrics["engine.edge_share"] > 0  # a budget ends inside a program
+    assert metrics["engine.turnover_row_steps"] > 0
+    assert last["metrics"]["engine.turnover_row_steps"]["unit"] == "row-steps"
+    # the vacant slots the ledger counts are the rows pad_fraction calls idle, over the
+    # pod's own edges (the two spans differ by the profiler's start: close, not equal)
+    assert (metrics["engine.vacant_queued_share"] + metrics["engine.vacant_idle_share"]
+            == pytest.approx(metrics["engine.pad_fraction"], abs=0.1))
+    # a CPU trace is no device trace: the seventh goes on the rehearsal's own line
+    rehearsed = next(l for l in lines if l.get("phase", "").startswith("rehearsed_on_a_cpu"))
+    assert 0 <= rehearsed["engine.nonchunk_device_share"] < 1
+    assert "engine.nonchunk_device_share" not in metrics
